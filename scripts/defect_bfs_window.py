@@ -28,10 +28,9 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tpuvsr.platform_select import ensure_backend
+import jax  # noqa: E402
 
-backend = ensure_backend(log=lambda m: print(f"[defect_window] {m}",
-                                             flush=True))
+backend = jax.default_backend()
 
 from tpuvsr.engine.paged_bfs import PagedBFS          # noqa: E402
 from tpuvsr.engine.spec import load_spec              # noqa: E402

@@ -30,9 +30,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tpuvsr.platform_select import force_cpu  # noqa: E402
-force_cpu()
-
 from tpuvsr.analysis import run_lint  # noqa: E402
 from tpuvsr.engine.spec import SpecModel  # noqa: E402
 from tpuvsr.frontend.cfg import parse_cfg_file, parse_cfg_text  # noqa: E402

@@ -17,7 +17,7 @@ property leaves (lower/compile) -> host fair-SCC.
 
 Writes/merges scripts/liveness_shipped.json.
 
-Usage: [TPUVSR_TPU=1] python scripts/liveness_shipped.py [a01|i01]
+Usage: [JAX_PLATFORMS=cpu] python scripts/liveness_shipped.py [a01|i01]
            [max_states] [tile] [chunk_tiles] [values] [timer]
 (values/timer override the shipped constants when given)
 """
@@ -30,14 +30,9 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tpuvsr.platform_select import ensure_backend, force_cpu
+import jax  # noqa: E402
 
-if os.environ.get("TPUVSR_TPU") == "1":
-    backend = ensure_backend(log=lambda m: print(f"[liveness] {m}",
-                                                 flush=True))
-else:
-    force_cpu()
-    backend = "cpu"
+backend = jax.default_backend()
 
 from tpuvsr.engine.device_liveness import DeviceGraph   # noqa: E402
 from tpuvsr.engine.liveness import liveness_check       # noqa: E402

@@ -39,10 +39,9 @@ STUB = "--stub" in sys.argv
 if STUB:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from tpuvsr.platform_select import ensure_backend  # noqa: E402
+import jax  # noqa: E402
 
-backend = ensure_backend(log=lambda m: print(f"[liveness] {m}",
-                                             flush=True))
+backend = jax.default_backend()
 
 from tpuvsr.engine.device_liveness import DeviceGraph  # noqa: E402
 from tpuvsr.engine.liveness import build_graph, liveness_check  # noqa: E402

@@ -18,10 +18,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tpuvsr.platform_select import force_cpu
-if os.environ.get("TPUVSR_TPU") != "1":
-    force_cpu()
-
 from tpuvsr.engine.bfs import bfs_check
 from tpuvsr.engine.spec import SpecModel
 from tpuvsr.frontend.cfg import parse_cfg_file, parse_cfg_text

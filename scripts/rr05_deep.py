@@ -13,7 +13,7 @@ oracle; any divergence is an engine regression, not progress).
 
 Writes scripts/rr05_deep.json.
 
-Usage: [TPUVSR_TPU=1] python scripts/rr05_deep.py [seconds] [tile] [chunk_tiles]
+Usage: [JAX_PLATFORMS=cpu] python scripts/rr05_deep.py [seconds] [tile] [chunk_tiles]
 """
 
 import json
@@ -24,9 +24,9 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tpuvsr.platform_select import ensure_backend
+import jax  # noqa: E402
 
-backend = ensure_backend(log=lambda m: print(f"[rr05] {m}", flush=True))
+backend = jax.default_backend()
 
 from tpuvsr.engine.paged_bfs import PagedBFS          # noqa: E402
 

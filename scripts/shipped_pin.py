@@ -13,7 +13,7 @@ This is also the first at-scale run with symmetry canonicalization ON
 
 Writes scripts/shipped_pin.json.
 
-Usage: [TPUVSR_TPU=1] python scripts/shipped_pin.py [seconds] [tile]
+Usage: [JAX_PLATFORMS=cpu] python scripts/shipped_pin.py [seconds] [tile]
            [chunk_tiles]
 """
 
@@ -25,14 +25,9 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tpuvsr.platform_select import ensure_backend, force_cpu
+import jax  # noqa: E402
 
-if os.environ.get("TPUVSR_TPU") == "1":
-    backend = ensure_backend(log=lambda m: print(f"[shipped] {m}",
-                                                 flush=True))
-else:
-    force_cpu()
-    backend = "cpu"
+backend = jax.default_backend()
 
 from tpuvsr.engine.paged_bfs import PagedBFS          # noqa: E402
 from tpuvsr.engine.spec import load_spec              # noqa: E402

@@ -22,10 +22,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tpuvsr.platform_select import force_cpu
-if os.environ.get("TPUVSR_TPU") != "1":
-    force_cpu()
-
 walkers = int(sys.argv[1]) if len(sys.argv) > 1 else 1 << 17
 max_seconds = float(sys.argv[2]) if len(sys.argv) > 2 else 900
 num = int(sys.argv[3]) if len(sys.argv) > 3 else 10**6

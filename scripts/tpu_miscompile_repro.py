@@ -32,7 +32,7 @@ never loses evidence (completed stages are skipped on re-run):
                insert claim race is the culprit and the barrier is the
                fix.
 
-Usage: [TPUVSR_TPU=1] python scripts/tpu_miscompile_repro.py [stage ...]
+Usage: [JAX_PLATFORMS=cpu] python scripts/tpu_miscompile_repro.py [stage ...]
 """
 
 import json
@@ -47,14 +47,9 @@ sys.path.insert(0, REPO)
 # diagnosis runs need the unvalidated width the guard refuses
 os.environ.setdefault("TPUVSR_UNSAFE_TILE", "1")
 
-from tpuvsr.platform_select import ensure_backend, force_cpu  # noqa: E402
+import jax  # noqa: E402
 
-if os.environ.get("TPUVSR_TPU") == "1":
-    backend = ensure_backend(log=lambda m: print(f"[repro] {m}",
-                                                 flush=True))
-else:
-    force_cpu()
-    backend = "cpu"
+backend = jax.default_backend()
 
 OUT = os.environ.get(
     "TPUVSR_REPRO_OUT", os.path.join(REPO, "scripts",

@@ -130,21 +130,31 @@ def test_exact_growth_and_calibration():
 
     e = stub_device_engine(tile_size=16)
     obs = _Obs()
-    # exact growth: observed need 11 for action 0 -> cap align8(11)=16
-    # clamped to T*L_a=16; action 1 untouched
+    # headroom growth: observed need 11 for action 0 -> cap
+    # align8(4*11)=48 clamped to T*L_a=16; action 1 (need 2, cap 8)
+    # untouched
     e._need_seen = np.array([11, 2], np.int64)
     e.expand_caps = [8, 8]
     e._grow_expand(0, obs, lambda m: None)
     assert e.expand_caps[0] == 16 and e.expand_caps[1] == 8
     assert ("expand_buffer", 16) in obs.grows
-    # calibration shrinks onto the observed maxima only when a
-    # representative level was measured and >= 20% of lanes are saved
+    # calibration shrinks over-grown caps onto 4x the observed maxima
+    # only when a representative level was measured and >= 20% of
+    # lanes are saved — and never below the static start (tile lanes
+    # per action; here that is also the full T*L_a=16)
     e.expand_caps = [16, 16]
     e._need_seen = np.array([3, 3], np.int64)
     assert not e._calibrate_caps(obs, lambda m: None,
                                  level_states=16)   # < 4*tile
+    assert not e._calibrate_caps(obs, lambda m: None, level_states=64)
+    assert e.expand_caps == [16, 16]
+    # a wider kernel (8 lanes/action: full T*L_a=128) whose caps grew
+    # to 64 calibrates down to max(static start 16, 4*need)
+    e.kern._lane_count = lambda name: 8
+    e.expand_caps = [64, 64]
+    e._need_seen = np.array([3, 6], np.int64)
     assert e._calibrate_caps(obs, lambda m: None, level_states=64)
-    assert e.expand_caps == [8, 8]      # floor is 8 lanes/action
+    assert e.expand_caps == [16, 24]
     # never shrinks below observation: a second call is a no-op
     assert not e._calibrate_caps(obs, lambda m: None, level_states=64)
 

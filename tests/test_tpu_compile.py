@@ -1,0 +1,156 @@
+"""AOT compiles of the main path for a described v5e:2x2 — the chip's
+compiler asked before the chip (on-chip-measurement guide §2.3).
+
+Nothing runs and no device is attached: each test lowers one program
+of the VSR checker at the defect widths (examples/VSR_defect.cfg:
+R=3, |Values|=3, MAX_MSGS=32 — the bound the defect window ends at)
+and the CLI's default tile, for `topo.devices[0]` (or a 4-device mesh
+of `topo.devices`), and requires the TPU compiler to accept it inside
+the chip's 16 GB.  A compile that passes is not a chip run:
+chip_smoke.py is.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load the TPU library, and xdist workers
+all import this file), the persistent compilation cache is off around
+the compiles (an AOT entry cannot be read back without a chip), and
+everything compiles in the test's own process.
+"""
+
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEFECT_CFG = os.path.join(REPO, "examples", "VSR_defect.cfg")
+HBM_BYTES = 16 << 30
+MAX_MSGS = 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "skip"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec():
+    from tpuvsr.engine.spec import load_spec
+    return load_spec("VSR", DEFECT_CFG)
+
+
+@pytest.fixture(scope="module")
+def engine(spec):
+    from tpuvsr.engine.device_bfs import DeviceBFS
+    return DeviceBFS(spec, max_msgs=MAX_MSGS)    # CLI defaults otherwise
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                       sharding=sharding), tree)
+
+
+def _compile(lowered, what):
+    """Compile, print seconds + memory analysis (CHANGES.md records
+    them), and require the program to fit one chip."""
+    t0 = time.time()
+    compiled = lowered.compile()
+    secs = time.time() - t0
+    ma = compiled.memory_analysis()
+    print(f"\n[tpu-compile] {what}: {secs:.1f}s  "
+          f"args={ma.argument_size_in_bytes} "
+          f"out={ma.output_size_in_bytes} "
+          f"temp={ma.temp_size_in_bytes} "
+          f"code={ma.generated_code_size_in_bytes}")
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"{what} needs {total} bytes of HBM"
+    return compiled
+
+
+def test_fused_level_program(engine, one_chip, no_cache):
+    """DeviceBFS's level program (`_fused_body_factory` inside
+    `_make_level`) at the CLI's default tile/chunk/capacities."""
+    from tpuvsr.engine.fpset import empty_table
+    assert engine.commit == "fused" and engine._pk is not None
+    bufs = engine._alloc_bufs(engine.next_cap)
+    i32 = jnp.zeros((), jnp.int32)
+    args = _shapes(
+        ({"slots": empty_table(engine.fpset_capacity)["slots"]},
+         bufs[0], i32, i32, *bufs, i32, jnp.zeros((), bool)),
+        one_chip)
+    lowered = engine._level.lower(*args, None, None,
+                                  _shapes(i32, one_chip))
+    _compile(lowered, f"fused level program tile={engine.tile} "
+                      f"lanes={engine.L}")
+
+
+def test_fpset_insert(one_chip, no_cache):
+    """`fpset.insert_core` at the default capacity, one tile-sized
+    batch of fingerprints."""
+    from tpuvsr.engine.fpset import empty_table, insert_core
+    cap = 1 << 20
+    args = _shapes((empty_table(cap), jnp.zeros((4096, 4), jnp.uint32),
+                    jnp.zeros((4096,), bool)), one_chip)
+    _compile(jax.jit(insert_core).lower(*args),
+             f"fpset.insert_core cap={cap}")
+
+
+def test_pack_unpack_pair(engine, one_chip, no_cache):
+    """The packed-frontier round trip at the defect layout."""
+    pk = engine._pk
+    zero = engine.codec.zero_state()
+    n = engine.tile
+    dense = _shapes({k: jnp.zeros((n,) + np.shape(v), jnp.int32)
+                     for k, v in zero.items()}, one_chip)
+    rows = _shapes(jnp.zeros((n, pk.words), jnp.uint32), one_chip)
+    _compile(jax.jit(jax.vmap(pk.pack)).lower(dense),
+             f"pack rows={n} words={pk.words}")
+    _compile(jax.jit(jax.vmap(pk.unpack)).lower(rows), f"unpack rows={n}")
+
+
+def test_sharded_step(spec, topo, no_cache):
+    """`step_shard` of ShardedBFS over a 4-device mesh of the
+    described chips: the all_to_all exchange must partition."""
+    from tpuvsr.parallel.sharded_bfs import ShardedBFS
+    mesh = Mesh(np.array(topo.devices), ("d",))
+    eng = ShardedBFS(spec, mesh, max_msgs=MAX_MSGS)
+    D, N = eng.D, eng.N
+    sh = NamedSharding(mesh, P("d"))
+    rows = jnp.zeros((D * N, eng._pk.words), jnp.uint32)
+    col = jnp.zeros((D * N,), jnp.int32)
+    per_dev = jnp.zeros((D,), jnp.int32)
+    args = _shapes(
+        ({"slots": jnp.zeros((D, eng.fp_cap, 5), jnp.uint32)},
+         rows, per_dev, per_dev, rows, col, col, col, per_dev,
+         per_dev), sh)
+    compiled = _compile(eng._step.lower(*args),
+                        f"sharded step D={D} tile={eng.tile}")
+    assert "all-to-all" in compiled.as_text()

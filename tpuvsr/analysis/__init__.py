@@ -34,7 +34,8 @@ Entry points:
   attributes; the bounds fixpoint and independence matrix are
   pure-AST and cached), raises ``LintError`` on error-severity
   findings, caches per spec object, honors ``TPUVSR_LINT=off`` (the
-  CLI's ``-lint=off``).
+  CLI's ``-lint=off``); a kernel-native spec (no AST) logs one line
+  and passes through.
 """
 
 from __future__ import annotations
@@ -60,7 +61,12 @@ def run_lint(spec, passes=None) -> LintReport:
     return report
 
 
-def lint_enabled() -> bool:
+def lint_enabled(spec=None) -> bool:
+    """The speclint gate: off under ``TPUVSR_LINT=off``, and for a
+    kernel-native spec (models/native.py), which has no AST for the
+    passes to read."""
+    if getattr(spec, "native", False):
+        return False
     return os.environ.get("TPUVSR_LINT", "").lower() not in (
         "off", "0", "false", "no")
 
@@ -72,7 +78,10 @@ def preflight(spec, log=None):
     per spec object; raises ``LintError`` if any error-severity finding
     survives.  Returns the report (or None when disabled via
     TPUVSR_LINT=off)."""
-    if not lint_enabled():
+    if not lint_enabled(spec):
+        if getattr(spec, "native", False) and log is not None:
+            log(f"speclint: skipped — native spec {spec.module.name} "
+                f"has no AST (bounds/POR facts not consumed)")
         return None
     cached = getattr(spec, "_speclint_report", None)
     if cached is not None:

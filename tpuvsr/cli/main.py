@@ -869,10 +869,26 @@ def main(argv=None):
         faults.install(args.inject)
     from ..engine.spec import load_spec
     from ..engine.trace import format_trace
-    from ..platform_select import ensure_backend
 
     cfg_path = args.config or os.path.splitext(args.spec)[0] + ".cfg"
     spec = load_spec(args.spec, cfg_path)
+
+    if getattr(spec, "native", False):
+        # a kernel-native spec (models/native.py) has no AST: whatever
+        # needs one is a loud exit 2, never a silently inert flag
+        for flag, on in (("-lint", args.lint == "full"),
+                         ("-bounds on", args.bounds == "on"),
+                         ("-por on", args.por == "on"),
+                         ("-lower", args.lower),
+                         ("-engine interp / -fpset host",
+                          args.engine == "interp"
+                          or args.fpset == "host")):
+            if on:
+                parser.error(
+                    f"{flag} needs the module's AST; the native spec "
+                    f"{spec.module.name!r} is built from its device "
+                    f"kernel alone — pass the .tla file instead of "
+                    f"the module name")
 
     if args.lint == "full":
         # lint-only mode: full report (all five passes), no dispatch
@@ -960,6 +976,7 @@ def main(argv=None):
             "(the walker fleet); this spec resolved to the "
             "interpreter — running plain host simulation")
 
+    device = None
     if engine in ("device", "paged", "sharded"):
         if engine == "sharded":
             # multi-host env (TPUVSR_MH_*): jax.distributed must
@@ -969,8 +986,10 @@ def main(argv=None):
             # rank-agreement degenerates to single-process)
             from ..parallel.multihost import init_from_env
             init_from_env()
-        backend = ensure_backend(log)
-        log(f"backend: {backend}")
+        from ..models.registry import device_doc
+        device = device_doc()
+        log("backend: {platform} ({device_kind} x {device_count})"
+            .format(**device))
     mode = ("trace validation" if args.validate
             else "simulation" if args.simulate else "BFS")
     log(f"spec {spec.module.name}, engine {engine}, {mode}")
@@ -1272,6 +1291,8 @@ def main(argv=None):
         print(f"Error: Invariant {res.violated_invariant} is violated.",
               file=sys.stderr)
         print(format_trace(res.trace))
+    if device:
+        summary["device"] = device
     if args.json:
         print(json.dumps(summary))
     else:

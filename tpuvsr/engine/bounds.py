@@ -41,11 +41,12 @@ def resolve_bounds(spec, req="auto"):
     if req is False or req == "off":
         return None
     from ..analysis import lint_enabled
-    if not lint_enabled():
+    if not lint_enabled(spec):
         if req is True or req == "on":
             raise TLAError(
                 "bounds=on requires the speclint gate: TPUVSR_LINT=off "
-                "/ -lint=off disables the static analysis the "
+                "/ -lint=off (or a native spec, which has no AST) "
+                "disables the static analysis the "
                 "tightened packing and pruned action lists would "
                 "trust (drop -bounds on or re-enable lint)")
         return None

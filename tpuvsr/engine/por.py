@@ -95,11 +95,12 @@ def resolve_por(spec, req="off", *, temporal=False, edges=False,
         raise TLAError(f"por must be 'auto', 'on' or 'off' (got {req!r})")
     forced = req is True or req == "on"
     from ..analysis import lint_enabled
-    if not lint_enabled():
+    if not lint_enabled(spec):
         if forced:
             raise TLAError(
                 "por=on requires the speclint gate: TPUVSR_LINT=off / "
-                "-lint=off disables the static independence analysis "
+                "-lint=off (or a native spec, which has no AST) "
+                "disables the static independence analysis "
                 "the ample-set filter would trust (drop -por on or "
                 "re-enable lint)")
         return None

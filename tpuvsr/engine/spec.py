@@ -215,6 +215,16 @@ def _synth_def(name, body, modname):
 
 
 def load_spec(tla_path: str, cfg_path: str) -> SpecModel:
+    """Bind a module to a cfg.  `tla_path` is a ``.tla`` file — or,
+    when no such file exists, the name of a module the kernel registry
+    knows: that builds the AST-free native spec (models/native.py)
+    from committed files.  Anything else fails as a missing file."""
+    import os
     from ..frontend.cfg import parse_cfg_file
+    if not os.path.exists(tla_path) and tla_path.isidentifier():
+        from ..models.native import native_spec
+        spec = native_spec(tla_path, parse_cfg_file(cfg_path))
+        if spec is not None:
+            return spec
     from ..frontend.parser import parse_module_file
     return SpecModel(parse_module_file(tla_path), parse_cfg_file(cfg_path))

@@ -1,0 +1,174 @@
+"""Kernel-native specs (ROADMAP R1 route B): a ``SpecModel``-shaped
+object for a registered module name plus a cfg, built from committed
+files only — no ``.tla``, no AST.
+
+The device engines never evaluate the spec's AST on the hot path: they
+take the transition relation, fingerprints and invariants from the
+hand kernel (``models/registry._resolve``) and read only a narrow
+facade from ``spec`` — cfg, constants, module name, init states, action
+names/locations for trace printing, and a host-side invariant check on
+decoded states.  This module provides exactly that facade:
+
+* init states come from a committed TLC-format trace whose entry 1 is
+  the module's complete initial state (``INIT_TRACES``); the codec
+  round trip proves it fits the cfg's constants, and the pinned level
+  sizes (scripts/pinned_levels_small.json, scripts/defect_window.json)
+  are the check that it is the right one;
+* ``check_invariants`` runs the kernel's own ``invariant_fn`` on
+  ``codec.encode(state)``; ``walk_trace`` holds the kernel to a
+  recorded TLC trace (the one committed oracle that does not come from
+  the device engine itself);
+* anything that needs the AST is refused loudly: SYMMETRY, PROPERTY /
+  SPECIFICATION, the speclint passes (``analysis.preflight`` logs one
+  line and returns None; ``-bounds on`` / ``-por on`` / ``-lint`` exit
+  2), and the interpreter engine.
+
+``engine.spec.load_spec`` resolves here when its spec argument is not
+an existing file but a module name the registry knows.  Only VSR has a
+committed init trace today; the other seven modules need one each.
+"""
+
+from __future__ import annotations
+
+import os
+import re
+
+import numpy as np
+
+from ..core.values import TLAError
+from ..engine.spec import Action
+from ..frontend.tla_ast import Module
+from ..interp.evalr import Evaluator
+from .registry import REPO, _resolve
+
+# module name -> committed TLC trace whose entry 1 is the full Init state
+INIT_TRACES = {"VSR": os.path.join(REPO, "examples",
+                                   "found_violation_trace.txt")}
+
+_LOCATION = re.compile(
+    r'name \|-> "(\w+)",\s*location \|-> "(line [^"]+)"')
+
+
+class NativeSpec:
+    """The facade the device engines read from a spec (see module doc)."""
+
+    native = True
+
+    def __init__(self, name, cfg):
+        for what, val in (("SYMMETRY", cfg.symmetry),
+                          ("PROPERTY", cfg.properties),
+                          ("SPECIFICATION", cfg.specification)):
+            if val:
+                raise TLAError(
+                    f"native spec {name!r}: cfg {what} needs the .tla "
+                    f"module's definitions; pass the module file "
+                    f"instead of its name")
+        self.module = Module(name=name)
+        self.cfg = cfg
+        self.ev = Evaluator(self.module, cfg.constants)
+        self.temporal_props = []
+        self.fairness = []
+        self.symmetry_perms = []
+        self._codec_cls, self._kern_cls = _resolve(name)
+        with open(INIT_TRACES[name]) as f:
+            self._trace_text = f.read()
+        locs = dict(_LOCATION.findall(self._trace_text))
+        self.actions = [
+            Action(name=a, expr=None,
+                   location=locs.get(a, f"native kernel of module {name}"))
+            for a in self._kern_cls.action_names]
+        self._models = {}        # max_msgs -> (codec, kernel, inv fns)
+
+    # -- host-side kernel binding --------------------------------------
+    def model(self, max_msgs):
+        """(codec, kernel, {invariant name -> jitted fn}) at a message
+        table bound, cached."""
+        if max_msgs not in self._models:
+            codec = self._codec_cls(self.cfg.constants, max_msgs=max_msgs)
+            self._models[max_msgs] = (codec, self._kern_cls(codec), {})
+        return self._models[max_msgs]
+
+    def _model_for(self, state):
+        """The cached model whose (power-of-two) message table holds
+        `state`'s bag."""
+        n = len(state["messages"].items)
+        return self.model(1 << max(4, (n - 1).bit_length()))
+
+    # -- checkable interface (engine/spec.SpecModel's) ------------------
+    def init_states(self):
+        from ..frontend.trace_parse import parse_trace_text
+        first = re.split(r"\],\s*\n\[", self._trace_text.strip(), 1)[0]
+        st = parse_trace_text(first + "]\n>>", self)[0].state
+        codec, _, _ = self._model_for(st)
+        try:
+            fits = codec.decode(codec.encode(st)) == st
+        except (TLAError, KeyError, IndexError):
+            fits = False
+        if not fits:
+            raise TLAError(
+                f"native spec {self.module.name!r}: the committed init "
+                f"state ({INIT_TRACES[self.module.name]}) does not fit "
+                f"this cfg's constants")
+        yield st
+
+    def check_invariants(self, state):
+        """Name of the first cfg invariant the kernel's invariant fn
+        rejects on the encoded state, or None."""
+        import jax
+        codec, kern, inv = self._model_for(state)
+        dense = codec.encode(state)
+        for name in self.cfg.invariants:
+            if name not in inv:
+                inv[name] = jax.jit(kern.invariant_fn([name]))
+            if not bool(inv[name](dense)):
+                return name
+        return None
+
+
+def native_spec(name, cfg):
+    """NativeSpec for a registered module name, None for a name the
+    registry does not know (the caller then fails as on any missing
+    file)."""
+    try:
+        _resolve(name)
+    except KeyError:
+        return None
+    if name not in INIT_TRACES:
+        raise TLAError(
+            f"module {name!r} has a device kernel but no committed "
+            f"init trace (models/native.INIT_TRACES): pass its .tla "
+            f"file")
+    return NativeSpec(name, cfg)
+
+
+def walk_trace(spec, path, max_msgs=64):
+    """Walk a recorded TLC trace through the kernel in one batch.
+
+    Encodes every recorded state, expands all but the last with ONE
+    ``kern.step_batch`` call, and requires that recorded state i+1 is
+    among the successors of state i produced by lanes of the recorded
+    action.  Returns (entries, ok): ``ok[i]`` is the conjunction of the
+    cfg invariants on state i, from the kernel's invariant fn.  Raises
+    TLAError on the first step the kernel cannot reproduce."""
+    import jax
+    from ..frontend.trace_parse import parse_trace_file
+    entries = parse_trace_file(path, spec)
+    codec, kern, _ = spec.model(max_msgs)
+    dense = [codec.encode(e.state) for e in entries]
+    batch = {k: np.stack([d[k] for d in dense]) for k in dense[0]}
+    succs, en = kern.step_batch({k: v[:-1] for k, v in batch.items()})
+    en = np.asarray(en)
+    succs = {k: np.asarray(v) for k, v in succs.items()}
+    lane_action = np.asarray(kern.lane_action)
+    for i, e in enumerate(entries[1:]):
+        aid = kern.action_names.index(e.action_name)
+        lanes = np.nonzero(en[i] & (lane_action == aid))[0]
+        if not any(int(succs["err"][i, ln]) == 0 and codec.decode(
+                {k: v[i, ln] for k, v in succs.items()}) == e.state
+                for ln in lanes):
+            raise TLAError(
+                f"kernel trace walk: no {e.action_name} lane of state "
+                f"{e.position - 1} ({len(lanes)} enabled) reproduces "
+                f"recorded state {e.position}")
+    inv = jax.jit(jax.vmap(kern.invariant_fn(list(spec.cfg.invariants))))
+    return entries, np.asarray(inv(batch))

@@ -25,30 +25,36 @@ import numpy as np
 
 import jax
 
-_cache_configured = False
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def ensure_compile_cache():
     """Persistent-compilation-cache setup, shared by every engine entry
-    point (device_bfs, device_sim, sharded_bfs, make_model).
+    point (device_bfs, device_sim, sharded_bfs, make_model) and the
+    tests.
 
-    Jitted kernels (level pass, sim chunk, sharded step) take minutes
-    to build on a single CPU core; persisting compiled binaries lets
-    bench/CLI/tests/hunt scripts share one cache.  Idempotent, never
-    overrides an explicitly configured cache dir, and honors
-    ``TPUVSR_JAX_CACHE=""`` (empty) to disable entirely.  This used to
-    run unconditionally at import time, which mutated global jax config
-    for any process that merely imported the registry."""
-    global _cache_configured
-    if _cache_configured or jax.config.jax_compilation_cache_dir:
-        return
-    cache_dir = os.environ.get("TPUVSR_JAX_CACHE",
-                               os.path.expanduser("~/.cache/tpuvsr_jax"))
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    The level kernels take minutes to compile; persisting the binaries
+    lets CLI, tests, scripts and chip_smoke.py share one cache.  When
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already keeps its cache
+    there and nothing is set in code; otherwise the cache is the fixed
+    ``<checkout>/.jax_cache`` (the path is part of the cache key, so it
+    must not move).  Returns the directory in use."""
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(REPO, ".jax_cache"))
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           5.0)
-    _cache_configured = True
+    return jax.config.jax_compilation_cache_dir
+
+
+def device_doc():
+    """The backend this process really has, as JAX reports it: stamped
+    on the CLI log and result, ``run_start`` and ``job_started``."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
 
 
 def ensure_debug_flags():
@@ -94,14 +100,6 @@ def has_device_model(spec) -> bool:
         codec_cls(spec.ev.constants)
         return True
     except (KeyError, TLAError):
-        return False
-    except ImportError as e:
-        # a registered module whose implementation cannot import is a
-        # packaging bug — degrade to the interpreter but say so loudly
-        import sys
-        print(f"[tpuvsr] WARNING: device model for {spec.module.name} "
-              f"failed to import ({e}); falling back to the interpreter",
-              file=sys.stderr)
         return False
 
 

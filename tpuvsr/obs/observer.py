@@ -176,9 +176,17 @@ class RunObserver:
         self._t0 = t0
         self._last_progress = time.time()
         self.backend = backend or self.backend or "host"
+        # the device this process really has, as JAX reports it (null
+        # on the host engines, which never touch JAX)
+        device = dict.fromkeys(("platform", "device_kind",
+                                "device_count"))
+        if self.backend != "host":
+            from ..models.registry import device_doc
+            device = device_doc()
         self.journal.write("run_start", schema=JOURNAL_SCHEMA,
                            engine=self.engine, module=self.module,
                            backend=self.backend, resumed=bool(resumed),
+                           **device,
                            pipeline=int(self.pipeline or 1),
                            pack=bool(self.pack),
                            commit=self.commit,

@@ -39,7 +39,7 @@ from .queue import (CLAIMABLE, HEARTBEAT_TIMEOUT, LEGAL, STATES,
                     TERMINAL, Job, JobQueue, QueueError)
 from .scheduler import (Decision, DevicePool, Scheduler,
                         advise_backend, detect_tpu_devices,
-                        pow2_floor, watch_backend)
+                        pow2_floor)
 from .worker import JobObserver, Worker, result_summary, \
     trace_to_jsonable
 
@@ -47,7 +47,7 @@ __all__ = [
     "Job", "JobQueue", "QueueError", "STATES", "TERMINAL", "CLAIMABLE",
     "LEGAL", "HEARTBEAT_TIMEOUT", "DevicePool", "Scheduler",
     "Decision", "advise_backend",
-    "detect_tpu_devices", "pow2_floor", "watch_backend", "Worker",
+    "detect_tpu_devices", "pow2_floor", "Worker",
     "JobObserver",
     "result_summary", "trace_to_jsonable",
 ]

@@ -496,8 +496,8 @@ class JobQueue:
         queue with its rescue-checkpoint handoff attached (the next
         attempt resumes, not restarts).  ``devices`` lets the scheduler
         reshape an elastic job's next mesh; ``uncount`` refunds the
-        attempt (a failure that never really ran, e.g. a tunnel
-        flap)."""
+        attempt (a failure that never really ran, e.g. a lost
+        machine)."""
         job = self.get(job_id)
         fields = {"reason": reason}
         if rescue is not None:

@@ -171,8 +171,8 @@ class Worker:
         self.bench_dir = bench_dir
         self.tpu_devices = tpu_devices
         # shell jobs only: gate(job, rc) -> True means the failure
-        # never really ran (e.g. a dead tunnel) — refund the attempt
-        # and requeue instead of burning one (tpu_queue flap logic)
+        # never really ran (e.g. a lost machine) — refund the attempt
+        # and requeue instead of burning one
         self.shell_retry_gate = shell_retry_gate
         self._log = log
         self._specs = {}             # job_id -> loaded spec (admission)
@@ -242,6 +242,16 @@ class Worker:
         finally:
             j.close()
 
+    def _placement(self, job):
+        """(backend, why) for a device job's ``job_started`` event:
+        the platform this process really has, as JAX reports it, and
+        the scheduler's advisory (which never places anything —
+        ROADMAP D1) as the reason string."""
+        from ..models.registry import device_doc
+        _advised, why = advise_backend(job, tpu_devices=self.tpu_devices,
+                                       bench_dir=self.bench_dir)
+        return device_doc()["platform"], why
+
     # -- admission (the speclint gate) ---------------------------------
     def _load_spec(self, job):
         if job.flags.get("stub"):
@@ -298,7 +308,7 @@ class Worker:
                 self._journal(job, "job_done", state="failed",
                               reason="no-device-kernel")
                 return
-        if lint_enabled():
+        if lint_enabled(spec):
             report = run_lint(spec)
             if report.exit_code:
                 findings = [f"{f.passname}: {f.message}"
@@ -579,8 +589,7 @@ class Worker:
                                             "sharded") else "device"
         alloc = self.scheduler.alloc_for(job)
         self.pool.alloc(job.job_id, alloc)
-        backend, why = advise_backend(job, tpu_devices=self.tpu_devices,
-                                      bench_dir=self.bench_dir)
+        backend, why = self._placement(job)
         self._journal(job, "job_started", attempt=job.attempts,
                       devices=alloc, backend=backend,
                       placement=why)
@@ -706,8 +715,7 @@ class Worker:
         spec = self._specs.get(job.job_id) or self._load_spec(job)
         alloc = self.scheduler.alloc_for(job)
         self.pool.alloc(job.job_id, alloc)
-        backend, why = advise_backend(job, tpu_devices=self.tpu_devices,
-                                      bench_dir=self.bench_dir)
+        backend, why = self._placement(job)
         self._journal(job, "job_started", attempt=job.attempts,
                       devices=alloc, backend=backend, placement=why)
         flags = job.flags
@@ -801,8 +809,7 @@ class Worker:
         spec = self._specs.get(job.job_id) or self._load_spec(job)
         alloc = self.scheduler.alloc_for(job)
         self.pool.alloc(job.job_id, alloc)
-        backend, why = advise_backend(job, tpu_devices=self.tpu_devices,
-                                      bench_dir=self.bench_dir)
+        backend, why = self._placement(job)
         self._journal(job, "job_started", attempt=job.attempts,
                       devices=alloc, backend=backend, placement=why)
         flags = job.flags
@@ -948,7 +955,7 @@ class Worker:
             return
         if state == "failed" and self.shell_retry_gate is not None \
                 and self.shell_retry_gate(job, rc):
-            # the failure never really ran (e.g. a tunnel flap):
+            # the failure never really ran (e.g. a lost machine):
             # refund the attempt and requeue
             self.queue.requeue(job.job_id, reason="retry-uncounted",
                                uncount=True)
