@@ -589,12 +589,6 @@ def _attach_and_lift():
                     loaded = json.load(f)
             except ValueError:
                 continue
-            if key == "tpu_run":
-                # a captured full bench run carries its own attachments;
-                # strip them so re-capturing stdout back to
-                # bench_tpu_run.json can never nest runs recursively
-                for k, _f in ATTACHMENTS:
-                    loaded.pop(k, None)
             RESULT[key] = loaded
     # walker-fleet simulation headline (ISSUE 7): walkers / walks/s /
     # split mode of the fleet-rebuilt sim_scale probe lifted to the

@@ -11,7 +11,7 @@ of the reference's headline workload.
 
 Checkpoint/resume: the run snapshots at level boundaries
 (scripts/defect_window_ckpt) and RESUMES from the snapshot when one
-exists — a tunnel flap mid-window costs only the partial level, and
+exists — a lost machine mid-window costs only the partial level, and
 re-running the job goes deeper instead of starting over.  Delete the
 checkpoint dir to start fresh.
 

@@ -1474,7 +1474,7 @@ class ShardedBFS:
                     self._fresh_jit = True
                     for _n, cap in grown:
                         obs.grow("expand_buffer", cap)
-                    emit("expand caps grown to exact need: "
+                    emit("expand caps grown (headroom over the exact need): "
                          + ", ".join(f"{n}={c}" for n, c in grown)
                          + " (recompiling)")
                 elif reason == R_NEXT_GROW:

@@ -231,9 +231,7 @@ def build_parser():
     sv.add_argument("--tpu-devices", type=int, default=None,
                     help="reachable TPU devices for the cpu-vs-tpu "
                          "placement advisory (default: "
-                         "TPUVSR_TPU_DEVICES env, else the TPU_UP "
-                         "flag file scripts/tpu_watch.py maintains, "
-                         "else 0)")
+                         "TPUVSR_TPU_DEVICES env, else 0)")
     sv.add_argument("--bench-dir", default=None,
                     help="directory of BENCH_r*.json docs for the "
                          "cross-backend throughput advisory "

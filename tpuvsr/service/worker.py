@@ -32,9 +32,9 @@ device/paged/sharded engines on the stub kernel
 (``tpuvsr/testing.py``) — the tier-1 path every service test and
 ``scripts/serve_demo.py`` exercises without the reference mount.
 
-``kind="shell"`` jobs (argv + timeout) exist for the absorbed
-``scripts/tpu_queue.py`` workload driver: same spool, same claim
-discipline, same exit-code table — one queue implementation.
+``kind="shell"`` jobs (argv + timeout) run arbitrary commands under
+the same spool, the same claim discipline and the same exit-code
+table — one queue implementation.
 """
 
 from __future__ import annotations
@@ -869,7 +869,7 @@ class Worker:
                 faults.clear()
         self._settle(job, out, validate_result_summary)
 
-    # -- shell jobs (the absorbed tpu_queue workload driver) -----------
+    # -- shell jobs ---------------------------------------------------
     def _run_shell(self, job):
         flags = job.flags
         argv = flags.get("argv") or []
@@ -941,7 +941,7 @@ class Worker:
         if rc == EX_RESUMABLE:
             # resumable, but bounded: a child that exits 75 forever
             # without progressing must not hot-loop (the attempt
-            # budget the absorbed tpu_queue enforced)
+            # budget)
             if job.attempts < int(flags.get("max_attempts") or 1):
                 self.queue.requeue(job.job_id, reason="exit-75",
                                    rescue=None)
