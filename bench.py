@@ -18,9 +18,8 @@ Robustness (round-1 failure modes):
   an accelerator: no probe child, no CPU fallback — a number from a
   CPU run is never a benchmark number (its rewrite is ROADMAP S0);
 * the spec is the kernel-native VSR (tpuvsr/models/native.py), built
-  from committed files, so there is no interpreter baseline leg;
-  `_stub_round` (the POR / symmetry / bounds levers on the in-repo
-  stub fixtures, ISSUE 16) is kept for S0 to fold in or drop.
+  from committed files, so there is no interpreter baseline leg and no
+  stub-fixture fallback round.
 """
 
 import json
@@ -738,178 +737,6 @@ def _embed_spool():
         finally:
             shutil.rmtree(tmp, ignore_errors=True)
     RESULT["spool"] = out
-
-
-def _stub_round(reason):
-    """Reference-absent fallback round (ISSUE 16): the VSR.tla
-    headline needs the reference corpus, but the POR / symmetry /
-    bounds levers are all measurable on the in-repo stub fixtures
-    (tpuvsr.testing) — the independent-counters fixture (16 states,
-    two invisible independent actions: the POR oracle) and the
-    SymPair fixture (16 states / 5 orbits: the symmetry oracle).
-
-    The throughput numbers here are honestly useless (tiny spaces,
-    compile-dominated — the perf gate is skipped and the round's
-    headline ``value`` is the POR cut ratio, not states/sec), but the
-    CUT RATIOS and the count/verdict identities are exact and
-    machine-checked, which is what the r06 measurement debt asked
-    for."""
-    import tpuvsr.testing as T
-    from tpuvsr.engine.bfs import bfs_check
-
-    RESULT["metric"] = ("stub-fixture lever A/B: POR cut ratio / "
-                        "symmetry orbit cut / bounds identity "
-                        "(reference corpus absent)")
-    RESULT["unit"] = "generated-kept / generated-full"
-    RESULT["reference_absent"] = reason
-    RESULT["mode"] = "fused"
-
-    def leg(e):
-        e.run(max_depth=1)          # compile + warm
-        r = e.run(max_seconds=max(30.0, DEADLINE - time.time()))
-        g = (r.metrics or {}).get("gauges", {})
-        return r, g, {
-            "distinct": r.distinct_states,
-            "generated": r.states_generated,
-            "diameter": r.diameter,
-            "elapsed_s": round(r.elapsed, 3),
-            "error": r.error,
-            # a deadlocked fixpoint is still a completed exploration
-            "completed": r.error in (None, "deadlock"),
-        }
-
-    def verdict(r):
-        return (r.ok, r.violated_invariant, r.error)
-
-    ab = {}
-    # --- POR A/B on the independent-counters fixture (the ISSUE 16
-    # acceptance oracle: cut ratio < 1.0, counts shrink, verdict and
-    # the (3,3) deadlock identical) --------------------------------
-    RESULT["phase"] = "stub-por-ab"
-    spec = T.counter_spec(inv_free=True)
-    interp = bfs_check(spec)
-    RESULT["baseline_interp_distinct_per_s"] = round(
-        interp.distinct_states / max(interp.elapsed, 1e-9), 1)
-    r_off, _g_off, ab["counter_por_off"] = leg(
-        T.stub_device_engine(spec=spec, por="off"))
-    r_on, g_on, ab["counter_por_on"] = leg(
-        T.stub_device_engine(spec=spec, por="on"))
-    ab["counter_por_on"].update({
-        "por_cut_ratio": g_on.get("por_cut_ratio"),
-        "ample_states": g_on.get("ample_states"),
-        "por_eligible_actions": g_on.get("por_eligible_actions"),
-    })
-    ab["counter_interp_matches_off"] = (
-        interp.distinct_states == r_off.distinct_states)
-    ab["counter_verdict_identical"] = verdict(r_off) == verdict(r_on)
-    ab["counter_distinct_shrunk_or_equal"] = (
-        r_on.distinct_states <= r_off.distinct_states)
-    cut = g_on.get("por_cut_ratio")
-    # the POR-on metrics document carries the por gauges — it IS the
-    # round's diffable metrics doc (scripts/compare_bench.py gate_por)
-    RESULT["metrics"] = r_on.metrics
-    RESULT["por_cut_ratio"] = cut
-    RESULT["por_eligible_actions"] = g_on.get("por_eligible_actions")
-    RESULT["value"] = cut if cut is not None else 0.0
-    RESULT["vs_baseline"] = (
-        round(r_off.states_generated
-              / max(1, r_on.states_generated), 3))
-
-    # --- POR A/B on the symmetric fixture (symmetry OFF so the cut
-    # is attributable to the ample filter alone) -------------------
-    r_soff, _gs, ab["sympair_por_off"] = leg(
-        T.stub_sym_engine(symmetry=False, por="off"))
-    r_son, g_son, ab["sympair_por_on"] = leg(
-        T.stub_sym_engine(symmetry=False, por="on"))
-    ab["sympair_por_on"]["por_cut_ratio"] = g_son.get("por_cut_ratio")
-    ab["sympair_verdict_identical"] = (
-        verdict(r_soff) == verdict(r_son))
-
-    # --- symmetry lever (ISSUE 11) on the same fixture ------------
-    RESULT["phase"] = "stub-symmetry-ab"
-    r_sym, g_sym, ab["sympair_symmetry_on"] = leg(
-        T.stub_sym_engine(symmetry="auto"))
-    ab["sympair_symmetry_on"]["orbit_cut"] = round(
-        r_soff.distinct_states / max(1, r_sym.distinct_states), 3)
-    RESULT["symmetry_perms"] = g_sym.get("symmetry_perms")
-    RESULT["orbit_ratio"] = g_sym.get("orbit_ratio")
-
-    # --- composed: symmetry + bounds + POR on one engine (the
-    # acceptance composition: verdicts must survive the stack) -----
-    RESULT["phase"] = "stub-composed"
-    try:
-        r_comp, g_comp, ab["sympair_composed"] = leg(
-            T.stub_sym_engine(symmetry="auto", por="on", bounds=True))
-        ab["sympair_composed"].update({
-            "por_cut_ratio": g_comp.get("por_cut_ratio"),
-            "verdict_identical": (
-                (r_comp.ok, r_comp.violated_invariant)
-                == (r_sym.ok, r_sym.violated_invariant)),
-        })
-    except Exception as e:  # noqa: BLE001 — a leg never kills bench
-        ab["sympair_composed"] = {"error": f"{type(e).__name__}: {e}"}
-
-    # --- bounds lever (ISSUE 13) on the dead-action fixture:
-    # pruned vs carried dead lane must be bit-identical -------------
-    RESULT["phase"] = "stub-bounds-ab"
-    try:
-        rb_on, gb_on, ab["counter_bounds_on"] = leg(
-            T.stub_device_engine(dead_action=True, bounds=True))
-        rb_off, _gb, ab["counter_bounds_off"] = leg(
-            T.stub_device_engine(dead_action=True, bounds=False))
-        ab["bounds_counts_identical"] = (
-            rb_on.distinct_states == rb_off.distinct_states
-            and rb_on.states_generated == rb_off.states_generated)
-        RESULT["bound_tightening_ratio"] = gb_on.get(
-            "bound_tightening_ratio")
-        RESULT["state_bound"] = gb_on.get("state_bound")
-    except Exception as e:  # noqa: BLE001
-        ab["counter_bounds_on"] = {"error": f"{type(e).__name__}: {e}"}
-
-    # --- projection onto the recorded deep pins: what the measured
-    # cut ratio would buy rr05_deep / shipped_pin IF those specs
-    # admit the same reduction.  A projection, not a measurement —
-    # corpus eligibility must be read off a reference mount via
-    # `scripts/lint_corpus.py --independence` ----------------------
-    proj = {}
-    if cut:
-        for name in ("rr05_deep", "shipped_pin"):
-            p = os.path.join(REPO, "scripts", f"{name}.json")
-            try:
-                with open(p) as f:
-                    doc = json.load(f)
-            except (OSError, ValueError):
-                continue
-            if not doc.get("distinct_per_s"):
-                continue
-            proj[name] = {
-                "recorded_distinct_per_s": doc.get("distinct_per_s"),
-                "recorded_depth": doc.get("depth_reached"),
-                "recorded_fixpoint": doc.get("fixpoint"),
-                "stub_cut_ratio": cut,
-                "projected_effective_distinct_per_s": round(
-                    doc["distinct_per_s"] / cut, 1),
-                "note": ("projection from the stub-measured cut "
-                         "ratio; an upper bound that holds only if "
-                         "the spec's actions are as independent and "
-                         "invisible as the stub's"),
-            }
-    RESULT["por_projection"] = proj or None
-
-    RESULT["phase"] = "done (stub-fixture round; reference absent)"
-    RESULT["pipeline_ab"] = ab
-    RESULT["perf_gate"] = {
-        "skipped": "stub-fixture round — the headline value is a cut "
-                   "ratio, not comparable to the reference VSR.tla "
-                   "rounds"}
-    _attach_and_lift()
-    print(f"bench: stub round por_cut_ratio={cut} (counters "
-          f"{ab['counter_por_off']['distinct']} -> "
-          f"{ab['counter_por_on']['distinct']} distinct, sympair "
-          f"cut={g_son.get('por_cut_ratio')}, orbit_cut="
-          f"{ab['sympair_symmetry_on']['orbit_cut']})",
-          file=sys.stderr)
-    emit(None)
 
 
 if __name__ == "__main__":

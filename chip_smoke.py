@@ -50,8 +50,11 @@ TRACE = os.path.join(REPO, "examples", "found_violation_trace.txt")
 OUT = os.path.join(REPO, "chiprun_out", "chip_smoke")
 # B's depth bound: the deepest whose cold-cache run keeps the whole
 # script inside the smoke contract's 1200 s with a 2x margin (measured
-# on the v5e, CHANGES.md PR 22).  Depth 9 = 148,897 distinct states.
-DEFECT_DEPTH = 8
+# on the v5e, CHANGES.md PR 22): 4,095 distinct states.  Depth 7
+# (14,143) outgrows the default next-frontier buffer, and every growth
+# is one more build of the level program, about two and a half minutes
+# on the chip machine's host; depth 9 = 148,897, depth 10 = 448,580.
+DEFECT_DEPTH = 6
 DEFECT_MAX_MSGS = 32     # the defect window's final message-table bound
 
 
@@ -99,7 +102,6 @@ def report(phase, meter, before, t0, **fields):
            "cache_hits": h1 - h0,
            "peak_bytes_in_use": stats.get("peak_bytes_in_use")}
     print(json.dumps(doc), flush=True)
-    return doc
 
 
 def _journal_events(path, event):
@@ -133,11 +135,11 @@ def phase_a(out, platform, meter):
     assert doc["device"]["platform"] == platform, doc["device"]
     (start,) = _journal_events(journal, "run_start")
     assert start["platform"] == platform, start
-    return report("A", meter, before, t0, distinct=doc["distinct_states"],
-                  diameter=doc["diameter"],
-                  grows=doc["metrics"]["counters"].get("grows", 0),
-                  platform=start["platform"],
-                  device_kind=start["device_kind"])
+    report("A", meter, before, t0, distinct=doc["distinct_states"],
+           diameter=doc["diameter"],
+           grows=doc["metrics"]["counters"].get("grows", 0),
+           platform=start["platform"],
+           device_kind=start["device_kind"])
 
 
 def phase_b(depth, meter):

@@ -235,12 +235,17 @@ def test_paged_and_sharded_bit_identity():
 
 def test_fanout_caps_zero_growth_redraws():
     # SymPair, symmetry off, tile 8: one tile holds states with three
-    # simultaneously enabled lanes per action — the default caps
-    # overflow (growth redraws + recompiles), the fanout-seeded caps
-    # never do (the ISSUE 13 zero-redraw acceptance)
+    # simultaneously enabled lanes per action.  The fanout-seeded caps
+    # are the proven per-state maximum and never redraw (the ISSUE 13
+    # zero-redraw acceptance); caps forced below it do (growth redraws
+    # + recompiles) with identical results
     e_on = stub_sym_engine(symmetry=False, tile_size=8)
+    assert e_on.expand_caps == [8 * e_on._facts.fanout[n]
+                                for n in e_on.kern.action_names]
     r_on = e_on.run()
     e_off = stub_sym_engine(symmetry=False, tile_size=8, bounds=False)
+    e_off.expand_caps = [8] * len(e_off.expand_caps)
+    e_off._build(None)          # re-jit the level program at the caps
     r_off = e_off.run()
     assert r_on.distinct_states == r_off.distinct_states \
         == SYMPAIR_DISTINCT

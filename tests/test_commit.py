@@ -140,21 +140,21 @@ def test_exact_growth_and_calibration():
     assert ("expand_buffer", 16) in obs.grows
     # calibration shrinks over-grown caps onto 4x the observed maxima
     # only when a representative level was measured and >= 20% of
-    # lanes are saved — and never below the static start (tile lanes
-    # per action; here that is also the full T*L_a=16)
+    # lanes are saved — and never below the static start (CAP_START
+    # lanes per state; here clamped to the full T*L_a=16)
     e.expand_caps = [16, 16]
     e._need_seen = np.array([3, 3], np.int64)
     assert not e._calibrate_caps(obs, lambda m: None,
                                  level_states=16)   # < 4*tile
     assert not e._calibrate_caps(obs, lambda m: None, level_states=64)
     assert e.expand_caps == [16, 16]
-    # a wider kernel (8 lanes/action: full T*L_a=128) whose caps grew
-    # to 64 calibrates down to max(static start 16, 4*need)
-    e.kern._lane_count = lambda name: 8
-    e.expand_caps = [64, 64]
-    e._need_seen = np.array([3, 6], np.int64)
+    # a wider kernel (64 lanes/action: full T*L_a=1024) whose caps
+    # grew to 512 calibrates down to max(static start 4*16, 4*need)
+    e.kern._lane_count = lambda name: 64
+    e.expand_caps = [512, 512]
+    e._need_seen = np.array([3, 40], np.int64)
     assert e._calibrate_caps(obs, lambda m: None, level_states=64)
-    assert e.expand_caps == [16, 24]
+    assert e.expand_caps == [64, 160]
     # never shrinks below observation: a second call is a no-op
     assert not e._calibrate_caps(obs, lambda m: None, level_states=64)
 

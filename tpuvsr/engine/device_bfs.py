@@ -129,6 +129,24 @@ def _align8(n):
 CAP_HEADROOM = 4
 
 
+# Enabled lanes per frontier state an action's cap allows before
+# anything was observed.  The flagship run peaks at 3.2 (408 lanes of
+# ReceiveMatchingSVC in a 128-state tile) and the defect window at 3.8
+# by depth 8; a start of 1 bought three growth rebuilds on the way
+# there, at about 110 s each on the v5e's host, while the wider program
+# compiles only ~1.5x slower (AOT for the v5e, small config: 98 s at
+# 2.4k lanes per tile, 143 s at 8.8k, 154 s at 15k).  Not 8: a tile
+# commits only while the next-frontier buffer has room for every cap
+# lane, so caps near the buffer's 16k rows force it to grow instead.
+CAP_START = 4
+
+
+def static_cap(tile, full):
+    """An action's expansion cap before the guard matrix has observed
+    anything (and the floor calibration never shrinks below)."""
+    return min(full, max(8, _align8(CAP_START * tile)))
+
+
 def grown_caps(caps, need_seen, full):
     """The fused commit's cap growth policy (shared with the sharded
     engine): per-action caps after a growth event.
@@ -314,11 +332,9 @@ class DeviceBFS:
         if self.commit == "fused":
             tl = [self.tile * self.kern._lane_count(n) for n in names]
             if self.expand_caps is None:
-                # modest static start; the exact-count growth events
-                # (and the level-boundary calibration) converge the
-                # caps onto the observed per-tile maxima
-                self.expand_caps = [min(t, max(8, _align8(self.tile)))
-                                    for t in tl]
+                # static start; growth events (and the level-boundary
+                # calibration) re-cap from the observed per-tile maxima
+                self.expand_caps = [static_cap(self.tile, t) for t in tl]
                 # static fanout bounds (ISSUE 13): the bounds pass
                 # proves at most `fanout` lanes of an action enable
                 # per state, so tile*fanout is a sound initial cap —
@@ -1470,8 +1486,9 @@ class DeviceBFS:
         # below the static start: a calibration that shrank onto the
         # exact maxima was undone by growth events over the next
         # levels, as frontier states grew richer and idle actions woke
-        tgt = [min(T * kern._lane_count(n),
-                   max(8, _align8(T), _align8(CAP_HEADROOM * int(s))))
+        tgt = [max(static_cap(T, T * kern._lane_count(n)),
+                   min(T * kern._lane_count(n),
+                       _align8(CAP_HEADROOM * int(s))))
                for n, s in zip(kern.action_names, self._need_seen)]
         cur = self._expand_caps()
         if sum(tgt) * 5 > sum(cur) * 4:

@@ -10,6 +10,7 @@ at VSR.tla:968 and the LivenessSpec split at A01:808-809), VIEW
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
 
 from ..core.values import (FnVal, TLAError, permute_value, value_key)
@@ -219,7 +220,6 @@ def load_spec(tla_path: str, cfg_path: str) -> SpecModel:
     when no such file exists, the name of a module the kernel registry
     knows: that builds the AST-free native spec (models/native.py)
     from committed files.  Anything else fails as a missing file."""
-    import os
     from ..frontend.cfg import parse_cfg_file
     if not os.path.exists(tla_path) and tla_path.isidentifier():
         from ..models.native import native_spec

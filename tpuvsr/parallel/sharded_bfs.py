@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..engine.device_bfs import grown_caps
+from ..engine.device_bfs import grown_caps, static_cap
 from ..engine.fpset import dedup_batch, insert_core
 from ..obs import closes_observer
 from ..resilience.faults import InjectedExchangeDrop, fault_point
@@ -804,8 +804,7 @@ class ShardedBFS:
             names = self.kern.action_names
             tl = [self.tile * self.kern._lane_count(n) for n in names]
             if self.expand_caps is None:
-                self.expand_caps = [min(t, max(8, self.tile))
-                                    for t in tl]
+                self.expand_caps = [static_cap(self.tile, t) for t in tl]
                 # static fanout bounds seed the caps (ISSUE 13): zero
                 # growth redraws on exact-bounds fixtures
                 if self._facts is not None:

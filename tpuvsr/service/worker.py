@@ -1023,8 +1023,12 @@ class Worker:
                 job = self.queue.claim_next(owner=self.owner,
                                             order=order)
                 if job is None:
-                    if self.multirunner is not None \
-                            and self.multirunner.inflight():
+                    # light jobs skipped above are not absent: if the
+                    # lane drained between the two inflight() reads,
+                    # an idle exit here would strand them admitted
+                    if order is not base_order or (
+                            self.multirunner is not None
+                            and self.multirunner.inflight()):
                         time.sleep(self.poll)
                         continue
                     if idle_exit:
