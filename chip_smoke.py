@@ -253,10 +253,16 @@ def main():
                  f"gives {len(devs)} x {devs[0].platform} "
                  f"({devs[0].device_kind})")
     sys.path.insert(0, REPO)
+    import inspect
+
+    from tpuvsr.engine.device_bfs import DeviceBFS
     from tpuvsr.models.registry import ensure_compile_cache
     cache = ensure_compile_cache()
-    print(json.dumps({"phase": "start", "compile_cache": cache,
-                      "jax": jax.__version__}), flush=True)
+    print(json.dumps({
+        "phase": "start", "compile_cache": cache, "jax": jax.__version__,
+        # the tile A and D run at: the CLI and the service pass none
+        "default_tile": inspect.signature(DeviceBFS).parameters[
+            "tile_size"].default}), flush=True)
     shutil.rmtree(OUT, ignore_errors=True)    # a rerun starts clean
     os.makedirs(OUT)
     meter = CompileMeter()
