@@ -1,0 +1,200 @@
+"""From a profiler trace to numbers: device-busy seconds, idle share,
+the device operations that took most time, and the longest idle gaps
+by what the host was doing.
+
+Two steps, so that the arithmetic can be tested on a small recorded
+extract (tests/data/) without a profiler:
+
+    extract(path)  .xplane.pb -> {"planes": [{"name", "lines": [{"name",
+                   "events": [[name, start_ns, duration_ns], ...]}]}]}
+    reduce(doc)    the extract -> busy_s, span_s, device_ops, idle_gaps
+
+Busy is the union of the intervals in which an operation ran on a
+device, averaged over the device planes.  An operation's seconds are
+its SELF time: an event that encloses others on its line (a `while`
+around its body) is charged only what its children leave uncovered, so
+the list names the work and not its wrapper.
+"""
+
+import glob
+import os
+import re
+
+DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+")
+OPS_LINE = "XLA Ops"
+# host lines that are not the program's threads
+SKIP_HOST_LINES = ("python",)
+_DIGITS = re.compile(r"\d+")
+# "%fusion.7 = u32[2097152,5]{0,1:T(8,128)} fusion(...), kind=kLoop, ..."
+_NAME = re.compile(r"^%?([\w.\-]+) = \(?(\w+\[[\d,]*\])?")
+_OPCODE = re.compile(r" ([a-z][a-z\-]*)\(")
+_KIND = re.compile(r"kind=(\w+)")
+
+
+def short_name(hlo):
+    """XLA's instruction name with its (first) result shape: enough to
+    know the FPSet update (u32[<slots>,5]) from the frontier scatter."""
+    m = _NAME.match(hlo)
+    return f"{m.group(1)} {m.group(2) or ''}".strip() if m else hlo[:80]
+
+
+def opcode(hlo):
+    """The instruction's opcode, with the fusion kind where it has one:
+    the coarse table of where device time goes."""
+    m = _OPCODE.search(hlo)
+    if not m:
+        return "other"
+    kind = _KIND.search(hlo) if m.group(1) == "fusion" else None
+    return f"fusion {kind.group(1)}" if kind else m.group(1)
+
+
+def find_xplane(directory):
+    """The newest .xplane.pb under a jax.profiler.trace directory."""
+    paths = glob.glob(os.path.join(directory, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return max(paths, key=os.path.getmtime) if paths else None
+
+
+def extract(path, keep_host=True):
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(path)
+    planes = []
+    for plane in data.planes:
+        device = bool(DEVICE_PLANE.match(plane.name))
+        if not device and not (keep_host and plane.name.startswith("/host:")):
+            continue
+        lines = []
+        for line in plane.lines:
+            if not device and line.name in SKIP_HOST_LINES:
+                continue
+            events = [[ev.name, int(ev.start_ns), int(ev.duration_ns)]
+                      for ev in line.events]
+            if events:
+                lines.append({"name": line.name, "events": events})
+        planes.append({"name": plane.name, "lines": lines})
+    return {"planes": planes}
+
+
+def _union(intervals):
+    """Sorted disjoint [start, end) list covering `intervals`."""
+    out = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            if e > out[-1][1]:
+                out[-1][1] = e
+        else:
+            out.append([s, e])
+    return out
+
+
+def _self_times(events):
+    """{name: self ns} for the events of one line; a child is an event
+    that starts inside the event on top of the stack."""
+    total = {}
+    stack = []      # [name, end, self_ns]
+
+    def close():
+        name, _end, self_ns = stack.pop()
+        total[name] = total.get(name, 0) + max(0, self_ns)
+
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and start >= stack[-1][1]:
+            close()
+        if stack:
+            stack[-1][2] -= min(dur, stack[-1][1] - start)
+        stack.append([name, start + dur, dur])
+    while stack:
+        close()
+    return total
+
+
+def ops_events(plane):
+    lines = [ln for ln in plane["lines"] if ln["name"] == OPS_LINE]
+    if not lines:       # a backend that names its lines otherwise
+        lines = [ln for ln in plane["lines"] if ln["name"] != "Steps"]
+    return [ev for ln in lines for ev in ln["events"]]
+
+
+def _host_spans(doc):
+    """(starts, ends, names) of every host span, as arrays."""
+    import numpy as np
+    starts, ends, names = [], [], []
+    for plane in doc["planes"]:
+        if DEVICE_PLANE.match(plane["name"]):
+            continue
+        for line in plane["lines"]:
+            if line["name"] in SKIP_HOST_LINES:
+                continue
+            for name, s, d in line["events"]:
+                if d > 0:
+                    starts.append(s)
+                    ends.append(s + d)
+                    names.append(name)
+    return np.asarray(starts, np.int64), np.asarray(ends, np.int64), names
+
+
+def _attribute(gap, spans):
+    """The innermost host span that covers the whole gap; failing that,
+    the innermost that covers its middle."""
+    import numpy as np
+    starts, ends, names = spans
+    mid = (gap[0] + gap[1]) // 2
+    hit = np.nonzero((starts <= gap[0]) & (ends >= gap[1]))[0]
+    if not len(hit):
+        hit = np.nonzero((starts <= mid) & (ends >= mid))[0]
+    if not len(hit):
+        return "no host span"
+    inner = hit[np.argmin(ends[hit] - starts[hit])]
+    return _DIGITS.sub("N", names[inner])
+
+
+def reduce(doc, top=10, gaps_examined=200):
+    devices = [p for p in doc["planes"] if DEVICE_PLANE.match(p["name"])]
+    busy, ops, codes, first, last = [], {}, {}, None, None
+    gaps, n_events = [], 0
+    for i, plane in enumerate(devices):
+        events = ops_events(plane)
+        if not events:
+            continue
+        n_events += len(events)
+        cover = _union([(s, s + d) for _n, s, d in events])
+        busy.append(sum(e - s for s, e in cover) / 1e9)
+        first = cover[0][0] if first is None else min(first, cover[0][0])
+        last = cover[-1][1] if last is None else max(last, cover[-1][1])
+        for name, ns in _self_times(events).items():
+            ops[short_name(name)] = ops.get(short_name(name), 0) + ns
+            codes[opcode(name)] = codes.get(opcode(name), 0) + ns
+        if i == 0:
+            gaps = [(a[1], b[0]) for a, b in zip(cover, cover[1:])]
+    if not busy:
+        return None
+    n = len(busy)
+    gaps.sort(key=lambda g: g[0] - g[1])
+    spans = _host_spans(doc)
+    by_host = {}
+    for gap in gaps[:gaps_examined]:
+        name = _attribute(gap, spans)
+        by_host[name] = by_host.get(name, 0) + (gap[1] - gap[0])
+    rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]  # noqa: E731
+    return {
+        "devices": n,
+        "busy_s": sum(busy) / n,
+        "busy_s_per_device": busy,
+        "span_s": (last - first) / 1e9,
+        "device_events": n_events,
+        "device_ops": [[k, v / 1e9 / n] for k, v in rank(ops)],
+        "device_opcodes": [[k, v / 1e9 / n] for k, v in rank(codes)],
+        "idle_gaps": [[k, v / 1e9] for k, v in rank(by_host)],
+    }
+
+
+def idle_share(trace, window_s):
+    """1 - device busy / traced window, in percent."""
+    if not trace or not window_s:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / window_s)
+
+
+def reduce_directory(directory):
+    path = find_xplane(directory)
+    return reduce(extract(path)) if path else None
