@@ -1,0 +1,6 @@
+"""`device.idle_unattributed_s` in a bfs-timed cell, where it moves
+`distinct_per_s`: read from the window's run."""
+
+import cells
+
+read = cells.load_plugin("layer_metrics", "device.idle_unattributed_s").read

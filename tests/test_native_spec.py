@@ -171,8 +171,13 @@ def test_lint_gate_is_off_for_a_native_spec(small):
 @pytest.mark.parametrize("flags", [["-bounds", "on"], ["-por", "on"],
                                    ["-lint"], ["-lower"],
                                    ["-engine", "interp"]])
-def test_cli_flags_that_need_the_ast_exit_2(flags, capsys):
+def test_cli_flags_that_need_the_ast_exit_2(flags, capsys, monkeypatch):
     from tpuvsr.cli.main import main
+    # main() exports -lower to os.environ for the engines: have
+    # monkeypatch own the key (an empty value reads as unset), so that
+    # it is restored after the test - or every later model this worker
+    # builds goes through the lowerer, which a native spec has no AST for
+    monkeypatch.setenv("TPUVSR_COMPILED", "")
     with pytest.raises(SystemExit) as e:
         main(["VSR", "-config", SMALL_CFG] + flags)
     assert e.value.code == 2

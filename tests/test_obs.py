@@ -406,7 +406,7 @@ def test_stub_device_bfs_journal_metrics(tmp_path):
     ph = doc["phases"]
     core = sum(ph.get(k, 0.0) for k in ("compile", "dispatch",
                                         "host_sync", "inflight",
-                                        "check"))
+                                        "check", "init"))
     # ISSUE 2 acceptance: the four core phases cover >=90% of elapsed
     assert core >= 0.90 * res.elapsed, (ph, res.elapsed)
     assert sum(ph.values()) <= 1.05 * res.elapsed
@@ -548,7 +548,7 @@ def test_stub_sharded_journal_and_shard_metrics(tmp_path):
     ph = doc["phases"]
     core = sum(ph.get(k, 0.0) for k in ("compile", "dispatch",
                                         "host_sync", "inflight",
-                                        "check"))
+                                        "check", "init"))
     assert core >= 0.90 * res.elapsed, (ph, res.elapsed)
 
 
@@ -603,7 +603,7 @@ def test_device_phase_timers_sum_to_elapsed(tmp_path):
     ph = doc["phases"]
     core = sum(ph.get(k, 0.0) for k in ("compile", "dispatch",
                                         "host_sync", "inflight",
-                                        "check"))
+                                        "check", "init"))
     assert core >= 0.90 * res.elapsed, (ph, res.elapsed)
     assert sum(ph.values()) <= 1.05 * res.elapsed, (ph, res.elapsed)
     assert doc["counters"]["dispatches"] >= 1
@@ -871,3 +871,331 @@ def test_trace_view_span_tree_and_perfetto(tmp_path):
             f.write(json.dumps(ev) + "\n")
     got, spans = trace_view.build_spans(trace_view.load_events(legacy))
     assert got is None and set(spans) == {"untraced"}
+
+
+# ---------------------------------------------------------------------
+# the one span primitive, build counters, named stages (ISSUE 25)
+# ---------------------------------------------------------------------
+class _Recorder:
+    """An injected annotation factory: records every TraceAnnotation
+    the observer would open, with its attributes, and its close."""
+
+    def __init__(self):
+        self.log = []           # ("open", name, attrs) | ("close", name)
+
+    def __call__(self, name, **attrs):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.log.append(("open", name, attrs))
+
+            def __exit__(self, *exc):
+                rec.log.append(("close", name))
+                return False
+        return _Ann()
+
+    def opened(self):
+        return [e[1] for e in self.log if e[0] == "open"]
+
+    def assert_nested(self):
+        stack = []
+        for e in self.log:
+            if e[0] == "open":
+                stack.append(e[1])
+            else:
+                assert stack and stack.pop() == e[1], self.log
+        assert not stack, stack
+
+
+def test_span_keeps_exclusive_phase_contract():
+    from tpuvsr.obs import spans
+    obs = RunObserver(annotation=lambda: None)
+    obs.start(time.time(), backend="host")
+    with obs.span(spans.DISPATCH, depth=1):
+        time.sleep(0.02)
+        with obs.span(spans.HOST_SYNC):
+            time.sleep(0.02)
+    with pytest.raises(RuntimeError):
+        with obs.span(spans.CHECKPOINT, depth=2):
+            raise RuntimeError("inside a span")
+    # the frame of the failed span was closed on the way out: only the
+    # root is open, and drain() (finish/close) closes that
+    assert [f[0] for f in obs.metrics._stack] == ["check"]
+    with pytest.raises(KeyError):
+        obs.span("level 7 dispatch")        # not in the vocabulary
+    span = obs.span(spans.INFLIGHT)
+    span.__enter__()
+    obs.close()                             # drains the open frame
+    span.__exit__(None, None, None)         # and this is then a no-op
+    ph = obs.metrics.phases
+    assert set(ph) == {"check", "dispatch", "host_sync", "checkpoint",
+                       "inflight"}
+    assert ph["dispatch"] >= 0.015 and ph["host_sync"] >= 0.015
+    assert ph["dispatch"] < ph["host_sync"] + 0.05      # exclusive
+    assert not obs.metrics._stack
+
+
+def test_span_annotations_follow_the_fixed_vocabulary(tmp_path):
+    from tpuvsr.obs import spans
+    rec = _Recorder()
+    asked = []
+
+    def factory():
+        asked.append(1)
+        return rec
+    with trace_scope("feedc0de00000001"):
+        obs = RunObserver(journal_path=str(tmp_path / "j.jsonl"),
+                          annotation=factory)
+        res = _stub_device_engine(pipeline=2).run(
+            obs=obs, checkpoint_path=str(tmp_path / "ck"))
+    assert res.ok and res.levels == [1, 2, 3, 4, 3, 2, 1]
+    assert asked == [1]         # decided once, at start()
+    rec.assert_nested()
+    names = rec.opened()
+    assert set(names) <= set(spans.ENGINE_SPANS)
+    assert not any(re.search(r"\d", n) for n in names)
+    # the root comes first and closes last, and carries the run's ids
+    first, last = rec.log[0], rec.log[-1]
+    assert first[:2] == ("open", spans.CHECK)
+    assert first[2] == {"run_id": obs.run_id,
+                        "trace_id": "feedc0de00000001"}
+    assert last == ("close", spans.CHECK)
+    # one annotation per phase entry: every dispatch, every snapshot
+    doc = res.metrics
+    n_launch = names.count(spans.BUILD) + names.count(spans.DISPATCH)
+    assert n_launch == doc["counters"]["dispatches"]
+    assert names.count(spans.BUILD) == 1
+    assert names.count(spans.INIT) == 1
+    assert names.count(spans.CHECKPOINT) == doc["counters"]["checkpoints"]
+    assert names.count(spans.CHECKPOINT) >= 1
+    assert names.count(spans.INFLIGHT) >= 1
+    assert {spans.ENGINE_SPANS[n] for n in names} == set(doc["phases"])
+    # numbers ride as attributes
+    depths = [e[2]["depth"] for e in rec.log
+              if e[0] == "open" and e[1] == spans.DISPATCH]
+    assert depths and all(isinstance(d, int) for d in depths)
+
+
+def test_span_with_profiling_off_never_makes_an_annotation(monkeypatch):
+    import jax.profiler
+    monkeypatch.delenv("TPUVSR_PROFILE", raising=False)
+
+    def boom(*a, **kw):
+        raise AssertionError("TraceAnnotation made with profiling off")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    reads = []
+    real = os.environ.get
+    res = _stub_device_engine().run()
+    assert res.ok and res.metrics["phases"]["dispatch"] > 0
+    # and no span reads the environment: a run's reads of
+    # TPUVSR_PROFILE do not grow with its dispatches
+    monkeypatch.setattr(
+        "tpuvsr.obs.profiler.profile_dir",
+        lambda: reads.append(1) or real("TPUVSR_PROFILE") or None)
+    res = _stub_device_engine().run()
+    assert res.metrics["counters"]["dispatches"] >= 7
+    assert len(reads) <= 2      # start(): the session and the factory
+
+
+def test_profile_trace_opens_without_python_tracer(tmp_path, monkeypatch):
+    from tpuvsr.obs.profiler import annotation_factory, profile_trace
+    monkeypatch.delenv("TPUVSR_PROFILE", raising=False)
+    assert annotation_factory() is None
+    import jax.profiler
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **kw: calls.append("start"))
+    with profile_trace() as on:
+        assert on is False and not calls           # off: a no-op
+    monkeypatch.setenv("TPUVSR_PROFILE", str(tmp_path / "prof"))
+    assert annotation_factory() is jax.profiler.TraceAnnotation
+
+    def fake_start_trace(directory, profiler_options=None):
+        calls.append((directory, profiler_options.python_tracer_level,
+                      profiler_options.host_tracer_level))
+    monkeypatch.setattr(jax.profiler, "start_trace", fake_start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop"))
+    with profile_trace() as on:
+        assert on is True
+    assert calls == [(str(tmp_path / "prof"), 0, 2), "stop"]
+
+    # a session is already active: the run carries on untraced-by-us
+    def refused(directory, profiler_options=None):
+        raise RuntimeError("Only one profile may be run at a time.")
+    monkeypatch.setattr(jax.profiler, "start_trace", refused)
+    said = []
+    with profile_trace(log=said.append) as on:
+        assert on is False
+    assert said and "continuing" in said[0]
+
+
+def _small_jit(salt):
+    import jax
+    import jax.numpy as jnp
+
+    def fresh_program(x):
+        return (x * salt + 1).sum()
+    fresh_program.__name__ = f"fresh_program_{salt}"
+    return jax.jit(fresh_program)(jnp.arange(8.0)).block_until_ready()
+
+
+def test_build_counters_follow_the_running_observer(tmp_path,
+                                                    monkeypatch):
+    import threading
+
+    from tpuvsr.obs import builds
+    monkeypatch.setattr(builds, "JOURNAL_BUILD_S", 0.0)
+    jp = str(tmp_path / "j.jsonl")
+    _small_jit(101)                     # outside any observer: nobody's
+    a = RunObserver(journal_path=jp)
+    a.engine = "device"
+    a.start(time.time(), backend="cpu")
+    _small_jit(102)
+    other = []
+    t = threading.Thread(target=lambda: other.append(_small_jit(103)))
+    t.start()
+    t.join()                            # another thread: not a's
+    n_a = a.builds.programs
+    assert n_a >= 1 and other
+    res = a.finish(_Res())
+    doc = validate_metrics(res.metrics)
+    assert doc["counters"]["build_programs"] == n_a
+    g = doc["gauges"]
+    assert g["build_trace_s"] > 0 and g["build_lower_s"] > 0
+    assert g["build_backend_s"] > 0 and g["build_cache_load_s"] >= 0
+    assert {"build_cache_hits", "build_cache_misses"} <= set(
+        doc["counters"])
+    _small_jit(104)                     # after finish(): nobody's
+    assert a.builds.programs == n_a
+    # a second observer in sequence starts from nothing
+    b = RunObserver()
+    b.engine = "device"
+    b.start(time.time(), backend="cpu")
+    assert b.builds.programs == 0
+    _small_jit(105)
+    seen = []
+
+    def in_thread():
+        c = RunObserver()
+        c.engine = "device"
+        c.start(time.time(), backend="cpu")
+        _small_jit(106)
+        seen.append(c.builds.programs)
+        c.finish(_Res())
+    t = threading.Thread(target=in_thread)
+    t.start()
+    t.join()
+    assert seen and seen[0] >= 1
+    n_b = b.builds.programs
+    assert 1 <= n_b < n_a + 2 and a.builds.programs == n_a
+    b.finish(_Res())
+    # the journal's build events validate and name their program
+    evs = [e for e in read_journal(jp) if e["event"] == "build"]
+    assert evs and len(evs) == n_a
+    assert any("fresh_program_102" in e["fun_name"] for e in evs)
+    assert not any("fresh_program_103" in e["fun_name"] for e in evs)
+    for e in evs:
+        assert e["cache"] in ("hit", "miss", "none")
+        assert e["trace_s"] >= 0 and e["lower_s"] >= 0
+        assert e["backend_s"] >= 0
+
+
+def test_build_meter_counts_nested_traces_once():
+    from tpuvsr.obs import builds
+    now = [100.0]
+    reported = []
+    m = builds.BuildMeter(report=reported.append, clock=lambda: now[0])
+    # an inner jit's trace (1 s, ending at t=102) lies inside the
+    # outer's (3 s, ending at t=103): the union is 3 s, not 4
+    now[0] = 102.0
+    m.duration(builds.TRACE_EVENT, 1.0, "inner")
+    now[0] = 103.0
+    m.duration(builds.TRACE_EVENT, 3.0, "level")
+    m.duration(builds.LOWER_EVENT, 2.0, "jit_level")
+    m.event(builds.CACHE_HIT_EVENT)
+    m.duration(builds.CACHE_LOAD_EVENT, 0.5)
+    m.duration(builds.BACKEND_EVENT, 0.75, "jit_level")
+    assert (m.trace_s, m.lower_s, m.backend_s) == (3.0, 2.0, 0.75)
+    assert (m.programs, m.cache_hits, m.cache_load_s) == (1, 1, 0.5)
+    assert reported == [{"fun_name": "jit_level", "trace_s": 3.0,
+                         "lower_s": 2.0, "backend_s": 0.75,
+                         "cache": "hit"}]
+    # a millisecond jit is counted and not journaled
+    now[0] = 104.0
+    m.duration(builds.TRACE_EVENT, 0.001, "tiny")
+    m.duration(builds.BACKEND_EVENT, 0.002, "jit_tiny")
+    assert m.programs == 2 and len(reported) == 1
+
+
+class _Res:
+    """The least a result needs for RunObserver.finish."""
+    ok = True
+    elapsed = 0.0
+
+
+def _lowered_stages(jitted, *args):
+    text = jitted.lower(*args).as_text(debug_info=True)
+    return set(re.findall(r"tpuvsr\.(?:level|shard)\.[a-z_]+", text))
+
+
+def _level_args(eng):
+    import jax.numpy as jnp
+    from tpuvsr.engine.fpset import empty_table
+    bufs = eng._alloc_bufs(eng.next_cap)
+    i32 = jnp.zeros((), jnp.int32)
+    return ({"slots": empty_table(eng.fpset_capacity)["slots"]},
+            bufs[0], i32, i32, *bufs, i32, jnp.zeros((), bool),
+            None, None, i32)
+
+
+def test_level_program_stages_are_named():
+    from tpuvsr.obs import spans
+    from tpuvsr.testing import STUB_LEVELS, stub_sym_engine
+    common = {spans.GUARD_MATRIX, spans.COMPACT, spans.EXPAND,
+              spans.FINGERPRINT, spans.FPSET_INSERT, spans.PACK_SCATTER,
+              spans.INVARIANTS}
+    fused = _stub_device_engine()
+    assert fused.commit == "fused" and fused._pk is not None
+    assert _lowered_stages(fused._level, *_level_args(fused)) == common
+    per_action = _stub_device_engine(commit="per-action")
+    assert _lowered_stages(per_action._level,
+                           *_level_args(per_action)) == common
+    sym = stub_sym_engine()
+    assert sym._canon is not None
+    assert _lowered_stages(sym._level, *_level_args(sym)) \
+        == common | {spans.CANON}
+    assert set(spans.LEVEL_STAGES) >= common | {spans.CANON}
+    # metadata only: the pinned stub run is what it was
+    res = fused.run()
+    assert res.levels == list(STUB_LEVELS) and res.distinct_states == 16
+    assert per_action.run().levels == list(STUB_LEVELS)
+
+
+@pytest.mark.skipif(len(__import__("jax").devices()) < 2,
+                    reason="needs 2 virtual devices")
+def test_sharded_step_stages_are_named():
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from tpuvsr.obs import spans
+    from tpuvsr.testing import STUB_LEVELS, stub_sharded_engine
+    eng = stub_sharded_engine(n_devices=2)
+    D, N = eng.D, eng.N
+    sh = NamedSharding(eng.mesh, P("d"))
+
+    def put(x):
+        return jax.device_put(x, sh)
+    rows = put(jnp.zeros((D * N, eng._pk.words), jnp.uint32))
+    col = put(jnp.zeros((D * N,), jnp.int32))
+    per_dev = put(jnp.zeros((D,), jnp.int32))
+    args = ({"slots": put(jnp.zeros((D, eng.fp_cap, 5), jnp.uint32))},
+            rows, per_dev, per_dev, rows, col, col, col, per_dev,
+            per_dev)
+    got = _lowered_stages(eng._step, *args)
+    assert got == {spans.GUARD_MATRIX, spans.COMPACT, spans.EXPAND,
+                   spans.FINGERPRINT, spans.FPSET_INSERT,
+                   spans.PACK_SCATTER, spans.INVARIANTS,
+                   spans.SHARD_BUCKET, spans.SHARD_ALL_TO_ALL}
+    assert eng.run().levels == list(STUB_LEVELS)

@@ -170,7 +170,7 @@ def test_pipelined_phases_sum_to_elapsed(tmp_path):
     ph = doc["phases"]
     core = sum(ph.get(k, 0.0) for k in ("compile", "dispatch",
                                         "host_sync", "inflight",
-                                        "check"))
+                                        "check", "init"))
     assert core >= 0.90 * res.elapsed, (ph, res.elapsed)
     assert sum(ph.values()) <= 1.05 * res.elapsed, (ph, res.elapsed)
     g = doc["gauges"]
@@ -304,3 +304,45 @@ def test_cli_pipeline_runs_interp(tmp_path):
     start = [e for e in read_journal(str(jp))
              if e["event"] == "run_start"][0]
     assert start["pipeline"] == 1      # interp has no dispatch window
+
+
+# ---------------------------------------------------------------------
+# what the budget stop drops has its own counter (ISSUE 25)
+# ---------------------------------------------------------------------
+def test_drain_files_tickets_by_reason():
+    from tpuvsr.engine.pipeline import DispatchPipeline
+
+    class _Ready:
+        def block_until_ready(self):
+            return self
+
+    obs = RunObserver(annotation=lambda: None)
+    pipe = DispatchPipeline(3, obs, ready=lambda out: _Ready())
+    for depth in (1, 1, 1):
+        pipe.launch(lambda: object(), depth=depth)
+    assert pipe.in_flight == 3
+    assert pipe.drain() == 3                    # a pause: replays
+    pipe.launch(lambda: object(), depth=2)
+    pipe.launch(lambda: object(), fresh=True, depth=2)
+    assert pipe.drain(reason="budget") == 2     # real chunks, dropped
+    assert pipe.drain(reason="budget") == 0
+    c = obs.metrics.counters
+    assert c["pipeline_replays"] == 3
+    assert c["budget_dropped_dispatches"] == 2
+    assert c["dispatches"] == 5
+    assert set(obs.metrics.phases) == {"dispatch", "compile"}
+
+
+@pytest.mark.parametrize("K", WINDOWS)
+def test_budget_stop_counts_the_dispatches_it_drops(K):
+    # the budget is spent at the first collect: what is still in the
+    # window (K - 1 tickets) is real work the run throws away
+    res = stub_device_engine(pipeline=K).run(max_seconds=1e-9)
+    assert res.error and res.error.startswith("time budget")
+    c = res.metrics["counters"]
+    assert c.get("budget_dropped_dispatches", 0) == K - 1
+    assert c.get("pipeline_replays", 0) == 0
+    # a run that ends by itself drops replays only
+    c = stub_device_engine(pipeline=K).run().metrics["counters"]
+    assert "budget_dropped_dispatches" not in c
+    assert (c.get("pipeline_replays", 0) > 0) == (K > 1)

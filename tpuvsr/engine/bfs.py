@@ -43,7 +43,7 @@ def bfs_check(spec: SpecModel, check_deadlock: bool = False,
               max_states: int = None, progress_every: float = 10.0,
               log=None, obs=None) -> CheckResult:
     from ..analysis import preflight
-    from ..obs import RunObserver
+    from ..obs import RunObserver, spans
     preflight(spec, log=log)      # speclint gate (TPUVSR_LINT=off skips)
     obs = RunObserver.ensure(obs, "interp", spec, log=log,
                              progress_every=progress_every)
@@ -92,7 +92,7 @@ def bfs_check(spec: SpecModel, check_deadlock: bool = False,
             depth += 1
             fault_point("level", depth=depth, obs=obs)
             next_frontier = []
-            with obs.annotate(f"level {depth}"):
+            with obs.span(spans.CHECK, depth=depth):
                 for sid in frontier:
                     state = states[sid]
                     n_succ = 0

@@ -57,9 +57,11 @@ import json
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..core.values import TLAError
+from ..obs import spans
 
 
 def kernel_fold_order(kern):
@@ -219,7 +221,12 @@ class CanonSpec:
         pre-fingerprint seam every engine hooks (fused/chunked commit
         stage 3, the paged insert path, the sharded pre-bucketing
         step, the fleet novelty set)."""
-        return lambda st: kern.fingerprint(self.canonicalize(st))
+        def fingerprint(st):
+            with jax.named_scope(spans.CANON):
+                image = self.canonicalize(st)
+            return kern.fingerprint(image)
+
+        return fingerprint
 
 
 def build_canon_spec(spec, codec, kern, symmetry="auto"):

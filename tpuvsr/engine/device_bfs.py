@@ -84,7 +84,7 @@ import jax.numpy as jnp
 from ..core.values import TLAError
 from ..models import registry
 from ..models.vsr import ERR_BAG_OVERFLOW
-from ..obs import RunObserver, closes_observer
+from ..obs import RunObserver, closes_observer, spans
 from ..resilience.faults import fault_point
 from ..resilience.supervisor import Preempted, preempt_signal
 from .bfs import CheckResult
@@ -450,10 +450,11 @@ class DeviceBFS:
 
         def mat(batch):
             segs = []
-            for name, guard in zip(kern.action_names, guards):
-                lanes = jnp.arange(kern._lane_count(name), dtype=I32)
-                segs.append(jax.vmap(lambda st: jax.vmap(
-                    lambda ln, g=guard: g(st, ln))(lanes))(batch))
+            with jax.named_scope(spans.GUARD_MATRIX):
+                for name, guard in zip(kern.action_names, guards):
+                    lanes = jnp.arange(kern._lane_count(name), dtype=I32)
+                    segs.append(jax.vmap(lambda st: jax.vmap(
+                        lambda ln, g=guard: g(st, ln))(lanes))(batch))
             return segs
 
         return mat
@@ -512,16 +513,19 @@ class DeviceBFS:
                 base = t * T
                 sidx = base + jnp.arange(T, dtype=I32)
                 valid = sidx < n_front
-                if pk is not None:
-                    # packed at-rest frontier: gather [T, words] rows,
-                    # unpack to the dense tile the kernel consumes
-                    tile = jax.vmap(pk.unpack)(
-                        frontier[jnp.clip(sidx, 0, F_cap - 1)])
-                else:
-                    tile = {k: v[jnp.clip(sidx, 0, F_cap - 1)]
-                            for k, v in frontier.items()}
+                with jax.named_scope(spans.PACK_SCATTER):
+                    if pk is not None:
+                        # packed at-rest frontier: gather [T, words]
+                        # rows, unpack to the dense tile the kernel
+                        # consumes
+                        tile = jax.vmap(pk.unpack)(
+                            frontier[jnp.clip(sidx, 0, F_cap - 1)])
+                    else:
+                        tile = {k: v[jnp.clip(sidx, 0, F_cap - 1)]
+                                for k, v in frontier.items()}
                 if incremental:
-                    parts = jax.vmap(kern.parent_parts)(tile)
+                    with jax.named_scope(spans.FINGERPRINT):
+                        parts = jax.vmap(kern.parent_parts)(tile)
 
                 slots = c["slots"]
                 nb, nbp, nba, nbprm = c["nb"], c["nbp"], c["nba"], c["nbprm"]
@@ -568,63 +572,78 @@ class DeviceBFS:
                     E_a = caps[aid]
 
                     # -- phase 1: cheap guard pass over every lane -----
-                    en = jax.vmap(lambda st: jax.vmap(
-                        lambda ln: guard(st, ln))(lanes))(tile)
-                    en = en & valid[:, None]
-                    en_any = en_any | en.any(axis=1)
-                    en_f = en.reshape(TL)
-                    n_en = en_f.sum()
-                    gen_local = gen_local + n_en
-                    act_local.append(n_en)
-                    ovf_a = n_en > E_a
-                    grow_aid = jnp.where(ovf_a & ~ovf_e, aid, grow_aid)
-                    ovf_e = ovf_e | ovf_a
+                    with jax.named_scope(spans.GUARD_MATRIX):
+                        en = jax.vmap(lambda st: jax.vmap(
+                            lambda ln: guard(st, ln))(lanes))(tile)
+                        en = en & valid[:, None]
+                        en_any = en_any | en.any(axis=1)
+                        en_f = en.reshape(TL)
+                        n_en = en_f.sum()
+                        gen_local = gen_local + n_en
+                        act_local.append(n_en)
+                        ovf_a = n_en > E_a
+                        grow_aid = jnp.where(ovf_a & ~ovf_e, aid,
+                                             grow_aid)
+                        ovf_e = ovf_e | ovf_a
 
                     # -- phase 2: expand only the enabled lanes --------
-                    (sel,) = jnp.nonzero(en_f, size=E_a, fill_value=TL)
-                    sel_ok = sel < TL
-                    pidx = jnp.clip(sel // L_a, 0, T - 1).astype(I32)
-                    lane_sel = (sel % L_a).astype(I32)
-                    st_sel = {k: v[pidx] for k, v in tile.items()}
+                    with jax.named_scope(spans.COMPACT):
+                        (sel,) = jnp.nonzero(en_f, size=E_a,
+                                             fill_value=TL)
+                        sel_ok = sel < TL
+                        pidx = jnp.clip(sel // L_a, 0, T - 1).astype(I32)
+                        lane_sel = (sel % L_a).astype(I32)
+                        st_sel = {k: v[pidx] for k, v in tile.items()}
 
                     if incremental:
-                        parts_sel = jax.tree_util.tree_map(
-                            lambda v: v[pidx], parts)
+                        with jax.named_scope(spans.COMPACT):
+                            parts_sel = jax.tree_util.tree_map(
+                                lambda v: v[pidx], parts)
 
                         def one(st, parts_one, lane, fn=fn, name=name):
-                            succ, en1 = fn(kern.seed_touch(st), lane)
-                            ri = kern.lane_replica(name, st, lane)
-                            fp = kern.fingerprint_incremental(
-                                succ, ri, parts_one, st)
+                            with jax.named_scope(spans.EXPAND):
+                                succ, en1 = fn(kern.seed_touch(st), lane)
+                            with jax.named_scope(spans.FINGERPRINT):
+                                ri = kern.lane_replica(name, st, lane)
+                                fp = kern.fingerprint_incremental(
+                                    succ, ri, parts_one, st)
                             clean = {k: v for k, v in succ.items()
                                      if not k.startswith("_")}
-                            return clean, fp, en1, inv(clean), clean["err"]
+                            with jax.named_scope(spans.INVARIANTS):
+                                iok1 = inv(clean)
+                            return clean, fp, en1, iok1, clean["err"]
                         succ_f, fp, en2, iok, errv = jax.vmap(one)(
                             st_sel, parts_sel, lane_sel)
                     else:
                         def one(st, lane, fn=fn):
-                            succ, en1 = fn(st, lane)
+                            with jax.named_scope(spans.EXPAND):
+                                succ, en1 = fn(st, lane)
                             clean = {k: v for k, v in succ.items()
                                      if not k.startswith("_")}
-                            return (clean, fpf(clean), en1,
-                                    inv(clean), clean["err"])
+                            with jax.named_scope(spans.FINGERPRINT):
+                                fp1 = fpf(clean)
+                            with jax.named_scope(spans.INVARIANTS):
+                                iok1 = inv(clean)
+                            return clean, fp1, en1, iok1, clean["err"]
                         succ_f, fp, en2, iok, errv = jax.vmap(one)(
                             st_sel, lane_sel)
 
-                    en_s = en2 & sel_ok
-                    errv = jnp.where(en_s, errv, 0)
-                    viol_l = en_s & ~iok & (errv == 0)
-                    a_bag = ((errv & ERR_BAG_OVERFLOW) != 0).any()
-                    a_slot = ((errv & ~ERR_BAG_OVERFLOW) != 0).any()
-                    have_v = viol_l.any()
-                    vidx = jnp.argmax(viol_l)
-                    vinfo = jnp.stack([(base + pidx[vidx]).astype(I32),
-                                       jnp.asarray(aid, I32),
-                                       lane_sel[vidx]])
-                    viol = jnp.where(have_v & (viol[0] < 0), vinfo, viol)
-                    viol_any = viol_any | have_v
-                    bag_err = bag_err | a_bag
-                    slot_err = slot_err | a_slot
+                    with jax.named_scope(spans.INVARIANTS):
+                        en_s = en2 & sel_ok
+                        errv = jnp.where(en_s, errv, 0)
+                        viol_l = en_s & ~iok & (errv == 0)
+                        a_bag = ((errv & ERR_BAG_OVERFLOW) != 0).any()
+                        a_slot = ((errv & ~ERR_BAG_OVERFLOW) != 0).any()
+                        have_v = viol_l.any()
+                        vidx = jnp.argmax(viol_l)
+                        vinfo = jnp.stack(
+                            [(base + pidx[vidx]).astype(I32),
+                             jnp.asarray(aid, I32), lane_sel[vidx]])
+                        viol = jnp.where(have_v & (viol[0] < 0), vinfo,
+                                         viol)
+                        viol_any = viol_any | have_v
+                        bag_err = bag_err | a_bag
+                        slot_err = slot_err | a_slot
 
                     # -- phase 3: insert + scatter, consumed in place --
                     commit_a = (commit & ~have_v & ~a_slot & ~a_bag
@@ -632,20 +651,23 @@ class DeviceBFS:
                     tbl, fresh, a_ovf_i = insert_core(
                         {"slots": slots}, fp, en_s & commit_a)
                     slots = tbl["slots"]
-                    dest = jnp.where(fresh, nn + jnp.cumsum(fresh) - 1,
-                                     N_cap).astype(I32)
-                    if pk is not None:
-                        # pack successors on exit: the next buffer holds
-                        # [words] uint32 rows, not dense planes
-                        nb = nb.at[dest].set(jax.vmap(pk.pack)(succ_f),
-                                             mode="drop")
-                    else:
-                        for k in nb:
-                            nb[k] = nb[k].at[dest].set(succ_f[k],
-                                                       mode="drop")
-                    nbp = nbp.at[dest].set(base + pidx, mode="drop")
-                    nba = nba.at[dest].set(aid, mode="drop")
-                    nbprm = nbprm.at[dest].set(lane_sel, mode="drop")
+                    with jax.named_scope(spans.PACK_SCATTER):
+                        dest = jnp.where(
+                            fresh, nn + jnp.cumsum(fresh) - 1,
+                            N_cap).astype(I32)
+                        if pk is not None:
+                            # pack successors on exit: the next buffer
+                            # holds [words] uint32 rows, not dense
+                            # planes
+                            nb = nb.at[dest].set(
+                                jax.vmap(pk.pack)(succ_f), mode="drop")
+                        else:
+                            for k in nb:
+                                nb[k] = nb[k].at[dest].set(succ_f[k],
+                                                           mode="drop")
+                        nbp = nbp.at[dest].set(base + pidx, mode="drop")
+                        nba = nba.at[dest].set(aid, mode="drop")
+                        nbprm = nbprm.at[dest].set(lane_sel, mode="drop")
                     nfi = fresh.sum()
                     nn = nn + nfi
                     dist = dist + nfi
@@ -657,9 +679,11 @@ class DeviceBFS:
                         # staged and appended once at tile end, gated
                         # on the whole tile committing — the same
                         # exactly-once discipline as `gen`
-                        gids_v = store_gids(
-                            slots, gids_v, fp,
-                            (edge_bases[1] + dest).astype(I32), fresh)
+                        with jax.named_scope(spans.EDGE_EMIT):
+                            gids_v = store_gids(
+                                slots, gids_v, fp,
+                                (edge_bases[1] + dest).astype(I32),
+                                fresh)
                         fp_segs.append(fp)
                         en_segs_e.append(en_s)
                         pidx_segs_e.append(pidx)
@@ -706,23 +730,24 @@ class DeviceBFS:
                     # queue order = the fused body's), gated on the
                     # final commit flag — a tile that paused or failed
                     # emits nothing and re-emits whole on re-entry
-                    fp_q = jnp.concatenate(fp_segs)
-                    emit = jnp.concatenate(en_segs_e) & commit
-                    pidx_q = jnp.concatenate(pidx_segs_e)
-                    dst_g = lookup_gids({"slots": slots}, gids_v,
-                                        fp_q, emit)
-                    edst = jnp.where(
-                        emit, c["edge_n"] + jnp.cumsum(emit) - 1,
-                        E_cap_e)
-                    ret["gids"] = gids_v
-                    ret["eb_src"] = c["eb_src"].at[edst].set(
-                        (edge_bases[0] + base + pidx_q).astype(I32),
-                        mode="drop")
-                    ret["eb_aid"] = c["eb_aid"].at[edst].set(
-                        aid_q_pa, mode="drop")
-                    ret["eb_dst"] = c["eb_dst"].at[edst].set(
-                        dst_g, mode="drop")
-                    ret["edge_n"] = c["edge_n"] + emit.sum()
+                    with jax.named_scope(spans.EDGE_EMIT):
+                        fp_q = jnp.concatenate(fp_segs)
+                        emit = jnp.concatenate(en_segs_e) & commit
+                        pidx_q = jnp.concatenate(pidx_segs_e)
+                        dst_g = lookup_gids({"slots": slots}, gids_v,
+                                            fp_q, emit)
+                        edst = jnp.where(
+                            emit, c["edge_n"] + jnp.cumsum(emit) - 1,
+                            E_cap_e)
+                        ret["gids"] = gids_v
+                        ret["eb_src"] = c["eb_src"].at[edst].set(
+                            (edge_bases[0] + base + pidx_q).astype(I32),
+                            mode="drop")
+                        ret["eb_aid"] = c["eb_aid"].at[edst].set(
+                            aid_q_pa, mode="drop")
+                        ret["eb_dst"] = c["eb_dst"].at[edst].set(
+                            dst_g, mode="drop")
+                        ret["edge_n"] = c["edge_n"] + emit.sum()
                 return ret
 
             return body
@@ -799,31 +824,36 @@ class DeviceBFS:
                 if chunk_ctx is not None:
                     cstates, csegs, c_start = chunk_ctx
                     off = (t - c_start) * T
-                    tile = {k: jax.lax.dynamic_slice_in_dim(v, off, T)
-                            for k, v in cstates.items()}
-                    en_segs = [jax.lax.dynamic_slice_in_dim(s, off, T)
-                               for s in csegs]
+                    with jax.named_scope(spans.GUARD_MATRIX):
+                        tile = {k: jax.lax.dynamic_slice_in_dim(v, off, T)
+                                for k, v in cstates.items()}
+                        en_segs = [
+                            jax.lax.dynamic_slice_in_dim(s, off, T)
+                            for s in csegs]
                 else:
-                    if pk is not None:
-                        tile = jax.vmap(pk.unpack)(
-                            frontier[jnp.clip(sidx, 0, F_cap - 1)])
-                    else:
-                        tile = {k: v[jnp.clip(sidx, 0, F_cap - 1)]
-                                for k, v in frontier.items()}
+                    with jax.named_scope(spans.PACK_SCATTER):
+                        if pk is not None:
+                            tile = jax.vmap(pk.unpack)(
+                                frontier[jnp.clip(sidx, 0, F_cap - 1)])
+                        else:
+                            tile = {k: v[jnp.clip(sidx, 0, F_cap - 1)]
+                                    for k, v in frontier.items()}
                     en_segs = guard_mat(tile)
                 # -- stage 1: guard matrix -> exact per-action counts --
-                en_segs = [e & valid[:, None] for e in en_segs]
-                cnts = jnp.stack([e.sum(dtype=I32) for e in en_segs])
-                en_any = jnp.zeros((T,), bool)
-                for e in en_segs:
-                    en_any = en_any | e.any(axis=1)
-                gen_local = cnts.sum()
-                ovf_vec = cnts > caps_v
-                ovf_e = ovf_vec.any()
-                grow_aid = jnp.where(ovf_e,
-                                     jnp.argmax(ovf_vec).astype(I32),
-                                     c["grow_aid"])
-                need = jnp.maximum(c["need"], cnts.astype(jnp.uint32))
+                with jax.named_scope(spans.GUARD_MATRIX):
+                    en_segs = [e & valid[:, None] for e in en_segs]
+                    cnts = jnp.stack([e.sum(dtype=I32) for e in en_segs])
+                    en_any = jnp.zeros((T,), bool)
+                    for e in en_segs:
+                        en_any = en_any | e.any(axis=1)
+                    gen_local = cnts.sum()
+                    ovf_vec = cnts > caps_v
+                    ovf_e = ovf_vec.any()
+                    grow_aid = jnp.where(ovf_e,
+                                         jnp.argmax(ovf_vec).astype(I32),
+                                         c["grow_aid"])
+                    need = jnp.maximum(c["need"],
+                                       cnts.astype(jnp.uint32))
                 if por_active:
                     # ample candidate per frontier row: one gather of
                     # the enabled bitmask against the independence
@@ -866,7 +896,8 @@ class DeviceBFS:
 
                 # -- stage 2: work-queue compaction + expansion --------
                 if incremental:
-                    parts = jax.vmap(kern.parent_parts)(tile)
+                    with jax.named_scope(spans.FINGERPRINT):
+                        parts = jax.vmap(kern.parent_parts)(tile)
                 succ_segs, fp_segs, en_s_segs = [], [], []
                 pidx_segs, lane_segs = [], []
                 viol_any = jnp.asarray(False)
@@ -878,30 +909,38 @@ class DeviceBFS:
                     L_a = kern._lane_count(name)
                     TL = T * L_a
                     E_a = caps[aid]
-                    en_f = en_segs[aid].reshape(TL)
-                    (sel,) = jnp.nonzero(en_f, size=E_a, fill_value=TL)
-                    sel_ok = sel < TL
-                    pidx = jnp.clip(sel // L_a, 0, T - 1).astype(I32)
-                    lane_sel = (sel % L_a).astype(I32)
-                    st_sel = {k: v[pidx] for k, v in tile.items()}
+                    with jax.named_scope(spans.COMPACT):
+                        en_f = en_segs[aid].reshape(TL)
+                        (sel,) = jnp.nonzero(en_f, size=E_a,
+                                             fill_value=TL)
+                        sel_ok = sel < TL
+                        pidx = jnp.clip(sel // L_a, 0, T - 1).astype(I32)
+                        lane_sel = (sel % L_a).astype(I32)
+                        st_sel = {k: v[pidx] for k, v in tile.items()}
 
                     if incremental:
-                        parts_sel = jax.tree_util.tree_map(
-                            lambda v: v[pidx], parts)
+                        with jax.named_scope(spans.COMPACT):
+                            parts_sel = jax.tree_util.tree_map(
+                                lambda v: v[pidx], parts)
 
                         def one(st, parts_one, lane, fn=fn, name=name):
-                            succ, en1 = fn(kern.seed_touch(st), lane)
-                            ri = kern.lane_replica(name, st, lane)
-                            fp = kern.fingerprint_incremental(
-                                succ, ri, parts_one, st)
+                            with jax.named_scope(spans.EXPAND):
+                                succ, en1 = fn(kern.seed_touch(st), lane)
+                            with jax.named_scope(spans.FINGERPRINT):
+                                ri = kern.lane_replica(name, st, lane)
+                                fp = kern.fingerprint_incremental(
+                                    succ, ri, parts_one, st)
                             clean = {k: v for k, v in succ.items()
                                      if not k.startswith("_")}
-                            return clean, fp, en1, inv(clean), clean["err"]
+                            with jax.named_scope(spans.INVARIANTS):
+                                iok1 = inv(clean)
+                            return clean, fp, en1, iok1, clean["err"]
                         succ_f, fp, en2, iok, errv = jax.vmap(one)(
                             st_sel, parts_sel, lane_sel)
                     else:
                         def one(st, lane, fn=fn):
-                            succ, en1 = fn(st, lane)
+                            with jax.named_scope(spans.EXPAND):
+                                succ, en1 = fn(st, lane)
                             clean = {k: v for k, v in succ.items()
                                      if not k.startswith("_")}
                             # ISSUE 11 commit stage: the fingerprint
@@ -909,44 +948,50 @@ class DeviceBFS:
                             # (fpf) while the staged queue keeps the
                             # generated state — orbit-mates dedup to
                             # one committed representative
-                            return (clean, fpf(clean), en1,
-                                    inv(clean), clean["err"])
+                            with jax.named_scope(spans.FINGERPRINT):
+                                fp1 = fpf(clean)
+                            with jax.named_scope(spans.INVARIANTS):
+                                iok1 = inv(clean)
+                            return clean, fp1, en1, iok1, clean["err"]
                         succ_f, fp, en2, iok, errv = jax.vmap(one)(
                             st_sel, lane_sel)
 
-                    en_s = en2 & sel_ok
-                    errv = jnp.where(en_s, errv, 0)
-                    viol_l = en_s & ~iok & (errv == 0)
-                    a_bag = ((errv & ERR_BAG_OVERFLOW) != 0).any()
-                    a_slot = ((errv & ~ERR_BAG_OVERFLOW) != 0).any()
-                    have_v = viol_l.any()
-                    vidx = jnp.argmax(viol_l)
-                    vinfo = jnp.stack([(base + pidx[vidx]).astype(I32),
-                                       jnp.asarray(aid, I32),
-                                       lane_sel[vidx]])
-                    viol = jnp.where(have_v & (viol[0] < 0), vinfo, viol)
-                    viol_any = viol_any | have_v
-                    bag_err = bag_err | a_bag
-                    slot_err = slot_err | a_slot
-                    # committed-prefix rule: every queue item of an
-                    # action at or past the FIRST failing one commits
-                    # nothing (identical to the per-action body's
-                    # carried commit flag going false there)
-                    bad_a = have_v | a_slot | a_bag | ovf_vec[aid]
-                    first_bad = jnp.minimum(
-                        first_bad, jnp.where(bad_a, aid, n_act))
+                    with jax.named_scope(spans.INVARIANTS):
+                        en_s = en2 & sel_ok
+                        errv = jnp.where(en_s, errv, 0)
+                        viol_l = en_s & ~iok & (errv == 0)
+                        a_bag = ((errv & ERR_BAG_OVERFLOW) != 0).any()
+                        a_slot = ((errv & ~ERR_BAG_OVERFLOW) != 0).any()
+                        have_v = viol_l.any()
+                        vidx = jnp.argmax(viol_l)
+                        vinfo = jnp.stack(
+                            [(base + pidx[vidx]).astype(I32),
+                             jnp.asarray(aid, I32), lane_sel[vidx]])
+                        viol = jnp.where(have_v & (viol[0] < 0), vinfo,
+                                         viol)
+                        viol_any = viol_any | have_v
+                        bag_err = bag_err | a_bag
+                        slot_err = slot_err | a_slot
+                        # committed-prefix rule: every queue item of an
+                        # action at or past the FIRST failing one
+                        # commits nothing (identical to the per-action
+                        # body's carried commit flag going false there)
+                        bad_a = have_v | a_slot | a_bag | ovf_vec[aid]
+                        first_bad = jnp.minimum(
+                            first_bad, jnp.where(bad_a, aid, n_act))
                     succ_segs.append(succ_f)
                     fp_segs.append(fp)
                     en_s_segs.append(en_s)
                     pidx_segs.append(pidx)
                     lane_segs.append(lane_sel)
 
-                succ_q = {k: jnp.concatenate([s[k] for s in succ_segs])
-                          for k in succ_segs[0]}
-                fp_q = jnp.concatenate(fp_segs)
-                en_q = jnp.concatenate(en_s_segs)
-                pidx_q = jnp.concatenate(pidx_segs)
-                lane_q = jnp.concatenate(lane_segs)
+                with jax.named_scope(spans.COMPACT):
+                    succ_q = {k: jnp.concatenate(
+                        [s[k] for s in succ_segs]) for k in succ_segs[0]}
+                    fp_q = jnp.concatenate(fp_segs)
+                    en_q = jnp.concatenate(en_s_segs)
+                    pidx_q = jnp.concatenate(pidx_segs)
+                    lane_q = jnp.concatenate(lane_segs)
 
                 # -- stage 3: ONE insert batch + ONE scatter per tile --
                 keep_q = en_q
@@ -979,22 +1024,25 @@ class DeviceBFS:
                 # FPSet claim column then only has to arbitrate
                 # distinct fingerprints racing for one probe slot
                 perm, keep = dedup_batch(fp_q, mcommit)
-                canon = jnp.zeros((total_E,), bool).at[perm].set(keep)
+                with jax.named_scope(spans.FPSET_INSERT):
+                    canon = jnp.zeros((total_E,),
+                                      bool).at[perm].set(keep)
                 tbl, fresh, ovf_i = insert_core(
                     {"slots": slots}, fp_q, canon)
                 slots = tbl["slots"]
-                dest = jnp.where(fresh, nn + jnp.cumsum(fresh) - 1,
-                                 N_cap).astype(I32)
-                if pk is not None:
-                    nb = nb.at[dest].set(jax.vmap(pk.pack)(succ_q),
-                                         mode="drop")
-                else:
-                    for k in nb:
-                        nb[k] = nb[k].at[dest].set(succ_q[k],
-                                                   mode="drop")
-                nbp = nbp.at[dest].set(base + pidx_q, mode="drop")
-                nba = nba.at[dest].set(aid_q, mode="drop")
-                nbprm = nbprm.at[dest].set(lane_q, mode="drop")
+                with jax.named_scope(spans.PACK_SCATTER):
+                    dest = jnp.where(fresh, nn + jnp.cumsum(fresh) - 1,
+                                     N_cap).astype(I32)
+                    if pk is not None:
+                        nb = nb.at[dest].set(jax.vmap(pk.pack)(succ_q),
+                                             mode="drop")
+                    else:
+                        for k in nb:
+                            nb[k] = nb[k].at[dest].set(succ_q[k],
+                                                       mode="drop")
+                    nbp = nbp.at[dest].set(base + pidx_q, mode="drop")
+                    nba = nba.at[dest].set(aid_q, mode="drop")
+                    nbprm = nbprm.at[dest].set(lane_q, mode="drop")
                 nfi = fresh.sum()
                 nn = nn + nfi
                 dist = dist + nfi
@@ -1072,24 +1120,25 @@ class DeviceBFS:
                     # re-entry emits exactly once, with its already-
                     # committed lanes resolving as duplicates
                     src_base, gid_base = edge_bases
-                    gids_v = store_gids(
-                        slots, c["gids"], fp_q,
-                        (gid_base + dest).astype(I32), fresh)
-                    emit = en_q & commit
-                    dst_g = lookup_gids({"slots": slots}, gids_v,
-                                        fp_q, emit)
-                    edst = jnp.where(
-                        emit, c["edge_n"] + jnp.cumsum(emit) - 1,
-                        E_cap_e)
-                    ret["gids"] = gids_v
-                    ret["eb_src"] = c["eb_src"].at[edst].set(
-                        (src_base + base + pidx_q).astype(I32),
-                        mode="drop")
-                    ret["eb_aid"] = c["eb_aid"].at[edst].set(
-                        aid_q, mode="drop")
-                    ret["eb_dst"] = c["eb_dst"].at[edst].set(
-                        dst_g, mode="drop")
-                    ret["edge_n"] = c["edge_n"] + emit.sum()
+                    with jax.named_scope(spans.EDGE_EMIT):
+                        gids_v = store_gids(
+                            slots, c["gids"], fp_q,
+                            (gid_base + dest).astype(I32), fresh)
+                        emit = en_q & commit
+                        dst_g = lookup_gids({"slots": slots}, gids_v,
+                                            fp_q, emit)
+                        edst = jnp.where(
+                            emit, c["edge_n"] + jnp.cumsum(emit) - 1,
+                            E_cap_e)
+                        ret["gids"] = gids_v
+                        ret["eb_src"] = c["eb_src"].at[edst].set(
+                            (src_base + base + pidx_q).astype(I32),
+                            mode="drop")
+                        ret["eb_aid"] = c["eb_aid"].at[edst].set(
+                            aid_q, mode="drop")
+                        ret["eb_dst"] = c["eb_dst"].at[edst].set(
+                            dst_g, mode="drop")
+                        ret["edge_n"] = c["edge_n"] + emit.sum()
                 return ret
 
             return body
@@ -1134,14 +1183,18 @@ class DeviceBFS:
                 cidx = start_t * T + jnp.arange(K * T, dtype=I32)
                 cvalid = cidx < n_front
                 gidx = jnp.clip(cidx, 0, F_cap - 1)
-                if pk is not None:
-                    cstates = jax.vmap(pk.unpack)(frontier[gidx])
-                else:
-                    cstates = {k: v[gidx] for k, v in frontier.items()}
-                csegs = [e & cvalid[:, None] for e in guard_mat(cstates)]
-                need0 = jnp.stack(
-                    [e.reshape(K, -1).sum(axis=1, dtype=I32).max()
-                     for e in csegs]).astype(jnp.uint32)
+                with jax.named_scope(spans.PACK_SCATTER):
+                    if pk is not None:
+                        cstates = jax.vmap(pk.unpack)(frontier[gidx])
+                    else:
+                        cstates = {k: v[gidx]
+                                   for k, v in frontier.items()}
+                csegs = guard_mat(cstates)
+                with jax.named_scope(spans.GUARD_MATRIX):
+                    csegs = [e & cvalid[:, None] for e in csegs]
+                    need0 = jnp.stack(
+                        [e.reshape(K, -1).sum(axis=1, dtype=I32).max()
+                         for e in csegs]).astype(jnp.uint32)
                 chunk_ctx = (cstates, csegs, start_t)
 
             def cond(c):
@@ -1854,17 +1907,18 @@ class DeviceBFS:
             # not leak the previous run's trajectory into an
             # init-violation result
             self.level_sizes = []
-            table, init_batch, n0, viol = self._register_init(res)
-            fp_count = n0
+            with obs.span(spans.INIT):
+                table, init_batch, n0, viol = self._register_init(res)
+                fp_count = n0
+                if viol is None:
+                    # --- device frontier + next buffers ---------------
+                    f_cap = max(self.next_cap, n0)
+                    front, fpar, fact, fprm = self._alloc_bufs(f_cap)
+                    front = self._set_rows(front, init_batch, n0)
+                    bufs = self._alloc_bufs(self.next_cap)
             if viol is not None:
                 return self._finish(res, obs, fp_count,
                                     table=table, fp_cap=fp_cap)
-
-            # --- device frontier + next buffers -----------------------
-            f_cap = max(self.next_cap, n0)
-            front, fpar, fact, fprm = self._alloc_bufs(f_cap)
-            front = self._set_rows(front, init_batch, n0)
-            bufs = self._alloc_bufs(self.next_cap)
             n_front = n0
             level_base = 0          # gid of frontier[0]
             depth = 0
@@ -1947,7 +2001,7 @@ class DeviceBFS:
                         jnp.asarray(bool(check_deadlock)), None, None,
                         jnp.asarray(depth - 1, I32),
                         fresh=self._fresh_jit,
-                        label=f"level {depth} dispatch")
+                        depth=depth)
                     self._fresh_jit = False
                     table = {"slots": out["slots"]}
                     if self._por_active:
@@ -1972,7 +2026,7 @@ class DeviceBFS:
                                  generated=res.states_generated)
                     if max_seconds and time.time() - t0 > max_seconds:
                         stop = f"time budget {max_seconds}s reached"
-                        pipe.drain()
+                        pipe.drain(reason="budget")
                         break
                     if start_t >= n_tiles:
                         pipe.drain()     # in-flight tickets are no-ops
@@ -2092,7 +2146,7 @@ class DeviceBFS:
                     or checkpoint_every is None
                     or time.time() - last_checkpoint >= checkpoint_every):
                 from .checkpoint import save_checkpoint, spec_digest
-                with obs.timer("checkpoint"):
+                with obs.span(spans.CHECKPOINT, depth=depth):
                     self._flush_pointers()
                     save_checkpoint(
                         checkpoint_path,
@@ -2219,20 +2273,21 @@ class DeviceBFS:
 
         fp_cap = self.fpset_capacity
         self.level_sizes = []      # no stale trajectory on init-viol
-        table, init_batch, n0, viol = self._register_init(res)
+        with obs.span(spans.INIT):
+            table, init_batch, n0, viol = self._register_init(res)
+            if viol is None:
+                # ping-pong buffers share one capacity in fused mode
+                f_cap = max(self.next_cap, n0)
+                front, nbp, nba, nbprm = self._alloc_bufs(f_cap)
+                front = self._set_rows(front, init_batch, n0)
+                nb, _, _, _ = self._alloc_bufs(f_cap)
+                tp_cap = max(4 * f_cap, 1 << 16)
+                tpp = jnp.full((tp_cap,), -1, I32)
+                tpa = jnp.full((tp_cap,), -1, I32)
+                tpm = jnp.zeros((tp_cap,), I32)
+                lvl_buf = jnp.zeros((levels_per_dispatch,), I32)
         if viol is not None:
             return self._finish(res, obs, n0, table=table, fp_cap=fp_cap)
-
-        # ping-pong buffers share one capacity in fused mode
-        f_cap = max(self.next_cap, n0)
-        front, nbp, nba, nbprm = self._alloc_bufs(f_cap)
-        front = self._set_rows(front, init_batch, n0)
-        nb, _, _, _ = self._alloc_bufs(f_cap)
-        tp_cap = max(4 * f_cap, 1 << 16)
-        tpp = jnp.full((tp_cap,), -1, I32)
-        tpa = jnp.full((tp_cap,), -1, I32)
-        tpm = jnp.zeros((tp_cap,), I32)
-        lvl_buf = jnp.zeros((levels_per_dispatch,), I32)
 
         # run() parity on the limit conventions: max_depth=0 is a real
         # limit there (`is not None` — stops before the first level)
@@ -2270,8 +2325,7 @@ class DeviceBFS:
             if self._ml is None:
                 self._ml = jax.jit(self._make_multilevel(),
                                    donate_argnums=tuple(range(10)))
-            with obs.timer("compile" if fresh else "dispatch"), \
-                    obs.annotate(f"fused dispatch (depth {depth}+)"):
+            with obs.span(spans.build_phase(fresh), depth=depth):
                 out = self._ml(
                     table["slots"], front, nb, nbp, nba, nbprm,
                     tpp, tpa, tpm, lvl_buf,
@@ -2297,7 +2351,7 @@ class DeviceBFS:
             nbp, nba, nbprm = out["nbp"], out["nba"], out["nbprm"]
             tpp, tpa, tpm = out["tpp"], out["tpa"], out["tpm"]
             lvl_buf = out["lvl_buf"]
-            with obs.timer("host_sync"):
+            with obs.span(spans.HOST_SYNC):
                 sc = jax.device_get(
                     [out[k] for k in ("reason", "n_front", "start_t",
                                       "nn", "gen_level", "gen", "depth",
@@ -2364,7 +2418,7 @@ class DeviceBFS:
                         or time.time() - last_checkpoint
                         >= checkpoint_every):
                     from .checkpoint import save_checkpoint, spec_digest
-                    with obs.timer("checkpoint"):
+                    with obs.span(spans.CHECKPOINT, depth=depth):
                         set_pointers(level_base + n_front)
                         save_checkpoint(
                             checkpoint_path,
@@ -2576,18 +2630,20 @@ class DeviceBFS:
 
         fp_cap = self.fpset_capacity
         self.level_sizes = []      # no stale trajectory on init-viol
-        table, init_batch, n0, viol = self._register_init(res)
+        with obs.span(spans.INIT):
+            table, init_batch, n0, viol = self._register_init(res)
+            if viol is None:
+                f_cap = max(self.next_cap, n0)
+                front, nbp, nba, nbprm = self._alloc_bufs(f_cap)
+                front = self._set_rows(front, init_batch, n0)
+                nb, _, _, _ = self._alloc_bufs(f_cap)
+                tp_cap = max(4 * f_cap, 1 << 16)
+                tpp = jnp.full((tp_cap,), -1, I32)
+                tpa = jnp.full((tp_cap,), -1, I32)
+                tpm = jnp.zeros((tp_cap,), I32)
+                lvl_buf = jnp.zeros((levels_cap,), I32)
         if viol is not None:
             return self._finish(res, obs, n0, table=table, fp_cap=fp_cap)
-        f_cap = max(self.next_cap, n0)
-        front, nbp, nba, nbprm = self._alloc_bufs(f_cap)
-        front = self._set_rows(front, init_batch, n0)
-        nb, _, _, _ = self._alloc_bufs(f_cap)
-        tp_cap = max(4 * f_cap, 1 << 16)
-        tpp = jnp.full((tp_cap,), -1, I32)
-        tpa = jnp.full((tp_cap,), -1, I32)
-        tpm = jnp.zeros((tp_cap,), I32)
-        lvl_buf = jnp.zeros((levels_cap,), I32)
         md = 2**31 - 1 if max_depth is None else int(max_depth)
         ms = int(max_states) if max_states else 2**31 - 1
 
@@ -2654,7 +2710,7 @@ class DeviceBFS:
                 # slot 0 of ITS lvl_buf output (which is why lvl_buf is
                 # excluded from donation: this read can race a newer
                 # in-flight dispatch)
-                with obs.timer("host_sync"):
+                with obs.span(spans.HOST_SYNC):
                     sizes = np.asarray(out["lvl_buf"][:lvl_cur])
                 cum = sum(self.level_sizes)
                 for x in sizes:
@@ -2697,7 +2753,7 @@ class DeviceBFS:
                 jnp.asarray(tile_budget, I32),
                 *((table["gids"], d_gfull_level, d_amp_level)
                   if por_on else ()),
-                fresh=fresh, label=f"window (depth {depth}+)")
+                fresh=fresh, depth=depth)
             self._fresh_jit = False
             table = {"slots": out["slots"]}
             if por_on:
@@ -2786,7 +2842,7 @@ class DeviceBFS:
                     if checkpoint_path:
                         from .checkpoint import (save_checkpoint,
                                                  spec_digest)
-                        with obs.timer("checkpoint"):
+                        with obs.span(spans.CHECKPOINT, depth=depth):
                             set_pointers(level_base + n_front)
                             save_checkpoint(
                                 checkpoint_path,

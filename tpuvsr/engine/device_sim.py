@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import registry
-from ..obs import RunObserver, closes_observer
+from ..obs import RunObserver, closes_observer, spans
 from .simulate import SimResult
 from .spec import SpecModel
 from .trace import TraceEntry
@@ -378,16 +378,15 @@ class DeviceSimulator:
                 key, sub = jax.random.split(key)
                 keys = jax.random.split(sub, k)
                 while True:
-                    phase = "compile" if self._fresh_jit else "dispatch"
-                    with obs.timer(phase), obs.annotate(
-                            f"sim chunk (depth {d}) {phase}"):
+                    with obs.span(spans.build_phase(self._fresh_jit),
+                                  depth=d):
                         (nstates, alive, bad, dead, err_any, ovf, steps,
                          hist) = self._chunk(states, was_alive, keys,
                                              logw)
                         err_any.block_until_ready()
                     self._fresh_jit = False
                     obs.count("dispatches")
-                    with obs.timer("host_sync"):
+                    with obs.span(spans.HOST_SYNC):
                         err_any_h = bool(err_any)
                         ovf = np.asarray(ovf)
                     if err_any_h:
@@ -419,7 +418,7 @@ class DeviceSimulator:
                         continue
                     break
                 hists.append(hist)
-                with obs.timer("host_sync"):
+                with obs.span(spans.HOST_SYNC):
                     res.steps += int(steps)
                     bad = np.asarray(bad)
                     dead = np.asarray(dead)

@@ -40,6 +40,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs import spans
+
 U32 = jnp.uint32
 MAX_PROBES = 64
 
@@ -67,6 +69,7 @@ def _slot_hash(fps):
     return h * jnp.uint32(0x27D4EB2F)
 
 
+@jax.named_scope(spans.FPSET_INSERT)
 def dedup_batch(fps, mask, tie=None):
     """Keep the first occurrence of each distinct fingerprint.
 
@@ -108,6 +111,7 @@ def _keyed(fps):
     return keyed, _slot_hash(keyed)
 
 
+@jax.named_scope(spans.FPSET_INSERT)
 def insert_core(table, fps, mask):
     """Insert fps[mask] into the table.  Duplicate fingerprints within
     the batch are allowed: exactly one lane per distinct new fingerprint

@@ -227,14 +227,14 @@ def liveness_check(spec: SpecModel, max_states=None,
     triple from build_graph, or a device-built
     engine.device_liveness.DeviceGraph (same attributes, lazy state
     decode, batched predicate evaluation)."""
-    from ..obs import RunObserver
+    from ..obs import RunObserver, spans
     obs = RunObserver.ensure(obs, "liveness", spec, log=log)
     res = LivenessResult()
     t0 = time.time()
     obs.start(t0, backend="host")
     dev_graph = None
     try:
-        with obs.timer("graph_build"):
+        with obs.span(spans.GRAPH_BUILD):
             if graph is None:
                 states, edges, inits = _build_graph(spec, max_states)
             elif hasattr(graph, "batch_predicate"):

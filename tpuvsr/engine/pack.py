@@ -56,6 +56,7 @@ import json
 import numpy as np
 
 from ..core.values import TLAError
+from ..obs import spans
 
 WORD_BITS = 32
 _FULL = np.uint32(0xFFFFFFFF)
@@ -201,37 +202,40 @@ class PackSpec:
         -> ``[words]`` uint32 row.  Pure jnp; call under jit/vmap."""
         import jax
         import jax.numpy as jnp
-        parts = [jnp.asarray(state[k], jnp.int32).reshape(-1)
-                 for k, _s, _p0, _p1 in self._splits_iter()]
-        flat = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-        v = (flat - jnp.asarray(self._lo)).astype(jnp.uint32) \
-            & jnp.asarray(self._mask)
-        off = jnp.asarray(self._off)
-        lo_w = jnp.left_shift(v, off)
-        hi_w = jnp.right_shift(
-            jnp.right_shift(v, jnp.asarray(self._hishift)), 1)
-        widx = jnp.asarray(self._widx)
-        words = jax.ops.segment_sum(
-            jnp.concatenate([lo_w, hi_w]),
-            jnp.concatenate([widx, widx + 1]),
-            num_segments=self.words + 1)
-        return words[:self.words].astype(jnp.uint32)
+        with jax.named_scope(spans.PACK_SCATTER):
+            parts = [jnp.asarray(state[k], jnp.int32).reshape(-1)
+                     for k, _s, _p0, _p1 in self._splits_iter()]
+            flat = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+            v = (flat - jnp.asarray(self._lo)).astype(jnp.uint32) \
+                & jnp.asarray(self._mask)
+            off = jnp.asarray(self._off)
+            lo_w = jnp.left_shift(v, off)
+            hi_w = jnp.right_shift(
+                jnp.right_shift(v, jnp.asarray(self._hishift)), 1)
+            widx = jnp.asarray(self._widx)
+            words = jax.ops.segment_sum(
+                jnp.concatenate([lo_w, hi_w]),
+                jnp.concatenate([widx, widx + 1]),
+                num_segments=self.words + 1)
+            return words[:self.words].astype(jnp.uint32)
 
     def unpack(self, row):
         """``[words]`` uint32 row -> dense per-row state dict."""
+        import jax
         import jax.numpy as jnp
-        w = jnp.asarray(row, jnp.uint32)
-        widx = jnp.asarray(self._widx)
-        w0 = w[widx]
-        w1 = w[jnp.minimum(widx + 1, self.words - 1)]
-        off = jnp.asarray(self._off)
-        v = (jnp.right_shift(w0, off)
-             | jnp.left_shift(
-                 jnp.left_shift(w1, jnp.asarray(self._hishift)), 1)) \
-            & jnp.asarray(self._mask)
-        flat = v.astype(jnp.int32) + jnp.asarray(self._lo)
-        return {k: flat[a:b].reshape(s)
-                for k, s, a, b in self._splits}
+        with jax.named_scope(spans.PACK_SCATTER):
+            w = jnp.asarray(row, jnp.uint32)
+            widx = jnp.asarray(self._widx)
+            w0 = w[widx]
+            w1 = w[jnp.minimum(widx + 1, self.words - 1)]
+            off = jnp.asarray(self._off)
+            v = (jnp.right_shift(w0, off)
+                 | jnp.left_shift(
+                     jnp.left_shift(w1, jnp.asarray(self._hishift)), 1)) \
+                & jnp.asarray(self._mask)
+            flat = v.astype(jnp.int32) + jnp.asarray(self._lo)
+            return {k: flat[a:b].reshape(s)
+                    for k, s, a, b in self._splits}
 
     def _splits_iter(self):
         return self._splits

@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.values import TLAError
-from ..obs import RunObserver, closes_observer
+from ..obs import RunObserver, closes_observer, spans
 from ..resilience.faults import fault_point
 from ..resilience.supervisor import Preempted, preempt_signal
 from .bfs import CheckResult
@@ -346,7 +346,8 @@ class PagedBFS(DeviceBFS):
         else:
             fp_cap = self.fpset_capacity
             self.level_sizes = []  # no stale trajectory on init-viol
-            table, init_batch, n0, viol = self._register_init(res)
+            with obs.span(spans.INIT):
+                table, init_batch, n0, viol = self._register_init(res)
             fp_count = n0
             if viol is not None:
                 return self._finish(res, obs, fp_count,
@@ -373,7 +374,8 @@ class PagedBFS(DeviceBFS):
         # re-floored on every in-run rebuild — a stale floor live-locks
         # the drain loop (commit never true with an empty buffer).
         self.next_cap = max(self.next_cap, self._total_E() + self.tile)
-        bufs = self._alloc_bufs(self.next_cap)
+        with obs.span(spans.INIT):
+            bufs = self._alloc_bufs(self.next_cap)
         # edge append buffer (ISSUE 15): same total_E + one-tile floor
         # as the next buffer (the kernel refuses to commit a tile
         # without total_E triples of headroom); default sized 4x the
@@ -439,7 +441,7 @@ class PagedBFS(DeviceBFS):
                 if n_next == 0:
                     return
                 nb, nbp, nba, nbprm = bufs
-                with obs.timer("host_sync"):
+                with obs.span(spans.HOST_SYNC):
                     rows, par, act, prm = jax.device_get(
                         (nb[:n_next] if self._pk is not None
                          else {k: v[:n_next] for k, v in nb.items()},
@@ -480,7 +482,7 @@ class PagedBFS(DeviceBFS):
                 if not self._edges_on or n_edge == 0:
                     return
                 es, ea, ed = ebufs
-                with obs.timer("host_sync"):
+                with obs.span(spans.HOST_SYNC):
                     s, a, d = jax.device_get(
                         (es[:n_edge], ea[:n_edge], ed[:n_edge]))
                 self.edge_sink.append(np.asarray(s), np.asarray(a),
@@ -545,7 +547,7 @@ class PagedBFS(DeviceBFS):
                             eb_arg, emeta_arg,
                             jnp.asarray(depth - 1, I32),
                             fresh=self._fresh_jit,
-                            label=f"level {depth} dispatch")
+                            depth=depth)
                         self._fresh_jit = False
                         table = {"slots": out["slots"]}
                         if self._por_active:
@@ -781,7 +783,7 @@ class PagedBFS(DeviceBFS):
                     if self.retain_levels:
                         fr_kw["graph_blocks"] = iter(
                             self.level_blocks)
-                with obs.timer("checkpoint"):
+                with obs.span(spans.CHECKPOINT, depth=depth):
                     save_checkpoint(
                         checkpoint_path,
                         slots=table["slots"],

@@ -51,6 +51,8 @@ from __future__ import annotations
 import time
 from collections import deque
 
+from ..obs import spans
+
 
 class DispatchPipeline:
     """A bounded window of in-flight jitted dispatches.
@@ -81,20 +83,19 @@ class DispatchPipeline:
     def has_room(self):
         return len(self._q) < self.window
 
-    def launch(self, fn, *args, fresh=False, label=""):
+    def launch(self, fn, *args, fresh=False, **attrs):
         """Enqueue ``fn(*args)``; returns the (async) output structure.
 
         The first dispatch after a (re)jit compiles synchronously at
         call time and is charged to the ``compile`` phase; at window 1
         the dispatch also blocks to completion here (synchronous-path
-        parity)."""
+        parity).  `attrs` (``depth=``) ride the span."""
         obs = self.obs
         # host work done since the last pipeline call counts as
         # overlapped when something was in flight through it (the
         # collect->handle->launch span is where the hidden work lives)
         self._credit_overlap()
-        with obs.timer("compile" if fresh else "dispatch"), \
-                obs.annotate(label):
+        with obs.span(spans.build_phase(fresh), **attrs):
             out = fn(*args)
             if self.window == 1:
                 self._ready(out).block_until_ready()
@@ -118,22 +119,29 @@ class DispatchPipeline:
         obs = self.obs
         self._credit_overlap()
         if self.window > 1:
-            with obs.timer("inflight"):
+            with obs.span(spans.INFLIGHT):
                 self._ready(out).block_until_ready()
-        with obs.timer("host_sync"):
+        with obs.span(spans.HOST_SYNC):
             sc = pull(out)
         if self._q:
             self._free_since = time.perf_counter()
         return out, sc
 
-    def drain(self):
-        """Discard every still-in-flight ticket (see module docstring:
-        everything behind a pause, stop, or level end is a replay/no-op
-        whose deltas must not be re-counted).  Returns the number of
-        tickets dropped."""
+    def drain(self, reason="replay"):
+        """Discard every still-in-flight ticket.  Returns the number of
+        tickets dropped.
+
+        ``reason="replay"`` (a pause, a stop, a level end): everything
+        behind is a replay/no-op whose deltas must not be re-counted
+        (module docstring), filed under ``pipeline_replays``.
+        ``reason="budget"`` (the time-budget stop of the default path):
+        the tickets hold REAL chunks that the run throws away, filed
+        under ``budget_dropped_dispatches``."""
         n = len(self._q)
         if n:
-            self.obs.count("pipeline_replays", n)
+            self.obs.count("budget_dropped_dispatches"
+                           if reason == "budget" else "pipeline_replays",
+                           n)
             self._q.clear()
         self._free_since = None
         return n

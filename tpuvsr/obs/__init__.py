@@ -1,6 +1,6 @@
 """tpuvsr.obs — shared observability layer for every checking engine.
 
-Three pieces (ISSUE 2 tentpole):
+The pieces:
 
 * **run journal** (``journal.py``) — append-only JSONL event stream
   (``run_start`` / ``level_done`` / ``checkpoint`` / ``spill`` /
@@ -10,17 +10,22 @@ Three pieces (ISSUE 2 tentpole):
   exclusive phase timers, dumped as ``tpuvsr-metrics/1`` JSON
   (``-metrics FILE.json``), merged into the ``-json`` one-line
   summary, and rendered as a final stats table on stderr;
-* **profiler hooks** (``profiler.py``) — ``TPUVSR_PROFILE=DIR`` wraps
-  the fixpoint loops in ``jax.profiler.trace`` with per-level/phase
-  ``TraceAnnotation`` spans.
+* **spans** (``spans.py``, ``profiler.py``) — ``RunObserver.span`` is
+  the one way to mark a host phase: an exclusive phase timer and,
+  under ``TPUVSR_PROFILE=DIR``, a ``TraceAnnotation`` of the same
+  fixed name inside the run's profiler session;
+* **build counters** (``builds.py``) — JAX's own trace / lower /
+  backend-compile events, forwarded to the observer running on the
+  calling thread.
 
-``RunObserver`` (``observer.py``) bundles the three; engines accept
+``RunObserver`` (``observer.py``) bundles them; engines accept
 ``obs=None`` and collect privately, so ``CheckResult.metrics`` exists
 on every run.  Schemas are documented in ``SCHEMA.md``.
 """
 
 from __future__ import annotations
 
+from . import spans
 from .journal import (EVENT_REQUIRED, JOURNAL_SCHEMA, Journal,
                       new_run_id, new_span_id, new_trace_id,
                       read_journal, root_span, trace_env, trace_scope,
@@ -28,7 +33,7 @@ from .journal import (EVENT_REQUIRED, JOURNAL_SCHEMA, Journal,
 from .metrics import (LEVEL_ROW_KEYS, METRICS_SCHEMA, Metrics,
                       validate_metrics)
 from .observer import RunObserver, closes_observer
-from .profiler import annotate, profile_dir, profile_trace
+from .profiler import profile_dir, profile_trace
 from .telemetry import (TELEMETRY_SCHEMA, TelemetryAggregator,
                         prometheus_text)
 
@@ -37,7 +42,7 @@ __all__ = [
     "JOURNAL_SCHEMA", "METRICS_SCHEMA", "EVENT_REQUIRED",
     "LEVEL_ROW_KEYS", "new_run_id", "read_journal",
     "validate_journal_line", "validate_metrics",
-    "annotate", "profile_dir", "profile_trace",
+    "profile_dir", "profile_trace", "spans",
     "new_trace_id", "new_span_id", "root_span", "trace_env",
     "trace_scope",
     "TELEMETRY_SCHEMA", "TelemetryAggregator", "prometheus_text",

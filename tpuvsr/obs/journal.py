@@ -44,6 +44,11 @@ EVENT_REQUIRED = {
     "grow": ("what", "to", "elapsed_s"),
     "violation": ("kind", "name", "elapsed_s"),
     "run_end": ("ok", "elapsed_s"),
+    # build counters (ISSUE 25): one program whose trace + lower +
+    # backend stages took half a second or more (obs/builds.py);
+    # `cache` is hit | miss | none (not kept by the persistent cache)
+    "build": ("fun_name", "trace_s", "lower_s", "backend_s", "cache",
+              "elapsed_s"),
     # resilience events (ISSUE 3): injected/real faults, supervised
     # retry/degrade steps, and preemption rescue snapshots
     "fault": ("what", "site", "elapsed_s"),

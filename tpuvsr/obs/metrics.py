@@ -41,7 +41,7 @@ METRICS_SCHEMA = "tpuvsr-metrics/1"
 # blocked wait on the oldest in-flight dispatch (ISSUE 4) — zero on
 # synchronous (-pipeline 1) runs.
 WELL_KNOWN_PHASES = ("check", "compile", "dispatch", "host_sync",
-                     "inflight")
+                     "inflight", "checkpoint", "init")
 
 # keys a metrics document must carry to be schema-valid
 REQUIRED_METRICS_KEYS = ("schema", "run_id", "engine", "elapsed_s",
@@ -68,6 +68,8 @@ class Metrics:
         self._stack.append([phase, 0.0, time.perf_counter()])
 
     def end(self):
+        if not self._stack:     # drain() already closed this frame
+            return
         phase, child, t0 = self._stack.pop()
         dt = time.perf_counter() - t0
         self.phases[phase] = self.phases.get(phase, 0.0) + dt - child

@@ -7,12 +7,13 @@ through its fixpoint loop:
 
     obs = RunObserver.ensure(obs, "device", spec, log=log)
     obs.start(t0, backend=jax.default_backend(), resumed=False)
-    # start() opens the run-wide "check" phase frame and (under
-    # TPUVSR_PROFILE) the jax.profiler trace; finish() closes both
+    # start() opens the run's root span (the catch-all "check" phase
+    # frame) and, under TPUVSR_PROFILE, the profiler session;
+    # finish() closes both
     while ...:
-        with obs.timer("dispatch"), obs.annotate(f"level {d}"):
+        with obs.span(spans.DISPATCH, depth=d):
             out = self._level(...)
-        with obs.timer("host_sync"):
+        with obs.span(spans.HOST_SYNC):
             sc = jax.device_get(...)
         obs.level_done(depth, frontier=.., distinct=.., generated=..)
         obs.progress(depth=.., distinct=.., generated=..)
@@ -36,10 +37,10 @@ import json
 import os
 import time
 
+from . import builds, spans
 from .journal import JOURNAL_SCHEMA, Journal
 from .metrics import Metrics
-from .profiler import annotate as _annotate
-from .profiler import profile_trace
+from .profiler import annotation_factory, profile_trace
 
 
 def closes_observer(fn):
@@ -61,10 +62,35 @@ def closes_observer(fn):
     return wrapper
 
 
+class _Span:
+    """One host phase: an exclusive phase frame of the metrics
+    collector and, when the run is profiled, a TraceAnnotation of the
+    same name around it."""
+
+    __slots__ = ("_metrics", "_phase", "_annotation")
+
+    def __init__(self, metrics, phase, annotation):
+        self._metrics = metrics
+        self._phase = phase
+        self._annotation = annotation
+
+    def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._metrics.begin(self._phase)
+        return self
+
+    def __exit__(self, *exc):
+        self._metrics.end()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
+
+
 class RunObserver:
     def __init__(self, journal_path=None, metrics_path=None, log=None,
                  progress_every=10.0, run_id=None, primary=True,
-                 table=None):
+                 table=None, annotation=annotation_factory):
         self.journal = Journal(journal_path if primary else None,
                                run_id=run_id)
         self.run_id = self.journal.run_id
@@ -119,6 +145,18 @@ class RunObserver:
         self._last_progress = None
         self._finished = False
         self._profile_cm = None
+        # the profiler side of a span: `annotation()` is asked ONCE, at
+        # start(), for the TraceAnnotation class or None (profiling
+        # off); tests inject a recording factory
+        self._annotation_factory = annotation
+        self._annotation = None
+        self._root = None
+        # what the run built (obs/builds.py), across every segment this
+        # observer rides; attached to the calling thread from start()
+        # to finish() on the device engines
+        self.builds = builds.BuildMeter(report=self._build_event)
+        self._builds_attached = False
+        self._builds_previous = None
 
     # ------------------------------------------------------------------
     @classmethod
@@ -196,7 +234,32 @@ class RunObserver:
                            por=self.por, **extra)
         self._profile_cm = profile_trace(log=self._log)
         self._profile_cm.__enter__()
-        self.metrics.begin("check")
+        self._annotation = self._annotation_factory()
+        if self.backend != "host":
+            self._builds_previous = builds.attach(self.builds)
+            self._builds_attached = True
+        attrs = {"run_id": self.run_id}
+        if self.journal.trace_id:
+            attrs["trace_id"] = self.journal.trace_id
+        self._root = self.span(spans.CHECK, **attrs)
+        self._root.__enter__()
+
+    def _stop_instruments(self):
+        """Close the root span with every frame an early return left
+        open, give the thread back its previous build meter, stop the
+        profiler session."""
+        self.metrics.drain()
+        if self._root is not None:
+            annotation, self._root = self._root._annotation, None
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
+        if self._builds_attached:
+            builds.detach(self._builds_previous)
+            self._builds_attached = False
+            self._builds_previous = None
+        if self._profile_cm is not None:
+            self._profile_cm.__exit__(None, None, None)
+            self._profile_cm = None
 
     def close(self):
         """Finalize instrumentation on an abnormal exit: drain open
@@ -207,10 +270,7 @@ class RunObserver:
         exception; elsewhere an in-band engine error behaves like a
         kill (valid journal prefix, no run_end — the documented crash
         contract)."""
-        self.metrics.drain()
-        if self._profile_cm is not None:
-            self._profile_cm.__exit__(None, None, None)
-            self._profile_cm = None
+        self._stop_instruments()
         self.journal.close()
 
     def set_epoch(self, t0):
@@ -222,19 +282,33 @@ class RunObserver:
     def elapsed(self):
         return time.time() - self._t0 if self._t0 is not None else 0.0
 
-    # -- metrics delegates ---------------------------------------------
-    def timer(self, phase):
-        return self.metrics.timer(phase)
+    # -- the one span primitive ----------------------------------------
+    def span(self, name, **attrs):
+        """Mark a host phase.  `name` is one of ``spans.ENGINE_SPANS``
+        (a KeyError otherwise: the vocabulary is fixed); numbers go in
+        `attrs`.  The phase is timed exclusively under its key of the
+        metrics document and, when the run is profiled, lies in the
+        profile as a TraceAnnotation of the same name."""
+        phase = spans.ENGINE_SPANS[name]
+        annotation = self._annotation
+        return _Span(self.metrics, phase,
+                     None if annotation is None
+                     else annotation(name, **attrs))
 
+    def _build_event(self, rec):
+        self.journal.write(
+            "build", fun_name=str(rec["fun_name"]),
+            trace_s=round(rec["trace_s"], 6),
+            lower_s=round(rec["lower_s"], 6),
+            backend_s=round(rec["backend_s"], 6), cache=rec["cache"],
+            elapsed_s=round(self.elapsed(), 3))
+
+    # -- metrics delegates ---------------------------------------------
     def count(self, name, n=1):
         self.metrics.count(name, n)
 
     def gauge(self, name, value):
         self.metrics.gauge(name, value)
-
-    # -- profiler delegates --------------------------------------------
-    def annotate(self, name):
-        return _annotate(name)
 
     # -- events --------------------------------------------------------
     def level_done(self, depth, *, frontier, distinct, generated,
@@ -437,10 +511,10 @@ class RunObserver:
         ``elapsed`` / ``states_per_sec`` / ``levels`` / ``metrics`` on
         the result object, journals violation + run_end, dumps the
         ``-metrics`` file, renders the stderr stats table."""
-        self.metrics.drain()          # close "check" + any open frames
-        if self._profile_cm is not None:
-            self._profile_cm.__exit__(None, None, None)
-            self._profile_cm = None
+        attached = self._builds_attached
+        self._stop_instruments()      # close "check" + any open frames
+        if attached:
+            self.builds.stamp(self.metrics)
         elapsed = self.elapsed() if self._t0 is not None \
             else getattr(res, "elapsed", 0.0) or 0.0
         res.elapsed = elapsed

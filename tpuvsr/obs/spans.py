@@ -1,0 +1,73 @@
+"""The fixed vocabulary of names the program writes into a profile.
+
+Two kinds, both documented in ``SCHEMA.md``:
+
+* **host spans** — one per host phase.  ``RunObserver.span(name,
+  **attrs)`` times the phase (``ENGINE_SPANS[name]`` is its key in the
+  metrics document's ``phases``) and, when profiling is on, opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, so the phase lies
+  on the device trace's clock.  Numbers (``depth=``, ``tiles=``) are
+  attributes, never part of the name.  The service's spans carry no
+  phase: the job journal already times a job.
+* **level-program stages** — ``jax.named_scope`` names around the
+  stages of the level program and the sharded step.  Metadata only:
+  they reach the trace on every device operation of the stage and
+  change no operation, shape or program.
+
+This module imports nothing: the service front imports ``tpuvsr.obs``
+without JAX.
+"""
+
+CHECK = "tpuvsr.engine.check"
+BUILD = "tpuvsr.engine.build"
+DISPATCH = "tpuvsr.engine.dispatch"
+INFLIGHT = "tpuvsr.engine.inflight"
+HOST_SYNC = "tpuvsr.engine.host_sync"
+CHECKPOINT = "tpuvsr.engine.checkpoint"
+INIT = "tpuvsr.engine.init"
+GRAPH_BUILD = "tpuvsr.engine.graph_build"
+
+#: host span -> phase key of the ``tpuvsr-metrics/1`` document
+ENGINE_SPANS = {
+    CHECK: "check",             # the run's catch-all frame
+    BUILD: "compile",           # first call of a fresh jit
+    DISPATCH: "dispatch",       # enqueue of a built program
+    INFLIGHT: "inflight",       # blocked wait on the oldest ticket
+    HOST_SYNC: "host_sync",     # scalar pulls, lvl_buf reads
+    CHECKPOINT: "checkpoint",   # level-boundary snapshot write
+    INIT: "init",               # Init registration + buffer allocation
+    GRAPH_BUILD: "graph_build",  # liveness: behavior-graph construction
+}
+
+JOB = "tpuvsr.service.job"
+LOAD_SPEC = "tpuvsr.service.load_spec"
+BUILD_ENGINE = "tpuvsr.service.build_engine"
+RUN = "tpuvsr.service.run"
+SETTLE = "tpuvsr.service.settle"
+
+#: the service worker's spans (profile only; no engine phase)
+SERVICE_SPANS = (JOB, LOAD_SPEC, BUILD_ENGINE, RUN, SETTLE)
+
+GUARD_MATRIX = "tpuvsr.level.guard_matrix"
+COMPACT = "tpuvsr.level.compact"
+EXPAND = "tpuvsr.level.expand"
+CANON = "tpuvsr.level.canon"
+FINGERPRINT = "tpuvsr.level.fingerprint"
+FPSET_INSERT = "tpuvsr.level.fpset_insert"
+PACK_SCATTER = "tpuvsr.level.pack_scatter"
+INVARIANTS = "tpuvsr.level.invariants"
+EDGE_EMIT = "tpuvsr.level.edge_emit"
+SHARD_BUCKET = "tpuvsr.shard.bucket"
+SHARD_ALL_TO_ALL = "tpuvsr.shard.all_to_all"
+
+#: ``jax.named_scope`` names of the level program's stages
+LEVEL_STAGES = (GUARD_MATRIX, COMPACT, EXPAND, CANON, FINGERPRINT,
+                FPSET_INSERT, PACK_SCATTER, INVARIANTS, EDGE_EMIT,
+                SHARD_BUCKET, SHARD_ALL_TO_ALL)
+
+
+def build_phase(fresh):
+    """The span of a jitted call: ``BUILD`` for the first call of a
+    fresh jit (it traces, lowers and compiles or loads synchronously),
+    ``DISPATCH`` for the enqueue of a built program."""
+    return BUILD if fresh else DISPATCH

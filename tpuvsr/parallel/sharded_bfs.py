@@ -50,7 +50,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.device_bfs import grown_caps, static_cap
 from ..engine.fpset import dedup_batch, insert_core
-from ..obs import closes_observer
+from ..obs import closes_observer, spans
 from ..resilience.faults import InjectedExchangeDrop, fault_point
 from ..resilience.supervisor import Preempted, preempt_signal
 from .multihost import make_replicator, put_sharded
@@ -326,57 +326,59 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
             base = t * T
             sidx = base + jnp.arange(T, dtype=jnp.int32)
             valid = sidx < n_loc
-            if pack_spec is not None:
-                tile_st = jax.vmap(pack_spec.unpack)(
-                    frontier[jnp.clip(sidx, 0, frontier.shape[0] - 1)])
-            else:
-                tile_st = {k: v[jnp.clip(sidx, 0, v.shape[0] - 1)]
-                           for k, v in frontier.items()}
+            with jax.named_scope(spans.PACK_SCATTER):
+                if pack_spec is not None:
+                    tile_st = jax.vmap(pack_spec.unpack)(
+                        frontier[jnp.clip(sidx, 0, frontier.shape[0] - 1)])
+                else:
+                    tile_st = {k: v[jnp.clip(sidx, 0, v.shape[0] - 1)]
+                               for k, v in frontier.items()}
             if fused:
                 # -- stage 1 (ISSUE 10): guard matrix, exact counts --
-                en_segs = []
-                for name, guard in zip(kern.action_names, guards):
-                    lanes = jnp.arange(kern._lane_count(name),
-                                       dtype=jnp.int32)
-                    seg = jax.vmap(lambda st: jax.vmap(
-                        lambda ln, g=guard: g(st, ln))(lanes))(tile_st)
-                    en_segs.append(seg & valid[:, None])
-                # deadlock witness from the UNMASKED matrix (POR must
-                # not manufacture deadlocks), before any ample masking
-                en_state = jnp.zeros((T,), bool)
-                for e in en_segs:
-                    en_state = en_state | e.any(axis=1)
-                if por_amat is not None:
-                    # ample-set stage-1 masking (ISSUE 16): rows with
-                    # a conflict-free candidate keep ONLY that
-                    # action's lanes; everything downstream (counts,
-                    # caps, compaction, exchange) sees the reduced
-                    # queue.  aid_star = lowest candidate id — a
-                    # deterministic pick keeps runs reproducible
-                    en_act_m = jnp.stack(
-                        [e.any(axis=1) for e in en_segs], axis=1)
-                    n_full = jnp.stack(
-                        [e.sum(dtype=jnp.int32)
-                         for e in en_segs]).sum()
-                    conflict = (en_act_m.astype(jnp.int32)
-                                @ (~por_amat).astype(jnp.int32).T) > 0
-                    cand_m = en_act_m & ~conflict
-                    has_cand = cand_m.any(axis=1)
-                    aid_star = jnp.argmax(cand_m, axis=1
-                                          ).astype(jnp.int32)
-                    en_segs = [e & (~has_cand
-                                    | (aid_star == a))[:, None]
-                               for a, e in enumerate(en_segs)]
-                    amp_t = (has_cand
-                             & (en_act_m.sum(axis=1, dtype=jnp.int32)
-                                > 1)).sum(dtype=jnp.int32)
-                cnts = jnp.stack([e.sum(dtype=jnp.int32)
-                                  for e in en_segs])
-                n_en = cnts.sum()
-                act_seg = cnts.astype(U32)
-                ovf_vec = cnts > caps_v
-                ovf_e = ovf_vec.any()
-                need = jnp.maximum(c["need"], cnts.astype(U32))
+                with jax.named_scope(spans.GUARD_MATRIX):
+                    en_segs = []
+                    for name, guard in zip(kern.action_names, guards):
+                        lanes = jnp.arange(kern._lane_count(name),
+                                           dtype=jnp.int32)
+                        seg = jax.vmap(lambda st: jax.vmap(
+                            lambda ln, g=guard: g(st, ln))(lanes))(tile_st)
+                        en_segs.append(seg & valid[:, None])
+                    # deadlock witness from the UNMASKED matrix (POR must
+                    # not manufacture deadlocks), before any ample masking
+                    en_state = jnp.zeros((T,), bool)
+                    for e in en_segs:
+                        en_state = en_state | e.any(axis=1)
+                    if por_amat is not None:
+                        # ample-set stage-1 masking (ISSUE 16): rows with
+                        # a conflict-free candidate keep ONLY that
+                        # action's lanes; everything downstream (counts,
+                        # caps, compaction, exchange) sees the reduced
+                        # queue.  aid_star = lowest candidate id — a
+                        # deterministic pick keeps runs reproducible
+                        en_act_m = jnp.stack(
+                            [e.any(axis=1) for e in en_segs], axis=1)
+                        n_full = jnp.stack(
+                            [e.sum(dtype=jnp.int32)
+                             for e in en_segs]).sum()
+                        conflict = (en_act_m.astype(jnp.int32)
+                                    @ (~por_amat).astype(jnp.int32).T) > 0
+                        cand_m = en_act_m & ~conflict
+                        has_cand = cand_m.any(axis=1)
+                        aid_star = jnp.argmax(cand_m, axis=1
+                                              ).astype(jnp.int32)
+                        en_segs = [e & (~has_cand
+                                        | (aid_star == a))[:, None]
+                                   for a, e in enumerate(en_segs)]
+                        amp_t = (has_cand
+                                 & (en_act_m.sum(axis=1, dtype=jnp.int32)
+                                    > 1)).sum(dtype=jnp.int32)
+                    cnts = jnp.stack([e.sum(dtype=jnp.int32)
+                                      for e in en_segs])
+                    n_en = cnts.sum()
+                    act_seg = cnts.astype(U32)
+                    ovf_vec = cnts > caps_v
+                    ovf_e = ovf_vec.any()
+                    need = jnp.maximum(c["need"], cnts.astype(U32))
 
                 # -- stage 2: per-action work-queue compaction; only
                 # REAL items are expanded (step_all expanded all T x L
@@ -387,16 +389,19 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                     L_a = lane_counts[a]
                     TL_a = T * L_a
                     off = int(seg_off[a])
-                    en_fa = en_segs[a].reshape(TL_a)
-                    (sel,) = jnp.nonzero(en_fa, size=caps[a],
-                                         fill_value=TL_a)
-                    sel_ok = sel < TL_a
-                    pidx = jnp.clip(sel // L_a, 0, T - 1
-                                    ).astype(jnp.int32)
-                    lane_loc = (sel % L_a).astype(jnp.int32)
-                    st_sel = {k: v[pidx] for k, v in tile_st.items()}
-                    s_a, en2 = jax.vmap(fn, in_axes=(0, 0))(
-                        st_sel, lane_loc)
+                    with jax.named_scope(spans.COMPACT):
+                        en_fa = en_segs[a].reshape(TL_a)
+                        (sel,) = jnp.nonzero(en_fa, size=caps[a],
+                                             fill_value=TL_a)
+                        sel_ok = sel < TL_a
+                        pidx = jnp.clip(sel // L_a, 0, T - 1
+                                        ).astype(jnp.int32)
+                        lane_loc = (sel % L_a).astype(jnp.int32)
+                        st_sel = {k: v[pidx]
+                                  for k, v in tile_st.items()}
+                    with jax.named_scope(spans.EXPAND):
+                        s_a, en2 = jax.vmap(fn, in_axes=(0, 0))(
+                            st_sel, lane_loc)
                     succ_segs.append({k: v for k, v in s_a.items()
                                       if not k.startswith("_")})
                     en_q_segs.append(en2 & sel_ok)
@@ -406,12 +411,14 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                     # trace metadata derive from it, which is what
                     # keeps compacted results bit-identical
                     pos_segs.append(pidx * L + off + lane_loc)
-                flat = {k: jnp.concatenate([s[k] for s in succ_segs])
-                        for k in succ_segs[0]}
-                en_f = jnp.concatenate(en_q_segs)
-                flatpos = jnp.concatenate(pos_segs)
+                with jax.named_scope(spans.COMPACT):
+                    flat = {k: jnp.concatenate(
+                        [s[k] for s in succ_segs]) for k in succ_segs[0]}
+                    en_f = jnp.concatenate(en_q_segs)
+                    flatpos = jnp.concatenate(pos_segs)
             else:
-                succs, en = jax.vmap(kern.step_all)(tile_st)
+                with jax.named_scope(spans.EXPAND):
+                    succs, en = jax.vmap(kern.step_all)(tile_st)
                 en = en & valid[:, None]
                 en_state = en.any(axis=1)
                 flat = {k: v.reshape((T * L,) + v.shape[2:])
@@ -432,8 +439,10 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                 # buckets, the wire, and the next frontier all move
                 # the packed row from here on
                 flat_rows = jax.vmap(pack_spec.pack)(flat)
-            fps = jax.vmap(fpf)(flat)
-            iok = jax.vmap(inv_fn)(flat)
+            with jax.named_scope(spans.FINGERPRINT):
+                fps = jax.vmap(fpf)(flat)
+            with jax.named_scope(spans.INVARIANTS):
+                iok = jax.vmap(inv_fn)(flat)
             errv = jnp.where(en_f, flat["err"], 0)
             viol_l = en_f & ~iok & (errv == 0)
             bag_err = ((errv & ERR_BAG_OVERFLOW) != 0).any()
@@ -462,42 +471,43 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
             # would
             perm, cand = dedup_batch(fps, en_f,
                                      tie=flatpos if fused else None)
-            fps_s = fps[perm]
-            owner = (route(fps_s) % jnp.uint32(n_dev)).astype(jnp.int32)
-            pos_s = flatpos[perm]
-            meta_p = base_gid[0] + (pos_s // L).astype(jnp.int32) + base
-            meta_a = lane_aid[pos_s % L]
-            meta_m = lane_prm[pos_s % L]
+            with jax.named_scope(spans.SHARD_BUCKET):
+                fps_s = fps[perm]
+                owner = (route(fps_s) % jnp.uint32(n_dev)).astype(jnp.int32)
+                pos_s = flatpos[perm]
+                meta_p = base_gid[0] + (pos_s // L).astype(jnp.int32) + base
+                meta_a = lane_aid[pos_s % L]
+                meta_m = lane_prm[pos_s % L]
 
-            cap = bucket_cap
-            b_fps = jnp.zeros((n_dev, cap, 4), U32)
-            b_mask = jnp.zeros((n_dev, cap), bool)
-            b_p = jnp.zeros((n_dev, cap), jnp.int32)
-            b_a = jnp.zeros((n_dev, cap), jnp.int32)
-            b_m = jnp.zeros((n_dev, cap), jnp.int32)
-            if pack_spec is not None:
-                b_st = {"rows": jnp.zeros(
-                    (n_dev, cap, pack_spec.words), U32)}
-                flat_src = {"rows": flat_rows}
-            else:
-                b_st = {k: jnp.zeros((n_dev, cap) + v.shape[1:],
-                                     v.dtype)
-                        for k, v in flat.items()}
-                flat_src = flat
-            ovf_b = jnp.asarray(False)
-            for d in range(n_dev):
-                m = cand & (owner == d)
-                pos = jnp.cumsum(m) - 1
-                ovf_b = ovf_b | ((pos[-1] + 1 > cap) & m.any())
-                idx = jnp.where(m & (pos < cap), pos, cap)
-                b_fps = b_fps.at[d, idx].set(fps_s, mode="drop")
-                b_mask = b_mask.at[d, idx].set(m, mode="drop")
-                b_p = b_p.at[d, idx].set(meta_p, mode="drop")
-                b_a = b_a.at[d, idx].set(meta_a, mode="drop")
-                b_m = b_m.at[d, idx].set(meta_m, mode="drop")
-                for k in b_st:
-                    b_st[k] = b_st[k].at[d, idx].set(
-                        flat_src[k][perm], mode="drop")
+                cap = bucket_cap
+                b_fps = jnp.zeros((n_dev, cap, 4), U32)
+                b_mask = jnp.zeros((n_dev, cap), bool)
+                b_p = jnp.zeros((n_dev, cap), jnp.int32)
+                b_a = jnp.zeros((n_dev, cap), jnp.int32)
+                b_m = jnp.zeros((n_dev, cap), jnp.int32)
+                if pack_spec is not None:
+                    b_st = {"rows": jnp.zeros(
+                        (n_dev, cap, pack_spec.words), U32)}
+                    flat_src = {"rows": flat_rows}
+                else:
+                    b_st = {k: jnp.zeros((n_dev, cap) + v.shape[1:],
+                                         v.dtype)
+                            for k, v in flat.items()}
+                    flat_src = flat
+                ovf_b = jnp.asarray(False)
+                for d in range(n_dev):
+                    m = cand & (owner == d)
+                    pos = jnp.cumsum(m) - 1
+                    ovf_b = ovf_b | ((pos[-1] + 1 > cap) & m.any())
+                    idx = jnp.where(m & (pos < cap), pos, cap)
+                    b_fps = b_fps.at[d, idx].set(fps_s, mode="drop")
+                    b_mask = b_mask.at[d, idx].set(m, mode="drop")
+                    b_p = b_p.at[d, idx].set(meta_p, mode="drop")
+                    b_a = b_a.at[d, idx].set(meta_a, mode="drop")
+                    b_m = b_m.at[d, idx].set(meta_m, mode="drop")
+                    for k in b_st:
+                        b_st[k] = b_st[k].at[d, idx].set(
+                            flat_src[k][perm], mode="drop")
 
             # deadlock: a valid frontier state with no enabled lane
             # (en_state comes from the guard matrix in fused commit,
@@ -518,16 +528,17 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
             abort_pre = gflags.any()
 
             # ONE exchange moves fingerprints + states + trace meta
-            a2a = lambda x: jax.lax.all_to_all(x, axis, 0, 0, tiled=False)
-            i_fps = a2a(b_fps).reshape(n_dev * cap, 4)
-            i_mask = a2a(b_mask).reshape(n_dev * cap)
-            i_p = a2a(b_p).reshape(n_dev * cap)
-            i_a = a2a(b_a).reshape(n_dev * cap)
-            i_m = a2a(b_m).reshape(n_dev * cap)
-            i_st = {k: a2a(v).reshape((n_dev * cap,) + v.shape[2:])
-                    for k, v in b_st.items()}
-            if pack_spec is not None:
-                i_st = i_st["rows"]     # [D*cap, words] packed rows
+            with jax.named_scope(spans.SHARD_ALL_TO_ALL):
+                a2a = lambda x: jax.lax.all_to_all(x, axis, 0, 0, tiled=False)
+                i_fps = a2a(b_fps).reshape(n_dev * cap, 4)
+                i_mask = a2a(b_mask).reshape(n_dev * cap)
+                i_p = a2a(b_p).reshape(n_dev * cap)
+                i_a = a2a(b_a).reshape(n_dev * cap)
+                i_m = a2a(b_m).reshape(n_dev * cap)
+                i_st = {k: a2a(v).reshape((n_dev * cap,) + v.shape[2:])
+                        for k, v in b_st.items()}
+                if pack_spec is not None:
+                    i_st = i_st["rows"]     # [D*cap, words] packed rows
 
             # receiver-side capacity vote (cross-sender dedup can only
             # shrink the count, so this bound is safe)
@@ -549,18 +560,19 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
             new_tab, fresh, probe_ovf = insert_core(
                 {"slots": slots}, i_fps[perm2], cand2 & commit)
             slots2 = new_tab["slots"]
-            dest = jnp.where(fresh, nn + jnp.cumsum(fresh) - 1, N
-                             ).astype(jnp.int32)
-            src = perm2
-            if pack_spec is not None:
-                nb = nb.at[dest].set(i_st[src], mode="drop")
-            else:
-                for k in nb:
-                    nb[k] = nb[k].at[dest].set(i_st[k][src],
-                                               mode="drop")
-            nbp = nbp.at[dest].set(i_p[src], mode="drop")
-            nba = nba.at[dest].set(i_a[src], mode="drop")
-            nbprm = nbprm.at[dest].set(i_m[src], mode="drop")
+            with jax.named_scope(spans.PACK_SCATTER):
+                dest = jnp.where(fresh, nn + jnp.cumsum(fresh) - 1, N
+                                 ).astype(jnp.int32)
+                src = perm2
+                if pack_spec is not None:
+                    nb = nb.at[dest].set(i_st[src], mode="drop")
+                else:
+                    for k in nb:
+                        nb[k] = nb[k].at[dest].set(i_st[k][src],
+                                                   mode="drop")
+                nbp = nbp.at[dest].set(i_p[src], mode="drop")
+                nba = nba.at[dest].set(i_a[src], mode="drop")
+                nbprm = nbprm.at[dest].set(i_m[src], mode="drop")
             n_fresh = fresh.sum()
 
             # committed-but-unresolved probes pause the level for table
@@ -958,7 +970,8 @@ class ShardedBFS:
         if check_deadlock is not None and bool(check_deadlock) != self._ckd:
             self._ckd = bool(check_deadlock)
             self._build(self.codec.shape.MAX_MSGS)
-        sharded_ins = make_sharded_insert(self.mesh, self.axis)
+        with obs.span(spans.INIT):
+            sharded_ins = make_sharded_insert(self.mesh, self.axis)
 
         # exchange metrics: useful rows shipped vs static wire volume
         # (all_to_all always moves full D x bucket_cap buckets).  Bytes
@@ -1124,52 +1137,53 @@ class ShardedBFS:
             emit(f"resumed from {resume_from}: depth {depth0}, "
                  f"{fp_count} distinct, frontier {int(counts0.sum())}")
         else:
-            tables = make_sharded_tables(self.mesh, self.axis,
-                                         self.fp_cap)
+            with obs.span(spans.INIT):
+                tables = make_sharded_tables(self.mesh, self.axis,
+                                             self.fp_cap)
 
-            # --- init states: dedup, assign to owner devices ----------
-            init_states = list(spec.init_states())
-            dense = [codec.encode(st) for st in init_states]
-            batch = {k: np.stack([d[k] for d in dense]) for k in dense[0]}
-            fps = np.asarray(self._fp_batch(batch))
-            keep, seen = [], set()
-            for i in range(len(dense)):
-                t = tuple(fps[i])
-                if t not in seen:
-                    seen.add(t)
-                    keep.append(i)
-            owners = (np.asarray(route(jnp.asarray(fps[keep])))
-                      % np.uint32(D)).astype(int)
-            order = np.argsort(owners, kind="stable")
-            keep = [keep[i] for i in order]
-            owners = owners[order]
-            self._init_states = [init_states[i] for i in keep]
-            n0 = len(keep)
-            counts0 = np.bincount(owners, minlength=D)
+                # --- init states: dedup, assign to owner devices ----------
+                init_states = list(spec.init_states())
+                dense = [codec.encode(st) for st in init_states]
+                batch = {k: np.stack([d[k] for d in dense]) for k in dense[0]}
+                fps = np.asarray(self._fp_batch(batch))
+                keep, seen = [], set()
+                for i in range(len(dense)):
+                    t = tuple(fps[i])
+                    if t not in seen:
+                        seen.add(t)
+                        keep.append(i)
+                owners = (np.asarray(route(jnp.asarray(fps[keep])))
+                          % np.uint32(D)).astype(int)
+                order = np.argsort(owners, kind="stable")
+                keep = [keep[i] for i in order]
+                owners = owners[order]
+                self._init_states = [init_states[i] for i in keep]
+                n0 = len(keep)
+                counts0 = np.bincount(owners, minlength=D)
 
-            F = self.N
-            self._dev_distinct = counts0.astype(np.int64).copy()
-            # build the initial frontier host-side (zeros + init rows)
-            # and scatter once: pulling a freshly-allocated GLOBAL
-            # array is illegal in multi-process mode
-            zero = self.codec.zero_state()
-            host_front = {k: np.zeros((D * F,) + np.shape(v), np.int32)
-                          for k, v in zero.items()}
-            pos = 0
-            for d in range(D):
-                for j in range(int(counts0[d])):
-                    row = dense[keep[pos]]
-                    for k in host_front:
-                        host_front[k][d * F + j] = row[k]
-                    pos += 1
-            front = (self._put(self._pk.pack_np(host_front))
-                     if self._pk is not None else
-                     {k: self._put(v) for k, v in host_front.items()})
-            n_front = self._put(counts0.astype(np.int32))
-            tables, _fr, ovf = sharded_ins(
-                tables, self._rep(fps[keep]),
-                self._rep(np.ones((n0,), bool)))
-            assert not bool(self._pull(ovf).any())
+                F = self.N
+                self._dev_distinct = counts0.astype(np.int64).copy()
+                # build the initial frontier host-side (zeros + init rows)
+                # and scatter once: pulling a freshly-allocated GLOBAL
+                # array is illegal in multi-process mode
+                zero = self.codec.zero_state()
+                host_front = {k: np.zeros((D * F,) + np.shape(v), np.int32)
+                              for k, v in zero.items()}
+                pos = 0
+                for d in range(D):
+                    for j in range(int(counts0[d])):
+                        row = dense[keep[pos]]
+                        for k in host_front:
+                            host_front[k][d * F + j] = row[k]
+                        pos += 1
+                front = (self._put(self._pk.pack_np(host_front))
+                         if self._pk is not None else
+                         {k: self._put(v) for k, v in host_front.items()})
+                n_front = self._put(counts0.astype(np.int32))
+                tables, _fr, ovf = sharded_ins(
+                    tables, self._rep(fps[keep]),
+                    self._rep(np.ones((n0,), bool)))
+                assert not bool(self._pull(ovf).any())
             fp_count = n0
 
             self._h_parent = [np.full(n0, -1, np.int64)]
@@ -1270,7 +1284,7 @@ class ShardedBFS:
         xretry = 0      # consecutive exchange-drop retries (bounded)
 
         while True:
-            with obs.timer("host_sync"):
+            with obs.span(spans.HOST_SYNC):
                 front_total = int(self._pull(n_front).sum())
             if front_total <= 0:
                 break
@@ -1326,7 +1340,7 @@ class ShardedBFS:
                         self._step, tables, front, n_front, start_t,
                         nb, nbp, nba, nbprm, nn, base_gid,
                         fresh=self._fresh_jit,
-                        label=f"level {depth} dispatch")
+                        depth=depth)
                     self._fresh_jit = False
                     (tables, nb, nbp, nba, nbprm, nn,
                      start_t) = out[:7]
@@ -1504,7 +1518,7 @@ class ShardedBFS:
 
             # committed tiles this level x full static bucket volume
             # (generated was already accumulated per dispatch attempt)
-            with obs.timer("host_sync"):
+            with obs.span(spans.HOST_SYNC):
                 tiles_lvl = int(self._pull(start_t).max())
                 wire = tiles_lvl * D * D * self.bucket_cap
                 exch_rows_wire += wire
@@ -1520,7 +1534,7 @@ class ShardedBFS:
                            distinct=fp_count,
                            generated=res.states_generated)
             if n_next:
-                with obs.timer("host_sync"):
+                with obs.span(spans.HOST_SYNC):
                     self._h_parent.append(
                         self._pull_rows(nbp, nn_h).astype(np.int64))
                     self._h_action.append(self._pull_rows(nba, nn_h))

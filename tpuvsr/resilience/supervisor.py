@@ -39,6 +39,7 @@ flag is never set and the checks are free.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import signal
 import time
@@ -46,7 +47,7 @@ from dataclasses import dataclass, field
 
 from ..exitcodes import (EX_OK, EX_RESUMABLE, EX_SOFTWARE, EX_VIOLATION,
                          job_state)
-from ..obs import Journal, RunObserver
+from ..obs import Journal, RunObserver, spans
 from .faults import InjectedFault, InjectedOOM
 
 #: exit code of a preempted-but-resumable supervised run (EX_TEMPFAIL:
@@ -206,7 +207,7 @@ class Supervisor:
                  engine_kwargs=None, engine_factory=None, fused=False,
                  chained=False, mesh_devices=None, min_devices=1,
                  sleep=time.sleep, observer_factory=None,
-                 on_event=None):
+                 on_event=None, span=None):
         if fused and chained:
             raise ValueError("fused and chained are mutually "
                              "exclusive supervision modes")
@@ -262,6 +263,10 @@ class Supervisor:
         # the journal file
         self._observer_factory = observer_factory or RunObserver
         self._on_event = on_event
+        # `span(name)` is the service worker's profile span (a context
+        # manager): engine construction happens here, outside any
+        # engine run, and is marked for the worker
+        self._span = span or (lambda name: contextlib.nullcontext())
         self.engine = None          # last engine instance (CLI liveness)
         self.attempts = 0           # engine runs started
         self.degrades = []          # [(what, from, to), ...]
@@ -336,7 +341,8 @@ class Supervisor:
             with PreemptionGuard(log=self._log):
                 while True:
                     self.attempts += 1
-                    self.engine = self._make_engine()
+                    with self._span(spans.BUILD_ENGINE):
+                        self.engine = self._make_engine()
                     obs = self._observer_factory(
                         journal_path=self.journal_path,
                         metrics_path=self.metrics_path,

@@ -39,6 +39,7 @@ table — one queue implementation.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import signal
 import subprocess
@@ -46,9 +47,10 @@ import threading
 import time
 
 from ..exitcodes import EX_RESUMABLE, job_state
-from ..obs import Journal, RunObserver
+from ..obs import Journal, RunObserver, spans
 from ..obs.journal import (new_span_id, root_span, trace_env,
                            trace_scope)
+from ..obs.profiler import annotation_factory
 from .scheduler import DevicePool, Scheduler, advise_backend
 
 # NOTE: the serving-tier pieces (fair-share policy, multi-runner) live
@@ -82,6 +84,9 @@ def result_summary(res):
     if res.trace:
         out["trace"] = trace_to_jsonable(res.trace)
     return out
+
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 class JobObserver(RunObserver):
@@ -177,6 +182,9 @@ class Worker:
         self._log = log
         self._specs = {}             # job_id -> loaded spec (admission)
         self._spans = {}             # job_id -> this attempt's span id
+        # TraceAnnotation while TPUVSR_PROFILE is set, asked once per
+        # job (run_one) and per admission sweep, never per span
+        self._annotation = None
         self._current = None
         self._preempt_sent = False
         self._cancelled = False
@@ -212,6 +220,14 @@ class Worker:
 
     def _release_hold(self, job_id):
         self._held.discard(job_id)
+
+    def _span(self, name, **attrs):
+        """One of ``spans.SERVICE_SPANS`` in the profile when one is
+        being taken, else a shared no-op.  No phase: the job journal
+        times a job."""
+        annotation = self._annotation
+        return _NO_SPAN if annotation is None else annotation(name,
+                                                              **attrs)
 
     def _trace_ctx(self, job):
         """This job's trace context for a service-side journal write:
@@ -254,6 +270,10 @@ class Worker:
 
     # -- admission (the speclint gate) ---------------------------------
     def _load_spec(self, job):
+        with self._span(spans.LOAD_SPEC, job_id=job.job_id):
+            return self._load_spec_inner(job)
+
+    def _load_spec_inner(self, job):
         if job.flags.get("stub"):
             from ..testing import bad_counter_spec, counter_spec
             if job.flags.get("stub_bad"):
@@ -272,6 +292,7 @@ class Worker:
         transition is a lost race against a concurrent worker (same as
         a lost claim): skip, never crash."""
         from .queue import FencedError, QueueError
+        self._annotation = annotation_factory()
         for job in [j for j in self.queue.jobs()
                     if j.state == "queued"]:
             try:
@@ -418,20 +439,25 @@ class Worker:
         # and the engine-run segments parent onto it via trace_scope
         if getattr(job, "trace_id", None):
             self._spans[job.job_id] = new_span_id()
+        self._annotation = annotation_factory()
         try:
-            if self._breaker_blocks(job):
-                return None
-            if job.kind == "shell":
-                return self._run_shell(job)
-            if job.kind == "sim":
-                return self._run_sim(job)
-            if job.kind == "validate":
+            # the job's spans share the journal's identifiers
+            with self._span(spans.JOB, job_id=job.job_id,
+                            trace_id=getattr(job, "trace_id", None)
+                            or ""):
+                if self._breaker_blocks(job):
+                    return None
+                if job.kind == "shell":
+                    return self._run_shell(job)
+                if job.kind == "sim":
+                    return self._run_sim(job)
+                if job.kind == "validate":
+                    if _is_light(job):
+                        return self._run_validate_interp(job)
+                    return self._run_validate(job)
                 if _is_light(job):
-                    return self._run_validate_interp(job)
-                return self._run_validate(job)
-            if _is_light(job):
-                return self._run_lint_only(job)
-            return self._run_check(job)
+                    return self._run_lint_only(job)
+                return self._run_check(job)
         finally:
             self._release_hold(job.job_id)
             self.pool.release(job.job_id)
@@ -623,9 +649,10 @@ class Worker:
             # level_done / fault / run_end of this attempt carries the
             # job's trace_id with a fresh per-segment span (ISSUE 17)
             with trace_scope(job.trace_id,
-                             parent_span=self._spans.get(job.job_id)):
+                             parent_span=self._spans.get(job.job_id)), \
+                    self._span(spans.RUN, job_id=job.job_id):
                 out = run_supervised(
-                    spec, engine=kind,
+                    spec, engine=kind, span=self._span,
                     checkpoint_path=self.queue.checkpoint_path(
                         job.job_id),
                     journal_path=self.queue.journal_path(job.job_id),
@@ -658,6 +685,10 @@ class Worker:
     def _settle(self, job, out, summarize):
         """Map a run :class:`Outcome` onto the queue — shared by the
         check and sim paths."""
+        with self._span(spans.SETTLE, job_id=job.job_id):
+            self._settle_inner(job, out, summarize)
+
+    def _settle_inner(self, job, out, summarize):
         if out.state == "preempted-requeued":
             if self._cancelled:
                 self._finish(job, "cancelled", reason="cancelled",

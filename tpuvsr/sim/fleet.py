@@ -638,7 +638,7 @@ class FleetSimulator:
                         self._chunk, key, cur[0], cur[1], cur[2],
                         cur[3], walk_ids, jnp.asarray(launched, I32),
                         depth_j, fresh=self._fresh_jit,
-                        label=f"sim chunk (step {launched})")
+                        step=launched)
                     self._fresh_jit = False
                     cur = (out[0], out[1], out[2], out[3])
                     launched += self.chunk

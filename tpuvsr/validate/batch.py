@@ -554,7 +554,7 @@ class BatchValidator:
                         cur[4], tlen, aid, m_sl, v_sl,
                         jnp.asarray(launched, I32),
                         fresh=self._fresh_jit,
-                        label=f"validate chunk (step {launched})")
+                        step=launched)
                     self._fresh_jit = False
                     cur = (out[0], out[1], out[2], out[3], out[4])
                     launched += self.chunk
