@@ -7,6 +7,9 @@ dedup) on small configs — the framework's analog of matching TLC's
 distinct-state counts (SURVEY.md §4.7).
 """
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -18,6 +21,8 @@ from tpuvsr.engine.fpset import dedup_batch, empty_table, insert_batch
 from tpuvsr.engine.spec import SpecModel
 from tpuvsr.frontend.cfg import parse_cfg_file
 from tpuvsr.frontend.parser import parse_module_file
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------
@@ -277,3 +282,80 @@ def test_checkpoint_resume_reaches_same_frontier(tmp_path):
     assert eng2.level_sizes[:len(sizes_at_kill)] == sizes_at_kill
     assert res2.distinct_states == res3.distinct_states
     assert res2.states_generated == res3.states_generated
+
+
+# ---------------------------------------------------------------------
+# trace-once stages of the level body (ISSUE 26), on the committed
+# native small check: no reference mount
+# ---------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def small_native():
+    from tpuvsr.engine.spec import load_spec
+    return load_spec("VSR", os.path.join(REPO, "examples",
+                                         "VSR_small.cfg"))
+
+
+def _trace_level(eng):
+    """Trace `eng`'s level program on shapes alone, under a build
+    meter of its own; returns (shared calls, shared traces)."""
+    import jax
+    import jax.numpy as jnp
+    from tpuvsr.obs import builds
+    bufs = eng._alloc_bufs(eng.next_cap)
+    i32 = jnp.zeros((), jnp.int32)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype),
+        ({"slots": empty_table(eng.fpset_capacity)["slots"]},
+         bufs[0], i32, i32, *bufs, i32, jnp.zeros((), bool)))
+    meter = builds.BuildMeter()
+    previous = builds.attach(meter)
+    try:
+        eng._level.trace(*args, None, None, i32)
+    finally:
+        builds.detach(previous)
+    return meter.shared_calls, meter.shared_traces
+
+
+@pytest.mark.parametrize("commit", ["fused", "per-action"])
+def test_level_trace_runs_each_shared_stage_once(small_native, commit):
+    """19 actions use the incremental fingerprint and the invariant
+    function: 38 uses, and the Python body of each runs once."""
+    eng = DeviceBFS(small_native, commit=commit)
+    assert len(eng.kern.action_names) == 19 and eng._fp_incremental
+    assert _trace_level(eng) == (38, 2)
+
+
+def test_grow_msgs_rebuilds_the_shared_stages(small_native):
+    """A grown message table is a new kernel: its stages are made
+    anew and traced anew, never carried over."""
+    eng = DeviceBFS(small_native, max_msgs=8)
+    assert _trace_level(eng) == (38, 2)
+    old_kern, old_fp, old_inv = eng.kern, eng._fp_stage, eng._inv_stage
+    assert old_fp.__wrapped__.__self__ is old_kern
+    eng._grow_msgs([])
+    assert eng.codec.shape.MAX_MSGS == 16 and eng.kern is not old_kern
+    assert eng._fp_stage is not old_fp and eng._inv_stage is not old_inv
+    assert eng._fp_stage.__wrapped__.__self__ is eng.kern
+    assert eng._inv_stage.__wrapped__ is eng._inv
+    assert _trace_level(eng) == (38, 2)
+
+
+@pytest.mark.parametrize("hash_mode", ["incremental", "full"])
+@pytest.mark.parametrize("commit", ["fused", "per-action"])
+def test_small_check_exact_counts(small_native, commit, hash_mode):
+    """The small check to its fixpoint under both tile bodies and both
+    hash modes: the pinned level sizes, 43,941 distinct, diameter 24.
+    The capacities are sized so that no buffer grows: every growth is
+    one more build of the level program, most of this test's time."""
+    with open(os.path.join(REPO, "scripts",
+                           "pinned_levels_small.json")) as f:
+        pin = json.load(f)["level_sizes"]
+    eng = DeviceBFS(small_native, commit=commit, hash_mode=hash_mode,
+                    next_capacity=1 << 16, expand_mult=4)
+    res = eng.run()
+    assert res.ok and res.error is None
+    assert list(eng.level_sizes) == pin
+    assert (res.distinct_states, res.diameter) == (43941, 24)
+    c = res.metrics["counters"]
+    assert c["build_shared_calls"] % 38 == 0
+    assert c["build_shared_traces"] == 2

@@ -73,6 +73,7 @@ the next tier (SURVEY.md §5 distributed backend, parallel/sharded_bfs).
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 
@@ -84,7 +85,7 @@ import jax.numpy as jnp
 from ..core.values import TLAError
 from ..models import registry
 from ..models.vsr import ERR_BAG_OVERFLOW
-from ..obs import RunObserver, closes_observer, spans
+from ..obs import RunObserver, builds, closes_observer, spans
 from ..resilience.faults import fault_point
 from ..resilience.supervisor import Preempted, preempt_signal
 from .bfs import CheckResult
@@ -94,6 +95,27 @@ from .spec import SpecModel
 from .trace import TraceEntry
 
 I32 = jnp.int32
+
+
+def trace_once(fn, name):
+    """`fn` as a stage that a program's trace runs once: ``jax.jit`` of
+    it, so a second use on tracers of equal types reuses the jaxpr of
+    the first (and its batched form, and one function of the lowered
+    module) instead of running the Python body again.  The level body
+    uses such a stage once per action.  Made once per built kernel,
+    which `fn` closes over, and never kept past it."""
+    def body(*args):
+        builds.shared_stage(traced=True)
+        return fn(*args)
+    body.__name__ = name
+    jitted = jax.jit(body, inline=True)
+
+    @functools.wraps(fn)
+    def stage(*args):
+        builds.shared_stage(traced=False)
+        return jitted(*args)
+    return stage
+
 
 # level-kernel stop reasons
 RUNNING = 0
@@ -417,6 +439,20 @@ class DeviceBFS:
         self._por_active = (self._por is not None
                             and self._por.any_eligible
                             and self.commit == "fused")
+        # the per-successor stages that no action changes, traced once
+        # for THIS kernel (trace_once): the level body uses each once
+        # per action.  Canon runs hash the orbit-least image, which
+        # cannot be reconstituted from the parent's per-row hash parts:
+        # they force the full hash path (the orbit-factor state cut
+        # dwarfs the incremental saving)
+        self._fp_incremental = (self.hash_mode == "incremental"
+                                and self._canon is None)
+        self._fp_stage = trace_once(
+            self.kern.fingerprint_incremental if self._fp_incremental
+            else self._canon.fingerprint_fn(self.kern)
+            if self._canon is not None else self.kern.fingerprint,
+            "fingerprint")
+        self._inv_stage = trace_once(self._inv, "invariants")
         self._level = jax.jit(self._make_level(),
                               donate_argnums=(0, 4, 5, 6, 7, 10))
         self._ml = None         # fused pass, built lazily (run_fused)
@@ -459,6 +495,41 @@ class DeviceBFS:
 
         return mat
 
+    def _successor_fn(self, name, fn):
+        """Stage 2 of both tile bodies for one enabled (state, lane)
+        item of action `name`: expand it with `fn`, fingerprint the
+        successor, check the invariants — each under its stage scope.
+        Returns ``one(st, parts, lane) -> (successor, fingerprint,
+        enabled, invariants ok, err)`` for the body to vmap over its
+        compacted lanes; `parts` is the parent's hash parts
+        (``kern.parent_parts``) under the incremental hash and None
+        under the full one."""
+        kern = self.kern
+        fp_stage, inv_stage = self._fp_stage, self._inv_stage
+        incremental = self._fp_incremental
+
+        def one(st, parts, lane):
+            with jax.named_scope(spans.EXPAND):
+                succ, en = fn(kern.seed_touch(st) if incremental else st,
+                              lane)
+            clean = {k: v for k, v in succ.items()
+                     if not k.startswith("_")}
+            # ISSUE 11 commit stage: under canon the fingerprint is
+            # taken on the canonical orbit image while the staged queue
+            # keeps the generated state — orbit-mates dedup to one
+            # committed representative
+            with jax.named_scope(spans.FINGERPRINT):
+                if incremental:
+                    fp = fp_stage(succ, kern.lane_replica(name, st, lane),
+                                  parts, st)
+                else:
+                    fp = fp_stage(clean)
+            with jax.named_scope(spans.INVARIANTS):
+                iok = inv_stage(clean)
+            return clean, fp, en, iok, clean["err"]
+
+        return one
+
     def _tile_body_factory(self):
         """Build the one-tile expansion body shared by the chunked
         level pass (_make_level) and the fused multi-level pass
@@ -479,18 +550,9 @@ class DeviceBFS:
         if self.commit == "fused":
             return self._fused_body_factory()
         kern = self.kern
-        inv = self._inv
         pk = self._pk
         T = self.tile
-        # symmetry canonicalization (ISSUE 11): fingerprints are taken
-        # on the orbit-least image, which cannot be reconstituted from
-        # the parent's per-row hash parts — canon runs force the full
-        # hash path (the orbit-factor state cut dwarfs the incremental
-        # saving)
-        canon = self._canon
-        incremental = self.hash_mode == "incremental" and canon is None
-        fpf = (canon.fingerprint_fn(kern) if canon is not None
-               else kern.fingerprint)
+        incremental = self._fp_incremental
 
         # per-action compaction capacities (adaptive; R_EXPAND_GROW
         # carries the overflowing action so only it grows)
@@ -595,38 +657,14 @@ class DeviceBFS:
                         lane_sel = (sel % L_a).astype(I32)
                         st_sel = {k: v[pidx] for k, v in tile.items()}
 
+                    parts_sel = None
                     if incremental:
                         with jax.named_scope(spans.COMPACT):
                             parts_sel = jax.tree_util.tree_map(
                                 lambda v: v[pidx], parts)
-
-                        def one(st, parts_one, lane, fn=fn, name=name):
-                            with jax.named_scope(spans.EXPAND):
-                                succ, en1 = fn(kern.seed_touch(st), lane)
-                            with jax.named_scope(spans.FINGERPRINT):
-                                ri = kern.lane_replica(name, st, lane)
-                                fp = kern.fingerprint_incremental(
-                                    succ, ri, parts_one, st)
-                            clean = {k: v for k, v in succ.items()
-                                     if not k.startswith("_")}
-                            with jax.named_scope(spans.INVARIANTS):
-                                iok1 = inv(clean)
-                            return clean, fp, en1, iok1, clean["err"]
-                        succ_f, fp, en2, iok, errv = jax.vmap(one)(
+                    succ_f, fp, en2, iok, errv = jax.vmap(
+                        self._successor_fn(name, fn))(
                             st_sel, parts_sel, lane_sel)
-                    else:
-                        def one(st, lane, fn=fn):
-                            with jax.named_scope(spans.EXPAND):
-                                succ, en1 = fn(st, lane)
-                            clean = {k: v for k, v in succ.items()
-                                     if not k.startswith("_")}
-                            with jax.named_scope(spans.FINGERPRINT):
-                                fp1 = fpf(clean)
-                            with jax.named_scope(spans.INVARIANTS):
-                                iok1 = inv(clean)
-                            return clean, fp1, en1, iok1, clean["err"]
-                        succ_f, fp, en2, iok, errv = jax.vmap(one)(
-                            st_sel, lane_sel)
 
                     with jax.named_scope(spans.INVARIANTS):
                         en_s = en2 & sel_ok
@@ -781,15 +819,9 @@ class DeviceBFS:
             rule on a failing tile are preserved verbatim, so results
             are bit-identical to commit="per-action"."""
         kern = self.kern
-        inv = self._inv
         pk = self._pk
         T = self.tile
-        # canon runs hash the orbit-least image — full hash path only
-        # (see _tile_body_factory)
-        canon = self._canon
-        incremental = self.hash_mode == "incremental" and canon is None
-        fpf = (canon.fingerprint_fn(kern) if canon is not None
-               else kern.fingerprint)
+        incremental = self._fp_incremental
         n_act = len(kern.action_names)
         caps = self._expand_caps()
         total_E = sum(caps)
@@ -918,43 +950,14 @@ class DeviceBFS:
                         lane_sel = (sel % L_a).astype(I32)
                         st_sel = {k: v[pidx] for k, v in tile.items()}
 
+                    parts_sel = None
                     if incremental:
                         with jax.named_scope(spans.COMPACT):
                             parts_sel = jax.tree_util.tree_map(
                                 lambda v: v[pidx], parts)
-
-                        def one(st, parts_one, lane, fn=fn, name=name):
-                            with jax.named_scope(spans.EXPAND):
-                                succ, en1 = fn(kern.seed_touch(st), lane)
-                            with jax.named_scope(spans.FINGERPRINT):
-                                ri = kern.lane_replica(name, st, lane)
-                                fp = kern.fingerprint_incremental(
-                                    succ, ri, parts_one, st)
-                            clean = {k: v for k, v in succ.items()
-                                     if not k.startswith("_")}
-                            with jax.named_scope(spans.INVARIANTS):
-                                iok1 = inv(clean)
-                            return clean, fp, en1, iok1, clean["err"]
-                        succ_f, fp, en2, iok, errv = jax.vmap(one)(
+                    succ_f, fp, en2, iok, errv = jax.vmap(
+                        self._successor_fn(name, fn))(
                             st_sel, parts_sel, lane_sel)
-                    else:
-                        def one(st, lane, fn=fn):
-                            with jax.named_scope(spans.EXPAND):
-                                succ, en1 = fn(st, lane)
-                            clean = {k: v for k, v in succ.items()
-                                     if not k.startswith("_")}
-                            # ISSUE 11 commit stage: the fingerprint
-                            # is taken on the canonical orbit image
-                            # (fpf) while the staged queue keeps the
-                            # generated state — orbit-mates dedup to
-                            # one committed representative
-                            with jax.named_scope(spans.FINGERPRINT):
-                                fp1 = fpf(clean)
-                            with jax.named_scope(spans.INVARIANTS):
-                                iok1 = inv(clean)
-                            return clean, fp1, en1, iok1, clean["err"]
-                        succ_f, fp, en2, iok, errv = jax.vmap(one)(
-                            st_sel, lane_sel)
 
                     with jax.named_scope(spans.INVARIANTS):
                         en_s = en2 & sel_ok

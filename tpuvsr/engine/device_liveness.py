@@ -244,8 +244,10 @@ class DeviceGraph:
         recording instead of FPSet insertion)."""
         kern = self.eng.kern
         T = self.eng.tile
-        incremental = (self.eng.hash_mode == "incremental"
-                       and hasattr(kern, "parent_parts"))
+        # the engine's trace-once fingerprint stage (liveness runs
+        # with symmetry off, so it hashes the state as generated)
+        fp_stage = self.eng._fp_stage
+        incremental = self.eng._fp_incremental
         caps = [min(T * kern._lane_count(nm),
                     max(64, T * self.eng.expand_mults[a]))
                 for a, nm in enumerate(kern.action_names)]
@@ -281,8 +283,7 @@ class DeviceGraph:
                     def one(st, parts_one, lane, fn=fn, name=name):
                         succ, en1 = fn(kern.seed_touch(st), lane)
                         ri = kern.lane_replica(name, st, lane)
-                        fp = kern.fingerprint_incremental(
-                            succ, ri, parts_one, st)
+                        fp = fp_stage(succ, ri, parts_one, st)
                         return fp, en1, succ["err"]
                     fp, en1, errv = jax.vmap(one)(st_sel, parts_sel,
                                                   lane_sel)
@@ -291,8 +292,7 @@ class DeviceGraph:
                         succ, en1 = fn(st, lane)
                         clean = {k: v for k, v in succ.items()
                                  if not k.startswith("_")}
-                        return (kern.fingerprint(clean), en1,
-                                clean["err"])
+                        return fp_stage(clean), en1, clean["err"]
                     fp, en1, errv = jax.vmap(one)(st_sel, lane_sel)
                 ok = en1 & sel_ok
                 err_any = err_any | jnp.where(

@@ -18,6 +18,11 @@ back), ``.../cache_misses`` (compiled and written) and
 ``.../cache_retrieval_time_sec`` say which.  A jit called while another
 is traced reports its own trace from inside the outer one's: the meter
 counts the union, so ``build_trace_s`` never counts a second twice.
+
+A stage that a program's trace runs many times with equal argument
+types (the engines' trace-once stages, ``engine/device_bfs.py``) says
+so here: ``shared_stage`` counts every use and, apart, the uses whose
+Python body really ran.
 """
 
 from __future__ import annotations
@@ -49,6 +54,7 @@ class BuildMeter:
         self.trace_s = self.lower_s = self.backend_s = 0.0
         self.cache_load_s = 0.0
         self.programs = self.cache_hits = self.cache_misses = 0
+        self.shared_calls = self.shared_traces = 0
         self._report = report
         self._clock = clock
         self._open = []         # top-level trace intervals [start, secs]
@@ -106,7 +112,9 @@ class BuildMeter:
         metrics.gauge("build_cache_load_s", self.cache_load_s)
         for name, n in (("build_programs", self.programs),
                         ("build_cache_hits", self.cache_hits),
-                        ("build_cache_misses", self.cache_misses)):
+                        ("build_cache_misses", self.cache_misses),
+                        ("build_shared_calls", self.shared_calls),
+                        ("build_shared_traces", self.shared_traces)):
             metrics.counters[name] = n
 
 
@@ -120,6 +128,18 @@ def _on_event(event, **_kw):
     meter = getattr(_local, "meter", None)
     if meter is not None:
         meter.event(event)
+
+
+def shared_stage(traced):
+    """One use of a trace-once stage while a program is traced;
+    `traced` when it is the stage's Python body running, so not a
+    reuse of an earlier trace."""
+    meter = getattr(_local, "meter", None)
+    if meter is not None:
+        if traced:
+            meter.shared_traces += 1
+        else:
+            meter.shared_calls += 1
 
 
 def _register():
