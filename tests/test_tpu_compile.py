@@ -154,3 +154,32 @@ def test_sharded_step(spec, topo, no_cache):
     compiled = _compile(eng._step.lower(*args),
                         f"sharded step D={D} tile={eng.tile}")
     assert "all-to-all" in compiled.as_text()
+
+
+def test_sharded_start_programs(spec, topo, no_cache):
+    """The two programs of a sharded run's start (ISSUE 27) at the
+    four-chip configuration's per-shard capacities: the packed first
+    frontier padded on each shard, and the Init insert."""
+    import json
+
+    from tpuvsr.parallel.sharded_bfs import ShardedBFS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "vsr-defect-4chip.json")) as f:
+        caps = json.load(f)["assumed"]["engine"]["sharded"]
+    mesh = Mesh(np.array(topo.devices), ("d",))
+    eng = ShardedBFS(spec, mesh, **caps)
+    D, N, words = eng.D, eng.N, eng._pk.words
+    assert (N, eng.fp_cap) == (1 << 18, 1 << 21)
+    sh, rep = NamedSharding(mesh, P("d")), NamedSharding(mesh, P())
+    u32 = jnp.uint32
+    _compile(eng._fill_packed.lower(
+        jax.ShapeDtypeStruct((words,), u32, sharding=rep),
+        jax.ShapeDtypeStruct((D, words), u32, sharding=sh), N),
+        f"sharded start fill D={D} N={N}")
+    _compile(eng._sharded_ins.lower(
+        {"slots": jax.ShapeDtypeStruct((D, eng.fp_cap, 5), u32,
+                                       sharding=sh)},
+        jax.ShapeDtypeStruct((1, 4), u32, sharding=rep),
+        jax.ShapeDtypeStruct((1,), jnp.bool_, sharding=rep)),
+        f"sharded Init insert D={D} slots={eng.fp_cap}")
+

@@ -142,6 +142,13 @@ class PackSpec:
         canon = [[k, list(s), n] for k, s, n, _v in entries]
         self.version = hashlib.sha256(
             json.dumps(canon, sort_keys=True).encode()).hexdigest()[:12]
+        # the packed all-zero state, one ``[words]`` row: what pads a
+        # packed buffer.  Not all-zero words: a lane whose range starts
+        # below 0 packs 0 as ``-lo``
+        self.zero_row = self.pack_np(
+            {k: np.zeros((1,) + s, np.int32)
+             for k, s, _a, _b in self._splits})[0]
+        self.zero_row.setflags(write=False)
 
     # -- sizing --------------------------------------------------------
     @property

@@ -669,17 +669,29 @@ class ShardedBFS:
     fingerprint set are hash-partitioned over the mesh axis and states
     migrate to their owner in the in-level all_to_all."""
 
+    # what a caller may have sized its capacities by, and names in
+    # `requires`: the start of a run packs on the host the rows that
+    # exist, never D x next_capacity (ISSUE 27; before it 131 s a
+    # run() at 4 x 262,144 rows)
+    PROVIDES = frozenset({"start_packs_live_rows"})
+
     def __init__(self, spec, mesh: Mesh, axis: str = "d", max_msgs=None,
                  tile=32, bucket_cap=None, next_capacity=1 << 12,
                  fpset_capacity=1 << 14, check_deadlock=False,
                  model_factory=None, pipeline=2, exchange_retries=5,
                  exchange_backoff=0.05, exchange_backoff_cap=2.0,
                  sleep=time.sleep, pack="auto", commit="fused",
-                 symmetry="auto", bounds="auto", por="off"):
+                 symmetry="auto", bounds="auto", por="off", requires=()):
         from ..core.values import TLAError
         if commit not in ("fused", "per-action"):
             raise TLAError(f"commit must be 'fused' or 'per-action' "
                            f"(got {commit!r})")
+        # refuse before anything is built: an engine without a
+        # property the capacities were sized by would run, for minutes
+        missing = sorted(set(requires) - self.PROVIDES)
+        if missing:
+            raise TLAError(f"this ShardedBFS does not provide {missing} "
+                           f"(it provides {sorted(self.PROVIDES)})")
         self.spec = spec
         self.mesh = mesh
         self.axis = axis
@@ -853,6 +865,10 @@ class ShardedBFS:
                                              else None))
         self._fresh_jit = True   # first dispatch after a (re)jit is
         #                          charged to the "compile" phase
+        # the start's two programs, built once per engine: a run()
+        # of a built engine finds them compiled
+        self._sharded_ins = make_sharded_insert(self.mesh, self.axis)
+        self._fill_packed = make_packed_fill(self.mesh, self.axis)
         self._sh = NamedSharding(self.mesh, P(self.axis))
         self._rep_sh = NamedSharding(self.mesh, P())
         # multi-process: host pulls of globally-sharded arrays must
@@ -908,6 +924,37 @@ class ShardedBFS:
                   for k, v in zero.items()}
         z = lambda: self._put(np.zeros((D * cap,), np.int32))
         return nb, z(), z(), z()
+
+    def _start_frontier(self, rows, counts0, obs):
+        """The run's first frontier as a global array: the dense batch
+        `rows` (shard-major: `counts0[d]` rows for each shard d) at the
+        head of each shard's `self.N` rows, the zero state behind them.
+        With packing on the host packs the rows that exist and every
+        shard pads its own piece on the device (`_fill_packed`): no
+        D x N array on the host, at any capacity."""
+        D, F = self.D, self.N
+        counts0 = [int(c) for c in counts0]
+        starts = np.concatenate([[0], np.cumsum(counts0)])
+
+        def heads(plane, batch):
+            # plane[d, :counts0[d]] = shard d's rows of the batch
+            for d, c in enumerate(counts0):
+                plane[d, :c] = batch[starts[d]:starts[d] + c]
+            return self._put(plane.reshape((-1,) + plane.shape[2:]))
+
+        if self._pk is not None:
+            obs.count("init_packed_rows", int(starts[-1]))
+            zero_row = self._pk.zero_row
+            head = np.empty((D, max(counts0 + [1]), zero_row.size),
+                            np.uint32)
+            head[:] = zero_row
+            return self._fill_packed(
+                self._rep(zero_row), heads(head, self._pk.pack_np(rows)),
+                F)
+        # dense planes are built host-side and put once: pulling a
+        # freshly-allocated GLOBAL array is illegal in multi-process mode
+        return {k: heads(np.zeros((D, F) + np.shape(v), np.int32), rows[k])
+                for k, v in self.codec.zero_state().items()}
 
     def _pull_rows(self, garr, counts):
         """Gather per-device live rows of a [D*cap, ...] global array."""
@@ -970,9 +1017,6 @@ class ShardedBFS:
         if check_deadlock is not None and bool(check_deadlock) != self._ckd:
             self._ckd = bool(check_deadlock)
             self._build(self.codec.shape.MAX_MSGS)
-        with obs.span(spans.INIT):
-            sharded_ins = make_sharded_insert(self.mesh, self.axis)
-
         # exchange metrics: useful rows shipped vs static wire volume
         # (all_to_all always moves full D x bucket_cap buckets).  Bytes
         # are accumulated with the row size current at the time (the
@@ -992,6 +1036,9 @@ class ShardedBFS:
         exch_rows_wire = 0
         exch_bytes_useful = 0
         exch_bytes_wire = 0
+        # of the wire bytes, those that leave a chip: a sender's
+        # buckets for the other D-1 owners, padding included
+        exch_bytes_offchip = 0
 
         if resume_from is not None:
             # --- resume from a level-boundary snapshot ----------------
@@ -1116,22 +1163,14 @@ class ShardedBFS:
             exch_rows_wire = xc.get("wire_rows", 0)
             exch_bytes_useful = xc.get("useful_bytes", 0)
             exch_bytes_wire = xc.get("wire_bytes", 0)
+            exch_bytes_offchip = xc.get("offchip_bytes", 0)
             F = self.N
-            zero = self.codec.zero_state()
-            host_front = {k: np.zeros((D * F,) + np.shape(v), np.int32)
-                          for k, v in zero.items()}
-            pos = 0
-            for d in range(D):
-                for j in range(int(counts0[d])):
-                    for k in host_front:
-                        host_front[k][d * F + j] = rows[k][pos]
-                    pos += 1
             # snapshots store dense planes (the engine-agnostic
-            # interchange format); pack the scatter when packing is on
-            front = (self._put(self._pk.pack_np(host_front))
-                     if self._pk is not None else
-                     {k: self._put(v) for k, v in host_front.items()})
-            n_front = self._put(counts0.astype(np.int32))
+            # interchange format); the start packs them when packing
+            # is on
+            with obs.span(spans.INIT):
+                front = self._start_frontier(rows, counts0, obs)
+                n_front = self._put(counts0.astype(np.int32))
             base_dev = (sum(self.level_sizes[:-1])
                         + np.concatenate([[0], np.cumsum(counts0)[:-1]]))
             emit(f"resumed from {resume_from}: depth {depth0}, "
@@ -1163,24 +1202,10 @@ class ShardedBFS:
 
                 F = self.N
                 self._dev_distinct = counts0.astype(np.int64).copy()
-                # build the initial frontier host-side (zeros + init rows)
-                # and scatter once: pulling a freshly-allocated GLOBAL
-                # array is illegal in multi-process mode
-                zero = self.codec.zero_state()
-                host_front = {k: np.zeros((D * F,) + np.shape(v), np.int32)
-                              for k, v in zero.items()}
-                pos = 0
-                for d in range(D):
-                    for j in range(int(counts0[d])):
-                        row = dense[keep[pos]]
-                        for k in host_front:
-                            host_front[k][d * F + j] = row[k]
-                        pos += 1
-                front = (self._put(self._pk.pack_np(host_front))
-                         if self._pk is not None else
-                         {k: self._put(v) for k, v in host_front.items()})
+                front = self._start_frontier(
+                    {k: v[keep] for k, v in batch.items()}, counts0, obs)
                 n_front = self._put(counts0.astype(np.int32))
-                tables, _fr, ovf = sharded_ins(
+                tables, _fr, ovf = self._sharded_ins(
                     tables, self._rep(fps[keep]),
                     self._rep(np.ones((n0,), bool)))
                 assert not bool(self._pull(ovf).any())
@@ -1208,6 +1233,7 @@ class ShardedBFS:
                 "useful_bytes": exch_bytes_useful,
                 "wire_rows": exch_rows_wire,
                 "wire_bytes": exch_bytes_wire,
+                "offchip_bytes": exch_bytes_offchip,
             }
             for k, v in r.exchange.items():
                 obs.gauge(f"exchange_{k}", int(v))
@@ -1523,6 +1549,8 @@ class ShardedBFS:
                 wire = tiles_lvl * D * D * self.bucket_cap
                 exch_rows_wire += wire
                 exch_bytes_wire += wire * _row_bytes()
+                exch_bytes_offchip += (tiles_lvl * D * (D - 1)
+                                       * self.bucket_cap * _row_bytes())
                 nn_h = self._pull(nn)
             # occupancy accounting (ISSUE 10): expand lanes dispatched
             # this level, under the cap set in effect
@@ -1601,7 +1629,9 @@ class ShardedBFS:
                                    "useful_rows": exch_rows_useful,
                                    "wire_rows": exch_rows_wire,
                                    "useful_bytes": exch_bytes_useful,
-                                   "wire_bytes": exch_bytes_wire}})
+                                   "wire_bytes": exch_bytes_wire,
+                                   "offchip_bytes":
+                                       exch_bytes_offchip}})
                 last_checkpoint = _time.time()
                 obs.checkpoint(checkpoint_path, depth, fp_count)
                 emit(f"checkpoint written to {checkpoint_path} "
@@ -1662,6 +1692,10 @@ class ShardedBFS:
             # rank that writes the metrics file / journal)
             obs.gauge("shard_distinct",
                       [int(x) for x in self._dev_distinct])
+            # the fullest shard against the mean: 1.0 is an even split
+            obs.gauge("shard_skew",
+                      round(float(self._dev_distinct.max()
+                                  / self._dev_distinct.mean()), 4))
         acts = getattr(self, "_act_counts", None)
         if acts is not None:
             obs.gauge("action_expansions",
@@ -1689,6 +1723,23 @@ class ShardedBFS:
                 for n, c in zip(self.kern.action_names,
                                 self.expand_caps))
         return self.tile * self.kern.n_lanes
+
+
+def make_packed_fill(mesh: Mesh, axis: str):
+    """fill(zero_row, head, F) -> a `[D*F, words]` packed frontier
+    sharded over `axis`: on every shard its `head` rows (`[D*k, words]`
+    sharded, k <= F) followed by F - k copies of the replicated packed
+    zero row."""
+    def fill(zero_row, head, F):
+        def piece(zero_row, head):
+            pad = jnp.broadcast_to(
+                zero_row, (F - head.shape[0],) + zero_row.shape)
+            return jnp.concatenate([head, pad])
+
+        return _shard_map(piece, mesh=mesh, in_specs=(P(), P(axis)),
+                          out_specs=P(axis))(zero_row, head)
+
+    return jax.jit(fill, static_argnums=2)
 
 
 def make_sharded_insert(mesh: Mesh, axis: str):
