@@ -118,6 +118,112 @@ def test_sharded_fused_vs_per_action_violation_bit_identical():
 
 
 # ---------------------------------------------------------------------
+# stage 2 in blocks (ISSUE 28): only the blocks of an action's segment
+# that hold an enabled lane are expanded
+# ---------------------------------------------------------------------
+def _pointers(e):
+    e._flush_pointers()
+    return [np.concatenate([np.asarray(x) for x in h]).tolist()
+            for h in (e._h_parent, e._h_action, e._h_param)]
+
+
+# (tile, block, engine keywords).  The stub's actions have one lane a
+# state, so an action's cap is the tile and its count the states of
+# the tile that enable it: 1, 2, 3, 3, 2, 1, 0 down the levels.
+BLOCK_CASES = {
+    # cap 4 = two blocks: counts 2 (== block) and 3 (== block + 1)
+    "cap4-block2": (4, 2, {}),
+    # cap 3 is no multiple: the second block is clamped onto rows 1-2
+    "cap3-block2-clamped": (3, 2, {}),
+    # count 3 == block, the clamped second block never runs
+    "cap4-block3": (4, 3, {}),
+    "cap4-block1": (4, 1, {}),
+    # Jump is never enabled: its segment runs no block in any tile
+    "dead-action": (4, 2, {"dead_action": True, "bounds": False}),
+    # the next-buffer pause and re-entry of the test above, in blocks
+    "pause-reentry": (4, 2, {"pipeline": 1, "pack": False,
+                             "next_capacity": 8}),
+    "pause-reentry-clamped": (3, 2, {"pipeline": 1, "pack": False,
+                                     "next_capacity": 8}),
+}
+
+
+@pytest.mark.parametrize("case", list(BLOCK_CASES))
+def test_expand_blocks_bit_identical(monkeypatch, case):
+    """Fused in blocks against per-action: levels, counters and trace
+    pointers, and the counterexample of a reachable violation."""
+    from tpuvsr.engine import device_bfs
+    tile, block, kw = BLOCK_CASES[case]
+    monkeypatch.setattr(device_bfs, "EXPAND_BLOCK", block)
+    ea = stub_device_engine(tile_size=tile, **kw)
+    ra = ea.run()
+    eb = stub_device_engine(tile_size=tile, commit="per-action", **kw)
+    rb = eb.run()
+    assert ra.distinct_states == rb.distinct_states == STUB_DISTINCT
+    assert ra.states_generated == rb.states_generated
+    assert ea.level_sizes == eb.level_sizes == STUB_LEVELS
+    assert list(ea._act_counts) == list(eb._act_counts)
+    assert _pointers(ea) == _pointers(eb)
+    blocks = list(ea._blocks_act)
+    assert all(b > 0 for b in blocks[:2])
+    if kw.get("dead_action"):
+        assert ea.kern.action_names[2] == "Jump" and blocks[2] == 0
+    if case == "cap4-block2":
+        # one tile a level: ceil(count / 2) = 1, 1, 2, 2, 1, 1, 0
+        assert blocks == [8, 8]
+    va = stub_device_engine(tile_size=tile, inv_bound=4, **kw).run()
+    vb = stub_device_engine(tile_size=tile, inv_bound=4,
+                            commit="per-action", **kw).run()
+    assert not va.ok and va.violated_invariant == vb.violated_invariant
+    assert _trace_tuples(va) == _trace_tuples(vb)
+    assert va.distinct_states == vb.distinct_states
+
+
+# DeviceBFS on examples/VSR_small.cfg to depth 6 at the parent of
+# ISSUE 28 (one vmap over every cap lane of every action): sha256 of
+# its parent / action / lane trace pointers, and its per-action
+# expansion counts
+SMALL_POINTERS_DEPTH6 = (
+    "2647ffe144f8c1e07342594cd9e8b40581865af1ce7b7ce1b6a3aee3f915edb8")
+SMALL_ACTS_DEPTH6 = [28, 198, 566, 333, 10, 69, 0, 0, 13, 35, 34, 17,
+                     0, 0, 0, 0, 0, 0, 0]
+
+
+@pytest.mark.parametrize("block", [8, 40])
+def test_expand_blocks_small_check_pinned(monkeypatch, block):
+    """The pinned small check in blocks of 8 (which divides the caps
+    of 384 and 512) and of 40 (which does not: the last block is
+    clamped): segments of many blocks, most of them never run."""
+    import hashlib
+    import json
+
+    from tpuvsr.engine import device_bfs
+    from tpuvsr.engine.spec import load_spec
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "scripts",
+                           "pinned_levels_small.json")) as f:
+        pin = json.load(f)["level_sizes"][:7]
+    monkeypatch.setattr(device_bfs, "EXPAND_BLOCK", block)
+    eng = device_bfs.DeviceBFS(load_spec(
+        "VSR", os.path.join(repo, "examples", "VSR_small.cfg")))
+    res = eng.run(max_depth=6)
+    assert res.ok and list(eng.level_sizes) == pin
+    assert res.distinct_states == sum(pin)
+    assert list(eng._act_counts) == SMALL_ACTS_DEPTH6
+    digest = hashlib.sha256()
+    for plane in _pointers(eng):
+        digest.update(np.asarray(plane, np.int64).tobytes())
+    assert digest.hexdigest() == SMALL_POINTERS_DEPTH6
+    caps = eng._expand_caps()
+    assert any(cap % block for cap in caps) == (block == 40)
+    per_tile = sum(-(-cap // block) for cap in caps)
+    blocks = eng._blocks_act
+    assert blocks.max() > eng._tiles_done        # many blocks a tile
+    assert blocks.sum() * 4 < eng._tiles_done * per_tile
+    assert [b == 0 for b in blocks] == [a == 0 for a in eng._act_counts]
+
+
+# ---------------------------------------------------------------------
 # exact-count growth + calibration (host logic; no engine run)
 # ---------------------------------------------------------------------
 def test_exact_growth_and_calibration():
@@ -239,6 +345,38 @@ def test_commit_key_and_gauges(tmp_path):
     assert g["inserts_per_tile"] == 1
     assert g["commit_mode"] == "fused"
     assert 0.0 < g["occupancy"] <= 1.0
+
+
+@pytest.mark.parametrize("mode",
+                         ["run", "run_fused", "run_chained", "paged"])
+def test_expand_block_counters(monkeypatch, mode):
+    """`expand_blocks_run` of `expand_blocks_cap`, and occupancy over
+    the lanes the device expanded, from every loop that builds its
+    body from `_tile_body_factory`."""
+    from tpuvsr.engine import device_bfs
+    from tpuvsr.engine.paged_bfs import PagedBFS
+    block = 2
+    monkeypatch.setattr(device_bfs, "EXPAND_BLOCK", block)
+    e = stub_device_engine(cls=PagedBFS if mode == "paged" else None,
+                           dead_action=True, bounds=False,
+                           chunk_tiles=2)
+    r = getattr(e, "run" if mode == "paged" else mode)()
+    assert r.ok and r.distinct_states == STUB_DISTINCT
+    c, g = r.metrics["counters"], r.metrics["gauges"]
+    acts = g["action_expansions"]
+    assert c["expand_blocks_run"] == int(e._blocks_act.sum()) == 16
+    # 7 tiles (one a level), three caps of 4 lanes = 2 blocks each
+    assert c["expand_blocks_cap"] == 7 * 3 * 2
+    assert c["expand_blocks_run"] <= c["expand_blocks_cap"]
+    assert g["occupancy"] == round(
+        sum(acts.values()) / (c["expand_blocks_run"] * block), 4)
+    assert acts["Jump"] == 0 and e._blocks_act[2] == 0
+    # per-action expands every cap lane of every tile, in no blocks
+    rp = stub_device_engine(dead_action=True, bounds=False,
+                            commit="per-action").run()
+    assert "expand_blocks_run" not in rp.metrics["counters"]
+    assert rp.metrics["gauges"]["occupancy"] == round(
+        sum(acts.values()) / (7 * 3 * 4), 4)
 
 
 # ---------------------------------------------------------------------

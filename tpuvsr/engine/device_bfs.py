@@ -147,7 +147,11 @@ def _align8(n):
 # Cap headroom over the exact per-tile need the guard matrix observed
 # (growth and calibration both): the needs creep up level by level as
 # frontier states grow richer, and 2x was breached five times in the
-# 24-level flagship run — six compiles of the level program.
+# 24-level flagship run — six compiles of the level program.  What the
+# padding costs since ISSUE 28: the tile-local queue and stage 3 (batch
+# dedup, FPSet insert, pack, scatter) run at the sum of the caps;
+# stage 2 expands only the blocks that hold enabled lanes
+# (EXPAND_BLOCK), whatever the caps.
 CAP_HEADROOM = 4
 
 
@@ -160,7 +164,28 @@ CAP_HEADROOM = 4
 # 2.4k lanes per tile, 143 s at 8.8k, 154 s at 15k).  Not 8: a tile
 # commits only while the next-frontier buffer has room for every cap
 # lane, so caps near the buffer's 16k rows force it to grow instead.
+# The start sizes the queue and stage 3 only (see CAP_HEADROOM): the
+# defect window filled 9.5 % of these lanes, and until ISSUE 28 the
+# action functions ran over all of them.
 CAP_START = 4
+
+
+# Slots of an action's segment that stage 2 of the fused body expands
+# in one call: the segment is walked in blocks of this many, and only
+# the blocks that hold an enabled lane run (the guard matrix counted
+# them).  Two readings on one v5e chip, the defect window to depth 10
+# (PERF.md, PR 28): at 128 the run commits 33,431 and 33,234 states/s
+# (12,279 and 12,287 with one call over every cap lane), at 64 33,874
+# and 33,960 on the same seeds.  1.3-2.2 % does not pay for twice the
+# device events a second, so 128: the size the sharded step's segments
+# already showed to be bound by lanes, not by launches.
+EXPAND_BLOCK = 128
+
+
+def block_rows(cap):
+    """Slots in one block of a segment of `cap`: a block never exceeds
+    its action's cap."""
+    return min(EXPAND_BLOCK, cap)
 
 
 def static_cap(tile, full):
@@ -453,13 +478,58 @@ class DeviceBFS:
             if self._canon is not None else self.kern.fingerprint,
             "fingerprint")
         self._inv_stage = trace_once(self._inv, "invariants")
-        self._level = jax.jit(self._make_level(),
-                              donate_argnums=(0, 4, 5, 6, 7, 10))
+        self._expand_stages = {}    # (action, block rows) -> stage
+        self._level_jit = None  # chunked pass, built lazily (_level)
         self._ml = None         # fused pass, built lazily (run_fused)
         self._wl = None         # chained window pass (run_chained)
         # obs accounting: the first dispatch after a (re)jit is charged
         # to the "compile" phase (jit traces+compiles at first call)
         self._fresh_jit = True
+
+    @property
+    def _level(self):
+        """The jitted chunked level pass, built at its first use: in a
+        run, so the run's observer meters the build, and not at all
+        for an engine that only runs the fused or chained pass."""
+        if self._level_jit is None:
+            self._level_jit = jax.jit(self._make_level(),
+                                      donate_argnums=(0, 4, 5, 6, 7, 10))
+        return self._level_jit
+
+    def _run_level(self, *args):
+        """One dispatch of the level pass.  The loops hand the pipeline
+        this, not `_level`: a program made here is made inside the
+        dispatch's span (``tpuvsr.engine.build`` when fresh), its
+        stages' traces included."""
+        return self._level(*args)
+
+    def _expand_stage(self, aid, rows, row, parts):
+        """Stage 2 of the fused body for one block of `rows` compacted
+        items of action `aid` (`row`, `parts`: the types of one state
+        row and of its hash parts): `_successor_fn` under `vmap`, traced
+        HERE, outside every trace, and inlined where the body's block
+        loop uses it.  The loop is a `while` inside the tile loop's
+        `while` inside `jit`, and the 19 action functions cost twice
+        the Python seconds when they are traced from in there (v5e
+        host, the small config: 10.3 s against 6.1 s with one `vmap`
+        in the tile loop; 6.3 s so).  Kept for the kernel, like the
+        `trace_once` stages: a cap growth or a calibration re-creates
+        the level program and finds the stage traced."""
+        key = (aid, rows)
+        if key not in self._expand_stages:
+            kern = self.kern
+
+            def batch(s):
+                return jax.ShapeDtypeStruct((rows,) + s.shape, s.dtype)
+
+            stage = jax.jit(jax.vmap(self._successor_fn(
+                kern.action_names[aid], kern._action_fns()[aid])),
+                inline=True)
+            stage.trace(jax.tree_util.tree_map(batch, row),
+                        jax.tree_util.tree_map(batch, parts),
+                        jax.ShapeDtypeStruct((rows,), I32))
+            self._expand_stages[key] = stage
+        return self._expand_stages[key]
 
     def _expand_caps(self):
         """Per-action enabled-lane compaction capacities, in lanes.
@@ -754,8 +824,9 @@ class DeviceBFS:
                     "reason": reason, "viol": viol, "dead": dead_i,
                     "grow_aid": grow_aid,
                     # per-action mode sizes growth by doubling; the
-                    # need vector only carries data in fused commit
-                    "need": c["need"],
+                    # need vector only carries data in fused commit,
+                    # and so does the count of expand blocks
+                    "need": c["need"], "blk": c["blk"],
                     "slots": slots,
                     "nb": nb, "nbp": nbp, "nba": nba, "nbprm": nbprm,
                     "nn": nn, "dist": dist,
@@ -830,6 +901,17 @@ class DeviceBFS:
                                       caps))
         guard_mat = self._guard_matrix(kern)
         edges_on = self._edges_on
+        if pk is not None:
+            row = jax.eval_shape(pk.unpack, jax.ShapeDtypeStruct(
+                (pk.words,), jnp.uint32))
+        else:
+            row = {k: jax.ShapeDtypeStruct(np.shape(v), np.int32)
+                   for k, v in self.codec.zero_state().items()}
+        parts_row = (jax.eval_shape(kern.parent_parts, row)
+                     if incremental else None)
+        expand_of = [self._expand_stage(aid, block_rows(cap), row,
+                                        parts_row)
+                     for aid, cap in enumerate(caps)]
         # ample-set POR (ISSUE 16): amat[a, b] says "expanding only a
         # is safe given an enabled b" (por.PORFilter); qoff slices the
         # action-major staging queue back into per-action segments for
@@ -930,14 +1012,16 @@ class DeviceBFS:
                 if incremental:
                     with jax.named_scope(spans.FINGERPRINT):
                         parts = jax.vmap(kern.parent_parts)(tile)
+                else:
+                    parts = None
                 succ_segs, fp_segs, en_s_segs = [], [], []
-                pidx_segs, lane_segs = [], []
+                pidx_segs, lane_segs, blk_segs = [], [], []
                 viol_any = jnp.asarray(False)
                 bag_err = jnp.asarray(False)
                 slot_err = jnp.asarray(False)
                 first_bad = jnp.asarray(n_act, I32)
-                for aid, (name, fn) in enumerate(
-                        zip(kern.action_names, kern._action_fns())):
+                zero = jnp.asarray(0, I32)
+                for aid, name in enumerate(kern.action_names):
                     L_a = kern._lane_count(name)
                     TL = T * L_a
                     E_a = caps[aid]
@@ -948,16 +1032,52 @@ class DeviceBFS:
                         sel_ok = sel < TL
                         pidx = jnp.clip(sel // L_a, 0, T - 1).astype(I32)
                         lane_sel = (sel % L_a).astype(I32)
-                        st_sel = {k: v[pidx] for k, v in tile.items()}
+                    # only the blocks of the segment that hold an
+                    # enabled lane are expanded: stage 1 counted them
+                    # exactly.  A slot no block wrote keeps its zeros
+                    # and en2 False; a slot at or past cnt in a block
+                    # that ran has sel_ok False.  Either way en_s is
+                    # False there and every later read is masked by it
+                    B = block_rows(E_a)
+                    n_blk = (jnp.minimum(cnts[aid], E_a) + B - 1) // B
+                    blk_segs.append(n_blk)
+                    expand = expand_of[aid]
 
-                    parts_sel = None
-                    if incremental:
+                    def block(b, out):
+                        # the last block of a cap that is no multiple
+                        # of B is clamped onto the one before it: the
+                        # rows they share are written twice, the same
                         with jax.named_scope(spans.COMPACT):
-                            parts_sel = jax.tree_util.tree_map(
-                                lambda v: v[pidx], parts)
-                    succ_f, fp, en2, iok, errv = jax.vmap(
-                        self._successor_fn(name, fn))(
-                            st_sel, parts_sel, lane_sel)
+                            lo = jnp.minimum(b * B, E_a - B)
+                            pidx_b = jax.lax.dynamic_slice_in_dim(
+                                pidx, lo, B)
+                            st_b = {k: v[pidx_b] for k, v in tile.items()}
+                            parts_b = jax.tree_util.tree_map(
+                                lambda v: v[pidx_b], parts)
+                        got = expand(
+                            st_b, parts_b,
+                            jax.lax.dynamic_slice_in_dim(lane_sel, lo, B))
+                        # the primitive, bound bare: `lo` needs no
+                        # wrap-around arithmetic, and 41 planes an
+                        # action would each trace theirs
+                        with jax.named_scope(spans.COMPACT):
+                            return jax.tree_util.tree_map(
+                                lambda buf, v:
+                                jax.lax.dynamic_update_slice_p.bind(
+                                    buf, v.astype(buf.dtype), lo,
+                                    *[zero] * (buf.ndim - 1)),
+                                out, got)
+
+                    # a successor is a state row, so the segment's
+                    # buffers take their types from the tile's planes
+                    succ0 = {k: jax.lax.full((E_a,) + v.shape[1:], 0,
+                                             v.dtype)
+                             for k, v in tile.items()}
+                    no = jax.lax.full((E_a,), False, bool)
+                    succ_f, fp, en2, iok, errv = jax.lax.fori_loop(
+                        0, n_blk, block,
+                        (succ0, jax.lax.full((E_a, 4), 0, jnp.uint32),
+                         no, no, succ0["err"]))
 
                     with jax.named_scope(spans.INVARIANTS):
                         en_s = en2 & sel_ok
@@ -1080,6 +1200,9 @@ class DeviceBFS:
                     "gen": c["gen"] + jnp.where(commit, gen_local, 0),
                     "act": c["act"] + jnp.where(
                         commit, cnts.astype(jnp.uint32), jnp.uint32(0)),
+                    # blocks of stage 2 this pass ran, committed or not
+                    "blk": c["blk"] + jnp.stack(blk_segs).astype(
+                        jnp.uint32),
                 }
                 if por_active:
                     # gen/act count the KEPT expansions (they feed
@@ -1223,6 +1346,7 @@ class DeviceBFS:
                 "dist": jnp.asarray(0, I32),
                 "gen": jnp.asarray(0, I32),
                 "act": jnp.zeros((len(_caps),), jnp.uint32),
+                "blk": jnp.zeros((len(_caps),), jnp.uint32),
             }
             if eb is not None:
                 init["gids"] = table["gids"]
@@ -1321,6 +1445,7 @@ class DeviceBFS:
                     "dist": jnp.asarray(0, I32),
                     "gen": c["gen_level"],
                     "act": c["act"],
+                    "blk": c["blk"],
                 }
                 if por_active:
                     iinit["gids"] = c["gids"]
@@ -1395,7 +1520,7 @@ class DeviceBFS:
                     "reason": r["reason"],
                     "viol": r["viol"], "dead": r["dead"],
                     "grow_aid": r["grow_aid"], "need": r["need"],
-                    "act": r["act"],
+                    "act": r["act"], "blk": r["blk"],
                 }
 
             init = {
@@ -1418,6 +1543,7 @@ class DeviceBFS:
                 "grow_aid": jnp.asarray(-1, I32),
                 "need": jnp.zeros((len(_caps),), jnp.uint32),
                 "act": jnp.zeros((len(_caps),), jnp.uint32),
+                "blk": jnp.zeros((len(_caps),), jnp.uint32),
             }
             if por_active:
                 init["gids"] = gids
@@ -1520,8 +1646,7 @@ class DeviceBFS:
             obs.grow("expand_buffer", self.expand_mults[aid])
             emit(f"expand buffer for {kern.action_names[aid]} grown "
                  f"to tile x {self.expand_mults[aid]} (recompiling)")
-        self._level = jax.jit(self._make_level(),
-                              donate_argnums=(0, 4, 5, 6, 7, 10))
+        self._level_jit = None
         self._ml = None
         self._wl = None
         self._fresh_jit = True
@@ -1534,7 +1659,11 @@ class DeviceBFS:
         (each calibration is a recompile); caps can only shrink onto
         real observations, so a later bigger tile simply triggers an
         exact growth event.  Cap changes never affect results — only
-        which lanes are padding (the occupancy gauge's denominator)."""
+        how many queue lanes are padding.  Since ISSUE 28 that padding
+        costs the queue and stage 3 alone: stage 2 runs the blocks
+        that hold enabled lanes (the occupancy gauge's denominator),
+        so a calibration no longer moves the expand stage or the
+        gauge, and a start that never shrinks (`static_cap`) is cheap."""
         if self.commit != "fused" or level_states < 4 * self.tile:
             return False
         kern, T = self.kern, self.tile
@@ -1550,8 +1679,7 @@ class DeviceBFS:
         if sum(tgt) * 5 > sum(cur) * 4:
             return False
         self.expand_caps = tgt
-        self._level = jax.jit(self._make_level(),
-                              donate_argnums=(0, 4, 5, 6, 7, 10))
+        self._level_jit = None
         self._ml = None
         self._wl = None
         self._fresh_jit = True
@@ -1561,11 +1689,40 @@ class DeviceBFS:
              f"({sum(cur)} -> {sum(tgt)} lanes/tile; recompiling)")
         return True
 
+    def _reset_accounting(self):
+        """Run-scoped counters that the tickets feed: per-action
+        expansions (the on-device accumulator), tiles committed, expand
+        lanes the device ran, and the expand blocks run of those the
+        caps hold."""
+        n_act = len(self.kern.action_names)
+        self._act_counts = np.zeros(n_act, np.int64)
+        self._blocks_act = np.zeros(n_act, np.int64)
+        self._blocks_cap = 0
+        self._tiles_done = 0
+        self._lanes_disp = 0
+
+    def _account_blocks(self, blk):
+        """One collected ticket's per-action counts of expand blocks
+        (fused commit; zeros from the per-action body): the lanes the
+        device really expanded, paused passes included."""
+        blk = np.asarray(blk, np.int64)
+        self._blocks_act += blk
+        self._lanes_disp += sum(
+            int(n) * block_rows(cap)
+            for n, cap in zip(blk, self._expand_caps()))
+
     def _account_tiles(self, n_tiles):
-        """Occupancy accounting: `n_tiles` frontier tiles were
-        dispatched under the current cap set."""
+        """`n_tiles` frontier tiles were committed under the current
+        cap set.  The per-action body expands every cap lane of each;
+        the fused body only its blocks (`_account_blocks`), of the
+        `_blocks_cap` the caps hold."""
+        caps = self._expand_caps()
         self._tiles_done += int(n_tiles)
-        self._lanes_disp += int(n_tiles) * sum(self._expand_caps())
+        if self.commit == "fused":
+            self._blocks_cap += int(n_tiles) * sum(
+                -(-cap // block_rows(cap)) for cap in caps)
+        else:
+            self._lanes_disp += int(n_tiles) * sum(caps)
 
     # ------------------------------------------------------------------
     def _alloc_bufs(self, cap):
@@ -1839,10 +1996,7 @@ class DeviceBFS:
         # per-action expansion counters (on-device accumulator, pulled
         # with the control scalars; run-scoped, not checkpointed) +
         # occupancy accounting (ISSUE 10)
-        self._act_counts = np.zeros(len(self.kern.action_names),
-                                    np.int64)
-        self._tiles_done = 0
-        self._lanes_disp = 0
+        self._reset_accounting()
         self._por_kept = self._por_full = self._por_amp = 0
         res = CheckResult()
         t0 = time.time()
@@ -1954,7 +2108,7 @@ class DeviceBFS:
             # ONE host round-trip for all control scalars — separate
             # int() pulls cost one device round-trip each
             vals = [o["reason"], o["t"], o["nn"], o["gen"], o["dist"],
-                    o["act"], o["need"]]
+                    o["act"], o["need"], o["blk"]]
             if self._por_active:
                 vals += [o["gfull"], o["amp"]]
             return jax.device_get(vals)
@@ -1998,7 +2152,7 @@ class DeviceBFS:
                 while pipe.has_room():
                     nb, nbp, nba, nbprm = bufs
                     out = pipe.launch(
-                        self._level, table, front,
+                        self._run_level, table, front,
                         jnp.asarray(n_front, I32), pend_t,
                         nb, nbp, nba, nbprm, pend_nn,
                         jnp.asarray(bool(check_deadlock)), None, None,
@@ -2019,10 +2173,11 @@ class DeviceBFS:
                 fp_count += dist_add
                 self._act_counts += np.asarray(sc[5], np.int64)
                 self._fold_need(sc[6])
+                self._account_blocks(sc[7])
                 if self._por_active:
                     self._por_kept += gen_add
-                    self._por_full += int(sc[7])
-                    self._por_amp += int(sc[8])
+                    self._por_full += int(sc[8])
+                    self._por_amp += int(sc[9])
 
                 if reason == RUNNING:
                     obs.progress(depth=depth, distinct=fp_count,
@@ -2264,10 +2419,7 @@ class DeviceBFS:
         obs.gauge("pipeline_depth", 1)
         self._obs_active = obs          # closes_observer finalizes it
         spec, codec = self.spec, self.codec
-        self._act_counts = np.zeros(len(self.kern.action_names),
-                                    np.int64)
-        self._tiles_done = 0
-        self._lanes_disp = 0
+        self._reset_accounting()
         self._por_kept = self._por_full = self._por_amp = 0
         res = CheckResult()
         t0 = time.time()
@@ -2360,7 +2512,7 @@ class DeviceBFS:
                                       "nn", "gen_level", "gen", "depth",
                                       "level_base", "fp_count",
                                       "lvl_cur", "act", "tiles",
-                                      "need")]
+                                      "need", "blk")]
                     + ([out[k] for k in ("gfull", "gfull_level",
                                          "amp", "amp_level")]
                        if por_on else []))
@@ -2369,11 +2521,12 @@ class DeviceBFS:
             self._act_counts += np.asarray(sc[10], np.int64)
             self._account_tiles(int(sc[11]))
             self._fold_need(sc[12])
+            self._account_blocks(sc[13])
             if por_on:
                 self._por_kept += gen_add
-                self._por_full += int(sc[13])
-                self._por_amp += int(sc[15])
-                gfull_level, amp_level = int(sc[14]), int(sc[16])
+                self._por_full += int(sc[14])
+                self._por_amp += int(sc[16])
+                gfull_level, amp_level = int(sc[15]), int(sc[17])
             res.states_generated += gen_add
             if lvl_cur:
                 # level boundaries inside one dispatch share its
@@ -2622,10 +2775,7 @@ class DeviceBFS:
         obs.por = self._por_doc()
         self._obs_active = obs          # closes_observer finalizes it
         spec = self.spec
-        self._act_counts = np.zeros(len(self.kern.action_names),
-                                    np.int64)
-        self._tiles_done = 0
-        self._lanes_disp = 0
+        self._reset_accounting()
         self._por_kept = self._por_full = self._por_amp = 0
         res = CheckResult()
         t0 = time.time()
@@ -2677,7 +2827,7 @@ class DeviceBFS:
             vals = [o["reason"], o["n_front"], o["depth"],
                     o["fp_count"], o["level_base"], o["lvl_cur"],
                     o["gen"], o["gen_level"], o["act"], o["start_t"],
-                    o["nn"], o["tiles"], o["need"]]
+                    o["nn"], o["tiles"], o["need"], o["blk"]]
             if por_on:
                 vals += [o["gfull"], o["gfull_level"],
                          o["amp"], o["amp_level"]]
@@ -2703,11 +2853,12 @@ class DeviceBFS:
             levels_unck += lvl_cur
             self._account_tiles(int(sc[11]))
             self._fold_need(sc[12])
+            self._account_blocks(sc[13])
             if por_on:
                 self._por_kept += gen_add
-                self._por_full += int(sc[13])
-                self._por_amp += int(sc[15])
-                gfull_level, amp_level = int(sc[14]), int(sc[16])
+                self._por_full += int(sc[14])
+                self._por_amp += int(sc[16])
+                gfull_level, amp_level = int(sc[15]), int(sc[17])
             if lvl_cur:
                 # each dispatch records its own committed levels from
                 # slot 0 of ITS lvl_buf output (which is why lvl_buf is
@@ -3038,13 +3189,18 @@ class DeviceBFS:
             obs.gauge("action_expansions",
                       {n: int(c) for n, c in
                        zip(self.kern.action_names, acts)})
-        # occupancy = real work items / expand lanes dispatched, and
-        # the structural insert_core batches per frontier tile
-        # (ISSUE 10: 1 fused vs n_actions per-action)
+        # occupancy = real work items / expand lanes the device ran
+        # (fused: the blocks of stage 2 that held an enabled lane, of
+        # the blocks the caps hold), and the structural insert_core
+        # batches per frontier tile (ISSUE 10: 1 fused vs n_actions
+        # per-action)
         lanes = getattr(self, "_lanes_disp", 0)
         if lanes and acts is not None:
             obs.gauge("occupancy",
                       round(float(acts.sum()) / lanes, 4))
+        if self.commit == "fused" and acts is not None:
+            obs.count("expand_blocks_run", int(self._blocks_act.sum()))
+            obs.count("expand_blocks_cap", self._blocks_cap)
         obs.gauge("inserts_per_tile",
                   1 if self.commit == "fused"
                   else len(self.kern.action_names))

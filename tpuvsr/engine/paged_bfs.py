@@ -229,10 +229,7 @@ class PagedBFS(DeviceBFS):
         obs.por = self._por_doc()
         self._obs_active = obs          # closes_observer finalizes it
         spec = self.spec
-        self._act_counts = np.zeros(len(self.kern.action_names),
-                                    np.int64)
-        self._tiles_done = 0
-        self._lanes_disp = 0
+        self._reset_accounting()
         self._por_kept = self._por_full = self._por_amp = 0
         res = CheckResult()
         t0 = time.time()
@@ -401,7 +398,7 @@ class PagedBFS(DeviceBFS):
 
         def pull(o):
             keys = [o["reason"], o["t"], o["nn"], o["gen"],
-                    o["dist"], o["act"], o["need"]]
+                    o["dist"], o["act"], o["need"], o["blk"]]
             if self._edges_on:
                 keys.append(o["edge_n"])
             if self._por_active:
@@ -540,7 +537,7 @@ class PagedBFS(DeviceBFS):
                                     level_base + n_front
                                     + n_next_total, I32)}
                         out = pipe.launch(
-                            self._level, table, dev_chunk,
+                            self._run_level, table, dev_chunk,
                             jnp.asarray(n_c, I32), pend_t,
                             nb, nbp, nba, nbprm, pend_nn,
                             jnp.asarray(bool(check_deadlock)),
@@ -567,12 +564,13 @@ class PagedBFS(DeviceBFS):
                     fp_count += dist_add
                     self._act_counts += np.asarray(sc[5], np.int64)
                     self._fold_need(sc[6])
+                    self._account_blocks(sc[7])
                     if self._edges_on:
-                        n_edge = int(sc[7])
+                        n_edge = int(sc[8])
                     if self._por_active:
                         self._por_kept += gen_add
-                        self._por_full += int(sc[7])
-                        self._por_amp += int(sc[8])
+                        self._por_full += int(sc[8])
+                        self._por_amp += int(sc[9])
 
                     if reason == RUNNING:
                         obs.progress(depth=depth, distinct=fp_count,
