@@ -16,8 +16,10 @@ bottleneck config): |Values|=1/timer=0 -> |Values|=1/timer=1 ->
 the reference-free stub-harness proxy (the tier-1 acceptance proxy
 for ``graph_overhead_ratio``).
 
-Headline keys (bench.py lifts them into the round doc;
-scripts/compare_bench.py's ``gate_liveness`` gates on them):
+Headline keys (scripts/compare_bench.py's ``gate_liveness`` gates on
+them; the repo's benchmark is
+``python3 benchmark/run.py --workload W --seed N --seconds S --trace 0|1``,
+which has no liveness cell yet):
 ``mode``, ``edges``, ``edges_per_s``, ``graph_overhead_ratio``,
 ``check_s``.
 
@@ -155,7 +157,7 @@ else:
             lambda v=values, t=timer: _ref_spec(v, t),
             dict(tile_size=128)))
 
-# headline = the largest pin that ran (bench.py lifts these)
+# headline = the largest pin that ran
 if out["pins"]:
     head = out["pins"][-1]
     out["edges"] = head["streamed"]["edges"]

@@ -26,9 +26,10 @@ round-trip.
 
 A throughput leg (default 2048 stub traces through the device-mesh
 validator) records ``traces_per_s``; ``--out FILE`` writes the JSON
-artifact ``bench.py`` attaches to the round doc (the
-``scripts/compare_bench.py`` traces/s gate input; cross-backend
-comparisons are advisory there).
+artifact (the ``scripts/compare_bench.py`` traces/s gate input;
+cross-backend comparisons are advisory there).  It is a drill, not
+the benchmark: that is
+``python3 benchmark/run.py --workload W --seed N --seconds S --trace 0|1``.
 
     python scripts/validate_demo.py [--traces N] [--out FILE]
 

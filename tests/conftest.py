@@ -30,6 +30,50 @@ def pytest_configure(config):
         "markers", "slow: long-running differential tests")
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMALL_CFG = os.path.join(REPO, "examples", "VSR_small.cfg")
+
+
+@pytest.fixture(scope="session")
+def small_native():
+    """The kernel-native small check, from committed files alone (no
+    reference mount, no interpreter)."""
+    from tpuvsr.engine.spec import load_spec
+    return load_spec("VSR", SMALL_CFG)
+
+
+@pytest.fixture(scope="session")
+def small_pin():
+    """Its pinned level sizes, depth 0 to the fixpoint at 24."""
+    import json
+    with open(os.path.join(REPO, "scripts",
+                           "pinned_levels_small.json")) as f:
+        return json.load(f)["level_sizes"]
+
+
+def check_native_growth(spec, pin, counter, journal, depth=8, **engine_kw):
+    """`DeviceBFS.run` on the native small check with one capacity
+    undersized by `engine_kw`: the growth `grow_<counter>` fires, the
+    level program is rebuilt, and the level sizes equal the pin
+    through `depth` all the same.  Every growth is a build of the
+    level program, most of a case's time: a case is sized to grow
+    once."""
+    from tpuvsr.engine.device_bfs import DeviceBFS
+    from tpuvsr.obs import RunObserver, read_journal
+    eng = DeviceBFS(spec, **engine_kw)
+    res = eng.run(max_depth=depth, obs=RunObserver(journal_path=journal))
+    c = res.metrics["counters"]
+    assert c["grows"] == c[f"grow_{counter}"] >= 1, c
+    assert res.ok and res.error == f"depth limit {depth} reached"
+    assert res.levels == list(eng.level_sizes) == pin[:depth + 1]
+    assert res.distinct_states == sum(pin[:depth + 1])
+    # a level ended after the growth: the sizes above include levels
+    # that the rebuilt program produced
+    events = [e["event"] for e in read_journal(journal)]
+    assert "level_done" in events[events.index("grow"):]
+    return eng
+
+
 def state_key(st):
     """Hashable identity of a full interpreter state dict."""
     return frozenset(st.items())

@@ -637,13 +637,10 @@ def _cli(*argv, cwd=None):
 
 
 @pytest.mark.parametrize("argv", [
-    ("spec.tla", "-fused", "-checkpoint", "5"),
-    ("spec.tla", "-fused", "-recover", "x.ckpt"),
     ("spec.tla", "-fpset", "host", "-engine", "device"),
     ("spec.tla", "-fpset", "hbm", "-engine", "interp"),
     ("spec.tla", "-fpset", "paged", "-engine", "interp"),
-], ids=["fused-ckpt", "fused-recover", "host-device", "hbm-interp",
-        "paged-interp"])
+], ids=["host-device", "hbm-interp", "paged-interp"])
 def test_cli_flag_conflicts_exit_2(argv):
     # conflicts are argparse errors BEFORE the spec file is touched:
     # the path does not exist, yet the exit is a usage error

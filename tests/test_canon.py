@@ -170,15 +170,6 @@ def test_interp_and_device_agree_on_orbit_count():
 
 
 @pytest.mark.slow
-def test_fused_and_chained_symmetry_fixpoints():
-    rf = stub_sym_engine().run_fused()
-    rc = stub_sym_engine().run_chained()
-    for r in (rf, rc):
-        assert r.ok and r.distinct_states == SYMPAIR_ORBITS
-        assert r.levels == SYMPAIR_ORBIT_LEVELS
-
-
-@pytest.mark.slow
 def test_paged_symmetry_on_off_ab(tmp_path):
     from tpuvsr.engine.paged_bfs import PagedBFS
     ron = stub_sym_engine(cls=PagedBFS).run()
@@ -229,8 +220,6 @@ def test_verdict_identity_device_on_off():
 def test_verdict_identity_other_engines_and_commit_modes():
     spec = sym_pair_spec(inv_pair=True)
     from tpuvsr.engine.paged_bfs import PagedBFS
-    _assert_nopair_violation(
-        stub_sym_engine(inv_pair=True).run_fused(), spec)
     _assert_nopair_violation(
         stub_sym_sharded(n_devices=2, inv_pair=True).run(), spec)
     _assert_nopair_violation(

@@ -13,7 +13,8 @@ import sys
 
 import pytest
 
-from tests.conftest import REFERENCE, requires_reference
+from tests.conftest import (REFERENCE, REPO, SMALL_CFG,
+                            requires_reference)
 
 
 def _run(*argv, timeout=420):
@@ -21,7 +22,7 @@ def _run(*argv, timeout=420):
         [sys.executable, "-m", "tpuvsr", *argv],
         capture_output=True, text=True, timeout=timeout,
         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
-             "PYTHONPATH": "/root/repo",
+             "PYTHONPATH": REPO,
              "HOME": "/root"})
 
 
@@ -90,18 +91,15 @@ def test_cli_analysis_spec_with_shipped_cfg():
 # mount needed: the conflicts fire at parse time.
 # ---------------------------------------------------------------------
 @pytest.mark.parametrize("bad", [
-    ["-engine", "sharded", "-fused"],
     ["-engine", "sharded", "-simulate"],
     ["-engine", "sharded", "-fpset", "host"],
     ["-engine", "sharded", "-fpset", "hbm"],
     ["-engine", "sharded", "-fpset", "paged"],
-    ["-supervise", "-engine", "sharded", "-fused"],
     ["-engine", "sharded", "-supervise", "-inject", "kill@level="],
     ["-engine", "sharded", "-inject", "exchange-drop:0@shard=0"],
     ["-engine", "sharded", "-pipeline", "0"],
-], ids=["fused", "simulate", "fpset-host", "fpset-hbm", "fpset-paged",
-        "supervise-fused", "bad-kill-spec", "zero-drop-count",
-        "bad-pipeline"])
+], ids=["simulate", "fpset-host", "fpset-hbm", "fpset-paged",
+        "bad-kill-spec", "zero-drop-count", "bad-pipeline"])
 def test_cli_sharded_flag_conflicts_exit_2(bad):
     r = _run("X.tla", *bad)
     assert r.returncode == 2, (r.stdout, r.stderr)
@@ -110,17 +108,41 @@ def test_cli_sharded_flag_conflicts_exit_2(bad):
 
 @pytest.mark.parametrize("bad", [
     ["-commit", "fused", "-engine", "interp"],
-    ["-chained", "-fused"],
-    ["-chained", "-recover", "ck"],
-], ids=["commit-interp", "chained-fused",
-        "chained-recover-unsupervised"])
+], ids=["commit-interp"])
 def test_cli_commit_flag_conflicts_exit_2(bad):
-    """ISSUE 10: -commit configures the BFS level kernel and -chained
-    the device dispatch window; their documented conflicts are
-    argparse errors (exit 2) before any spec is loaded."""
+    """ISSUE 10: -commit configures the BFS level kernel; its
+    documented conflicts are argparse errors (exit 2) before any spec
+    is loaded."""
     r = _run("X.tla", *bad)
     assert r.returncode == 2, (r.stdout, r.stderr)
     assert "usage" in r.stderr or "error" in r.stderr
+
+
+@pytest.mark.parametrize("engine", [["-engine", "device"],
+                                    ["-fpset", "paged"]],
+                         ids=["device", "paged"])
+def test_cli_native_check_exact_count(engine, small_pin):
+    """`python -m tpuvsr VSR -config examples/VSR_small.cfg` from
+    committed files under each one-chip engine: the state limit is
+    tested between levels, so the run ends after the level that
+    passes 500 states, depth 6, with the pinned count."""
+    r = _run("VSR", "-config", SMALL_CFG, *engine,
+             "-maxstates", "500", "-json")
+    assert r.returncode == 0, r.stderr
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["mode"] == "bfs" and out["ok"] is True
+    assert out["distinct_states"] == sum(small_pin[:7]) == 599
+    assert out["diameter"] == 6
+
+
+@pytest.mark.parametrize("flag", ["-fused", "-chained"])
+def test_cli_removed_driver_flags_exit_2(flag):
+    """`DeviceBFS` has one driver, `run`: the flags that selected the
+    other two are unknown arguments, refused before any spec is
+    loaded (the path does not exist)."""
+    r = _run("X.tla", flag)
+    assert r.returncode == 2, (r.stdout, r.stderr)
+    assert "unrecognized arguments: " + flag in r.stderr
 
 
 @pytest.mark.parametrize("bad", [

@@ -12,9 +12,6 @@ legs and the seams the restructure touches:
 * fused vs per-action bit-identity on the device/paged/sharded
   engines, including violation traces and a growth-pause re-entry at
   a mid-chunk boundary;
-* the run_chained level-boundary rescue seam (satellite): cadence
-  checkpoints, SIGTERM rescue, resume through run() bit-identical to
-  the uninterrupted oracle, and the supervisor's chained mode degrade;
 * exact-count cap growth + level-boundary calibration host logic;
 * the obs surface: run_start `commit` key (key-set parity), and the
   `occupancy` / `inserts_per_tile` / `commit_mode` gauges.
@@ -24,14 +21,12 @@ the fused half of that cross is what every other module runs tier-1.
 """
 
 import os
-import signal
 
 import numpy as np
 import pytest
 
 from tpuvsr.testing import (STUB_DISTINCT, STUB_LEVELS, counter_spec,
-                            stub_device_engine, stub_engine_factory,
-                            stub_sharded_engine)
+                            stub_device_engine, stub_sharded_engine)
 
 
 def _trace_tuples(res):
@@ -266,63 +261,6 @@ def test_exact_growth_and_calibration():
 
 
 # ---------------------------------------------------------------------
-# run_chained rescue seam (satellite)
-# ---------------------------------------------------------------------
-def test_chained_checkpoint_seam_resumes_through_run(tmp_path):
-    ck = str(tmp_path / "ck")
-    e = stub_device_engine(chunk_tiles=1)
-    r = e.run_chained(checkpoint_path=ck, checkpoint_every=0.0)
-    assert r.ok and r.distinct_states == STUB_DISTINCT
-    assert os.path.isdir(ck)
-    e2 = stub_device_engine()
-    r2 = e2.run(resume_from=ck)
-    assert r2.ok and r2.distinct_states == STUB_DISTINCT
-    assert e2.level_sizes == STUB_LEVELS
-
-
-def test_chained_preempt_rescue_bit_identical(tmp_path):
-    """A pending SIGTERM makes the chained window finish the in-flight
-    level, write a run()-format rescue snapshot at the boundary, and
-    exit resumable; the resumed run reaches the exact fixpoint."""
-    from tpuvsr.resilience.supervisor import (Preempted,
-                                              PreemptionGuard)
-    ck = str(tmp_path / "rescue-ck")
-    preempted = None
-    with PreemptionGuard():
-        os.kill(os.getpid(), signal.SIGTERM)
-        try:
-            stub_device_engine(chunk_tiles=1).run_chained(
-                checkpoint_path=ck)
-        except Preempted as p:
-            preempted = p
-    assert preempted is not None and preempted.path == ck
-    res = stub_device_engine().run(resume_from=ck)
-    assert res.ok and res.distinct_states == STUB_DISTINCT
-    # the resumed trajectory is the uninterrupted one
-
-
-def test_supervisor_chained_mode_degrades_on_resume(tmp_path):
-    """-supervise + chained: a retry that has a snapshot resumes
-    through the chunked engine, journaled as a mode degrade exactly
-    like the fused one (ISSUE 10 satellite)."""
-    from tpuvsr.resilience.supervisor import Supervisor
-    spec = counter_spec()
-    # the degrade path: feed it a resume snapshot
-    e = stub_device_engine()
-    e.run(checkpoint_path=str(tmp_path / "ck2"))
-    sup2 = Supervisor(spec, engine="device", chained=True,
-                      checkpoint_path=str(tmp_path / "ck2"),
-                      engine_factory=stub_engine_factory(spec))
-    res2 = sup2.run(resume_from=str(tmp_path / "ck2"))
-    assert res2.ok and res2.distinct_states == STUB_DISTINCT
-    assert sup2.summary()["chained"] is True
-    assert ("mode", "chained", "chunked") in [
-        tuple(d) for d in sup2.degrades]
-    with pytest.raises(ValueError):
-        Supervisor(spec, engine="device", fused=True, chained=True)
-
-
-# ---------------------------------------------------------------------
 # obs surface
 # ---------------------------------------------------------------------
 def test_commit_key_and_gauges(tmp_path):
@@ -347,12 +285,11 @@ def test_commit_key_and_gauges(tmp_path):
     assert 0.0 < g["occupancy"] <= 1.0
 
 
-@pytest.mark.parametrize("mode",
-                         ["run", "run_fused", "run_chained", "paged"])
+@pytest.mark.parametrize("mode", ["run", "paged"])
 def test_expand_block_counters(monkeypatch, mode):
     """`expand_blocks_run` of `expand_blocks_cap`, and occupancy over
-    the lanes the device expanded, from every loop that builds its
-    body from `_tile_body_factory`."""
+    the lanes the device expanded, from both loops that run the level
+    program."""
     from tpuvsr.engine import device_bfs
     from tpuvsr.engine.paged_bfs import PagedBFS
     block = 2
@@ -360,7 +297,7 @@ def test_expand_block_counters(monkeypatch, mode):
     e = stub_device_engine(cls=PagedBFS if mode == "paged" else None,
                            dead_action=True, bounds=False,
                            chunk_tiles=2)
-    r = getattr(e, "run" if mode == "paged" else mode)()
+    r = e.run()
     assert r.ok and r.distinct_states == STUB_DISTINCT
     c, g = r.metrics["counters"], r.metrics["gauges"]
     acts = g["action_expansions"]
@@ -380,16 +317,15 @@ def test_expand_block_counters(monkeypatch, mode):
 
 
 # ---------------------------------------------------------------------
-# extended cross (slow): per-action across modes x pack x K — the
-# fused half of this cross is every other module's tier-1 default
+# extended cross (slow): per-action across pack x K — the fused half
+# of this cross is every other module's tier-1 default
 # ---------------------------------------------------------------------
 @pytest.mark.slow
-@pytest.mark.parametrize("mode", ["run", "run_fused", "run_chained"])
 @pytest.mark.parametrize("pack", [True, False], ids=["pack", "dense"])
 @pytest.mark.parametrize("k", [1, 2])
-def test_per_action_cross_matches_oracle(mode, pack, k):
+def test_per_action_cross_matches_oracle(pack, k):
     e = stub_device_engine(pipeline=k, pack=("auto" if pack else False),
                            chunk_tiles=2, commit="per-action")
-    r = getattr(e, mode)()
+    r = e.run()
     assert r.ok and r.distinct_states == STUB_DISTINCT
     assert e.level_sizes == STUB_LEVELS

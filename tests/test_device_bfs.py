@@ -7,9 +7,6 @@ dedup) on small configs — the framework's analog of matching TLC's
 distinct-state counts (SURVEY.md §4.7).
 """
 
-import json
-import os
-
 import numpy as np
 import pytest
 
@@ -21,8 +18,6 @@ from tpuvsr.engine.fpset import dedup_batch, empty_table, insert_batch
 from tpuvsr.engine.spec import SpecModel
 from tpuvsr.frontend.cfg import parse_cfg_file
 from tpuvsr.frontend.parser import parse_module_file
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------
@@ -288,13 +283,6 @@ def test_checkpoint_resume_reaches_same_frontier(tmp_path):
 # trace-once stages of the level body (ISSUE 26), on the committed
 # native small check: no reference mount
 # ---------------------------------------------------------------------
-@pytest.fixture(scope="module")
-def small_native():
-    from tpuvsr.engine.spec import load_spec
-    return load_spec("VSR", os.path.join(REPO, "examples",
-                                         "VSR_small.cfg"))
-
-
 def _trace_level(eng):
     """Trace `eng`'s level program on shapes alone, under a build
     meter of its own; returns (shared calls, shared traces)."""
@@ -342,19 +330,17 @@ def test_grow_msgs_rebuilds_the_shared_stages(small_native):
 
 @pytest.mark.parametrize("hash_mode", ["incremental", "full"])
 @pytest.mark.parametrize("commit", ["fused", "per-action"])
-def test_small_check_exact_counts(small_native, commit, hash_mode):
+def test_small_check_exact_counts(small_native, small_pin, commit,
+                                  hash_mode):
     """The small check to its fixpoint under both tile bodies and both
     hash modes: the pinned level sizes, 43,941 distinct, diameter 24.
     The capacities are sized so that no buffer grows: every growth is
     one more build of the level program, most of this test's time."""
-    with open(os.path.join(REPO, "scripts",
-                           "pinned_levels_small.json")) as f:
-        pin = json.load(f)["level_sizes"]
     eng = DeviceBFS(small_native, commit=commit, hash_mode=hash_mode,
                     next_capacity=1 << 16, expand_mult=4)
     res = eng.run()
     assert res.ok and res.error is None
-    assert list(eng.level_sizes) == pin
+    assert list(eng.level_sizes) == small_pin
     assert (res.distinct_states, res.diameter) == (43941, 24)
     c = res.metrics["counters"]
     assert c["build_shared_calls"] % 38 == 0

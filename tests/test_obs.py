@@ -434,21 +434,6 @@ def test_stub_device_interp_journal_key_sets_match(tmp_path):
         assert ev in ki and ev in kd
 
 
-def test_stub_fused_run_metrics():
-    eng = _stub_device_engine()
-    res = eng.run_fused()
-    assert res.ok and res.distinct_states == 16
-    assert res.levels == [1, 2, 3, 4, 3, 2, 1]
-    doc = validate_metrics(res.metrics)
-    assert doc["engine"] == "device-fused"
-    assert doc["counters"]["dispatches"] >= 1
-    # fused records the 6 non-empty levels beyond init (the final
-    # expansion that generates nothing gets no on-device row)
-    assert len(doc["levels"]) == 6
-    assert [r["frontier"] for r in doc["levels"]] == [1, 2, 3, 4, 3, 2]
-    assert doc["levels"][-1]["distinct"] == 16
-
-
 def test_stub_paged_bfs_spill_events(tmp_path):
     from tpuvsr.engine.paged_bfs import PagedBFS
     jp = str(tmp_path / "paged.jsonl")

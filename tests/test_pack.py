@@ -136,25 +136,6 @@ def test_unpack_row_np_per_row_shapes():
         assert np.array_equal(one[k], batch[k][0]), k
 
 
-def test_fused_growth_pause_mid_level_completes():
-    """Regression: the multilevel pass's per-dispatch tile budget is
-    saturating — run_fused passes the 2^31-1 sentinel, and a growth
-    pause carried back in at start_t > 0 must not wrap t_stop int32
-    (a wrapped-negative bound made the inner loop a permanent no-op
-    and hung the fixpoint; this config pauses for FPSet AND frontier
-    growth mid-level)."""
-    from tpuvsr.engine.device_bfs import DeviceBFS
-    eng = DeviceBFS(counter_spec(),
-                    model_factory=stub_model_factory(),
-                    hash_mode="full", tile_size=1,
-                    fpset_capacity=4, next_capacity=4)
-    msgs = []
-    res = eng.run_fused(log=msgs.append)
-    assert res.ok and res.distinct_states == STUB_DISTINCT
-    assert eng.level_sizes == STUB_LEVELS
-    assert any("grown" in m for m in msgs)     # the pause path ran
-
-
 def test_manifest_roundtrip_and_tamper():
     _codec, pk = _layout_spec("VSR", max_msgs=4)
     mf = pk.manifest()
@@ -229,32 +210,6 @@ def test_device_packed_vs_dense_bit_identical():
     g = rp.metrics["gauges"]
     assert g["pack_ratio"] == 4.0          # 4 planes -> 1 word
     assert g["frontier_bytes_per_state"] == 4
-
-
-def test_fused_packed_vs_dense_bit_identical():
-    rd = stub_device_engine(pack=False).run_fused()
-    rp = stub_device_engine().run_fused()
-    assert rp.ok and _sig(rp) == _sig(rd)
-    assert rp.levels == STUB_LEVELS
-
-
-def test_chained_windows_packed_bit_identical():
-    """Cross-level chaining (ISSUE 9 lever 3): run_chained keeps the
-    K-deep window alive across level boundaries; counts/levels/action
-    counters stay bit-identical to the synchronous dense run for every
-    K, with packing on."""
-    oracle = _sig(stub_device_engine(pack=False).run())
-    for K in (1, 2, 4):
-        eng = stub_device_engine(pipeline=K, chunk_tiles=2)
-        res = eng.run_chained()
-        assert res.ok and _sig(res) == oracle, K
-    # and the chained violation trace matches the synchronous one
-    tr_oracle = _trace_sig(stub_device_engine(inv_bound=4,
-                                              pack=False).run())
-    for K in (1, 4):
-        res = stub_device_engine(inv_bound=4, pipeline=K,
-                                 chunk_tiles=2).run_chained()
-        assert not res.ok and _trace_sig(res) == tr_oracle, K
 
 
 def test_paged_packed_vs_dense_spill_schedule_identical():

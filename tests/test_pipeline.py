@@ -10,9 +10,7 @@ resume seam.  Everything runs tier-1 on the stub harness
 
 Plus the new observability surface: the ``inflight`` phase keeps the
 phase timers summing to wall-clock, the ``pipeline_depth`` /
-``overlap_saved_s`` gauges land in the metrics document, and the
-fused engine's rescue-quantum checkpoints (the -supervise -fused
-combo) resume to the exact fixpoint.
+``overlap_saved_s`` gauges land in the metrics document.
 """
 
 import json
@@ -26,8 +24,7 @@ import pytest
 from tpuvsr.obs import RunObserver, read_journal, validate_metrics
 from tpuvsr.resilience import faults
 from tpuvsr.resilience.supervisor import (Preempted, PreemptionGuard,
-                                          Supervisor, clear_preemption,
-                                          request_preemption)
+                                          Supervisor, clear_preemption)
 from tpuvsr.testing import (STUB_DISTINCT, STUB_LEVELS, counter_spec,
                             stub_device_engine, stub_engine_factory)
 
@@ -196,76 +193,6 @@ def test_run_start_journals_pipeline_depth(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# fused rescue-quantum checkpoints (the -supervise -fused combo)
-# ---------------------------------------------------------------------
-def test_fused_rescue_at_quantum_boundary_resumes_exactly(tmp_path):
-    ck = str(tmp_path / "ck")
-    jp = str(tmp_path / "j.jsonl")
-    faults.install("kill@level=3")     # fires at the depth-2 boundary
-    preempted = None
-    with PreemptionGuard():
-        try:
-            stub_device_engine().run_fused(
-                checkpoint_path=ck, rescue_quantum=2,
-                obs=RunObserver(journal_path=jp))
-        except Preempted as p:
-            preempted = p
-    faults.clear()
-    assert preempted is not None and preempted.path == ck
-    # the rescue landed at the NEXT quantum boundary after the signal
-    assert preempted.depth == 4
-    # a fused snapshot resumes through the chunked engine
-    res2 = stub_device_engine().run(resume_from=ck)
-    assert res2.ok and res2.distinct_states == STUB_DISTINCT
-    assert res2.levels == STUB_LEVELS
-    ev = [e["event"] for e in read_journal(jp)]
-    assert "rescue_checkpoint" in ev and "checkpoint" in ev
-
-
-def test_fused_preemption_before_first_boundary(tmp_path):
-    ck = str(tmp_path / "ck")
-    with PreemptionGuard():
-        request_preemption("SIGTERM")
-        with pytest.raises(Preempted) as ei:
-            stub_device_engine().run_fused(checkpoint_path=ck,
-                                           rescue_quantum=2)
-    assert os.path.isdir(ck)
-    res2 = stub_device_engine().run(resume_from=ck)
-    assert res2.ok and res2.distinct_states == STUB_DISTINCT
-    assert res2.levels == STUB_LEVELS
-    assert ei.value.depth >= 1
-
-
-def test_supervisor_fused_oom_degrades_to_chunked_resume(tmp_path):
-    spec = counter_spec()
-    # the oom fires at the depth-4 quantum boundary, AFTER that
-    # boundary's snapshot landed — the retry resumes chunked
-    faults.install("oom@level=5")
-    sup = Supervisor(spec, checkpoint_path=str(tmp_path / "ck"),
-                     engine_factory=stub_engine_factory(spec),
-                     fused=True, tile_size=4, min_tile=2,
-                     backoff_base=0.0, sleep=lambda s: None)
-    res = sup.run()
-    assert res.ok and res.distinct_states == STUB_DISTINCT
-    assert res.levels == STUB_LEVELS
-    assert sup.summary()["fused"] is True
-    assert ("mode", "fused", "chunked") in sup.degrades
-
-
-def test_supervisor_fused_clean_run_stays_fused(tmp_path):
-    spec = counter_spec()
-    sup = Supervisor(spec, checkpoint_path=str(tmp_path / "ck"),
-                     engine_factory=stub_engine_factory(spec),
-                     fused=True, tile_size=4, backoff_base=0.0,
-                     sleep=lambda s: None)
-    res = sup.run()
-    assert res.ok and res.distinct_states == STUB_DISTINCT
-    assert res.levels == STUB_LEVELS
-    assert sup.attempts == 1 and not sup.degrades
-    assert res.metrics["engine"] == "device-fused"
-
-
-# ---------------------------------------------------------------------
 # CLI flag surface
 # ---------------------------------------------------------------------
 def _cli(*argv):
@@ -281,13 +208,9 @@ def _cli(*argv):
 def test_cli_pipeline_flag_validation():
     r = _cli("spec.tla", "-pipeline", "0")
     assert r.returncode == 2
-    # -fused -checkpoint is still a conflict WITHOUT -supervise...
-    r = _cli("spec.tla", "-fused", "-checkpoint", "5")
-    assert r.returncode == 2
-    # ...but parses with it (fails later on the missing spec file, a
+    # a valid depth parses (fails later on the missing spec file, a
     # non-usage error)
-    r = _cli("/nonexistent/spec.tla", "-fused", "-checkpoint", "5",
-             "-supervise")
+    r = _cli("/nonexistent/spec.tla", "-pipeline", "2")
     assert r.returncode != 2
 
 

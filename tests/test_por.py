@@ -220,18 +220,6 @@ def test_device_reduction_verdict_and_deadlock_identity():
     assert on._por_full == POR_STUB_FULL
 
 
-def test_fused_and_chained_reduction_parity():
-    def mk():
-        return stub_device_engine(spec=counter_spec(inv_free=True),
-                                  por="on")
-    r_f = mk().run_fused()
-    r_c = mk().run_chained()
-    for r in (r_f, r_c):
-        assert r.ok
-        assert r.distinct_states == POR_STUB_DISTINCT
-        assert r.levels == POR_STUB_LEVELS
-
-
 def test_paged_reduction_parity():
     from tpuvsr.engine.paged_bfs import PagedBFS
     e = stub_device_engine(cls=PagedBFS, chunk_tiles=1,

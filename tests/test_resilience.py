@@ -665,14 +665,11 @@ def _cli(args):
     ["-supervise", "-engine", "interp"],
     ["-supervise", "-fpset", "host"],
     ["-inject", "explode@level=1"],
-    ["-engine", "sharded", "-fused"],
     ["-engine", "sharded", "-simulate"],
     ["-engine", "sharded", "-fpset", "paged"],
-    ["-supervise", "-engine", "sharded", "-fused"],
     ["-inject", "exchange-drop:x@shard=0"],
 ], ids=["simulate", "interp", "host-fpset", "bad-inject",
-        "sharded-fused", "sharded-simulate", "sharded-fpset",
-        "sharded-supervise-fused", "bad-drop-count"])
+        "sharded-simulate", "sharded-fpset", "bad-drop-count"])
 def test_cli_supervise_and_inject_flag_validation(bad):
     r = _cli(["X.tla"] + bad)
     assert r.returncode == 2, r.stderr

@@ -459,7 +459,6 @@ def _run_cli(*argv, timeout=420):
 
 @pytest.mark.parametrize("bad", [
     ["-validate", "t.jsonl", "-simulate"],
-    ["-validate", "t.jsonl", "-fused"],
     ["-validate", "t.jsonl", "-supervise"],
     ["-validate", "t.jsonl", "-deadlock"],
     ["-validate", "t.jsonl", "-maxstates", "10"],
@@ -468,7 +467,7 @@ def _run_cli(*argv, timeout=420):
     ["-validate", "t.jsonl", "-fpset", "hbm"],
     ["-batch", "64"],
     ["-validate", "t.jsonl", "-batch", "0"],
-], ids=["simulate", "fused", "supervise", "deadlock", "maxstates",
+], ids=["simulate", "supervise", "deadlock", "maxstates",
         "checkpoint", "sharded", "fpset-hbm", "batch-no-validate",
         "zero-batch"])
 def test_cli_validate_flag_conflicts_exit_2(bad):

@@ -69,20 +69,6 @@ TLC CLI that the reference's README drives (workers/simulation/depth):
                    BFS; TLC's -checkpoint)
   -checkpointdir P snapshot directory (default: <spec>.ckpt)
   -recover PATH    resume a BFS run from a snapshot (TLC's -recover)
-  -fused           device BFS: whole fixpoint in O(1) dispatches (no
-                   per-level host syncs — the remote-TPU mode; not
-                   combinable with -checkpoint/-recover or temporal
-                   properties, EXCEPT under -supervise, where each
-                   fused dispatch is bounded to a rescue quantum so
-                   level-boundary snapshots and SIGTERM rescues work;
-                   a supervised resume continues through the chunked
-                   engine)
-  -chained         device BFS: cross-level chained window
-                   (run_chained) — the dispatch window survives level
-                   boundaries; checkpointable via its level-boundary
-                   rescue seam (-checkpoint; snapshots resume through
-                   the chunked engine, so -recover needs -supervise,
-                   which journals the mode degrade)
   -commit MODE     fused | per-action (default fused): level-kernel
                    commit mode.  fused runs the occupancy-packed
                    three-stage tile pass (chunk-wide guard matrix ->
@@ -202,20 +188,16 @@ spans (view with TensorBoard / Perfetto).  TPUVSR_FAULT=SPEC arms
 fault injection (same grammar as -inject).
 
 Mutually exclusive flags (argparse errors, exit code 2, before any
-spec is loaded): -fused with -checkpoint/-recover (unless -supervise,
-whose rescue quantum makes fused snapshots possible); -fpset host with
--engine device; -fpset hbm/paged with -engine interp; -supervise with
--simulate/-engine interp/-fpset host; -engine sharded with
--simulate/-fused (the sharded engine has no fused fixpoint) or any
-non-auto -fpset (its fingerprint set is always the mesh-sharded HBM
-table); -walkers/-split/-hunt without -simulate, or with
+spec is loaded): -fpset host with -engine device; -fpset hbm/paged
+with -engine interp; -supervise with -simulate/-engine interp/
+-fpset host; -engine sharded with -simulate or any non-auto -fpset
+(its fingerprint set is always the mesh-sharded HBM table);
+-walkers/-split/-hunt without -simulate, or with
 -engine interp/-fpset host (the fleet is a device backend);
 explicit -pack on with -engine interp/-fpset host (the packed
 frontier is a device-engine format; the interpreter has no dense
-frontier to pack); -chained with -fused/-engine sharded/-engine
-interp/-fpset host/-simulate/-validate, or with -recover unless
--supervise (the chained window has no resume path of its own);
-explicit -commit with -engine interp/-fpset host/-simulate/-validate
+frontier to pack); explicit -commit with -engine interp/
+-fpset host/-simulate/-validate
 (it configures the BFS level kernel); explicit -symmetry with
 -engine interp/-fpset host (the interpreter always applies the
 declared SYMMETRY itself) and -symmetry on with -validate (trace
@@ -231,7 +213,7 @@ bounds facts — a forced flag must not be silently inert);
 -validate/-edges on/-commit per-action, or a PROPERTY cfg (the
 ample-set reduction preserves invariant/deadlock verdicts, not the
 behavior graph — the cfg conflict is checked after it loads);
--validate with -simulate/-hunt/-fused/-supervise/-deadlock/
+-validate with -simulate/-hunt/-supervise/-deadlock/
 -maxstates/-checkpoint/-engine sharded/-fpset hbm|paged (validation
 is its own engine mode: rescue checkpoints are preemption-driven, the
 batch validator owns its mesh, and traces have no deadlock notion);
@@ -337,18 +319,6 @@ def build_parser():
     p.add_argument("-recover", default=None, metavar="PATH")
     p.add_argument("-json", action="store_true")
     p.add_argument("-maxseconds", type=float, default=None)
-    p.add_argument("-fused", action="store_true",
-                   help="device engine: run the whole fixpoint in O(1)"
-                        " dispatches (no per-level host syncs; remote-"
-                        "TPU mode; excludes -checkpoint/-recover "
-                        "unless -supervise)")
-    p.add_argument("-chained", action="store_true",
-                   help="device engine: cross-level chained window "
-                        "(run_chained) — the dispatch window survives "
-                        "level boundaries; now checkpointable via its "
-                        "level-boundary rescue seam (-checkpoint; a "
-                        "snapshot resumes through the chunked engine, "
-                        "so -recover needs -supervise)")
     p.add_argument("-commit", choices=["fused", "per-action"],
                    default=None, metavar="MODE",
                    help="level-kernel commit mode (default fused): "
@@ -470,34 +440,8 @@ def validate_args(parser, args):
     """Flag-conflict validation at parse time: documented mutual
     exclusions fail with argparse's usage error (exit code 2) instead
     of a late engine failure."""
-    if args.fused and not args.supervise and (
-            args.checkpoint is not None or args.recover):
-        parser.error("-fused cannot be combined with "
-                     "-checkpoint/-recover without -supervise (only "
-                     "the supervised fused run bounds its dispatch to "
-                     "a rescue quantum; a fused resume continues "
-                     "through the chunked engine)")
     if args.pipeline is not None and args.pipeline < 1:
         parser.error(f"-pipeline must be >= 1 (got {args.pipeline})")
-    if args.chained:
-        if args.fused:
-            parser.error("-chained and -fused are different device "
-                         "dispatch modes; pick one")
-        if args.engine == "sharded":
-            parser.error("-chained is the device engine's cross-level "
-                         "window; the sharded engine's per-level "
-                         "exchange needs the host in the loop")
-        if args.engine == "interp" or args.fpset == "host":
-            parser.error("-chained needs the device engine")
-        if args.simulate or args.validate is not None:
-            parser.error("-chained configures the BFS dispatch "
-                         "window; it cannot be combined with "
-                         "-simulate/-validate")
-        if args.recover and not args.supervise:
-            parser.error("-chained has no resume path (its snapshots "
-                         "resume through the chunked engine): combine "
-                         "-recover with -supervise, which journals "
-                         "the mode degrade, or drop -chained")
     if args.commit is not None:
         if args.engine == "interp" or args.fpset == "host":
             parser.error("-commit configures the device level kernel; "
@@ -517,11 +461,6 @@ def validate_args(parser, args):
         if args.simulate:
             parser.error("-engine sharded checks by BFS; simulation "
                          "runs on the device/interp engines")
-        if args.fused:
-            parser.error("-engine sharded cannot be combined with "
-                         "-fused (the sharded engine has no fused "
-                         "fixpoint; its per-level exchange needs the "
-                         "host in the loop)")
         if args.fpset != "auto":
             parser.error(f"-engine sharded always uses the "
                          f"mesh-sharded HBM fingerprint set; it "
@@ -667,10 +606,6 @@ def validate_args(parser, args):
             parser.error("-walkers/-split/-hunt configure the "
                          "simulation fleet; they cannot be combined "
                          "with -validate")
-        if args.fused:
-            parser.error("-validate has no fused fixpoint (its chunk "
-                         "loop needs the host to commit divergences); "
-                         "it cannot be combined with -fused")
         if args.supervise:
             parser.error("-validate runs its own rescue/resume and "
                          "OOM batch-halving ladder; it cannot be "
@@ -1089,13 +1024,6 @@ def main(argv=None):
                                       if args.checkpoint else None),
                     journal_path=args.journal,
                     metrics_path=args.metrics, log=log,
-                    # -fused under -supervise: rescue-quantum-bounded
-                    # fused dispatches; resume continues chunked.
-                    # -chained likewise: the chained window's
-                    # level-boundary rescue seam checkpoints, resume
-                    # continues chunked (journaled mode degrade)
-                    fused=args.fused and engine == "device",
-                    chained=args.chained and engine == "device",
                     engine_kwargs={"pipeline": args.pipeline,
                                    "pack": pack_kw,
                                    "commit": commit_kw,
@@ -1170,59 +1098,22 @@ def main(argv=None):
                                     pack=pack_kw, commit=commit_kw,
                                     symmetry=symmetry_kw,
                                     bounds=bounds_kw, por=por_kw)
-                use_fused = (args.fused and isinstance(eng, DeviceBFS)
-                             and not isinstance(eng, PagedBFS))
-                if args.fused and not use_fused:
-                    log("-fused needs the plain device engine (no "
-                        "temporal properties / -fpset paged); using "
-                        "chunked run")
-                if use_fused and (args.checkpoint or args.recover):
-                    log("-fused excludes -checkpoint/-recover; "
-                        "using chunked run")
-                    use_fused = False
-                use_chained = (args.chained
-                               and isinstance(eng, DeviceBFS)
-                               and not isinstance(eng, PagedBFS))
-                if args.chained and not use_chained:
-                    log("-chained needs the plain device engine (no "
-                        "temporal properties / -fpset paged); using "
-                        "chunked run")
-                if use_fused:
-                    res = eng.run_fused(
-                        max_states=args.maxstates,
-                        max_seconds=args.maxseconds,
-                        check_deadlock=args.deadlock, log=log, obs=obs)
-                elif use_chained:
-                    # the chained window is checkpointable through its
-                    # level-boundary rescue seam (ISSUE 10 satellite)
-                    # — no more silent fallback to run() for
-                    # checkpointed runs
-                    res = eng.run_chained(
-                        max_states=args.maxstates,
-                        max_seconds=args.maxseconds,
-                        check_deadlock=args.deadlock, log=log, obs=obs,
-                        checkpoint_path=(ckpt_dir if args.checkpoint
-                                         else None),
-                        checkpoint_every=(args.checkpoint * 60.0
-                                          if args.checkpoint
-                                          else None))
-                else:
-                    res = eng.run(
-                        max_states=args.maxstates,
-                        max_seconds=args.maxseconds,
-                        check_deadlock=args.deadlock, log=log, obs=obs,
-                        checkpoint_path=(ckpt_dir if args.checkpoint or
-                                         args.recover else None),
-                        # checkpoint_every=None means "every level
-                        # boundary"; a resumed run without an explicit
-                        # -checkpoint gets TLC's default 30-minute
-                        # cadence instead of an unrequested full
-                        # snapshot per level
-                        checkpoint_every=(args.checkpoint * 60.0
-                                          if args.checkpoint else
-                                          30 * 60.0 if args.recover
-                                          else None),
-                        resume_from=args.recover)
+                res = eng.run(
+                    max_states=args.maxstates,
+                    max_seconds=args.maxseconds,
+                    check_deadlock=args.deadlock, log=log, obs=obs,
+                    checkpoint_path=(ckpt_dir if args.checkpoint or
+                                     args.recover else None),
+                    # checkpoint_every=None means "every level
+                    # boundary"; a resumed run without an explicit
+                    # -checkpoint gets TLC's default 30-minute
+                    # cadence instead of an unrequested full
+                    # snapshot per level
+                    checkpoint_every=(args.checkpoint * 60.0
+                                      if args.checkpoint else
+                                      30 * 60.0 if args.recover
+                                      else None),
+                    resume_from=args.recover)
         else:
             if args.checkpoint or args.recover:
                 log("checkpoint/recover is a device-engine feature; "

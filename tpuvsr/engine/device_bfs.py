@@ -479,18 +479,15 @@ class DeviceBFS:
             "fingerprint")
         self._inv_stage = trace_once(self._inv, "invariants")
         self._expand_stages = {}    # (action, block rows) -> stage
-        self._level_jit = None  # chunked pass, built lazily (_level)
-        self._ml = None         # fused pass, built lazily (run_fused)
-        self._wl = None         # chained window pass (run_chained)
+        self._level_jit = None  # the level pass, built lazily (_level)
         # obs accounting: the first dispatch after a (re)jit is charged
         # to the "compile" phase (jit traces+compiles at first call)
         self._fresh_jit = True
 
     @property
     def _level(self):
-        """The jitted chunked level pass, built at its first use: in a
-        run, so the run's observer meters the build, and not at all
-        for an engine that only runs the fused or chained pass."""
+        """The jitted level pass, built at its first use: in a run, so
+        the run's observer meters the build."""
         if self._level_jit is None:
             self._level_jit = jax.jit(self._make_level(),
                                       donate_argnums=(0, 4, 5, 6, 7, 10))
@@ -550,8 +547,7 @@ class DeviceBFS:
         action's guard over a dense state batch in one vmapped sweep —
         returns the per-action [B, L_a] enabled matrices.  Applied
         chunk-wide by _make_level (exact per-action counts for the
-        whole chunk of tiles) and tile-wide inside the multilevel
-        body."""
+        whole chunk of tiles)."""
         guards = kern._guard_fns()
 
         def mat(batch):
@@ -601,15 +597,14 @@ class DeviceBFS:
         return one
 
     def _tile_body_factory(self):
-        """Build the one-tile expansion body shared by the chunked
-        level pass (_make_level) and the fused multi-level pass
-        (_make_multilevel).  Returns (caps, total_E, make_body) where
-        make_body(frontier, n_front, want_deadlock, chunk_ctx=None)
-        closes over the (possibly traced) frontier and count;
-        ``chunk_ctx`` optionally feeds the body a chunk-wide
-        precomputed (dense states, guard matrix, start tile) so the
-        fused body consumes the hoisted stage-1 pass instead of
-        re-deriving it per tile.
+        """Build the one-tile expansion body of the level pass
+        (_make_level, its one caller).  Returns (caps, total_E,
+        make_body) where make_body(frontier, n_front, want_deadlock,
+        chunk_ctx, edge_bases, pdepth) closes over the traced frontier
+        and count; ``chunk_ctx`` is the chunk-wide precomputed (dense
+        states, guard matrix, start tile) of the hoisted stage-1 pass
+        that the fused body slices its tile from (None under
+        per-action commit, whose body derives its own).
 
         Packed frontier (ISSUE 9): with a pack spec bound, the at-rest
         frontier and next buffers are ``[cap, words]`` uint32 planes —
@@ -632,11 +627,12 @@ class DeviceBFS:
         aid_q_pa = jnp.asarray(np.repeat(
             np.arange(len(caps), dtype=np.int32), caps))
 
-        def make_body(frontier, n_front, want_deadlock, chunk_ctx=None,
-                      edge_bases=None, pdepth=None):
-            # pdepth is the fused commit's POR level marker; POR is a
-            # resolve_por blocker under per-action commit, so it is
-            # accepted here only for the shared launcher signature
+        def make_body(frontier, n_front, want_deadlock, chunk_ctx,
+                      edge_bases, pdepth):
+            # chunk_ctx and pdepth (the fused commit's POR level
+            # marker; POR is a resolve_por blocker under per-action
+            # commit) are accepted here only for the signature the two
+            # bodies share
             F_cap = (frontier.shape[0] if pk is not None
                      else frontier["status"].shape[0])
 
@@ -899,7 +895,6 @@ class DeviceBFS:
         caps_v = jnp.asarray(caps, I32)
         aid_q = jnp.asarray(np.repeat(np.arange(n_act, dtype=np.int32),
                                       caps))
-        guard_mat = self._guard_matrix(kern)
         edges_on = self._edges_on
         if pk is not None:
             row = jax.eval_shape(pk.unpack, jax.ShapeDtypeStruct(
@@ -925,34 +920,24 @@ class DeviceBFS:
             amat_dev = jnp.asarray(self._por.amat)
             qoff = np.concatenate(([0], np.cumsum(caps))).astype(int)
 
-        def make_body(frontier, n_front, want_deadlock, chunk_ctx=None,
-                      edge_bases=None, pdepth=None):
-            F_cap = (frontier.shape[0] if pk is not None
-                     else frontier["status"].shape[0])
+        def make_body(frontier, n_front, want_deadlock, chunk_ctx,
+                      edge_bases, pdepth):
+            # the tile's rows come out of the chunk's unpacked states:
+            # this body never reads `frontier` itself
+            cstates, csegs, c_start = chunk_ctx
 
             def body(c):
                 t = c["t"]
                 base = t * T
                 sidx = base + jnp.arange(T, dtype=I32)
                 valid = sidx < n_front
-                if chunk_ctx is not None:
-                    cstates, csegs, c_start = chunk_ctx
-                    off = (t - c_start) * T
-                    with jax.named_scope(spans.GUARD_MATRIX):
-                        tile = {k: jax.lax.dynamic_slice_in_dim(v, off, T)
-                                for k, v in cstates.items()}
-                        en_segs = [
-                            jax.lax.dynamic_slice_in_dim(s, off, T)
-                            for s in csegs]
-                else:
-                    with jax.named_scope(spans.PACK_SCATTER):
-                        if pk is not None:
-                            tile = jax.vmap(pk.unpack)(
-                                frontier[jnp.clip(sidx, 0, F_cap - 1)])
-                        else:
-                            tile = {k: v[jnp.clip(sidx, 0, F_cap - 1)]
-                                    for k, v in frontier.items()}
-                    en_segs = guard_mat(tile)
+                off = (t - c_start) * T
+                with jax.named_scope(spans.GUARD_MATRIX):
+                    tile = {k: jax.lax.dynamic_slice_in_dim(v, off, T)
+                            for k, v in cstates.items()}
+                    en_segs = [
+                        jax.lax.dynamic_slice_in_dim(s, off, T)
+                        for s in csegs]
                 # -- stage 1: guard matrix -> exact per-action counts --
                 with jax.named_scope(spans.GUARD_MATRIX):
                     en_segs = [e & valid[:, None] for e in en_segs]
@@ -1360,201 +1345,6 @@ class DeviceBFS:
 
         return level
 
-    def _make_multilevel(self):
-        """The fused pass: an OUTER device while_loop over whole BFS
-        levels (ping-pong frontier buffers, on-device trace-pointer and
-        level-size accumulation), so a run to fixpoint is ONE dispatch
-        with zero per-level host syncs — where a host round-trip is
-        slow the per-level round-trips are the whole runtime.  Pause protocol is unchanged: growth events exit the
-        outer loop with (start_t, nn, gen_level) preserved so the host
-        grows the structure and re-enters mid-level."""
-        if self._edges_on:
-            raise TLAError(
-                "edge emission needs the host in the loop to drain "
-                "the append buffer into the CSR builder; the fused/"
-                "chained multilevel passes cannot stream edges — run "
-                "the chunked paged engine")
-        T = self.tile
-        _caps, _tot, make_body = self._tile_body_factory()
-        por_active = self._por_active
-
-        def multilevel(slots, front, nb, nbp, nba, nbprm,
-                       tpp, tpa, tpm, lvl_buf,
-                       n_front, start_t, nn0, gen_level0, depth0,
-                       level_base0, fp_count0,
-                       want_deadlock, max_depth, max_states, max_lvls,
-                       tiles0, tile_budget,
-                       gids=None, gfull_level0=None, amp_level0=None):
-            F_cap = nbp.shape[0]
-            TP_CAP = tpp.shape[0]
-            LVL_CAP = lvl_buf.shape[0]
-            # max_lvls (traced, <= LVL_CAP) bounds levels per dispatch
-            # so the host can check wall-clock budgets between
-            # dispatches without recompiling.  tile_budget (traced)
-            # bounds the COMMITTED TILES per dispatch instead — the
-            # cross-level chaining mode (run_chained, ISSUE 9) gives
-            # each dispatch a chunk-sized budget and keeps a K-deep
-            # window of them in flight; the fused mode passes 2^31-1
-            # so its behavior is unchanged.  A budget boundary can land
-            # MID-LEVEL (start_t/nn/gen_level carry the partial level,
-            # exactly like a growth pause), so the window never drains
-            # at a level transition.
-            idx = jnp.arange(F_cap, dtype=I32)
-
-            def ocond(c):
-                return ((c["reason"] == RUNNING) & (c["n_front"] > 0)
-                        & (c["depth"] < max_depth)
-                        & (c["fp_count"] < max_states)
-                        & (c["lvl_cur"] < max_lvls)
-                        & (c["tiles"] < tile_budget)
-                        & (c["level_base"] + c["n_front"] + F_cap
-                           <= TP_CAP))
-
-            def obody(c):
-                n_front_l = c["n_front"]
-                n_tiles = (n_front_l + T - 1) // T
-                # POR C3: the frontier being expanded sits at level
-                # c["depth"], which is exactly the marker threshold
-                body = make_body(c["front"], n_front_l, want_deadlock,
-                                 pdepth=c["depth"] if por_active
-                                 else None)
-                # remaining per-dispatch tile budget, as an inner
-                # bound.  Saturated: the fused mode's 2^31-1 sentinel
-                # budget added to a carried start_t > 0 (a re-entry
-                # after a mid-level growth pause) must not wrap int32
-                # — a wrapped-negative t_stop would make the inner
-                # loop a permanent no-op and hang the outer fixpoint
-                t_stop = c["start_t"] + jnp.minimum(
-                    tile_budget - c["tiles"], jnp.int32(1 << 30))
-
-                def icond(ic):
-                    return ((ic["t"] < n_tiles) & (ic["t"] < t_stop)
-                            & (ic["reason"] == RUNNING))
-
-                iinit = {
-                    "t": c["start_t"],
-                    "reason": jnp.asarray(RUNNING, I32),
-                    "viol": jnp.full((3,), -1, I32),
-                    "dead": jnp.asarray(-1, I32),
-                    "grow_aid": jnp.asarray(-1, I32),
-                    "need": c["need"],
-                    "slots": c["slots"],
-                    "nb": c["nb"], "nbp": c["nbp"], "nba": c["nba"],
-                    "nbprm": c["nbprm"],
-                    "nn": c["nn"],
-                    "dist": jnp.asarray(0, I32),
-                    "gen": c["gen_level"],
-                    "act": c["act"],
-                    "blk": c["blk"],
-                }
-                if por_active:
-                    iinit["gids"] = c["gids"]
-                    iinit["gfull"] = c["gfull_level"]
-                    iinit["amp"] = c["amp_level"]
-                r = jax.lax.while_loop(icond, body, iinit)
-                # level committed only when every tile ran; a budget
-                # stop mid-level exits the outer loop with the partial
-                # (start_t, nn, gen_level) carried — no swap
-                committed = (r["reason"] == RUNNING) & (r["t"] >= n_tiles)
-                n_next = r["nn"]
-                # gids of the completed level start right after the
-                # current frontier's; stable across pause/resume since
-                # nn persists
-                dest_base = c["level_base"] + n_front_l
-
-                live = committed & (idx < n_next)
-                sdest = jnp.where(live, dest_base + idx, TP_CAP)
-                tpp = c["tpp"].at[sdest].set(
-                    r["nbp"] + c["level_base"], mode="drop")
-                tpa = c["tpa"].at[sdest].set(r["nba"], mode="drop")
-                tpm = c["tpm"].at[sdest].set(r["nbprm"], mode="drop")
-                # record only non-empty levels (run() parity: the final
-                # expansion that generates nothing is counted in depth
-                # but never appended to level_sizes)
-                record = committed & (n_next > 0)
-                lvl_buf = c["lvl_buf"].at[
-                    jnp.where(record, c["lvl_cur"], LVL_CAP)
-                ].set(n_next, mode="drop")
-
-                # ping-pong: the completed level's buffer becomes the
-                # frontier, the old frontier becomes scratch
-                swap = committed
-                front = jax.tree_util.tree_map(
-                    lambda a, b: jnp.where(swap, a, b),
-                    r["nb"], c["front"])
-                nb = jax.tree_util.tree_map(
-                    lambda a, b: jnp.where(swap, a, b),
-                    c["front"], r["nb"])
-                ext = {}
-                if por_active:
-                    # gfull/amp mirror gen's swap discipline: the
-                    # completed level's deltas fold into the dispatch
-                    # totals, a partial level rides the *_level carry
-                    ext = {
-                        "gids": r["gids"],
-                        "gfull_level": jnp.where(swap, 0, r["gfull"]),
-                        "gfull": c["gfull"] + jnp.where(
-                            swap, r["gfull"], 0),
-                        "amp_level": jnp.where(swap, 0, r["amp"]),
-                        "amp": c["amp"] + jnp.where(swap, r["amp"], 0),
-                    }
-                return {
-                    **ext,
-                    "slots": r["slots"],
-                    "front": front, "nb": nb,
-                    "nbp": r["nbp"], "nba": r["nba"],
-                    "nbprm": r["nbprm"],
-                    "tpp": tpp, "tpa": tpa, "tpm": tpm,
-                    "lvl_buf": lvl_buf,
-                    "n_front": jnp.where(swap, n_next, n_front_l),
-                    "start_t": jnp.where(swap, 0, r["t"]),
-                    "nn": jnp.where(swap, 0, n_next),
-                    "gen_level": jnp.where(swap, 0, r["gen"]),
-                    "gen": c["gen"] + jnp.where(swap, r["gen"], 0),
-                    "depth": c["depth"] + jnp.where(swap, 1, 0),
-                    "level_base": jnp.where(swap, dest_base,
-                                            c["level_base"]),
-                    "fp_count": c["fp_count"] + r["dist"],
-                    "lvl_cur": c["lvl_cur"] + jnp.where(record, 1, 0),
-                    "tiles": c["tiles"] + (r["t"] - c["start_t"]),
-                    "reason": r["reason"],
-                    "viol": r["viol"], "dead": r["dead"],
-                    "grow_aid": r["grow_aid"], "need": r["need"],
-                    "act": r["act"], "blk": r["blk"],
-                }
-
-            init = {
-                "slots": slots, "front": front, "nb": nb,
-                "nbp": nbp, "nba": nba, "nbprm": nbprm,
-                "tpp": tpp, "tpa": tpa, "tpm": tpm, "lvl_buf": lvl_buf,
-                "n_front": jnp.asarray(n_front, I32),
-                "start_t": jnp.asarray(start_t, I32),
-                "nn": jnp.asarray(nn0, I32),
-                "gen_level": jnp.asarray(gen_level0, I32),
-                "gen": jnp.asarray(0, I32),
-                "depth": jnp.asarray(depth0, I32),
-                "level_base": jnp.asarray(level_base0, I32),
-                "fp_count": jnp.asarray(fp_count0, I32),
-                "lvl_cur": jnp.asarray(0, I32),
-                "tiles": jnp.asarray(tiles0, I32),
-                "reason": jnp.asarray(RUNNING, I32),
-                "viol": jnp.full((3,), -1, I32),
-                "dead": jnp.asarray(-1, I32),
-                "grow_aid": jnp.asarray(-1, I32),
-                "need": jnp.zeros((len(_caps),), jnp.uint32),
-                "act": jnp.zeros((len(_caps),), jnp.uint32),
-                "blk": jnp.zeros((len(_caps),), jnp.uint32),
-            }
-            if por_active:
-                init["gids"] = gids
-                init["gfull_level"] = jnp.asarray(gfull_level0, I32)
-                init["gfull"] = jnp.asarray(0, I32)
-                init["amp_level"] = jnp.asarray(amp_level0, I32)
-                init["amp"] = jnp.asarray(0, I32)
-            return jax.lax.while_loop(ocond, obody, init)
-
-        return multilevel
-
     # ------------------------------------------------------------------
     # growth handlers
     # ------------------------------------------------------------------
@@ -1611,11 +1401,11 @@ class DeviceBFS:
                 self._need_seen, np.asarray(need, np.int64))
 
     def _grow_expand(self, aid, obs, emit):
-        """R_EXPAND_GROW handler shared by the chunked/fused/chained
-        (and paged) loops.  Fused commit: the chunk-wide guard matrix
-        already measured the true per-action maxima, so one recompile
-        re-caps EVERY action with headroom (``grown_caps``) instead of
-        one doubling guess per tile.
+        """R_EXPAND_GROW handler shared by `run` and the paged loop.
+        Fused commit: the chunk-wide guard matrix already measured the
+        true per-action maxima, so one recompile re-caps EVERY action
+        with headroom (``grown_caps``) instead of one doubling guess
+        per tile.
         Per-action commit: the historical doubling of the overflowing
         action's tile multiplier."""
         kern = self.kern
@@ -1647,8 +1437,6 @@ class DeviceBFS:
             emit(f"expand buffer for {kern.action_names[aid]} grown "
                  f"to tile x {self.expand_mults[aid]} (recompiling)")
         self._level_jit = None
-        self._ml = None
-        self._wl = None
         self._fresh_jit = True
 
     def _calibrate_caps(self, obs, emit, level_states):
@@ -1680,8 +1468,6 @@ class DeviceBFS:
             return False
         self.expand_caps = tgt
         self._level_jit = None
-        self._ml = None
-        self._wl = None
         self._fresh_jit = True
         obs.grow("expand_calibrate", sum(tgt))
         emit(f"expand caps calibrated to {CAP_HEADROOM}x the exact chunk "
@@ -1922,10 +1708,9 @@ class DeviceBFS:
     def _register_init(self, res):
         """Encode, dedup, and FPSet-register the initial states; seed
         the host pointer store and check invariants on them (shared by
-        run() and run_fused() — the two must stay observationally
-        identical).  Returns (table, init_batch, n0, viol_index);
-        viol_index is non-None when an init state violates, with
-        res.trace already built."""
+        run() and PagedBFS.run()).  Returns (table, init_batch, n0,
+        viol_index); viol_index is non-None when an init state
+        violates, with res.trace already built."""
         spec, codec = self.spec, self.codec
         table = empty_table(self.fpset_capacity)
         init_states = list(spec.init_states())
@@ -2381,749 +2166,6 @@ class DeviceBFS:
                     f"[{int(vals.min())}, {int(vals.max())}] at depth "
                     f"{depth}, outside the derived range [{lo}, {hi}] "
                     f"(TPUVSR_DEBUG_NANS width assertion)")
-
-    # ------------------------------------------------------------------
-    # fused run: whole fixpoint in O(1) dispatches
-    # ------------------------------------------------------------------
-    @closes_observer
-    def run_fused(self, max_states=None, max_depth=None,
-                  max_seconds=None, check_deadlock=False, log=None,
-                  levels_per_dispatch=256, checkpoint_path=None,
-                  checkpoint_every=None, rescue_quantum=8,
-                  obs=None) -> CheckResult:
-        """Like run(), but through the fused multi-level pass
-        (_make_multilevel): the whole reachable space is explored in a
-        handful of dispatches (one, absent growth pauses), eliminating
-        the per-level host round-trips that dominate on a remote TPU.
-        Trace pointers and level sizes accumulate on device and are
-        pulled once at the end.
-
-        With ``checkpoint_path`` (the supervised mode, ISSUE 4
-        satellite) each dispatch is bounded to a ``rescue_quantum``
-        level quantum so the host regains control at level boundaries:
-        run()-format snapshots are written there (every boundary, or on
-        the ``checkpoint_every`` cadence), and a pending SIGTERM/SIGINT
-        (PreemptionGuard) turns into a rescue snapshot + ``Preempted``
-        exactly like the chunked engine.  The snapshot resumes through
-        ``run()`` — the fused pass itself has no resume path."""
-        from ..analysis import preflight
-        preflight(self.spec, log=log)   # fail fast, before any dispatch
-        obs = RunObserver.ensure(obs, "device-fused", self.spec, log=log)
-        obs.pipeline = 1                # one fused dispatch in flight
-        obs.pack = self._pk is not None
-        obs.commit = self.commit
-        obs.symmetry = self._symmetry_on()
-        obs.bounds = self._bounds_doc()
-        obs.edges = self._edges_on
-        obs.por = self._por_doc()
-        obs.gauge("pipeline_depth", 1)
-        self._obs_active = obs          # closes_observer finalizes it
-        spec, codec = self.spec, self.codec
-        self._reset_accounting()
-        self._por_kept = self._por_full = self._por_amp = 0
-        res = CheckResult()
-        t0 = time.time()
-        obs.start(t0, backend=jax.default_backend())
-        emit = obs.log
-
-        fp_cap = self.fpset_capacity
-        self.level_sizes = []      # no stale trajectory on init-viol
-        with obs.span(spans.INIT):
-            table, init_batch, n0, viol = self._register_init(res)
-            if viol is None:
-                # ping-pong buffers share one capacity in fused mode
-                f_cap = max(self.next_cap, n0)
-                front, nbp, nba, nbprm = self._alloc_bufs(f_cap)
-                front = self._set_rows(front, init_batch, n0)
-                nb, _, _, _ = self._alloc_bufs(f_cap)
-                tp_cap = max(4 * f_cap, 1 << 16)
-                tpp = jnp.full((tp_cap,), -1, I32)
-                tpa = jnp.full((tp_cap,), -1, I32)
-                tpm = jnp.zeros((tp_cap,), I32)
-                lvl_buf = jnp.zeros((levels_per_dispatch,), I32)
-        if viol is not None:
-            return self._finish(res, obs, n0, table=table, fp_cap=fp_cap)
-
-        # run() parity on the limit conventions: max_depth=0 is a real
-        # limit there (`is not None` — stops before the first level)
-        # while max_states=0 means unlimited (`if max_states and ...`);
-        # md/ms must encode the SAME semantics the host checks below
-        # use, or a md=0 run explores a whole dispatch quantum before
-        # the host notices (ADVICE r4)
-        md = 2**31 - 1 if max_depth is None else int(max_depth)
-        ms = int(max_states) if max_states else 2**31 - 1
-        n_front, start_t, nn, gen_level = n0, 0, 0, 0
-        depth, level_base, fp_count = 0, 0, n0
-        por_on = self._por_active
-        gfull_level, amp_level = 0, 0
-        self.level_sizes = [n0]
-        last_checkpoint = time.time()
-        # adaptive dispatch quantum: small first dispatches give the
-        # host early wall-clock checkpoints for max_seconds, growing
-        # toward levels_per_dispatch so steady state stays O(1)
-        # dispatches (on a remote TPU the extra early syncs are noise).
-        # A checkpointing (supervised) run stays bounded at
-        # rescue_quantum so a preemption is never more than that many
-        # levels away from a rescue boundary.
-        q_cap = (min(levels_per_dispatch, max(1, int(rescue_quantum)))
-                 if checkpoint_path else levels_per_dispatch)
-        quantum = min(4, q_cap) if (max_seconds or checkpoint_path) \
-            else levels_per_dispatch
-
-        def set_pointers(n):
-            self._h_parent = [np.asarray(tpp[:n]).astype(np.int64)]
-            self._h_action = [np.asarray(tpa[:n])]
-            self._h_param = [np.asarray(tpm[:n])]
-
-        while True:
-            fresh = self._fresh_jit or self._ml is None
-            if self._ml is None:
-                self._ml = jax.jit(self._make_multilevel(),
-                                   donate_argnums=tuple(range(10)))
-            with obs.span(spans.build_phase(fresh), depth=depth):
-                out = self._ml(
-                    table["slots"], front, nb, nbp, nba, nbprm,
-                    tpp, tpa, tpm, lvl_buf,
-                    jnp.asarray(n_front, I32), jnp.asarray(start_t, I32),
-                    jnp.asarray(nn, I32), jnp.asarray(gen_level, I32),
-                    jnp.asarray(depth, I32), jnp.asarray(level_base, I32),
-                    jnp.asarray(fp_count, I32),
-                    jnp.asarray(bool(check_deadlock)),
-                    jnp.asarray(md, I32), jnp.asarray(ms, I32),
-                    jnp.asarray(min(quantum, levels_per_dispatch), I32),
-                    jnp.asarray(0, I32),
-                    jnp.asarray(2**31 - 1, I32),
-                    *((table["gids"], jnp.asarray(gfull_level, I32),
-                       jnp.asarray(amp_level, I32)) if por_on else ()))
-                out["reason"].block_until_ready()
-            self._fresh_jit = False
-            obs.count("dispatches")
-            quantum = min(quantum * 4, q_cap)
-            table = {"slots": out["slots"]}
-            if por_on:
-                table["gids"] = out["gids"]
-            front, nb = out["front"], out["nb"]
-            nbp, nba, nbprm = out["nbp"], out["nba"], out["nbprm"]
-            tpp, tpa, tpm = out["tpp"], out["tpa"], out["tpm"]
-            lvl_buf = out["lvl_buf"]
-            with obs.span(spans.HOST_SYNC):
-                sc = jax.device_get(
-                    [out[k] for k in ("reason", "n_front", "start_t",
-                                      "nn", "gen_level", "gen", "depth",
-                                      "level_base", "fp_count",
-                                      "lvl_cur", "act", "tiles",
-                                      "need", "blk")]
-                    + ([out[k] for k in ("gfull", "gfull_level",
-                                         "amp", "amp_level")]
-                       if por_on else []))
-            (reason, n_front, start_t, nn, gen_level, gen_add, depth,
-             level_base, fp_count, lvl_cur) = (int(x) for x in sc[:10])
-            self._act_counts += np.asarray(sc[10], np.int64)
-            self._account_tiles(int(sc[11]))
-            self._fold_need(sc[12])
-            self._account_blocks(sc[13])
-            if por_on:
-                self._por_kept += gen_add
-                self._por_full += int(sc[14])
-                self._por_amp += int(sc[16])
-                gfull_level, amp_level = int(sc[15]), int(sc[17])
-            res.states_generated += gen_add
-            if lvl_cur:
-                # level boundaries inside one dispatch share its
-                # host-side timestamp and generated count (the device
-                # never synced mid-dispatch) — documented in SCHEMA.md
-                cum = sum(self.level_sizes)
-                for x in np.asarray(lvl_buf[:lvl_cur]):
-                    prev = self.level_sizes[-1]
-                    self.level_sizes.append(int(x))
-                    cum += int(x)
-                    obs.level_done(len(self.level_sizes) - 1,
-                                   frontier=prev, distinct=cum,
-                                   generated=res.states_generated)
-            obs.progress(depth=depth, distinct=fp_count,
-                         generated=res.states_generated, force=True)
-
-            if reason == RUNNING:
-                if n_front == 0:
-                    break                           # fixpoint
-                if max_depth is not None and depth >= max_depth:
-                    res.error = f"depth limit {max_depth} reached"
-                    break
-                if max_states and fp_count >= max_states:
-                    res.error = f"state limit {max_states} reached"
-                    break
-                if max_seconds and time.time() - t0 > max_seconds:
-                    res.error = f"time budget {max_seconds}s reached"
-                    break
-                # quantum boundary == level boundary (ocond only exits
-                # between levels): rescue/cadence checkpoint first
-                # (ISSUE 4 satellite — the fused fixpoint is
-                # preemption-safe under -supervise), then the level
-                # fault hook for the next quantum's first level —
-                # mirroring the chunked engine's checkpoint-then-
-                # fault chronology so a fault always finds the
-                # freshest snapshot behind it.  The preemption flag is
-                # polled regardless of checkpoint_path (chunked-run
-                # parity: a guard-caught SIGTERM must never be
-                # silently swallowed — Preempted's message reports the
-                # missing snapshot)
-                rescue = preempt_signal()
-                if checkpoint_path and (
-                        rescue is not None
-                        or checkpoint_every is None
-                        or time.time() - last_checkpoint
-                        >= checkpoint_every):
-                    from .checkpoint import save_checkpoint, spec_digest
-                    with obs.span(spans.CHECKPOINT, depth=depth):
-                        set_pointers(level_base + n_front)
-                        save_checkpoint(
-                            checkpoint_path,
-                            slots=table["slots"],
-                            frontier=self._dense_rows(front, n_front),
-                            n_front=n_front,
-                            h_parent=np.concatenate(self._h_parent),
-                            h_action=np.concatenate(self._h_action),
-                            h_param=np.concatenate(self._h_param),
-                            init_dense=self._init_dense,
-                            level_sizes=self.level_sizes, depth=depth,
-                            fp_count=fp_count,
-                            states_generated=res.states_generated,
-                            max_msgs=self.codec.shape.MAX_MSGS,
-                            expand_mults=self.expand_mults,
-                            elapsed=time.time() - t0,
-                            digest=spec_digest(spec),
-                            pack=self._pack_manifest(),
-                            canon=self._canon_manifest(),
-                            bounds=self._bounds_manifest(),
-                            por=self._por_manifest(), obs=obs)
-                    last_checkpoint = time.time()
-                    obs.checkpoint(checkpoint_path, depth, fp_count)
-                    emit(f"checkpoint written to {checkpoint_path} "
-                         f"(depth {depth}, {fp_count} distinct; "
-                         f"resume via the chunked engine)")
-                if rescue is not None:
-                    obs.rescue(checkpoint_path or "", depth, fp_count,
-                               rescue)
-                    emit(f"preempted by {rescue}: rescue snapshot at "
-                         f"depth {depth} ({checkpoint_path}); exiting "
-                         f"resumable")
-                    raise Preempted(checkpoint_path, depth, fp_count,
-                                    rescue)
-                # quantum boundaries are level boundaries: safe spot
-                # to shrink the fused expansion caps onto the exact
-                # observed maxima (no dispatch in flight)
-                self._calibrate_caps(obs, emit, n_front)
-                # the next quantum starts with level depth+1 — same
-                # depth convention as the chunked engine's per-level
-                # hook.  The host only sees quantum boundaries, so a
-                # level-pinned fault fires iff its level is the first
-                # of a quantum (pin rescue_quantum accordingly in
-                # injection tests)
-                fault_point("level", depth=depth + 1, obs=obs)
-                if level_base + n_front + f_cap > tp_cap:
-                    add = tp_cap                     # double
-                    tpp = jnp.concatenate(
-                        [tpp, jnp.full((add,), -1, I32)])
-                    tpa = jnp.concatenate(
-                        [tpa, jnp.full((add,), -1, I32)])
-                    tpm = jnp.concatenate(
-                        [tpm, jnp.zeros((add,), I32)])
-                    tp_cap += add
-                    self._fresh_jit = True   # shape change: retrace
-                    obs.grow("trace_pointer_store", tp_cap)
-                    emit(f"trace-pointer store grown to {tp_cap}")
-                # else: level counter full — drained above, re-enter
-                continue
-            if reason == R_VIOLATION:
-                # committed tiles of the in-flight level count (run()
-                # adds per-chunk gen on every call incl. the last)
-                res.states_generated += gen_level
-                if por_on:
-                    self._por_kept += gen_level
-                    self._por_full += gfull_level
-                    self._por_amp += amp_level
-                vp, va, vprm = (int(v) for v in np.asarray(out["viol"]))
-                gid = level_base + vp
-                parent_dense = self._fetch_row(front, vp)
-                vstate = self._materialize_one(parent_dense, va, vprm)
-                bad = spec.check_invariants(self.codec.decode(vstate))
-                if bad is None:
-                    raise TLAError(
-                        "device/interpreter divergence: device "
-                        "invariant kernel reported a violation the "
-                        "interpreter accepts (parent gid "
-                        f"{gid}, action {self.kern.action_names[va]})")
-                set_pointers(level_base + n_front)
-                res.ok = False
-                res.violated_invariant = bad
-                res.trace = self._trace(gid, extra=(va, vprm))
-                # depth counts committed levels; the violation is in
-                # the in-progress one (chunked run() parity)
-                res.diameter = depth + 1
-                return self._finish(res, obs, fp_count,
-                                    table=table, fp_cap=fp_cap)
-            if reason == R_DEADLOCK:
-                res.states_generated += gen_level
-                if por_on:
-                    self._por_kept += gen_level
-                    self._por_full += gfull_level
-                    self._por_amp += amp_level
-                di = int(out["dead"])
-                set_pointers(level_base + n_front)
-                res.ok = False
-                res.error = "deadlock"
-                res.deadlock_state = self.codec.decode(
-                    self._fetch_row(front, di))
-                res.trace = self._trace(level_base + di)
-                res.diameter = depth + 1
-                return self._finish(res, obs, fp_count,
-                                    table=table, fp_cap=fp_cap)
-            if reason == R_BAG_GROW:
-                front, nb = self._grow_msgs([front, nb])
-                obs.grow("message_table", self.codec.shape.MAX_MSGS)
-                emit(f"message table grown to "
-                     f"{self.codec.shape.MAX_MSGS} slots (recompiling)")
-            elif reason == R_FPSET_GROW:
-                table = grow(table)
-                fp_cap *= 4
-                self._fresh_jit = True       # shape change: retrace
-                obs.grow("fpset", fp_cap)
-                emit(f"FPSet grown to {fp_cap} slots")
-            elif reason == R_NEXT_GROW:
-                old_cap = nbp.shape[0]
-                front, nbp, nba, nbprm = self._grow_next(
-                    (front, nbp, nba, nbprm))
-                f_cap = nbp.shape[0]
-                nb = self._pad_rows(nb, f_cap - old_cap)
-                self._fresh_jit = True       # shape change: retrace
-                obs.grow("next_buffer", f_cap)
-                emit(f"frontier buffers grown to {f_cap}")
-            elif reason == R_EXPAND_GROW:
-                self._grow_expand(int(out["grow_aid"]), obs, emit)
-            elif reason == R_SLOT_ERR:
-                raise TLAError(
-                    "dense-layout slot collision (a second DVC or "
-                    "recovery response from one source in one view): "
-                    "this restart-era interleaving needs the "
-                    "multi-slot layout (vsr.py docstring)")
-
-        # a limit break straight after a growth pause still carries an
-        # in-flight level's committed-tile gen (run() adds per chunk)
-        res.states_generated += gen_level
-        if por_on:
-            self._por_kept += gen_level
-            self._por_full += gfull_level
-            self._por_amp += amp_level
-        set_pointers(fp_count if reason == RUNNING and n_front == 0
-                     else level_base + n_front)
-        res.diameter = depth
-        return self._finish(res, obs, fp_count,
-                            table=table, fp_cap=fp_cap)
-
-    # ------------------------------------------------------------------
-    # chained run: a pipelined window that survives level boundaries
-    # ------------------------------------------------------------------
-    @closes_observer
-    def run_chained(self, max_states=None, max_depth=None,
-                    max_seconds=None, check_deadlock=False, log=None,
-                    progress_every=10.0, levels_cap=1024,
-                    checkpoint_path=None, checkpoint_every=None,
-                    obs=None) -> CheckResult:
-        """Like run() with ``-pipeline K``, but the dispatch window
-        SURVIVES level transitions (ISSUE 9 tentpole lever 3): run()
-        must drain its window at every level boundary — the host swaps
-        the frontier buffers and resets the chain scalars — so on a
-        level-heavy space the device idles through one host round-trip
-        per level no matter how deep the window is.  Here each dispatch
-        is the fused multi-level pass (_make_multilevel) bounded to a
-        ``chunk_tiles`` TILE budget: a budget boundary can land
-        mid-level (the partial (start_t, nn, gen_level) ride the carry,
-        exactly like a growth pause), the on-device ping-pong swap
-        carries the frontier across level ends, and the next dispatch
-        chains on the previous one's device-side carry — so the K-deep
-        window stays full through level transitions with zero host
-        syncs to refill it.
-
-        Pause discipline is unchanged: dispatches chained behind a
-        pause re-attempt the same tile, commit nothing, and re-fail
-        identically, so drained tickets carry no deltas and counts /
-        level sizes / violation traces are BIT-IDENTICAL to run() for
-        every K (tests/test_pack.py asserts it).  Trace pointers and
-        level sizes accumulate on device fused-style and are pulled per
-        collected ticket (level sizes) / at the end (pointers).
-
-        Rescue seam (ISSUE 10 satellite): with ``checkpoint_path`` the
-        chained run is checkpointable — when the cadence fires (or a
-        PreemptionGuard signal is pending) the window stops refilling,
-        drains through normal collects (trailing tickets hold REAL
-        work), and if the chain sits mid-level ONE level-bounded
-        dispatch (``max_lvls=1``, unbounded tile budget) completes the
-        current level exactly; a run()-format snapshot is then written
-        at the boundary, so a checkpointed run no longer has to fall
-        back to run().  The snapshot resumes through ``run()`` (the
-        supervisor journals that as a mode degrade, like fused)."""
-        from ..analysis import preflight
-        preflight(self.spec, log=log)
-        obs = RunObserver.ensure(obs, "device-chained", self.spec,
-                                 log=log, progress_every=progress_every)
-        obs.pipeline = self.pipe_window
-        obs.pack = self._pk is not None
-        obs.commit = self.commit
-        obs.symmetry = self._symmetry_on()
-        obs.bounds = self._bounds_doc()
-        obs.edges = self._edges_on
-        obs.por = self._por_doc()
-        self._obs_active = obs          # closes_observer finalizes it
-        spec = self.spec
-        self._reset_accounting()
-        self._por_kept = self._por_full = self._por_amp = 0
-        res = CheckResult()
-        t0 = time.time()
-        obs.start(t0, backend=jax.default_backend())
-
-        fp_cap = self.fpset_capacity
-        self.level_sizes = []      # no stale trajectory on init-viol
-        with obs.span(spans.INIT):
-            table, init_batch, n0, viol = self._register_init(res)
-            if viol is None:
-                f_cap = max(self.next_cap, n0)
-                front, nbp, nba, nbprm = self._alloc_bufs(f_cap)
-                front = self._set_rows(front, init_batch, n0)
-                nb, _, _, _ = self._alloc_bufs(f_cap)
-                tp_cap = max(4 * f_cap, 1 << 16)
-                tpp = jnp.full((tp_cap,), -1, I32)
-                tpa = jnp.full((tp_cap,), -1, I32)
-                tpm = jnp.zeros((tp_cap,), I32)
-                lvl_buf = jnp.zeros((levels_cap,), I32)
-        if viol is not None:
-            return self._finish(res, obs, n0, table=table, fp_cap=fp_cap)
-        md = 2**31 - 1 if max_depth is None else int(max_depth)
-        ms = int(max_states) if max_states else 2**31 - 1
-
-        # device-side chain scalars: rebound from every launch's output
-        # so filling the window costs zero host syncs (run()'s chain is
-        # just (start_t, nn); here the whole fused carry chains)
-        d_n_front = jnp.asarray(n0, I32)
-        d_start = jnp.asarray(0, I32)
-        d_nn = jnp.asarray(0, I32)
-        d_gen_level = jnp.asarray(0, I32)
-        d_depth = jnp.asarray(0, I32)
-        d_level_base = jnp.asarray(0, I32)
-        d_fp = jnp.asarray(n0, I32)
-        por_on = self._por_active
-        d_gfull_level = jnp.asarray(0, I32)
-        d_amp_level = jnp.asarray(0, I32)
-        gfull_level, amp_level = 0, 0
-        self.level_sizes = [n0]
-        depth, fp_count, n_front = 0, n0, n0
-        level_base, gen_level = 0, 0
-        h_start, h_nn = 0, 0      # collected chain position (seam)
-
-        from .pipeline import DispatchPipeline
-        pipe = DispatchPipeline(self.pipe_window, obs,
-                                ready=lambda o: o["reason"])
-
-        def pull(o):
-            vals = [o["reason"], o["n_front"], o["depth"],
-                    o["fp_count"], o["level_base"], o["lvl_cur"],
-                    o["gen"], o["gen_level"], o["act"], o["start_t"],
-                    o["nn"], o["tiles"], o["need"], o["blk"]]
-            if por_on:
-                vals += [o["gfull"], o["gfull_level"],
-                         o["amp"], o["amp_level"]]
-            return jax.device_get(vals)
-
-        def set_pointers(n):
-            self._h_parent = [np.asarray(tpp[:n]).astype(np.int64)]
-            self._h_action = [np.asarray(tpa[:n])]
-            self._h_param = [np.asarray(tpm[:n])]
-
-        def collect_one():
-            """Collect the oldest ticket, fold its deltas into the
-            host-side totals, and emit its committed levels."""
-            nonlocal depth, fp_count, n_front, level_base, gen_level
-            nonlocal h_start, h_nn, levels_unck
-            nonlocal gfull_level, amp_level
-            out, sc = pipe.collect(pull)
-            (reason, n_front, depth, fp_count, level_base, lvl_cur,
-             gen_add, gen_level) = (int(x) for x in sc[:8])
-            res.states_generated += gen_add
-            self._act_counts += np.asarray(sc[8], np.int64)
-            h_start, h_nn = int(sc[9]), int(sc[10])
-            levels_unck += lvl_cur
-            self._account_tiles(int(sc[11]))
-            self._fold_need(sc[12])
-            self._account_blocks(sc[13])
-            if por_on:
-                self._por_kept += gen_add
-                self._por_full += int(sc[14])
-                self._por_amp += int(sc[16])
-                gfull_level, amp_level = int(sc[15]), int(sc[17])
-            if lvl_cur:
-                # each dispatch records its own committed levels from
-                # slot 0 of ITS lvl_buf output (which is why lvl_buf is
-                # excluded from donation: this read can race a newer
-                # in-flight dispatch)
-                with obs.span(spans.HOST_SYNC):
-                    sizes = np.asarray(out["lvl_buf"][:lvl_cur])
-                cum = sum(self.level_sizes)
-                for x in sizes:
-                    prev = self.level_sizes[-1]
-                    self.level_sizes.append(int(x))
-                    cum += int(x)
-                    obs.level_done(len(self.level_sizes) - 1,
-                                   frontier=prev, distinct=cum,
-                                   generated=res.states_generated)
-            return out, reason
-
-        emit = obs.log
-        stop = None
-        ckpt_due = False
-        levels_unck = 0     # levels committed since the last snapshot
-        last_checkpoint = time.time()
-
-        def launch_next(tile_budget, max_lvls):
-            nonlocal table, front, nb, nbp, nba, nbprm, tpp, tpa, tpm
-            nonlocal lvl_buf, d_n_front, d_start, d_nn, d_gen_level
-            nonlocal d_depth, d_level_base, d_fp
-            nonlocal d_gfull_level, d_amp_level
-            fresh = self._fresh_jit or self._wl is None
-            if self._wl is None:
-                # the SAME pass run_fused jits, minus the lvl_buf
-                # donation (argnum 9): collected tickets read their
-                # level counters back while newer dispatches are
-                # already consuming the other buffers
-                self._wl = jax.jit(self._make_multilevel(),
-                                   donate_argnums=tuple(range(9)))
-            out = pipe.launch(
-                self._wl, table["slots"], front, nb, nbp, nba,
-                nbprm, tpp, tpa, tpm, lvl_buf,
-                d_n_front, d_start, d_nn, d_gen_level, d_depth,
-                d_level_base, d_fp,
-                jnp.asarray(bool(check_deadlock)),
-                jnp.asarray(md, I32), jnp.asarray(ms, I32),
-                jnp.asarray(max_lvls, I32),
-                jnp.asarray(0, I32),
-                jnp.asarray(tile_budget, I32),
-                *((table["gids"], d_gfull_level, d_amp_level)
-                  if por_on else ()),
-                fresh=fresh, depth=depth)
-            self._fresh_jit = False
-            table = {"slots": out["slots"]}
-            if por_on:
-                table["gids"] = out["gids"]
-                d_gfull_level = out["gfull_level"]
-                d_amp_level = out["amp_level"]
-            front, nb = out["front"], out["nb"]
-            nbp, nba, nbprm = out["nbp"], out["nba"], out["nbprm"]
-            tpp, tpa, tpm = out["tpp"], out["tpa"], out["tpm"]
-            lvl_buf = out["lvl_buf"]
-            d_n_front, d_start = out["n_front"], out["start_t"]
-            d_nn, d_gen_level = out["nn"], out["gen_level"]
-            d_depth, d_level_base = out["depth"], out["level_base"]
-            d_fp = out["fp_count"]
-
-        while True:
-            if not ckpt_due:
-                while pipe.has_room():
-                    launch_next(self.chunk_tiles, levels_cap)
-            if pipe.in_flight:
-                out, reason = collect_one()
-            else:
-                # rescue seam: the window drained while a checkpoint
-                # was pending — fall through to the seam below
-                out, reason = None, RUNNING
-            obs.progress(depth=depth, distinct=fp_count,
-                         generated=res.states_generated)
-
-            if reason == RUNNING:
-                if n_front == 0:
-                    pipe.drain()            # trailing no-op tickets
-                    break
-                if max_depth is not None and depth >= max_depth:
-                    stop = f"depth limit {max_depth} reached"
-                elif max_states and fp_count >= max_states:
-                    stop = f"state limit {max_states} reached"
-                elif max_seconds and time.time() - t0 > max_seconds:
-                    stop = f"time budget {max_seconds}s reached"
-                if stop:
-                    # trailing tickets hold REAL committed work (unlike
-                    # a pause, whose replays commit nothing): consume
-                    # them so the reported counts reflect what ran
-                    while pipe.in_flight:
-                        out, reason = collect_one()
-                    break
-                if level_base + n_front + nbp.shape[0] > tp_cap:
-                    # trace-pointer store pressure paused the kernel
-                    # (trailing tickets hit the same guard: no-ops)
-                    pipe.drain()
-                    add = tp_cap
-                    tpp = jnp.concatenate(
-                        [tpp, jnp.full((add,), -1, I32)])
-                    tpa = jnp.concatenate(
-                        [tpa, jnp.full((add,), -1, I32)])
-                    tpm = jnp.concatenate([tpm, jnp.zeros((add,), I32)])
-                    tp_cap += add
-                    self._fresh_jit = True   # shape change: retrace
-                    obs.grow("trace_pointer_store", tp_cap)
-                    emit(f"trace-pointer store grown to {tp_cap}")
-                    continue
-                # ---- level-boundary rescue seam (ISSUE 10 satellite):
-                # stop refilling, drain through normal collects
-                # (trailing tickets hold real work), complete the
-                # current level with ONE level-bounded dispatch when
-                # the chain sits mid-level, then snapshot in run()
-                # format at the boundary
-                rescue = preempt_signal()
-                # checkpoint_every=None means "every level boundary"
-                # (run() parity) — gated on a NEW committed level so
-                # the seam never drains the window without fresh work
-                # to snapshot
-                if rescue is not None or (checkpoint_path and (
-                        (checkpoint_every is None and levels_unck > 0)
-                        or (checkpoint_every is not None
-                            and time.time() - last_checkpoint
-                            >= checkpoint_every))):
-                    ckpt_due = True
-                if ckpt_due:
-                    if pipe.in_flight:
-                        continue
-                    if h_start or h_nn:
-                        launch_next(2**31 - 1, 1)
-                        continue
-                    ckpt_due = False
-                    levels_unck = 0
-                    if checkpoint_path:
-                        from .checkpoint import (save_checkpoint,
-                                                 spec_digest)
-                        with obs.span(spans.CHECKPOINT, depth=depth):
-                            set_pointers(level_base + n_front)
-                            save_checkpoint(
-                                checkpoint_path,
-                                slots=table["slots"],
-                                frontier=self._dense_rows(front,
-                                                          n_front),
-                                n_front=n_front,
-                                h_parent=np.concatenate(self._h_parent),
-                                h_action=np.concatenate(self._h_action),
-                                h_param=np.concatenate(self._h_param),
-                                init_dense=self._init_dense,
-                                level_sizes=self.level_sizes,
-                                depth=depth, fp_count=fp_count,
-                                states_generated=res.states_generated,
-                                max_msgs=self.codec.shape.MAX_MSGS,
-                                expand_mults=self.expand_mults,
-                                elapsed=time.time() - t0,
-                                digest=spec_digest(spec),
-                                pack=self._pack_manifest(),
-                                canon=self._canon_manifest(),
-                                bounds=self._bounds_manifest(),
-                                por=self._por_manifest(), obs=obs)
-                        last_checkpoint = time.time()
-                        obs.checkpoint(checkpoint_path, depth, fp_count)
-                        emit(f"checkpoint written to {checkpoint_path} "
-                             f"(depth {depth}, {fp_count} distinct; "
-                             f"resume via the chunked engine)")
-                    if rescue is not None:
-                        obs.rescue(checkpoint_path or "", depth,
-                                   fp_count, rescue)
-                        emit(f"preempted by {rescue}: "
-                             + (f"rescue snapshot at depth {depth} "
-                                f"({checkpoint_path}); exiting "
-                                f"resumable" if checkpoint_path else
-                                f"no checkpoint path — exiting at the "
-                                f"depth-{depth} boundary with no "
-                                f"snapshot"))
-                        raise Preempted(checkpoint_path, depth,
-                                        fp_count, rescue)
-                # else: tile budget (the normal windowed cadence) or a
-                # full per-dispatch level counter (next dispatch resets
-                # it) — just keep the window full
-                continue
-            # pause or terminal: trailing tickets are commit-nothing
-            # replays; handle the reason on the chain-tip buffers
-            pipe.drain()
-            if reason == R_VIOLATION:
-                res.states_generated += gen_level
-                if por_on:
-                    self._por_kept += gen_level
-                    self._por_full += gfull_level
-                    self._por_amp += amp_level
-                vp, va, vprm = (int(v) for v in np.asarray(out["viol"]))
-                gid = level_base + vp
-                parent_dense = self._fetch_row(front, vp)
-                vstate = self._materialize_one(parent_dense, va, vprm)
-                bad = spec.check_invariants(self.codec.decode(vstate))
-                if bad is None:
-                    raise TLAError(
-                        "device/interpreter divergence: device "
-                        "invariant kernel reported a violation the "
-                        "interpreter accepts (parent gid "
-                        f"{gid}, action {self.kern.action_names[va]})")
-                set_pointers(level_base + n_front)
-                res.ok = False
-                res.violated_invariant = bad
-                res.trace = self._trace(gid, extra=(va, vprm))
-                res.diameter = depth + 1
-                return self._finish(res, obs, fp_count,
-                                    table=table, fp_cap=fp_cap)
-            if reason == R_DEADLOCK:
-                res.states_generated += gen_level
-                if por_on:
-                    self._por_kept += gen_level
-                    self._por_full += gfull_level
-                    self._por_amp += amp_level
-                di = int(out["dead"])
-                set_pointers(level_base + n_front)
-                res.ok = False
-                res.error = "deadlock"
-                res.deadlock_state = self.codec.decode(
-                    self._fetch_row(front, di))
-                res.trace = self._trace(level_base + di)
-                res.diameter = depth + 1
-                return self._finish(res, obs, fp_count,
-                                    table=table, fp_cap=fp_cap)
-            if reason == R_BAG_GROW:
-                front, nb = self._grow_msgs([front, nb])
-                obs.grow("message_table", self.codec.shape.MAX_MSGS)
-                emit(f"message table grown to "
-                     f"{self.codec.shape.MAX_MSGS} slots (recompiling)")
-            elif reason == R_FPSET_GROW:
-                table = grow(table)
-                fp_cap *= 4
-                self._fresh_jit = True
-                obs.grow("fpset", fp_cap)
-                emit(f"FPSet grown to {fp_cap} slots")
-            elif reason == R_NEXT_GROW:
-                old_cap = nbp.shape[0]
-                front, nbp, nba, nbprm = self._grow_next(
-                    (front, nbp, nba, nbprm))
-                nb = self._pad_rows(nb, nbp.shape[0] - old_cap)
-                f_cap = nbp.shape[0]
-                self._fresh_jit = True
-                obs.grow("next_buffer", f_cap)
-                emit(f"frontier buffers grown to {f_cap}")
-            elif reason == R_EXPAND_GROW:
-                self._grow_expand(int(out["grow_aid"]), obs, emit)
-            elif reason == R_SLOT_ERR:
-                raise TLAError(
-                    "dense-layout slot collision (a second DVC or "
-                    "recovery response from one source in one view): "
-                    "this restart-era interleaving needs the "
-                    "multi-slot layout (vsr.py docstring)")
-
-        res.states_generated += gen_level
-        if por_on:
-            self._por_kept += gen_level
-            self._por_full += gfull_level
-            self._por_amp += amp_level
-        set_pointers(fp_count if (stop is None and n_front == 0)
-                     else level_base + n_front)
-        if stop:
-            res.error = stop
-        res.diameter = depth
-        return self._finish(res, obs, fp_count,
-                            table=table, fp_cap=fp_cap)
 
     # ------------------------------------------------------------------
     def _flush_pointers(self):

@@ -3,9 +3,10 @@ README:22, rebuilt as a scan-based XLA program; BASELINE.json
 configs[2]).
 
 SUPERSEDED as the simulation backend by the sharded walker fleet
-(``tpuvsr/sim``, ISSUE 7): the CLI ``-simulate`` path, ``bench.py``'s
-``sim_scale``/``defect_hunt`` probes and the service ``kind="sim"``
-jobs all run the fleet — per-(seed, walk-id) deterministic draws,
+(``tpuvsr/sim``, ISSUE 7): the CLI ``-simulate`` path and the service
+``kind="sim"`` jobs run the fleet (the benchmark,
+``python3 benchmark/run.py --workload W --seed N --seconds S --trace 0|1``,
+has no walker cell yet) — per-(seed, walk-id) deterministic draws,
 shard_map across the mesh, the ``engine/pipeline.py`` dispatch window,
 and importance splitting over a fingerprint-novelty seen-set.  This
 class remains the single-device scan oracle (its chunk kernel is the

@@ -20,8 +20,7 @@ One ``Metrics`` instance rides a single engine run (inside a
 
 Per-level rows (``level(...)``) capture the BFS trajectory: frontier
 size, cumulative distinct/generated, and elapsed at each level
-boundary — the data a ``-metrics FILE.json`` dump and the diffable
-``BENCH_*.json`` trajectories are built from.
+boundary — the data a ``-metrics FILE.json`` dump is built from.
 
 The serialized form (``to_dict``) is the ``tpuvsr-metrics/1`` schema
 documented in ``tpuvsr/obs/SCHEMA.md`` and validated by

@@ -204,13 +204,10 @@ class Supervisor:
                  metrics_path=None, log=None, tile_size=128,
                  min_tile=DEFAULT_MIN_TILE, max_retries=6,
                  backoff_base=0.5, backoff_cap=30.0,
-                 engine_kwargs=None, engine_factory=None, fused=False,
-                 chained=False, mesh_devices=None, min_devices=1,
+                 engine_kwargs=None, engine_factory=None,
+                 mesh_devices=None, min_devices=1,
                  sleep=time.sleep, observer_factory=None,
                  on_event=None, span=None):
-        if fused and chained:
-            raise ValueError("fused and chained are mutually "
-                             "exclusive supervision modes")
         if engine not in ("device", "paged", "sharded"):
             raise ValueError(f"Supervisor supervises the device/paged/"
                              f"sharded engines, not {engine!r}")
@@ -227,21 +224,6 @@ class Supervisor:
         else:
             self.n_dev = None
         self.min_devices = max(1, int(min_devices))
-        # fused=True: first attempt runs the fused fixpoint with its
-        # dispatch bounded to a rescue quantum (run_fused checkpoint
-        # mode, ISSUE 4 satellite); any retry that has a snapshot to
-        # resume from continues through the chunked engine (the fused
-        # pass has no resume path) — journaled as a mode degrade
-        self.fused = bool(fused)
-        self._fused_degraded = False
-        # chained=True (ISSUE 10 satellite): first attempt runs the
-        # cross-level chained window with its new level-boundary
-        # rescue seam (run_chained checkpoint mode); any retry that
-        # has a snapshot resumes through the chunked engine — the
-        # chained pass has no resume path — journaled as a mode
-        # degrade exactly like the fused one
-        self.chained = bool(chained)
-        self._chained_degraded = False
         self.checkpoint_path = checkpoint_path
         self.checkpoint_every = checkpoint_every
         self.journal_path = journal_path
@@ -326,8 +308,7 @@ class Supervisor:
 
     def summary(self):
         return {"attempts": self.attempts, "engine": self.kind,
-                "tile": self.tile, "fused": self.fused,
-                "chained": self.chained,
+                "tile": self.tile,
                 "mesh_devices": self.n_dev,
                 "resharded_from": getattr(self.engine,
                                           "resharded_from", None),
@@ -347,50 +328,7 @@ class Supervisor:
                         journal_path=self.journal_path,
                         metrics_path=self.metrics_path,
                         log=self._log)
-                    use_fused = self.fused and self.kind == "device"
-                    use_chained = self.chained and self.kind == "device"
-                    if use_fused and resume is not None \
-                            and not self._fused_degraded:
-                        self._fused_degraded = True
-                        self.degrades.append(("mode", "fused",
-                                              "chunked"))
-                        self._jwrite("degrade", what="mode",
-                                     **{"from": "fused",
-                                        "to": "chunked"})
-                        self.log("resuming from a snapshot: the fused "
-                                 "pass has no resume path; continuing "
-                                 "through the chunked engine")
-                    if use_chained and resume is not None \
-                            and not self._chained_degraded:
-                        self._chained_degraded = True
-                        self.degrades.append(("mode", "chained",
-                                              "chunked"))
-                        self._jwrite("degrade", what="mode",
-                                     **{"from": "chained",
-                                        "to": "chunked"})
-                        self.log("resuming from a snapshot: the "
-                                 "chained window has no resume path; "
-                                 "continuing through the chunked "
-                                 "engine")
                     try:
-                        if use_fused and resume is None:
-                            return self.engine.run_fused(
-                                max_states=max_states,
-                                max_depth=max_depth,
-                                max_seconds=max_seconds,
-                                check_deadlock=check_deadlock,
-                                checkpoint_path=self.checkpoint_path,
-                                checkpoint_every=self.checkpoint_every,
-                                obs=obs, log=self._log, **run_kwargs)
-                        if use_chained and resume is None:
-                            return self.engine.run_chained(
-                                max_states=max_states,
-                                max_depth=max_depth,
-                                max_seconds=max_seconds,
-                                check_deadlock=check_deadlock,
-                                checkpoint_path=self.checkpoint_path,
-                                checkpoint_every=self.checkpoint_every,
-                                obs=obs, log=self._log, **run_kwargs)
                         return self.engine.run(
                             max_states=max_states, max_depth=max_depth,
                             max_seconds=max_seconds,
