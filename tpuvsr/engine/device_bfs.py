@@ -14,20 +14,24 @@ THREE-STAGE pass (ISSUE 10, ``commit="fused"``, the default):
                           exact cap-overflow `need` so growth hits the
                           true count and level boundaries calibrate
                           the caps back down onto observed maxima)
-  tile  --work queue  --> enabled (state, lane) items packed into
-                          dense per-action segments of one tile-local
-                          staging queue; ONLY real items are expanded
-                          (vsr_kernel), fingerprinted (VIEW +
-                          symmetry, incremental 128-bit), and
-                          invariant-checked — expand FLOPs scale with
-                          `generated`, not sum of static caps
-  tile  --single commit-> ONE FPSet insert_core batch + ONE scatter
-                          set per tile (vs n_actions of each): a
+  tile  --work queue  --> enabled (state, lane) items compacted per
+                          action; ONLY the blocks of them that hold a
+                          real item are expanded (vsr_kernel),
+                          fingerprinted (VIEW + symmetry, incremental
+                          128-bit), invariant-checked and packed, then
+                          appended to one dense tile-local queue —
+                          expand FLOPs scale with `generated`, not sum
+                          of static caps
+  tile  --commit      --> the queue's written prefix, COMMIT_PIECE
+                          lanes at a time (ISSUE 30): per piece a
                           stable first-occurrence dedup mask picks the
                           earliest queue item among duplicate
-                          fingerprints (= the per-action commit
-                          order), the claim column arbitrates distinct
-                          fingerprints racing for a slot, and the
+                          fingerprints, ONE FPSet insert_core batch
+                          whose claim column arbitrates distinct
+                          fingerprints racing for a slot, ONE scatter
+                          set; a later piece finds an earlier one's
+                          fingerprints in the table, so the winners
+                          are the per-action commit order's, and the
                           headroom check at tile entry keeps inserts
                           and scatters atomic
 
@@ -38,7 +42,7 @@ two modes are BIT-IDENTICAL in counts, level sizes and traces
 committed-action-prefix rule on a failing tile are replicated
 verbatim).  One documented edge: an FPSet PROBE-OVERFLOW pause
 (R_FPSET_GROW mid-tile, rare — the proactive between-level growth
-keeps chains short) commits the resolvable subset of the single batch
+keeps chains short) commits the resolvable subset of every piece
 where per-action committed an action prefix, so after re-entry that
 tile's next-frontier gids may be ORDERED differently between the
 modes; the committed sets, counts, level sizes and trace CONTENT
@@ -148,10 +152,13 @@ def _align8(n):
 # (growth and calibration both): the needs creep up level by level as
 # frontier states grow richer, and 2x was breached five times in the
 # 24-level flagship run — six compiles of the level program.  What the
-# padding costs since ISSUE 28: the tile-local queue and stage 3 (batch
-# dedup, FPSet insert, pack, scatter) run at the sum of the caps;
-# stage 2 expands only the blocks that hold enabled lanes
-# (EXPAND_BLOCK), whatever the caps.
+# padding costs the one-chip level program since ISSUE 30: the headroom
+# gate (a tile commits only while the next buffer has room for the sum
+# of the caps) and the `nonzero` of each action's segment.  Stage 2
+# expands only the blocks that hold enabled lanes (EXPAND_BLOCK) and
+# appends them to a dense queue; stage 3 walks what was appended
+# (COMMIT_PIECE).  The sharded step still runs its own stages 2 and 3
+# at the sum of the caps.
 CAP_HEADROOM = 4
 
 
@@ -164,9 +171,9 @@ CAP_HEADROOM = 4
 # 2.4k lanes per tile, 143 s at 8.8k, 154 s at 15k).  Not 8: a tile
 # commits only while the next-frontier buffer has room for every cap
 # lane, so caps near the buffer's 16k rows force it to grow instead.
-# The start sizes the queue and stage 3 only (see CAP_HEADROOM): the
-# defect window filled 9.5 % of these lanes, and until ISSUE 28 the
-# action functions ran over all of them.
+# The start sizes the headroom gate only (see CAP_HEADROOM): the
+# defect window filled 9.5 % of these lanes; until ISSUE 28 the action
+# functions ran over all of them, and until ISSUE 30 stage 3 did.
 CAP_START = 4
 
 
@@ -186,6 +193,26 @@ def block_rows(cap):
     """Slots in one block of a segment of `cap`: a block never exceeds
     its action's cap."""
     return min(EXPAND_BLOCK, cap)
+
+
+# Lanes of the tile-local commit queue that stage 3 of the fused body
+# (batch dedup, FPSet insert, scatter) takes in one trip: the queue's
+# written prefix is walked in pieces of this many, one after the other.
+# Two readings on one v5e chip, the defect window to depth 10 (PERF.md,
+# PR 30; the parent, one batch of 8,960 lanes a tile: 33,261 and 33,408
+# states/s): at 1,024 the run commits 43,518 and 43,271, in 2,205
+# pieces over its 1,170 tiles; at 2,048 45,372 and 45,310 on the same
+# seeds, one piece a tile.  A trip costs more than its lanes (every
+# probe round of the insert moves the whole table), so 2,048: the
+# window's fullest tile fits in one.  A queue no wider than one piece
+# (every small tile) is one piece of the queue's own width, with no
+# loop.
+COMMIT_PIECE = 2048
+
+
+def piece_lanes(total):
+    """Lanes in one piece of a commit queue that holds `total`."""
+    return min(COMMIT_PIECE, total)
 
 
 def static_cap(tile, full):
@@ -479,6 +506,7 @@ class DeviceBFS:
             "fingerprint")
         self._inv_stage = trace_once(self._inv, "invariants")
         self._expand_stages = {}    # (action, block rows) -> stage
+        self._pack_stages = {}      # block rows -> stage
         self._level_jit = None  # the level pass, built lazily (_level)
         # obs accounting: the first dispatch after a (re)jit is charged
         # to the "compile" phase (jit traces+compiles at first call)
@@ -527,6 +555,22 @@ class DeviceBFS:
                         jax.ShapeDtypeStruct((rows,), I32))
             self._expand_stages[key] = stage
         return self._expand_stages[key]
+
+    def _pack_stage(self, rows, row):
+        """`pk.pack` over one block of `rows` successors (`row`: the
+        types of one state row), traced HERE once per kernel and block
+        size like `_expand_stage`, so the 19 block loops share one
+        pack instead of each tracing theirs.  Without a pack spec a
+        queue row is the plane dict itself."""
+        if self._pk is None:
+            return lambda succ: succ
+        if rows not in self._pack_stages:
+            stage = jax.jit(jax.vmap(self._pk.pack), inline=True)
+            stage.trace(jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct((rows,) + s.shape, s.dtype),
+                row))
+            self._pack_stages[rows] = stage
+        return self._pack_stages[rows]
 
     def _expand_caps(self):
         """Per-action enabled-lane compaction capacities, in lanes.
@@ -871,16 +915,25 @@ class DeviceBFS:
             observed per-action maxima so growth is sized to the real
             count, not a doubling guess);
         (2) **work-queue compaction**: each action's enabled
-            (state, lane) items are packed into a dense per-action
-            segment of one tile-local staging queue (action-major, so
-            queue order == the per-action commit order) and ONLY those
-            lanes are expanded/fingerprinted/invariant-checked;
-        (3) **single-commit**: the staged segments are committed with
-            ONE FPSet ``insert_core`` batch and ONE scatter set per
-            tile (vs n_actions of each).  A stable first-occurrence
-            dedup mask makes the intra-batch winner for duplicate
-            fingerprints the earliest queue item — exactly the action
-            order the per-action body commits in — and the
+            (state, lane) items are compacted, and ONLY the blocks of
+            them that hold an enabled lane are expanded, fingerprinted,
+            invariant-checked and packed.  A block is appended, packed,
+            at the running end of ONE tile-local commit queue
+            (ISSUE 30) with its action, parent index and lane per
+            slot: action-major and, within an action, in `nonzero`
+            order, so queue order == the per-action commit order, and
+            the queue holds no lane of a block that did not run;
+        (3) **commit in pieces**: the queue's written prefix is
+            committed COMMIT_PIECE lanes at a time — batch dedup,
+            FPSet ``insert_core``, scatter of rows and pointers — one
+            piece after the other (one piece, no loop, where the whole
+            queue is no wider).  A stable first-occurrence dedup mask
+            makes the winner among a piece's duplicate fingerprints
+            the earliest queue item, a later piece finds an earlier
+            piece's fingerprints in the table, and ``dest`` carries on
+            from piece to piece: every next-buffer row and pointer is
+            what ONE batch over the whole queue gives, which is the
+            action order the per-action body commits in.  The
             failure-cause priority (violation > slot > bag >
             expand-grow > fpset-grow) plus the committed-action-prefix
             rule on a failing tile are preserved verbatim, so results
@@ -893,32 +946,47 @@ class DeviceBFS:
         caps = self._expand_caps()
         total_E = sum(caps)
         caps_v = jnp.asarray(caps, I32)
-        aid_q = jnp.asarray(np.repeat(np.arange(n_act, dtype=np.int32),
-                                      caps))
+        # the queue is a whole number of pieces, so no piece's slice is
+        # clamped onto the one before it
+        P = piece_lanes(total_E)
+        Q = -(-total_E // P) * P
         edges_on = self._edges_on
         if pk is not None:
             row = jax.eval_shape(pk.unpack, jax.ShapeDtypeStruct(
                 (pk.words,), jnp.uint32))
+            qrow = jax.ShapeDtypeStruct((pk.words,), jnp.uint32)
         else:
             row = {k: jax.ShapeDtypeStruct(np.shape(v), np.int32)
                    for k, v in self.codec.zero_state().items()}
+            qrow = row
         parts_row = (jax.eval_shape(kern.parent_parts, row)
                      if incremental else None)
         expand_of = [self._expand_stage(aid, block_rows(cap), row,
                                         parts_row)
                      for aid, cap in enumerate(caps)]
+        pack_of = [self._pack_stage(block_rows(cap), row)
+                   for cap in caps]
         # ample-set POR (ISSUE 16): amat[a, b] says "expanding only a
-        # is safe given an enabled b" (por.PORFilter); qoff slices the
-        # action-major staging queue back into per-action segments for
-        # the kept-lane counters.  POR and edge emission are mutually
-        # exclusive (resolve_por blocker), so the FPSet gids column
-        # has exactly one meaning per run: graph node ids under
-        # -edges, C3 level markers under -por
+        # is safe given an enabled b" (por.PORFilter).  POR and edge
+        # emission are mutually exclusive (resolve_por blocker), so
+        # the FPSet gids column has exactly one meaning per run: graph
+        # node ids under -edges, C3 level markers under -por
         por_active = self._por_active
         if por_active:
             assert not edges_on
             amat_dev = jnp.asarray(self._por.amat)
-            qoff = np.concatenate(([0], np.cumsum(caps))).astype(int)
+
+        def put(bufs, vals, at):
+            """`vals` written into `bufs` from row `at` on.  The
+            primitive, bound bare: `at` needs no wrap-around
+            arithmetic, and 41 planes an action would each trace
+            theirs."""
+            zero = jnp.asarray(0, I32)
+            return jax.tree_util.tree_map(
+                lambda buf, v: jax.lax.dynamic_update_slice_p.bind(
+                    buf, v.astype(buf.dtype), at,
+                    *[zero] * (buf.ndim - 1)),
+                bufs, vals)
 
         def make_body(frontier, n_front, want_deadlock, chunk_ctx,
                       edge_bases, pdepth):
@@ -970,10 +1038,9 @@ class DeviceBFS:
                     has_cand = cand.any(axis=1)
                     aid_star = jnp.argmax(cand, axis=1).astype(I32)
 
-                slots = c["slots"]
-                nb, nbp, nba, nbprm = c["nb"], c["nbp"], c["nba"], c["nbprm"]
+                nbp = c["nbp"]
                 N_cap = nbp.shape[0]
-                nn, dist = c["nn"], c["dist"]
+                nn = c["nn"]
                 reason, viol = c["reason"], c["viol"]
                 # same headroom gate as the per-action body: with
                 # N_cap - nn >= total_E no scatter can overrun, so an
@@ -999,13 +1066,24 @@ class DeviceBFS:
                         parts = jax.vmap(kern.parent_parts)(tile)
                 else:
                     parts = None
-                succ_segs, fp_segs, en_s_segs = [], [], []
-                pidx_segs, lane_segs, blk_segs = [], [], []
+                blk_segs = []
                 viol_any = jnp.asarray(False)
                 bag_err = jnp.asarray(False)
                 slot_err = jnp.asarray(False)
                 first_bad = jnp.asarray(n_act, I32)
-                zero = jnp.asarray(0, I32)
+
+                def lanes(dtype, *shape):
+                    return jax.lax.full((Q,) + shape, 0, dtype)
+
+                # the commit queue: a slot no block wrote keeps its
+                # zeros, with `en` False
+                queue = {
+                    "rows": jax.tree_util.tree_map(
+                        lambda s: lanes(s.dtype, *s.shape), qrow),
+                    "fp": lanes(jnp.uint32, 4), "en": lanes(bool),
+                    "aid": lanes(I32), "pidx": lanes(I32),
+                    "lane": lanes(I32)}
+                q_end = jnp.asarray(0, I32)
                 for aid, name in enumerate(kern.action_names):
                     L_a = kern._lane_count(name)
                     TL = T * L_a
@@ -1019,50 +1097,52 @@ class DeviceBFS:
                         lane_sel = (sel % L_a).astype(I32)
                     # only the blocks of the segment that hold an
                     # enabled lane are expanded: stage 1 counted them
-                    # exactly.  A slot no block wrote keeps its zeros
-                    # and en2 False; a slot at or past cnt in a block
-                    # that ran has sel_ok False.  Either way en_s is
-                    # False there and every later read is masked by it
+                    # exactly.  A slot at or past cnt in a block that
+                    # ran has sel_ok False, so `en` is False there and
+                    # every later read is masked by it
                     B = block_rows(E_a)
                     n_blk = (jnp.minimum(cnts[aid], E_a) + B - 1) // B
                     blk_segs.append(n_blk)
-                    expand = expand_of[aid]
+                    expand, pack = expand_of[aid], pack_of[aid]
+                    aid_b = jax.lax.full((B,), aid, I32)
 
                     def block(b, out):
                         # the last block of a cap that is no multiple
                         # of B is clamped onto the one before it: the
                         # rows they share are written twice, the same
+                        queue, seg = out
                         with jax.named_scope(spans.COMPACT):
                             lo = jnp.minimum(b * B, E_a - B)
                             pidx_b = jax.lax.dynamic_slice_in_dim(
                                 pidx, lo, B)
+                            lane_b = jax.lax.dynamic_slice_in_dim(
+                                lane_sel, lo, B)
+                            ok_b = jax.lax.dynamic_slice_in_dim(
+                                sel_ok, lo, B)
                             st_b = {k: v[pidx_b] for k, v in tile.items()}
                             parts_b = jax.tree_util.tree_map(
                                 lambda v: v[pidx_b], parts)
-                        got = expand(
-                            st_b, parts_b,
-                            jax.lax.dynamic_slice_in_dim(lane_sel, lo, B))
-                        # the primitive, bound bare: `lo` needs no
-                        # wrap-around arithmetic, and 41 planes an
-                        # action would each trace theirs
+                        succ, fp, en2, iok, errv = expand(
+                            st_b, parts_b, lane_b)
+                        # a successor is a state row: packed here, a
+                        # block at a time, it never exists unpacked at
+                        # the queue's width
+                        rows_b = pack({k: succ[k].astype(s.dtype)
+                                       for k, s in row.items()})
                         with jax.named_scope(spans.COMPACT):
-                            return jax.tree_util.tree_map(
-                                lambda buf, v:
-                                jax.lax.dynamic_update_slice_p.bind(
-                                    buf, v.astype(buf.dtype), lo,
-                                    *[zero] * (buf.ndim - 1)),
-                                out, got)
+                            queue = put(queue, {
+                                "rows": rows_b, "fp": fp,
+                                "en": en2 & ok_b, "aid": aid_b,
+                                "pidx": pidx_b, "lane": lane_b},
+                                q_end + lo)
+                            return queue, put(seg, (en2, iok, errv), lo)
 
-                    # a successor is a state row, so the segment's
-                    # buffers take their types from the tile's planes
-                    succ0 = {k: jax.lax.full((E_a,) + v.shape[1:], 0,
-                                             v.dtype)
-                             for k, v in tile.items()}
                     no = jax.lax.full((E_a,), False, bool)
-                    succ_f, fp, en2, iok, errv = jax.lax.fori_loop(
+                    queue, (en2, iok, errv) = jax.lax.fori_loop(
                         0, n_blk, block,
-                        (succ0, jax.lax.full((E_a, 4), 0, jnp.uint32),
-                         no, no, succ0["err"]))
+                        (queue, (no, no, jax.lax.full(
+                            (E_a,), 0, row["err"].dtype))))
+                    q_end = q_end + jnp.minimum(n_blk * B, E_a)
 
                     with jax.named_scope(spans.INVARIANTS):
                         en_s = en2 & sel_ok
@@ -1087,21 +1167,12 @@ class DeviceBFS:
                         bad_a = have_v | a_slot | a_bag | ovf_vec[aid]
                         first_bad = jnp.minimum(
                             first_bad, jnp.where(bad_a, aid, n_act))
-                    succ_segs.append(succ_f)
-                    fp_segs.append(fp)
-                    en_s_segs.append(en_s)
-                    pidx_segs.append(pidx)
-                    lane_segs.append(lane_sel)
 
-                with jax.named_scope(spans.COMPACT):
-                    succ_q = {k: jnp.concatenate(
-                        [s[k] for s in succ_segs]) for k in succ_segs[0]}
-                    fp_q = jnp.concatenate(fp_segs)
-                    en_q = jnp.concatenate(en_s_segs)
-                    pidx_q = jnp.concatenate(pidx_segs)
-                    lane_q = jnp.concatenate(lane_segs)
+                rows_q, fp_q, en_q = queue["rows"], queue["fp"], queue["en"]
+                aid_q, pidx_q, lane_q = (queue["aid"], queue["pidx"],
+                                         queue["lane"])
 
-                # -- stage 3: ONE insert batch + ONE scatter per tile --
+                # -- stage 3: the written prefix, a piece at a time ----
                 keep_q = en_q
                 if por_active:
                     # C3 proviso (timing-immune level markers): a row
@@ -1111,50 +1182,123 @@ class DeviceBFS:
                     # (marker pdepth+1).  A marker <= pdepth means the
                     # successor closes a potential cycle at this or an
                     # earlier level: fall back to full expansion.
-                    # Probed on the PRE-insert slots, so a paused
+                    # Probed on the PRE-insert slots, over the whole
+                    # queue before its first piece commits, so a paused
                     # tile's re-entry sees its own earlier inserts as
                     # marker pdepth+1 (= fresh) and repeats the same
                     # decision bit-identically.  Violations/deadlock/
                     # need stay on the full en_q (stages 1-2 above)
                     is_amp = (en_q & has_cand[pidx_q]
                               & (aid_q == aid_star[pidx_q]))
-                    g = lookup_gids({"slots": slots}, c["gids"],
+                    g = lookup_gids({"slots": c["slots"]}, c["gids"],
                                     fp_q, is_amp)
                     old_i = is_amp & (g >= 0) & (g <= pdepth)
                     amp_bad = jnp.zeros((T,), bool).at[pidx_q].max(old_i)
                     take = has_cand & ~amp_bad
                     keep_q = en_q & (~take[pidx_q]
                                      | (aid_q == aid_star[pidx_q]))
+                # what a tile may commit is known before its first
+                # piece, but for a probe overflow (`ovf`, below)
+                whole = commit0 & (first_bad >= n_act)
                 mcommit = keep_q & (aid_q < first_bad) & commit0
-                # stable first-occurrence dedup: the winner among equal
-                # fingerprints is the earliest queue item (= earliest
-                # action, matching the per-action commit order); the
-                # FPSet claim column then only has to arbitrate
-                # distinct fingerprints racing for one probe slot
-                perm, keep = dedup_batch(fp_q, mcommit)
-                with jax.named_scope(spans.FPSET_INSERT):
-                    canon = jnp.zeros((total_E,),
-                                      bool).at[perm].set(keep)
-                tbl, fresh, ovf_i = insert_core(
-                    {"slots": slots}, fp_q, canon)
-                slots = tbl["slots"]
-                with jax.named_scope(spans.PACK_SCATTER):
-                    dest = jnp.where(fresh, nn + jnp.cumsum(fresh) - 1,
-                                     N_cap).astype(I32)
-                    if pk is not None:
-                        nb = nb.at[dest].set(jax.vmap(pk.pack)(succ_q),
-                                             mode="drop")
-                    else:
-                        for k in nb:
-                            nb[k] = nb[k].at[dest].set(succ_q[k],
-                                                       mode="drop")
-                    nbp = nbp.at[dest].set(base + pidx_q, mode="drop")
-                    nba = nba.at[dest].set(aid_q, mode="drop")
-                    nbprm = nbprm.at[dest].set(lane_q, mode="drop")
-                nfi = fresh.sum()
-                nn = nn + nfi
-                dist = dist + nfi
-                commit = commit0 & (first_bad >= n_act) & ~ovf_i
+
+                def piece(p, st):
+                    """Commit queue lanes [p * P, (p + 1) * P)."""
+                    def cut(v):
+                        return (v if P == Q else
+                                jax.lax.dynamic_slice_in_dim(v, p * P, P))
+                    fp_p, pidx_p, aid_p = cut(fp_q), cut(pidx_q), cut(aid_q)
+                    # stable first-occurrence dedup: the winner among
+                    # equal fingerprints is the earliest queue item (=
+                    # earliest action, matching the per-action commit
+                    # order); the FPSet claim column then only has to
+                    # arbitrate distinct fingerprints racing for one
+                    # probe slot
+                    perm, keep = dedup_batch(fp_p, cut(mcommit))
+                    with jax.named_scope(spans.FPSET_INSERT):
+                        canon = jnp.zeros((P,), bool).at[perm].set(keep)
+                    tbl, fresh, ovf = insert_core(
+                        {"slots": st["slots"]}, fp_p, canon)
+                    nn = st["nn"]
+                    st = dict(st, slots=tbl["slots"], ovf=st["ovf"] | ovf,
+                              nn=nn + fresh.sum(dtype=I32))
+                    with jax.named_scope(spans.PACK_SCATTER):
+                        dest = jnp.where(fresh, nn + jnp.cumsum(fresh) - 1,
+                                         N_cap).astype(I32)
+                        st["nb"] = jax.tree_util.tree_map(
+                            lambda buf, v: buf.at[dest].set(
+                                cut(v), mode="drop"),
+                            st["nb"], rows_q)
+                        st["nbp"] = st["nbp"].at[dest].set(
+                            base + pidx_p, mode="drop")
+                        st["nba"] = st["nba"].at[dest].set(
+                            aid_p, mode="drop")
+                        st["nbprm"] = st["nbprm"].at[dest].set(
+                            cut(lane_q), mode="drop")
+                    if por_active:
+                        # level markers ride the insert UNGATED (mask =
+                        # fresh), mirroring the edge-gid persistence
+                        # rule: insert_core mutates slots even on a
+                        # tile that ends up pausing, so the marker must
+                        # land beside the fingerprint for re-entry to
+                        # probe
+                        st["gids"] = store_gids(
+                            st["slots"], st["gids"], fp_p,
+                            jnp.full((P,), 1, I32) * (pdepth + 1), fresh)
+                    if edges_on:
+                        # edge emission (ISSUE 15): the queue holds
+                        # (source row, action, successor fp) for every
+                        # enabled lane, fresh and duplicate.  Fresh
+                        # states' gids (gid_base + next-buffer row) are
+                        # stored next to their slots UNGATED, mirroring
+                        # insert persistence across a pause, so a lane
+                        # resolves its `dst` after its own piece's
+                        # insert: a duplicate of an earlier piece as a
+                        # duplicate of an earlier tile does.  Triples
+                        # are appended past `edge_n`, which moves only
+                        # when the tile COMMITS (the `gen` discipline):
+                        # a paused tile's re-entry writes over them and
+                        # emits exactly once, with its already-
+                        # committed lanes resolving as duplicates
+                        src_base, gid_base = edge_bases
+                        with jax.named_scope(spans.EDGE_EMIT):
+                            st["gids"] = store_gids(
+                                st["slots"], st["gids"], fp_p,
+                                (gid_base + dest).astype(I32), fresh)
+                            emit = cut(en_q) & whole
+                            dst_g = lookup_gids(
+                                {"slots": st["slots"]}, st["gids"],
+                                fp_p, emit)
+                            edst = jnp.where(
+                                emit, st["edge_n"] + jnp.cumsum(emit) - 1,
+                                E_cap_e)
+                            st["eb_src"] = st["eb_src"].at[edst].set(
+                                (src_base + base + pidx_p).astype(I32),
+                                mode="drop")
+                            st["eb_aid"] = st["eb_aid"].at[edst].set(
+                                aid_p, mode="drop")
+                            st["eb_dst"] = st["eb_dst"].at[edst].set(
+                                dst_g, mode="drop")
+                            st["edge_n"] = st["edge_n"] + emit.sum(
+                                dtype=I32)
+                    return st
+
+                st = {k: c[k] for k in ("slots", "nb", "nbp", "nba",
+                                        "nbprm", "nn")}
+                st["ovf"] = jnp.asarray(False)
+                if por_active or edges_on:
+                    st["gids"] = c["gids"]
+                if edges_on:
+                    for k in ("eb_src", "eb_aid", "eb_dst", "edge_n"):
+                        st[k] = c[k]
+                if P == Q:
+                    n_pieces = jnp.asarray(1, I32)
+                    st = piece(0, st)
+                else:
+                    n_pieces = (q_end + P - 1) // P
+                    st = jax.lax.fori_loop(0, n_pieces, piece, st)
+                ovf_i = st.pop("ovf")
+                commit = whole & ~ovf_i
 
                 # failure cause priority: violation > slot error > bag
                 # growth > expand-capacity > fpset growth (same order
@@ -1174,30 +1318,33 @@ class DeviceBFS:
                 reason = jnp.where(dl & (reason == RUNNING),
                                    R_DEADLOCK, reason)
                 dead_i = jnp.where(dl, base + jnp.argmax(dead), c["dead"])
-                ret = {
+                ret = dict(st)
+                ret.update({
                     "t": jnp.where(commit & (reason == RUNNING),
                                    t + 1, t),
                     "reason": reason, "viol": viol, "dead": dead_i,
                     "grow_aid": grow_aid, "need": need,
-                    "slots": slots,
-                    "nb": nb, "nbp": nbp, "nba": nba, "nbprm": nbprm,
-                    "nn": nn, "dist": dist,
+                    "dist": c["dist"] + (st["nn"] - nn),
                     "gen": c["gen"] + jnp.where(commit, gen_local, 0),
                     "act": c["act"] + jnp.where(
                         commit, cnts.astype(jnp.uint32), jnp.uint32(0)),
-                    # blocks of stage 2 this pass ran, committed or not
+                    # blocks of stage 2 and pieces of stage 3 this pass
+                    # ran, committed or not
                     "blk": c["blk"] + jnp.stack(blk_segs).astype(
                         jnp.uint32),
-                }
+                    "cpl": c["cpl"] + n_pieces.astype(jnp.uint32),
+                })
+                if edges_on:
+                    ret["edge_n"] = jnp.where(commit, st["edge_n"],
+                                              c["edge_n"])
                 if por_active:
                     # gen/act count the KEPT expansions (they feed
                     # states_generated and action_expansions, which
                     # must describe the reduced run); gfull keeps the
                     # unreduced count for the por_cut_ratio gauge, amp
                     # counts rows where the shortcut dropped real work
-                    kept_act = jnp.stack(
-                        [keep_q[qoff[a]:qoff[a + 1]].sum(dtype=I32)
-                         for a in range(n_act)])
+                    kept_act = jnp.zeros((n_act,), I32).at[aid_q].add(
+                        keep_q.astype(I32))
                     ret["gen"] = c["gen"] + jnp.where(
                         commit, kept_act.sum(), 0)
                     ret["act"] = c["act"] + jnp.where(
@@ -1209,47 +1356,6 @@ class DeviceBFS:
                     ret["amp"] = c["amp"] + jnp.where(
                         commit,
                         (take & (n_en_row > 1)).sum(dtype=I32), 0)
-                    # level markers ride the insert UNGATED (mask =
-                    # fresh), mirroring the edge-gid persistence rule:
-                    # insert_core mutates slots even on a tile that
-                    # ends up pausing, so the marker must land beside
-                    # the fingerprint for re-entry to probe
-                    ret["gids"] = store_gids(
-                        slots, c["gids"], fp_q,
-                        jnp.full((total_E,), 1, I32) * (pdepth + 1),
-                        fresh)
-                if edges_on:
-                    # edge emission (ISSUE 15): stage 3 already holds
-                    # (source row, action, successor fp) for every
-                    # enabled lane, fresh and duplicate — the two
-                    # things the two-pass re-expansion used to
-                    # recompute.  Fresh states' gids (gid_base + next-
-                    # buffer row) are stored next to their slots
-                    # UNGATED, mirroring insert persistence across a
-                    # pause; triples append only when the tile COMMITS
-                    # (the `gen` discipline), so a paused tile's
-                    # re-entry emits exactly once, with its already-
-                    # committed lanes resolving as duplicates
-                    src_base, gid_base = edge_bases
-                    with jax.named_scope(spans.EDGE_EMIT):
-                        gids_v = store_gids(
-                            slots, c["gids"], fp_q,
-                            (gid_base + dest).astype(I32), fresh)
-                        emit = en_q & commit
-                        dst_g = lookup_gids({"slots": slots}, gids_v,
-                                            fp_q, emit)
-                        edst = jnp.where(
-                            emit, c["edge_n"] + jnp.cumsum(emit) - 1,
-                            E_cap_e)
-                        ret["gids"] = gids_v
-                        ret["eb_src"] = c["eb_src"].at[edst].set(
-                            (src_base + base + pidx_q).astype(I32),
-                            mode="drop")
-                        ret["eb_aid"] = c["eb_aid"].at[edst].set(
-                            aid_q, mode="drop")
-                        ret["eb_dst"] = c["eb_dst"].at[edst].set(
-                            dst_g, mode="drop")
-                        ret["edge_n"] = c["edge_n"] + emit.sum()
                 return ret
 
             return body
@@ -1333,6 +1439,8 @@ class DeviceBFS:
                 "act": jnp.zeros((len(_caps),), jnp.uint32),
                 "blk": jnp.zeros((len(_caps),), jnp.uint32),
             }
+            if fused:
+                init["cpl"] = jnp.asarray(0, jnp.uint32)
             if eb is not None:
                 init["gids"] = table["gids"]
                 init["eb_src"], init["eb_aid"], init["eb_dst"] = eb
@@ -1447,11 +1555,12 @@ class DeviceBFS:
         (each calibration is a recompile); caps can only shrink onto
         real observations, so a later bigger tile simply triggers an
         exact growth event.  Cap changes never affect results — only
-        how many queue lanes are padding.  Since ISSUE 28 that padding
-        costs the queue and stage 3 alone: stage 2 runs the blocks
-        that hold enabled lanes (the occupancy gauge's denominator),
-        so a calibration no longer moves the expand stage or the
-        gauge, and a start that never shrinks (`static_cap`) is cheap."""
+        how many cap lanes are padding.  That padding costs the
+        headroom gate alone (CAP_HEADROOM): stage 2 runs the blocks
+        that hold enabled lanes (the occupancy gauge's denominator)
+        and stage 3 the pieces of the queue they wrote, so a
+        calibration moves neither stage nor the gauges, and a start
+        that never shrinks (`static_cap`) is cheap."""
         if self.commit != "fused" or level_states < 4 * self.tile:
             return False
         kern, T = self.kern, self.tile
@@ -1478,24 +1587,28 @@ class DeviceBFS:
     def _reset_accounting(self):
         """Run-scoped counters that the tickets feed: per-action
         expansions (the on-device accumulator), tiles committed, expand
-        lanes the device ran, and the expand blocks run of those the
-        caps hold."""
+        lanes the device ran, the expand blocks run of those the caps
+        hold, and the commit lanes run of those."""
         n_act = len(self.kern.action_names)
         self._act_counts = np.zeros(n_act, np.int64)
         self._blocks_act = np.zeros(n_act, np.int64)
         self._blocks_cap = 0
+        self._commit_run = 0
+        self._commit_cap = 0
         self._tiles_done = 0
         self._lanes_disp = 0
 
-    def _account_blocks(self, blk):
+    def _account_blocks(self, blk, pieces):
         """One collected ticket's per-action counts of expand blocks
-        (fused commit; zeros from the per-action body): the lanes the
-        device really expanded, paused passes included."""
+        and its count of commit pieces (fused commit; zeros from the
+        per-action body): the lanes the device really expanded and
+        committed over, paused passes included."""
         blk = np.asarray(blk, np.int64)
+        caps = self._expand_caps()
         self._blocks_act += blk
         self._lanes_disp += sum(
-            int(n) * block_rows(cap)
-            for n, cap in zip(blk, self._expand_caps()))
+            int(n) * block_rows(cap) for n, cap in zip(blk, caps))
+        self._commit_run += int(pieces) * piece_lanes(sum(caps))
 
     def _account_tiles(self, n_tiles):
         """`n_tiles` frontier tiles were committed under the current
@@ -1507,6 +1620,7 @@ class DeviceBFS:
         if self.commit == "fused":
             self._blocks_cap += int(n_tiles) * sum(
                 -(-cap // block_rows(cap)) for cap in caps)
+            self._commit_cap += int(n_tiles) * sum(caps)
         else:
             self._lanes_disp += int(n_tiles) * sum(caps)
 
@@ -1893,7 +2007,7 @@ class DeviceBFS:
             # ONE host round-trip for all control scalars — separate
             # int() pulls cost one device round-trip each
             vals = [o["reason"], o["t"], o["nn"], o["gen"], o["dist"],
-                    o["act"], o["need"], o["blk"]]
+                    o["act"], o["need"], o["blk"], o.get("cpl", 0)]
             if self._por_active:
                 vals += [o["gfull"], o["amp"]]
             return jax.device_get(vals)
@@ -1958,11 +2072,11 @@ class DeviceBFS:
                 fp_count += dist_add
                 self._act_counts += np.asarray(sc[5], np.int64)
                 self._fold_need(sc[6])
-                self._account_blocks(sc[7])
+                self._account_blocks(sc[7], sc[8])
                 if self._por_active:
                     self._por_kept += gen_add
-                    self._por_full += int(sc[8])
-                    self._por_amp += int(sc[9])
+                    self._por_full += int(sc[9])
+                    self._por_amp += int(sc[10])
 
                 if reason == RUNNING:
                     obs.progress(depth=depth, distinct=fp_count,
@@ -2243,6 +2357,12 @@ class DeviceBFS:
         if self.commit == "fused" and acts is not None:
             obs.count("expand_blocks_run", int(self._blocks_act.sum()))
             obs.count("expand_blocks_cap", self._blocks_cap)
+            # and what stage 3 walked of the queue the caps allow
+            obs.count("commit_lanes_run", self._commit_run)
+            obs.count("commit_lanes_cap", self._commit_cap)
+            if self._commit_run:
+                obs.gauge("commit_occupancy", round(
+                    float(acts.sum()) / self._commit_run, 4))
         obs.gauge("inserts_per_tile",
                   1 if self.commit == "fused"
                   else len(self.kern.action_names))

@@ -398,7 +398,8 @@ class PagedBFS(DeviceBFS):
 
         def pull(o):
             keys = [o["reason"], o["t"], o["nn"], o["gen"],
-                    o["dist"], o["act"], o["need"], o["blk"]]
+                    o["dist"], o["act"], o["need"], o["blk"],
+                    o.get("cpl", 0)]
             if self._edges_on:
                 keys.append(o["edge_n"])
             if self._por_active:
@@ -564,13 +565,13 @@ class PagedBFS(DeviceBFS):
                     fp_count += dist_add
                     self._act_counts += np.asarray(sc[5], np.int64)
                     self._fold_need(sc[6])
-                    self._account_blocks(sc[7])
+                    self._account_blocks(sc[7], sc[8])
                     if self._edges_on:
-                        n_edge = int(sc[8])
+                        n_edge = int(sc[9])
                     if self._por_active:
                         self._por_kept += gen_add
-                        self._por_full += int(sc[8])
-                        self._por_amp += int(sc[9])
+                        self._por_full += int(sc[9])
+                        self._por_amp += int(sc[10])
 
                     if reason == RUNNING:
                         obs.progress(depth=depth, distinct=fp_count,
