@@ -35,4 +35,20 @@ def test_paged_native_exact_levels(small_native, small_pin, tmp_path,
         == sum(pin[1:])
     row = eng._state_row_bytes()
     assert all(e["bytes"] == e["rows"] * row for e in spills)
-    assert res.metrics["counters"]["spill_rows"] == eng.spill_rows
+    c = res.metrics["counters"]
+    assert c["spill_rows"] == eng.spill_rows
+    assert c["spills"] == len(spills)
+    # and every parent came in once, in a journaled page of at most a
+    # chunk's rows: a page a chunk, each the one shape (one block of
+    # 512 rows in; out, the rows and their pointers)
+    cc = CHUNK_TILES * eng.tile
+    ins = [e for e in read_journal(jp) if e["event"] == "page_in"]
+    assert [e["rows"] for e in ins] == [
+        min(cc, n - at) for n in pin[:DEPTH] for at in range(0, n, cc)]
+    assert all(e["bytes"] == e["rows"] * row for e in ins)
+    assert c["page_ins"] == len(ins)
+    assert c["page_in_rows"] == sum(pin[:DEPTH])
+    assert c["page_in_bytes"] == sum(pin[:DEPTH]) * row
+    assert max(e["rows"] for e in spills) <= cc
+    assert c["page_shapes"] == 2
+    assert len(eng.page_shapes["in"]) == len(eng.page_shapes["out"]) == 1

@@ -183,3 +183,40 @@ def test_sharded_start_programs(spec, topo, no_cache):
         jax.ShapeDtypeStruct((1,), jnp.bool_, sharding=rep)),
         f"sharded Init insert D={D} slots={eng.fp_cap}")
 
+
+
+@pytest.mark.parametrize("what", ["insert", "stats", "page-out"])
+def test_paged_deployment_programs(one_chip, no_cache, what):
+    """The programs beside the level program that touch the paged
+    configuration's FPSet and pages (ISSUE 31), at its capacities:
+    each must fit the chip beside a table of 1<<28 slots — 8.59 GB
+    there, not 5.37: the compiler pads a slot's 5 words to 8."""
+    import json
+
+    from tpuvsr.engine import fpset
+    from tpuvsr.engine.paged_bfs import _drain_page
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "vsr-defect-paged.json")) as f:
+        caps = json.load(f)["assumed"]["engine"]["paged"]
+    cap, nc = caps["fpset_capacity"], caps["next_capacity"]
+    rows = caps["chunk_tiles"] * caps["tile_size"]
+    assert cap == 1 << 28
+
+    def s(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if what == "insert":
+        compiled = _compile(fpset.insert_batch.lower(
+            {"slots": s((cap, 5))}, s((2048, 4)), s((2048,), jnp.bool_)),
+            f"fpset.insert_batch cap={cap}")
+        table = compiled.memory_analysis().alias_size_in_bytes
+        assert table == cap * 32            # donated, in place, padded
+    elif what == "stats":
+        compiled = _compile(fpset._occupied_displaced.lower(s((cap, 5))),
+                            f"fpset._occupied_displaced cap={cap}")
+        # a pass in pieces: no temporary the size of a table column
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 26
+    else:
+        col = s((nc,), jnp.int32)
+        _compile(_drain_page.lower((s((nc, 119)), col, col, col),
+                                   s((), jnp.int32), rows=rows),
+                 f"paged page-out rows={rows} of {nc}")

@@ -300,6 +300,7 @@ class DeviceBFS:
         # recompiles); dict forms are resolved against the kernel's
         # action names once the kernel exists (_build)
         self.expand_mults = expand_mults
+        self._mults_given = expand_mults is not None
         self._expand_mult_default = expand_mult
         # level-kernel commit mode (ISSUE 10 tentpole).  "fused" (the
         # default) restructures the tile pass into three stages —
@@ -408,7 +409,8 @@ class DeviceBFS:
             if self.expand_caps is None:
                 # static start; growth events (and the level-boundary
                 # calibration) re-cap from the observed per-tile maxima
-                self.expand_caps = [static_cap(self.tile, t) for t in tl]
+                self.expand_caps = [self._cap_floor(a, t)
+                                    for a, t in enumerate(tl)]
                 # static fanout bounds (ISSUE 13): the bounds pass
                 # proves at most `fanout` lanes of an action enable
                 # per state, so tile*fanout is a sound initial cap —
@@ -571,6 +573,19 @@ class DeviceBFS:
                 row))
             self._pack_stages[rows] = stage
         return self._pack_stages[rows]
+
+    def _cap_floor(self, a, full):
+        """Action `a`'s fused cap before the guard matrix has observed
+        anything, and the floor calibration keeps: the static start,
+        or the caller's multiplier of the tile where that is more (a
+        pre-calibrated `expand_mults` skips the growth rebuild of a
+        depth the caller knows, here as under the per-action
+        commit)."""
+        cap = static_cap(self.tile, full)
+        if self._mults_given:
+            cap = max(cap, min(full, _align8(
+                self.tile * self.expand_mults[a])))
+        return cap
 
     def _expand_caps(self):
         """Per-action enabled-lane compaction capacities, in lanes.
@@ -1568,10 +1583,11 @@ class DeviceBFS:
         # below the static start: a calibration that shrank onto the
         # exact maxima was undone by growth events over the next
         # levels, as frontier states grew richer and idle actions woke
-        tgt = [max(static_cap(T, T * kern._lane_count(n)),
+        tgt = [max(self._cap_floor(a, T * kern._lane_count(n)),
                    min(T * kern._lane_count(n),
                        _align8(CAP_HEADROOM * int(s))))
-               for n, s in zip(kern.action_names, self._need_seen)]
+               for a, (n, s) in enumerate(
+                   zip(kern.action_names, self._need_seen))]
         cur = self._expand_caps()
         if sum(tgt) * 5 > sum(cur) * 4:
             return False

@@ -165,33 +165,45 @@ def insert_core(table, fps, mask):
 insert_batch = partial(jax.jit, donate_argnums=(0,))(insert_core)
 
 
+STATS_PIECE = 1 << 22
+
+
+@jax.jit
+def _occupied_displaced(slots):
+    """(occupied, displaced) of a ``slots`` array, reduced where it
+    lies, `STATS_PIECE` slots at a time (one pass over 1<<28 slots
+    keeps 1.3 GB of temporaries).  Stored words are already keyed, so
+    `_slot_hash` of a slot's first four is its probe-chain start.
+    uint32 sums: exact to 2**32 slots."""
+    cap = slots.shape[0]
+    piece = min(cap, STATS_PIECE)        # both powers of two
+
+    def body(i, acc):
+        at = i * piece
+        part = jax.lax.dynamic_slice_in_dim(slots, at, piece)
+        occ = part[:, 0] != 0
+        home = _slot_hash(part[:, :4]) & jnp.uint32(cap - 1)
+        idx = at.astype(U32) + jnp.arange(piece, dtype=U32)
+        return (acc[0] + occ.sum(dtype=U32),
+                acc[1] + (occ & (home != idx)).sum(dtype=U32))
+    zero = jnp.zeros((), U32)
+    return jax.lax.fori_loop(0, cap // piece, body, (zero, zero))
+
+
 def table_stats(slots):
-    """Host-side occupancy/collision stats of a table's ``slots``
-    array (device or numpy).  "Displaced" slots are occupied slots not
-    sitting at their probe-chain start — the linear-probing collision
-    measure the obs layer reports as ``fpset_collision_rate``.  Costs
-    one table pull; callers gate it on metrics being requested."""
-    s = np.asarray(slots)
-    cap = int(s.shape[0])
-    occ = s[:, 0] != 0
-    n = int(occ.sum())
-    out = {"capacity": cap, "occupied": n,
-           "occupancy": n / cap if cap else 0.0,
-           "displaced": 0, "collision_rate": 0.0}
-    if n == 0:
-        return out
-    keyed = s[occ, :4].astype(np.uint32)
-    with np.errstate(over="ignore"):
-        # numpy replica of _slot_hash (stored words are already keyed)
-        h = keyed[:, 0] ^ (keyed[:, 1] * np.uint32(0x9E3779B1))
-        h = h ^ (keyed[:, 2] * np.uint32(0x85EBCA6B)) ^ (keyed[:, 3] >> 5)
-        h = h ^ (h >> 15)
-        home = (h * np.uint32(0x27D4EB2F)) & np.uint32(cap - 1)
-    idx = np.nonzero(occ)[0].astype(np.uint32)
-    displaced = int((home != idx).sum())
-    out["displaced"] = displaced
-    out["collision_rate"] = displaced / n
-    return out
+    """Occupancy/collision stats of a table's ``slots`` array (device
+    or numpy).  "Displaced" slots are occupied slots not sitting at
+    their probe-chain start — the linear-probing collision measure the
+    obs layer reports as ``fpset_collision_rate``.  Reduced on the
+    device the table lies on: two scalars come back, never the table
+    (`tests/test_fpset_stats.py` keeps the numpy walk as the
+    reference)."""
+    cap = int(slots.shape[0])
+    n, displaced = (int(x) for x in jax.device_get(
+        _occupied_displaced(slots)))
+    return {"capacity": cap, "occupied": n, "occupancy": n / cap,
+            "displaced": displaced,
+            "collision_rate": displaced / n if n else 0.0}
 
 
 def query_core(table, fps, mask):

@@ -7,14 +7,24 @@ it queue/state storage, not fingerprints
 hits sooner: at ~7 KiB per dense state one chip's spare HBM holds
 under a million frontier states, while a defect-scale BFS level can
 exceed that by orders of magnitude.  This engine keeps ONLY the
-fingerprint set resident in device memory (not the binding constraint:
-16 GB of HBM holds ~800 M fingerprint slots) and pages the frontier
-through the device in fixed-size chunks:
+fingerprint set resident in device memory and pages the frontier
+through the device in fixed-size chunks.  (The set is not the binding
+constraint, but it is dearer than its 20 bytes a slot: the v5e lays
+`u32[N, 5]` out with the 5 words padded to 8, 32 bytes a slot, so a
+table of 1<<28 slots is 8.59 GB of the chip's 16 — the largest power
+of two that fits, 134 M states at 50 % load — PERF.md §4, PR 31.)
 
   host frontier (numpy; the 125 GB host holds ~17 M dense states)
-      --chunk in-->  device chunk buffer [chunk_tiles x tile states]
+      --page in-->  device chunk buffer [chunk_tiles x tile states]
       --level kernel (DeviceBFS._make_level, unchanged)-->
-      next-frontier buffer fills --> DRAIN to host, reset, continue
+      next-frontier buffer fills --> PAGE OUT to host, reset, continue
+
+A page has ONE shape, in and out (ISSUE 31): a chunk goes in as a
+block of `chunk_tiles x tile` rows whatever it holds, and the next
+buffer's committed rows come out in blocks of as many, cut by one
+program whose offset is a scalar (`_drain_page`); the host pads the
+one and cuts the tail of the other.  So a run builds no program for a
+page; before, every page of a new length was three to five.
 
 The drain reuses the level kernel's existing pause protocol: the
 headroom check that raised R_NEXT_GROW in the resident engine (grow
@@ -38,6 +48,7 @@ snapshots cheap).
 from __future__ import annotations
 
 import time
+from functools import partial
 
 import numpy as np
 
@@ -56,6 +67,24 @@ from .fpset import grow
 from .spill import EdgeCSR
 
 
+@partial(jax.jit, static_argnames=("rows",))
+def _drain_page(bufs, start, rows):
+    """Rows [start, start + rows) of the next buffers: the frontier
+    rows as they lie there, and the three trace-pointer columns as one
+    [3, rows] array.  `start` is a traced scalar, so every page-out of
+    an engine is this one program; the caller keeps start + rows
+    inside the buffers (a slice past the end would be clamped)."""
+    def cut(v):
+        return jax.lax.dynamic_slice_in_dim(v, start, rows, axis=0)
+    nb, nbp, nba, nbprm = bufs
+    return jax.tree.map(cut, nb), jnp.stack(
+        [cut(nbp), cut(nba), cut(nbprm)])
+
+
+def _shape_key(tree):
+    return tuple(np.shape(v) for v in jax.tree.leaves(tree))
+
+
 class PagedBFS(DeviceBFS):
     """DeviceBFS with a host-RAM frontier paged through the device.
 
@@ -64,9 +93,28 @@ class PagedBFS(DeviceBFS):
     enumeration pass the device liveness graph builder reuses
     (engine/device_liveness.py)."""
 
+    # what a caller may have sized its capacities by, and names in
+    # `requires` (ISSUE 31).  fixed_page_shapes: a page goes in as one
+    # [chunk_tiles x tile]-row block and comes out as blocks of the
+    # same row count, whatever it holds, so a run builds no program
+    # per page (before: three to five eager programs for every page
+    # of a new length).  device_table_stats: the end of a run reduces
+    # the FPSet's occupancy on the device and pulls two scalars, not
+    # the table (5.4 GB at 1<<28 slots)
+    PROVIDES = frozenset({"fixed_page_shapes", "device_table_stats"})
+
     def __init__(self, *args, retain_levels=False, spill_dir=None,
                  spill_ram_rows=None, edges=False, edge_capacity=None,
-                 edge_spill_dir=None, edge_ram_rows=None, **kwargs):
+                 edge_spill_dir=None, edge_ram_rows=None, requires=(),
+                 **kwargs):
+        # refuse before anything is built, as ShardedBFS does
+        missing = sorted(set(requires) - self.PROVIDES)
+        if missing:
+            raise TLAError(f"this PagedBFS does not provide {missing} "
+                           f"(it provides {sorted(self.PROVIDES)})")
+        # the shapes of every page this engine moved, and this run
+        self.page_shapes = {"in": set(), "out": set()}
+        self._run_page_shapes = set()
         self.retain_levels = retain_levels
         self.level_blocks = []
         # streamed edge emission (ISSUE 15): the fused commit's stage 3
@@ -186,7 +234,61 @@ class PagedBFS(DeviceBFS):
         return {k: host_front[k][i] for k in host_front}
 
     def _chunk_cap(self):
+        """Rows of a page, in and out."""
         return self.chunk_tiles * self.tile
+
+    def _floor_next_cap(self):
+        """The next buffer holds at least one tile's commit on top of
+        the caps (the level kernel's headroom test), in whole pages:
+        the last page-out of a full buffer then ends at its end."""
+        page = self._chunk_cap()
+        need = max(self.next_cap, self._total_E() + self.tile)
+        self.next_cap = -(-need // page) * page
+
+    def _note_shape(self, way, page):
+        key = _shape_key(page)
+        self.page_shapes[way].add(key)
+        self._run_page_shapes.add((way, key))
+
+    def _page_in(self, block, n):
+        """The first `n` rows of a host block on the device as one
+        page: always `_chunk_cap()` rows (the tail zeros, which the
+        level kernel masks by its row count), so no page's length
+        makes a program."""
+        cc = self._chunk_cap()
+
+        def pad(v):
+            v = np.asarray(v)
+            if n == cc:
+                return v
+            out = np.zeros((cc,) + v.shape[1:], v.dtype)
+            out[:n] = v
+            return out
+        # blocked on: the span around this is the transfer's time
+        page = jax.block_until_ready(
+            jax.device_put(jax.tree.map(pad, block)))
+        self._note_shape("in", page)
+        return page
+
+    def _page_out(self, bufs, n):
+        """The first `n` rows of the next buffers as host arrays, a
+        page at a time: (rows, parent, action, param) per page, the
+        tail of the last page cut here.  One program for every page
+        (`_drain_page`; the buffers are whole pages long, see
+        `_floor_next_cap`), and the copies of one call overlap."""
+        cc = self._chunk_cap()
+        pages = [_drain_page(bufs, np.int32(at), rows=cc)
+                 for at in range(0, n, cc)]
+        self._note_shape("out", pages[0])
+        out = []
+        for i, (rows, meta) in enumerate(jax.device_get(pages)):
+            take = min(cc, n - i * cc)
+            if take < cc:
+                # copies: a view would keep the whole page alive
+                rows = jax.tree.map(lambda v: v[:take].copy(), rows)
+                meta = meta[:, :take].copy()
+            out.append((rows, meta[0], meta[1], meta[2]))
+        return out
 
     def _total_E(self):
         # same caps the level kernel compacts with (fused commit: the
@@ -240,6 +342,7 @@ class PagedBFS(DeviceBFS):
 
         self.spill_count = 0     # drains triggered by a full buffer
         self.spill_rows = 0      # total rows paged out to host
+        self._run_page_shapes = set()   # of this run's pages
         self.level_blocks = []   # fresh per run (retain_levels)
         if self._edges_on:
             # incremental host CSR builder the edge drains feed
@@ -361,7 +464,7 @@ class PagedBFS(DeviceBFS):
             self.level_sizes = [n0]
 
         last_checkpoint = time.time()
-        dev_chunk = None        # allocated lazily; realloc on bag growth
+        dev_chunk = None        # the page on the device
         # the level kernel refuses to commit a tile unless the next
         # buffer has total_E rows of headroom, so total_E + one tile's
         # worth is the functional floor; size it larger (the default
@@ -370,7 +473,7 @@ class PagedBFS(DeviceBFS):
         # max_msgs from the checkpoint can enlarge total_E) and
         # re-floored on every in-run rebuild — a stale floor live-locks
         # the drain loop (commit never true with an empty buffer).
-        self.next_cap = max(self.next_cap, self._total_E() + self.tile)
+        self._floor_next_cap()
         with obs.span(spans.INIT):
             bufs = self._alloc_bufs(self.next_cap)
         # edge append buffer (ISSUE 15): same total_E + one-tile floor
@@ -438,23 +541,23 @@ class PagedBFS(DeviceBFS):
                 nonlocal n_next_total, n_next
                 if n_next == 0:
                     return
-                nb, nbp, nba, nbprm = bufs
-                with obs.span(spans.HOST_SYNC):
-                    rows, par, act, prm = jax.device_get(
-                        (nb[:n_next] if self._pk is not None
-                         else {k: v[:n_next] for k, v in nb.items()},
-                         nbp[:n_next], nba[:n_next], nbprm[:n_next]))
-                drained.append(np.asarray(rows)
-                               if self._pk is not None else
-                               {k: np.asarray(v) for k, v in rows.items()})
-                # par is chunk-relative; lift to level-relative now
-                d_par.append(np.asarray(par, np.int64) + chunk_start)
-                d_act.append(np.asarray(act))
-                d_prm.append(np.asarray(prm))
+                if self.pipe_window > 1:
+                    # the chain tip may still run (the dispatch past
+                    # the chunk's end): that wait is the device's work
+                    with obs.span(spans.INFLIGHT):
+                        jax.block_until_ready(bufs[1])
+                with obs.span(spans.PAGE_OUT, depth=depth, rows=n_next):
+                    pages = self._page_out(bufs, n_next)
+                row_bytes = self._state_row_bytes()
+                for rows, par, act, prm in pages:
+                    drained.append(rows)
+                    # par is chunk-relative; lift to level-relative now
+                    d_par.append(par.astype(np.int64) + chunk_start)
+                    d_act.append(act)
+                    d_prm.append(prm)
+                    obs.spill(depth, len(par), len(par) * row_bytes)
                 n_next_total += n_next
                 self.spill_rows += n_next
-                obs.spill(depth, n_next,
-                          n_next * self._state_row_bytes())
                 n_next = 0
 
             def refloor_edges():
@@ -493,22 +596,11 @@ class PagedBFS(DeviceBFS):
 
             def put_chunk():
                 nonlocal dev_chunk
-                cc = self._chunk_cap()
                 block = self._front_block(host_front, chunk_start,
                                           n_c)
-                if self._pk is not None:
-                    if dev_chunk is None:
-                        dev_chunk = jnp.zeros((cc, self._pk.words),
-                                              jnp.uint32)
-                    dev_chunk = dev_chunk.at[:n_c].set(block)
-                    return
-                if dev_chunk is None:
-                    dev_chunk = {
-                        k: jnp.zeros((cc,) + np.shape(v), np.int32)
-                        for k, v in self.codec.zero_state().items()}
-                dev_chunk = {
-                    k: dev_chunk[k].at[:n_c].set(block[k])
-                    for k in dev_chunk}
+                with obs.span(spans.PAGE_IN, depth=depth, rows=n_c):
+                    dev_chunk = self._page_in(block, n_c)
+                obs.page_in(depth, n_c, n_c * self._state_row_bytes())
 
             while chunk_start < n_front and stop is None:
                 n_c = min(self._chunk_cap(), n_front - chunk_start)
@@ -655,9 +747,7 @@ class PagedBFS(DeviceBFS):
                             self.codec.pad_msgs(b, old)
                             for b in self.level_blocks]
                         self._pad_init_dense(old)
-                        dev_chunk = None
-                        self.next_cap = max(
-                            self.next_cap, self._total_E() + self.tile)
+                        self._floor_next_cap()
                         bufs = self._alloc_bufs(self.next_cap)
                         if self._edges_on:
                             refloor_edges()
@@ -678,7 +768,7 @@ class PagedBFS(DeviceBFS):
                                           emit)
                         if self.next_cap < self._total_E() + self.tile:
                             spill()
-                            self.next_cap = self._total_E() + self.tile
+                            self._floor_next_cap()
                             bufs = self._alloc_bufs(self.next_cap)
                             pend_nn = jnp.asarray(0, I32)
                         if self._edges_on and self.edge_cap < \
@@ -832,6 +922,7 @@ class PagedBFS(DeviceBFS):
 
 
     def _finish(self, res, obs, fp_count, table=None, fp_cap=None):
+        obs.count("page_shapes", len(self._run_page_shapes))
         if self._spill_dir is not None:
             # cumulative bytes the run wrote to the disk tier (files
             # of consumed levels included), then release what is left

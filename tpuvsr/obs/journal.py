@@ -37,6 +37,9 @@ EVENT_REQUIRED = {
                    "elapsed_s"),
     "checkpoint": ("path", "depth", "distinct", "elapsed_s"),
     "spill": ("depth", "rows", "bytes", "elapsed_s"),
+    # the paged engine's page-ins (ISSUE 31): one frontier page, host
+    # RAM -> device; `spill` is the way out
+    "page_in": ("depth", "rows", "bytes", "elapsed_s"),
     # streamed edge emission (ISSUE 15): a committed block of behavior-
     # graph (src, action, dst) triples drained off the device append
     # buffer into the host CSR builder — the edge-stream spill analog

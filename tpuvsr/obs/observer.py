@@ -340,6 +340,17 @@ class RunObserver:
                            bytes=int(nbytes),
                            elapsed_s=round(self.elapsed(), 3), **extra)
 
+    def page_in(self, depth, rows, nbytes):
+        """A frontier page moved up a tier: host RAM -> device (the
+        paged engine's chunk).  `nbytes` counts the rows the page
+        holds, as ``spill`` does; the transfer is a whole page."""
+        self.count("page_ins")
+        self.count("page_in_rows", rows)
+        self.count("page_in_bytes", nbytes)
+        self.journal.write("page_in", depth=int(depth), rows=int(rows),
+                           bytes=int(nbytes),
+                           elapsed_s=round(self.elapsed(), 3))
+
     def grow(self, what, to):
         """A growth pause (message table / FPSet / buffers / exchange
         bucket): counters + journal; the engine logs its own wording."""

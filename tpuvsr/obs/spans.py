@@ -26,6 +26,8 @@ HOST_SYNC = "tpuvsr.engine.host_sync"
 CHECKPOINT = "tpuvsr.engine.checkpoint"
 INIT = "tpuvsr.engine.init"
 GRAPH_BUILD = "tpuvsr.engine.graph_build"
+PAGE_IN = "tpuvsr.engine.page_in"
+PAGE_OUT = "tpuvsr.engine.page_out"
 
 #: host span -> phase key of the ``tpuvsr-metrics/1`` document
 ENGINE_SPANS = {
@@ -33,10 +35,12 @@ ENGINE_SPANS = {
     BUILD: "compile",           # first call of a fresh jit
     DISPATCH: "dispatch",       # enqueue of a built program
     INFLIGHT: "inflight",       # blocked wait on the oldest ticket
-    HOST_SYNC: "host_sync",     # scalar pulls, lvl_buf reads
+    HOST_SYNC: "host_sync",     # scalar pulls, lvl_buf reads, edge drains
     CHECKPOINT: "checkpoint",   # level-boundary snapshot write
     INIT: "init",               # Init registration + buffer allocation
     GRAPH_BUILD: "graph_build",  # liveness: behavior-graph construction
+    PAGE_IN: "page_in",         # paged engine: one frontier page, host -> device
+    PAGE_OUT: "page_out",       # paged engine: the next buffer's pages, device -> host
 }
 
 JOB = "tpuvsr.service.job"

@@ -439,6 +439,9 @@ class Supervisor:
                     if p.kind not in (p.VAR_POSITIONAL, p.VAR_KEYWORD):
                         accepted.add(name)
             accepted.discard("self")
+            # names what the sharded capacities were sized by: nothing
+            # the paged engine is asked to provide
+            accepted.discard("requires")
             for k in [k for k in self._engine_kwargs
                       if k not in accepted]:
                 self._engine_kwargs.pop(k)
