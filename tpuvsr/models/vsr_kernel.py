@@ -793,7 +793,8 @@ class VSRKernel:
     # enabledness over the full [T, n_lanes] lane space at ~1% of the
     # cost of building successors, then expand only the enabled lanes.
     # Kept in lockstep with the action bodies; `test_guard_fns_match`
-    # holds them to the actions differentially.
+    # holds them to the actions differentially (and, with no reference
+    # mounted, tests/test_native_guard_table.py holds SendGetState's).
     # ==================================================================
     def _recv_guard(self, st, k, mtype):
         return ((st["m_present"][k] == 1) & (st["m_count"][k] > 0)
@@ -873,24 +874,61 @@ class VSRKernel:
         return (self._is_primary(st, i, i + 1) & (st["status"][i] == NORMAL)
                 & (st["commit"][i] < st["op"][i]) & committed)
 
+    def guard_send_get_state_table(self, st):
+        """[M, R] bool: SendGetState's guard for every (message k,
+        rDest d + 1) at once; lane ``k * R + d`` of the action.
+
+        Everything here depends on the state alone, never on a lane:
+        under a vmap over lanes it stays unbatched and is computed
+        once a state.  SendOnce (VSR.tla:250-252) asks whether the
+        GetState record the lane would send is in the bag's domain
+        already, tombstones included.  The action's truncation
+        rewrites one replica's log, op and view and leaves the bag
+        alone, so the membership test may read the parent state.  Of
+        that record only four columns vary — view (the Prepare's), op
+        (the truncated log's length), dest (rDest) and source (the
+        Prepare's dest) — and every other column, entry and log word
+        is ``_row(M_GETSTATE)``'s.  So the bag is read once for the
+        slots that are GetState records in all but those four ([M]),
+        each message is held against each such slot on three of them
+        ([M, M]) and the fourth, dest, spreads a hit over rDest."""
+        R = self.R
+        hdr = st["m_hdr"]                                       # [M, NHDR]
+        tmpl = self._row(M_GETSTATE)
+        vary = np.zeros((self.NHDR,), bool)
+        vary[[H_VIEW, H_OP, H_DEST, H_SRC]] = True
+        gs = ((st["m_present"] == 1)
+              & ((hdr == tmpl["hdr"]) | vary).all(-1)
+              & (st["m_entry"] == tmpl["entry"]).all(-1)
+              & (st["m_log"] == tmpl["log"]).all((-1, -2))
+              & (st["m_log_len"] == tmpl["log_len"])
+              & (st["m_has_log"] == tmpl["has_log"]))           # [M]
+        # the replica columns at each message's dest: R is small, a
+        # one-hot select and not a gather a message
+        r = hdr[:, H_DEST]                                      # [M]
+        dests = jnp.arange(1, R + 1, dtype=I32)
+        at_r = jnp.clip(r, 1, R)[:, None] == dests              # [M, R]
+
+        def at(col):
+            return jnp.where(at_r, st[col], 0).sum(-1)
+        view, op = at("view"), at("op")
+        cheap = (self._recv_guard(st, ..., M_PREPARE)            # all k
+                 & (self._primary(view, R) != r)
+                 & (at("status") == NORMAL)
+                 & (hdr[:, H_VIEW] > view) & (hdr[:, H_OP] > op + 1))
+        trunc = jnp.minimum(at("commit"), at("log_len"))
+        hit = (gs & (hdr[:, H_VIEW] == hdr[:, H_VIEW, None])
+               & (hdr[:, H_OP] == trunc[:, None])
+               & (hdr[:, H_SRC] == r[:, None]))                 # [k, k']
+        to_d = hdr[:, H_DEST, None] == dests                    # [k', R]
+        blocked = (hit[:, :, None] & to_d).any(1)               # [M, R]
+        return cheap[:, None] & (r[:, None] != dests) & ~blocked
+
     def guard_send_get_state(self, st, lane):
-        k = lane // self.R
-        rdest = lane % self.R + 1
-        hdr = st["m_hdr"][k]
-        r = hdr[H_DEST]
-        i = jnp.clip(r - 1, 0, self.R - 1)
-        en = (self._recv_guard(st, k, M_PREPARE)
-              & ~self._is_primary(st, i, r) & (r != rdest)
-              & (st["status"][i] == NORMAL)
-              & (hdr[H_VIEW] > st["view"][i])
-              & (hdr[H_OP] > st["op"][i] + 1))
-        # SendOnce: the GetState record must not already be in the bag
-        # (VSR.tla:250-252); the bag is unchanged by the truncation, so
-        # the membership test can run against the parent state
-        trunc = jnp.minimum(st["commit"][i], st["log_len"][i])
-        row = self._row(M_GETSTATE, view=hdr[H_VIEW], op=trunc,
-                        dest=rdest, src=r)
-        return en & ~self._row_eq(st, row).any()
+        # on the v5e this read of 96 lanes from 8,192 tables costs
+        # nothing beside the table (0.26 ms a chunk with it, 0.28 ms
+        # for the rows taken whole; CHANGES.md, PR 32)
+        return self.guard_send_get_state_table(st).reshape(-1)[lane]
 
     def guard_receive_get_state(self, st, k):
         i = self._dest_i(st, k)
