@@ -3,15 +3,17 @@
 Every exact pin so far used shrunken constants; the reference's shipped
 flagship config — R=3, C=1, |Values|=2, StartViewOnTimerLimit=2,
 RestartEmptyLimit=0, SYMMETRY symmValues ON, INVARIANT
-AcknowledgedWriteNotLost (VSR.cfg:4-8,29-37, loaded UNCHANGED) — has
-never been run to fixpoint.  This script runs it through the paged
-engine in resumable wall-clock windows (checkpoint scripts/shipped_ckpt)
-and records the fixpoint when reached, or an honest bounded pin.
+AcknowledgedWriteNotLost (VSR.cfg:4-8,29-37; re-typed as
+benchmark/configs/vsr-shipped.cfg and loaded through the kernel-native
+spec, no .tla) — has never been run to fixpoint.  This script runs it
+through the paged engine in resumable wall-clock windows (checkpoint
+scripts/shipped_ckpt) and records the fixpoint when reached, or an
+honest bounded pin with EVERY level size: the next pin past depth 15
+(benchmark/oracles/shipped_levels.json) is this one command.
 
-This is also the first at-scale run with symmetry canonicalization ON
-(|Values|=2 -> min over 2 permutations per fingerprint).
-
-Writes scripts/shipped_pin.json.
+Writes scripts/shipped_pin_levels.json.  scripts/shipped_pin.json is
+the record of the one run an earlier round made with the spec loaded
+from VSR.tla (its last eight level sizes); this script leaves it alone.
 
 Usage: [JAX_PLATFORMS=cpu] python scripts/shipped_pin.py [seconds] [tile]
            [chunk_tiles]
@@ -37,11 +39,10 @@ tile = int(sys.argv[2]) if len(sys.argv) > 2 else 512
 chunk_tiles = int(sys.argv[3]) if len(sys.argv) > 3 else 32
 
 CKPT = os.path.join(REPO, "scripts", "shipped_ckpt")
-OUT = os.path.join(REPO, "scripts", "shipped_pin.json")
+OUT = os.path.join(REPO, "scripts", "shipped_pin_levels.json")
 
-REF = os.environ.get(
-    "TPUVSR_REFERENCE", "/root/reference/vsr-revisited/paper")
-spec = load_spec(f"{REF}/VSR.tla", f"{REF}/VSR.cfg")
+spec = load_spec("VSR", os.path.join(REPO, "benchmark", "configs",
+                                     "vsr-shipped.cfg"))
 assert spec.symmetry_perms, "shipped VSR.cfg declares SYMMETRY"
 
 t0 = time.time()
@@ -58,11 +59,12 @@ res = eng.run(max_seconds=prev_elapsed + seconds, resume_from=resume,
               log=lambda m: print(f"[shipped] {m}", flush=True))
 elapsed = res.elapsed
 out = {
-    "config": "VSR.cfg UNCHANGED (R=3, C=1, |Values|=2, timer=2, "
-              "restarts=0, SYMMETRY ON, AcknowledgedWriteNotLost)",
+    "config": "benchmark/configs/vsr-shipped.cfg (R=3, C=1, |Values|=2, "
+              "timer=2, restarts=0, SYMMETRY ON, "
+              "AcknowledgedWriteNotLost)",
     "engine": "paged",
     "backend": backend,
-    "symmetry_perms": len(spec.symmetry_perms),
+    "symmetry_perms": len(spec.symmetry_perms) + 1,
     "window_s": seconds,
     "tile": tile,
     "elapsed_s": round(elapsed, 1),
@@ -72,8 +74,8 @@ out = {
     "distinct_per_s": round(res.distinct_states / max(elapsed, 1e-9),
                             1),
     "fixpoint": res.error is None,
-    "level_sizes_tail": eng.level_sizes[-8:],
-    "n_levels": len(eng.level_sizes),
+    # the last one is partial unless `fixpoint`
+    "level_sizes": [int(x) for x in eng.level_sizes],
     "violated": res.violated_invariant,
     "error": res.error,
     "ok": res.ok,

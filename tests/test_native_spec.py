@@ -124,10 +124,12 @@ def test_registered_module_without_init_trace_is_loud():
         load_spec("VR_STATE_TRANSFER", SMALL_CFG)
 
 
-@pytest.mark.parametrize("section", ["SYMMETRY symmValues",
+@pytest.mark.parametrize("section", ["SYMMETRY symmReplicas",
                                      "PROPERTY AllReplicasMoveToSameView",
                                      "SPECIFICATION Spec"])
 def test_cfg_sections_that_need_the_ast_are_refused(section, tmp_path):
+    """PROPERTY, SPECIFICATION and a SYMMETRY definition the committed
+    table (native.SYMMETRY_SETS) does not know."""
     with open(SMALL_CFG) as f:
         text = f.read()
     if section.startswith("SPECIFICATION"):
@@ -136,6 +138,53 @@ def test_cfg_sections_that_need_the_ast_are_refused(section, tmp_path):
     cfg.write_text(text + "\n" + section + "\n")
     with pytest.raises(TLAError, match="needs the .tla"):
         load_spec("VSR", str(cfg))
+
+
+def _with_symmetry(tmp_path, values):
+    with open(SMALL_CFG) as f:
+        text = f.read()
+    cfg = tmp_path / "symm.cfg"
+    cfg.write_text(text.replace("Values = {v1}", f"Values = {values}")
+                   + "\nSYMMETRY symmValues\n")
+    return load_spec("VSR", str(cfg))
+
+
+@pytest.mark.parametrize("values", ["{v1, v2}", "{v1, v2, v3}"])
+def test_symmetry_group_is_permutations_of_values(values, tmp_path):
+    """`SYMMETRY symmValues` from the committed table: what
+    `SpecModel._symmetry_perms` evaluates `Permutations(Values)` to
+    (dicts ModelValue -> ModelValue, fixed points and the identity
+    dropped), a closed group."""
+    import itertools
+    from tpuvsr.engine.canon import group_closed
+    spec = _with_symmetry(tmp_path, values)
+    elems = sorted(spec.ev.constants["Values"], key=lambda v: v.name)
+    want = {frozenset((a, b) for a, b in zip(elems, image) if a is not b)
+            for image in itertools.permutations(elems)} - {frozenset()}
+    got = [frozenset(p.items()) for p in spec.symmetry_perms]
+    assert len(got) == len(set(got)) == len(want)
+    assert set(got) == want
+    assert group_closed(spec.symmetry_perms)
+    # a 3-cycle without its inverse is no group: the check can fail
+    cycles = [p for p in spec.symmetry_perms if len(p) == 3]
+    assert all(not group_closed([c]) for c in cycles)
+    assert len(cycles) == (2 if len(elems) == 3 else 0)
+
+
+def test_symmetry_of_one_value_is_no_group(tmp_path):
+    spec = _with_symmetry(tmp_path, "{v1}")
+    assert spec.symmetry_perms == []
+
+
+def test_shipped_cfg_is_the_one_swap():
+    """benchmark/configs/vsr-shipped.cfg: upstream's VSR.cfg."""
+    spec = load_spec("VSR", os.path.join(
+        REPO, "benchmark", "configs", "vsr-shipped.cfg"))
+    (swap,) = spec.symmetry_perms
+    assert {k.name: v.name for k, v in swap.items()} == {"v1": "v2",
+                                                         "v2": "v1"}
+    assert spec.cfg.view == "view"
+    assert spec.cfg.invariants == ["AcknowledgedWriteNotLost"]
 
 
 def test_init_refuses_constants_it_does_not_fit(tmp_path):

@@ -201,20 +201,27 @@ class CanonSpec:
             [jnp.asarray(st[k], jnp.uint32).reshape(-1)
              for k in sorted(self.planes)])
 
-    def canonicalize(self, st):
-        """One dense state row -> the least element of its orbit (a
-        small sort-network fold over the enumerated group)."""
+    def least(self, st):
+        """One dense state row -> (the least element of its orbit,
+        whether that is another image than the identity's): a small
+        sort-network fold over the enumerated group."""
         if self.perms == 1:
-            return st
+            return st, jnp.asarray(False)
         best = self._apply(st, self._jgroup[0])      # identity image
         bkey = self._key(best)
+        moved = jnp.asarray(False)
         for p in range(1, self.perms):
             cand = self._apply(st, self._jgroup[p])
             ckey = self._key(cand)
             less = _lex_less(ckey, bkey)
             bkey = jnp.where(less, ckey, bkey)
             best = {k: jnp.where(less, cand[k], best[k]) for k in best}
-        return best
+            moved = moved | less
+        return best, moved
+
+    def canonicalize(self, st):
+        """One dense state row -> the least element of its orbit."""
+        return self.least(st)[0]
 
     def fingerprint_fn(self, kern):
         """``st -> kern.fingerprint(canonicalize(st))`` — the one

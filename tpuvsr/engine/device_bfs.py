@@ -503,9 +503,14 @@ class DeviceBFS:
                                 and self._canon is None)
         self._fp_stage = trace_once(
             self.kern.fingerprint_incremental if self._fp_incremental
-            else self._canon.fingerprint_fn(self.kern)
+            else self._fingerprint_least
             if self._canon is not None else self.kern.fingerprint,
             "fingerprint")
+        # what canon did, counted on the device beside the action
+        # counts (the fused body; ISSUE 33): only a program that
+        # canonicalizes carries the counter
+        self._canon_counts = (self._canon is not None
+                              and self.commit == "fused")
         self._inv_stage = trace_once(self._inv, "invariants")
         self._expand_stages = {}    # (action, block rows) -> stage
         self._pack_stages = {}      # block rows -> stage
@@ -620,18 +625,28 @@ class DeviceBFS:
 
         return mat
 
+    def _fingerprint_least(self, st):
+        """``CanonSpec.fingerprint_fn`` with what the level program
+        counts as ``canon_relabelled``: (fingerprint of `st`'s least
+        orbit image, whether that image is not the identity's)."""
+        with jax.named_scope(spans.CANON):
+            image, moved = self._canon.least(st)
+        return self.kern.fingerprint(image), moved
+
     def _successor_fn(self, name, fn):
         """Stage 2 of both tile bodies for one enabled (state, lane)
         item of action `name`: expand it with `fn`, fingerprint the
         successor, check the invariants — each under its stage scope.
         Returns ``one(st, parts, lane) -> (successor, fingerprint,
-        enabled, invariants ok, err)`` for the body to vmap over its
-        compacted lanes; `parts` is the parent's hash parts
+        enabled, invariants ok, err, relabelled)`` for the body to vmap
+        over its compacted lanes; `parts` is the parent's hash parts
         (``kern.parent_parts``) under the incremental hash and None
-        under the full one."""
+        under the full one; `relabelled` is None unless the run
+        canonicalizes."""
         kern = self.kern
         fp_stage, inv_stage = self._fp_stage, self._inv_stage
         incremental = self._fp_incremental
+        canon = self._canon is not None
 
         def one(st, parts, lane):
             with jax.named_scope(spans.EXPAND):
@@ -643,15 +658,18 @@ class DeviceBFS:
             # taken on the canonical orbit image while the staged queue
             # keeps the generated state — orbit-mates dedup to one
             # committed representative
+            moved = None
             with jax.named_scope(spans.FINGERPRINT):
                 if incremental:
                     fp = fp_stage(succ, kern.lane_replica(name, st, lane),
                                   parts, st)
+                elif canon:
+                    fp, moved = fp_stage(clean)
                 else:
                     fp = fp_stage(clean)
             with jax.named_scope(spans.INVARIANTS):
                 iok = inv_stage(clean)
-            return clean, fp, en, iok, clean["err"]
+            return clean, fp, en, iok, clean["err"], moved
 
         return one
 
@@ -787,7 +805,7 @@ class DeviceBFS:
                         with jax.named_scope(spans.COMPACT):
                             parts_sel = jax.tree_util.tree_map(
                                 lambda v: v[pidx], parts)
-                    succ_f, fp, en2, iok, errv = jax.vmap(
+                    succ_f, fp, en2, iok, errv, _moved = jax.vmap(
                         self._successor_fn(name, fn))(
                             st_sel, parts_sel, lane_sel)
 
@@ -966,6 +984,7 @@ class DeviceBFS:
         P = piece_lanes(total_E)
         Q = -(-total_E // P) * P
         edges_on = self._edges_on
+        canon_counts = self._canon_counts
         if pk is not None:
             row = jax.eval_shape(pk.unpack, jax.ShapeDtypeStruct(
                 (pk.words,), jnp.uint32))
@@ -1098,6 +1117,8 @@ class DeviceBFS:
                     "fp": lanes(jnp.uint32, 4), "en": lanes(bool),
                     "aid": lanes(I32), "pidx": lanes(I32),
                     "lane": lanes(I32)}
+                if canon_counts:
+                    queue["moved"] = lanes(bool)
                 q_end = jnp.asarray(0, I32)
                 for aid, name in enumerate(kern.action_names):
                     L_a = kern._lane_count(name)
@@ -1137,7 +1158,7 @@ class DeviceBFS:
                             st_b = {k: v[pidx_b] for k, v in tile.items()}
                             parts_b = jax.tree_util.tree_map(
                                 lambda v: v[pidx_b], parts)
-                        succ, fp, en2, iok, errv = expand(
+                        succ, fp, en2, iok, errv, moved = expand(
                             st_b, parts_b, lane_b)
                         # a successor is a state row: packed here, a
                         # block at a time, it never exists unpacked at
@@ -1145,11 +1166,12 @@ class DeviceBFS:
                         rows_b = pack({k: succ[k].astype(s.dtype)
                                        for k, s in row.items()})
                         with jax.named_scope(spans.COMPACT):
-                            queue = put(queue, {
-                                "rows": rows_b, "fp": fp,
-                                "en": en2 & ok_b, "aid": aid_b,
-                                "pidx": pidx_b, "lane": lane_b},
-                                q_end + lo)
+                            item = {"rows": rows_b, "fp": fp,
+                                    "en": en2 & ok_b, "aid": aid_b,
+                                    "pidx": pidx_b, "lane": lane_b}
+                            if canon_counts:
+                                item["moved"] = moved
+                            queue = put(queue, item, q_end + lo)
                             return queue, put(seg, (en2, iok, errv), lo)
 
                     no = jax.lax.full((E_a,), False, bool)
@@ -1349,6 +1371,15 @@ class DeviceBFS:
                         jnp.uint32),
                     "cpl": c["cpl"] + n_pieces.astype(jnp.uint32),
                 })
+                if canon_counts:
+                    # real lanes the canon stage ran for, and those
+                    # whose least image is not the identity's; gated
+                    # as `gen` is
+                    ret["cn"] = c["cn"] + jnp.where(
+                        commit, jnp.stack([
+                            en_q.sum(dtype=jnp.uint32),
+                            (en_q & queue["moved"]).sum(
+                                dtype=jnp.uint32)]), jnp.uint32(0))
                 if edges_on:
                     ret["edge_n"] = jnp.where(commit, st["edge_n"],
                                               c["edge_n"])
@@ -1456,6 +1487,8 @@ class DeviceBFS:
             }
             if fused:
                 init["cpl"] = jnp.asarray(0, jnp.uint32)
+            if self._canon_counts:
+                init["cn"] = jnp.zeros((2,), jnp.uint32)
             if eb is not None:
                 init["gids"] = table["gids"]
                 init["eb_src"], init["eb_aid"], init["eb_dst"] = eb
@@ -1613,6 +1646,7 @@ class DeviceBFS:
         self._commit_cap = 0
         self._tiles_done = 0
         self._lanes_disp = 0
+        self._canon_cn = np.zeros(2, np.int64)
 
     def _account_blocks(self, blk, pieces):
         """One collected ticket's per-action counts of expand blocks
@@ -2026,6 +2060,8 @@ class DeviceBFS:
                     o["act"], o["need"], o["blk"], o.get("cpl", 0)]
             if self._por_active:
                 vals += [o["gfull"], o["amp"]]
+            if self._canon_counts:
+                vals.append(o["cn"])
             return jax.device_get(vals)
         return self._chunk_loop(
             res, obs, pipe, pull, table=table, front=front,
@@ -2093,6 +2129,8 @@ class DeviceBFS:
                     self._por_kept += gen_add
                     self._por_full += int(sc[9])
                     self._por_amp += int(sc[10])
+                if self._canon_counts:
+                    self._canon_cn += np.asarray(sc[-1], np.int64)
 
                 if reason == RUNNING:
                     obs.progress(depth=depth, distinct=fp_count,
@@ -2350,6 +2388,10 @@ class DeviceBFS:
         if res.states_generated and fp_count:
             obs.gauge("orbit_ratio",
                       round(res.states_generated / fp_count, 4))
+        if self._canon_counts:
+            lanes_c, moved_c = (int(x) for x in self._canon_cn)
+            obs.count("canon_lanes", lanes_c)
+            obs.count("canon_relabelled", moved_c)
         if fp_cap:
             obs.gauge("fpset_capacity", int(fp_cap))
             obs.gauge("fpset_occupancy", fp_count / fp_cap)

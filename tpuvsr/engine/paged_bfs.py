@@ -507,6 +507,8 @@ class PagedBFS(DeviceBFS):
                 keys.append(o["edge_n"])
             if self._por_active:
                 keys += [o["gfull"], o["amp"]]
+            if self._canon_counts:
+                keys.append(o["cn"])
             return jax.device_get(keys)
 
         while n_front > 0 and stop is None:
@@ -664,6 +666,8 @@ class PagedBFS(DeviceBFS):
                         self._por_kept += gen_add
                         self._por_full += int(sc[9])
                         self._por_amp += int(sc[10])
+                    if self._canon_counts:
+                        self._canon_cn += np.asarray(sc[-1], np.int64)
 
                     if reason == RUNNING:
                         obs.progress(depth=depth, distinct=fp_count,

@@ -18,10 +18,16 @@ decoded states.  This module provides exactly that facade:
   ``codec.encode(state)``; ``walk_trace`` holds the kernel to a
   recorded TLC trace (the one committed oracle that does not come from
   the device engine itself);
-* anything that needs the AST is refused loudly: SYMMETRY, PROPERTY /
-  SPECIFICATION, the speclint passes (``analysis.preflight`` logs one
-  line and returns None; ``-bounds on`` / ``-por on`` / ``-lint`` exit
-  2), and the interpreter engine.
+* cfg ``SYMMETRY`` names a definition; what it evaluates to is
+  committed knowledge of the module (``SYMMETRY_SETS``: the constant
+  set whose full permutation group it is), so ``symmetry_perms`` is
+  what ``SpecModel._symmetry_perms`` evaluates from the AST and the
+  engines canonicalize as they do for a ``.tla``-loaded spec;
+* anything else that needs the AST is refused loudly: a SYMMETRY name
+  the table does not know, PROPERTY / SPECIFICATION, the speclint
+  passes (``analysis.preflight`` logs one line and returns None;
+  ``-bounds on`` / ``-por on`` / ``-lint`` exit 2), and the
+  interpreter engine.
 
 ``engine.spec.load_spec`` resolves here when its spec argument is not
 an existing file but a module name the registry knows.  Only VSR has a
@@ -30,12 +36,13 @@ committed init trace today; the other seven modules need one each.
 
 from __future__ import annotations
 
+import itertools
 import os
 import re
 
 import numpy as np
 
-from ..core.values import TLAError
+from ..core.values import TLAError, value_key
 from ..engine.spec import Action
 from ..frontend.tla_ast import Module
 from ..interp.evalr import Evaluator
@@ -44,6 +51,10 @@ from .registry import REPO, _resolve
 # module name -> committed TLC trace whose entry 1 is the full Init state
 INIT_TRACES = {"VSR": os.path.join(REPO, "examples",
                                    "found_violation_trace.txt")}
+
+# module name -> cfg SYMMETRY definition name -> the constant set the
+# definition permutes (VSR.tla:151: symmValues == Permutations(Values))
+SYMMETRY_SETS = {"VSR": {"symmValues": "Values"}}
 
 _LOCATION = re.compile(
     r'name \|-> "(\w+)",\s*location \|-> "(line [^"]+)"')
@@ -55,7 +66,9 @@ class NativeSpec:
     native = True
 
     def __init__(self, name, cfg):
-        for what, val in (("SYMMETRY", cfg.symmetry),
+        known = SYMMETRY_SETS.get(name, {})
+        for what, val in (("SYMMETRY", cfg.symmetry
+                           if cfg.symmetry not in known else None),
                           ("PROPERTY", cfg.properties),
                           ("SPECIFICATION", cfg.specification)):
             if val:
@@ -68,7 +81,8 @@ class NativeSpec:
         self.ev = Evaluator(self.module, cfg.constants)
         self.temporal_props = []
         self.fairness = []
-        self.symmetry_perms = []
+        self.symmetry_perms = _permutations(
+            cfg.constants[known[cfg.symmetry]]) if cfg.symmetry else []
         self._codec_cls, self._kern_cls = _resolve(name)
         with open(INIT_TRACES[name]) as f:
             self._trace_text = f.read()
@@ -123,6 +137,16 @@ class NativeSpec:
             if not bool(inv[name](dense)):
                 return name
         return None
+
+
+def _permutations(values):
+    """``Permutations(values)`` as ``SpecModel._symmetry_perms`` hands
+    it to the engines: one dict ModelValue -> ModelValue a permutation,
+    fixed points and the identity dropped."""
+    elems = sorted(values, key=value_key)
+    perms = ({a: b for a, b in zip(elems, image) if a is not b}
+             for image in itertools.permutations(elems))
+    return [p for p in perms if p]
 
 
 def native_spec(name, cfg):
