@@ -51,6 +51,17 @@ def small_pin():
         return json.load(f)["level_sizes"]
 
 
+@pytest.fixture
+def empty_store(tmp_path, monkeypatch):
+    """An empty store of traced programs of this test's own
+    (engine/program_store.py): its first build of a program is a miss,
+    whatever the checkout's store holds."""
+    from tpuvsr.engine import program_store
+    path = str(tmp_path / "store")
+    monkeypatch.setattr(program_store, "store_directory", lambda: path)
+    return path
+
+
 def check_native_growth(spec, pin, counter, journal, depth=8, **engine_kw):
     """`DeviceBFS.run` on the native small check with one capacity
     undersized by `engine_kw`: the growth `grow_<counter>` fires, the

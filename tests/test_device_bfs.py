@@ -331,11 +331,12 @@ def test_grow_msgs_rebuilds_the_shared_stages(small_native):
 @pytest.mark.parametrize("hash_mode", ["incremental", "full"])
 @pytest.mark.parametrize("commit", ["fused", "per-action"])
 def test_small_check_exact_counts(small_native, small_pin, commit,
-                                  hash_mode):
+                                  hash_mode, empty_store):
     """The small check to its fixpoint under both tile bodies and both
     hash modes: the pinned level sizes, 43,941 distinct, diameter 24.
     The capacities are sized so that no buffer grows: every growth is
-    one more build of the level program, most of this test's time."""
+    one more build of the level program, most of this test's time.
+    From an empty store, so that the program is traced here."""
     eng = DeviceBFS(small_native, commit=commit, hash_mode=hash_mode,
                     next_capacity=1 << 16, expand_mult=4)
     res = eng.run()

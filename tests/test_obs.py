@@ -1106,7 +1106,7 @@ def test_build_meter_counts_nested_traces_once():
     assert (m.programs, m.cache_hits, m.cache_load_s) == (1, 1, 0.5)
     assert reported == [{"fun_name": "jit_level", "trace_s": 3.0,
                          "lower_s": 2.0, "backend_s": 0.75,
-                         "cache": "hit"}]
+                         "cache": "hit", "export": "none"}]
     # a millisecond jit is counted and not journaled
     now[0] = 104.0
     m.duration(builds.TRACE_EVENT, 0.001, "tiny")

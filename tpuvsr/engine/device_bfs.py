@@ -79,6 +79,7 @@ from __future__ import annotations
 
 import functools
 import os
+import sys
 import time
 
 import numpy as np
@@ -92,6 +93,7 @@ from ..models.vsr import ERR_BAG_OVERFLOW
 from ..obs import RunObserver, builds, closes_observer, spans
 from ..resilience.faults import fault_point
 from ..resilience.supervisor import Preempted, preempt_signal
+from . import program_store
 from .bfs import CheckResult
 from .fpset import (dedup_batch, empty_table, grow, insert_batch,
                     insert_core, lookup_gids, store_gids)
@@ -521,12 +523,47 @@ class DeviceBFS:
 
     @property
     def _level(self):
-        """The jitted level pass, built at its first use: in a run, so
-        the run's observer meters the build."""
+        """The level pass, built at its first use: in a run, so the
+        run's observer meters the build.  It goes through the store of
+        traced programs (engine/program_store.py): a process that finds
+        this engine's program there does not trace it again."""
         if self._level_jit is None:
-            self._level_jit = jax.jit(self._make_level(),
-                                      donate_argnums=(0, 4, 5, 6, 7, 10))
+            self._level_jit = program_store.StoredProgram(
+                self._make_level, "level", (0, 4, 5, 6, 7, 10),
+                self._level_key_doc())
         return self._level_jit
+
+    def _level_key_doc(self):
+        """Everything the trace of `_make_level` reads of this engine,
+        for the store's key (the arguments' types, where the
+        capacities live, come with the call), or None where a class
+        the package's source does not determine has a hand in it: a
+        kernel, a codec or an engine defined elsewhere, or inside a
+        function."""
+        from .checkpoint import spec_digest
+        from . import fpset
+        spec = self.spec
+        try:
+            return program_store.describe({
+                "engine": type(self),
+                "spec": spec_digest(spec), "module": spec.module,
+                "kernel": self.kern, "codec": self.codec,
+                "pruned": self._pruned,
+                "tile": self.tile, "chunk_tiles": self.chunk_tiles,
+                "commit": self.commit, "hash_mode": self.hash_mode,
+                "expand_caps": self.expand_caps,
+                "expand_mults": self.expand_mults,
+                "constants": program_store.module_constants(
+                    sys.modules[__name__], fpset),
+                "inv_names": self.inv_names,
+                "pack": self._pack_manifest(),
+                "canon": self._canon_manifest(),
+                "bounds": self._bounds_manifest(),
+                "por": [self._por_manifest(), self._por_active],
+                "edges": self._edges_on,
+                "debug_checks": self.debug_checks})
+        except program_store.Uncovered:
+            return None
 
     def _run_level(self, *args):
         """One dispatch of the level pass.  The loops hand the pipeline

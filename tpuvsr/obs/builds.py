@@ -23,6 +23,11 @@ A stage that a program's trace runs many times with equal argument
 types (the engines' trace-once stages, ``engine/device_bfs.py``) says
 so here: ``shared_stage`` counts every use and, apart, the uses whose
 Python body really ran.
+
+A program that went to the store of traced programs
+(``engine/program_store.py``) says how it came out, just before its
+backend stage: ``export_store`` counts the hits and the misses, the
+seconds of each, and stamps the outcome on the program's record.
 """
 
 from __future__ import annotations
@@ -55,6 +60,8 @@ class BuildMeter:
         self.cache_load_s = 0.0
         self.programs = self.cache_hits = self.cache_misses = 0
         self.shared_calls = self.shared_traces = 0
+        self.export_hits = self.export_misses = 0
+        self.export_load_s = self.export_store_s = 0.0
         self._report = report
         self._clock = clock
         self._open = []         # top-level trace intervals [start, secs]
@@ -63,7 +70,7 @@ class BuildMeter:
     @staticmethod
     def _fresh():
         return {"fun_name": None, "trace_s": 0.0, "lower_s": 0.0,
-                "cache": "none"}
+                "cache": "none", "export": "none"}
 
     def duration(self, event, secs, fun_name=None):
         if event == TRACE_EVENT:
@@ -104,17 +111,29 @@ class BuildMeter:
             self.cache_misses += 1
             self._pending["cache"] = "miss"
 
+    def export(self, outcome, load_s, store_s):
+        # just before the backend stage of the program it describes
+        self.export_hits += outcome == "hit"
+        self.export_misses += outcome == "miss"
+        self.export_load_s += load_s
+        self.export_store_s += store_s
+        self._pending["export"] = outcome
+
     def stamp(self, metrics):
         """Write the gauges and counters of the metrics document."""
         metrics.gauge("build_trace_s", self.trace_s)
         metrics.gauge("build_lower_s", self.lower_s)
         metrics.gauge("build_backend_s", self.backend_s)
         metrics.gauge("build_cache_load_s", self.cache_load_s)
+        metrics.gauge("build_export_load_s", self.export_load_s)
+        metrics.gauge("build_export_store_s", self.export_store_s)
         for name, n in (("build_programs", self.programs),
                         ("build_cache_hits", self.cache_hits),
                         ("build_cache_misses", self.cache_misses),
                         ("build_shared_calls", self.shared_calls),
-                        ("build_shared_traces", self.shared_traces)):
+                        ("build_shared_traces", self.shared_traces),
+                        ("build_export_hits", self.export_hits),
+                        ("build_export_misses", self.export_misses)):
             metrics.counters[name] = n
 
 
@@ -140,6 +159,17 @@ def shared_stage(traced):
             meter.shared_traces += 1
         else:
             meter.shared_calls += 1
+
+
+def export_store(outcome, load_s=0.0, store_s=0.0):
+    """A program is about to enter its backend stage as a ``hit`` of
+    the store of traced programs (`load_s`: read, deserialize, lower
+    the wrapper), a ``miss`` (`store_s`: serialize, write; the trace
+    and the lowering are counted by their own events) or a
+    ``bypass``."""
+    meter = getattr(_local, "meter", None)
+    if meter is not None:
+        meter.export(outcome, load_s, store_s)
 
 
 def _register():

@@ -143,6 +143,7 @@ COMMON_REQUIRED = ("event", "ts", "run_id")
 # worker exports around each engine run), so one correlation id
 # survives the service -> worker -> engine process hops.
 TRACE_KEYS = ("trace_id", "span_id", "parent_span")
+TRACE_ENV_KEYS = ("TPUVSR_TRACE_ID", "TPUVSR_SPAN_ID", "TPUVSR_PARENT_SPAN")
 
 
 def new_run_id():
@@ -173,9 +174,8 @@ def trace_scope(trace_id=None, span_id=None, parent_span=None):
     child process it launches.  Journals created inside the scope with
     no explicit trace context inherit it, minting their own segment
     span under ``parent_span``."""
-    keys = ("TPUVSR_TRACE_ID", "TPUVSR_SPAN_ID", "TPUVSR_PARENT_SPAN")
-    saved = {k: os.environ.get(k) for k in keys}
-    for k in keys:
+    saved = {k: os.environ.get(k) for k in TRACE_ENV_KEYS}
+    for k in TRACE_ENV_KEYS:
         os.environ.pop(k, None)
     os.environ.update(trace_env(trace_id, span_id, parent_span))
     try:

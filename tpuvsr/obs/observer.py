@@ -301,6 +301,7 @@ class RunObserver:
             trace_s=round(rec["trace_s"], 6),
             lower_s=round(rec["lower_s"], 6),
             backend_s=round(rec["backend_s"], 6), cache=rec["cache"],
+            export=rec["export"],
             elapsed_s=round(self.elapsed(), 3))
 
     # -- metrics delegates ---------------------------------------------
