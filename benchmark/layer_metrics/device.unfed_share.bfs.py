@@ -1,0 +1,6 @@
+"""`device.unfed_share.verdict` in a bfs-timed cell, where it moves
+`distinct_per_s`: read from the window's run."""
+
+import cells
+
+read = cells.load_plugin("layer_metrics", "device.unfed_share.verdict").read

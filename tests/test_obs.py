@@ -406,7 +406,8 @@ def test_stub_device_bfs_journal_metrics(tmp_path):
     ph = doc["phases"]
     core = sum(ph.get(k, 0.0) for k in ("compile", "dispatch",
                                         "host_sync", "inflight",
-                                        "check", "init"))
+                                        "check", "init",
+                                        "boundary", "finish"))
     # ISSUE 2 acceptance: the four core phases cover >=90% of elapsed
     assert core >= 0.90 * res.elapsed, (ph, res.elapsed)
     assert sum(ph.values()) <= 1.05 * res.elapsed
@@ -533,7 +534,8 @@ def test_stub_sharded_journal_and_shard_metrics(tmp_path):
     ph = doc["phases"]
     core = sum(ph.get(k, 0.0) for k in ("compile", "dispatch",
                                         "host_sync", "inflight",
-                                        "check", "init"))
+                                        "check", "init",
+                                        "boundary", "finish"))
     assert core >= 0.90 * res.elapsed, (ph, res.elapsed)
 
 
@@ -588,7 +590,8 @@ def test_device_phase_timers_sum_to_elapsed(tmp_path):
     ph = doc["phases"]
     core = sum(ph.get(k, 0.0) for k in ("compile", "dispatch",
                                         "host_sync", "inflight",
-                                        "check", "init"))
+                                        "check", "init",
+                                        "boundary", "finish"))
     assert core >= 0.90 * res.elapsed, (ph, res.elapsed)
     assert sum(ph.values()) <= 1.05 * res.elapsed, (ph, res.elapsed)
     assert doc["counters"]["dispatches"] >= 1
@@ -955,6 +958,15 @@ def test_span_annotations_follow_the_fixed_vocabulary(tmp_path):
     assert names.count(spans.CHECKPOINT) == doc["counters"]["checkpoints"]
     assert names.count(spans.CHECKPOINT) >= 1
     assert names.count(spans.INFLIGHT) >= 1
+    # a boundary before the first level and after each, closed by the
+    # next launch or by the run's end; one finish
+    assert names.count(spans.BOUNDARY) == len(res.levels) + 1
+    assert names.count(spans.FINISH) == 1
+    assert rec.log[-3:-1] == [("open", spans.FINISH, {}),
+                              ("close", spans.FINISH)]
+    assert [e[2] for e in rec.log
+            if e[0] == "open" and e[1] == spans.BOUNDARY] \
+        == [{"depth": d} for d in range(len(res.levels) + 1)]
     assert {spans.ENGINE_SPANS[n] for n in names} == set(doc["phases"])
     # numbers ride as attributes
     depths = [e[2]["depth"] for e in rec.log
@@ -973,6 +985,9 @@ def test_span_with_profiling_off_never_makes_an_annotation(monkeypatch):
     real = os.environ.get
     res = _stub_device_engine().run()
     assert res.ok and res.metrics["phases"]["dispatch"] > 0
+    # the spans held open across statements are phase frames too
+    assert res.metrics["phases"]["boundary"] > 0
+    assert "finish" in res.metrics["phases"]
     # and no span reads the environment: a run's reads of
     # TPUVSR_PROFILE do not grow with its dispatches
     monkeypatch.setattr(

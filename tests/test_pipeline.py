@@ -10,7 +10,7 @@ resume seam.  Everything runs tier-1 on the stub harness
 
 Plus the new observability surface: the ``inflight`` phase keeps the
 phase timers summing to wall-clock, the ``pipeline_depth`` /
-``overlap_saved_s`` gauges land in the metrics document.
+``unfed_s`` gauges land in the metrics document.
 """
 
 import json
@@ -167,12 +167,19 @@ def test_pipelined_phases_sum_to_elapsed(tmp_path):
     ph = doc["phases"]
     core = sum(ph.get(k, 0.0) for k in ("compile", "dispatch",
                                         "host_sync", "inflight",
-                                        "check", "init"))
+                                        "check", "init",
+                                        "boundary", "finish"))
     assert core >= 0.90 * res.elapsed, (ph, res.elapsed)
     assert sum(ph.values()) <= 1.05 * res.elapsed, (ph, res.elapsed)
     g = doc["gauges"]
     assert g["pipeline_depth"] == 4
-    assert g.get("overlap_saved_s", 0.0) >= 0.0
+    # the unfed clock (ISSUE 35): by phase, never under inflight, no
+    # more than each phase's own seconds, summing to the gauge
+    unfed = doc["phases_unfed"]
+    assert "inflight" not in unfed and "boundary" in unfed
+    assert all(0.0 <= v <= ph[k] + 1e-6 for k, v in unfed.items())
+    assert abs(sum(unfed.values()) - g["unfed_s"]) < 1e-4
+    assert 0.0 < g["unfed_s"] <= doc["elapsed_s"]
     assert sum(g["action_expansions"].values()) \
         == res.states_generated - 1
 

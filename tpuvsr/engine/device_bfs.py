@@ -1989,6 +1989,10 @@ class DeviceBFS:
         obs.start(t0, backend=jax.default_backend(),
                   resumed=resume_from is not None)
         emit = obs.log
+        # made as the run starts: its unfed clock counts the set-up
+        from .pipeline import DispatchPipeline
+        pipe = DispatchPipeline(self.pipe_window, obs,
+                                ready=lambda o: o["reason"])
 
         if resume_from is not None:
             # --- resume from a level-boundary snapshot ----------------
@@ -2067,9 +2071,9 @@ class DeviceBFS:
             depth = 0
             self.level_sizes = [n0]
         last_checkpoint = time.time()
-        return self._run_loop(
-            res, obs, table=table, front=front, bufs=bufs, fpar=fpar,
-            fact=fact, fprm=fprm, n_front=n_front,
+        return self._chunk_loop(
+            res, obs, pipe, table=table, front=front, bufs=bufs,
+            fpar=fpar, fact=fact, fprm=fprm, n_front=n_front,
             level_base=level_base, depth=depth, fp_count=fp_count,
             fp_cap=fp_cap, t0=t0, max_states=max_states,
             max_depth=max_depth, max_seconds=max_seconds,
@@ -2078,18 +2082,14 @@ class DeviceBFS:
             checkpoint_every=checkpoint_every,
             last_checkpoint=last_checkpoint)
 
-    def _run_loop(self, res, obs, *, table, front, bufs, fpar, fact,
-                  fprm, n_front, level_base, depth, fp_count, fp_cap,
-                  t0, max_states, max_depth, max_seconds,
-                  check_deadlock, checkpoint_path, checkpoint_every,
-                  last_checkpoint):
+    def _chunk_loop(self, res, obs, pipe, *, table, front, bufs,
+                    fpar, fact, fprm, n_front, level_base, depth,
+                    fp_count, fp_cap, t0, max_states, max_depth,
+                    max_seconds, check_deadlock, checkpoint_path,
+                    checkpoint_every, last_checkpoint):
         # keyword-only: the loop state is a pile of same-typed ints and
         # identically shaped buffers — a transposed positional arg
         # would type-check and silently corrupt traces/metrics
-        from .pipeline import DispatchPipeline
-        pipe = DispatchPipeline(self.pipe_window, obs,
-                                ready=lambda o: o["reason"])
-
         def pull(o):
             # ONE host round-trip for all control scalars — separate
             # int() pulls cost one device round-trip each
@@ -2100,24 +2100,12 @@ class DeviceBFS:
             if self._canon_counts:
                 vals.append(o["cn"])
             return jax.device_get(vals)
-        return self._chunk_loop(
-            res, obs, pipe, pull, table=table, front=front,
-            bufs=bufs, fpar=fpar, fact=fact, fprm=fprm,
-            n_front=n_front, level_base=level_base, depth=depth,
-            fp_count=fp_count, fp_cap=fp_cap, t0=t0,
-            max_states=max_states, max_depth=max_depth,
-            max_seconds=max_seconds, check_deadlock=check_deadlock,
-            checkpoint_path=checkpoint_path,
-            checkpoint_every=checkpoint_every,
-            last_checkpoint=last_checkpoint)
 
-    def _chunk_loop(self, res, obs, pipe, pull, *, table, front, bufs,
-                    fpar, fact, fprm, n_front, level_base, depth,
-                    fp_count, fp_cap, t0, max_states, max_depth,
-                    max_seconds, check_deadlock, checkpoint_path,
-                    checkpoint_every, last_checkpoint):
         spec = self.spec
         emit = obs.log
+        # the host between two levels' device work (and before the
+        # first): open from here, or from a level's end, to the launch
+        obs.boundary(depth=depth)
         while n_front > 0:
             if max_depth is not None and depth >= max_depth:
                 res.error = f"depth limit {max_depth} reached"
@@ -2257,6 +2245,7 @@ class DeviceBFS:
                     break
 
             # ---- level complete: pull trace pointers, swap buffers ---
+            obs.boundary(depth=depth)
             obs.level_done(depth, frontier=n_front, distinct=fp_count,
                            generated=res.states_generated)
             self._account_tiles(min(start_t, n_tiles))
@@ -2269,6 +2258,7 @@ class DeviceBFS:
                 par, act, prm = nbp[:n_next], nba[:n_next], nbprm[:n_next]
                 for a in (par, act, prm):
                     a.copy_to_host_async()
+                    obs.count("boundary_pull_bytes", a.nbytes)
                 self._h_parent.append((par, level_base))
                 self._h_action.append(act)
                 self._h_param.append(prm)
@@ -2410,6 +2400,14 @@ class DeviceBFS:
         """Uniform result finalization: the collector (not the engine)
         stamps elapsed/states_per_sec/levels/metrics (ISSUE 2
         satellite — no more post-hoc res.elapsed patching)."""
+        obs.end_boundary()
+        with obs.span(spans.FINISH):
+            self._final_gauges(res, obs, fp_count, table, fp_cap)
+        return obs.finish(res, levels=getattr(self, "level_sizes", None))
+
+    def _final_gauges(self, res, obs, fp_count, table, fp_cap):
+        """The run's last counters and gauges (span
+        ``tpuvsr.engine.finish``); `table_stats` runs on the device."""
         res.distinct_states = fp_count
         self._pack_gauges(obs)
         self._bounds_gauges(obs)
@@ -2442,9 +2440,7 @@ class DeviceBFS:
                        zip(self.kern.action_names, acts)})
         # occupancy = real work items / expand lanes the device ran
         # (fused: the blocks of stage 2 that held an enabled lane, of
-        # the blocks the caps hold), and the structural insert_core
-        # batches per frontier tile (ISSUE 10: 1 fused vs n_actions
-        # per-action)
+        # the blocks the caps hold)
         lanes = getattr(self, "_lanes_disp", 0)
         if lanes and acts is not None:
             obs.gauge("occupancy",
@@ -2458,16 +2454,12 @@ class DeviceBFS:
             if self._commit_run:
                 obs.gauge("commit_occupancy", round(
                     float(acts.sum()) / self._commit_run, 4))
-        obs.gauge("inserts_per_tile",
-                  1 if self.commit == "fused"
-                  else len(self.kern.action_names))
         obs.gauge("commit_mode", self.commit)
         if table is not None and obs.detailed:
             from .fpset import table_stats
             st = table_stats(table["slots"])
             obs.gauge("fpset_occupancy", st["occupancy"])
             obs.gauge("fpset_collision_rate", st["collision_rate"])
-        return obs.finish(res, levels=getattr(self, "level_sizes", None))
 
     def _trace(self, gid, extra=None):
         """Walk the host pointer table back to an init state, then

@@ -339,6 +339,15 @@ class PagedBFS(DeviceBFS):
         obs.start(t0, backend=jax.default_backend(),
                   resumed=resume_from is not None)
         emit = obs.log
+        # pipelined dispatch window (ISSUE 4): chained on device-side
+        # (start_t, nn) scalars; host-side spill compaction and
+        # journal/metrics work overlap the in-flight dispatches.  The
+        # window drains at every pause/spill/chunk boundary — dropped
+        # tickets are replays that committed nothing (engine/pipeline.py).
+        # Made as the run starts: its unfed clock counts the set-up
+        from .pipeline import DispatchPipeline
+        pipe = DispatchPipeline(self.pipe_window, obs,
+                                ready=lambda o: o["reason"])
 
         self.spill_count = 0     # drains triggered by a full buffer
         self.spill_rows = 0      # total rows paged out to host
@@ -490,15 +499,6 @@ class PagedBFS(DeviceBFS):
                           for _ in range(3))
         stop = None
 
-        # pipelined dispatch window (ISSUE 4): chained on device-side
-        # (start_t, nn) scalars; host-side spill compaction and
-        # journal/metrics work overlap the in-flight dispatches.  The
-        # window drains at every pause/spill/chunk boundary — dropped
-        # tickets are replays that committed nothing (engine/pipeline.py)
-        from .pipeline import DispatchPipeline
-        pipe = DispatchPipeline(self.pipe_window, obs,
-                                ready=lambda o: o["reason"])
-
         def pull(o):
             keys = [o["reason"], o["t"], o["nn"], o["gen"],
                     o["dist"], o["act"], o["need"], o["blk"],
@@ -511,6 +511,10 @@ class PagedBFS(DeviceBFS):
                 keys.append(o["cn"])
             return jax.device_get(keys)
 
+        # the host between two units of device work (a chunk, a level)
+        # and before the first: open from here, or from a chunk's end,
+        # to the next launch
+        obs.boundary(depth=depth)
         while n_front > 0 and stop is None:
             if max_depth is not None and depth >= max_depth:
                 res.error = f"depth limit {max_depth} reached"
@@ -546,8 +550,7 @@ class PagedBFS(DeviceBFS):
                 if self.pipe_window > 1:
                     # the chain tip may still run (the dispatch past
                     # the chunk's end): that wait is the device's work
-                    with obs.span(spans.INFLIGHT):
-                        jax.block_until_ready(bufs[1])
+                    pipe.wait(bufs[1])
                 with obs.span(spans.PAGE_OUT, depth=depth, rows=n_next):
                     pages = self._page_out(bufs, n_next)
                 row_bytes = self._state_row_bytes()
@@ -808,6 +811,8 @@ class PagedBFS(DeviceBFS):
                 # and drain the chunk's committed edge triples (so the
                 # CSR builder sees whole chunks in commit order and a
                 # level boundary always finds the buffer empty)
+                obs.boundary(depth=depth,
+                             chunk=chunk_start // self._chunk_cap())
                 self._account_tiles(min(start_t, n_tiles_c))
                 spill()
                 drain_edges()
@@ -925,7 +930,7 @@ class PagedBFS(DeviceBFS):
                             table=table, fp_cap=fp_cap)
 
 
-    def _finish(self, res, obs, fp_count, table=None, fp_cap=None):
+    def _final_gauges(self, res, obs, fp_count, table, fp_cap):
         obs.count("page_shapes", len(self._run_page_shapes))
         if self._spill_dir is not None:
             # cumulative bytes the run wrote to the disk tier (files
@@ -947,8 +952,7 @@ class PagedBFS(DeviceBFS):
                      1e-9)
             obs.gauge("edges_per_s",
                       round(self._edge_rows_total / el, 1))
-        return super()._finish(res, obs, fp_count, table=table,
-                               fp_cap=fp_cap)
+        super()._final_gauges(res, obs, fp_count, table, fp_cap)
 
 
 def paged_bfs_check(spec, max_states=None, max_depth=None,

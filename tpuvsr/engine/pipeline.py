@@ -33,10 +33,21 @@ including the phase accounting (the dispatch blocks inside the
 With ``window>1`` the enqueue cost lands in ``dispatch``/``compile``,
 the blocking wait on the oldest ticket in a new ``inflight`` phase,
 and the scalar pull in ``host_sync`` — the phases stay disjoint and
-still sum to the run's wall-clock (tpuvsr/obs/SCHEMA.md).  The
-``overlap_saved_s`` gauge reports host time spent OUTSIDE pipeline
-calls while at least one dispatch was in flight — the work the window
-actually hid behind device compute.
+still sum to the run's wall-clock (tpuvsr/obs/SCHEMA.md).
+
+The window also keeps the run's **unfed clock** (``Metrics.unfed``,
+the document's ``phases_unfed``): it runs while every dispatch the
+host has launched is known to be done — from the window's making (an
+engine makes it as its run starts, so ``init`` counts), after the
+collect that empties the queue, after a ``drain`` — and stops when
+the next enqueue returns, so a build with nothing in flight is unfed
+time under ``compile``.  It never runs while the host is blocked on
+device work (``collect``'s wait, ``wait``).  At window 1 ``launch``
+blocks to completion, so the clock restarts when it returns.  Known
+bias: the replays ``drain`` drops still run, so unfed over-reads by
+the device time of at most ``pipeline_replays`` dispatches.  The real
+chunks a budget stop drops keep the device fed: that drain leaves the
+clock stopped.
 
 Drained-but-unconsumed replay dispatches still run on device (they
 were already enqueued); their FPSet inserts are idempotent, so only
@@ -48,7 +59,6 @@ race an in-flight dispatch.
 
 from __future__ import annotations
 
-import time
 from collections import deque
 
 from ..obs import spans
@@ -59,22 +69,17 @@ class DispatchPipeline:
 
     ``ready(out)`` must return a device array of the dispatch output to
     block on (the control-scalar leaf every engine already syncs on).
-    One instance rides one engine run; the window gauges
-    (``pipeline_depth``, ``overlap_saved_s``) are stamped on the
-    observer incrementally, so every engine return path sees them.
+    One instance rides one engine run, from its start: making it
+    starts the unfed clock (module docstring).
     """
 
     def __init__(self, window, obs, ready):
         self.window = max(1, int(window))
         self.obs = obs
         self._ready = ready
-        self._q = deque()            # (out, enqueue perf_counter)
-        self._overlap = 0.0          # host-work seconds hidden by the window
-        self._free_since = None      # host running free with work in flight
-        # gauges are stamped incrementally (last-write-wins) so the
-        # run's metrics document carries them no matter which engine
-        # return path finalizes the observer first
+        self._q = deque()            # outputs of the dispatches in flight
         obs.gauge("pipeline_depth", self.window)
+        obs.metrics.unfed_start()
 
     @property
     def in_flight(self):
@@ -89,43 +94,44 @@ class DispatchPipeline:
         The first dispatch after a (re)jit compiles synchronously at
         call time and is charged to the ``compile`` phase; at window 1
         the dispatch also blocks to completion here (synchronous-path
-        parity).  `attrs` (``depth=``) ride the span."""
+        parity).  `attrs` (``depth=``) ride the span.  The first
+        launch after ``RunObserver.boundary`` closes that span."""
         obs = self.obs
-        # host work done since the last pipeline call counts as
-        # overlapped when something was in flight through it (the
-        # collect->handle->launch span is where the hidden work lives)
-        self._credit_overlap()
+        obs.end_boundary()
         with obs.span(spans.build_phase(fresh), **attrs):
             out = fn(*args)
+            obs.metrics.unfed_stop()        # the device has work
             if self.window == 1:
                 self._ready(out).block_until_ready()
+                obs.metrics.unfed_start()   # and has done it
         obs.count("dispatches")
-        self._q.append((out, time.perf_counter()))
-        self._free_since = time.perf_counter()
+        self._q.append(out)
         return out
-
-    def _credit_overlap(self):
-        if self._free_since is None:
-            return
-        self._overlap += time.perf_counter() - self._free_since
-        self._free_since = None
-        if self.window > 1:
-            self.obs.gauge("overlap_saved_s", round(self._overlap, 6))
 
     def collect(self, pull):
         """Block on the OLDEST in-flight dispatch, pull its control
         scalars with ``pull(out)``, and return ``(out, scalars)``."""
-        out, _t_push = self._q.popleft()
+        out = self._q.popleft()
         obs = self.obs
-        self._credit_overlap()
         if self.window > 1:
             with obs.span(spans.INFLIGHT):
                 self._ready(out).block_until_ready()
+            if not self._q:
+                obs.metrics.unfed_start()
         with obs.span(spans.HOST_SYNC):
             sc = pull(out)
-        if self._q:
-            self._free_since = time.perf_counter()
         return out, sc
+
+    def wait(self, tip):
+        """Block on a device array the queue no longer holds (the chain
+        tip behind a ``drain``), under ``inflight``: the device is
+        working, so the unfed clock stops for the wait."""
+        obs = self.obs
+        obs.metrics.unfed_stop()
+        with obs.span(spans.INFLIGHT):
+            tip.block_until_ready()
+        if not self._q:
+            obs.metrics.unfed_start()
 
     def drain(self, reason="replay"):
         """Discard every still-in-flight ticket.  Returns the number of
@@ -143,5 +149,8 @@ class DispatchPipeline:
                            if reason == "budget" else "pipeline_replays",
                            n)
             self._q.clear()
-        self._free_since = None
+        if reason != "budget":
+            # a dropped replay still runs: the known bias (module
+            # docstring); a dropped real chunk is work, not idling
+            self.obs.metrics.unfed_start()
         return n

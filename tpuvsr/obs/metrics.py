@@ -18,9 +18,18 @@ One ``Metrics`` instance rides a single engine run (inside a
 * **gauges** — last-write-wins numbers (``fpset_capacity``,
   ``fpset_occupancy``, ``dedup_hit_rate``…).
 
+* **the unfed clock** — seconds in which the device had nothing
+  queued (``unfed_start`` / ``unfed_stop``, driven by
+  ``engine/pipeline.DispatchPipeline``), charged to the phase that is
+  current while it runs: ``begin`` and ``end`` settle the running
+  clock onto the phase they leave, so ``unfed`` is exclusive by phase
+  like ``phases`` and each entry is at most its phase's seconds.
+
 Per-level rows (``level(...)``) capture the BFS trajectory: frontier
 size, cumulative distinct/generated, and elapsed at each level
-boundary — the data a ``-metrics FILE.json`` dump is built from.
+boundary — the data a ``-metrics FILE.json`` dump is built from — and
+what the level cost: its own wall seconds, the phase and unfed seconds
+and the dispatches accrued since the previous row.
 
 The serialized form (``to_dict``) is the ``tpuvsr-metrics/1`` schema
 documented in ``tpuvsr/obs/SCHEMA.md`` and validated by
@@ -40,7 +49,8 @@ METRICS_SCHEMA = "tpuvsr-metrics/1"
 # blocked wait on the oldest in-flight dispatch (ISSUE 4) — zero on
 # synchronous (-pipeline 1) runs.
 WELL_KNOWN_PHASES = ("check", "compile", "dispatch", "host_sync",
-                     "inflight", "checkpoint", "init")
+                     "inflight", "checkpoint", "init", "boundary",
+                     "finish")
 
 # keys a metrics document must carry to be schema-valid
 REQUIRED_METRICS_KEYS = ("schema", "run_id", "engine", "elapsed_s",
@@ -51,12 +61,16 @@ LEVEL_ROW_KEYS = ("depth", "frontier", "distinct", "generated",
 
 
 class Metrics:
-    def __init__(self):
+    def __init__(self, clock=time.perf_counter):
         self.phases = {}        # name -> exclusive seconds
+        self.unfed = {}         # name -> exclusive seconds, device unfed
         self.counters = {}      # name -> int
         self.gauges = {}        # name -> number
         self.levels = []        # per-level trajectory rows
+        self._clock = clock
         self._stack = []        # [phase, child_seconds, t0] frames
+        self._unfed_since = None    # clock reading; None: device is fed
+        self._row_base = None   # (t, phases, unfed, dispatches) at the last row
 
     # -- phase timers --------------------------------------------------
     def begin(self, phase):
@@ -64,13 +78,17 @@ class Metrics:
         innermost open frame; RunObserver.finish drains any frames an
         early return left open, so unpaired ``begin`` is safe for
         run-scoped phases like the outer "check"."""
-        self._stack.append([phase, 0.0, time.perf_counter()])
+        t = self._clock()
+        self._settle_unfed(t)
+        self._stack.append([phase, 0.0, t])
 
     def end(self):
         if not self._stack:     # drain() already closed this frame
             return
+        t = self._clock()
+        self._settle_unfed(t)
         phase, child, t0 = self._stack.pop()
-        dt = time.perf_counter() - t0
+        dt = t - t0
         self.phases[phase] = self.phases.get(phase, 0.0) + dt - child
         if self._stack:
             self._stack[-1][1] += dt
@@ -78,6 +96,41 @@ class Metrics:
     def drain(self):
         while self._stack:
             self.end()
+        self._unfed_since = None    # no frame, no run: nothing to feed
+
+    # -- the unfed clock -----------------------------------------------
+    def unfed_start(self):
+        """The device has nothing queued from now on (no-op while the
+        clock already runs)."""
+        if self._unfed_since is None:
+            self._unfed_since = self._clock()
+
+    def unfed_stop(self):
+        """Work was enqueued: charge the running clock to the current
+        phase and stop it (no-op while it is stopped)."""
+        if self._unfed_since is not None:
+            self._settle_unfed(self._clock())
+            self._unfed_since = None
+
+    def _settle_unfed(self, t):
+        """Charge the running clock, up to `t`, to the current phase."""
+        if self._unfed_since is None:
+            return
+        # outside every frame nothing is timed, unfed seconds neither
+        if self._stack and t > self._unfed_since:
+            phase = self._stack[-1][0]
+            self.unfed[phase] = (self.unfed.get(phase, 0.0)
+                                 + t - self._unfed_since)
+        self._unfed_since = t
+
+    def _phases_at(self, t):
+        """Exclusive seconds by phase up to `t`, open frames included."""
+        out = dict(self.phases)
+        inner_t0 = t
+        for phase, child, t0 in reversed(self._stack):
+            out[phase] = out.get(phase, 0.0) + inner_t0 - t0 - child
+            inner_t0 = t0
+        return out
 
     @contextmanager
     def timer(self, phase):
@@ -99,9 +152,28 @@ class Metrics:
     # -- per-level trajectory ------------------------------------------
     def level(self, depth, *, frontier, distinct, generated, elapsed_s,
               **extra):
+        """One row a level.  Beside the trajectory it says what the
+        level cost, as accrued since the previous row (the first row:
+        since the first frame opened): ``wall_s``, exclusive ``phases``
+        seconds (open frames included), ``unfed_s`` and
+        ``dispatches``."""
+        t = self._clock()
+        self._settle_unfed(t)
+        phases = self._phases_at(t)
+        unfed = sum(self.unfed.values())
+        dispatches = self.counters.get("dispatches", 0)
+        t_b, phases_b, unfed_b, dispatches_b = self._row_base or (
+            self._stack[0][2] if self._stack else t, {}, 0.0, 0)
+        self._row_base = (t, phases, unfed, dispatches)
         row = {"depth": int(depth), "frontier": int(frontier),
                "distinct": int(distinct), "generated": int(generated),
-               "elapsed_s": round(float(elapsed_s), 6)}
+               "elapsed_s": round(float(elapsed_s), 6),
+               "wall_s": round(t - t_b, 6),
+               "phases": {k: round(v - phases_b.get(k, 0.0), 6)
+                          for k, v in phases.items()
+                          if v > phases_b.get(k, 0.0)},
+               "unfed_s": round(unfed - unfed_b, 6),
+               "dispatches": dispatches - dispatches_b}
         row.update(extra)
         self.levels.append(row)
         return row
@@ -117,6 +189,10 @@ class Metrics:
         out["gauges"] = {
             k: (round(v, 6) if isinstance(v, float) else v)
             for k, v in self.gauges.items()}
+        if self.unfed:      # only a run that drove a dispatch window
+            out["phases_unfed"] = {k: round(v, 6)
+                                   for k, v in self.unfed.items()}
+            out["gauges"]["unfed_s"] = round(sum(self.unfed.values()), 6)
         out["levels"] = list(self.levels)
         return out
 
@@ -166,9 +242,15 @@ def validate_metrics(doc, strict=False):
     for section in ("phases", "counters", "gauges"):
         if not isinstance(doc[section], dict):
             raise ValueError(f"{section} must be an object")
-    for name, v in doc["phases"].items():
-        if not isinstance(v, (int, float)) or v < 0:
-            raise ValueError(f"phase {name} has non-duration value {v!r}")
+    # `phases_unfed` is optional: documents from before the unfed clock,
+    # and runs that drove no dispatch window, carry none
+    if not isinstance(doc.get("phases_unfed", {}), dict):
+        raise ValueError("phases_unfed must be an object")
+    for section in ("phases", "phases_unfed"):
+        for name, v in doc.get(section, {}).items():
+            if not isinstance(v, (int, float)) or v < 0:
+                raise ValueError(f"{section} {name} has non-duration "
+                                 f"value {v!r}")
     for name, v in doc["counters"].items():
         if not isinstance(v, int):
             raise ValueError(f"counter {name} has non-int value {v!r}")

@@ -7,14 +7,15 @@ through its fixpoint loop:
 
     obs = RunObserver.ensure(obs, "device", spec, log=log)
     obs.start(t0, backend=jax.default_backend(), resumed=False)
-    # start() opens the run's root span (the catch-all "check" phase
-    # frame) and, under TPUVSR_PROFILE, the profiler session;
-    # finish() closes both
+    # start() opens the run's root span (the "check" phase frame: what
+    # no span below names) and, under TPUVSR_PROFILE, the profiler
+    # session; finish() closes both
     while ...:
-        with obs.span(spans.DISPATCH, depth=d):
+        with obs.span(spans.DISPATCH, depth=d):     # DispatchPipeline
             out = self._level(...)
         with obs.span(spans.HOST_SYNC):
             sc = jax.device_get(...)
+        obs.boundary(depth=d)       # open until the next launch
         obs.level_done(depth, frontier=.., distinct=.., generated=..)
         obs.progress(depth=.., distinct=.., generated=..)
     return self._finish(res, obs, fp_count)   # -> obs.finish(res, ...)
@@ -151,6 +152,7 @@ class RunObserver:
         self._annotation_factory = annotation
         self._annotation = None
         self._root = None
+        self._boundary = None       # the open boundary span, if any
         # what the run built (obs/builds.py), across every segment this
         # observer rides; attached to the calling thread from start()
         # to finish() on the device engines
@@ -249,10 +251,10 @@ class RunObserver:
         open, give the thread back its previous build meter, stop the
         profiler session."""
         self.metrics.drain()
-        if self._root is not None:
-            annotation, self._root = self._root._annotation, None
-            if annotation is not None:
-                annotation.__exit__(None, None, None)
+        for held in (self._boundary, self._root):   # inner first
+            if held is not None and held._annotation is not None:
+                held._annotation.__exit__(None, None, None)
+        self._boundary = self._root = None
         if self._builds_attached:
             builds.detach(self._builds_previous)
             self._builds_attached = False
@@ -295,6 +297,22 @@ class RunObserver:
                      None if annotation is None
                      else annotation(name, **attrs))
 
+    def boundary(self, **attrs):
+        """Open the ``tpuvsr.engine.boundary`` span: the host is between
+        the last collect of one unit of device work (a level; a chunk of
+        the paged engine) and the first launch of the next.  It stays
+        open across statements, other spans nest inside it, and
+        ``end_boundary`` closes it: ``DispatchPipeline.launch`` does, and
+        an engine's ``_finish``.  A no-op while one is open."""
+        if self._boundary is None:
+            self._boundary = self.span(spans.BOUNDARY, **attrs)
+            self._boundary.__enter__()
+
+    def end_boundary(self):
+        span, self._boundary = self._boundary, None
+        if span is not None:
+            span.__exit__(None, None, None)
+
     def _build_event(self, rec):
         self.journal.write(
             "build", fun_name=str(rec["fun_name"]),
@@ -315,12 +333,17 @@ class RunObserver:
     def level_done(self, depth, *, frontier, distinct, generated,
                    **extra):
         el = self.elapsed()
-        self.metrics.level(depth, frontier=frontier, distinct=distinct,
-                           generated=generated, elapsed_s=el, **extra)
+        row = self.metrics.level(depth, frontier=frontier,
+                                 distinct=distinct, generated=generated,
+                                 elapsed_s=el, **extra)
+        # the journal outlives a killed run: it carries what the level
+        # cost too
         self.journal.write("level_done", depth=int(depth),
                            frontier=int(frontier), distinct=int(distinct),
                            generated=int(generated),
-                           elapsed_s=round(el, 3), **extra)
+                           elapsed_s=round(el, 3), wall_s=row["wall_s"],
+                           phases=row["phases"], unfed_s=row["unfed_s"],
+                           dispatches=row["dispatches"], **extra)
 
     def checkpoint(self, path, depth, distinct):
         self.count("checkpoints")
@@ -599,8 +622,15 @@ class RunObserver:
         ph = doc["phases"]
         if ph:
             tot = sum(ph.values()) or 1e-9
+            unfed = doc.get("phases_unfed")
+
+            def cell(k, v):
+                out = f"{k} {v:.2f}s ({100 * v / tot:.0f}%"
+                if unfed is not None:
+                    out += f", unfed {unfed.get(k, 0.0):.2f}s"
+                return out + ")"
             self.log("phase seconds: " + ", ".join(
-                f"{k} {v:.2f}s ({100 * v / tot:.0f}%)"
+                cell(k, v)
                 for k, v in sorted(ph.items(), key=lambda kv: -kv[1])))
         if doc["counters"]:
             self.log("counters: " + ", ".join(

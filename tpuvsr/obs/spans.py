@@ -28,10 +28,12 @@ INIT = "tpuvsr.engine.init"
 GRAPH_BUILD = "tpuvsr.engine.graph_build"
 PAGE_IN = "tpuvsr.engine.page_in"
 PAGE_OUT = "tpuvsr.engine.page_out"
+BOUNDARY = "tpuvsr.engine.boundary"
+FINISH = "tpuvsr.engine.finish"
 
 #: host span -> phase key of the ``tpuvsr-metrics/1`` document
 ENGINE_SPANS = {
-    CHECK: "check",             # the run's catch-all frame
+    CHECK: "check",             # the run's root frame: what no span below names
     BUILD: "compile",           # first call of a fresh jit
     DISPATCH: "dispatch",       # enqueue of a built program
     INFLIGHT: "inflight",       # blocked wait on the oldest ticket
@@ -41,6 +43,8 @@ ENGINE_SPANS = {
     GRAPH_BUILD: "graph_build",  # liveness: behavior-graph construction
     PAGE_IN: "page_in",         # paged engine: one frontier page, host -> device
     PAGE_OUT: "page_out",       # paged engine: the next buffer's pages, device -> host
+    BOUNDARY: "boundary",       # last collect of a level (paged: a chunk) -> next launch
+    FINISH: "finish",           # an engine's _finish, up to RunObserver.finish
 }
 
 JOB = "tpuvsr.service.job"

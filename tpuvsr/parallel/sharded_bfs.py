@@ -901,7 +901,11 @@ class ShardedBFS:
         (they ride the per-level collective gather already)."""
     del _DB
 
-    def _put(self, arr):
+    def _put(self, arr, obs=None):
+        """Host array -> sharded global array.  With `obs` (the puts a
+        level starts with) its bytes count as ``boundary_put_bytes``."""
+        if obs is not None:
+            obs.count("boundary_put_bytes", arr.nbytes)
         return put_sharded(arr, self._sh)
 
     def _rep(self, arr):
@@ -909,20 +913,21 @@ class ShardedBFS:
         global array (a P() input of the sharded kernels)."""
         return put_sharded(arr, self._rep_sh)
 
-    def _alloc_frontier(self, cap):
+    def _alloc_frontier(self, cap, obs):
+        """A level's next buffers: zeros made on the host and put."""
         D = self.D
         if self._pk is not None:
             # packed at-rest frontier (ISSUE 9): [D*cap, words] uint32
             # planes — the exchange and the next frontier move packed
             # rows, so this buffer IS the interchange format
             nb = self._put(np.zeros((D * cap, self._pk.words),
-                                    np.uint32))
+                                    np.uint32), obs)
         else:
             zero = self.codec.zero_state()
             nb = {k: self._put(np.zeros((D * cap,) + np.shape(v),
-                                        np.int32))
+                                        np.int32), obs)
                   for k, v in zero.items()}
-        z = lambda: self._put(np.zeros((D * cap,), np.int32))
+        z = lambda: self._put(np.zeros((D * cap,), np.int32), obs)
         return nb, z(), z(), z()
 
     def _start_frontier(self, rows, counts0, obs):
@@ -956,10 +961,14 @@ class ShardedBFS:
         return {k: heads(np.zeros((D, F) + np.shape(v), np.int32), rows[k])
                 for k, v in self.codec.zero_state().items()}
 
-    def _pull_rows(self, garr, counts):
-        """Gather per-device live rows of a [D*cap, ...] global array."""
+    def _pull_rows(self, garr, counts, obs=None):
+        """Gather per-device live rows of a [D*cap, ...] global array:
+        the whole array comes to the host.  With `obs` (a level's
+        pointer planes) its bytes count as ``boundary_pull_bytes``."""
         cap = garr.shape[0] // self.D
         host = self._pull(garr)
+        if obs is not None:
+            obs.count("boundary_pull_bytes", host.nbytes)
         return np.concatenate(
             [host[d * cap:d * cap + int(counts[d])]
              for d in range(self.D)], axis=0)
@@ -1013,6 +1022,16 @@ class ShardedBFS:
         obs.start(t0, backend=jax.default_backend(),
                   resumed=resume_from is not None)
         emit = obs.log
+        # pipelined dispatch window (ISSUE 4): the sharded step is one
+        # whole-level attempt, chained on its own outputs; the host
+        # blocks only on the oldest in-flight step's reason.  Replays
+        # behind a pause commit nothing (every sharded abort is a
+        # pre-commit vote), so pipe.drain() discarding them keeps
+        # counts/levels/traces identical to -pipeline 1.  Made as the
+        # run starts: its unfed clock counts the set-up
+        from ..engine.pipeline import DispatchPipeline
+        pipe = DispatchPipeline(self.pipe_window, obs,
+                                ready=lambda o: o[7])
 
         if check_deadlock is not None and bool(check_deadlock) != self._ckd:
             self._ckd = bool(check_deadlock)
@@ -1270,16 +1289,6 @@ class ShardedBFS:
                 return bool(flag)
 
             agree_any = bool
-        # pipelined dispatch window (ISSUE 4): the sharded step is one
-        # whole-level attempt, chained on its own outputs; the host
-        # blocks only on the oldest in-flight step's reason.  Replays
-        # behind a pause commit nothing (every sharded abort is a
-        # pre-commit vote), so pipe.drain() discarding them keeps
-        # counts/levels/traces identical to -pipeline 1.
-        from ..engine.pipeline import DispatchPipeline
-        pipe = DispatchPipeline(self.pipe_window, obs,
-                                ready=lambda o: o[7])
-
         pack_scalars = jax.jit(
             lambda r, s, g, gf, am, a: jnp.concatenate(
                 [r[:, None], s[:, None], g[:, None], gf[:, None],
@@ -1309,6 +1318,9 @@ class ShardedBFS:
                     else None)
         xretry = 0      # consecutive exchange-drop retries (bounded)
 
+        # the host between two levels' device work (and before the
+        # first): open from here, or from a level's end, to the launch
+        obs.boundary(depth=depth)
         while True:
             with obs.span(spans.HOST_SYNC):
                 front_total = int(self._pull(n_front).sum())
@@ -1319,10 +1331,10 @@ class ShardedBFS:
                 break
             depth += 1
             fault_point("level", depth=depth, shard=my_shard, obs=obs)
-            nb, nbp, nba, nbprm = self._alloc_frontier(self.N)
-            nn = self._put(np.zeros(D, np.int32))
-            start_t = self._put(np.zeros(D, np.int32))
-            base_gid = self._put(base_dev.astype(np.int32))
+            nb, nbp, nba, nbprm = self._alloc_frontier(self.N, obs)
+            nn = self._put(np.zeros(D, np.int32), obs)
+            start_t = self._put(np.zeros(D, np.int32), obs)
+            base_gid = self._put(base_dev.astype(np.int32), obs)
             while True:
                 while pipe.has_room():
                     # transient exchange failure: bounded exponential-
@@ -1544,6 +1556,7 @@ class ShardedBFS:
 
             # committed tiles this level x full static bucket volume
             # (generated was already accumulated per dispatch attempt)
+            obs.boundary(depth=depth)
             with obs.span(spans.HOST_SYNC):
                 tiles_lvl = int(self._pull(start_t).max())
                 wire = tiles_lvl * D * D * self.bucket_cap
@@ -1564,9 +1577,11 @@ class ShardedBFS:
             if n_next:
                 with obs.span(spans.HOST_SYNC):
                     self._h_parent.append(
-                        self._pull_rows(nbp, nn_h).astype(np.int64))
-                    self._h_action.append(self._pull_rows(nba, nn_h))
-                    self._h_param.append(self._pull_rows(nbprm, nn_h))
+                        self._pull_rows(nbp, nn_h, obs).astype(np.int64))
+                    self._h_action.append(
+                        self._pull_rows(nba, nn_h, obs))
+                    self._h_param.append(
+                        self._pull_rows(nbprm, nn_h, obs))
                 self.level_sizes.append(n_next)
                 self._dev_distinct += nn_h
             # gid bases of the new frontier (device-order concatenation)
@@ -1587,51 +1602,53 @@ class ShardedBFS:
                     _time.time() - last_checkpoint >= checkpoint_every)):
                 from ..engine.checkpoint import (save_checkpoint,
                                                  spec_digest)
-                # the pulls are collectives in multi-process mode —
-                # every process participates; only rank 0 writes
-                ck_slots = self._pull(tables["slots"])
-                # snapshots always store DENSE planes — the interchange
-                # format any engine/pack configuration can resume
-                ck_front = (self._pk.unpack_np(
-                    self._pull_rows(front, nn_h))
-                    if self._pk is not None else
-                    {k: self._pull_rows(v, nn_h)
-                     for k, v in front.items()})
-                if jax.process_index() == 0:
-                    save_checkpoint(
-                        checkpoint_path,
-                        slots=ck_slots,
-                        frontier=ck_front,
-                        n_front=n_next,
-                        h_parent=np.concatenate(self._h_parent),
-                        h_action=np.concatenate(self._h_action),
-                        h_param=np.concatenate(self._h_param),
-                        init_dense=[self.codec.encode(st)
-                                    for st in self._init_states],
-                        level_sizes=self.level_sizes, depth=depth,
-                        fp_count=fp_count,
-                        states_generated=res.states_generated,
-                        max_msgs=self.codec.shape.MAX_MSGS,
-                        expand_mults=[],
-                        elapsed=_time.time() - t0,
-                        digest=spec_digest(spec),
-                        pack=self._pack_manifest(),
-                        canon=self._canon_manifest(),
-                        bounds=self._bounds_manifest(),
-                        por=self._por_manifest(), obs=obs,
-                        extra={"sharded": True,
-                               "shard_counts": [int(x) for x in nn_h],
-                               "bucket_cap": self.bucket_cap,
-                               "fp_cap": self.fp_cap, "N": self.N,
-                               "dev_distinct": [int(x) for x in
-                                                self._dev_distinct],
-                               "exchange": {
-                                   "useful_rows": exch_rows_useful,
-                                   "wire_rows": exch_rows_wire,
-                                   "useful_bytes": exch_bytes_useful,
-                                   "wire_bytes": exch_bytes_wire,
-                                   "offchip_bytes":
-                                       exch_bytes_offchip}})
+                with obs.span(spans.CHECKPOINT, depth=depth):
+                    # the pulls are collectives in multi-process mode —
+                    # every process participates; only rank 0 writes
+                    ck_slots = self._pull(tables["slots"])
+                    # snapshots always store DENSE planes — the
+                    # interchange format any engine/pack configuration
+                    # can resume
+                    ck_front = (self._pk.unpack_np(
+                        self._pull_rows(front, nn_h))
+                        if self._pk is not None else
+                        {k: self._pull_rows(v, nn_h)
+                         for k, v in front.items()})
+                    if jax.process_index() == 0:
+                        save_checkpoint(
+                            checkpoint_path,
+                            slots=ck_slots,
+                            frontier=ck_front,
+                            n_front=n_next,
+                            h_parent=np.concatenate(self._h_parent),
+                            h_action=np.concatenate(self._h_action),
+                            h_param=np.concatenate(self._h_param),
+                            init_dense=[self.codec.encode(st)
+                                        for st in self._init_states],
+                            level_sizes=self.level_sizes, depth=depth,
+                            fp_count=fp_count,
+                            states_generated=res.states_generated,
+                            max_msgs=self.codec.shape.MAX_MSGS,
+                            expand_mults=[],
+                            elapsed=_time.time() - t0,
+                            digest=spec_digest(spec),
+                            pack=self._pack_manifest(),
+                            canon=self._canon_manifest(),
+                            bounds=self._bounds_manifest(),
+                            por=self._por_manifest(), obs=obs,
+                            extra={"sharded": True,
+                                   "shard_counts": [int(x) for x in nn_h],
+                                   "bucket_cap": self.bucket_cap,
+                                   "fp_cap": self.fp_cap, "N": self.N,
+                                   "dev_distinct": [int(x) for x in
+                                                    self._dev_distinct],
+                                   "exchange": {
+                                       "useful_rows": exch_rows_useful,
+                                       "wire_rows": exch_rows_wire,
+                                       "useful_bytes": exch_bytes_useful,
+                                       "wire_bytes": exch_bytes_wire,
+                                       "offchip_bytes":
+                                           exch_bytes_offchip}})
                 last_checkpoint = _time.time()
                 obs.checkpoint(checkpoint_path, depth, fp_count)
                 emit(f"checkpoint written to {checkpoint_path} "
@@ -1669,6 +1686,13 @@ class ShardedBFS:
         return self._finish(res, obs, fp_count)
 
     def _finish(self, res, obs, fp_count):
+        obs.end_boundary()
+        with obs.span(spans.FINISH):
+            self._final_gauges(res, obs, fp_count)
+        return obs.finish(res,
+                          levels=getattr(self, "level_sizes", None))
+
+    def _final_gauges(self, res, obs, fp_count):
         self._bounds_gauges(obs)
         self._por_gauges(obs)
         res.distinct_states = fp_count
@@ -1702,16 +1726,12 @@ class ShardedBFS:
                       {n: int(c) for n, c in
                        zip(self.kern.action_names, acts)})
         # occupancy = real work items / expand lanes dispatched
-        # (ISSUE 10); the sharded step always commits with ONE insert
-        # batch per tile (the exchange receiver), in both commit modes
+        # (ISSUE 10)
         lanes = getattr(self, "_lanes_disp", 0)
         if lanes and acts is not None:
             obs.gauge("occupancy",
                       round(float(acts.sum()) / lanes, 4))
-        obs.gauge("inserts_per_tile", 1)
         obs.gauge("commit_mode", self.commit)
-        return obs.finish(res,
-                          levels=getattr(self, "level_sizes", None))
 
     def _lanes_per_tile(self):
         """Expand lanes one tile dispatches on one device: the fused
