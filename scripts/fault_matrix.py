@@ -14,9 +14,9 @@ reference mount, no TPU, seconds on the CPU backend:
                      Preempted raised; -recover reproduces the
                      uninterrupted run's counts exactly
   pack-kill-rescue   same kill with the packed frontier ON (ISSUE 9):
-                     the rescue snapshot stores DENSE planes, and both
-                     a packed and a -pack off engine resume it to the
-                     exact fixpoint
+                     the rescue snapshot loads as DENSE planes, and
+                     both a packed and a -pack off engine resume it to
+                     the exact fixpoint
   corrupt-ckpt       crash-corrupted snapshot write (payload truncated,
                      .old kept) -> load_checkpoint falls back to .old
                      and the resumed run still reaches the fixpoint
@@ -268,7 +268,7 @@ def scenario_kill_rescue(tmp):
 
 def scenario_pack_kill_rescue(tmp):
     """ISSUE 9 satellite: kill mid-run with the packed frontier ON ->
-    rescue checkpoint (stored DENSE, the interchange format), then BOTH
+    rescue checkpoint (loaded DENSE, the interchange format), then BOTH
     a packed and a dense engine resume it to the exact fixpoint — the
     packed at-rest representation is invisible across the rescue
     seam."""

@@ -26,6 +26,23 @@ The manifest also records a digest of the spec identity (module name,
 constants, invariants, view/symmetry) so ``-recover`` with a mismatched
 spec or .cfg is rejected instead of silently resuming with
 incompatible fingerprints (TLC likewise errors on recover mismatch).
+
+**Format 4** (ISSUE 36): the payloads hold what the run holds, in the
+form the device holds it, so a snapshot costs what the run found and
+not what its table could hold.  ``fpset.npz`` stores the OCCUPIED slots
+(``index``: flat indices over the leading dimensions, ``rows``: their
+words, ``shape``: the table's; ``gid_rows``: the same rows of the
+parallel gid column) and the loader scatters them into zeros, so the
+table comes back bit for bit.  ``frontier.npz`` stores the PACKED rows
+(one member ``packed``, ``[n_front, words]``) when the writer hands
+them over with its pack manifest; the loader unpacks them with
+``PackSpec.from_manifest`` and still returns dense planes, the
+interchange form any engine or pack configuration resumes.  Dense
+writers and the streamed ``frontier_blocks`` path write dense planes
+as before.  **Format 3** (the whole table and dense planes always) is
+still read: a job whose worker died before the upgrade resumes.  File
+names, CRCs, fsyncs, renames and the ``.old`` fallback are those of
+format 3.
 """
 
 from __future__ import annotations
@@ -41,7 +58,10 @@ import zlib
 import numpy as np
 from numpy.lib import format as _npformat
 
-FORMAT_VERSION = 3
+FORMAT_VERSION = 4
+#: formats ``load_checkpoint`` reads: 3 wrote the whole table and dense
+#: frontier planes; a 4 reader takes both member layouts by their names
+READ_FORMATS = (3, 4)
 
 #: the payload files of one snapshot directory, in write order
 PAYLOADS = ("fpset.npz", "frontier.npz", "trace.npz", "init.npz")
@@ -115,6 +135,22 @@ def _write_frontier_chunks(path, blocks):
     return rows
 
 
+def _dense_frontier(fr, manifest):
+    """A loaded frontier payload as dense planes: packed rows (the
+    manifest says ``frontier_packed``; one member, ``packed``) are
+    unpacked by the spec the manifest's ``pack`` rebuilds; dense
+    members, chunked or plain, are assembled."""
+    if not manifest.get("frontier_packed"):
+        return _assemble_frontier(fr)
+    if set(fr) != {"packed"} or not manifest.get("pack"):
+        raise CheckpointCorrupt(
+            "manifest says the frontier is packed: it needs a pack "
+            f"spec and the one member 'packed', not {sorted(fr)}")
+    from .pack import PackSpec
+    return PackSpec.from_manifest(manifest["pack"]).unpack_np(
+        fr["packed"])
+
+
 def _assemble_frontier(fr):
     """Reassemble a frontier payload dict: plain per-plane arrays pass
     through; chunked members (``<plane>.<i>``) concatenate in chunk
@@ -135,26 +171,70 @@ def _assemble_frontier(fr):
         for plane, parts in chunks.items()}
 
 
+def _occupied_members(slots, gids):
+    """The members of a format-4 ``fpset.npz``: the occupied slots of
+    `slots` (``[..., cap, words]``; word 0 is the tag and 0 is the
+    empty slot, engine/fpset.py) as flat indices over the leading
+    dimensions, their words (claim column included) and the table's
+    shape; of `gids`, the parallel column, the same rows."""
+    slots = np.asarray(slots)
+    flat = slots.reshape(-1, slots.shape[-1])
+    index = np.flatnonzero(flat[:, 0])
+    arrs = {"shape": np.asarray(slots.shape, np.int64),
+            "index": (index.astype(np.uint32)
+                      if flat.shape[0] <= 1 << 32 else index),
+            "rows": flat[index]}
+    if gids is not None:
+        arrs["gid_rows"] = np.asarray(gids).reshape(-1)[index]
+    return arrs
+
+
+def _scatter_table(fp):
+    """Undo ``_occupied_members``: ``(slots, gids)`` of a loaded
+    ``fpset.npz``, bit for bit the arrays that were saved.  A format-3
+    payload holds them whole."""
+    if "slots" in fp:
+        return fp["slots"], fp.get("gids")
+    shape = tuple(int(x) for x in fp["shape"])
+    index = fp["index"]
+    slots = np.zeros(shape, fp["rows"].dtype)
+    slots.reshape(-1, shape[-1])[index] = fp["rows"]
+    gids = None
+    if "gid_rows" in fp:
+        gids = np.zeros(shape[:-1], fp["gid_rows"].dtype)
+        gids.reshape(-1)[index] = fp["gid_rows"]
+    return slots, gids
+
+
 def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
                     h_action, h_param, init_dense, level_sizes, depth,
                     fp_count, states_generated, max_msgs, expand_mults,
                     elapsed, digest=None, extra=None, pack=None,
                     canon=None, bounds=None, por=None,
-                    frontier_blocks=None,
+                    frontier_blocks=None, frontier_packed=None,
                     gids=None, edge_blocks=None, graph_blocks=None,
                     obs=None):
-    """Write a complete engine snapshot to `path` (atomic + durable).
+    """Write a complete engine snapshot to `path` (atomic + durable);
+    returns the bytes staged (payloads + manifest).
+
+    `slots` is the whole table (any leading dimensions: the sharded
+    engine passes ``[D, cap, 5]``); its OCCUPIED slots are what
+    fpset.npz holds (format 4, module docstring).
 
     `frontier` rows beyond `n_front` are dropped; `h_*` are the
     concatenated host trace-pointer arrays; `init_dense` is the dense
     encoding of the (deduped) initial states, in gid order.
 
     `pack` is the packed-frontier spec manifest the writing engine ran
-    under (engine/pack.PackSpec.manifest(); None = packing off).  The
-    frontier payload itself is ALWAYS dense planes — the interchange
-    format any engine/pack configuration can resume — but the manifest
-    records the spec version so resuming under a MISMATCHED widths
-    table is a loud policy error (ISSUE 9 satellite).
+    under (engine/pack.PackSpec.manifest(); None = packing off).  A
+    writer that packs hands its rows over as they are,
+    `frontier_packed` (``[n, words]``, the first `n_front` kept), in
+    place of `frontier`: frontier.npz then holds them packed and
+    ``load_checkpoint`` unpacks them by the manifest's spec — it
+    returns DENSE planes either way, the interchange form any
+    engine/pack configuration can resume — and the manifest's spec
+    version makes resuming under a MISMATCHED widths table a loud
+    policy error (ISSUE 9 satellite).
 
     `frontier_blocks` (ISSUE 13 satellite) replaces `frontier` with an
     ITERATOR of dense plane-dict blocks: each block is streamed into
@@ -177,10 +257,10 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
     if os.path.isdir(tmp):
         shutil.rmtree(tmp)
     os.makedirs(tmp)
-    fp_arrs = {"slots": np.asarray(slots)}
-    if gids is not None:
-        fp_arrs["gids"] = np.asarray(gids)
-    np.savez_compressed(os.path.join(tmp, "fpset.npz"), **fp_arrs)
+    # stored, not deflated: fingerprints are hash words, deflate takes
+    # a fifth off them at sixteen times the time
+    np.savez(os.path.join(tmp, "fpset.npz"),
+             **_occupied_members(slots, gids))
     extra_payloads = []
     if edge_blocks is not None:
         _write_frontier_chunks(os.path.join(tmp, "edges.npz"),
@@ -190,6 +270,7 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
         _write_frontier_chunks(os.path.join(tmp, "graph.npz"),
                                graph_blocks)
         extra_payloads.append("graph.npz")
+    packed = frontier_blocks is None and frontier_packed is not None
     if frontier_blocks is not None:
         rows = _write_frontier_chunks(
             os.path.join(tmp, "frontier.npz"), frontier_blocks)
@@ -197,6 +278,13 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
             raise ValueError(
                 f"frontier_blocks yielded {rows} rows, n_front is "
                 f"{n_front}")
+    elif packed:
+        if pack is None:
+            raise ValueError("frontier_packed needs the writer's pack "
+                             "manifest to be read back")
+        np.savez_compressed(
+            os.path.join(tmp, "frontier.npz"),
+            packed=np.asarray(frontier_packed)[:n_front])
     else:
         np.savez_compressed(
             os.path.join(tmp, "frontier.npz"),
@@ -210,8 +298,9 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
     # CRCs are computed over the INTENDED payload bytes, before the
     # corrupt-ckpt fault hook below mangles anything — a fault-injected
     # torn write is therefore CRC-detectable, like a real one
+    payloads = list(PAYLOADS) + extra_payloads
     crcs = {name: _crc32_file(os.path.join(tmp, name))
-            for name in list(PAYLOADS) + extra_payloads}
+            for name in payloads}
     manifest = {
         "format": FORMAT_VERSION,
         "n_front": int(n_front),
@@ -228,6 +317,9 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
         # packed-frontier spec identity (ISSUE 9): version digest +
         # plane table of the writer's packing spec, None when dense
         "pack": pack,
+        # frontier.npz holds the writer's packed rows (to be unpacked
+        # by `pack`), not dense planes
+        "frontier_packed": packed,
         # symmetry canonicalization spec (ISSUE 11): version digest +
         # group order + orbit plane table of the writer's CanonSpec,
         # None when the run stored raw (non-canonical) fingerprints.
@@ -255,6 +347,10 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
         json.dump(manifest, f)
         f.flush()
         os.fsync(f.fileno())
+        staged = f.tell()
+    # sized before the fault hook below may truncate one
+    staged += sum(os.path.getsize(os.path.join(tmp, name))
+                  for name in payloads)
     # fault hook: emulate a corrupted write AND leave the previous
     # snapshot as .old (the crash window between rename-into-place and
     # .old cleanup).  Two flavors (resilience/faults.py): corrupt-ckpt
@@ -274,7 +370,7 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
                 f.write(bytes(b ^ 0xFF for b in chunk))
             else:
                 f.truncate(max(1, size // 2))
-    for name in list(PAYLOADS) + extra_payloads:
+    for name in payloads:
         _fsync_path(os.path.join(tmp, name))
     _fsync_path(tmp)
     old = path + ".old"
@@ -288,6 +384,7 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
     if os.path.isdir(old) and not corrupt:
         shutil.rmtree(old)
         _fsync_path(parent)
+    return staged
 
 
 def snapshot_info(path):
@@ -333,10 +430,10 @@ def _read_snapshot(path, expect_digest):
     except ValueError as e:
         raise CheckpointCorrupt(f"{mf}: manifest is not valid JSON "
                                 f"({e})")
-    if manifest.get("format") != FORMAT_VERSION:
+    if manifest.get("format") not in READ_FORMATS:
         raise ValueError(
             f"checkpoint format {manifest.get('format')} unsupported "
-            f"(want {FORMAT_VERSION})")
+            f"(want one of {READ_FORMATS})")
     if expect_digest is not None and manifest.get("spec_digest") and \
             manifest["spec_digest"] != expect_digest:
         raise ValueError(
@@ -366,7 +463,8 @@ def _read_snapshot(path, expect_digest):
                 f"{p}: unreadable payload "
                 f"({type(e).__name__}: {e})")
     n_front = int(manifest["n_front"])
-    arrs["frontier.npz"] = _assemble_frontier(arrs["frontier.npz"])
+    arrs["frontier.npz"] = _dense_frontier(arrs["frontier.npz"],
+                                           manifest)
     for k, v in arrs["frontier.npz"].items():
         if v.shape[0] != n_front:
             raise CheckpointCorrupt(
@@ -410,12 +508,13 @@ def load_checkpoint(path, expect_digest=None, log=None):
             return None
         d = _assemble_frontier(d)
         return d or None        # zero-block payload == absent
+    slots, gids = _scatter_table(fp)
     return {
-        "slots": fp["slots"],
+        "slots": slots,
         # streamed edge emission (ISSUE 15): the gid column and the
         # drained edge / retained graph rows, when the writer ran
         # with edges on (None otherwise)
-        "gids": fp.get("gids"),
+        "gids": gids,
         "edges": _opt_chunked("edges.npz"),
         "graph": _opt_chunked("graph.npz"),
         "frontier": dict(fr),

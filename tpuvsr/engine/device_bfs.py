@@ -1731,14 +1731,15 @@ class DeviceBFS:
         return {k: buf[k].at[:n].set(jnp.asarray(batch[k]))
                 for k in buf}
 
-    def _dense_rows(self, buf, n):
-        """First `n` rows of a frontier-format buffer as a dense host
-        plane dict (the checkpoint interchange format: snapshots always
-        store DENSE planes so any engine/pack configuration can resume
-        them)."""
+    def _snapshot_frontier(self, buf, n):
+        """``save_checkpoint``'s frontier keywords for the first `n`
+        rows of a frontier-format buffer: the packed rows as the device
+        holds them (the loader unpacks them by the manifest's pack
+        spec, so any engine/pack configuration still resumes dense
+        planes), or the dense planes of a run that does not pack."""
         if self._pk is not None:
-            return self._pk.unpack_np(np.asarray(buf[:n]))
-        return {k: np.asarray(v[:n]) for k, v in buf.items()}
+            return {"frontier_packed": np.asarray(buf[:n])}
+        return {"frontier": {k: np.asarray(v[:n]) for k, v in buf.items()}}
 
     def _pack_manifest(self):
         return self._pk.manifest() if self._pk is not None else None
@@ -1790,7 +1791,7 @@ class DeviceBFS:
         re-encode — a drifted widths table means the run would pack
         fields into different budgets than the ones speclint verified
         for the snapshot's trajectory.  pack=off on either side is
-        compatible by construction (snapshots store dense planes)."""
+        compatible by construction (snapshots load as dense planes)."""
         ckpk = ck.get("pack")
         if ckpk and self._pk is not None and \
                 ckpk.get("version") != self._pk.version:
@@ -2283,13 +2284,14 @@ class DeviceBFS:
                     rescue is not None
                     or checkpoint_every is None
                     or time.time() - last_checkpoint >= checkpoint_every):
-                from .checkpoint import save_checkpoint, spec_digest
+                from .checkpoint import (FORMAT_VERSION, save_checkpoint,
+                                         spec_digest)
                 with obs.span(spans.CHECKPOINT, depth=depth):
                     self._flush_pointers()
-                    save_checkpoint(
+                    staged = save_checkpoint(
                         checkpoint_path,
                         slots=table["slots"],
-                        frontier=self._dense_rows(front, n_next),
+                        **self._snapshot_frontier(front, n_next),
                         n_front=n_next,
                         h_parent=np.concatenate(self._h_parent),
                         h_action=np.concatenate(self._h_action),
@@ -2307,7 +2309,8 @@ class DeviceBFS:
                         bounds=self._bounds_manifest(),
                         por=self._por_manifest(), obs=obs)
                 last_checkpoint = time.time()
-                obs.checkpoint(checkpoint_path, depth, fp_count)
+                obs.checkpoint(checkpoint_path, depth, fp_count, staged,
+                               FORMAT_VERSION)
                 emit(f"checkpoint written to {checkpoint_path} "
                      f"(depth {depth}, {fp_count} distinct)")
             if rescue is not None:

@@ -172,16 +172,6 @@ class PagedBFS(DeviceBFS):
             return host_front[start:start + n]
         return {k: v[start:start + n] for k, v in host_front.items()}
 
-    def _front_dense(self, host_front, n):
-        """First `n` rows as dense planes (the checkpoint interchange
-        format)."""
-        from .spill import SpillTier
-        if isinstance(host_front, SpillTier):
-            host_front = host_front.all_rows()
-        if self._pk is not None:
-            return self._pk.unpack_np(np.asarray(host_front)[:n])
-        return {k: np.asarray(v)[:n] for k, v in host_front.items()}
-
     def _front_dense_blocks(self, tier, n):
         """Generator of dense plane-dict blocks over a disk-tiered
         frontier, page by page — the streaming checkpoint writer's
@@ -439,8 +429,8 @@ class PagedBFS(DeviceBFS):
             t0 -= ck["elapsed"]
             obs.set_epoch(t0)
             n_front = ck["n_front"]
-            # snapshots store dense planes (the engine-agnostic
-            # interchange format); pack them on load when packing is on
+            # snapshots load as dense planes (the engine-agnostic
+            # interchange format); pack them when packing is on
             host_front = (self._pk.pack_np(
                 {k: np.asarray(v) for k, v in ck["frontier"].items()})
                 if self._pk is not None else
@@ -858,7 +848,8 @@ class PagedBFS(DeviceBFS):
                     rescue is not None
                     or checkpoint_every is None
                     or time.time() - last_checkpoint >= checkpoint_every):
-                from .checkpoint import save_checkpoint, spec_digest
+                from .checkpoint import (FORMAT_VERSION, save_checkpoint,
+                                         spec_digest)
                 from .spill import SpillTier
                 # disk-tiered frontier: STREAM pages into the staged
                 # npz (peak residency = one page) instead of
@@ -868,8 +859,7 @@ class PagedBFS(DeviceBFS):
                     {"frontier_blocks":
                      self._front_dense_blocks(host_front, n_front)}
                     if isinstance(host_front, SpillTier) else
-                    {"frontier": self._front_dense(host_front,
-                                                   n_front)})
+                    self._snapshot_frontier(host_front, n_front))
                 if self._edges_on:
                     # edge-stream seam (ISSUE 15): the gid column,
                     # the drained edge rows up to this committed
@@ -882,7 +872,7 @@ class PagedBFS(DeviceBFS):
                         fr_kw["graph_blocks"] = iter(
                             self.level_blocks)
                 with obs.span(spans.CHECKPOINT, depth=depth):
-                    save_checkpoint(
+                    staged = save_checkpoint(
                         checkpoint_path,
                         slots=table["slots"],
                         n_front=n_front,
@@ -903,7 +893,8 @@ class PagedBFS(DeviceBFS):
                         bounds=self._bounds_manifest(),
                         por=self._por_manifest(), obs=obs)
                 last_checkpoint = time.time()
-                obs.checkpoint(checkpoint_path, depth, fp_count)
+                obs.checkpoint(checkpoint_path, depth, fp_count, staged,
+                               FORMAT_VERSION)
                 emit(f"checkpoint written to {checkpoint_path} "
                      f"(depth {depth}, {fp_count} distinct)")
             if rescue is not None:

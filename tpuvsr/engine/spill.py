@@ -29,9 +29,10 @@ Design:
   violation/deadlock parent materialization;
 * ``map_pages`` rewrites every page through a transform — the
   MAX_MSGS bag-growth re-pack rides it;
-* checkpoints store DENSE planes regardless (the engine-agnostic
-  interchange format), so a resume re-packs and re-spills under the
-  resuming run's own budget.  Snapshot WRITES stream (ISSUE 13
+* a spilled frontier's checkpoint stores DENSE planes (the
+  engine-agnostic interchange format every snapshot loads as), so a
+  resume re-packs and re-spills under the resuming run's own budget.
+  Snapshot WRITES stream (ISSUE 13
   satellite — the PR 11 residual): ``save_checkpoint`` accepts a
   block iterator (``frontier_blocks``) and the paged engine feeds it
   the tier's pages one at a time (``PagedBFS._front_dense_blocks``),
@@ -176,11 +177,6 @@ class SpillTier:
 
     def row(self, i):
         return self.block(int(i), 1)
-
-    def all_rows(self):
-        if self.rows == 0:
-            return _concat([b for b in self._ram]) if self._ram else None
-        return self.block(0, self.rows)
 
     # -- maintenance ---------------------------------------------------
     def map_pages(self, fn):

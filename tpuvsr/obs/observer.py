@@ -345,10 +345,15 @@ class RunObserver:
                            phases=row["phases"], unfed_s=row["unfed_s"],
                            dispatches=row["dispatches"], **extra)
 
-    def checkpoint(self, path, depth, distinct):
+    def checkpoint(self, path, depth, distinct, nbytes, fmt):
+        """A level-boundary snapshot was written: `nbytes` staged
+        (payloads + manifest, ``save_checkpoint``'s return; 0 on a
+        rank that wrote nothing) in snapshot format `fmt`."""
         self.count("checkpoints")
+        self.count("checkpoint_bytes", nbytes)
         self.journal.write("checkpoint", path=str(path), depth=int(depth),
-                           distinct=int(distinct),
+                           distinct=int(distinct), bytes=int(nbytes),
+                           format=int(fmt),
                            elapsed_s=round(self.elapsed(), 3))
 
     def spill(self, depth, rows, nbytes, **extra):

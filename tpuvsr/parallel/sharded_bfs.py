@@ -1184,7 +1184,7 @@ class ShardedBFS:
             exch_bytes_wire = xc.get("wire_bytes", 0)
             exch_bytes_offchip = xc.get("offchip_bytes", 0)
             F = self.N
-            # snapshots store dense planes (the engine-agnostic
+            # snapshots load as dense planes (the engine-agnostic
             # interchange format); the start packs them when packing
             # is on
             with obs.span(spans.INIT):
@@ -1600,25 +1600,28 @@ class ShardedBFS:
             if checkpoint_path and n_next and (want_rescue or agree(
                     checkpoint_every is None or
                     _time.time() - last_checkpoint >= checkpoint_every)):
-                from ..engine.checkpoint import (save_checkpoint,
+                from ..engine.checkpoint import (FORMAT_VERSION,
+                                                 save_checkpoint,
                                                  spec_digest)
                 with obs.span(spans.CHECKPOINT, depth=depth):
                     # the pulls are collectives in multi-process mode —
                     # every process participates; only rank 0 writes
                     ck_slots = self._pull(tables["slots"])
-                    # snapshots always store DENSE planes — the
-                    # interchange format any engine/pack configuration
-                    # can resume
-                    ck_front = (self._pk.unpack_np(
-                        self._pull_rows(front, nn_h))
+                    # the packed rows as the shards hold them (the
+                    # loader unpacks them: any engine/pack
+                    # configuration resumes dense planes), or the
+                    # dense planes of a run that does not pack
+                    ck_front = (
+                        {"frontier_packed": self._pull_rows(front, nn_h)}
                         if self._pk is not None else
-                        {k: self._pull_rows(v, nn_h)
-                         for k, v in front.items()})
+                        {"frontier": {k: self._pull_rows(v, nn_h)
+                                      for k, v in front.items()}})
+                    staged = 0      # only rank 0 writes
                     if jax.process_index() == 0:
-                        save_checkpoint(
+                        staged = save_checkpoint(
                             checkpoint_path,
                             slots=ck_slots,
-                            frontier=ck_front,
+                            **ck_front,
                             n_front=n_next,
                             h_parent=np.concatenate(self._h_parent),
                             h_action=np.concatenate(self._h_action),
@@ -1650,7 +1653,8 @@ class ShardedBFS:
                                        "offchip_bytes":
                                            exch_bytes_offchip}})
                 last_checkpoint = _time.time()
-                obs.checkpoint(checkpoint_path, depth, fp_count)
+                obs.checkpoint(checkpoint_path, depth, fp_count, staged,
+                               FORMAT_VERSION)
                 emit(f"checkpoint written to {checkpoint_path} "
                      f"(depth {depth}, {fp_count} distinct)")
             if want_rescue:
