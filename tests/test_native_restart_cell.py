@@ -1,0 +1,230 @@
+"""The cell's cfg of the restart era (benchmark/configs/
+vsr-shipped-restart.cfg: the shipped VSR.cfg with RestartEmptyLimit
+turned to 1, SYMMETRY on) from committed files:
+
+- the receive-set is a set: two arrival orders of the same two
+  records give one row, one fingerprint, one canon key, and a
+  symmetry relabel that turns their order leaves them in canonical
+  order again (models/vsr.py, layout);
+- the three engines, symmetry on, against the plain reference of
+  orbit reduction (benchmark/tools/orbit_reference.py), as
+  tests/test_native_shipped.py does for the shipped cfg;
+- a shape that cannot restart is untouched: the pack manifest, the
+  program store's key and the lowered level program of vsr-shipped
+  and vsr-defect are what they were before the layout learned K.
+"""
+
+import hashlib
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from tpuvsr.core.values import FnVal, mk_record, permute_value
+from tpuvsr.engine.spec import load_spec
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmark", "tools"))
+import orbit_reference  # noqa: E402
+
+CONFIGS = os.path.join(REPO, "benchmark", "configs")
+CFG = os.path.join(CONFIGS, "vsr-shipped-restart.cfg")
+DEPTH = 5
+MAX_MSGS = 32
+ORBITS = [1, 6, 27, 113, 446, 1695]
+
+
+@pytest.fixture(scope="module")
+def spec():
+    return load_spec("VSR", CFG)
+
+
+# ---------------------------------------------------------------------
+# (c) arrival order does not reach row, fingerprint or canon key
+# ---------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def model(spec):
+    """(codec, kernel as the engines build it, canon spec, pack spec)."""
+    from tpuvsr.engine.canon import build_canon_spec
+    from tpuvsr.engine.pack import build_pack_spec
+    from tpuvsr.models.registry import make_model
+    codec, kern = make_model(spec, max_msgs=MAX_MSGS, fold_symmetry=False)
+    return (codec, kern, build_canon_spec(spec, codec, kern),
+            build_pack_spec(codec, spec=spec))
+
+
+def _two_pending(spec):
+    """Init with replica 2, primary of view 2, in a view change, and
+    two DoViewChange records of replica 3 for it in the bag: equal in
+    all but the value their one log entry holds."""
+    c = spec.cfg.constants
+    (init,) = spec.init_states()
+    v1, v2 = sorted(c["Values"], key=repr)
+
+    def dvc(value):
+        return mk_record(
+            type=c["DoViewChangeMsg"], view_number=2,
+            log=FnVal([(1, mk_record(view_number=1, operation=value,
+                                     client_id=1, request_number=1))]),
+            last_normal_vn=1, op_number=1, commit_number=0, dest=2,
+            source=3)
+    state = dict(init)
+    state["rep_view_number"] = init["rep_view_number"].updated(2, 2)
+    state["rep_status"] = init["rep_status"].updated(2, c["ViewChange"])
+    state["messages"] = FnVal([(dvc(v1), 1), (dvc(v2), 1)])
+    return state, {v1: v2, v2: v1}
+
+
+def test_two_arrival_orders_give_one_state(spec, model):
+    codec, kern, canon, pk = model
+    assert kern.K == 3 and canon.perms == 2
+    start, swap = _two_pending(spec)
+    dense = {k: jnp.asarray(v) for k, v in codec.encode(start).items()}
+    receive = jax.jit(kern.act_receive_matching_dvc)
+
+    def deliver(order):
+        st = dense
+        for lane in order:
+            st, enabled = receive(st, jnp.asarray(lane, jnp.int32))
+            assert bool(enabled) and int(st["err"]) == 0
+        return st
+    a, b = deliver((0, 1)), deliver((1, 0))
+    for k in a:
+        assert np.array_equal(a[k], b[k]), k
+    assert np.array_equal(pk.pack(a), pk.pack(b))
+    assert np.array_equal(kern.fingerprint(a), kern.fingerprint(b))
+    key = jax.jit(lambda st: canon._key(canon.least(st)[0]))
+    assert np.array_equal(key(a), key(b))
+    # the set the host sees holds both, whatever the order
+    got = codec.decode(a)
+    assert len(got["rep_dvc_recv"].apply(2)) == 2
+    assert codec.decode(codec.encode(got)) == got
+
+    # a relabel that swaps the two records' values swaps their order:
+    # the image is in canonical order again, i.e. the state the codec
+    # makes of the permuted host value, and both have one fingerprint
+    image = kern._permuted(a, jnp.asarray(canon.group[1]))
+    want = codec.encode({k: permute_value(v, swap) for k, v in got.items()})
+    # (the replica planes: the bag's slots keep the order they were
+    # filled in, and its hash does not read it)
+    for k in kern.REP_KEYS:
+        assert np.array_equal(image[k], want[k]), k
+    # here the swapped set is the set itself; the relabel alone
+    # leaves its two records the wrong way round
+    assert np.array_equal(image["dvc_log"], a["dvc_log"])
+    turned = kern._permuted(a, jnp.asarray(canon.group[1]), resort=False)
+    assert not np.array_equal(turned["dvc_log"], image["dvc_log"])
+    fingerprint = jax.jit(canon.fingerprint_fn(kern))
+    assert np.array_equal(
+        fingerprint(a), fingerprint({k: jnp.asarray(v)
+                                     for k, v in want.items()}))
+
+
+# ---------------------------------------------------------------------
+# (e) the three engines, symmetry on, against the plain reference
+# ---------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def reference(spec):
+    """Per level: the set of least images of a symmetry-off run."""
+    eng, _res = orbit_reference.symmetry_off_run(spec, DEPTH,
+                                                 max_msgs=MAX_MSGS)
+    return [set(orbit_reference.level_images(
+        eng.codec, spec.symmetry_perms, b)) for b in eng.level_blocks]
+
+
+def _device(spec):
+    from tpuvsr.engine.device_bfs import DeviceBFS
+    return DeviceBFS(spec, max_msgs=MAX_MSGS)
+
+
+def _paged(spec):
+    from tpuvsr.engine.paged_bfs import PagedBFS
+    return PagedBFS(spec, max_msgs=MAX_MSGS, retain_levels=True)
+
+
+def _sharded(spec):
+    from tpuvsr.parallel.sharded_bfs import ShardedBFS
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    return ShardedBFS(spec, Mesh(np.array(jax.devices()[:4]), ("d",)),
+                      max_msgs=MAX_MSGS, tile=32, bucket_cap=128,
+                      next_capacity=1 << 11, fpset_capacity=1 << 13)
+
+
+def test_reference_counts_the_pinned_orbits(reference):
+    assert [len(s) for s in reference] == ORBITS
+
+
+@pytest.mark.parametrize("build", [_device, _paged, _sharded],
+                         ids=["device", "paged", "sharded"])
+def test_symmetry_on_levels_equal_the_reference(build, spec, reference):
+    eng = build(spec)
+    res = eng.run(max_depth=DEPTH)
+    assert res.ok and res.error == f"depth limit {DEPTH} reached"
+    assert list(eng.level_sizes) == ORBITS
+    assert res.distinct_states == sum(ORBITS)
+    assert res.metrics["gauges"]["symmetry_perms"] == 2
+    if build is _sharded:
+        return
+    assert res.metrics["counters"]["recovering_states"] > 0
+    if build is _paged:
+        # the representatives the run kept: one an orbit, and level
+        # for level the reference's orbits
+        for block, want in zip(eng.level_blocks, reference):
+            images = orbit_reference.level_images(
+                eng.codec, spec.symmetry_perms, block)
+            assert len(set(images)) == len(images)
+            assert set(images) == want
+
+
+# ---------------------------------------------------------------------
+# (f) a shape with RestartEmptyLimit = 0 is the object it was
+# ---------------------------------------------------------------------
+# Read at commit 1d23b93 (the parent of the PR that gave the receive-set
+# its K slots), in a process of its own, with this file's `_program`.
+# A PR that changes the level program of the restart-free shapes on
+# purpose reads them again; this PR had to leave them alone.
+BEFORE_K = {
+    "vsr-shipped": {
+        "pack": "4794ecbbab3f66ae8443bca05016080f448e065ace9c9565337be2002ba5b17c",
+        "key": "34582b919a2c249e7187a7a41a9789c92531f4bc6bb3ef01e40e4884d7faad2b",
+        "lowered": "7ae60d01018a035a50438d586bc0315e8764a0357359c18b08b434bbc0b5d801"},
+    "vsr-defect": {
+        "pack": "1730ab9928885a97b25695c893b0321fda8edeb4d2ed3b664416ebd42db6952d",
+        "key": "102b033ef3ff7e2083075e2a2c730dcbd346bd4e7f58f097191b9eebc6c06278",
+        "lowered": "e866da7456c9b4ce2c902c74e42c8dd7ed28142f42ea74bce4a344228fc6b0c7"},
+}
+
+
+def _program(config):
+    """sha256 of the pack manifest, the program store's key for what
+    the engine says its trace reads (the process's part, which digests
+    the package's source, left out) and the lowered level program."""
+    from tpuvsr.engine import program_store
+    from tpuvsr.engine.device_bfs import DeviceBFS
+    from tpuvsr.engine.fpset import empty_table
+    eng = DeviceBFS(load_spec("VSR", os.path.join(CONFIGS, config + ".cfg")),
+                    max_msgs=32, tile_size=128, next_capacity=1 << 12,
+                    fpset_capacity=1 << 14)
+    bufs = eng._alloc_bufs(eng.next_cap)
+    i32 = jnp.zeros((), jnp.int32)
+    args = ({"slots": empty_table(eng.fpset_capacity)["slots"]}, bufs[0],
+            i32, i32, *bufs, i32, jnp.zeros((), bool), None, None, i32)
+
+    def sha(text):
+        return hashlib.sha256(text.encode()).hexdigest()
+    return {"pack": sha(json.dumps(eng._pack_manifest(), sort_keys=True)),
+            "key": program_store.program_key(
+                eng._level_key_doc(), program_store._signature(args)),
+            "lowered": sha(eng._level.lower(*args).as_text())}
+
+
+@pytest.mark.parametrize("config", sorted(BEFORE_K))
+def test_a_shape_without_restarts_is_untouched(config):
+    assert _program(config) == BEFORE_K[config]

@@ -14,9 +14,12 @@ alone (interval abstract interpretation over the constant bindings —
 no codec construction, so it still fires when the codec itself would
 refuse the config) and proves each range fits its allocated bit-width:
 
-* view_number  <= 1 + StartViewOnTimerLimit (+ RestartEmptyLimit on
-  VSR: views are only minted by TimerSendSVC under ``aux_svc < limit``,
-  VSR.tla:578-580; a restarted replica can re-reach old views)
+* view_number  <= 1 + StartViewOnTimerLimit: views are only minted by
+  TimerSendSVC under ``aux_svc < limit``, VSR.tla:578-580, and
+  ``aux_svc`` counts the firings of all replicas together; VSR's
+  RestartEmpty sends a replica back to view 1, from where it re-reaches
+  views that exist and mints none, so RestartEmptyLimit adds nothing
+  (models/vsr.py ``MAX_VIEW`` is the same bound)
 * op_number / request_number / operation id <= |Values| (each value is
   requested at most once — the aux_client_acked ghost guard)
 * client_id <= ClientCount
@@ -129,7 +132,6 @@ def derive_ranges_from(constants, module_name):
             else None
 
     timer = geti("StartViewOnTimerLimit")
-    restarts = geti("RestartEmptyLimit", 0)
     crashes = geti("CrashLimit", 0)
     values = c.get("Values")
     nvalues = len(values) if isinstance(values, frozenset) else None
@@ -137,10 +139,7 @@ def derive_ranges_from(constants, module_name):
     replicas = geti("ReplicaCount")
 
     if timer is not None:
-        extra = restarts or 0
-        if module_name != "VSR":
-            extra = 0          # only VSR's RestartEmpty re-mints views
-        rng["view_number"] = (0, 1 + timer + extra)
+        rng["view_number"] = (0, 1 + timer)
     if nvalues is not None:
         rng["operation"] = (0, nvalues)
         rng["op_number"] = (0, nvalues)        # MAX_OPS = |Values|

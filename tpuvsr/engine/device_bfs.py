@@ -243,6 +243,20 @@ def grown_caps(caps, need_seen, full):
     return out
 
 
+def slot_error(codec):
+    """What R_SLOT_ERR says to the user, for all three BFS engines: a
+    successor holds a receive-set the codec's dense layout cannot, and
+    the run stops rather than drop a record (models/vsr.py, layout)."""
+    slots = getattr(codec.shape, "DVC_SLOTS", 1)
+    return (f"dense-layout slot overflow: a successor's rep_dvc_recv "
+            f"would hold more than {slots} different DoViewChange "
+            f"record(s) of one source in one view (K = {slots} at "
+            f"RestartEmptyLimit = "
+            f"{getattr(codec.shape, 'restart_limit', 0)}), or a second "
+            f"recovery response of one source to one nonce; the run "
+            f"stops here and drops nothing (models/vsr.py, layout)")
+
+
 # Largest tile width validated against the pinned fixpoint counts on
 # a real TPU: tile=1024 once mis-explored the flagship config
 # (58,957 distinct vs pinned 43,941), an unresolved TPU-lowering
@@ -513,6 +527,17 @@ class DeviceBFS:
         # canonicalizes carries the counter
         self._canon_counts = (self._canon is not None
                               and self.commit == "fused")
+        # what the kernel asks to have counted over the states a run
+        # commits (``commit_stats``: VSR's recovering_states and
+        # dvc_set_peak, ISSUE 37), beside them on the device; a kernel
+        # without the hook, or one that returns None for its shape,
+        # gets the program it always had
+        self._stat_fn = (getattr(self.kern, "commit_stats", None)
+                         if self.commit == "fused" else None)
+        # which entries of the stat vector add up (the others: maxima)
+        self._stat_sums = np.array(
+            [how == "sum" for _n, how in self.kern.COMMIT_STATS]
+            if self._stat_fn else [], bool)
         self._inv_stage = trace_once(self._inv, "invariants")
         self._expand_stages = {}    # (action, block rows) -> stage
         self._pack_stages = {}      # block rows -> stage
@@ -679,11 +704,14 @@ class DeviceBFS:
         over its compacted lanes; `parts` is the parent's hash parts
         (``kern.parent_parts``) under the incremental hash and None
         under the full one; `relabelled` is None unless the run
-        canonicalizes."""
+        canonicalizes.  With a kernel that counts over committed
+        states (``commit_stats``) the tuple ends with the successor's
+        stat vector."""
         kern = self.kern
         fp_stage, inv_stage = self._fp_stage, self._inv_stage
         incremental = self._fp_incremental
         canon = self._canon is not None
+        stat_fn = self._stat_fn
 
         def one(st, parts, lane):
             with jax.named_scope(spans.EXPAND):
@@ -706,7 +734,8 @@ class DeviceBFS:
                     fp = fp_stage(clean)
             with jax.named_scope(spans.INVARIANTS):
                 iok = inv_stage(clean)
-            return clean, fp, en, iok, clean["err"], moved
+            out = (clean, fp, en, iok, clean["err"], moved)
+            return out + (stat_fn(clean),) if stat_fn else out
 
         return one
 
@@ -842,7 +871,7 @@ class DeviceBFS:
                         with jax.named_scope(spans.COMPACT):
                             parts_sel = jax.tree_util.tree_map(
                                 lambda v: v[pidx], parts)
-                    succ_f, fp, en2, iok, errv, _moved = jax.vmap(
+                    succ_f, fp, en2, iok, errv, *_ = jax.vmap(
                         self._successor_fn(name, fn))(
                             st_sel, parts_sel, lane_sel)
 
@@ -1022,6 +1051,8 @@ class DeviceBFS:
         Q = -(-total_E // P) * P
         edges_on = self._edges_on
         canon_counts = self._canon_counts
+        stats = self._stat_fn is not None
+        stat_sums = self._stat_sums
         if pk is not None:
             row = jax.eval_shape(pk.unpack, jax.ShapeDtypeStruct(
                 (pk.words,), jnp.uint32))
@@ -1156,6 +1187,8 @@ class DeviceBFS:
                     "lane": lanes(I32)}
                 if canon_counts:
                     queue["moved"] = lanes(bool)
+                if stats:
+                    queue["stat"] = lanes(jnp.uint32, len(stat_sums))
                 q_end = jnp.asarray(0, I32)
                 for aid, name in enumerate(kern.action_names):
                     L_a = kern._lane_count(name)
@@ -1195,7 +1228,7 @@ class DeviceBFS:
                             st_b = {k: v[pidx_b] for k, v in tile.items()}
                             parts_b = jax.tree_util.tree_map(
                                 lambda v: v[pidx_b], parts)
-                        succ, fp, en2, iok, errv, moved = expand(
+                        succ, fp, en2, iok, errv, moved, *stat = expand(
                             st_b, parts_b, lane_b)
                         # a successor is a state row: packed here, a
                         # block at a time, it never exists unpacked at
@@ -1208,6 +1241,8 @@ class DeviceBFS:
                                     "pidx": pidx_b, "lane": lane_b}
                             if canon_counts:
                                 item["moved"] = moved
+                            if stats:
+                                item["stat"] = stat[0]
                             queue = put(queue, item, q_end + lo)
                             return queue, put(seg, (en2, iok, errv), lo)
 
@@ -1296,6 +1331,16 @@ class DeviceBFS:
                     nn = st["nn"]
                     st = dict(st, slots=tbl["slots"], ovf=st["ovf"] | ovf,
                               nn=nn + fresh.sum(dtype=I32))
+                    if stats:
+                        # over the states this piece commits, counted
+                        # where `nn` is: an insert persists across a
+                        # pause, and so does its count
+                        new = jnp.where(fresh[:, None],
+                                        cut(queue["stat"]), 0)
+                        st["cs"] = jnp.where(
+                            stat_sums,
+                            st["cs"] + new.sum(0, dtype=jnp.uint32),
+                            jnp.maximum(st["cs"], new.max(0)))
                     with jax.named_scope(spans.PACK_SCATTER):
                         dest = jnp.where(fresh, nn + jnp.cumsum(fresh) - 1,
                                          N_cap).astype(I32)
@@ -1360,6 +1405,8 @@ class DeviceBFS:
                 st = {k: c[k] for k in ("slots", "nb", "nbp", "nba",
                                         "nbprm", "nn")}
                 st["ovf"] = jnp.asarray(False)
+                if stats:
+                    st["cs"] = c["cs"]
                 if por_active or edges_on:
                     st["gids"] = c["gids"]
                 if edges_on:
@@ -1526,6 +1573,8 @@ class DeviceBFS:
                 init["cpl"] = jnp.asarray(0, jnp.uint32)
             if self._canon_counts:
                 init["cn"] = jnp.zeros((2,), jnp.uint32)
+            if self._stat_fn is not None:
+                init["cs"] = jnp.zeros((len(self._stat_sums),), jnp.uint32)
             if eb is not None:
                 init["gids"] = table["gids"]
                 init["eb_src"], init["eb_aid"], init["eb_dst"] = eb
@@ -1684,6 +1733,23 @@ class DeviceBFS:
         self._tiles_done = 0
         self._lanes_disp = 0
         self._canon_cn = np.zeros(2, np.int64)
+        self._stat_cn = np.zeros(len(self._stat_sums), np.int64)
+
+    def _device_counts(self, out):
+        """The counters only some level programs carry, as the tail of
+        a ticket's pull: the kernel's commit stats, then canon's."""
+        return ([out["cs"]] if self._stat_fn else []) \
+            + ([out["cn"]] if self._canon_counts else [])
+
+    def _fold_device_counts(self, pulled):
+        """Fold that tail (`_device_counts`) into the run's totals."""
+        if self._canon_counts:
+            self._canon_cn += np.asarray(pulled[-1], np.int64)
+        if self._stat_fn:
+            got = np.asarray(pulled[-2 if self._canon_counts else -1],
+                             np.int64)
+            self._stat_cn = np.where(self._stat_sums, self._stat_cn + got,
+                                     np.maximum(self._stat_cn, got))
 
     def _account_blocks(self, blk, pieces):
         """One collected ticket's per-action counts of expand blocks
@@ -2098,9 +2164,7 @@ class DeviceBFS:
                     o["act"], o["need"], o["blk"], o.get("cpl", 0)]
             if self._por_active:
                 vals += [o["gfull"], o["amp"]]
-            if self._canon_counts:
-                vals.append(o["cn"])
-            return jax.device_get(vals)
+            return jax.device_get(vals + self._device_counts(o))
 
         spec = self.spec
         emit = obs.log
@@ -2155,8 +2219,7 @@ class DeviceBFS:
                     self._por_kept += gen_add
                     self._por_full += int(sc[9])
                     self._por_amp += int(sc[10])
-                if self._canon_counts:
-                    self._canon_cn += np.asarray(sc[-1], np.int64)
+                self._fold_device_counts(sc)
 
                 if reason == RUNNING:
                     obs.progress(depth=depth, distinct=fp_count,
@@ -2221,11 +2284,7 @@ class DeviceBFS:
                 elif reason == R_EXPAND_GROW:
                     self._grow_expand(int(out["grow_aid"]), obs, emit)
                 elif reason == R_SLOT_ERR:
-                    raise TLAError(
-                        "dense-layout slot collision (a second DVC or "
-                        "recovery response from one source in one view): "
-                        "this restart-era interleaving needs the "
-                        "multi-slot layout (vsr.py docstring)")
+                    raise TLAError(slot_error(self.codec))
                 elif reason == R_DEADLOCK:
                     di = int(out["dead"])
                     gid = level_base + di
@@ -2430,6 +2489,11 @@ class DeviceBFS:
             lanes_c, moved_c = (int(x) for x in self._canon_cn)
             obs.count("canon_lanes", lanes_c)
             obs.count("canon_relabelled", moved_c)
+        if self._stat_fn:
+            for (name, how), value in zip(self.kern.COMMIT_STATS,
+                                          self._stat_cn):
+                (obs.count if how == "sum" else obs.gauge)(name,
+                                                           int(value))
         if fp_cap:
             obs.gauge("fpset_capacity", int(fp_cap))
             obs.gauge("fpset_occupancy", fp_count / fp_cap)

@@ -62,7 +62,8 @@ from ..resilience.supervisor import Preempted, preempt_signal
 from .bfs import CheckResult
 from .device_bfs import (DeviceBFS, I32, R_BAG_GROW, R_DEADLOCK,
                          R_EDGE_FLUSH, R_EXPAND_GROW, R_FPSET_GROW,
-                         R_NEXT_GROW, R_SLOT_ERR, R_VIOLATION, RUNNING)
+                         R_NEXT_GROW, R_SLOT_ERR, R_VIOLATION, RUNNING,
+                         slot_error)
 from .fpset import grow
 from .spill import EdgeCSR
 
@@ -497,9 +498,7 @@ class PagedBFS(DeviceBFS):
                 keys.append(o["edge_n"])
             if self._por_active:
                 keys += [o["gfull"], o["amp"]]
-            if self._canon_counts:
-                keys.append(o["cn"])
-            return jax.device_get(keys)
+            return jax.device_get(keys + self._device_counts(o))
 
         # the host between two units of device work (a chunk, a level)
         # and before the first: open from here, or from a chunk's end,
@@ -659,8 +658,7 @@ class PagedBFS(DeviceBFS):
                         self._por_kept += gen_add
                         self._por_full += int(sc[9])
                         self._por_amp += int(sc[10])
-                    if self._canon_counts:
-                        self._canon_cn += np.asarray(sc[-1], np.int64)
+                    self._fold_device_counts(sc)
 
                     if reason == RUNNING:
                         obs.progress(depth=depth, distinct=fp_count,
@@ -772,12 +770,7 @@ class PagedBFS(DeviceBFS):
                                 self._total_E() + self.tile:
                             refloor_edges()
                     elif reason == R_SLOT_ERR:
-                        raise TLAError(
-                            "dense-layout slot collision (a second DVC "
-                            "or recovery response from one source in "
-                            "one view): this restart-era interleaving "
-                            "needs the multi-slot layout (vsr.py "
-                            "docstring)")
+                        raise TLAError(slot_error(self.codec))
                     elif reason == R_DEADLOCK:
                         di = int(out["dead"])
                         gid = level_base + chunk_start + di

@@ -16,6 +16,12 @@ Layout derivation (constants -> shapes), with reference citations:
 * ``MAX_VIEW = 1 + StartViewOnTimerLimit``: views are only ever minted by
   TimerSendSVC incrementing by one under ``aux_svc < limit``
   (VSR.tla:578-580); every other view adoption copies an existing view.
+  Restarts add nothing: ``aux_svc`` counts the timer firings of all
+  replicas together and RestartEmpty sends its replica back to view 1,
+  from where a firing mints a view that exists already.  (The lint's
+  range, ``analysis/passes/widths.py``, adds RestartEmptyLimit and is
+  the looser, still sound, of the two; ``plane_bounds`` takes the
+  larger of what it is handed and ``MAX_VIEW``.)
 * Message bag (VSR.tla:228-275): a content-addressed slot table of
   ``MAX_MSGS`` rows.  A row holds the scalar header fields, the Prepare
   payload entry, an optional log payload, and a pending-delivery count.
@@ -32,11 +38,35 @@ Layout derivation (constants -> shapes), with reference citations:
       so DVC slots are keyed [dest, source] and store only the payload;
     - every RecoveryResponse in ``rep_rec_recv[r]`` has x =
       rep_rec_number[r] (guard VSR.tla:873) and dest = r.
-  One slot per (dest, source) is exact while RestartEmptyLimit = 0
-  (a second distinct same-view DVC from one source needs a restarted
-  replica to re-reach an old view); the kernel raises an overflow flag
-  if the bound is ever violated, and the layout refuses restarts > 0
-  with more than one slot budget unavailable.
+  One slot per (dest, source) is exact while RestartEmptyLimit = 0: a
+  replica sends one DVC a view (``rep_sent_dvc``, reset only when its
+  view rises or a StartView makes it Normal, and no Normal replica
+  turns ViewChange in the view it is in), so a second, different
+  same-view DVC from one source needs that source to have restarted
+  and climbed back to the old view.
+* With RestartEmptyLimit > 0 the DoViewChange receive-set is held as
+  the set it is: ``DVC_SLOTS`` = K records a (dest, source), the
+  ``dvc*`` planes ``[R, R, K, ...]``.  Every incarnation of a source
+  sends one DVC a view and ``aux_restart`` counts the restarts of all
+  replicas together, so a pair holds at most 1 + RestartEmptyLimit
+  different records; K is that bound plus one spare (VSR.tla is not in
+  the repository to hold the argument to).  Set semantics: a record
+  that is there already changes nothing, a different one takes a free
+  slot.  Canonical order: the present records of a pair lie first, in
+  ascending (commit_number, last_normal_vn, log, op_number) — the
+  ``value_key`` order of two DVC records that share view, dest and
+  source — and the kernel re-sorts after every insert and after a
+  symmetry relabel (``VSRKernel._permuted``), so equal sets give equal
+  rows, fingerprints and canon keys whatever the arrival order.  A
+  record that finds its pair full raises ``ERR_DVC_OVERFLOW`` and the
+  engines stop (``R_SLOT_ERR``): nothing is ever dropped.
+  ``rep_rec_recv`` stays one slot a (dest, source) at every limit: a
+  nonce is minted once a restart (``UniqueNumber``), its RecoveryMsg
+  reaches each peer once, and a peer answers it once, so a second
+  response from one source to one nonce does not exist
+  (``ERR_REC_OVERFLOW`` still guards it).  A shape with
+  RestartEmptyLimit = 0 has K = 1 and the planes, rows and programs
+  it always had.
 * Client table faithful to VSR.tla:337-339, 379-384; the layout requires
   ``C = 1`` because ReceivePrepareMsg's other-client arm dereferences the
   nonexistent ``m.commit`` field (VSR.tla:421) and would fault in TLC for
@@ -95,6 +125,16 @@ ERR_DVC_OVERFLOW = 2
 ERR_REC_OVERFLOW = 4
 
 
+def entry_sort_key(rows):
+    """value_key order of log-entry records ``[..., NENT]`` (numpy or
+    jax): fields compare alphabetically (client_id, operation,
+    request_number, view_number), packed big-endian into one int32;
+    all-zero padding rows -> 0.  The kernel's deterministic CHOOSE and
+    the canonical order of the DVC receive-set both read it."""
+    return (rows[..., E_CLIENT] * (1 << 20) + rows[..., E_OPER] * (1 << 16)
+            + rows[..., E_REQ] * (1 << 8) + rows[..., E_VIEW])
+
+
 @dataclass(frozen=True)
 class VSRShape:
     """Static shape parameters for one spec x constants binding."""
@@ -111,6 +151,13 @@ class VSRShape:
     def f(self):
         return self.R // 2
 
+    @property
+    def DVC_SLOTS(self):
+        """K: records of ``rep_dvc_recv`` a (dest, source) (module
+        docstring).  A property, not a field: a shape with no restarts
+        is the object it was, to the program store's key too."""
+        return self.restart_limit + 2 if self.restart_limit else 1
+
 
 def shape_from_cfg(constants, max_msgs=None):
     """Derive the dense shapes from a bound .cfg constant map."""
@@ -126,10 +173,10 @@ def shape_from_cfg(constants, max_msgs=None):
     # Field-width bounds of the packed log-entry sort key used for the
     # kernel's deterministic CHOOSE (vsr_kernel._entry_sort_key): client
     # 4 bits, operation 4 bits, request_number 8 bits, view 8 bits.
-    if V >= 16 or 1 + T + restarts >= 256:
+    if V >= 16 or 1 + T >= 256:
         raise TLAError(
             f"config exceeds packed sort-key field widths (V={V} < 16, "
-            f"max view {1 + T + restarts} < 256 required)")
+            f"max view {1 + T} < 256 required)")
     if max_msgs is None:
         # The distinct-message universe is bounded but loose; start
         # small — lane count and state size scale with MAX_MSGS, and the
@@ -174,6 +221,9 @@ class VSRCodec:
     def zero_state(self):
         s = self.shape
         z = lambda *sh: np.zeros(sh, np.int32)
+        # the DVC receive-set: K records a (dest, source); no K axis
+        # where K = 1
+        ks = (s.DVC_SLOTS,) if s.DVC_SLOTS > 1 else ()
         return {
             "status": z(s.R), "view": z(s.R), "op": z(s.R),
             "commit": z(s.R), "lnv": z(s.R),
@@ -181,10 +231,10 @@ class VSRCodec:
             "peer_op": z(s.R, s.R),
             "ct": z(s.R, s.C, 3),
             "svc": z(s.R, s.R),
-            "dvc": z(s.R, s.R), "dvc_lnv": z(s.R, s.R),
-            "dvc_op": z(s.R, s.R), "dvc_commit": z(s.R, s.R),
-            "dvc_log": z(s.R, s.R, s.MAX_OPS, NENT),
-            "dvc_log_len": z(s.R, s.R),
+            "dvc": z(s.R, s.R, *ks), "dvc_lnv": z(s.R, s.R, *ks),
+            "dvc_op": z(s.R, s.R, *ks), "dvc_commit": z(s.R, s.R, *ks),
+            "dvc_log": z(s.R, s.R, *ks, s.MAX_OPS, NENT),
+            "dvc_log_len": z(s.R, s.R, *ks),
             "sent_dvc": z(s.R), "sent_sv": z(s.R),
             "rec_number": z(s.R),
             "rec": z(s.R, s.R), "rec_view": z(s.R, s.R),
@@ -216,9 +266,7 @@ class VSRCodec:
 
     def plane_bounds(self, ranges):
         s = self.shape
-        view = max(self._range_hi(ranges, "view_number",
-                                  s.MAX_VIEW - 1),
-                   s.MAX_VIEW - 1 + s.restart_limit)
+        view = self._range_hi(ranges, "view_number", s.MAX_VIEW)
         ops = self._range_hi(ranges, "op_number", s.MAX_OPS)
         req = self._range_hi(ranges, "request_number", s.V)
         cli = self._range_hi(ranges, "client_id", s.C)
@@ -380,19 +428,32 @@ class VSRCodec:
                 if m.apply("view_number") != d["view"][i] or m.apply("dest") != r:
                     raise TLAError("svc_recv implied-field invariant violated")
                 d["svc"][i][m.apply("source") - 1] = 1
+            by_src = {}
             for m in st["rep_dvc_recv"].apply(r):
                 if m.apply("view_number") != d["view"][i] or m.apply("dest") != r:
                     raise TLAError("dvc_recv implied-field invariant violated")
-                j = m.apply("source") - 1
-                if d["dvc"][i][j]:
-                    raise TLAError("DVC slot collision: restart-era spec "
-                                   "state needs multi-slot layout")
-                d["dvc"][i][j] = 1
-                d["dvc_lnv"][i][j] = m.apply("last_normal_vn")
-                d["dvc_op"][i][j] = m.apply("op_number")
-                d["dvc_commit"][i][j] = m.apply("commit_number")
-                d["dvc_log"][i][j], d["dvc_log_len"][i][j] = \
-                    self._enc_log(m.apply("log"))
+                log, n = self._enc_log(m.apply("log"))
+                by_src.setdefault(m.apply("source") - 1, []).append(
+                    (m.apply("commit_number"), m.apply("last_normal_vn"),
+                     log, m.apply("op_number"), n))
+            for j, recs in by_src.items():
+                if len(recs) > s.DVC_SLOTS:
+                    raise TLAError(
+                        f"rep_dvc_recv holds {len(recs)} DoViewChange "
+                        f"records of one source; the dense layout holds "
+                        f"{s.DVC_SLOTS} (RestartEmptyLimit = "
+                        f"{s.restart_limit})")
+                # the kernel's canonical order (VSRKernel._dvc_sorted)
+                recs.sort(key=lambda t: (
+                    t[0], t[1], entry_sort_key(t[2]).tolist(), t[3]))
+                for k, (commit, lnv, log, op, n) in enumerate(recs):
+                    at = (i, j, k) if s.DVC_SLOTS > 1 else (i, j)
+                    d["dvc"][at] = 1
+                    d["dvc_lnv"][at] = lnv
+                    d["dvc_op"][at] = op
+                    d["dvc_commit"][at] = commit
+                    d["dvc_log"][at] = log
+                    d["dvc_log_len"][at] = n
             d["sent_dvc"][i] = 1 if st["rep_sent_dvc"].apply(r) else 0
             d["sent_sv"][i] = 1 if st["rep_sent_sv"].apply(r) else 0
             d["rec_number"][i] = st["rep_rec_number"].apply(r)
@@ -517,17 +578,23 @@ class VSRCodec:
                        ("dest", r), ("source", r2)])
                 for r2 in reps if d["svc"][r - 1][r2 - 1]))
             for r in reps)
+        K = s.DVC_SLOTS
+        dvc = {k: d[k].reshape((s.R, s.R, K) + d[k].shape[2 + (K > 1):])
+               for k in ("dvc", "dvc_lnv", "dvc_op", "dvc_commit",
+                         "dvc_log", "dvc_log_len")}
         st["rep_dvc_recv"] = FnVal(
             (r, frozenset(
                 FnVal([("type", self.mtype_mv[M_DVC]),
                        ("view_number", int(d["view"][r - 1])),
-                       ("log", self._dec_log(d["dvc_log"][r - 1][j],
-                                             d["dvc_log_len"][r - 1][j])),
-                       ("last_normal_vn", int(d["dvc_lnv"][r - 1][j])),
-                       ("op_number", int(d["dvc_op"][r - 1][j])),
-                       ("commit_number", int(d["dvc_commit"][r - 1][j])),
+                       ("log", self._dec_log(dvc["dvc_log"][r - 1][j][k],
+                                             dvc["dvc_log_len"][r - 1][j][k])),
+                       ("last_normal_vn", int(dvc["dvc_lnv"][r - 1][j][k])),
+                       ("op_number", int(dvc["dvc_op"][r - 1][j][k])),
+                       ("commit_number",
+                        int(dvc["dvc_commit"][r - 1][j][k])),
                        ("dest", r), ("source", j + 1)])
-                for j in range(s.R) if d["dvc"][r - 1][j]))
+                for j in range(s.R) for k in range(K)
+                if dvc["dvc"][r - 1][j][k]))
             for r in reps)
         st["rep_sent_dvc"] = FnVal((r, bool(d["sent_dvc"][r - 1])) for r in reps)
         st["rep_sent_sv"] = FnVal((r, bool(d["sent_sv"][r - 1])) for r in reps)

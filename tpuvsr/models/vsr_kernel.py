@@ -61,13 +61,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .vsr import (E_CLIENT, E_OPER, E_REQ, E_VIEW, ERR_BAG_OVERFLOW,
+from .vsr import (E_OPER, E_REQ, ERR_BAG_OVERFLOW,
                   ERR_DVC_OVERFLOW, ERR_REC_OVERFLOW, H_COMMIT, H_DEST,
                   H_FIRST, H_LNV, H_OP, H_SRC, H_TYPE, H_VIEW, H_X,
                   M_DVC, M_GETSTATE, M_NEWSTATE, M_PREPARE, M_PREPAREOK,
                   M_RECOVERY, M_RECOVERYRESP, M_SV, M_SVC, NENT,
                   NORMAL, RECOVERING, T_EXEC, T_OP, T_REQ, VIEWCHANGE,
-                  VSRCodec)
+                  VSRCodec, entry_sort_key)
 
 I32 = jnp.int32
 INF = np.int32(0x7FFFFFFF)
@@ -91,6 +91,9 @@ MSG_KEYS = ("m_present", "m_count", "m_hdr", "m_entry", "m_log",
             "m_log_len", "m_has_log")
 AUX_KEYS = ("aux_svc", "aux_restart", "aux_acked", "err")
 ALL_KEYS = REP_KEYS + MSG_KEYS + AUX_KEYS
+# the planes of one DoViewChange record of rep_dvc_recv (vsr.py layout)
+DVC_KEYS = ("dvc", "dvc_lnv", "dvc_op", "dvc_commit", "dvc_log",
+            "dvc_log_len")
 
 
 def _lex_less(a, b):
@@ -162,8 +165,35 @@ class VSRKernel:
         self.step_batch = jax.jit(jax.vmap(self.step_all))
         self.fingerprint_batch = jax.jit(jax.vmap(self.fingerprint))
 
+    @property
+    def K(self):
+        """Records of the DVC receive-set a (dest, source); the planes
+        carry a K axis only where K > 1 (vsr.py layout)."""
+        return self.shape.DVC_SLOTS
+
+    @property
+    def commit_stats(self):
+        """What the level program counts over the states it commits
+        (``COMMIT_STATS``), or None: only a shape that can restart
+        carries the counters, so every other program is the one it
+        was."""
+        return self._commit_stats if self.shape.restart_limit else None
+
+    #: name and reduction of each entry of ``commit_stats(st)``
+    COMMIT_STATS = (("recovering_states", "sum"), ("dvc_set_peak", "max"))
+
+    def _commit_stats(self, st):
+        """[2] uint32 of one state: whether a replica is Recovering,
+        and the most DVC records one (dest, source) pair holds."""
+        held = (st["dvc"] == 1).reshape(self.R, self.R, -1).sum(-1)
+        return jnp.stack([(st["status"] == RECOVERING).any(),
+                          held.max()]).astype(jnp.uint32)
+
     def _rep_shape(self, k):
         s = self.shape
+        if k in DVC_KEYS and self.K > 1:
+            tail = (s.MAX_OPS, NENT) if k == "dvc_log" else ()
+            return (s.R, s.R, self.K) + tail
         return {
             "status": (s.R,), "view": (s.R,), "op": (s.R,), "commit": (s.R,),
             "lnv": (s.R,), "log": (s.R, s.MAX_OPS, NENT), "log_len": (s.R,),
@@ -315,14 +345,89 @@ class VSRKernel:
         """value_key order of a log entry record: fields compare
         alphabetically (client_id, operation, request_number, view_number).
         Packed big-endian into one int32; all-zero padding rows -> 0."""
-        return (rows[..., E_CLIENT] * (1 << 20) + rows[..., E_OPER] * (1 << 16)
-                + rows[..., E_REQ] * (1 << 8) + rows[..., E_VIEW])
+        return entry_sort_key(rows)
 
     def _log_sort_key(self, log_rows):
         """[..., MAX_OPS] per-position keys; prefix-padding with 0 makes a
         shorter log order before any extension, matching FnVal item-tuple
         comparison (core/values.py value_key)."""
         return self._entry_sort_key(log_rows)
+
+    # -- the DVC receive-set where K > 1 (vsr.py layout) ----------------
+    def _dvc_sorted(self, g):
+        """DVC planes (a dict over DVC_KEYS, ``[..., K]`` with any
+        leading pair axes) with every pair's K slots in canonical
+        order: the records first, by ascending (commit_number,
+        last_normal_vn, log, op_number) — the value_key order of DVC
+        records that share view, dest and source — then the free
+        slots, which are all-zero and equal.  Two records of one set
+        differ in that key (a log's length is the number of its
+        non-zero entry keys), so the order is a function of the set
+        alone.  Elementwise throughout (no gather, no scatter): the
+        canon stage runs it for every image of every generated
+        state."""
+        K = self.K
+        keys = jnp.concatenate(
+            [g["dvc_commit"][..., None], g["dvc_lnv"][..., None],
+             self._log_sort_key(g["dvc_log"]), g["dvc_op"][..., None]],
+            axis=-1)                                     # [..., K, L]
+        keys = jnp.where((g["dvc"] == 1)[..., None], keys, INF)
+        # slot a against slot b > a, lexicographically
+        less, same = {}, {}
+        for a in range(K):
+            for b in range(a + 1, K):
+                lt = jnp.zeros(keys.shape[:-2], bool)
+                eq = jnp.ones(keys.shape[:-2], bool)
+                for col in range(keys.shape[-1]):
+                    x, y = keys[..., a, col], keys[..., b, col]
+                    lt = lt | (eq & (x < y))
+                    eq = eq & (x == y)
+                less[a, b], same[a, b] = lt, eq
+        # rank of a slot: how many order before it (free slots tie,
+        # and keep their order)
+        rank = []
+        for a in range(K):
+            n = jnp.zeros(keys.shape[:-2], I32)
+            for b in range(K):
+                if b < a:
+                    n = n + (less[b, a] | same[b, a])
+                elif b > a:
+                    n = n + (~less[a, b] & ~same[a, b])
+            rank.append(n)
+        rank = jnp.stack(rank, axis=-1)                  # [..., K]
+        to = rank[..., None, :] == jnp.arange(K, dtype=I32)[:, None]
+
+        def place(v):
+            tail = v.ndim - rank.ndim
+            sel = to.reshape(to.shape + (1,) * tail)     # [..., t, a, 1..]
+            return jnp.where(sel, jnp.expand_dims(v, rank.ndim - 1),
+                             0).sum(rank.ndim)
+        return {k: place(v) for k, v in g.items()}
+
+    def _dvc_insert(self, st, i, j, rec, pred):
+        """``rep_dvc_recv[i] \\union {rec}`` for a record of source j
+        where `pred`: a record that is there already changes nothing,
+        another takes a free slot and the pair is put in order again.
+        Returns (st, overflow): a new record that finds no free slot
+        is not stored, and the caller raises ERR_DVC_OVERFLOW."""
+        g = {k: st[k][i, j] for k in DVC_KEYS}
+        there = ((g["dvc"] == 1)
+                 & (g["dvc_lnv"] == rec["dvc_lnv"])
+                 & (g["dvc_op"] == rec["dvc_op"])
+                 & (g["dvc_commit"] == rec["dvc_commit"])
+                 & (g["dvc_log_len"] == rec["dvc_log_len"])
+                 & (g["dvc_log"] == rec["dvc_log"]).all((-1, -2))).any()
+        free = g["dvc"] == 0
+        new = pred & ~there
+        add = new & free.any()
+        slot = jnp.argmax(free)
+        g = self._dvc_sorted(
+            {k: jnp.where(add, v.at[slot].set(rec[k]), v)
+             for k, v in g.items()})
+        st = dict(st)
+        for k in DVC_KEYS:
+            st[k] = st[k].at[i, j].set(g[k])
+        return st, new & ~free.any()
 
     # ==================================================================
     # the 19 actions.  Each takes (st, lane) and returns (succ, enabled);
@@ -373,19 +478,10 @@ class VSRKernel:
         s2 = self._bag_discard(s2, k)
         return s2, en
 
-    def act_send_dvc(self, st, lane):             # VSR.tla:648-669
-        i = lane
-        r = i + 1
-        view = st["view"][i]
-        prim = self._primary(view, self.R)
-        en = ((st["status"][i] == VIEWCHANGE) & (st["sent_dvc"][i] == 0)
-              & (st["svc"][i].sum() >= self.R // 2))
-        s2 = dict(st)
-        s2["sent_dvc"] = st["sent_dvc"].at[i].set(1)
-        # self-delivery: the new primary registers its own DVC directly;
-        # set-union of an identical record is a no-op, a different one
-        # needs the multi-slot layout (vsr.py docstring)
-        self_case = prim == r
+    def _send_dvc_self_one_slot(self, st, s2, i, self_case):
+        """SendDVC's self-delivery where a pair holds one record:
+        set-union of an identical record is a no-op, a different one
+        does not fit (ERR_DVC_OVERFLOW)."""
         same = ((st["dvc_lnv"][i, i] == st["lnv"][i])
                 & (st["dvc_op"][i, i] == st["op"][i])
                 & (st["dvc_commit"][i, i] == st["commit"][i])
@@ -406,6 +502,28 @@ class VSRKernel:
             self_case, s2["dvc_log_len"].at[i, i].set(st["log_len"][i]),
             s2["dvc_log_len"])
         s2["err"] = s2["err"] | jnp.where(collide, ERR_DVC_OVERFLOW, 0)
+        return s2
+
+    def act_send_dvc(self, st, lane):             # VSR.tla:648-669
+        i = lane
+        r = i + 1
+        view = st["view"][i]
+        prim = self._primary(view, self.R)
+        en = ((st["status"][i] == VIEWCHANGE) & (st["sent_dvc"][i] == 0)
+              & (st["svc"][i].sum() >= self.R // 2))
+        s2 = dict(st)
+        s2["sent_dvc"] = st["sent_dvc"].at[i].set(1)
+        # self-delivery: the new primary registers its own DVC directly
+        self_case = prim == r
+        if self.K > 1:
+            s2, over = self._dvc_insert(s2, i, i, {
+                "dvc": jnp.asarray(1, I32), "dvc_lnv": st["lnv"][i],
+                "dvc_op": st["op"][i], "dvc_commit": st["commit"][i],
+                "dvc_log": st["log"][i], "dvc_log_len": st["log_len"][i]},
+                self_case)
+            s2["err"] = s2["err"] | jnp.where(over, ERR_DVC_OVERFLOW, 0)
+        else:
+            s2 = self._send_dvc_self_one_slot(st, s2, i, self_case)
         row = self._row(M_DVC, view=view, op=st["op"][i],
                         commit=st["commit"][i], dest=prim, src=r,
                         lnv=st["lnv"][i], log=st["log"][i],
@@ -425,12 +543,14 @@ class VSRKernel:
         s2["view"] = st["view"].at[i].set(hdr[H_VIEW])
         s2["status"] = st["status"].at[i].set(VIEWCHANGE)
         s2 = self._clear_vc(s2, i)
-        s2["dvc"] = s2["dvc"].at[i, j].set(1)
-        s2["dvc_lnv"] = s2["dvc_lnv"].at[i, j].set(hdr[H_LNV])
-        s2["dvc_op"] = s2["dvc_op"].at[i, j].set(hdr[H_OP])
-        s2["dvc_commit"] = s2["dvc_commit"].at[i, j].set(hdr[H_COMMIT])
-        s2["dvc_log"] = s2["dvc_log"].at[i, j].set(st["m_log"][k])
-        s2["dvc_log_len"] = s2["dvc_log_len"].at[i, j].set(st["m_log_len"][k])
+        # the first record of an emptied set: slot 0 of its pair
+        at = (i, j, 0) if self.K > 1 else (i, j)
+        s2["dvc"] = s2["dvc"].at[at].set(1)
+        s2["dvc_lnv"] = s2["dvc_lnv"].at[at].set(hdr[H_LNV])
+        s2["dvc_op"] = s2["dvc_op"].at[at].set(hdr[H_OP])
+        s2["dvc_commit"] = s2["dvc_commit"].at[at].set(hdr[H_COMMIT])
+        s2["dvc_log"] = s2["dvc_log"].at[at].set(st["m_log"][k])
+        s2["dvc_log_len"] = s2["dvc_log_len"].at[at].set(st["m_log_len"][k])
         s2 = self._reset_sent(s2, i)
         s2 = self._bag_discard(s2, k)
         s2 = self._broadcast(s2, self._row(M_SVC, view=hdr[H_VIEW], src=r), r)
@@ -444,8 +564,17 @@ class VSRKernel:
         j = jnp.clip(hdr[H_SRC] - 1, 0, self.R - 1)
         en = ((st["m_present"][k] == 1) & (st["m_count"][k] > 0)
               & (hdr[H_TYPE] == M_DVC) & (hdr[H_VIEW] == st["view"][i]))
-        # set-union: identical record already present is a no-op; a
-        # *different* DVC from the same source needs the multi-slot layout
+        if self.K > 1:
+            s2, over = self._dvc_insert(st, i, j, {
+                "dvc": jnp.asarray(1, I32), "dvc_lnv": hdr[H_LNV],
+                "dvc_op": hdr[H_OP], "dvc_commit": hdr[H_COMMIT],
+                "dvc_log": st["m_log"][k],
+                "dvc_log_len": st["m_log_len"][k]}, jnp.asarray(True))
+            s2["err"] = st["err"] | jnp.where(over & en,
+                                              ERR_DVC_OVERFLOW, 0)
+            return self._bag_discard(s2, k), en
+        # set-union: an identical record already present is a no-op; a
+        # *different* DVC from the same source does not fit one slot
         same = ((st["dvc"][i, j] == 1)
                 & (st["dvc_lnv"][i, j] == hdr[H_LNV])
                 & (st["dvc_op"][i, j] == hdr[H_OP])
@@ -464,6 +593,34 @@ class VSRKernel:
         s2 = self._bag_discard(s2, k)
         return s2, en
 
+    def _highest_log_of_set(self, st, i):
+        """SendSV's reading of rep_dvc_recv[r] where a pair holds K
+        records: (HighestLog's log, its length, the highest
+        commit_number) over every record of the set, as the quorum
+        counts every record (the spec's Cardinality)."""
+        n = self.R * self.K
+        d = {k: st[k][i].reshape((n,) + st[k].shape[3:])
+             for k in DVC_KEYS}
+        mask = d["dvc"] == 1
+        pair = d["dvc_lnv"] * (self.MAX_OPS + 1) + d["dvc_op"]
+        maximal = mask & (pair == jnp.max(jnp.where(mask, pair, -1)))
+        # CHOOSE among the maximal: value_key order (commit_number,
+        # log, source); two records of one source that tie there are
+        # one record
+        src_ids = jnp.repeat(jnp.arange(1, self.R + 1, dtype=I32), self.K)
+        keys = jnp.concatenate(
+            [d["dvc_commit"][:, None], self._log_sort_key(d["dvc_log"]),
+             src_ids[:, None]], axis=1)
+        keys = jnp.where(maximal[:, None], keys, INF)
+        best_j = jnp.asarray(0, I32)
+        best_key = keys[0]
+        for j in range(1, n):
+            less = _lex_less(keys[j], best_key)
+            best_key = jnp.where(less, keys[j], best_key)
+            best_j = jnp.where(less, j, best_j)
+        return (d["dvc_log"][best_j], d["dvc_log_len"][best_j],
+                jnp.max(jnp.where(mask, d["dvc_commit"], -1)))
+
     def act_send_sv(self, st, lane):              # VSR.tla:716-758
         i = lane
         r = i + 1
@@ -471,26 +628,30 @@ class VSRKernel:
         mask = st["dvc"][i] == 1
         en = ((st["status"][i] == VIEWCHANGE) & (st["sent_sv"][i] == 0)
               & (mask.sum() >= self.R // 2 + 1))
-        # HighestLog (VSR.tla:716-722): maximal by (last_normal_vn,
-        # op_number); CHOOSE ties broken by value_key record order
-        # (commit, dest=, lnv=, log, op=, source).
-        pair = st["dvc_lnv"][i] * (self.MAX_OPS + 1) + st["dvc_op"][i]
-        best_pair = jnp.max(jnp.where(mask, pair, -1))
-        maximal = mask & (pair == best_pair)
-        logk = self._log_sort_key(st["dvc_log"][i])          # [R, MAX_OPS]
-        src_ids = jnp.arange(1, self.R + 1, dtype=I32)
-        keys = jnp.concatenate(
-            [st["dvc_commit"][i][:, None], logk, src_ids[:, None]], axis=1)
-        keys = jnp.where(maximal[:, None], keys, INF)
-        best_j = jnp.asarray(0, I32)
-        best_key = keys[0]
-        for j in range(1, self.R):
-            less = _lex_less(keys[j], best_key)
-            best_key = jnp.where(less, keys[j], best_key)
-            best_j = jnp.where(less, j, best_j)
-        new_log = st["dvc_log"][i, best_j]
-        new_on = st["dvc_log_len"][i, best_j]   # HighestOpNumber = Len(log)
-        new_cn = jnp.max(jnp.where(mask, st["dvc_commit"][i], -1))
+        if self.K > 1:
+            new_log, new_on, new_cn = self._highest_log_of_set(st, i)
+        else:
+            # HighestLog (VSR.tla:716-722): maximal by (last_normal_vn,
+            # op_number); CHOOSE ties broken by value_key record order
+            # (commit, dest=, lnv=, log, op=, source).
+            pair = st["dvc_lnv"][i] * (self.MAX_OPS + 1) + st["dvc_op"][i]
+            best_pair = jnp.max(jnp.where(mask, pair, -1))
+            maximal = mask & (pair == best_pair)
+            logk = self._log_sort_key(st["dvc_log"][i])      # [R, MAX_OPS]
+            src_ids = jnp.arange(1, self.R + 1, dtype=I32)
+            keys = jnp.concatenate(
+                [st["dvc_commit"][i][:, None], logk, src_ids[:, None]],
+                axis=1)
+            keys = jnp.where(maximal[:, None], keys, INF)
+            best_j = jnp.asarray(0, I32)
+            best_key = keys[0]
+            for j in range(1, self.R):
+                less = _lex_less(keys[j], best_key)
+                best_key = jnp.where(less, keys[j], best_key)
+                best_j = jnp.where(less, j, best_j)
+            new_log = st["dvc_log"][i, best_j]
+            new_on = st["dvc_log_len"][i, best_j]  # HighestOpNumber = Len(log)
+            new_cn = jnp.max(jnp.where(mask, st["dvc_commit"][i], -1))
         s2 = dict(st)
         s2["status"] = st["status"].at[i].set(NORMAL)
         s2["log"] = st["log"].at[i].set(new_log)
@@ -1050,16 +1211,29 @@ class VSRKernel:
         x = x ^ (x >> 16)
         return x
 
-    def _permuted(self, st, perm):
+    def _permuted(self, st, perm, resort=True):
         """Remap value ids through one symmetry permutation ([V+1] table,
         0 -> 0).  Value ids live in the operation column of every log-
-        entry row (rep/dvc/rec logs, message entry and payload logs)."""
+        entry row (rep/dvc/rec logs, message entry and payload logs).
+        Where a pair of the DVC receive-set holds K > 1 records, their
+        order reads the logs: the pairs are put in canonical order
+        again, so the image of a state is the state TLC's permuted
+        value would encode to (`resort` False: the caller knows `perm`
+        is the identity)."""
         st = dict(st)
         for k in ("log", "dvc_log", "rec_log", "m_log"):
             st[k] = st[k].at[..., E_OPER].set(perm[st[k][..., E_OPER]])
         st["m_entry"] = st["m_entry"].at[..., E_OPER].set(
             perm[st["m_entry"][..., E_OPER]])
+        if resort and self.K > 1:
+            st.update(self._dvc_sorted({k: st[k] for k in DVC_KEYS}))
         return st
+
+    @property
+    def _folds(self):
+        """Whether the fingerprint folds a group itself (more than the
+        identity in `perms`); the engines' kernels do not."""
+        return self.perms.shape[0] > 1
 
     def _rep_rows(self, st):
         """[R, n_rep] uint32 content rows, one per replica: the replica
@@ -1094,7 +1268,7 @@ class VSRKernel:
                            + self._seeds[None, :])       # [M, 4]
 
     def _fp_one(self, st, perm):
-        st = self._permuted(st, perm)
+        st = self._permuted(st, perm, resort=self._folds)
         h_rep = self._rep_hashes(st).sum(axis=0)
         pres = jnp.asarray(st["m_present"], jnp.uint32)[:, None]
         h_msg = (self._slot_hashes(st) * pres).sum(axis=0)
@@ -1132,7 +1306,7 @@ class VSRKernel:
         """Per-permutation hash parts of a parent state:
         rep [P, R, 4], slot [P, M, 4], total [P, 4] (pre-mix sums)."""
         def parts_one(perm):
-            stp = self._permuted(st, perm)
+            stp = self._permuted(st, perm, resort=self._folds)
             rep = self._rep_hashes(stp)
             slot = self._slot_hashes(stp)
             pres = jnp.asarray(stp["m_present"], jnp.uint32)[:, None]
@@ -1148,9 +1322,16 @@ class VSRKernel:
     def _rep_row_one(self, st, i, perm):
         """[n_rep] content row of replica i with `perm` applied."""
         cols = [jnp.asarray(i, jnp.uint32)[None]]
+        resorted = {}
+        if self.K > 1 and self._folds:      # as _permuted does
+            resorted = self._dvc_sorted(
+                {k: self._perm_entry_cols(st[k][i], perm)
+                 if k == "dvc_log" else st[k][i] for k in DVC_KEYS})
         for k in REP_KEYS:
             v = st[k][i]
-            if k in ("log", "dvc_log", "rec_log"):
+            if k in resorted:
+                v = resorted[k]
+            elif k in ("log", "dvc_log", "rec_log"):
                 v = self._perm_entry_cols(v, perm)
             cols.append(jnp.asarray(v, jnp.uint32).reshape(-1))
         return jnp.concatenate(cols)

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..engine.device_bfs import grown_caps, static_cap
+from ..engine.device_bfs import grown_caps, slot_error, static_cap
 from ..engine.fpset import dedup_batch, insert_core
 from ..obs import closes_observer, spans
 from ..resilience.faults import InjectedExchangeDrop, fault_point
@@ -1418,9 +1418,7 @@ class ShardedBFS:
                     _attach_exchange(res)
                     return self._finish(res, obs, fp_count)
                 if reason == R_SLOT_ERR:
-                    raise TLAError(
-                        "dense-layout slot collision in sharded BFS "
-                        "(see models/vsr.py docstring)")
+                    raise TLAError(slot_error(self.codec))
                 if reason == R_DEADLOCK:
                     dd = self._pull(out[11])
                     d = int(np.nonzero(dd >= 0)[0][0])
