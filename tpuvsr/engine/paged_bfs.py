@@ -34,6 +34,20 @@ zeroes the counter, and re-enters at the same tile.  Transfers are
 sequential block copies proportional to bytes/state x generated/s
 (CAPACITY.md mitigation 1).
 
+The dispatch window runs over the PAGES of a level (ISSUE 38): while
+page i runs, the host cuts page i+1, puts it on the device and
+launches its dispatch behind page i's, chained on that one's outputs
+(table, next buffers, `nn`), so the pages of a burst append to one
+next buffer in commit order and the buffer goes out when it fills or
+the level ends.  Whether the queued dispatch runs is decided on the
+device (`_page_start`): behind a page that paused it starts past its
+own last tile, runs none, and is dropped; the host handles the pause,
+re-enters page i at the paused tile and launches page i+1 again from
+the array it holds.  Nothing is launched behind a level's last page.
+(Until ISSUE 38 the second ticket of the window went to the page just
+finished: it found no tile to run, but ran the level program's stage
+1 over a whole page first, once a page.)
+
 Everything else — fingerprinting, invariant evaluation, growth of the
 message table / FPSet / per-action expand buffers, violation handling,
 deadlock detection, trace replay — is inherited from DeviceBFS; the
@@ -48,6 +62,8 @@ snapshots cheap).
 from __future__ import annotations
 
 import time
+from collections import deque
+from dataclasses import dataclass
 from functools import partial
 
 import numpy as np
@@ -80,6 +96,32 @@ def _drain_page(bufs, start, rows):
     nb, nbp, nba, nbprm = bufs
     return jax.tree.map(cut, nb), jnp.stack(
         [cut(nbp), cut(nba), cut(nbprm)])
+
+
+@jax.jit
+def _page_start(prev_t, prev_reason, prev_tiles, resume_t, own_tiles):
+    """The tile at which a dispatch queued behind another starts, as a
+    device scalar, so that queuing it costs no host sync (`prev_t`,
+    `prev_reason`: that one's outputs, still on the device;
+    `prev_tiles`: the tiles of its page).  `resume_t` where the
+    dispatch before ran its page to the end; past its own last tile
+    otherwise: behind a pause it then runs no tile, commits nothing,
+    and its own `t` (one past `own_tiles`) says the same to whatever is
+    queued behind IT.  A dispatch with nothing before it gives zeros
+    and `RUNNING`, and starts at `resume_t`."""
+    clean = (prev_t == prev_tiles) & (prev_reason == RUNNING)
+    return jnp.where(clean, resume_t, own_tiles + 1).astype(I32)
+
+
+@dataclass
+class _Page:
+    """A page of the level being expanded: frontier rows [start,
+    start + n), `tiles` tiles of them, and the device array once it
+    has gone in."""
+    start: int
+    n: int
+    tiles: int
+    dev: object = None
 
 
 def _shape_key(tree):
@@ -330,12 +372,14 @@ class PagedBFS(DeviceBFS):
         obs.start(t0, backend=jax.default_backend(),
                   resumed=resume_from is not None)
         emit = obs.log
-        # pipelined dispatch window (ISSUE 4): chained on device-side
-        # (start_t, nn) scalars; host-side spill compaction and
-        # journal/metrics work overlap the in-flight dispatches.  The
-        # window drains at every pause/spill/chunk boundary — dropped
-        # tickets are replays that committed nothing (engine/pipeline.py).
-        # Made as the run starts: its unfed clock counts the set-up
+        # pipelined dispatch window (ISSUE 4) over the PAGES of a level
+        # (ISSUE 38): while a page runs, the next one is cut, put and
+        # launched behind it, chained on device-side (start_t, nn)
+        # scalars; what is queued behind a pause runs no tile
+        # (`_page_start`) and is dropped as a replay that committed
+        # nothing (engine/pipeline.py).  Nothing is launched past a
+        # level's last page.  Made as the run starts: its unfed clock
+        # counts the set-up
         from .pipeline import DispatchPipeline
         pipe = DispatchPipeline(self.pipe_window, obs,
                                 ready=lambda o: o["reason"])
@@ -464,7 +508,6 @@ class PagedBFS(DeviceBFS):
             self.level_sizes = [n0]
 
         last_checkpoint = time.time()
-        dev_chunk = None        # the page on the device
         # the level kernel refuses to commit a tile unless the next
         # buffer has total_E rows of headroom, so total_E + one tile's
         # worth is the functional floor; size it larger (the default
@@ -489,6 +532,16 @@ class PagedBFS(DeviceBFS):
             ebufs = tuple(jnp.zeros((self.edge_cap,), I32)
                           for _ in range(3))
         stop = None
+        # a host scalar as a dispatch's outputs are: int32, committed to
+        # the table's device.  What is chained on a dispatch takes its
+        # outputs where a first launch takes these, and an argument of
+        # another kind is a program more — built inside a window whose
+        # warm-up saw one-page levels only
+        home = next(iter(table["slots"].devices()))
+
+        def scalar(x):
+            return jax.device_put(np.int32(x), home)
+        zero, running = scalar(0), scalar(RUNNING)
 
         def pull(o):
             keys = [o["reason"], o["t"], o["nn"], o["gen"],
@@ -524,29 +577,41 @@ class PagedBFS(DeviceBFS):
                        if self._spill_dir is not None else [])
             d_par, d_act, d_prm = [], [], []
             n_next_total = 0
-            chunk_start = 0
-            n_c = 0
             n_next = 0
+            # whose rows the next buffer holds: (first frontier row of a
+            # page, rows of the buffer up to that page's last collect),
+            # in commit order — the pages of a burst append to one
+            # buffer, and a trace pointer is relative to its own page
+            segs = []
 
             def spill():
                 """Page the first n_next rows of the next buffers out to
-                host RAM and reset the counter.  Reads the chain-tip
-                buffers: identical to the paused dispatch's (replays
-                commit nothing)."""
+                host RAM and reset the counter.  Only with no real page
+                in flight: it reads the chain-tip buffers, which are the
+                last collected dispatch's (what is dropped behind a
+                pause committed nothing)."""
                 nonlocal n_next_total, n_next
                 if n_next == 0:
                     return
                 if self.pipe_window > 1:
-                    # the chain tip may still run (the dispatch past
-                    # the chunk's end): that wait is the device's work
+                    # the chain tip may still run (a dispatch dropped
+                    # behind a pause): that wait is the device's work
                     pipe.wait(bufs[1])
                 with obs.span(spans.PAGE_OUT, depth=depth, rows=n_next):
                     pages = self._page_out(bufs, n_next)
+                # par is page-relative; lift to level-relative now (the
+                # newest collect left n_next, so the last seg ends there)
+                firsts, ends = zip(*segs)
+                lift = np.repeat(np.asarray(firsts, np.int64),
+                                 np.diff((0,) + ends))
+                segs.clear()
                 row_bytes = self._state_row_bytes()
+                at = 0
                 for rows, par, act, prm in pages:
                     drained.append(rows)
-                    # par is chunk-relative; lift to level-relative now
-                    d_par.append(par.astype(np.int64) + chunk_start)
+                    d_par.append(par.astype(np.int64)
+                                 + lift[at:at + len(par)])
+                    at += len(par)
                     d_act.append(act)
                     d_prm.append(prm)
                     obs.spill(depth, len(par), len(par) * row_bytes)
@@ -560,19 +625,18 @@ class PagedBFS(DeviceBFS):
                 against the new total_E headroom requirement, and
                 re-zero it (a stale floor live-locks the commit gate,
                 exactly like the next_cap floor above)."""
-                nonlocal ebufs, pend_en
+                nonlocal ebufs
                 drain_edges()
                 self.edge_cap = max(self.edge_cap,
                                     self._total_E() + self.tile)
                 ebufs = tuple(jnp.zeros((self.edge_cap,), I32)
                               for _ in range(3))
-                pend_en = jnp.asarray(0, I32)
 
             def drain_edges():
                 """Drain the committed edge triples off the device
-                append buffer into the CSR builder (ISSUE 15).  Reads
-                the chain-tip edge buffers — identical to the
-                collected ticket's, since replays commit nothing."""
+                append buffer into the CSR builder (ISSUE 15).  As
+                `spill`: with no real page in flight, off the chain
+                tip."""
                 nonlocal n_edge
                 if not self._edges_on or n_edge == 0:
                     return
@@ -588,218 +652,254 @@ class PagedBFS(DeviceBFS):
                                n_edge * EdgeCSR.ROW_BYTES)
                 n_edge = 0
 
-            def put_chunk():
-                nonlocal dev_chunk
-                block = self._front_block(host_front, chunk_start,
-                                          n_c)
-                with obs.span(spans.PAGE_IN, depth=depth, rows=n_c):
-                    dev_chunk = self._page_in(block, n_c)
-                obs.page_in(depth, n_c, n_c * self._state_row_bytes())
+            def put(page):
+                block = self._front_block(host_front, page.start, page.n)
+                with obs.span(spans.PAGE_IN, depth=depth, rows=page.n):
+                    page.dev = self._page_in(block, page.n)
+                obs.page_in(depth, page.n,
+                            page.n * self._state_row_bytes())
 
-            while chunk_start < n_front and stop is None:
-                n_c = min(self._chunk_cap(), n_front - chunk_start)
-                put_chunk()
-                n_tiles_c = (n_c + self.tile - 1) // self.tile
-                start_t = 0
-                pend_t = jnp.asarray(0, I32)
-                pend_nn = jnp.asarray(n_next, I32)
-                pend_en = jnp.asarray(n_edge, I32)
-                while True:
-                    while pipe.has_room():
-                        nb, nbp, nba, nbprm = bufs
-                        eb_arg, emeta_arg = None, None
-                        if self._edges_on:
-                            # gid_base maps a next-buffer row to its
-                            # global gid (spilled rows precede the
-                            # buffer); src_base lifts a chunk row to
-                            # its frontier gid.  Both are constant
-                            # within a pipelined burst: spills only
-                            # happen behind a drained pause
-                            eb_arg = ebufs
-                            emeta_arg = {
-                                "n": pend_en,
-                                "src_base": jnp.asarray(
-                                    level_base + chunk_start, I32),
-                                "gid_base": jnp.asarray(
-                                    level_base + n_front
-                                    + n_next_total, I32)}
-                        out = pipe.launch(
-                            self._run_level, table, dev_chunk,
-                            jnp.asarray(n_c, I32), pend_t,
-                            nb, nbp, nba, nbprm, pend_nn,
-                            jnp.asarray(bool(check_deadlock)),
-                            eb_arg, emeta_arg,
-                            jnp.asarray(depth - 1, I32),
-                            fresh=self._fresh_jit,
-                            depth=depth)
-                        self._fresh_jit = False
-                        table = {"slots": out["slots"]}
-                        if self._por_active:
-                            table["gids"] = out["gids"]
-                        bufs = (out["nb"], out["nbp"], out["nba"],
-                                out["nbprm"])
-                        pend_t, pend_nn = out["t"], out["nn"]
-                        if self._edges_on:
-                            table["gids"] = out["gids"]
-                            ebufs = (out["eb_src"], out["eb_aid"],
-                                     out["eb_dst"])
-                            pend_en = out["edge_n"]
-                    out, sc = pipe.collect(pull)
-                    reason, start_t, n_next, gen_add, dist_add = (
-                        int(x) for x in sc[:5])
-                    res.states_generated += gen_add
-                    fp_count += dist_add
-                    self._act_counts += np.asarray(sc[5], np.int64)
-                    self._fold_need(sc[6])
-                    self._account_blocks(sc[7], sc[8])
+            cc = self._chunk_cap()
+            next_row = 0        # the first frontier row no page holds yet
+            # pages to launch before any new one is cut, oldest first: a
+            # paused page (it re-enters at `resume_t`) and what was
+            # queued behind it
+            todo = deque()
+            resume_t = 0
+            flying = deque()    # the pages of the window's dispatches
+            # the newest dispatch's output and its page's tiles, while
+            # the next launch chains on it; None where the host knows
+            # the scalars (a level's first page, after a pause)
+            tip = None
+            while True:
+                while stop is None and pipe.has_room() and (
+                        todo or next_row < n_front):
+                    if todo:
+                        page = todo.popleft()
+                    else:
+                        n = min(cc, n_front - next_row)
+                        page = _Page(next_row, n, -(-n // self.tile))
+                        next_row += n
+                    if page.dev is None:
+                        put(page)
+                    if tip is None:
+                        pend_t = _page_start(
+                            zero, running, zero,
+                            scalar(resume_t), scalar(page.tiles))
+                        pend_nn = scalar(n_next)
+                        pend_en = scalar(n_edge)
+                    else:
+                        # behind a dispatch the host has not read: the
+                        # device decides whether this one runs
+                        prev, prev_tiles = tip
+                        pend_t = _page_start(
+                            prev["t"], prev["reason"],
+                            scalar(prev_tiles), zero,
+                            scalar(page.tiles))
+                        pend_nn = prev["nn"]
+                        pend_en = prev.get("edge_n")
+                        if pipe.in_flight:
+                            obs.count("pages_ahead")
+                    nb, nbp, nba, nbprm = bufs
+                    eb_arg, emeta_arg = None, None
                     if self._edges_on:
-                        n_edge = int(sc[9])
+                        # gid_base maps a next-buffer row to its
+                        # global gid (spilled rows precede the
+                        # buffer); src_base lifts a page row to its
+                        # frontier gid.  gid_base is constant within
+                        # a pipelined burst: spills only happen with
+                        # nothing in flight
+                        eb_arg = ebufs
+                        emeta_arg = {
+                            "n": pend_en,
+                            "src_base": jnp.asarray(
+                                level_base + page.start, I32),
+                            "gid_base": jnp.asarray(
+                                level_base + n_front
+                                + n_next_total, I32)}
+                    out = pipe.launch(
+                        self._run_level, table, page.dev,
+                        jnp.asarray(page.n, I32), pend_t,
+                        nb, nbp, nba, nbprm, pend_nn,
+                        jnp.asarray(bool(check_deadlock)),
+                        eb_arg, emeta_arg,
+                        jnp.asarray(depth - 1, I32),
+                        fresh=self._fresh_jit,
+                        depth=depth)
+                    self._fresh_jit = False
+                    table = {"slots": out["slots"]}
                     if self._por_active:
-                        self._por_kept += gen_add
-                        self._por_full += int(sc[9])
-                        self._por_amp += int(sc[10])
-                    self._fold_device_counts(sc)
+                        table["gids"] = out["gids"]
+                    bufs = (out["nb"], out["nbp"], out["nba"],
+                            out["nbprm"])
+                    if self._edges_on:
+                        table["gids"] = out["gids"]
+                        ebufs = (out["eb_src"], out["eb_aid"],
+                                 out["eb_dst"])
+                    resume_t = 0
+                    tip = (out, page.tiles)
+                    flying.append(page)
+                if not flying:
+                    break       # the level's last page, or a stop
+                page = flying.popleft()
+                out, sc = pipe.collect(pull)
+                reason, start_t, n_next, gen_add, dist_add = (
+                    int(x) for x in sc[:5])
+                res.states_generated += gen_add
+                fp_count += dist_add
+                self._act_counts += np.asarray(sc[5], np.int64)
+                self._fold_need(sc[6])
+                self._account_blocks(sc[7], sc[8])
+                if self._edges_on:
+                    n_edge = int(sc[9])
+                if self._por_active:
+                    self._por_kept += gen_add
+                    self._por_full += int(sc[9])
+                    self._por_amp += int(sc[10])
+                self._fold_device_counts(sc)
+                segs.append((page.start, n_next))
 
-                    if reason == RUNNING:
-                        obs.progress(depth=depth, distinct=fp_count,
-                                     generated=res.states_generated,
-                                     frontier=n_front,
-                                     extra="host-paged")
-                        if max_seconds and time.time() - t0 > max_seconds:
-                            stop = f"time budget {max_seconds}s reached"
-                            pipe.drain()
-                            break
-                        if start_t >= n_tiles_c:
-                            pipe.drain()     # no-op tickets past the end
-                            break            # chunk complete
-                        continue
-                    # pause/terminal: in-flight tickets are replays of
-                    # the same paused tile — drop, then handle on the
-                    # chain-tip table/buffers
-                    pipe.drain()
-                    if reason == R_VIOLATION:
-                        vp, va, vprm = (int(v)
-                                        for v in np.asarray(out["viol"]))
-                        gid = level_base + chunk_start + vp
-                        parent_dense = self._host_row(
-                            host_front, chunk_start + vp)
-                        vstate = self._materialize_one(
-                            parent_dense, va, vprm)
-                        bad = spec.check_invariants(
-                            self.codec.decode(vstate))
-                        if bad is None:
-                            raise TLAError(
-                                "device/interpreter divergence: device "
-                                "invariant kernel reported a violation "
-                                "the interpreter accepts (parent gid "
-                                f"{gid}, action "
-                                f"{self.kern.action_names[va]})")
-                        res.ok = False
-                        res.violated_invariant = bad
-                        res.trace = self._trace(gid, extra=(va, vprm))
-                        res.diameter = depth
-                        return self._finish(res, obs, fp_count,
-                                            table=table, fp_cap=fp_cap)
-                    elif reason == R_NEXT_GROW:
-                        # the spill tier: page the filled buffer out to
-                        # host RAM instead of growing it in HBM; the
-                        # refilled window then overlaps the host-side
-                        # compaction below with device compute
-                        self.spill_count += 1
-                        spill()
-                        pend_nn = jnp.asarray(0, I32)
-                    elif reason == R_EDGE_FLUSH:
-                        # edge append buffer full (ISSUE 15): drain the
-                        # committed triples into the CSR builder and
-                        # re-enter — the edge analog of the spill above
-                        drain_edges()
-                        pend_en = jnp.asarray(0, I32)
-                    elif reason == R_BAG_GROW:
-                        old = self.codec.shape.MAX_MSGS
-                        spill()
-                        old_pk = self._pk
-                        self._build(old * 2)
-                        obs.grow("message_table",
-                                 self.codec.shape.MAX_MSGS)
-                        if old_pk is not None:
-                            # packed pages: round-trip through the OLD
-                            # spec to dense, pad, re-pack under the
-                            # rebuilt one (see DeviceBFS._grow_msgs)
-                            def regrow(rows):
-                                d = self.codec.pad_msgs(
-                                    old_pk.unpack_np(rows), old)
-                                return self._pk.pack_np(d)
-                        else:
-                            def regrow(rows):
-                                return self.codec.pad_msgs(rows, old)
-                        if self._spill_dir is not None:
-                            host_front.map_pages(regrow)
-                            drained.map_pages(regrow)
-                        else:
-                            host_front = regrow(host_front)
-                            drained = [regrow(d) for d in drained]
-                        self.level_blocks = [
-                            self.codec.pad_msgs(b, old)
-                            for b in self.level_blocks]
-                        self._pad_init_dense(old)
-                        self._floor_next_cap()
-                        bufs = self._alloc_bufs(self.next_cap)
-                        if self._edges_on:
-                            refloor_edges()
-                        put_chunk()     # same chunk, re-enter at start_t
-                        pend_t = jnp.asarray(start_t, I32)
-                        pend_nn = jnp.asarray(0, I32)
-                        emit(f"message table grown to "
-                             f"{self.codec.shape.MAX_MSGS} slots "
-                             f"(recompiling)")
-                    elif reason == R_FPSET_GROW:
-                        table = grow(table)
-                        fp_cap *= 4
-                        self._fresh_jit = True   # shape change
-                        obs.grow("fpset", fp_cap)
-                        emit(f"FPSet grown to {fp_cap} slots")
-                    elif reason == R_EXPAND_GROW:
-                        self._grow_expand(int(out["grow_aid"]), obs,
-                                          emit)
-                        if self.next_cap < self._total_E() + self.tile:
-                            spill()
-                            self._floor_next_cap()
-                            bufs = self._alloc_bufs(self.next_cap)
-                            pend_nn = jnp.asarray(0, I32)
-                        if self._edges_on and self.edge_cap < \
-                                self._total_E() + self.tile:
-                            refloor_edges()
-                    elif reason == R_SLOT_ERR:
-                        raise TLAError(slot_error(self.codec))
-                    elif reason == R_DEADLOCK:
-                        di = int(out["dead"])
-                        gid = level_base + chunk_start + di
-                        res.ok = False
-                        res.error = "deadlock"
-                        res.deadlock_state = self.codec.decode(
-                            self._host_row(host_front, chunk_start + di))
-                        res.trace = self._trace(gid)
-                        res.diameter = depth
-                        return self._finish(res, obs, fp_count,
-                                            table=table, fp_cap=fp_cap)
-                    # growth pauses fall through here; terminal reasons
-                    # returned above
+                if reason == RUNNING and start_t >= page.tiles:
+                    # the page is done; what is queued behind it is
+                    # the next page, running: on a stop it is
+                    # collected and counted like this one
+                    self._account_tiles(page.tiles)
+                    obs.boundary(depth=depth, chunk=page.start // cc)
                     obs.progress(depth=depth, distinct=fp_count,
                                  generated=res.states_generated,
                                  frontier=n_front, extra="host-paged")
                     if max_seconds and time.time() - t0 > max_seconds:
                         stop = f"time budget {max_seconds}s reached"
-                        break
-                # chunk done (or stopped): spill whatever accumulated,
-                # and drain the chunk's committed edge triples (so the
-                # CSR builder sees whole chunks in commit order and a
-                # level boundary always finds the buffer empty)
-                obs.boundary(depth=depth,
-                             chunk=chunk_start // self._chunk_cap())
-                self._account_tiles(min(start_t, n_tiles_c))
-                spill()
-                drain_edges()
-                chunk_start += n_c
+                    continue
+                # pause/terminal: what is queued behind ran no tile —
+                # drop it, keep its pages for a second launch, and
+                # handle the pause on the chain-tip table/buffers
+                void = pipe.drain()
+                if void:
+                    obs.count("pages_ahead_void", void)
+                todo.extendleft(reversed([page, *flying]))
+                flying.clear()
+                resume_t, tip = start_t, None
+                if reason == R_VIOLATION:
+                    vp, va, vprm = (int(v)
+                                    for v in np.asarray(out["viol"]))
+                    gid = level_base + page.start + vp
+                    parent_dense = self._host_row(
+                        host_front, page.start + vp)
+                    vstate = self._materialize_one(
+                        parent_dense, va, vprm)
+                    bad = spec.check_invariants(
+                        self.codec.decode(vstate))
+                    if bad is None:
+                        raise TLAError(
+                            "device/interpreter divergence: device "
+                            "invariant kernel reported a violation "
+                            "the interpreter accepts (parent gid "
+                            f"{gid}, action "
+                            f"{self.kern.action_names[va]})")
+                    res.ok = False
+                    res.violated_invariant = bad
+                    res.trace = self._trace(gid, extra=(va, vprm))
+                    res.diameter = depth
+                    return self._finish(res, obs, fp_count,
+                                        table=table, fp_cap=fp_cap)
+                elif reason == R_SLOT_ERR:
+                    raise TLAError(slot_error(self.codec))
+                elif reason == R_DEADLOCK:
+                    di = int(out["dead"])
+                    gid = level_base + page.start + di
+                    res.ok = False
+                    res.error = "deadlock"
+                    res.deadlock_state = self.codec.decode(
+                        self._host_row(host_front, page.start + di))
+                    res.trace = self._trace(gid)
+                    res.diameter = depth
+                    return self._finish(res, obs, fp_count,
+                                        table=table, fp_cap=fp_cap)
+                elif stop is not None:
+                    # a page collected behind a stop paused: what it
+                    # committed is counted, and nothing re-enters
+                    break
+                elif reason == R_NEXT_GROW:
+                    # the spill tier: page the filled buffer out to
+                    # host RAM instead of growing it in HBM
+                    self.spill_count += 1
+                    spill()
+                elif reason == R_EDGE_FLUSH:
+                    # edge append buffer full (ISSUE 15): drain the
+                    # committed triples into the CSR builder and
+                    # re-enter — the edge analog of the spill above
+                    drain_edges()
+                elif reason == R_BAG_GROW:
+                    old = self.codec.shape.MAX_MSGS
+                    spill()
+                    old_pk = self._pk
+                    self._build(old * 2)
+                    obs.grow("message_table",
+                             self.codec.shape.MAX_MSGS)
+                    if old_pk is not None:
+                        # packed pages: round-trip through the OLD
+                        # spec to dense, pad, re-pack under the
+                        # rebuilt one (see DeviceBFS._grow_msgs)
+                        def regrow(rows):
+                            d = self.codec.pad_msgs(
+                                old_pk.unpack_np(rows), old)
+                            return self._pk.pack_np(d)
+                    else:
+                        def regrow(rows):
+                            return self.codec.pad_msgs(rows, old)
+                    if self._spill_dir is not None:
+                        host_front.map_pages(regrow)
+                        drained.map_pages(regrow)
+                    else:
+                        host_front = regrow(host_front)
+                        drained = [regrow(d) for d in drained]
+                    self.level_blocks = [
+                        self.codec.pad_msgs(b, old)
+                        for b in self.level_blocks]
+                    self._pad_init_dense(old)
+                    self._floor_next_cap()
+                    bufs = self._alloc_bufs(self.next_cap)
+                    if self._edges_on:
+                        refloor_edges()
+                    for held in todo:   # cut again, at the new width
+                        held.dev = None
+                    emit(f"message table grown to "
+                         f"{self.codec.shape.MAX_MSGS} slots "
+                         f"(recompiling)")
+                elif reason == R_FPSET_GROW:
+                    table = grow(table)
+                    fp_cap *= 4
+                    self._fresh_jit = True   # shape change
+                    obs.grow("fpset", fp_cap)
+                    emit(f"FPSet grown to {fp_cap} slots")
+                elif reason == R_EXPAND_GROW:
+                    self._grow_expand(int(out["grow_aid"]), obs,
+                                      emit)
+                    if self.next_cap < self._total_E() + self.tile:
+                        spill()
+                        self._floor_next_cap()
+                        bufs = self._alloc_bufs(self.next_cap)
+                    if self._edges_on and self.edge_cap < \
+                            self._total_E() + self.tile:
+                        refloor_edges()
+                # a growth pause (or a dispatch that ran out of tiles
+                # to run, which re-enters as it is) falls through here
+                obs.progress(depth=depth, distinct=fp_count,
+                             generated=res.states_generated,
+                             frontier=n_front, extra="host-paged")
+                if max_seconds and time.time() - t0 > max_seconds:
+                    stop = f"time budget {max_seconds}s reached"
+                    break
+            # level done (or stopped): page out what accumulated, and
+            # drain the committed edge triples (a level boundary
+            # always finds both buffers empty)
+            obs.boundary(depth=depth)
+            if todo:
+                # a stop behind a pause: the tiles the page had done
+                self._account_tiles(resume_t)
+            spill()
+            drain_edges()
 
             # ---- level complete: assemble next frontier on host ------
             obs.level_done(depth, frontier=n_front, distinct=fp_count,

@@ -21,11 +21,17 @@ window of K dispatches in flight instead:
   pause protocol: a dispatch chained after a paused one re-attempts
   the same tile, commits nothing, and re-fails identically (committed
   lanes dedup against the FPSet), and a dispatch chained after the
-  level's last tile is an empty while_loop that passes its buffers
-  through untouched.  So the tickets behind a pause, a stop, or a
+  level's last tile runs no tile and hands its buffers on as they
+  came.  It is not free: the level program's stage 1 — unpacking a
+  whole chunk's rows and every guard over them — stands before the
+  tile loop and runs all the same (15-18.5 ms at the defect widths,
+  PERF.md §5).  So the tickets behind a pause, a stop, or a
   level end are replays/no-ops whose host-visible deltas must NOT be
   double-counted — dropping them keeps counts, level sizes and traces
-  bit-identical to the synchronous (K=1) path.
+  bit-identical to the synchronous (K=1) path.  (`PagedBFS` launches
+  nothing past a page's end since ISSUE 38: its window runs over the
+  PAGES of a level, what is queued is the next page, and only what
+  stood behind a pause is dropped — `engine/paged_bfs.py`.)
 
 Window semantics: ``window=1`` reproduces today's behavior exactly,
 including the phase accounting (the dispatch blocks inside the
