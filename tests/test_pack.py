@@ -23,6 +23,8 @@ Plus the ISSUE 9 acceptance anchor: the VSR defect layout
 dense planes (measured: 10.93x).
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -32,6 +34,8 @@ from tpuvsr.engine.pack import PackSpec, build_pack_spec
 from tpuvsr.testing import (STUB_DISTINCT, STUB_LEVELS, counter_spec,
                             stub_device_engine, stub_model_factory,
                             stub_sharded_engine)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 ALL_MODULES = ("VSR", "VR_STATE_TRANSFER", "VR_ASSUME_NEWVIEWCHANGE",
                "VR_INC_RESEND", "VR_APP_STATE", "VR_REPLICA_RECOVERY",
@@ -57,9 +61,21 @@ def _consts():
     return consts
 
 
+# a module's layout at the constants its committed cfg binds, through
+# the native door (load_spec by name) instead of the hand-made dict
+NATIVE_DOOR = {"VR_STATE_TRANSFER:native-door": (
+    "VR_STATE_TRANSFER", "benchmark/configs/vr-state-transfer.cfg")}
+
+
 def _layout_spec(mod, max_msgs=6):
     from tpuvsr.analysis.passes.widths import derive_ranges_from
     from tpuvsr.models import registry
+    if mod in NATIVE_DOOR:
+        from tpuvsr.engine.spec import load_spec
+        name, cfg = NATIVE_DOOR[mod]
+        spec = load_spec(name, os.path.join(REPO, cfg))
+        codec, _kern, _inv = spec.model(max_msgs)
+        return codec, build_pack_spec(codec, spec=spec)
     codec_cls, _ = registry._resolve(mod)
     codec = codec_cls(_consts(), max_msgs=max_msgs)
     pk = build_pack_spec(codec,
@@ -87,7 +103,7 @@ def _random_rows(pk, n, rng):
 # ---------------------------------------------------------------------
 # round-trip property battery: all 8 registered layouts
 # ---------------------------------------------------------------------
-@pytest.mark.parametrize("mod", ALL_MODULES)
+@pytest.mark.parametrize("mod", ALL_MODULES + tuple(NATIVE_DOOR))
 def test_roundtrip_all_layouts(mod):
     codec, pk = _layout_spec(mod)
     assert pk is not None and pk.ratio > 2.0, (mod, pk and pk.ratio)

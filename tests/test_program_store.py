@@ -127,7 +127,21 @@ def _changed_x64(tmp_path, monkeypatch):
     return _small_engine()
 
 
+def _changed_module(tmp_path, monkeypatch):
+    """The second module through the native door, VR_STATE_TRANSFER,
+    at the small check's constants: another kernel class and codec
+    under the same engine."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "vr-state-transfer.cfg")) as f:
+        text = f.read()
+    cfg = tmp_path / "st03_small.cfg"
+    cfg.write_text(text.replace("{v1, v2}", "{v1}").replace(
+        "StartViewOnTimerLimit = 2", "StartViewOnTimerLimit = 1"))
+    return DeviceBFS(load_spec("VR_STATE_TRANSFER", str(cfg)))
+
+
 CHANGES = {f.__name__[len("_changed_"):]: f for f in (
+    _changed_module,
     _changed_constant, _changed_invariant, _changed_tile, _changed_cap,
     _changed_commit_piece, _changed_next_capacity, _changed_hash_mode,
     _changed_commit, _changed_env, _changed_source, _changed_x64)}

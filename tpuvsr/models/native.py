@@ -30,8 +30,12 @@ decoded states.  This module provides exactly that facade:
   interpreter engine.
 
 ``engine.spec.load_spec`` resolves here when its spec argument is not
-an existing file but a module name the registry knows.  Only VSR has a
-committed init trace today; the other seven modules need one each.
+an existing file but a module name the registry knows.  Two modules
+have a committed init trace today: VSR, and VR_STATE_TRANSFER (ST03,
+the base kernel of the analysis family; its trace holds entry 1 alone,
+the state ``ST03Codec.decode`` prints for the zero state in view 1,
+and its action locations are the line ranges the kernel cites).  The
+other six modules are refused by name until each has one.
 """
 
 from __future__ import annotations
@@ -49,8 +53,11 @@ from ..interp.evalr import Evaluator
 from .registry import REPO, _resolve
 
 # module name -> committed TLC trace whose entry 1 is the full Init state
-INIT_TRACES = {"VSR": os.path.join(REPO, "examples",
-                                   "found_violation_trace.txt")}
+INIT_TRACES = {
+    "VSR": os.path.join(REPO, "examples", "found_violation_trace.txt"),
+    "VR_STATE_TRANSFER": os.path.join(
+        REPO, "examples", "VR_STATE_TRANSFER_init_trace.txt"),
+}
 
 # module name -> cfg SYMMETRY definition name -> the constant set the
 # definition permutes (VSR.tla:151: symmValues == Permutations(Values))
@@ -86,7 +93,11 @@ class NativeSpec:
         self._codec_cls, self._kern_cls = _resolve(name)
         with open(INIT_TRACES[name]) as f:
             self._trace_text = f.read()
-        locs = dict(_LOCATION.findall(self._trace_text))
+        # TLC's locations where the trace records them, else the line
+        # range this kernel class itself cites (never a base class's)
+        locs = {a: f"lines {lo}-{hi} of module {name}" for a, (lo, hi)
+                in vars(self._kern_cls).get("ACTION_LINES", {}).items()}
+        locs.update(_LOCATION.findall(self._trace_text))
         self.actions = [
             Action(name=a, expr=None,
                    location=locs.get(a, f"native kernel of module {name}"))
@@ -111,8 +122,9 @@ class NativeSpec:
     # -- checkable interface (engine/spec.SpecModel's) ------------------
     def init_states(self):
         from ..frontend.trace_parse import parse_trace_text
-        first = re.split(r"\],\s*\n\[", self._trace_text.strip(), 1)[0]
-        st = parse_trace_text(first + "]\n>>", self)[0].state
+        first, *rest = re.split(r"\],\s*\n\[", self._trace_text.strip(), 1)
+        st = parse_trace_text(first + "]\n>>" if rest else first,
+                              self)[0].state
         codec, _, _ = self._model_for(st)
         try:
             fits = codec.decode(codec.encode(st)) == st

@@ -71,6 +71,20 @@ def _lex_less(a, b):
 
 class ST03Kernel:
     action_names = ACTION_NAMES
+    # ST03's own line range of each action, as the action functions
+    # cite them: the location a native spec prints for a counterexample
+    # step (read off this class alone, never off a subclass)
+    ACTION_LINES = {
+        "TimerSendSVC": (515, 535), "ReceiveHigherSVC": (537, 556),
+        "ReceiveMatchingSVC": (558, 575), "SendDVC": (577, 614),
+        "ReceiveHigherDVC": (616, 635), "ReceiveMatchingDVC": (637, 654),
+        "SendSV": (699, 731), "ReceiveSV": (733, 762),
+        "ReceiveClientRequest": (293, 325),
+        "ReceivePrepareMsg": (327, 348),
+        "ReceivePrepareOkMsg": (350, 374), "ExecuteOp": (377, 405),
+        "SendGetState": (407, 447), "ReceiveGetState": (449, 477),
+        "ReceiveNewState": (479, 507), "NoProgressChange": (764, 776),
+    }
     REP_KEYS = REP_KEYS          # per-replica hashed planes (class attr
                                  # so subclasses can extend the layout)
     MSG_KEYS = MSG_KEYS
@@ -127,6 +141,23 @@ class ST03Kernel:
 
         self.step_batch = jax.jit(jax.vmap(self.step_all))
         self.fingerprint_batch = jax.jit(jax.vmap(self.fingerprint))
+
+    #: name and reduction of each entry of ``commit_stats(st)``: what
+    #: the level program counts over the states it commits (the hook
+    #: ``DeviceBFS`` / ``PagedBFS`` read, fused commit)
+    COMMIT_STATS = (("state_transfer_states", "sum"), ("bag_slots", "sum"),
+                    ("bag_tombstones", "sum"), ("bag_peak", "max"))
+
+    def commit_stats(self, st):
+        """[4] uint32 of one state: whether a replica is in
+        StateTransfer, the bag's present slots (twice: summed, and the
+        run's peak, what ``max_msgs`` is sized by) and those at count
+        0, the tombstones the quorum guards scan (ST03:595-600, 703)."""
+        present = st["m_present"] == 1
+        slots = present.sum()
+        return jnp.stack([(st["status"] == STATETRANSFER).any(), slots,
+                          (present & (st["m_count"] == 0)).sum(),
+                          slots]).astype(jnp.uint32)
 
     def _nmsg(self):
         # hdr + entry + log + count
