@@ -63,6 +63,10 @@ class Cell:
         files = {c["name"]: c["file"] for c in doc["configs"]}
         with open(os.path.join(ROOT, files[config])) as f:
             self.config = json.load(f)
+        # the depth a traced slice ends at: run.py sets it in a
+        # --trace 1 run from the configuration's `assumed.trace_depth`;
+        # None in every timed run and for a configuration without one
+        self.trace_depth = None
 
     def path(self, rel):
         """A file the configuration names, relative to benchmark/."""

@@ -10,10 +10,14 @@ oracles, outside the window -> one JSON object on the last line.
 
 With --trace 0 the line carries the cell's end-to-end metrics, with
 --trace 1 its per-layer metrics, read from a profiler trace of a
-shorter window (the traffic file's `trace_seconds`) and from the
-program's own counters.  --rehearse drives the same code on whatever
-backend JAX has (the CPU), at the traffic file's `rehearse_config`, and
-prints no metric: a CPU run is never a device number.
+shorter window and from the program's own counters.  The shorter
+window ends at a depth, the configuration's `assumed.trace_depth`, so
+that parent and change of a pair trace the same states however fast
+either is; only a configuration without the key (the rehearsal's
+vsr-small) is cut by seconds, the traffic file's `trace_seconds`.
+--rehearse drives the same code on whatever backend JAX has (the CPU),
+at the traffic file's `rehearse_config`, and prints no metric: a CPU
+run is never a device number.
 """
 
 import time
@@ -40,6 +44,9 @@ OUT = os.path.join(ROOT, ".bench_out")
 # what a traffic kind's window may report for the run's record
 RECORD_KEYS = ("levels", "level_elapsed_s", "distinct", "elapsed_s", "grows",
                "verdict_s")
+# a traced run whose device events span less of its window than this
+# is flagged: its idle share and its time a state are not the cell's
+LOW_COVERAGE = 0.5
 
 
 def log(msg):
@@ -114,8 +121,12 @@ def main(argv=None):
     seconds = args.seconds
     trace_dir = None
     if args.trace:
-        seconds = min(seconds, float(cell.traffic.get("trace_seconds",
-                                                       seconds)))
+        # a traced slice ends at the configuration's depth, under the
+        # whole budget; only one without a depth is cut by seconds
+        cell.trace_depth = cell.config.get("assumed", {}).get("trace_depth")
+        if cell.trace_depth is None:
+            seconds = min(seconds, float(cell.traffic.get("trace_seconds",
+                                                           seconds)))
         trace_dir = os.path.join(cell.out_dir, "trace")
         start_trace(trace_dir)
     opened = time.time()
@@ -161,6 +172,14 @@ def main(argv=None):
         if trace:
             device["busy_s"] = trace["busy_s"]
             device["window_s"] = window_s
+            trace["coverage"] = coverage = trace_reduce.coverage(
+                trace, window_s)
+            if coverage is not None and coverage < LOW_COVERAGE:
+                log(f"WARNING: the device's events span {coverage:.2f} of "
+                    f"the traced window ({trace['span_s']:.2f} of "
+                    f"{window_s:.2f} s): device.idle_share and "
+                    "level.busy_us_per_state of this run are not the "
+                    "cell's")
             line["breakdown"] = {"device_ops": trace["device_ops"],
                                  "idle_gaps": trace["idle_gaps"]}
     if args.rehearse:
@@ -180,13 +199,27 @@ def main(argv=None):
               "setup_s": setup_s, "window_s": window_s,
               "setup_builds": built, "window_builds": obs["window_builds"]}
     record.update((k, obs[k]) for k in RECORD_KEYS if k in obs)
+    if cell.trace_depth is not None:
+        record["trace_depth"] = cell.trace_depth
     if trace:
         record["device_opcodes"] = trace["device_opcodes"]
+        record["coverage"] = trace["coverage"]
     print(json.dumps(record), flush=True)
     with open(os.path.join(cell.out_dir, "run.json"), "w") as f:
         json.dump(dict(record, line=line, comparisons=comparisons), f,
                   indent=1, default=str)
-    print(json.dumps(line), flush=True)
+    # each number compared beside its limit: the last key of the last
+    # line, and the last lines of standard error (what is kept of a run
+    # that is not correct)
+    line["compared"] = {
+        c["name"]: {k: c[k] for k in ("ok", "got", "want", "limit")}
+        for c in comparisons}
+    for name, c in line["compared"].items():
+        print(f"compared {name}: got {json.dumps(c['got'], default=str)} "
+              f"want {json.dumps(c['want'], default=str)} limit "
+              f"{c['limit']} {'ok' if c['ok'] else 'NOT OK'}",
+              file=sys.stderr, flush=True)
+    print(json.dumps(line, default=str), flush=True)
     return 0
 
 

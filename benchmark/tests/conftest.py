@@ -21,9 +21,9 @@ def run_cell(capsys):
     configuration on this backend) and return its last line."""
     import run
 
-    def drive(workload, seconds=4, seed=7):
+    def drive(workload, seconds=4, seed=7, trace=0):
         run.main(["--workload", workload, "--seed", str(seed),
-                  "--seconds", str(seconds), "--trace", "0",
+                  "--seconds", str(seconds), "--trace", str(trace),
                   "--rehearse"])
         out = capsys.readouterr().out.strip().splitlines()
         return json.loads(out[-1]), [json.loads(x) for x in out[:-1]]

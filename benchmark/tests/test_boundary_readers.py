@@ -81,9 +81,13 @@ def test_boundary_readers(name):
 
 
 def test_every_new_metric_has_its_reader_and_its_cells():
-    bfs = ["defect-bfs-timed", "defect-bfs-timed-4chip",
-           "defect-bfs-timed-paged", "shipped-bfs-timed"]
-    entries = {m["name"]: m for m in cells.benchmark_doc()["per_layer"]}
+    doc = cells.benchmark_doc()
+    # every cell that reports distinct_per_s
+    rate, = (m for m in doc["end_to_end"] if m["name"] == "distinct_per_s")
+    bfs = [w["name"] for w in doc["workloads"]
+           if w["name"] in rate["workloads"]]
+    assert len(bfs) >= 6 and "small-verdict" not in bfs
+    entries = {m["name"]: m for m in doc["per_layer"]}
     for name in list(WANT) + list(TWINS):
         m = entries[name]
         assert m["better"] == "lower"
@@ -93,7 +97,8 @@ def test_every_new_metric_has_its_reader_and_its_cells():
             want = (bfs, "distinct_per_s")
         else:
             want = (["small-verdict"], "verdict_s")
-        assert (m["workloads"], m["moves"]) == want, name
+        assert (sorted(m["workloads"]), m["moves"]) == \
+            (sorted(want[0]), want[1]), name
 
 
 def test_level_floor_needs_a_small_level():

@@ -50,7 +50,7 @@ def test_no_lanes_no_share():
 def test_both_are_the_cells_metrics():
     cell = cells.Cell("shipped-bfs-timed")
     names = [m["name"] for m in cell.metrics_for("per_layer")]
-    assert names[-2:] == ["canon.images_per_state", "canon.relabel_share"]
+    assert {"canon.images_per_state", "canon.relabel_share"} <= set(names)
     assert cell.config["name"] == "vsr-shipped"
     assert cell.oracle_levels()[-1] == 838162
     assert sum(cell.oracle_levels()) == 1946857

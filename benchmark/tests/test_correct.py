@@ -9,6 +9,12 @@ def test_sound_run_is_correct(run_cell):
     assert line["correct"] is True and line["failed"] == 0
     assert line["rehearsal"] is True and line["metrics"] == {}
     assert all(r["ok"] for r in rows if "compared" in r)
+    # each number compared beside its limit, last in the last line
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == {r["compared"] for r in rows
+                                     if "compared" in r}
+    assert all(c["ok"] and c["limit"] == 0
+               for c in line["compared"].values())
 
 
 @pytest.mark.parametrize("workload,bits", [("defect-bfs-timed", 16),

@@ -131,3 +131,14 @@ def test_short_names_and_opcodes():
     assert trace_reduce.short_name(tup) == "dus_fusion.3 s32[8960,32,9]"
     assert trace_reduce.opcode(tup) == "fusion kLoop"
     assert trace_reduce.opcode("jit_level") == "other"
+
+
+def test_coverage_is_span_over_window():
+    """What the device's events span of the traced window: the hand-made
+    extract spans 34 ms.  A line like the ledger's PR 34
+    defect-bfs-timed (0.8 of 3.8 s held) reads far under 0.5."""
+    r = trace_reduce.reduce(hand_made())
+    assert abs(trace_reduce.coverage(r, 0.034) - 1.0) < 1e-9
+    assert abs(trace_reduce.coverage(r, 0.17) - 0.2) < 1e-9
+    assert trace_reduce.coverage(None, 3.0) is None
+    assert trace_reduce.coverage(r, 0) is None

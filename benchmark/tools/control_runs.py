@@ -2,7 +2,7 @@
 """Run the control of `correct` at a cell's own size, in one process:
 
     python3 benchmark/tools/control_runs.py --workload defect-bfs-timed \
-        --seeds 1,2,3 --bits 24 [--seconds N]
+        --seeds 1,2,3 --bits 24 [--seconds N] [--trace 0|1]
 
 Every run drives the cell with narrow fingerprints
 (control.narrow_fingerprints) and has to print `correct: false`.  Exit
@@ -32,6 +32,9 @@ def main():
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--bits", type=int, default=24)
     ap.add_argument("--seconds", type=float, default=doc["run_seconds"])
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0,
+                    help="1: the control on the traced slice (whole "
+                         "levels to the configuration's trace_depth)")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     caught = 0
@@ -41,11 +44,12 @@ def main():
         with contextlib.redirect_stdout(buf), \
                 control.narrow_fingerprints(args.bits):
             run.main(["--workload", args.workload, "--seed", seed,
-                      "--seconds", f"{args.seconds:g}", "--trace", "0"]
+                      "--seconds", f"{args.seconds:g}",
+                      "--trace", str(args.trace)]
                      + (["--rehearse"] if args.rehearse else []))
         rows = [json.loads(x) for x in buf.getvalue().strip().splitlines()]
         line = rows[-1]
-        bad = [r for r in rows if "compared" in r and not r["ok"]]
+        bad = [r for r in rows[:-1] if "compared" in r and not r["ok"]]
         caught += line["correct"] is False
         print(json.dumps({"seed": int(seed), "bits": args.bits,
                           "correct": line["correct"],

@@ -28,10 +28,11 @@ import cells  # noqa: E402  (no JAX in it: the runs keep the chip)
 
 
 def spread(values):
-    if len(values) < 2:
+    median = statistics.median(values) if values else 0
+    if len(values) < 2 or not median:       # a count that reads 0
         return None
     q1, _q2, q3 = statistics.quantiles(values, n=4)
-    return (q3 - q1) / statistics.median(values)
+    return (q3 - q1) / median
 
 
 def main():

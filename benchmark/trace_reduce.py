@@ -195,6 +195,17 @@ def idle_share(trace, window_s):
     return 100.0 * (1.0 - trace["busy_s"] / window_s)
 
 
+def coverage(trace, window_s):
+    """What the device planes' events span (first start to last end)
+    of the traced window, 0..1.  Far under 1 the profiler kept only
+    part of the window's device events while `idle_share` divides by
+    all of it (ledger, PR 34, defect-bfs-timed: ~0.8 of 3.8 s held,
+    idle 84.1 % read where the same tree reads 7.05 %)."""
+    if not trace or not window_s:
+        return None
+    return trace["span_s"] / window_s
+
+
 def reduce_directory(directory):
     path = find_xplane(directory)
     return reduce(extract(path)) if path else None

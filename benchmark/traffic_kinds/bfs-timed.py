@@ -5,9 +5,17 @@ set-up built and warmed.
 The traffic file gives `engine` ("device": DeviceBFS on one chip;
 "sharded": ShardedBFS over a 1-D mesh of every device), `engine_flags`
 (constructor arguments beyond the configuration's capacities; empty =
-the engine's defaults), `warmup_depth` and `trace_seconds` (the traced
-run's shorter window).  The configuration gives the cfg, the widths,
-the capacities (`assumed.engine.<engine>`) and the oracles.
+the engine's defaults), `warmup_depth` and `trace_seconds`.  The
+configuration gives the cfg, the widths, the capacities
+(`assumed.engine.<engine>`), the oracles and `assumed.trace_depth`.
+
+A traced run (`cell.trace_depth` set) is the same run under the same
+budget, ended by that depth instead: whole levels 0..trace_depth,
+nothing in flight dropped, the same states on both sides of a pair
+however fast either side is.  `check` then holds it to the oracle's
+first trace_depth + 1 levels and fails it if the budget ended it.
+`trace_seconds` cuts the traced run only of a configuration without
+the key (the rehearsal's vsr-small; run.py).
 """
 
 import os
@@ -32,6 +40,15 @@ def build_engine(cell, spec):
         from tpuvsr.parallel.sharded_bfs import ShardedBFS
         return ShardedBFS(spec, Mesh(np.array(jax.devices()), ("d",)), **kw)
     raise SystemExit(f"bfs-timed: unknown engine {kind!r}")
+
+
+def pinned_levels(cell):
+    """The oracle's level sizes through the depth this run may reach:
+    the last pinned one, or a traced run's trace_depth."""
+    levels = cell.oracle_levels()
+    if cell.trace_depth is not None:
+        levels = levels[:cell.trace_depth + 1]
+    return levels
 
 
 def _observer(cell, tag):
@@ -74,7 +91,7 @@ def setup(cell):
 
 
 def window(cell, state, seconds):
-    oracle_levels = cell.oracle_levels()
+    oracle_levels = pinned_levels(cell)
     res = state["engine"].run(
         max_seconds=seconds, max_depth=len(oracle_levels) - 1,
         obs=_observer(cell, "window"), log=cell.log)
@@ -89,7 +106,7 @@ def window(cell, state, seconds):
 
 def check(cell, state, obs):
     res = obs["result"]
-    oracle_levels = cell.oracle_levels()
+    oracle_levels = pinned_levels(cell)
     timed_out = bool(res.error) and res.error.startswith("time budget")
     # DeviceBFS tests its budget at every chunk collect, so the level
     # it stopped in is partial; ShardedBFS only between levels
@@ -101,14 +118,24 @@ def check(cell, state, obs):
     out.append(oracle.compare("no_violation_before_pinned_depth",
                               [res.ok, res.violated_invariant],
                               [True, None]))
-    # the run used its whole budget, or reached the last pinned depth
-    stop_ok = obs["elapsed_s"] >= obs["seconds"] if timed_out \
-        else len(obs["levels"]) == len(oracle_levels)
-    out.append(oracle.compare(
-        "stopped_by_budget_or_at_last_pinned_depth",
-        [res.error, obs["elapsed_s"], len(obs["levels"]) - 1],
-        [f"time budget after >= {obs['seconds']:g}s", "or depth",
-         len(oracle_levels) - 1], ok=stop_ok))
+    if cell.trace_depth is not None:
+        # a traced slice is whole levels: the depth ended it, never
+        # the budget (which would leave its last level partial)
+        reached = len(obs["levels"]) - 1
+        out.append(oracle.compare(
+            "stopped_at_trace_depth", [res.error, reached],
+            ["not the time budget", cell.trace_depth],
+            ok=not timed_out and reached == cell.trace_depth))
+    else:
+        # the run used its whole budget, or reached the last pinned
+        # depth
+        stop_ok = obs["elapsed_s"] >= obs["seconds"] if timed_out \
+            else len(obs["levels"]) == len(oracle_levels)
+        out.append(oracle.compare(
+            "stopped_by_budget_or_at_last_pinned_depth",
+            [res.error, obs["elapsed_s"], len(obs["levels"]) - 1],
+            [f"time budget after >= {obs['seconds']:g}s", "or depth",
+             len(oracle_levels) - 1], ok=stop_ok))
     out.append(oracle.compare(
         "warmup.levels", state["warmup_levels"],
         oracle_levels[:len(state["warmup_levels"])]))
