@@ -666,3 +666,37 @@ def test_queue_durability_over_quorum_driver(tmp_path):
     assert j2.state == "done" and j2.attempts == 1
     assert j2.result == {"distinct": 7, "ok": True}
     assert q2.spool_status()["driver"] == "quorum"
+
+
+def test_a_finished_jobs_engine_is_freed_when_the_job_ends(
+        tmp_path, capsys, monkeypatch):
+    """The engine of a finished job sits in reference cycles and holds
+    its device buffers and the level program's executable; `run_one`
+    collects at the job's end, so the next job never loads its own
+    beside them (ISSUE 43: a second served job's executable load took
+    a second longer whenever the cyclic collector had not yet run a
+    full pass).  With the collector off, only that call can free it."""
+    import gc
+    import weakref
+
+    from tpuvsr.engine.device_bfs import DeviceBFS
+    from tpuvsr.service.api import main as api_main
+    born = []
+    init = DeviceBFS.__init__
+
+    def spy(self, *args, **kw):
+        born.append(weakref.ref(self))
+        init(self, *args, **kw)
+    monkeypatch.setattr(DeviceBFS, "__init__", spy)
+    spool = str(tmp_path / "spool")
+    api_main(["submit", "--stub", "--spool", spool])
+    gc.collect()
+    gc.disable()
+    try:
+        assert api_main(["serve", "--drain", "--devices", "1",
+                         "--spool", spool, "--quiet"]) == 0
+        alive = [r for r in born if r() is not None]
+    finally:
+        gc.enable()
+    capsys.readouterr()
+    assert born and not alive

@@ -257,6 +257,66 @@ def slot_error(codec):
             f"stops here and drops nothing (models/vsr.py, layout)")
 
 
+def _cut(v, start, rows):
+    return jax.lax.dynamic_slice_in_dim(v, start, rows, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _cut_rows(buf, start, rows):
+    """Rows [start, start + rows) of every plane of a buffer.  `start`
+    is a traced scalar, so every page of one buffer shape is this one
+    program, whatever a level holds."""
+    return jax.tree.map(lambda v: _cut(v, start, rows), buf)
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _cut_pointers(planes, start, rows):
+    """The same rows of the three trace-pointer planes as one
+    [3, rows] array: one copy to the host a page."""
+    return jnp.stack([_cut(v, start, rows) for v in planes])
+
+
+class RowPages:
+    """The first `n` rows of a device buffer on their way to the host
+    in pages of `rows` rows (the whole buffer where it is shorter).
+
+    No program here depends on `n`: a slice of `n` rows is a new
+    program for every new `n`, and a level's end compiled one with the
+    chip idle (ISSUE 43).  `cut` (`_cut_rows`, or `_cut_pointers`,
+    whose rows lie along `axis` 1) is launched once a page and each
+    page's copy to the host started; `host()` joins them and cuts the
+    tail.  A last page that would pass the buffer's end starts early
+    enough to end there instead (the device would clamp it the same
+    way, silently), and gives up its head on the host."""
+
+    def __init__(self, cut, buf, n, rows, axis=0):
+        cap = jax.tree.leaves(buf)[0].shape[0]
+        self.n, self.axis = n, axis
+        self.rows = rows = min(rows, cap)
+        self.starts = [min(at, cap - rows)
+                       for at in range(0, max(n, 1), rows)]
+        self.pages = [cut(buf, np.int32(s), rows=rows)
+                      for s in self.starts]
+        for leaf in jax.tree.leaves(self.pages):
+            leaf.copy_to_host_async()
+
+    @property
+    def nbytes(self):
+        return sum(v.nbytes for v in jax.tree.leaves(self.pages))
+
+    def host(self):
+        """The rows as host arrays of their own; waits for the
+        copies."""
+        head = (slice(None),) * self.axis
+        parts = []
+        for i, page in enumerate(jax.device_get(self.pages)):
+            at, start = i * self.rows, self.starts[i]
+            keep = slice(at - start, min(at + self.rows, self.n) - start)
+            parts.append(jax.tree.map(lambda v: v[head + (keep,)], page))
+        return jax.tree.map(
+            lambda *vs: np.concatenate(vs, axis=self.axis), *parts)
+
+
 # Largest tile width validated against the pinned fixpoint counts on
 # a real TPU: tile=1024 once mis-explored the flagship config
 # (58,957 distinct vs pinned 43,941), an unresolved TPU-lowering
@@ -1803,9 +1863,18 @@ class DeviceBFS:
         holds them (the loader unpacks them by the manifest's pack
         spec, so any engine/pack configuration still resumes dense
         planes), or the dense planes of a run that does not pack."""
-        if self._pk is not None:
-            return {"frontier_packed": np.asarray(buf[:n])}
-        return {"frontier": {k: np.asarray(v[:n]) for k, v in buf.items()}}
+        if all(isinstance(v, np.ndarray) for v in jax.tree.leaves(buf)):
+            # the paged engine's frontier: the host cuts what it holds
+            rows = jax.tree.map(lambda v: v[:n], buf)
+        else:
+            rows = self._pull(_cut_rows, buf, n).host()
+        return {"frontier_packed" if self._pk is not None
+                else "frontier": rows}
+
+    def _pull(self, cut, buf, n, axis=0):
+        """The first `n` rows of a device buffer, leaving for the host
+        in pages of a chunk's rows: one program a buffer shape."""
+        return RowPages(cut, buf, n, self.chunk_tiles * self.tile, axis)
 
     def _pack_manifest(self):
         return self._pk.manifest() if self._pk is not None else None
@@ -2311,17 +2380,18 @@ class DeviceBFS:
             self._account_tiles(min(start_t, n_tiles))
             nb, nbp, nba, nbprm = bufs
             if n_next:
-                # async pointer fetch: the copies overlap the next
-                # level's compute and are only materialized on demand
-                # (_flush_pointers) — a blocking device_get here costs
-                # a full device round-trip per level
-                par, act, prm = nbp[:n_next], nba[:n_next], nbprm[:n_next]
-                for a in (par, act, prm):
-                    a.copy_to_host_async()
-                    obs.count("boundary_pull_bytes", a.nbytes)
-                self._h_parent.append((par, level_base))
-                self._h_action.append(act)
-                self._h_param.append(prm)
+                # async pointer fetch, in pages of one shape: the
+                # copies overlap the next level's compute and are only
+                # materialized on demand (_flush_pointers) — a blocking
+                # device_get here costs a full device round-trip per
+                # level, a slice of n_next rows a compile per level
+                pages = self._pull(_cut_pointers, (nbp, nba, nbprm),
+                                   n_next, axis=1)
+                obs.count("boundary_pull_pages", len(pages.pages))
+                obs.count("boundary_pull_bytes", pages.nbytes)
+                self._h_parent.append((pages, level_base))
+                self._h_action.append(None)
+                self._h_param.append(None)
                 self.level_sizes.append(n_next)
             level_base += n_front
             # the old frontier set becomes the next scratch buffer set
@@ -2427,15 +2497,18 @@ class DeviceBFS:
     # ------------------------------------------------------------------
     def _flush_pointers(self):
         """Materialize any still-on-device trace-pointer levels (the
-        per-level fetches are issued async)."""
+        per-level fetches are issued async): until then a level's
+        entry of `_h_parent` holds its pages and the gid of its
+        frontier's first row, and its two neighbours nothing."""
         for i, v in enumerate(self._h_parent):
             if isinstance(v, tuple):
-                arr, off = v
-                self._h_parent[i] = np.asarray(arr).astype(np.int64) + off
-        for lst in (self._h_action, self._h_param):
-            for i, v in enumerate(lst):
-                if not isinstance(v, np.ndarray):
-                    lst[i] = np.asarray(v, np.int32)
+                pages, off = v
+                par, act, prm = pages.host()
+                self._h_parent[i] = par.astype(np.int64) + off
+                # copies: a row of the joined pages would keep all
+                # three alive
+                self._h_action[i] = act.copy()
+                self._h_param[i] = prm.copy()
 
     def _fetch_row(self, batch, i):
         """One dense state row from a frontier-format buffer (packed

@@ -40,6 +40,7 @@ table — one queue implementation.
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import signal
 import subprocess
@@ -464,6 +465,14 @@ class Worker:
             self._current = None
             self._specs.pop(job.job_id, None)
             self._spans.pop(job.job_id, None)
+            # the finished job's engine is garbage in reference cycles,
+            # and holds its device buffers and the level program's
+            # executable: free them here, not whenever the cyclic
+            # collector next runs a full pass.  While they live, the
+            # next job's executable loads a second slower, so what a
+            # job cost depended on how many objects its predecessor
+            # had allocated (PERF.md §6, PR 43)
+            gc.collect()
 
     def run_one_light(self, job):
         """Run one LIGHT job (shell / interp validate / lint-only) —
