@@ -99,6 +99,8 @@ def test_second_run_of_the_same_engine(built):
     assert again.distinct_states == built.first.distinct_states
     assert again.states_generated == built.first.states_generated
     assert again.metrics["counters"]["init_packed_rows"] == 1
+    # every program it needs is the engine's, built by the first run
+    assert again.metrics["counters"]["build_programs"] == 0
 
 
 def test_resume_from_a_mid_run_snapshot(built, tmp_path):

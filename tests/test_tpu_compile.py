@@ -156,21 +156,28 @@ def test_sharded_step(spec, topo, no_cache):
     assert "all-to-all" in compiled.as_text()
 
 
-def test_sharded_start_programs(spec, topo, no_cache):
-    """The two programs of a sharded run's start (ISSUE 27) at the
-    four-chip configuration's per-shard capacities: the packed first
-    frontier padded on each shard, and the Init insert."""
+@pytest.fixture(scope="module")
+def four_chip_engine(spec, topo):
+    """ShardedBFS at the four-chip configuration's capacities over a
+    mesh of the described chips (nothing is compiled to make it)."""
     import json
 
     from tpuvsr.parallel.sharded_bfs import ShardedBFS
     with open(os.path.join(REPO, "benchmark", "configs",
                            "vsr-defect-4chip.json")) as f:
         caps = json.load(f)["assumed"]["engine"]["sharded"]
-    mesh = Mesh(np.array(topo.devices), ("d",))
-    eng = ShardedBFS(spec, mesh, **caps)
+    eng = ShardedBFS(spec, Mesh(np.array(topo.devices), ("d",)), **caps)
+    assert (eng.N, eng.fp_cap) == (1 << 18, 1 << 21)
+    return eng
+
+
+def test_sharded_start_programs(four_chip_engine, no_cache):
+    """The two programs of a sharded run's start (ISSUE 27) at the
+    four-chip configuration's per-shard capacities: the packed first
+    frontier padded on each shard, and the Init insert."""
+    eng = four_chip_engine
     D, N, words = eng.D, eng.N, eng._pk.words
-    assert (N, eng.fp_cap) == (1 << 18, 1 << 21)
-    sh, rep = NamedSharding(mesh, P("d")), NamedSharding(mesh, P())
+    sh, rep = eng._sh, eng._rep_sh
     u32 = jnp.uint32
     _compile(eng._fill_packed.lower(
         jax.ShapeDtypeStruct((words,), u32, sharding=rep),
@@ -183,6 +190,37 @@ def test_sharded_start_programs(spec, topo, no_cache):
         jax.ShapeDtypeStruct((1,), jnp.bool_, sharding=rep)),
         f"sharded Init insert D={D} slots={eng.fp_cap}")
 
+
+@pytest.mark.parametrize("what", ["next buffer", "pointer plane",
+                                  "table shards"])
+def test_sharded_zero_fill(four_chip_engine, no_cache, what):
+    """The zero arrays of a sharded level's start and of `init`
+    (ISSUE 44) at the same capacities: each chip fills its own
+    quarter, and the program takes nothing from the host."""
+    eng = four_chip_engine
+    D, N = eng.D, eng.N
+    shape, dtype = {"next buffer": ((D * N, eng._pk.words), np.uint32),
+                    "pointer plane": ((D * N,), np.int32),
+                    "table shards": ((D, eng.fp_cap, 5), np.uint32)}[what]
+    compiled = _compile(eng._zero_fill.lower(shape, np.dtype(dtype)),
+                        f"sharded zero fill {what} {shape}")
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes == 0
+    # a quarter of the array, as the chip tiles it (119 words lie in 120)
+    quarter = int(np.prod(shape)) * 4 // D
+    assert quarter <= ma.output_size_in_bytes < 1.02 * quarter
+
+
+def test_sharded_control_scalars(four_chip_engine, no_cache):
+    """The one pull of a dispatch's control scalars (`pack_scalars`,
+    the engine's since ISSUE 44)."""
+    eng = four_chip_engine
+    D, A = eng.D, len(eng.kern.action_names)
+    vec = jax.ShapeDtypeStruct((D,), jnp.int32, sharding=eng._sh)
+    _compile(eng._pack_scalars.lower(
+        vec, vec, vec, vec, vec,
+        jax.ShapeDtypeStruct((D, A), jnp.uint32, sharding=eng._sh)),
+        f"sharded control scalars D={D} A={A}")
 
 
 @pytest.mark.parametrize("what", ["insert", "stats", "page-out"])

@@ -368,10 +368,12 @@ def test_sharded_boundary_bytes_are_the_buffers_it_moves():
     c = res.metrics["counters"]
     levels = len(res.metrics["levels"])
     D, N, words = eng.D, eng.N, eng._pk.words
-    # a level starts with the zero next buffer, three pointer planes
-    # and three control vectors put from the host ...
-    assert c["boundary_put_bytes"] == levels * (
-        D * N * words * 4 + 3 * D * N * 4 + 3 * D * 4)
+    # a level starts with three control vectors put from the host ...
+    assert c["boundary_put_bytes"] == levels * 3 * D * 4
+    # ... and the zero next buffer and three pointer planes filled on
+    # the device, as the FPSet shards were at the run's start
+    assert c["boundary_fill_bytes"] == levels * (
+        D * N * words * 4 + 3 * D * N * 4) + D * eng.fp_cap * 5 * 4
     # ... and ends, while it found states, with the three pointer
     # planes pulled whole
     assert c["boundary_pull_bytes"] == (levels - 1) * 3 * D * N * 4

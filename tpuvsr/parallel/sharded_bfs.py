@@ -72,15 +72,6 @@ def route(fps):
     return (fps[..., 1] * jnp.uint32(0x9E3779B9)) ^ (fps[..., 3] >> 7)
 
 
-def make_sharded_tables(mesh, axis, capacity_per_device):
-    """Global FPSet: one independent shard per device, stacked on the
-    leading (sharded) axis."""
-    n = mesh.shape[axis]
-    sh = NamedSharding(mesh, P(axis))
-    return {"slots": put_sharded(
-        np.zeros((n, capacity_per_device, 5), np.uint32), sh)}
-
-
 # ======================================================================
 # Elastic resharding (ISSUE 5): host-side re-hash-partitioning of a
 # snapshot's FPSet shards + frontier onto a different mesh size
@@ -871,6 +862,16 @@ class ShardedBFS:
         self._fill_packed = make_packed_fill(self.mesh, self.axis)
         self._sh = NamedSharding(self.mesh, P(self.axis))
         self._rep_sh = NamedSharding(self.mesh, P())
+        # ... and the two a level needs.  fill(shape, dtype): a zero
+        # global array sharded over its rows, one program a shape and
+        # dtype; each device fills its own piece, nothing comes from
+        # the host.  And the one pull of a dispatch's control
+        # scalars.  No jax.jit is created inside a run(): a new
+        # function object misses JAX's in-process cache and compiles
+        # with the chips idle
+        self._zero_fill = jax.jit(jnp.zeros, static_argnums=(0, 1),
+                                  out_shardings=self._sh)
+        self._pack_scalars = jax.jit(pack_scalars)
         # multi-process: host pulls of globally-sharded arrays must
         # reshard to replicated first (parallel/multihost.py)
         self._pull = make_replicator(self.mesh)
@@ -913,21 +914,28 @@ class ShardedBFS:
         global array (a P() input of the sharded kernels)."""
         return put_sharded(arr, self._rep_sh)
 
+    def _zeros(self, shape, dtype, obs):
+        """A zero-filled global array, row-sharded like every buffer
+        of the step, filled on the device (every process of a
+        multi-process mesh calls the same program; the host makes and
+        moves nothing).  Its bytes count as ``boundary_fill_bytes``."""
+        arr = self._zero_fill(tuple(shape), np.dtype(dtype))
+        obs.count("boundary_fill_bytes", arr.nbytes)
+        return arr
+
     def _alloc_frontier(self, cap, obs):
-        """A level's next buffers: zeros made on the host and put."""
+        """A level's next buffers: zeros, made on the device."""
         D = self.D
         if self._pk is not None:
             # packed at-rest frontier (ISSUE 9): [D*cap, words] uint32
             # planes — the exchange and the next frontier move packed
             # rows, so this buffer IS the interchange format
-            nb = self._put(np.zeros((D * cap, self._pk.words),
-                                    np.uint32), obs)
+            nb = self._zeros((D * cap, self._pk.words), np.uint32, obs)
         else:
             zero = self.codec.zero_state()
-            nb = {k: self._put(np.zeros((D * cap,) + np.shape(v),
-                                        np.int32), obs)
+            nb = {k: self._zeros((D * cap,) + np.shape(v), np.int32, obs)
                   for k, v in zero.items()}
-        z = lambda: self._put(np.zeros((D * cap,), np.int32), obs)
+        z = lambda: self._zeros((D * cap,), np.int32, obs)
         return nb, z(), z(), z()
 
     def _start_frontier(self, rows, counts0, obs):
@@ -1196,8 +1204,10 @@ class ShardedBFS:
                  f"{fp_count} distinct, frontier {int(counts0.sum())}")
         else:
             with obs.span(spans.INIT):
-                tables = make_sharded_tables(self.mesh, self.axis,
-                                             self.fp_cap)
+                # global FPSet: one independent shard per device,
+                # stacked on the leading (sharded) axis
+                tables = {"slots": self._zeros((D, self.fp_cap, 5),
+                                               np.uint32, obs)}
 
                 # --- init states: dedup, assign to owner devices ----------
                 init_states = list(spec.init_states())
@@ -1289,10 +1299,6 @@ class ShardedBFS:
                 return bool(flag)
 
             agree_any = bool
-        pack_scalars = jax.jit(
-            lambda r, s, g, gf, am, a: jnp.concatenate(
-                [r[:, None], s[:, None], g[:, None], gf[:, None],
-                 am[:, None], a.astype(jnp.int32)], axis=1))
 
         def pull(o):
             # ONE replication pull for all per-dispatch control
@@ -1301,8 +1307,8 @@ class ShardedBFS:
             # gen/gfull/amp and the [D, A] act counters into a single
             # [D, 5+A] array first
             packed = np.asarray(self._pull(
-                pack_scalars(o[7], o[10], o[9], o[14], o[15],
-                             o[12])), np.int64)
+                self._pack_scalars(o[7], o[10], o[9], o[14], o[15],
+                                   o[12])), np.int64)
             reason = int(packed[0, 0])
             sent = int(packed[:, 1].sum())
             gen = int(packed[:, 2].sum())
@@ -1745,6 +1751,14 @@ class ShardedBFS:
                 for n, c in zip(self.kern.action_names,
                                 self.expand_caps))
         return self.tile * self.kern.n_lanes
+
+
+def pack_scalars(reason, sent, gen, gfull, amp, act):
+    """A dispatch's per-shard control scalars, [D] each, and its
+    [D, A] action counters as one [D, 5+A] int32 array."""
+    return jnp.concatenate(
+        [reason[:, None], sent[:, None], gen[:, None], gfull[:, None],
+         amp[:, None], act.astype(jnp.int32)], axis=1)
 
 
 def make_packed_fill(mesh: Mesh, axis: str):
