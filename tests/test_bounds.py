@@ -299,19 +299,26 @@ def test_bounds_gauges():
 # ---------------------------------------------------------------------
 # checkpoint seams
 # ---------------------------------------------------------------------
-def test_checkpoint_records_digest_and_refuses_flip(tmp_path):
+@pytest.mark.parametrize("engine", ["device", "paged", "sharded"])
+def test_checkpoint_records_digest_and_refuses_flip(tmp_path, engine):
+    # every engine refuses a snapshot it wrote itself, letter for letter
     import json
+    from tpuvsr.testing import stub_bfs_engine
     ck = str(tmp_path / "ck")
-    e = stub_device_engine()
+    e = stub_bfs_engine(engine)
     e.run(checkpoint_path=ck, max_depth=4)
     with open(os.path.join(ck, "manifest.json")) as f:
         mf = json.load(f)
     assert mf["bounds"]["digest"] == e._facts.digest
     assert mf["bounds"]["tightened"] is True
-    with pytest.raises(TLAError, match="bounds"):
-        stub_device_engine(bounds=False).run(resume_from=ck)
+    with pytest.raises(TLAError, match=(
+            r"was written under bounds facts \S+ but this engine "
+            r"consumes off; the tightened packing and pruned action "
+            r"ids are not comparable — resume with the matching "
+            r"-bounds setting \(and the same cfg constants\)$")):
+        stub_bfs_engine(engine, bounds=False).run(resume_from=ck)
     # matched resume completes the exact fixpoint
-    r = stub_device_engine().run(resume_from=ck)
+    r = stub_bfs_engine(engine).run(resume_from=ck)
     assert r.distinct_states == STUB_DISTINCT
     assert r.levels == STUB_LEVELS
 

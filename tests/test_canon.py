@@ -232,13 +232,22 @@ def test_verdict_identity_other_engines_and_commit_modes():
 # ---------------------------------------------------------------------
 # checkpoint/resume policy (ISSUE 11 satellite)
 # ---------------------------------------------------------------------
-def test_resume_with_flipped_symmetry_is_policy_error(tmp_path):
+@pytest.mark.parametrize("engine", ["device", "paged", "sharded"])
+def test_resume_with_flipped_symmetry_is_policy_error(tmp_path, engine):
+    # every engine refuses a snapshot it wrote itself, letter for letter
+    from tpuvsr.testing import stub_bfs_engine
     ck = str(tmp_path / "ck")
-    r = stub_sym_engine().run(max_depth=1, checkpoint_path=ck)
+    r = stub_bfs_engine(engine, sym=True).run(max_depth=1,
+                                              checkpoint_path=ck)
     assert r.distinct_states == 3       # init orbit + level-1 orbits
-    with pytest.raises(TLAError, match="symmetry canonicalization"):
-        stub_sym_engine(symmetry=False).run(resume_from=ck)
-    r2 = stub_sym_engine().run(resume_from=ck)
+    with pytest.raises(TLAError, match=(
+            r"was written with symmetry canonicalization \S+ but this "
+            r"engine runs off; the stored fingerprints are not "
+            r"comparable — resume with the matching -symmetry "
+            r"setting/group$")):
+        stub_bfs_engine(engine, sym=True, symmetry=False).run(
+            resume_from=ck)
+    r2 = stub_bfs_engine(engine, sym=True).run(resume_from=ck)
     assert r2.ok and r2.distinct_states == SYMPAIR_ORBITS
 
 

@@ -289,27 +289,30 @@ def test_packed_checkpoint_resume_bit_identical(tmp_path):
         assert res.levels == oracle.levels
 
 
-def test_pack_version_mismatch_is_policy_error(tmp_path):
+@pytest.mark.parametrize("engine", ["device", "paged", "sharded"])
+def test_pack_version_mismatch_is_policy_error(tmp_path, engine):
     """Resume under a MISMATCHED widths table (different bit budgets
     -> different spec version) is a loud TLAError, not a silent
-    re-encode.  Run with bounds=False on both sides: the ISSUE 13
-    reachable-interval tightening would otherwise intersect BOTH
-    tables down to the same (identical, compatible) reachable budgets
-    — this test pins the DECLARED-widths policy seam."""
-    from tpuvsr.engine.device_bfs import DeviceBFS
+    re-encode, on every engine and letter for letter.  Run with
+    bounds=False on both sides: the ISSUE 13 reachable-interval
+    tightening would otherwise intersect BOTH tables down to the same
+    (identical, compatible) reachable budgets — this test pins the
+    DECLARED-widths policy seam."""
+    from tpuvsr.testing import stub_bfs_engine
     ck = str(tmp_path / "mismatch.ckpt")
-    r1 = stub_device_engine(bounds=False).run(max_depth=3,
-                                              checkpoint_path=ck)
+    r1 = stub_bfs_engine(engine, bounds=False).run(max_depth=3,
+                                                   checkpoint_path=ck)
     assert r1.error
     # limit=7 widens x/y to 4-bit budgets: a different packing spec
-    eng = DeviceBFS(counter_spec(),
-                    model_factory=stub_model_factory(limit=7),
-                    hash_mode="full", tile_size=4,
-                    fpset_capacity=1 << 8, next_capacity=1 << 6,
-                    bounds=False)
+    eng = stub_bfs_engine(engine, bounds=False,
+                          model_factory=stub_model_factory(limit=7))
     assert eng._pk.version != \
-        stub_device_engine(bounds=False)._pk.version
-    with pytest.raises(TLAError, match="packing spec"):
+        stub_bfs_engine(engine, bounds=False)._pk.version
+    with pytest.raises(TLAError, match=(
+            r"was written under packing spec \S+ but this engine "
+            r"derives \S+ from its widths table; refusing to resume "
+            r"\(rebuild with the matching spec/.cfg or pass "
+            r"pack=False\)$")):
         eng.run(resume_from=ck)
 
 

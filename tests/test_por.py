@@ -388,21 +388,29 @@ def test_cut_ratio_gauges():
 # ---------------------------------------------------------------------
 # checkpoint seam
 # ---------------------------------------------------------------------
-def test_checkpoint_records_digest_and_refuses_flip(tmp_path):
+@pytest.mark.parametrize("engine", ["device", "paged", "sharded"])
+def test_checkpoint_records_digest_and_refuses_flip(tmp_path, engine):
+    # every engine refuses a snapshot it wrote itself, letter for letter
+    from tpuvsr.testing import stub_bfs_engine
     ck = str(tmp_path / "ck")
-    e = stub_device_engine(spec=counter_spec(inv_free=True), por="on")
+    e = stub_bfs_engine(engine, spec=counter_spec(inv_free=True),
+                        por="on")
     e.run(checkpoint_path=ck, max_depth=3)
     with open(os.path.join(ck, "manifest.json")) as f:
         mf = json.load(f)
     assert mf["por"] == {"digest": e._por.digest,
                          "eligible_actions": 2,
-                         "sharded_proviso": False}
-    with pytest.raises(TLAError, match="POR"):
-        stub_device_engine(spec=counter_spec(inv_free=True)).run(
+                         "sharded_proviso": engine == "sharded"}
+    with pytest.raises(TLAError, match=(
+            r"was written under POR facts \S+ but this engine consumes "
+            r"off; the explored state sets are not comparable — resume "
+            r"with the matching -por setting \(and the same "
+            r"spec/cfg\)$")):
+        stub_bfs_engine(engine, spec=counter_spec(inv_free=True)).run(
             resume_from=ck)
     # matched resume completes the exact reduced fixpoint
-    r = stub_device_engine(spec=counter_spec(inv_free=True),
-                           por="on").run(resume_from=ck)
+    r = stub_bfs_engine(engine, spec=counter_spec(inv_free=True),
+                        por="on").run(resume_from=ck)
     assert r.distinct_states == POR_STUB_DISTINCT
     assert r.levels == POR_STUB_LEVELS
 

@@ -81,6 +81,7 @@ import functools
 import os
 import sys
 import time
+from dataclasses import dataclass
 
 import numpy as np
 
@@ -95,10 +96,10 @@ from ..resilience.faults import fault_point
 from ..resilience.supervisor import Preempted, preempt_signal
 from . import program_store
 from .bfs import CheckResult
+from .checked import CheckedModel, of_model
 from .fpset import (dedup_batch, empty_table, grow, insert_batch,
                     insert_core, lookup_gids, store_gids)
 from .spec import SpecModel
-from .trace import TraceEntry
 
 I32 = jnp.int32
 
@@ -327,20 +328,73 @@ class RowPages:
 MAX_VALIDATED_TPU_TILE = 512
 
 
+@dataclass
+class _Run:
+    """One `run()` of a one-chip engine: what the caller asked for and
+    what the host loop carries from level to level.  The steps an
+    engine overrides are methods that take it (`DeviceBFS.run`); an
+    engine with more to carry subclasses it (`_run_record`)."""
+    res: CheckResult
+    obs: RunObserver
+    pipe: object
+    t0: float
+    max_states: int
+    max_depth: int
+    max_seconds: float
+    check_deadlock: bool
+    checkpoint_path: str
+    checkpoint_every: float
+    resume_from: str
+    table: dict = None      # the FPSet (and its gid column)
+    fp_cap: int = 0
+    fp_count: int = 0
+    n_front: int = 0
+    level_base: int = 0     # gid of the frontier's first row
+    depth: int = 0
+    last_checkpoint: float = 0.0
+    stop: str = None        # why the run ends with the level it is in
+    n_next: int = 0         # rows the next buffer holds
+    # the resident frontier, its pointer planes, and the next buffers
+    front: object = None
+    fpar: object = None
+    fact: object = None
+    fprm: object = None
+    bufs: tuple = None
+
+    def out_of_time(self, now):
+        """Whether `now` (the calling engine module's clock) is past
+        the run's budget; `stop` then says so."""
+        if self.max_seconds and now - self.t0 > self.max_seconds:
+            self.stop = f"time budget {self.max_seconds}s reached"
+        return self.stop is not None
+
+
 class DeviceBFS:
+    _run_record = _Run
+
+    # what is checked lives on `self.model` (engine/checked.py); the
+    # names this file, its subclasses and the tests read it by
+    codec, kern = of_model("codec"), of_model("kern")
+    commit, inv_names = of_model("commit"), of_model("inv_names")
+    _inv, _facts, _pruned = (of_model("inv"), of_model("facts"),
+                             of_model("pruned"))
+    _pk, _pk_decl = of_model("pk"), of_model("pk_decl")
+    _canon, _sym_fold = of_model("canon"), of_model("sym_fold")
+    _por, _por_facts, _por_active = (of_model("por"), of_model("por_facts"),
+                                     of_model("por_active"))
+    _symmetry_on = of_model("symmetry_on")
+    _pack_manifest = of_model("pack_manifest")
+
     def __init__(self, spec: SpecModel, max_msgs=None, tile_size=128,
                  fpset_capacity=1 << 20, hash_mode="incremental",
                  next_capacity=1 << 14, chunk_tiles=64, expand_mult=2,
                  expand_mults=None, model_factory=None, pipeline=2,
                  pack="auto", commit="fused", symmetry="auto",
                  bounds="auto", edges=False, por="off"):
-        if commit not in ("fused", "per-action"):
-            raise TLAError(f"commit must be 'fused' or 'per-action' "
-                           f"(got {commit!r})")
         if edges and not getattr(self, "_edges_on", False):
             # the tile bodies support emission on any engine, but the
             # drain seam (R_EDGE_FLUSH -> host CSR builder) lives in
-            # the host-paged run loop
+            # the host-paged level loop
             raise TLAError(
                 "edge emission needs the host-paged drain loop; "
                 "construct PagedBFS(edges=True) (or run the CLI "
@@ -361,6 +415,12 @@ class DeviceBFS:
         # successor fingerprint to a gid on device and append
         # (src gid, action, dst gid) triples to the edge buffer
         self._edges_on = getattr(self, "_edges_on", False)
+        # the spec and the five levers as a codec, a kernel and the
+        # specs bound to them (engine/checked.py)
+        self.model = CheckedModel(
+            spec, model_factory, pack=pack, commit=commit,
+            symmetry=symmetry, bounds=bounds, por=por,
+            edges=self._edges_on)
         self.tile = tile_size
         self.fpset_capacity = fpset_capacity
         self.hash_mode = hash_mode
@@ -378,71 +438,13 @@ class DeviceBFS:
         self.expand_mults = expand_mults
         self._mults_given = expand_mults is not None
         self._expand_mult_default = expand_mult
-        # level-kernel commit mode (ISSUE 10 tentpole).  "fused" (the
-        # default) restructures the tile pass into three stages —
-        # chunk-wide guard matrix, work-queue compaction, single-commit
-        # tiles — so each tile issues ONE FPSet insert batch and ONE
-        # scatter instead of n_actions of each, and the per-action
-        # expansion caps are sized by EXACT enabled counts instead of
-        # tile-multiple guesses.  "per-action" is the pre-ISSUE-10
-        # serial-phase body; results are bit-identical between the two
-        # (tests/test_commit.py).
-        self.commit = commit
         # fused-mode per-action expansion caps (absolute lane counts,
         # exact-count grown/calibrated; run-scoped — snapshots keep the
         # per-action expand_mults format and a resumed fused run simply
-        # re-calibrates)
+        # re-calibrates).  The fused commit (the default) sizes them by
+        # EXACT enabled counts instead of tile-multiple guesses
         self.expand_caps = None
         self._need_seen = None
-        self.inv_names = list(spec.cfg.invariants)
-        # symmetry canonicalization (ISSUE 11): "auto" = on iff the
-        # cfg declares SYMMETRY (TLC's semantics — declaring
-        # Permutations IS enabling the reduction); True/False force.
-        # When on, a CanonSpec (engine/canon.py) maps every successor
-        # to the least element of its symmetry orbit PRE-FINGERPRINT
-        # inside the jitted level kernel, so the FPSet and frontier
-        # hold one entry per orbit; the kernel itself is built with an
-        # identity-only perm table (fold_symmetry=False) — the engine
-        # seam, not the P-fold hash, owns the reduction, which makes
-        # -symmetry off a real A/B lever
-        self._symmetry_req = symmetry
-        # model_factory(spec, max_msgs=..) -> (codec, kernel); default
-        # is the hand-kernel registry, tests/the CLI can pass the
-        # AST-compiled factory (lower/compile.make_compiled_model)
-        self._model_factory = model_factory or (
-            lambda spec, max_msgs=None: registry.make_model(
-                spec, max_msgs=max_msgs, fold_symmetry=False))
-        # packed frontier encoding (ISSUE 9): "auto" packs whenever the
-        # codec declares plane_bounds (every registered layout + the
-        # stub harness); False runs dense; True forces the interchange
-        # format even without bounds (ratio 1.0).  Results are
-        # bit-identical either way — the pack/unpack round trip is
-        # exact for in-range values, which the widths lint pass proves.
-        self._pack_req = pack
-        # speclint bounds pre-pass (ISSUE 13): "auto" consumes the
-        # interval-analysis facts iff the lint gate is live — dead
-        # actions pruned from the kernel lane tables, packing
-        # tightened to reachable intervals, fused expansion caps
-        # seeded from static fanout.  False runs declared widths and
-        # full action lists (the A/B lever); results are bit-identical
-        # either way (tests/test_bounds.py oracles)
-        from .bounds import resolve_bounds
-        self._facts = resolve_bounds(spec, bounds)
-        self._pruned = []
-        # ample-set partial-order reduction (ISSUE 16): consume the
-        # independence pass's facts behind the same resolve contract
-        # as -bounds, with the soundness blockers (temporal
-        # properties, edge emission, non-fused commit) refused here
-        # for library callers and at argparse time for the CLI.
-        # Constructor default is "off" — the reduction shrinks
-        # distinct-state counts, so library callers opt in; the CLI's
-        # -por defaults to auto
-        from .por import resolve_por
-        self._por_facts = resolve_por(
-            spec, por,
-            temporal=bool(getattr(spec, "temporal_props", ())),
-            edges=self._edges_on, commit=self.commit)
-        self._por = None
         self._por_kept = 0
         self._por_full = 0
         self._por_amp = 0
@@ -454,22 +456,11 @@ class DeviceBFS:
     # kernel + jitted level construction
     # ------------------------------------------------------------------
     def _build(self, max_msgs):
-        """(Re)build codec, kernel, and the jitted level pass for a
-        message-table bound; called again on bag growth."""
-        spec = self.spec
-        self.codec, self.kern = self._model_factory(spec,
-                                                    max_msgs=max_msgs)
-        # statically dead actions (bounds pass): drop them from the
-        # kernel's lane tables — the fused commit's guard matrix and
-        # staging queue shrink, and a dead guard is never evaluated.
-        # Dead actions are never enabled, so results are bit-identical
-        if self._facts is not None and self._facts.dead_actions:
-            from .bounds import prune_kernel
-            dead = [n for n in self._facts.dead_actions
-                    if n in self.kern.action_names]
-            if dead and len(dead) < len(self.kern.action_names):
-                self.kern = prune_kernel(self.kern, dead)
-                self._pruned = dead
+        """(Re)build the checked model (codec, kernel, lever specs) and
+        what this engine makes of it — expansion caps, traced stages,
+        the level pass — for a message-table bound; called again on
+        bag growth."""
+        self.model.build(max_msgs)
         names = self.kern.action_names
         if self.expand_mults is None:
             self.expand_mults = [self._expand_mult_default] * len(names)
@@ -507,68 +498,6 @@ class DeviceBFS:
                     len(self._need_seen) != len(names):
                 self._need_seen = np.zeros(len(names), np.int64)
         self.L = self.kern.n_lanes
-        self._inv = self.kern.invariant_fn(self.inv_names)
-        self._mat = {}          # action id -> jitted single-action fn
-        # symmetry canonicalization spec (ISSUE 11): rebuilt with the
-        # codec (the group table depends on V, the orbit plane table
-        # on the kernel class); None = no reduction.  A custom
-        # model_factory may hand us a pre-ISSUE-11 FOLDED kernel (its
-        # fingerprint already min-hashes over the group): the fold IS
-        # the reduction then — the canon seam stands down rather than
-        # double-reduce, and -symmetry off is impossible to honor
-        # (the fold is baked into the kernel), so forcing it is a
-        # loud error, not a silent no-op
-        from .canon import build_canon_spec, kernel_fold_order
-        self._sym_fold = kernel_fold_order(self.kern)
-        if spec.symmetry_perms and self._sym_fold > 1:
-            if self._symmetry_req is False:
-                raise TLAError(
-                    "symmetry=False requested but the model factory "
-                    "built a kernel with a FOLDED perm table (its "
-                    "fingerprints min-hash over the group); rebuild "
-                    "it with fold_symmetry=False "
-                    "(registry.make_model) to make -symmetry off real")
-            self._canon = None
-        else:
-            self._canon = build_canon_spec(spec, self.codec, self.kern,
-                                           self._symmetry_req)
-        if self._edges_on and (self._canon is not None
-                               or self._sym_fold > 1):
-            raise TLAError(
-                "edge emission requires symmetry off: the behavior "
-                "graph's nodes are concrete states, so orbit-folded "
-                "fingerprints would merge distinct graph nodes "
-                "(liveness keeps its SYMMETRY-off requirement)")
-        # packed-frontier spec for THIS codec binding (rebuilt with the
-        # codec on bag growth: MAX_MSGS changes the lane count).
-        # Bounds tightening (ISSUE 13): reachable intervals intersect
-        # the declared plane bounds — fewer bits/state, exact round
-        # trip for every reachable state.  _pk_decl keeps the
-        # untightened spec for the bound_tightening_ratio gauge
-        from .pack import build_pack_spec
-        tighten = (self._facts.plane_tighten()
-                   if self._facts is not None else {})
-        if self._pack_req is False:
-            self._pk = None
-            self._pk_decl = None
-        else:
-            self._pk = build_pack_spec(self.codec, spec=spec,
-                                       force=self._pack_req is True,
-                                       tighten=tighten or None)
-            self._pk_decl = (build_pack_spec(
-                self.codec, spec=spec,
-                force=self._pack_req is True) if tighten else self._pk)
-        # ample-set filter bound to THIS kernel (ISSUE 16): rebuilt
-        # with the kernel so the action-name alignment survives bag
-        # growth and pruning.  _por_active gates the device tables —
-        # facts with no eligible action journal their digest but leave
-        # every jitted graph untouched (bit-identical to por=off)
-        if self._por_facts is not None:
-            from .por import PORFilter
-            self._por = PORFilter(self._por_facts, self.kern)
-        self._por_active = (self._por is not None
-                            and self._por.any_eligible
-                            and self.commit == "fused")
         # the per-successor stages that no action changes, traced once
         # for THIS kernel (trace_once): the level body uses each once
         # per action.  Canon runs hash the orbit-least image, which
@@ -641,10 +570,10 @@ class DeviceBFS:
                 "constants": program_store.module_constants(
                     sys.modules[__name__], fpset),
                 "inv_names": self.inv_names,
-                "pack": self._pack_manifest(),
-                "canon": self._canon_manifest(),
-                "bounds": self._bounds_manifest(),
-                "por": [self._por_manifest(), self._por_active],
+                "pack": self.model.pack_manifest(),
+                "canon": self.model.canon_manifest(),
+                "bounds": self.model.bounds_manifest(),
+                "por": [self.model.por_manifest(), self._por_active],
                 "edges": self._edges_on,
                 "debug_checks": self.debug_checks})
         except program_store.Uncovered:
@@ -1876,185 +1805,18 @@ class DeviceBFS:
         in pages of a chunk's rows: one program a buffer shape."""
         return RowPages(cut, buf, n, self.chunk_tiles * self.tile, axis)
 
-    def _pack_manifest(self):
-        return self._pk.manifest() if self._pk is not None else None
-
-    def _fp_batch(self, batch):
-        """Fingerprint a dense batch through the canonical seam (the
-        host-side twin of the in-kernel fpf closure: init
-        registration, resume re-routing)."""
-        if self._canon is None:
-            return self.kern.fingerprint_batch(batch)
-        arr = {k: jnp.asarray(v) for k, v in batch.items()}
-        return jax.vmap(self._canon.fingerprint_fn(self.kern))(arr)
-
-    def _canon_manifest(self):
-        return (self._canon.manifest() if self._canon is not None
-                else None)
-
-    def _symmetry_on(self):
-        """True when this run's fingerprints are orbit-reduced —
-        through the canon seam OR a factory-supplied folded kernel."""
-        return self._canon is not None or (
-            bool(self.spec.symmetry_perms) and self._sym_fold > 1)
-
-    def _check_canon_manifest(self, ck, path):
-        """Resume-seam policy (ISSUE 11 satellite): a snapshot records
-        the canonicalization spec it was fingerprinted under; resuming
-        a symmetry-on snapshot with -symmetry off (or vice versa, or
-        under a changed group/orbit table) is a loud policy error —
-        the FPSet slots hold fingerprints of a different space, so the
-        resumed run would silently re-admit or drop states.  (A
-        changed SYMMETRY *definition* already fails the spec-digest
-        check; this guards the engine-level switch.)  Mirrors the
-        pack-spec mismatch rule."""
-        ckc = ck.get("canon")
-        mine = self._canon.version if self._canon is not None else None
-        theirs = (ckc or {}).get("version")
-        if theirs != mine:
-            raise TLAError(
-                f"checkpoint {path} was written with symmetry "
-                f"canonicalization {theirs or 'off'} but this engine "
-                f"runs {mine or 'off'}; the stored fingerprints are "
-                f"not comparable — resume with the matching "
-                f"-symmetry setting/group")
-
-    def _check_pack_manifest(self, ck, path):
-        """Resume-seam policy (ISSUE 9 satellite): a snapshot records
-        the packing-spec version it was written under; resuming with a
-        MISMATCHED widths table is a loud policy error, not a silent
-        re-encode — a drifted widths table means the run would pack
-        fields into different budgets than the ones speclint verified
-        for the snapshot's trajectory.  pack=off on either side is
-        compatible by construction (snapshots load as dense planes)."""
-        ckpk = ck.get("pack")
-        if ckpk and self._pk is not None and \
-                ckpk.get("version") != self._pk.version:
-            raise TLAError(
-                f"checkpoint {path} was written under packing spec "
-                f"{ckpk.get('version')} but this engine derives "
-                f"{self._pk.version} from its widths table; refusing "
-                f"to resume (rebuild with the matching spec/.cfg or "
-                f"pass pack=False)")
-
-    def _pack_gauges(self, obs):
-        """frontier_bytes_per_state / pack_ratio (ISSUE 9 satellite):
-        the at-rest bytes one frontier row costs this run, and the
-        dense/packed ratio (1.0 when packing is off)."""
-        zero = self.codec.zero_state()
-        dense = sum(int(np.prod(np.shape(v)) or 1) * 4
-                    for v in zero.values())
-        packed = self._pk.packed_bytes if self._pk is not None else dense
-        obs.gauge("frontier_bytes_per_state", int(packed))
-        obs.gauge("pack_ratio", round(dense / packed, 3))
-
-    # -- bounds pre-pass consumption (ISSUE 13) ------------------------
-    def _bounds_doc(self):
-        """The run_start journal `bounds` object (None = off)."""
-        return (self._facts.journal_doc()
-                if self._facts is not None else None)
-
-    def _bounds_manifest(self):
-        """Checkpoint manifest record of the consumed facts (None =
-        bounds off): the digest resume compatibility is judged by."""
-        if self._facts is None:
-            return None
-        return {"digest": self._facts.digest,
-                "tightened": self._facts.tightened}
-
-    def _check_bounds_manifest(self, ck, path):
-        """Resume-seam policy (ISSUE 13 satellite): a snapshot records
-        the bounds facts it consumed (tightened packing + pruned lane
-        ids both depend on them); resuming under a flipped ``-bounds``
-        or changed facts is a loud policy error, mirroring the
-        pack/canon rules.  (Changed cfg constants already fail the
-        spec-digest check; this guards the engine-level switch.)"""
-        theirs = (ck.get("bounds") or {}).get("digest")
-        mine = (self._facts.digest if self._facts is not None
-                else None)
-        if theirs != mine:
-            raise TLAError(
-                f"checkpoint {path} was written under bounds facts "
-                f"{theirs or 'off'} but this engine consumes "
-                f"{mine or 'off'}; the tightened packing and pruned "
-                f"action ids are not comparable — resume with the "
-                f"matching -bounds setting (and the same cfg "
-                f"constants)")
-
-    def _bounds_gauges(self, obs):
-        """state_bound / dead_actions / bound_tightening_ratio
-        (ISSUE 13): what the static pre-pass proved and how many
-        pack bits it saved (declared bits / tightened bits; 1.0 when
-        untightened or bounds off)."""
-        if self._facts is None:
-            return
-        f = self._facts
-        if f.state_bound is not None:
-            obs.gauge("state_bound", int(f.state_bound))
-        obs.gauge("dead_actions", len(self._pruned))
-        ratio = 1.0
-        if self._pk is not None and self._pk_decl is not None and \
-                self._pk.total_bits:
-            ratio = self._pk_decl.total_bits / self._pk.total_bits
-        obs.gauge("bound_tightening_ratio", round(ratio, 4))
-
-    # -- ample-set POR consumption (ISSUE 16) --------------------------
-    def _por_doc(self):
-        """The run_start journal `por` object (None = off) — key-set
-        parity across all engines (obs/SCHEMA.md)."""
-        return (self._por.journal_doc()
-                if self._por is not None else None)
-
-    def _por_manifest(self):
-        """Checkpoint manifest record of the consumed independence
-        facts (None = POR off): flip-on-resume policy anchor."""
-        return self._por.manifest() if self._por is not None else None
-
-    def _check_por_manifest(self, ck, path):
-        """Resume-seam policy (ISSUE 16 satellite): a snapshot records
-        the independence facts its reduced exploration trusted;
-        resuming under a flipped ``-por`` or changed facts is a loud
-        policy error, mirroring the pack/canon/bounds rules — the
-        stored frontier/visited set cover a DIFFERENT (reduced or
-        full) slice of the space, so the resumed run would silently
-        drop or re-admit interleavings."""
-        theirs = (ck.get("por") or {}).get("digest")
-        mine = self._por.digest if self._por is not None else None
-        if theirs != mine:
-            raise TLAError(
-                f"checkpoint {path} was written under POR facts "
-                f"{theirs or 'off'} but this engine consumes "
-                f"{mine or 'off'}; the explored state sets are not "
-                f"comparable — resume with the matching -por setting "
-                f"(and the same spec/cfg)")
-
-    def _por_gauges(self, obs):
-        """por_cut_ratio / ample_states (ISSUE 16): generated kept /
-        generated full under the ample filter (1.0 when POR off or
-        inert), and how many expanded states took the shortcut with
-        real work elided."""
-        if self._por is None:
-            return
-        full = int(self._por_full)
-        kept = int(self._por_kept)
-        obs.gauge("por_cut_ratio",
-                  round(kept / full, 4) if full else 1.0)
-        obs.gauge("ample_states", int(self._por_amp))
-        obs.gauge("por_eligible_actions", self._por.n_eligible)
-
     def _register_init(self, res):
         """Encode, dedup, and FPSet-register the initial states; seed
-        the host pointer store and check invariants on them (shared by
-        run() and PagedBFS.run()).  Returns (table, init_batch, n0,
-        viol_index); viol_index is non-None when an init state
-        violates, with res.trace already built."""
+        the host pointer store and check invariants on them.  Returns
+        (table, init_batch, n0, viol_index); viol_index is non-None
+        when an init state violates, with res.trace already built."""
         spec, codec = self.spec, self.codec
         table = empty_table(self.fpset_capacity)
         init_states = list(spec.init_states())
         init_dense = [codec.encode(st) for st in init_states]
         init_batch = {k: np.stack([d[k] for d in init_dense])
                       for k in init_dense[0]}
-        fps = np.asarray(self._fp_batch(init_batch))
+        fps = np.asarray(self.model.fp_batch(init_batch))
         keep, seen = [], set()
         for i in range(len(init_dense)):
             key = tuple(fps[i])
@@ -2097,378 +1859,424 @@ class DeviceBFS:
         res.states_generated += len(init_dense)
         return table, init_batch, n0, None
 
+    # ------------------------------------------------------------------
+    # the host loop of the one-chip engines
+    # ------------------------------------------------------------------
     @closes_observer
     def run(self, max_states=None, max_depth=None, max_seconds=None,
             check_deadlock=False, log=None, progress_every=10.0,
             checkpoint_path=None, checkpoint_every=None,
             resume_from=None, obs=None) -> CheckResult:
-        from ..analysis import preflight
-        preflight(self.spec, log=log)   # fail fast, before any dispatch
-        obs = RunObserver.ensure(obs, "device", self.spec, log=log,
-                                 progress_every=progress_every)
-        obs.pipeline = self.pipe_window
-        obs.pack = self._pk is not None
-        obs.commit = self.commit
-        obs.symmetry = self._symmetry_on()
-        obs.bounds = self._bounds_doc()
-        obs.edges = self._edges_on
-        obs.por = self._por_doc()
-        self._obs_active = obs          # closes_observer finalizes it
-        spec, codec = self.spec, self.codec  # codec only for init encode
-        # per-action expansion counters (on-device accumulator, pulled
-        # with the control scalars; run-scoped, not checkpointed) +
-        # occupancy accounting (ISSUE 10)
-        self._reset_accounting()
-        self._por_kept = self._por_full = self._por_amp = 0
-        res = CheckResult()
-        t0 = time.time()
-        obs.start(t0, backend=jax.default_backend(),
-                  resumed=resume_from is not None)
-        emit = obs.log
-        # made as the run starts: its unfed clock counts the set-up
-        from .pipeline import DispatchPipeline
-        pipe = DispatchPipeline(self.pipe_window, obs,
-                                ready=lambda o: o["reason"])
-
+        """Check the spec breadth first, a level at a time.  The loop
+        is one for the resident and the host-paged frontier; an engine
+        says how its frontier starts (`_start_frontier`,
+        `_open_levels`), how one level of it is expanded
+        (`_expand_level`: each engine's own dispatch window), and how
+        the level just made becomes the frontier (`_close_level`,
+        `_hand_over`, `_snapshot_keywords`)."""
+        run = self._open_run(
+            log, progress_every, obs,
+            max_states=max_states, max_depth=max_depth,
+            max_seconds=max_seconds, check_deadlock=check_deadlock,
+            checkpoint_path=checkpoint_path,
+            checkpoint_every=checkpoint_every, resume_from=resume_from)
+        res, obs = run.res, run.obs
         if resume_from is not None:
-            # --- resume from a level-boundary snapshot ----------------
-            from .checkpoint import load_checkpoint, spec_digest
-            ck = load_checkpoint(resume_from,
-                                 expect_digest=spec_digest(spec),
-                                 log=emit)
-            if (ck.get("extra") or {}).get("sharded"):
-                raise TLAError("checkpoint was written by the sharded "
-                               "engine; resume it there")
-            # an EMPTY expand_mults means the snapshot carries no
-            # per-action multipliers (written by the sharded engine,
-            # then converted single-device for the supervisor's paged
-            # fallback) — keep this engine's own defaults
-            if ck["max_msgs"] != self.codec.shape.MAX_MSGS or \
-                    (ck["expand_mults"] and list(ck["expand_mults"])
-                     != list(self.expand_mults)):
-                if ck["expand_mults"]:
-                    self.expand_mults = list(ck["expand_mults"])
-                self._build(ck["max_msgs"])
-                codec = self.codec
-            self._check_bounds_manifest(ck, resume_from)
-            self._check_pack_manifest(ck, resume_from)
-            self._check_canon_manifest(ck, resume_from)
-            table = {"slots": jnp.asarray(ck["slots"])}
-            fp_cap = int(ck["slots"].shape[0])
-            if self._por_active:
-                self._check_por_manifest(ck, resume_from)
-                # markers are NOT snapshotted: at a level boundary
-                # every stored fingerprint belongs to the frontier's
-                # level or earlier, so an all-zeros column (marker 0
-                # <= any pdepth = old) reproduces every C3 decision
-                table["gids"] = jnp.zeros((fp_cap,), jnp.int32)
-            elif ck.get("por"):
-                self._check_por_manifest(ck, resume_from)
-            self._init_dense = ck["init_dense"]
-            self._init_states = [codec.decode(d)
-                                 for d in ck["init_dense"]]
-            self._h_parent = [ck["h_parent"]]
-            self._h_action = [ck["h_action"]]
-            self._h_param = [ck["h_param"]]
-            self.level_sizes = list(ck["level_sizes"])
-            depth = ck["depth"]
-            fp_count = ck["fp_count"]
-            res.states_generated = ck["states_generated"]
-            t0 -= ck["elapsed"]            # keep cumulative wall clock
-            obs.set_epoch(t0)
-            n_front = ck["n_front"]
-            f_cap = max(self.next_cap, n_front)
-            front, fpar, fact, fprm = self._alloc_bufs(f_cap)
-            front = self._set_rows(front, ck["frontier"], n_front)
-            bufs = self._alloc_bufs(self.next_cap)
-            level_base = sum(self.level_sizes[:-1])
-            emit(f"resumed from {resume_from}: depth {depth}, "
-                 f"{fp_count} distinct, frontier {n_front}")
+            ck = self._load_snapshot(run)
+            self._start_frontier(run, ck["frontier"], ck["n_front"], ck)
+            obs.log(f"resumed from {resume_from}: depth {run.depth}, "
+                    f"{run.fp_count} distinct, frontier {run.n_front}")
         else:
-            fp_cap = self.fpset_capacity
+            run.fp_cap = self.fpset_capacity
             # reset BEFORE registration: a reused engine instance must
             # not leak the previous run's trajectory into an
             # init-violation result
             self.level_sizes = []
             with obs.span(spans.INIT):
-                table, init_batch, n0, viol = self._register_init(res)
-                fp_count = n0
+                run.table, init_batch, n0, viol = self._register_init(res)
+                run.fp_count = n0
                 if viol is None:
-                    # --- device frontier + next buffers ---------------
-                    f_cap = max(self.next_cap, n0)
-                    front, fpar, fact, fprm = self._alloc_bufs(f_cap)
-                    front = self._set_rows(front, init_batch, n0)
-                    bufs = self._alloc_bufs(self.next_cap)
+                    self._start_frontier(run, init_batch, n0)
             if viol is not None:
-                return self._finish(res, obs, fp_count,
-                                    table=table, fp_cap=fp_cap)
-            n_front = n0
-            level_base = 0          # gid of frontier[0]
-            depth = 0
+                return self._finish(res, obs, run.fp_count,
+                                    table=run.table, fp_cap=run.fp_cap)
+            run.n_front = n0
             self.level_sizes = [n0]
-        last_checkpoint = time.time()
-        return self._chunk_loop(
-            res, obs, pipe, table=table, front=front, bufs=bufs,
-            fpar=fpar, fact=fact, fprm=fprm, n_front=n_front,
-            level_base=level_base, depth=depth, fp_count=fp_count,
-            fp_cap=fp_cap, t0=t0, max_states=max_states,
-            max_depth=max_depth, max_seconds=max_seconds,
-            check_deadlock=check_deadlock,
-            checkpoint_path=checkpoint_path,
-            checkpoint_every=checkpoint_every,
-            last_checkpoint=last_checkpoint)
-
-    def _chunk_loop(self, res, obs, pipe, *, table, front, bufs,
-                    fpar, fact, fprm, n_front, level_base, depth,
-                    fp_count, fp_cap, t0, max_states, max_depth,
-                    max_seconds, check_deadlock, checkpoint_path,
-                    checkpoint_every, last_checkpoint):
-        # keyword-only: the loop state is a pile of same-typed ints and
-        # identically shaped buffers — a transposed positional arg
-        # would type-check and silently corrupt traces/metrics
-        def pull(o):
-            # ONE host round-trip for all control scalars — separate
-            # int() pulls cost one device round-trip each
-            vals = [o["reason"], o["t"], o["nn"], o["gen"], o["dist"],
-                    o["act"], o["need"], o["blk"], o.get("cpl", 0)]
-            if self._por_active:
-                vals += [o["gfull"], o["amp"]]
-            return jax.device_get(vals + self._device_counts(o))
-
-        spec = self.spec
-        emit = obs.log
-        # the host between two levels' device work (and before the
-        # first): open from here, or from a level's end, to the launch
-        obs.boundary(depth=depth)
-        while n_front > 0:
-            if max_depth is not None and depth >= max_depth:
+        run.last_checkpoint = time.time()
+        self._open_levels(run)
+        # the host between two units of device work (a level; a page
+        # of the paged engine) and before the first: open from here,
+        # or from such a unit's end, to the next launch
+        obs.boundary(depth=run.depth)
+        while run.n_front > 0:
+            if max_depth is not None and run.depth >= max_depth:
                 res.error = f"depth limit {max_depth} reached"
                 break
-            depth += 1
-            fault_point("level", depth=depth, obs=obs)
-            start_t = 0
-            n_next = 0
-            n_tiles = (n_front + self.tile - 1) // self.tile
-            stop = None
-            # device-side chain: the next dispatch's (start_t, nn)
-            # come straight off the previous dispatch's outputs, so
-            # filling the window costs zero host syncs
-            pend_t = jnp.asarray(0, I32)
-            pend_nn = jnp.asarray(0, I32)
-            while True:
-                # keep the window full (speculation past a pause or the
-                # level end is safe: such dispatches commit nothing and
-                # pipe.drain() discards their deltas — pipeline.py)
-                while pipe.has_room():
-                    nb, nbp, nba, nbprm = bufs
-                    out = pipe.launch(
-                        self._run_level, table, front,
-                        jnp.asarray(n_front, I32), pend_t,
-                        nb, nbp, nba, nbprm, pend_nn,
-                        jnp.asarray(bool(check_deadlock)), None, None,
-                        jnp.asarray(depth - 1, I32),
-                        fresh=self._fresh_jit,
-                        depth=depth)
-                    self._fresh_jit = False
-                    table = {"slots": out["slots"]}
-                    if self._por_active:
-                        table["gids"] = out["gids"]
-                    bufs = (out["nb"], out["nbp"], out["nba"],
-                            out["nbprm"])
-                    pend_t, pend_nn = out["t"], out["nn"]
-                out, sc = pipe.collect(pull)
-                reason, start_t, n_next, gen_add, dist_add = (
-                    int(x) for x in sc[:5])
-                res.states_generated += gen_add
-                fp_count += dist_add
-                self._act_counts += np.asarray(sc[5], np.int64)
-                self._fold_need(sc[6])
-                self._account_blocks(sc[7], sc[8])
+            run.depth += 1
+            fault_point("level", depth=run.depth, obs=obs)
+            if self._expand_level(run) or not self._end_level(run):
+                break
+        res.diameter = run.depth
+        return self._finish(res, obs, run.fp_count,
+                            table=run.table, fp_cap=run.fp_cap)
+
+    def _open_run(self, log, progress_every, obs, **asked):
+        """Everything of a run before its frontier: the speclint gate,
+        the observer and what it says of the levers, the run's
+        counters, the dispatch window."""
+        from ..analysis import preflight
+        preflight(self.spec, log=log)   # fail fast, before any dispatch
+        obs = self._observer(obs, log=log, progress_every=progress_every)
+        self.model.announce(obs, pipeline=self.pipe_window)
+        self._obs_active = obs          # closes_observer finalizes it
+        # per-action expansion counters (on-device accumulator, pulled
+        # with the control scalars; run-scoped, not checkpointed) +
+        # occupancy accounting (ISSUE 10)
+        self._reset_accounting()
+        self._por_kept = self._por_full = self._por_amp = 0
+        t0 = time.time()
+        obs.start(t0, backend=jax.default_backend(),
+                  resumed=asked["resume_from"] is not None)
+        # the window of level-kernel dispatches (ISSUE 4), made as the
+        # run starts: its unfed clock counts the set-up
+        from .pipeline import DispatchPipeline
+        pipe = DispatchPipeline(self.pipe_window, obs,
+                                ready=lambda o: o["reason"])
+        return self._run_record(CheckResult(), obs, pipe, t0, **asked)
+
+    def _observer(self, obs, **kw):
+        """The run's observer, under this engine's name in journals
+        and metrics (obs/SCHEMA.md)."""
+        return RunObserver.ensure(obs, "device", self.spec, **kw)
+
+    def _load_snapshot(self, run):
+        """The head of a resume: a level-boundary snapshot read,
+        refused where it was written under other levers, and the
+        table, the pointer store and the counts as it left them."""
+        from .checkpoint import load_checkpoint, spec_digest
+        path = run.resume_from
+        ck = load_checkpoint(path, expect_digest=spec_digest(self.spec),
+                             log=run.obs.log)
+        if (ck.get("extra") or {}).get("sharded"):
+            raise TLAError("checkpoint was written by the sharded "
+                           "engine; resume it there")
+        # an EMPTY expand_mults means the snapshot carries no
+        # per-action multipliers (written by the sharded engine,
+        # then converted single-device for the supervisor's paged
+        # fallback, parallel.sharded_bfs.convert_sharded_snapshot) —
+        # keep this engine's own defaults
+        if ck["max_msgs"] != self.codec.shape.MAX_MSGS or \
+                (ck["expand_mults"] and list(ck["expand_mults"])
+                 != list(self.expand_mults)):
+            if ck["expand_mults"]:
+                self.expand_mults = list(ck["expand_mults"])
+            self._build(ck["max_msgs"])
+        self.model.check_manifests(ck, path)
+        run.table = {"slots": jnp.asarray(ck["slots"])}
+        run.fp_cap = int(ck["slots"].shape[0])
+        if self._por_active:
+            # the C3 level markers are NOT snapshotted: at a level
+            # boundary every stored fingerprint belongs to the
+            # frontier's level or earlier, so an all-zeros column
+            # (marker 0 <= any pdepth = old) reproduces every decision
+            run.table["gids"] = jnp.zeros((run.fp_cap,), jnp.int32)
+        self._init_dense = ck["init_dense"]
+        self._init_states = [self.codec.decode(d)
+                             for d in ck["init_dense"]]
+        self._h_parent = [ck["h_parent"]]
+        self._h_action = [ck["h_action"]]
+        self._h_param = [ck["h_param"]]
+        self.level_sizes = list(ck["level_sizes"])
+        run.depth = ck["depth"]
+        run.fp_count = ck["fp_count"]
+        run.res.states_generated = ck["states_generated"]
+        run.t0 -= ck["elapsed"]            # keep cumulative wall clock
+        run.obs.set_epoch(run.t0)
+        run.n_front = ck["n_front"]
+        run.level_base = sum(self.level_sizes[:-1])
+        return ck
+
+    def _start_frontier(self, run, batch, n, ck=None):
+        """The run's first frontier from `n` dense rows (Init, inside
+        the init span; or a snapshot `ck`'s): here on the device,
+        beside the next buffers."""
+        run.front, run.fpar, run.fact, run.fprm = self._alloc_bufs(
+            max(self.next_cap, n))
+        run.front = self._set_rows(run.front, batch, n)
+        run.bufs = self._alloc_bufs(self.next_cap)
+
+    def _open_levels(self, run):
+        """What is left to set up once the frontier stands (here
+        nothing)."""
+
+    def _pull_scalars(self, o):
+        """ONE host round-trip for all control scalars of a dispatch —
+        separate int() pulls cost one device round-trip each."""
+        vals = [o["reason"], o["t"], o["nn"], o["gen"], o["dist"],
+                o["act"], o["need"], o["blk"], o.get("cpl", 0)]
+        if self._edges_on:
+            vals.append(o["edge_n"])
+        if self._por_active:
+            vals += [o["gfull"], o["amp"]]
+        return jax.device_get(vals + self._device_counts(o))
+
+    def _collect(self, run):
+        """The oldest dispatch of the window, folded into the run's
+        counters.  Returns its output, its reason, the tile it
+        stopped at, the rows of the next buffer and (edge emission)
+        of the edge buffer."""
+        out, sc = run.pipe.collect(self._pull_scalars)
+        reason, t, nn, gen_add, dist_add = (int(x) for x in sc[:5])
+        run.res.states_generated += gen_add
+        run.fp_count += dist_add
+        self._act_counts += np.asarray(sc[5], np.int64)
+        self._fold_need(sc[6])
+        self._account_blocks(sc[7], sc[8])
+        at, n_edge = 9, None
+        if self._edges_on:
+            at, n_edge = 10, int(sc[9])
+        if self._por_active:
+            self._por_kept += gen_add
+            self._por_full += int(sc[at])
+            self._por_amp += int(sc[at + 1])
+        self._fold_device_counts(sc)
+        return out, reason, t, nn, n_edge
+
+    def _verdict(self, run, reason, out, base, row_of):
+        """A reason that ends the run: a violation or a deadlock at
+        row `base` + the index the device reports of the frontier
+        (`row_of`: that row, dense) fills the result and returns True;
+        a slot error raises.  False for a growth pause."""
+        res = run.res
+        if reason == R_VIOLATION:
+            vp, va, vprm = (int(v) for v in np.asarray(out["viol"]))
+            gid = run.level_base + base + vp
+            vstate = self.model.materialize_one(row_of(base + vp), va, vprm)
+            bad = self.spec.check_invariants(self.codec.decode(vstate))
+            if bad is None:
+                # device said violated, interpreter disagrees:
+                # engine bug — fail loudly, don't fabricate a
+                # counterexample (see device_sim for rationale)
+                raise TLAError(
+                    "device/interpreter divergence: device "
+                    "invariant kernel reported a violation the "
+                    "interpreter accepts (parent gid "
+                    f"{gid}, action {self.kern.action_names[va]})")
+            res.ok = False
+            res.violated_invariant = bad
+            res.trace = self._trace(gid, extra=(va, vprm))
+            return True
+        if reason == R_SLOT_ERR:
+            raise TLAError(slot_error(self.codec))
+        if reason == R_DEADLOCK:
+            di = int(out["dead"])
+            res.ok = False
+            res.error = "deadlock"
+            res.deadlock_state = self.codec.decode(row_of(base + di))
+            res.trace = self._trace(run.level_base + base + di)
+            return True
+        return False
+
+    def _grow_fpset(self, run):
+        run.table = grow(run.table)
+        run.fp_cap *= 4
+        # shape change -> the next dispatch retraces and recompiles;
+        # charge it to "compile", not "dispatch" (as every growth)
+        self._fresh_jit = True
+        run.obs.grow("fpset", run.fp_cap)
+        run.obs.log(f"FPSet grown to {run.fp_cap} slots")
+
+    def _expand_level(self, run):
+        """One level of the resident frontier: the window re-launches
+        the same `front`, chained on the device-side (t, nn) of the
+        dispatch before, and is drained at the level's end.  Returns
+        True where the level ended the run with a verdict."""
+        obs, pipe, res = run.obs, run.pipe, run.res
+        emit = obs.log
+        depth, n_front = run.depth, run.n_front
+        start_t = 0
+        run.n_next = 0
+        n_tiles = (n_front + self.tile - 1) // self.tile
+        # device-side chain: the next dispatch's (start_t, nn)
+        # come straight off the previous dispatch's outputs, so
+        # filling the window costs zero host syncs
+        pend_t = jnp.asarray(0, I32)
+        pend_nn = jnp.asarray(0, I32)
+        while True:
+            # keep the window full (speculation past a pause or the
+            # level end is safe: such dispatches commit nothing and
+            # pipe.drain() discards their deltas — pipeline.py)
+            while pipe.has_room():
+                nb, nbp, nba, nbprm = run.bufs
+                out = pipe.launch(
+                    self._run_level, run.table, run.front,
+                    jnp.asarray(n_front, I32), pend_t,
+                    nb, nbp, nba, nbprm, pend_nn,
+                    jnp.asarray(bool(run.check_deadlock)), None, None,
+                    jnp.asarray(depth - 1, I32),
+                    fresh=self._fresh_jit,
+                    depth=depth)
+                self._fresh_jit = False
+                run.table = {"slots": out["slots"]}
                 if self._por_active:
-                    self._por_kept += gen_add
-                    self._por_full += int(sc[9])
-                    self._por_amp += int(sc[10])
-                self._fold_device_counts(sc)
+                    run.table["gids"] = out["gids"]
+                run.bufs = (out["nb"], out["nbp"], out["nba"],
+                            out["nbprm"])
+                pend_t, pend_nn = out["t"], out["nn"]
+            out, reason, start_t, run.n_next, _ = self._collect(run)
 
-                if reason == RUNNING:
-                    obs.progress(depth=depth, distinct=fp_count,
-                                 generated=res.states_generated)
-                    if max_seconds and time.time() - t0 > max_seconds:
-                        stop = f"time budget {max_seconds}s reached"
-                        pipe.drain(reason="budget")
-                        break
-                    if start_t >= n_tiles:
-                        pipe.drain()     # in-flight tickets are no-ops
-                        break            # level complete
-                    continue
-                # pause or terminal reason: everything still in flight
-                # is a replay of the same paused tile — drop it, then
-                # handle the reason on the chain-tip table/buffers
-                # (identical to the consumed ticket's: replays commit
-                # nothing)
-                pipe.drain()
-                if reason == R_VIOLATION:
-                    vp, va, vprm = (int(v) for v in np.asarray(out["viol"]))
-                    gid = level_base + vp
-                    parent_dense = self._fetch_row(front, vp)
-                    vstate = self._materialize_one(parent_dense, va, vprm)
-                    bad = spec.check_invariants(
-                        self.codec.decode(vstate))
-                    if bad is None:
-                        # device said violated, interpreter disagrees:
-                        # engine bug — fail loudly, don't fabricate a
-                        # counterexample (see device_sim for rationale)
-                        raise TLAError(
-                            "device/interpreter divergence: device "
-                            "invariant kernel reported a violation the "
-                            "interpreter accepts (parent gid "
-                            f"{gid}, action {self.kern.action_names[va]})")
-                    res.ok = False
-                    res.violated_invariant = bad
-                    res.trace = self._trace(gid, extra=(va, vprm))
-                    res.diameter = depth
-                    return self._finish(res, obs, fp_count,
-                                        table=table, fp_cap=fp_cap)
-                elif reason == R_BAG_GROW:
-                    front, nb = self._grow_msgs([front, bufs[0]])
-                    bufs = (nb,) + bufs[1:]
-                    obs.grow("message_table", self.codec.shape.MAX_MSGS)
-                    emit(f"message table grown to "
-                         f"{self.codec.shape.MAX_MSGS} slots (recompiling)")
-                elif reason == R_FPSET_GROW:
-                    table = grow(table)
-                    fp_cap *= 4
-                    # shape change -> the next dispatch retraces and
-                    # recompiles; charge it to "compile", not
-                    # "dispatch" (same for every growth below)
-                    self._fresh_jit = True
-                    obs.grow("fpset", fp_cap)
-                    emit(f"FPSet grown to {fp_cap} slots")
-                elif reason == R_NEXT_GROW:
-                    bufs = self._grow_next(bufs)
-                    self._fresh_jit = True
-                    obs.grow("next_buffer", bufs[1].shape[0])
-                    emit(f"next-frontier buffer grown to "
-                         f"{bufs[1].shape[0]}")
-                elif reason == R_EXPAND_GROW:
-                    self._grow_expand(int(out["grow_aid"]), obs, emit)
-                elif reason == R_SLOT_ERR:
-                    raise TLAError(slot_error(self.codec))
-                elif reason == R_DEADLOCK:
-                    di = int(out["dead"])
-                    gid = level_base + di
-                    res.ok = False
-                    res.error = "deadlock"
-                    res.deadlock_state = self.codec.decode(
-                        self._fetch_row(front, di))
-                    res.trace = self._trace(gid)
-                    res.diameter = depth
-                    return self._finish(res, obs, fp_count,
-                                        table=table, fp_cap=fp_cap)
-                # growth pauses fall through here; terminal reasons
-                # returned above
-                obs.progress(depth=depth, distinct=fp_count,
+            if reason == RUNNING:
+                obs.progress(depth=depth, distinct=run.fp_count,
                              generated=res.states_generated)
-                if max_seconds and time.time() - t0 > max_seconds:
-                    stop = f"time budget {max_seconds}s reached"
+                if run.out_of_time(time.time()):
+                    pipe.drain(reason="budget")
                     break
-
-            # ---- level complete: pull trace pointers, swap buffers ---
-            obs.boundary(depth=depth)
-            obs.level_done(depth, frontier=n_front, distinct=fp_count,
-                           generated=res.states_generated)
-            self._account_tiles(min(start_t, n_tiles))
-            nb, nbp, nba, nbprm = bufs
-            if n_next:
-                # async pointer fetch, in pages of one shape: the
-                # copies overlap the next level's compute and are only
-                # materialized on demand (_flush_pointers) — a blocking
-                # device_get here costs a full device round-trip per
-                # level, a slice of n_next rows a compile per level
-                pages = self._pull(_cut_pointers, (nbp, nba, nbprm),
-                                   n_next, axis=1)
-                obs.count("boundary_pull_pages", len(pages.pages))
-                obs.count("boundary_pull_bytes", pages.nbytes)
-                self._h_parent.append((pages, level_base))
-                self._h_action.append(None)
-                self._h_param.append(None)
-                self.level_sizes.append(n_next)
-            level_base += n_front
-            # the old frontier set becomes the next scratch buffer set
-            front, bufs = nb, (front, fpar, fact, fprm)
-            fpar, fact, fprm = nbp, nba, nbprm
-            n_front = n_next
-            if self.debug_checks and n_next:
-                self._debug_assert_widths(front, n_next, depth)
-            # fused commit: shrink the expansion caps onto the exact
-            # observed maxima (the window is drained here, so the
-            # recompile never races an in-flight dispatch)
-            if n_next and stop is None:
-                self._calibrate_caps(obs, emit, n_front)
-            # a pending SIGTERM/SIGINT (supervisor's PreemptionGuard)
-            # forces a rescue snapshot at this boundary regardless of
-            # cadence; at fixpoint (n_next == 0) the run finishes anyway
-            rescue = preempt_signal() if n_next else None
-            if checkpoint_path and n_next and (
-                    rescue is not None
-                    or checkpoint_every is None
-                    or time.time() - last_checkpoint >= checkpoint_every):
-                from .checkpoint import (FORMAT_VERSION, save_checkpoint,
-                                         spec_digest)
-                with obs.span(spans.CHECKPOINT, depth=depth):
-                    self._flush_pointers()
-                    staged = save_checkpoint(
-                        checkpoint_path,
-                        slots=table["slots"],
-                        **self._snapshot_frontier(front, n_next),
-                        n_front=n_next,
-                        h_parent=np.concatenate(self._h_parent),
-                        h_action=np.concatenate(self._h_action),
-                        h_param=np.concatenate(self._h_param),
-                        init_dense=self._init_dense,
-                        level_sizes=self.level_sizes, depth=depth,
-                        fp_count=fp_count,
-                        states_generated=res.states_generated,
-                        max_msgs=self.codec.shape.MAX_MSGS,
-                        expand_mults=self.expand_mults,
-                        elapsed=time.time() - t0,
-                        digest=spec_digest(spec),
-                        pack=self._pack_manifest(),
-                        canon=self._canon_manifest(),
-                        bounds=self._bounds_manifest(),
-                        por=self._por_manifest(), obs=obs)
-                last_checkpoint = time.time()
-                obs.checkpoint(checkpoint_path, depth, fp_count, staged,
-                               FORMAT_VERSION)
-                emit(f"checkpoint written to {checkpoint_path} "
-                     f"(depth {depth}, {fp_count} distinct)")
-            if rescue is not None:
-                obs.rescue(checkpoint_path or "", depth, fp_count,
-                           rescue)
-                emit(f"preempted by {rescue}: rescue snapshot at depth "
-                     f"{depth} ({checkpoint_path}); exiting resumable")
-                raise Preempted(checkpoint_path, depth, fp_count,
-                                rescue)
-            if stop:
-                res.error = stop
-                break
-            if n_next == 0:
-                break
-            if max_states and fp_count >= max_states:
-                res.error = f"state limit {max_states} reached"
-                break
-            # proactive FPSet growth between levels keeps probe chains
-            # short and the in-level overflow pause rare
-            if fp_count > 0.5 * fp_cap:
-                table = grow(table)
-                fp_cap *= 4
+                if start_t >= n_tiles:
+                    pipe.drain()     # in-flight tickets are no-ops
+                    break            # level complete
+                continue
+            # pause or terminal reason: everything still in flight
+            # is a replay of the same paused tile — drop it, then
+            # handle the reason on the chain-tip table/buffers
+            # (identical to the consumed ticket's: replays commit
+            # nothing)
+            pipe.drain()
+            if self._verdict(run, reason, out, 0,
+                             lambda i: self.model.fetch_row(run.front, i)):
+                return True
+            if reason == R_BAG_GROW:
+                run.front, nb = self._grow_msgs([run.front, run.bufs[0]])
+                run.bufs = (nb,) + run.bufs[1:]
+                obs.grow("message_table", self.codec.shape.MAX_MSGS)
+                emit(f"message table grown to "
+                     f"{self.codec.shape.MAX_MSGS} slots (recompiling)")
+            elif reason == R_FPSET_GROW:
+                self._grow_fpset(run)
+            elif reason == R_NEXT_GROW:
+                run.bufs = self._grow_next(run.bufs)
                 self._fresh_jit = True
-                obs.grow("fpset", fp_cap)
-                emit(f"FPSet grown to {fp_cap} slots")
+                obs.grow("next_buffer", run.bufs[1].shape[0])
+                emit(f"next-frontier buffer grown to "
+                     f"{run.bufs[1].shape[0]}")
+            elif reason == R_EXPAND_GROW:
+                self._grow_expand(int(out["grow_aid"]), obs, emit)
+            obs.progress(depth=depth, distinct=run.fp_count,
+                         generated=res.states_generated)
+            if run.out_of_time(time.time()):
+                break
+        self._account_tiles(min(start_t, n_tiles))
+        return False
 
-        res.diameter = depth
-        return self._finish(res, obs, fp_count,
-                            table=table, fp_cap=fp_cap)
+    def _close_level(self, run):
+        """What of a level's device work is left when its window is
+        empty (here nothing)."""
+
+    def _hand_over(self, run):
+        """The level just made becomes the frontier: its trace
+        pointers start for the host, the buffer sets swap."""
+        nb, nbp, nba, nbprm = run.bufs
+        n_next = run.n_next
+        if n_next:
+            # async pointer fetch, in pages of one shape: the
+            # copies overlap the next level's compute and are only
+            # materialized on demand (_flush_pointers) — a blocking
+            # device_get here costs a full device round-trip per
+            # level, a slice of n_next rows a compile per level
+            pages = self._pull(_cut_pointers, (nbp, nba, nbprm),
+                               n_next, axis=1)
+            run.obs.count("boundary_pull_pages", len(pages.pages))
+            run.obs.count("boundary_pull_bytes", pages.nbytes)
+            self._h_parent.append((pages, run.level_base))
+            self._h_action.append(None)
+            self._h_param.append(None)
+            self.level_sizes.append(n_next)
+        run.level_base += run.n_front
+        # the old frontier set becomes the next scratch buffer set
+        run.front, run.bufs = nb, (run.front, run.fpar, run.fact,
+                                   run.fprm)
+        run.fpar, run.fact, run.fprm = nbp, nba, nbprm
+        run.n_front = n_next
+        if self.debug_checks and n_next:
+            self._debug_assert_widths(run.front, n_next, run.depth)
+
+    def _snapshot_keywords(self, run):
+        """``save_checkpoint``'s frontier keywords at a level's end."""
+        return self._snapshot_frontier(run.front, run.n_front)
+
+    def _end_level(self, run):
+        """A level's end, the same for every frontier: the level is
+        filed and handed over, then caps are calibrated, a due or
+        forced snapshot is written, and the run's limits are tested.
+        Returns False where no level follows."""
+        res, obs, depth = run.res, run.obs, run.depth
+        emit = obs.log
+        obs.boundary(depth=depth)
+        self._close_level(run)
+        obs.level_done(depth, frontier=run.n_front, distinct=run.fp_count,
+                       generated=res.states_generated)
+        self._hand_over(run)
+        if run.stop:
+            # the budget cut this level short: what was handed over is
+            # a part of one (the run's result says so, `levels[-1]`),
+            # and nothing below may file it as a level.  The snapshot
+            # on disk stays the last whole level's
+            res.error = run.stop
+            return False
+        # fused commit: shrink the expansion caps onto the exact
+        # observed maxima (the window is drained here, so the
+        # recompile never races an in-flight dispatch)
+        self._calibrate_caps(obs, emit, run.n_front)
+        # a pending SIGTERM/SIGINT (supervisor's PreemptionGuard)
+        # forces a rescue snapshot at this boundary regardless of
+        # cadence; at fixpoint (no next level) the run finishes anyway
+        rescue = preempt_signal() if run.n_front else None
+        path = run.checkpoint_path
+        if path and run.n_front and (
+                rescue is not None
+                or run.checkpoint_every is None
+                or time.time() - run.last_checkpoint
+                >= run.checkpoint_every):
+            from .checkpoint import (FORMAT_VERSION, save_checkpoint,
+                                     spec_digest)
+            with obs.span(spans.CHECKPOINT, depth=depth):
+                self._flush_pointers()
+                staged = save_checkpoint(
+                    path,
+                    slots=run.table["slots"],
+                    **self._snapshot_keywords(run),
+                    n_front=run.n_front,
+                    h_parent=np.concatenate(self._h_parent),
+                    h_action=np.concatenate(self._h_action),
+                    h_param=np.concatenate(self._h_param),
+                    init_dense=self._init_dense,
+                    level_sizes=self.level_sizes, depth=depth,
+                    fp_count=run.fp_count,
+                    states_generated=res.states_generated,
+                    max_msgs=self.codec.shape.MAX_MSGS,
+                    expand_mults=self.expand_mults,
+                    elapsed=time.time() - run.t0,
+                    digest=spec_digest(self.spec),
+                    **self.model.manifests(), obs=obs)
+            run.last_checkpoint = time.time()
+            obs.checkpoint(path, depth, run.fp_count, staged,
+                           FORMAT_VERSION)
+            emit(f"checkpoint written to {path} "
+                 f"(depth {depth}, {run.fp_count} distinct)")
+        if rescue is not None:
+            obs.rescue(path or "", depth, run.fp_count, rescue)
+            emit(f"preempted by {rescue}: rescue snapshot at depth "
+                 f"{depth} ({path}); exiting resumable")
+            raise Preempted(path, depth, run.fp_count, rescue)
+        if run.n_front == 0:
+            return False
+        if run.max_states and run.fp_count >= run.max_states:
+            res.error = f"state limit {run.max_states} reached"
+            return False
+        # proactive FPSet growth between levels keeps probe chains
+        # short and the in-level overflow pause rare
+        if run.fp_count > 0.5 * run.fp_cap:
+            self._grow_fpset(run)
+        return True
 
     def _debug_assert_widths(self, front, n_front, depth):
         """TPUVSR_DEBUG_NANS=1 overflow guard: after each level, pull
@@ -2510,27 +2318,6 @@ class DeviceBFS:
                 self._h_action[i] = act.copy()
                 self._h_param[i] = prm.copy()
 
-    def _fetch_row(self, batch, i):
-        """One dense state row from a frontier-format buffer (packed
-        rows are unpacked host-side)."""
-        if not isinstance(batch, dict):
-            return self._pk.unpack_row_np(np.asarray(batch[i]))
-        return {k: np.asarray(v[i]) for k, v in batch.items()}
-
-    def _materialize_one(self, st, aid, param):
-        """Apply one recorded (action, lane param) to a single dense
-        state — the trace-replay step."""
-        fn = self._mat.get(aid)
-        if fn is None:
-            fn = jax.jit(jax.vmap(self.kern._action_fns()[aid],
-                                  in_axes=(0, 0)))
-            self._mat[aid] = fn
-        batch = {k: np.asarray(v)[None] for k, v in st.items()}
-        succ, en = fn(batch, jnp.asarray([param], jnp.int32))
-        assert bool(np.asarray(en)[0]), "trace replay chose a disabled lane"
-        return {k: np.asarray(v)[0] for k, v in succ.items()
-                if not k.startswith("_")}
-
     def _finish(self, res, obs, fp_count, table=None, fp_cap=None):
         """Uniform result finalization: the collector (not the engine)
         stamps elapsed/states_per_sec/levels/metrics (ISSUE 2
@@ -2544,20 +2331,8 @@ class DeviceBFS:
         """The run's last counters and gauges (span
         ``tpuvsr.engine.finish``); `table_stats` runs on the device."""
         res.distinct_states = fp_count
-        self._pack_gauges(obs)
-        self._bounds_gauges(obs)
-        self._por_gauges(obs)
-        # symmetry canonicalization gauges (ISSUE 11): group order
-        # this run reduced by (1 = off), and the headline
-        # generated/distinct-after-canon ratio — on a symmetry-on run
-        # it folds the orbit factor on top of ordinary dedup, so the
-        # on-vs-off A/B reads the orbit cut straight off the journal
-        obs.gauge("symmetry_perms",
-                  self._canon.perms if self._canon is not None
-                  else self._sym_fold)
-        if res.states_generated and fp_count:
-            obs.gauge("orbit_ratio",
-                      round(res.states_generated / fp_count, 4))
+        self.model.gauges(obs, res.states_generated, fp_count,
+                          (self._por_kept, self._por_full, self._por_amp))
         if self._canon_counts:
             lanes_c, moved_c = (int(x) for x in self._canon_cn)
             obs.count("canon_lanes", lanes_c)
@@ -2602,32 +2377,14 @@ class DeviceBFS:
             obs.gauge("fpset_collision_rate", st["collision_rate"])
 
     def _trace(self, gid, extra=None):
-        """Walk the host pointer table back to an init state, then
-        replay the recorded (action, param) chain through the kernel to
-        materialize each state, emitting TRACE-format entries."""
+        """The counterexample that ends at `gid` (and one step
+        `extra` past it), replayed from the host pointer table."""
         self._flush_pointers()
-        parent = np.concatenate(self._h_parent)
-        action = np.concatenate(self._h_action)
-        param = np.concatenate(self._h_param)
-        steps = []
-        cur = gid
-        while action[cur] >= 0:
-            steps.append((int(action[cur]), int(param[cur])))
-            cur = int(parent[cur])
-        steps.reverse()
-        if extra is not None:
-            steps.append(extra)
-        loc = {a.name: a.location for a in self.spec.actions}
-        st = self.codec.encode(self._init_states[cur])
-        out = [TraceEntry(position=1, action_name=None, location=None,
-                          state=self.codec.decode(st))]
-        for pos, (aid, prm) in enumerate(steps):
-            st = self._materialize_one(st, aid, prm)
-            name = self.kern.action_names[aid]
-            out.append(TraceEntry(position=pos + 2, action_name=name,
-                                  location=loc.get(name),
-                                  state=self.codec.decode(st)))
-        return out
+        return self.model.trace(
+            (np.concatenate(self._h_parent),
+             np.concatenate(self._h_action),
+             np.concatenate(self._h_param)),
+            self._init_states, gid, extra)
 
 
 def device_bfs_check(spec: SpecModel, max_states=None, max_depth=None,

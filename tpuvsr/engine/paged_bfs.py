@@ -1,4 +1,5 @@
-"""Host-paged BFS engine: the frontier spill tier for defect-scale runs.
+"""Host-paged BFS engine: `DeviceBFS`'s host loop over a frontier that
+lies in host RAM (or on disk) and is paged through the device.
 
 The reference's flagship run — exhaustive BFS of VSR.tla at the
 defect-repro constants — drove TLC to >=500 GB of disk, nearly all of
@@ -18,6 +19,16 @@ of two that fits, 134 M states at 50 % load — PERF.md §4, PR 31.)
       --page in-->  device chunk buffer [chunk_tiles x tile states]
       --level kernel (DeviceBFS._make_level, unchanged)-->
       next-frontier buffer fills --> PAGE OUT to host, reset, continue
+
+`PagedBFS` defines no `run`: the run's opening, the resume, a level's
+end (calibration, snapshot, rescue, limits) and the verdicts are
+`DeviceBFS`'s, and so are the level program, the growth handlers and
+the trace replay — paged results match resident results exactly
+(tests/test_paged.py).  What is here is what a host frontier changes:
+how it starts (`_start_frontier`, `_open_levels`), how one level of it
+is expanded (`_expand_level`), and how the pages a level spilled become
+the next frontier and a snapshot's keywords (`_close_level`,
+`_hand_over`, `_snapshot_keywords`).
 
 A page has ONE shape, in and out (ISSUE 31): a chunk goes in as a
 block of `chunk_tiles x tile` rows whatever it holds, and the next
@@ -46,17 +57,9 @@ re-enters page i at the paused tile and launches page i+1 again from
 the array it holds.  Nothing is launched behind a level's last page.
 (Until ISSUE 38 the second ticket of the window went to the page just
 finished: it found no tile to run, but ran the level program's stage
-1 over a whole page first, once a page.)
-
-Everything else — fingerprinting, invariant evaluation, growth of the
-message table / FPSet / per-action expand buffers, violation handling,
-deadlock detection, trace replay — is inherited from DeviceBFS; the
-two engines run the SAME jitted level pass, so paged results match
-resident results exactly (asserted in tests/test_paged.py).
-
-Checkpoint/resume reuses the level-boundary snapshot format of
-engine/checkpoint.py (the frontier is already host-side here, making
-snapshots cheap).
+1 over a whole page first, once a page.)  A budget that trips is
+tested at a page's end: the page in flight behind it is collected and
+counted, where the resident window drops what it has in flight.
 """
 
 from __future__ import annotations
@@ -72,15 +75,11 @@ import jax
 import jax.numpy as jnp
 
 from ..core.values import TLAError
-from ..obs import RunObserver, closes_observer, spans
-from ..resilience.faults import fault_point
-from ..resilience.supervisor import Preempted, preempt_signal
+from ..obs import RunObserver, spans
 from .bfs import CheckResult
-from .device_bfs import (DeviceBFS, I32, R_BAG_GROW, R_DEADLOCK,
-                         R_EDGE_FLUSH, R_EXPAND_GROW, R_FPSET_GROW,
-                         R_NEXT_GROW, R_SLOT_ERR, R_VIOLATION, RUNNING,
-                         slot_error)
-from .fpset import grow
+from .device_bfs import (DeviceBFS, I32, R_BAG_GROW, R_EDGE_FLUSH,
+                         R_EXPAND_GROW, R_FPSET_GROW, R_NEXT_GROW, RUNNING,
+                         _Run)
 from .spill import EdgeCSR
 
 
@@ -128,6 +127,27 @@ def _shape_key(tree):
     return tuple(np.shape(v) for v in jax.tree.leaves(tree))
 
 
+@dataclass
+class _PagedRun(_Run):
+    """A run whose frontier lies on the host: `front` stays None."""
+    host_front: object = None   # rows at rest, or their SpillTier
+    ebufs: tuple = None         # the edge append buffers (ISSUE 15)
+    n_edge: int = 0             # triples they hold
+    # host scalars as a dispatch's outputs are (`_open_levels`)
+    home: object = None
+    zero: object = None
+    running: object = None
+    # of the level being expanded: the pages it has spilled, their
+    # level-relative pointers and row count, and whose rows the next
+    # buffer holds
+    drained: object = None
+    d_par: list = None
+    d_act: list = None
+    d_prm: list = None
+    n_next_total: int = 0
+    segs: list = None
+
+
 class PagedBFS(DeviceBFS):
     """DeviceBFS with a host-RAM frontier paged through the device.
 
@@ -145,6 +165,7 @@ class PagedBFS(DeviceBFS):
     # the FPSet's occupancy on the device and pulls two scalars, not
     # the table (5.4 GB at 1<<28 slots)
     PROVIDES = frozenset({"fixed_page_shapes", "device_table_stats"})
+    _run_record = _PagedRun
 
     def __init__(self, *args, retain_levels=False, spill_dir=None,
                  spill_ram_rows=None, edges=False, edge_capacity=None,
@@ -340,50 +361,17 @@ class PagedBFS(DeviceBFS):
         it: packed words when the pack spec is bound, dense otherwise
         (the spill `bytes` journal field and gauges report REAL
         transfer volume)."""
-        if self._pk is not None:
-            return self._pk.packed_bytes
-        zero = self.codec.zero_state()
-        return sum(int(np.prod(np.shape(v)) or 1) * 4
-                   for v in zero.values())
+        return self.model.row_bytes()
 
-    @closes_observer
-    def run(self, max_states=None, max_depth=None, max_seconds=None,
-            check_deadlock=False, log=None, progress_every=10.0,
-            checkpoint_path=None, checkpoint_every=None,
-            resume_from=None, obs=None) -> CheckResult:
-        from ..analysis import preflight
-        preflight(self.spec, log=log)   # fail fast, before any dispatch
-        obs = RunObserver.ensure(obs, "paged", self.spec, log=log,
-                                 progress_every=progress_every)
-        obs.pipeline = self.pipe_window
-        obs.pack = self._pk is not None
-        obs.commit = self.commit
-        obs.symmetry = self._symmetry_on()
-        obs.bounds = self._bounds_doc()
-        obs.edges = self._edges_on
-        obs.por = self._por_doc()
-        self._obs_active = obs          # closes_observer finalizes it
-        spec = self.spec
-        self._reset_accounting()
-        self._por_kept = self._por_full = self._por_amp = 0
-        res = CheckResult()
-        t0 = time.time()
-        self._run_t0 = t0
-        obs.start(t0, backend=jax.default_backend(),
-                  resumed=resume_from is not None)
-        emit = obs.log
-        # pipelined dispatch window (ISSUE 4) over the PAGES of a level
-        # (ISSUE 38): while a page runs, the next one is cut, put and
-        # launched behind it, chained on device-side (start_t, nn)
-        # scalars; what is queued behind a pause runs no tile
-        # (`_page_start`) and is dropped as a replay that committed
-        # nothing (engine/pipeline.py).  Nothing is launched past a
-        # level's last page.  Made as the run starts: its unfed clock
-        # counts the set-up
-        from .pipeline import DispatchPipeline
-        pipe = DispatchPipeline(self.pipe_window, obs,
-                                ready=lambda o: o["reason"])
+    # ------------------------------------------------------------------
+    # DeviceBFS.run's steps over a host-paged frontier
+    # ------------------------------------------------------------------
+    def _observer(self, obs, **kw):
+        return RunObserver.ensure(obs, "paged", self.spec, **kw)
 
+    def _open_run(self, log, progress_every, obs, **asked):
+        run = super()._open_run(log, progress_every, obs, **asked)
+        self._run_t0 = run.t0
         self.spill_count = 0     # drains triggered by a full buffer
         self.spill_rows = 0      # total rows paged out to host
         self._run_page_shapes = set()   # of this run's pages
@@ -393,121 +381,57 @@ class PagedBFS(DeviceBFS):
             # (ISSUE 15); fresh per run like the level blocks
             self.edge_sink = EdgeCSR(spill_dir=self._edge_spill_dir,
                                      ram_rows=self._edge_ram_rows,
-                                     obs=obs)
+                                     obs=run.obs)
             self._edge_rows_total = 0
             self._edge_hw = 0
+        return run
 
-        if resume_from is not None:
-            from .checkpoint import load_checkpoint, spec_digest
-            ck = load_checkpoint(resume_from,
-                                 expect_digest=spec_digest(spec),
-                                 log=emit)
-            if (ck.get("extra") or {}).get("sharded"):
-                raise TLAError("checkpoint was written by the sharded "
-                               "engine; resume it there")
-            # empty expand_mults (a converted sharded snapshot, see
-            # parallel.sharded_bfs.convert_sharded_snapshot): keep
-            # this engine's own per-action defaults
-            if ck["max_msgs"] != self.codec.shape.MAX_MSGS or \
-                    (ck["expand_mults"] and list(ck["expand_mults"])
-                     != list(self.expand_mults)):
-                if ck["expand_mults"]:
-                    self.expand_mults = list(ck["expand_mults"])
-                self._build(ck["max_msgs"])
-            self._check_bounds_manifest(ck, resume_from)
-            self._check_pack_manifest(ck, resume_from)
-            self._check_canon_manifest(ck, resume_from)
-            table = {"slots": jnp.asarray(ck["slots"])}
-            fp_cap = int(ck["slots"].shape[0])
-            # POR manifest policy (ISSUE 16): resuming under a flipped
-            # -por or changed independence facts is a loud error; on a
-            # matching resume the C3 level markers are rebuilt as
-            # zeros — at a level boundary every stored fingerprint is
-            # old, which reproduces the writer's decisions exactly
-            if self._por_active:
-                self._check_por_manifest(ck, resume_from)
-                table["gids"] = jnp.zeros((fp_cap,), jnp.int32)
-            elif ck.get("por"):
-                self._check_por_manifest(ck, resume_from)
-            if self._edges_on:
-                # edge-stream resume seam (ISSUE 15): the snapshot
-                # must carry the gid column and the drained edge rows
-                # up to its committed level — resuming a plain-BFS
-                # snapshot with edges on would leave every pre-resume
-                # state gid-less, so it is a policy error
-                if ck.get("gids") is None:
+    def _start_frontier(self, run, batch, n, ck=None):
+        """The first frontier stays on the host, in the at-rest row
+        format (snapshots load as dense planes, the engine-agnostic
+        interchange format); with edge emission a snapshot also gives
+        back the gid column, the edge rows drained up to its level and
+        the retained level blocks."""
+        if ck is not None and self._edges_on:
+            # edge-stream resume seam (ISSUE 15): resuming a plain-BFS
+            # snapshot with edges on would leave every pre-resume
+            # state gid-less, so it is a policy error
+            if ck.get("gids") is None:
+                raise TLAError(
+                    f"checkpoint {run.resume_from} was written "
+                    f"without the edge stream (no gid column); "
+                    f"resume with edges off, or restart the "
+                    f"temporal run from scratch")
+            run.table["gids"] = jnp.asarray(ck["gids"])
+            if ck.get("edges") is not None:
+                self.edge_sink.seed(ck["edges"])
+                self._edge_rows_total = self.edge_sink.rows
+            if self.retain_levels:
+                g = ck.get("graph")
+                sizes = [int(x) for x in ck["level_sizes"][:-1]]
+                have = (0 if g is None
+                        else int(next(iter(g.values())).shape[0]))
+                if have != sum(sizes):
                     raise TLAError(
-                        f"checkpoint {resume_from} was written "
-                        f"without the edge stream (no gid column); "
-                        f"resume with edges off, or restart the "
-                        f"temporal run from scratch")
-                table["gids"] = jnp.asarray(ck["gids"])
-                if ck.get("edges") is not None:
-                    self.edge_sink.seed(ck["edges"])
-                    self._edge_rows_total = self.edge_sink.rows
-                if self.retain_levels:
-                    g = ck.get("graph")
-                    sizes = [int(x) for x in ck["level_sizes"][:-1]]
-                    have = (0 if g is None
-                            else int(next(iter(g.values())).shape[0]))
-                    if have != sum(sizes):
-                        raise TLAError(
-                            f"checkpoint {resume_from} retains "
-                            f"{have} graph rows, the committed "
-                            f"levels hold {sum(sizes)} — snapshot "
-                            f"not written by a retain_levels run")
-                    off = 0
-                    for s in sizes:
-                        self.level_blocks.append(
-                            {k: v[off:off + s] for k, v in g.items()})
-                        off += s
-            self._init_dense = ck["init_dense"]
-            self._init_states = [self.codec.decode(d)
-                                 for d in ck["init_dense"]]
-            self._h_parent = [ck["h_parent"]]
-            self._h_action = [ck["h_action"]]
-            self._h_param = [ck["h_param"]]
-            self.level_sizes = list(ck["level_sizes"])
-            depth = ck["depth"]
-            fp_count = ck["fp_count"]
-            res.states_generated = ck["states_generated"]
-            t0 -= ck["elapsed"]
-            obs.set_epoch(t0)
-            n_front = ck["n_front"]
-            # snapshots load as dense planes (the engine-agnostic
-            # interchange format); pack them when packing is on
-            host_front = (self._pk.pack_np(
-                {k: np.asarray(v) for k, v in ck["frontier"].items()})
-                if self._pk is not None else
-                {k: np.asarray(v) for k, v in ck["frontier"].items()})
-            if self._spill_dir is not None:
-                # reload through the tier: a resumed frontier larger
-                # than the RAM budget spills right back to disk
-                host_front = self._tier(depth, host_front, obs)
-            level_base = sum(self.level_sizes[:-1])
-            emit(f"resumed from {resume_from}: depth {depth}, "
-                 f"{fp_count} distinct, frontier {n_front}")
-        else:
-            fp_cap = self.fpset_capacity
-            self.level_sizes = []  # no stale trajectory on init-viol
-            with obs.span(spans.INIT):
-                table, init_batch, n0, viol = self._register_init(res)
-            fp_count = n0
-            if viol is not None:
-                return self._finish(res, obs, fp_count,
-                                    table=table, fp_cap=fp_cap)
-            init_rows = {k: init_batch[k][:n0].astype(np.int32)
-                         for k in init_batch}
-            host_front = (self._pk.pack_np(init_rows)
-                          if self._pk is not None else init_rows)
-            if self._spill_dir is not None:
-                host_front = self._tier(0, host_front, obs)
-            n_front = n0
-            level_base = 0
-            depth = 0
-            self.level_sizes = [n0]
+                        f"checkpoint {run.resume_from} retains "
+                        f"{have} graph rows, the committed "
+                        f"levels hold {sum(sizes)} — snapshot "
+                        f"not written by a retain_levels run")
+                off = 0
+                for s in sizes:
+                    self.level_blocks.append(
+                        {k: v[off:off + s] for k, v in g.items()})
+                    off += s
+        rows = {k: np.asarray(v[:n], np.int32) for k, v in batch.items()}
+        run.host_front = (self._pk.pack_np(rows)
+                          if self._pk is not None else rows)
+        if self._spill_dir is not None:
+            # through the tier: a frontier larger than the RAM budget
+            # (a resumed one) spills right back to disk
+            run.host_front = self._tier(run.depth, run.host_front,
+                                        run.obs)
 
-        last_checkpoint = time.time()
+    def _open_levels(self, run):
         # the level kernel refuses to commit a tile unless the next
         # buffer has total_E rows of headroom, so total_E + one tile's
         # worth is the functional floor; size it larger (the default
@@ -517,502 +441,380 @@ class PagedBFS(DeviceBFS):
         # re-floored on every in-run rebuild — a stale floor live-locks
         # the drain loop (commit never true with an empty buffer).
         self._floor_next_cap()
-        with obs.span(spans.INIT):
-            bufs = self._alloc_bufs(self.next_cap)
+        with run.obs.span(spans.INIT):
+            run.bufs = self._alloc_bufs(self.next_cap)
         # edge append buffer (ISSUE 15): same total_E + one-tile floor
         # as the next buffer (the kernel refuses to commit a tile
         # without total_E triples of headroom); default sized 4x the
         # next buffer so R_EDGE_FLUSH drains stay block-sized
-        ebufs = None
-        n_edge = 0
         if self._edges_on:
             self.edge_cap = max(int(self._edge_capacity
                                     or 4 * self.next_cap),
                                 self._total_E() + self.tile)
-            ebufs = tuple(jnp.zeros((self.edge_cap,), I32)
-                          for _ in range(3))
-        stop = None
+            run.ebufs = tuple(jnp.zeros((self.edge_cap,), I32)
+                              for _ in range(3))
         # a host scalar as a dispatch's outputs are: int32, committed to
         # the table's device.  What is chained on a dispatch takes its
         # outputs where a first launch takes these, and an argument of
         # another kind is a program more — built inside a window whose
         # warm-up saw one-page levels only
-        home = next(iter(table["slots"].devices()))
+        run.home = next(iter(run.table["slots"].devices()))
+        run.zero, run.running = (self._scalar(run, 0),
+                                 self._scalar(run, RUNNING))
+
+    @staticmethod
+    def _scalar(run, x):
+        return jax.device_put(np.int32(x), run.home)
+
+    def _spill(self, run):
+        """Page the first n_next rows of the next buffers out to
+        host RAM and reset the counter.  Only with no real page
+        in flight: it reads the chain-tip buffers, which are the
+        last collected dispatch's (what is dropped behind a
+        pause committed nothing)."""
+        if run.n_next == 0:
+            return
+        obs, depth = run.obs, run.depth
+        if self.pipe_window > 1:
+            # the chain tip may still run (a dispatch dropped
+            # behind a pause): that wait is the device's work
+            run.pipe.wait(run.bufs[1])
+        with obs.span(spans.PAGE_OUT, depth=depth, rows=run.n_next):
+            pages = self._page_out(run.bufs, run.n_next)
+        # par is page-relative; lift to level-relative now (the
+        # newest collect left n_next, so the last seg ends there)
+        firsts, ends = zip(*run.segs)
+        lift = np.repeat(np.asarray(firsts, np.int64),
+                         np.diff((0,) + ends))
+        run.segs.clear()
+        row_bytes = self._state_row_bytes()
+        at = 0
+        for rows, par, act, prm in pages:
+            run.drained.append(rows)
+            run.d_par.append(par.astype(np.int64)
+                             + lift[at:at + len(par)])
+            at += len(par)
+            run.d_act.append(act)
+            run.d_prm.append(prm)
+            obs.spill(depth, len(par), len(par) * row_bytes)
+        run.n_next_total += run.n_next
+        self.spill_rows += run.n_next
+        run.n_next = 0
+
+    def _refloor_edges(self, run):
+        """Kernel rebuilt with (possibly) wider caps: drain
+        the plain-int triples, re-floor the append buffer
+        against the new total_E headroom requirement, and
+        re-zero it (a stale floor live-locks the commit gate,
+        exactly like the next_cap floor)."""
+        self._drain_edges(run)
+        self.edge_cap = max(self.edge_cap, self._total_E() + self.tile)
+        run.ebufs = tuple(jnp.zeros((self.edge_cap,), I32)
+                          for _ in range(3))
+
+    def _drain_edges(self, run):
+        """Drain the committed edge triples off the device
+        append buffer into the CSR builder (ISSUE 15).  As
+        `_spill`: with no real page in flight, off the chain
+        tip."""
+        n_edge = run.n_edge
+        if not self._edges_on or n_edge == 0:
+            return
+        es, ea, ed = run.ebufs
+        with run.obs.span(spans.HOST_SYNC):
+            s, a, d = jax.device_get(
+                (es[:n_edge], ea[:n_edge], ed[:n_edge]))
+        self.edge_sink.append(np.asarray(s), np.asarray(a),
+                              np.asarray(d))
+        self._edge_rows_total += n_edge
+        self._edge_hw = max(self._edge_hw, n_edge)
+        run.obs.edge_flush(run.depth, n_edge, n_edge * EdgeCSR.ROW_BYTES)
+        run.n_edge = 0
+
+    def _put_page(self, run, page):
+        block = self._front_block(run.host_front, page.start, page.n)
+        with run.obs.span(spans.PAGE_IN, depth=run.depth, rows=page.n):
+            page.dev = self._page_in(block, page.n)
+        run.obs.page_in(run.depth, page.n,
+                        page.n * self._state_row_bytes())
+
+    def _expand_level(self, run):
+        """One level of the host frontier: the window runs over its
+        PAGES (module docstring), the next buffer goes out to the host
+        when it fills, the edge buffer when it does.  Returns True
+        where the level ended the run with a verdict."""
+        obs, pipe, res = run.obs, run.pipe, run.res
+        emit = obs.log
+        depth, n_front, level_base = run.depth, run.n_front, run.level_base
+        if self.retain_levels:
+            # level blocks stay DENSE: the device liveness graph
+            # builder enumerates them as plane dicts
+            self.level_blocks.append(
+                self._pk.unpack_np(run.host_front)
+                if self._pk is not None else run.host_front)
+        # per-level host accumulators for drained next states and
+        # their (level-relative) trace pointers.  Disk tier:
+        # `drained` is a SpillTier — same .append seam, but pages
+        # beyond the RAM budget flush to level files
+        run.drained = (self._tier(depth, None, obs)
+                       if self._spill_dir is not None else [])
+        run.d_par, run.d_act, run.d_prm = [], [], []
+        run.n_next_total = 0
+        run.n_next = 0
+        # whose rows the next buffer holds: (first frontier row of a
+        # page, rows of the buffer up to that page's last collect),
+        # in commit order — the pages of a burst append to one
+        # buffer, and a trace pointer is relative to its own page
+        run.segs = []
+        zero, running = run.zero, run.running
 
         def scalar(x):
-            return jax.device_put(np.int32(x), home)
-        zero, running = scalar(0), scalar(RUNNING)
+            return self._scalar(run, x)
 
-        def pull(o):
-            keys = [o["reason"], o["t"], o["nn"], o["gen"],
-                    o["dist"], o["act"], o["need"], o["blk"],
-                    o.get("cpl", 0)]
-            if self._edges_on:
-                keys.append(o["edge_n"])
-            if self._por_active:
-                keys += [o["gfull"], o["amp"]]
-            return jax.device_get(keys + self._device_counts(o))
-
-        # the host between two units of device work (a chunk, a level)
-        # and before the first: open from here, or from a chunk's end,
-        # to the next launch
-        obs.boundary(depth=depth)
-        while n_front > 0 and stop is None:
-            if max_depth is not None and depth >= max_depth:
-                res.error = f"depth limit {max_depth} reached"
-                break
-            if self.retain_levels:
-                # level blocks stay DENSE: the device liveness graph
-                # builder enumerates them as plane dicts
-                self.level_blocks.append(
-                    self._pk.unpack_np(host_front)
-                    if self._pk is not None else host_front)
-            depth += 1
-            fault_point("level", depth=depth, obs=obs)
-            # per-level host accumulators for drained next states and
-            # their (level-relative) trace pointers.  Disk tier:
-            # `drained` is a SpillTier — same .append seam, but pages
-            # beyond the RAM budget flush to level files
-            drained = (self._tier(depth, None, obs)
-                       if self._spill_dir is not None else [])
-            d_par, d_act, d_prm = [], [], []
-            n_next_total = 0
-            n_next = 0
-            # whose rows the next buffer holds: (first frontier row of a
-            # page, rows of the buffer up to that page's last collect),
-            # in commit order — the pages of a burst append to one
-            # buffer, and a trace pointer is relative to its own page
-            segs = []
-
-            def spill():
-                """Page the first n_next rows of the next buffers out to
-                host RAM and reset the counter.  Only with no real page
-                in flight: it reads the chain-tip buffers, which are the
-                last collected dispatch's (what is dropped behind a
-                pause committed nothing)."""
-                nonlocal n_next_total, n_next
-                if n_next == 0:
-                    return
-                if self.pipe_window > 1:
-                    # the chain tip may still run (a dispatch dropped
-                    # behind a pause): that wait is the device's work
-                    pipe.wait(bufs[1])
-                with obs.span(spans.PAGE_OUT, depth=depth, rows=n_next):
-                    pages = self._page_out(bufs, n_next)
-                # par is page-relative; lift to level-relative now (the
-                # newest collect left n_next, so the last seg ends there)
-                firsts, ends = zip(*segs)
-                lift = np.repeat(np.asarray(firsts, np.int64),
-                                 np.diff((0,) + ends))
-                segs.clear()
-                row_bytes = self._state_row_bytes()
-                at = 0
-                for rows, par, act, prm in pages:
-                    drained.append(rows)
-                    d_par.append(par.astype(np.int64)
-                                 + lift[at:at + len(par)])
-                    at += len(par)
-                    d_act.append(act)
-                    d_prm.append(prm)
-                    obs.spill(depth, len(par), len(par) * row_bytes)
-                n_next_total += n_next
-                self.spill_rows += n_next
-                n_next = 0
-
-            def refloor_edges():
-                """Kernel rebuilt with (possibly) wider caps: drain
-                the plain-int triples, re-floor the append buffer
-                against the new total_E headroom requirement, and
-                re-zero it (a stale floor live-locks the commit gate,
-                exactly like the next_cap floor above)."""
-                nonlocal ebufs
-                drain_edges()
-                self.edge_cap = max(self.edge_cap,
-                                    self._total_E() + self.tile)
-                ebufs = tuple(jnp.zeros((self.edge_cap,), I32)
-                              for _ in range(3))
-
-            def drain_edges():
-                """Drain the committed edge triples off the device
-                append buffer into the CSR builder (ISSUE 15).  As
-                `spill`: with no real page in flight, off the chain
-                tip."""
-                nonlocal n_edge
-                if not self._edges_on or n_edge == 0:
-                    return
-                es, ea, ed = ebufs
-                with obs.span(spans.HOST_SYNC):
-                    s, a, d = jax.device_get(
-                        (es[:n_edge], ea[:n_edge], ed[:n_edge]))
-                self.edge_sink.append(np.asarray(s), np.asarray(a),
-                                      np.asarray(d))
-                self._edge_rows_total += n_edge
-                self._edge_hw = max(self._edge_hw, n_edge)
-                obs.edge_flush(depth, n_edge,
-                               n_edge * EdgeCSR.ROW_BYTES)
-                n_edge = 0
-
-            def put(page):
-                block = self._front_block(host_front, page.start, page.n)
-                with obs.span(spans.PAGE_IN, depth=depth, rows=page.n):
-                    page.dev = self._page_in(block, page.n)
-                obs.page_in(depth, page.n,
-                            page.n * self._state_row_bytes())
-
-            cc = self._chunk_cap()
-            next_row = 0        # the first frontier row no page holds yet
-            # pages to launch before any new one is cut, oldest first: a
-            # paused page (it re-enters at `resume_t`) and what was
-            # queued behind it
-            todo = deque()
-            resume_t = 0
-            flying = deque()    # the pages of the window's dispatches
-            # the newest dispatch's output and its page's tiles, while
-            # the next launch chains on it; None where the host knows
-            # the scalars (a level's first page, after a pause)
-            tip = None
-            while True:
-                while stop is None and pipe.has_room() and (
-                        todo or next_row < n_front):
-                    if todo:
-                        page = todo.popleft()
-                    else:
-                        n = min(cc, n_front - next_row)
-                        page = _Page(next_row, n, -(-n // self.tile))
-                        next_row += n
-                    if page.dev is None:
-                        put(page)
-                    if tip is None:
-                        pend_t = _page_start(
-                            zero, running, zero,
-                            scalar(resume_t), scalar(page.tiles))
-                        pend_nn = scalar(n_next)
-                        pend_en = scalar(n_edge)
-                    else:
-                        # behind a dispatch the host has not read: the
-                        # device decides whether this one runs
-                        prev, prev_tiles = tip
-                        pend_t = _page_start(
-                            prev["t"], prev["reason"],
-                            scalar(prev_tiles), zero,
-                            scalar(page.tiles))
-                        pend_nn = prev["nn"]
-                        pend_en = prev.get("edge_n")
-                        if pipe.in_flight:
-                            obs.count("pages_ahead")
-                    nb, nbp, nba, nbprm = bufs
-                    eb_arg, emeta_arg = None, None
-                    if self._edges_on:
-                        # gid_base maps a next-buffer row to its
-                        # global gid (spilled rows precede the
-                        # buffer); src_base lifts a page row to its
-                        # frontier gid.  gid_base is constant within
-                        # a pipelined burst: spills only happen with
-                        # nothing in flight
-                        eb_arg = ebufs
-                        emeta_arg = {
-                            "n": pend_en,
-                            "src_base": jnp.asarray(
-                                level_base + page.start, I32),
-                            "gid_base": jnp.asarray(
-                                level_base + n_front
-                                + n_next_total, I32)}
-                    out = pipe.launch(
-                        self._run_level, table, page.dev,
-                        jnp.asarray(page.n, I32), pend_t,
-                        nb, nbp, nba, nbprm, pend_nn,
-                        jnp.asarray(bool(check_deadlock)),
-                        eb_arg, emeta_arg,
-                        jnp.asarray(depth - 1, I32),
-                        fresh=self._fresh_jit,
-                        depth=depth)
-                    self._fresh_jit = False
-                    table = {"slots": out["slots"]}
-                    if self._por_active:
-                        table["gids"] = out["gids"]
-                    bufs = (out["nb"], out["nbp"], out["nba"],
-                            out["nbprm"])
-                    if self._edges_on:
-                        table["gids"] = out["gids"]
-                        ebufs = (out["eb_src"], out["eb_aid"],
-                                 out["eb_dst"])
-                    resume_t = 0
-                    tip = (out, page.tiles)
-                    flying.append(page)
-                if not flying:
-                    break       # the level's last page, or a stop
-                page = flying.popleft()
-                out, sc = pipe.collect(pull)
-                reason, start_t, n_next, gen_add, dist_add = (
-                    int(x) for x in sc[:5])
-                res.states_generated += gen_add
-                fp_count += dist_add
-                self._act_counts += np.asarray(sc[5], np.int64)
-                self._fold_need(sc[6])
-                self._account_blocks(sc[7], sc[8])
+        cc = self._chunk_cap()
+        next_row = 0        # the first frontier row no page holds yet
+        # pages to launch before any new one is cut, oldest first: a
+        # paused page (it re-enters at `resume_t`) and what was
+        # queued behind it
+        todo = deque()
+        resume_t = 0
+        flying = deque()    # the pages of the window's dispatches
+        # the newest dispatch's output and its page's tiles, while
+        # the next launch chains on it; None where the host knows
+        # the scalars (a level's first page, after a pause)
+        tip = None
+        while True:
+            while run.stop is None and pipe.has_room() and (
+                    todo or next_row < n_front):
+                if todo:
+                    page = todo.popleft()
+                else:
+                    n = min(cc, n_front - next_row)
+                    page = _Page(next_row, n, -(-n // self.tile))
+                    next_row += n
+                if page.dev is None:
+                    self._put_page(run, page)
+                if tip is None:
+                    pend_t = _page_start(
+                        zero, running, zero,
+                        scalar(resume_t), scalar(page.tiles))
+                    pend_nn = scalar(run.n_next)
+                    pend_en = scalar(run.n_edge)
+                else:
+                    # behind a dispatch the host has not read: the
+                    # device decides whether this one runs
+                    prev, prev_tiles = tip
+                    pend_t = _page_start(
+                        prev["t"], prev["reason"],
+                        scalar(prev_tiles), zero,
+                        scalar(page.tiles))
+                    pend_nn = prev["nn"]
+                    pend_en = prev.get("edge_n")
+                    if pipe.in_flight:
+                        obs.count("pages_ahead")
+                nb, nbp, nba, nbprm = run.bufs
+                eb_arg, emeta_arg = None, None
                 if self._edges_on:
-                    n_edge = int(sc[9])
+                    # gid_base maps a next-buffer row to its
+                    # global gid (spilled rows precede the
+                    # buffer); src_base lifts a page row to its
+                    # frontier gid.  gid_base is constant within
+                    # a pipelined burst: spills only happen with
+                    # nothing in flight
+                    eb_arg = run.ebufs
+                    emeta_arg = {
+                        "n": pend_en,
+                        "src_base": jnp.asarray(
+                            level_base + page.start, I32),
+                        "gid_base": jnp.asarray(
+                            level_base + n_front
+                            + run.n_next_total, I32)}
+                out = pipe.launch(
+                    self._run_level, run.table, page.dev,
+                    jnp.asarray(page.n, I32), pend_t,
+                    nb, nbp, nba, nbprm, pend_nn,
+                    jnp.asarray(bool(run.check_deadlock)),
+                    eb_arg, emeta_arg,
+                    jnp.asarray(depth - 1, I32),
+                    fresh=self._fresh_jit,
+                    depth=depth)
+                self._fresh_jit = False
+                run.table = {"slots": out["slots"]}
                 if self._por_active:
-                    self._por_kept += gen_add
-                    self._por_full += int(sc[9])
-                    self._por_amp += int(sc[10])
-                self._fold_device_counts(sc)
-                segs.append((page.start, n_next))
+                    run.table["gids"] = out["gids"]
+                run.bufs = (out["nb"], out["nbp"], out["nba"],
+                            out["nbprm"])
+                if self._edges_on:
+                    run.table["gids"] = out["gids"]
+                    run.ebufs = (out["eb_src"], out["eb_aid"],
+                                 out["eb_dst"])
+                resume_t = 0
+                tip = (out, page.tiles)
+                flying.append(page)
+            if not flying:
+                break       # the level's last page, or a stop
+            page = flying.popleft()
+            out, reason, start_t, run.n_next, n_edge = self._collect(run)
+            if self._edges_on:
+                run.n_edge = n_edge
+            run.segs.append((page.start, run.n_next))
 
-                if reason == RUNNING and start_t >= page.tiles:
-                    # the page is done; what is queued behind it is
-                    # the next page, running: on a stop it is
-                    # collected and counted like this one
-                    self._account_tiles(page.tiles)
-                    obs.boundary(depth=depth, chunk=page.start // cc)
-                    obs.progress(depth=depth, distinct=fp_count,
-                                 generated=res.states_generated,
-                                 frontier=n_front, extra="host-paged")
-                    if max_seconds and time.time() - t0 > max_seconds:
-                        stop = f"time budget {max_seconds}s reached"
-                    continue
-                # pause/terminal: what is queued behind ran no tile —
-                # drop it, keep its pages for a second launch, and
-                # handle the pause on the chain-tip table/buffers
-                void = pipe.drain()
-                if void:
-                    obs.count("pages_ahead_void", void)
-                todo.extendleft(reversed([page, *flying]))
-                flying.clear()
-                resume_t, tip = start_t, None
-                if reason == R_VIOLATION:
-                    vp, va, vprm = (int(v)
-                                    for v in np.asarray(out["viol"]))
-                    gid = level_base + page.start + vp
-                    parent_dense = self._host_row(
-                        host_front, page.start + vp)
-                    vstate = self._materialize_one(
-                        parent_dense, va, vprm)
-                    bad = spec.check_invariants(
-                        self.codec.decode(vstate))
-                    if bad is None:
-                        raise TLAError(
-                            "device/interpreter divergence: device "
-                            "invariant kernel reported a violation "
-                            "the interpreter accepts (parent gid "
-                            f"{gid}, action "
-                            f"{self.kern.action_names[va]})")
-                    res.ok = False
-                    res.violated_invariant = bad
-                    res.trace = self._trace(gid, extra=(va, vprm))
-                    res.diameter = depth
-                    return self._finish(res, obs, fp_count,
-                                        table=table, fp_cap=fp_cap)
-                elif reason == R_SLOT_ERR:
-                    raise TLAError(slot_error(self.codec))
-                elif reason == R_DEADLOCK:
-                    di = int(out["dead"])
-                    gid = level_base + page.start + di
-                    res.ok = False
-                    res.error = "deadlock"
-                    res.deadlock_state = self.codec.decode(
-                        self._host_row(host_front, page.start + di))
-                    res.trace = self._trace(gid)
-                    res.diameter = depth
-                    return self._finish(res, obs, fp_count,
-                                        table=table, fp_cap=fp_cap)
-                elif stop is not None:
-                    # a page collected behind a stop paused: what it
-                    # committed is counted, and nothing re-enters
-                    break
-                elif reason == R_NEXT_GROW:
-                    # the spill tier: page the filled buffer out to
-                    # host RAM instead of growing it in HBM
-                    self.spill_count += 1
-                    spill()
-                elif reason == R_EDGE_FLUSH:
-                    # edge append buffer full (ISSUE 15): drain the
-                    # committed triples into the CSR builder and
-                    # re-enter — the edge analog of the spill above
-                    drain_edges()
-                elif reason == R_BAG_GROW:
-                    old = self.codec.shape.MAX_MSGS
-                    spill()
-                    old_pk = self._pk
-                    self._build(old * 2)
-                    obs.grow("message_table",
-                             self.codec.shape.MAX_MSGS)
-                    if old_pk is not None:
-                        # packed pages: round-trip through the OLD
-                        # spec to dense, pad, re-pack under the
-                        # rebuilt one (see DeviceBFS._grow_msgs)
-                        def regrow(rows):
-                            d = self.codec.pad_msgs(
-                                old_pk.unpack_np(rows), old)
-                            return self._pk.pack_np(d)
-                    else:
-                        def regrow(rows):
-                            return self.codec.pad_msgs(rows, old)
-                    if self._spill_dir is not None:
-                        host_front.map_pages(regrow)
-                        drained.map_pages(regrow)
-                    else:
-                        host_front = regrow(host_front)
-                        drained = [regrow(d) for d in drained]
-                    self.level_blocks = [
-                        self.codec.pad_msgs(b, old)
-                        for b in self.level_blocks]
-                    self._pad_init_dense(old)
-                    self._floor_next_cap()
-                    bufs = self._alloc_bufs(self.next_cap)
-                    if self._edges_on:
-                        refloor_edges()
-                    for held in todo:   # cut again, at the new width
-                        held.dev = None
-                    emit(f"message table grown to "
-                         f"{self.codec.shape.MAX_MSGS} slots "
-                         f"(recompiling)")
-                elif reason == R_FPSET_GROW:
-                    table = grow(table)
-                    fp_cap *= 4
-                    self._fresh_jit = True   # shape change
-                    obs.grow("fpset", fp_cap)
-                    emit(f"FPSet grown to {fp_cap} slots")
-                elif reason == R_EXPAND_GROW:
-                    self._grow_expand(int(out["grow_aid"]), obs,
-                                      emit)
-                    if self.next_cap < self._total_E() + self.tile:
-                        spill()
-                        self._floor_next_cap()
-                        bufs = self._alloc_bufs(self.next_cap)
-                    if self._edges_on and self.edge_cap < \
-                            self._total_E() + self.tile:
-                        refloor_edges()
-                # a growth pause (or a dispatch that ran out of tiles
-                # to run, which re-enters as it is) falls through here
-                obs.progress(depth=depth, distinct=fp_count,
+            if reason == RUNNING and start_t >= page.tiles:
+                # the page is done; what is queued behind it is
+                # the next page, running: on a stop it is
+                # collected and counted like this one
+                self._account_tiles(page.tiles)
+                obs.boundary(depth=depth, chunk=page.start // cc)
+                obs.progress(depth=depth, distinct=run.fp_count,
                              generated=res.states_generated,
                              frontier=n_front, extra="host-paged")
-                if max_seconds and time.time() - t0 > max_seconds:
-                    stop = f"time budget {max_seconds}s reached"
-                    break
-            # level done (or stopped): page out what accumulated, and
-            # drain the committed edge triples (a level boundary
-            # always finds both buffers empty)
-            obs.boundary(depth=depth)
-            if todo:
-                # a stop behind a pause: the tiles the page had done
-                self._account_tiles(resume_t)
-            spill()
-            drain_edges()
+                run.out_of_time(time.time())
+                continue
+            # pause/terminal: what is queued behind ran no tile —
+            # drop it, keep its pages for a second launch, and
+            # handle the pause on the chain-tip table/buffers
+            void = pipe.drain()
+            if void:
+                obs.count("pages_ahead_void", void)
+            todo.extendleft(reversed([page, *flying]))
+            flying.clear()
+            resume_t, tip = start_t, None
+            if self._verdict(run, reason, out, page.start,
+                             lambda i: self._host_row(run.host_front, i)):
+                return True
+            if run.stop is not None:
+                # a page collected behind a stop paused: what it
+                # committed is counted, and nothing re-enters
+                break
+            if reason == R_NEXT_GROW:
+                # the spill tier: page the filled buffer out to
+                # host RAM instead of growing it in HBM
+                self.spill_count += 1
+                self._spill(run)
+            elif reason == R_EDGE_FLUSH:
+                # edge append buffer full (ISSUE 15): drain the
+                # committed triples into the CSR builder and
+                # re-enter — the edge analog of the spill above
+                self._drain_edges(run)
+            elif reason == R_BAG_GROW:
+                self._spill(run)
+                self._grow_bag(run)
+                for held in todo:   # cut again, at the new width
+                    held.dev = None
+                emit(f"message table grown to "
+                     f"{self.codec.shape.MAX_MSGS} slots "
+                     f"(recompiling)")
+            elif reason == R_FPSET_GROW:
+                self._grow_fpset(run)
+            elif reason == R_EXPAND_GROW:
+                self._grow_expand(int(out["grow_aid"]), obs, emit)
+                if self.next_cap < self._total_E() + self.tile:
+                    self._spill(run)
+                    self._floor_next_cap()
+                    run.bufs = self._alloc_bufs(self.next_cap)
+                if self._edges_on and self.edge_cap < \
+                        self._total_E() + self.tile:
+                    self._refloor_edges(run)
+            # a growth pause (or a dispatch that ran out of tiles
+            # to run, which re-enters as it is) falls through here
+            obs.progress(depth=depth, distinct=run.fp_count,
+                         generated=res.states_generated,
+                         frontier=n_front, extra="host-paged")
+            if run.out_of_time(time.time()):
+                break
+        if todo:
+            # a stop behind a pause: the tiles the page had done
+            self._account_tiles(resume_t)
+        return False
 
-            # ---- level complete: assemble next frontier on host ------
-            obs.level_done(depth, frontier=n_front, distinct=fp_count,
-                           generated=res.states_generated)
-            if n_next_total:
-                if self._spill_dir is not None:
-                    host_next = drained       # the tier holds the rows
-                elif self._pk is not None:
-                    host_next = np.concatenate(drained)
-                else:
-                    host_next = {k: np.concatenate(
-                        [d[k] for d in drained]) for k in host_front}
-                self._h_parent.append(
-                    np.concatenate(d_par) + level_base)
-                self._h_action.append(np.concatenate(d_act))
-                self._h_param.append(np.concatenate(d_prm))
-                self.level_sizes.append(n_next_total)
-            else:
-                host_next = (drained if self._spill_dir is not None
-                             else self._host_zero(0))
-            level_base += n_front
+    def _grow_bag(self, run):
+        """R_BAG_GROW with the next buffer paged out: rebuild at twice
+        the message table, and pad every row the host holds."""
+        old = self.codec.shape.MAX_MSGS
+        old_pk = self._pk
+        self._build(old * 2)
+        run.obs.grow("message_table", self.codec.shape.MAX_MSGS)
+        if old_pk is not None:
+            # packed pages: round-trip through the OLD
+            # spec to dense, pad, re-pack under the
+            # rebuilt one (see DeviceBFS._grow_msgs)
+            def regrow(rows):
+                d = self.codec.pad_msgs(old_pk.unpack_np(rows), old)
+                return self._pk.pack_np(d)
+        else:
+            def regrow(rows):
+                return self.codec.pad_msgs(rows, old)
+        if self._spill_dir is not None:
+            run.host_front.map_pages(regrow)
+            run.drained.map_pages(regrow)
+        else:
+            run.host_front = regrow(run.host_front)
+            run.drained = [regrow(d) for d in run.drained]
+        self.level_blocks = [self.codec.pad_msgs(b, old)
+                             for b in self.level_blocks]
+        self._pad_init_dense(old)
+        self._floor_next_cap()
+        run.bufs = self._alloc_bufs(self.next_cap)
+        if self._edges_on:
+            self._refloor_edges(run)
+
+    def _close_level(self, run):
+        # level done (or stopped): page out what accumulated, and
+        # drain the committed edge triples (a level boundary
+        # always finds both buffers empty)
+        self._spill(run)
+        self._drain_edges(run)
+
+    def _hand_over(self, run):
+        """Assemble the next frontier on the host from what the level
+        paged out, lift its pointers to gids, drop the level it came
+        from."""
+        host_front, drained = run.host_front, run.drained
+        if run.n_next_total:
             if self._spill_dir is not None:
-                # the consumed level's files are dead weight now:
-                # steady-state disk holds two levels' worth of rows
-                host_front.drop()
-            host_front = host_next
-            n_front = n_next_total
+                host_next = drained       # the tier holds the rows
+            elif self._pk is not None:
+                host_next = np.concatenate(drained)
+            else:
+                host_next = {k: np.concatenate(
+                    [d[k] for d in drained]) for k in host_front}
+            self._h_parent.append(
+                np.concatenate(run.d_par) + run.level_base)
+            self._h_action.append(np.concatenate(run.d_act))
+            self._h_param.append(np.concatenate(run.d_prm))
+            self.level_sizes.append(run.n_next_total)
+        else:
+            host_next = (drained if self._spill_dir is not None
+                         else self._host_zero(0))
+        run.level_base += run.n_front
+        if self._spill_dir is not None:
+            # the consumed level's files are dead weight now:
+            # steady-state disk holds two levels' worth of rows
+            host_front.drop()
+        run.host_front = host_next
+        run.n_front = run.n_next_total
 
-            if stop:
-                res.error = stop
-                break
-            # fused commit: shrink the expansion caps onto the exact
-            # observed maxima (window drained at the level boundary)
-            self._calibrate_caps(obs, emit, n_front)
-            # pending preemption forces a rescue snapshot at this
-            # boundary regardless of cadence (see device_bfs)
-            rescue = preempt_signal() if n_front else None
-            if checkpoint_path and n_front and (
-                    rescue is not None
-                    or checkpoint_every is None
-                    or time.time() - last_checkpoint >= checkpoint_every):
-                from .checkpoint import (FORMAT_VERSION, save_checkpoint,
-                                         spec_digest)
-                from .spill import SpillTier
-                # disk-tiered frontier: STREAM pages into the staged
-                # npz (peak residency = one page) instead of
-                # materializing n_front dense rows (ISSUE 13 satellite
-                # — the PR 11 save_checkpoint residual)
-                fr_kw = (
-                    {"frontier_blocks":
-                     self._front_dense_blocks(host_front, n_front)}
-                    if isinstance(host_front, SpillTier) else
-                    self._snapshot_frontier(host_front, n_front))
-                if self._edges_on:
-                    # edge-stream seam (ISSUE 15): the gid column,
-                    # the drained edge rows up to this committed
-                    # level, and — on a retain_levels (temporal) run
-                    # — the retained level blocks, so a SIGTERM'd
-                    # temporal run resumes to a bit-identical CSR
-                    fr_kw["gids"] = np.asarray(table["gids"])
-                    fr_kw["edge_blocks"] = self.edge_sink.blocks()
-                    if self.retain_levels:
-                        fr_kw["graph_blocks"] = iter(
-                            self.level_blocks)
-                with obs.span(spans.CHECKPOINT, depth=depth):
-                    staged = save_checkpoint(
-                        checkpoint_path,
-                        slots=table["slots"],
-                        n_front=n_front,
-                        **fr_kw,
-                        h_parent=np.concatenate(self._h_parent),
-                        h_action=np.concatenate(self._h_action),
-                        h_param=np.concatenate(self._h_param),
-                        init_dense=self._init_dense,
-                        level_sizes=self.level_sizes, depth=depth,
-                        fp_count=fp_count,
-                        states_generated=res.states_generated,
-                        max_msgs=self.codec.shape.MAX_MSGS,
-                        expand_mults=self.expand_mults,
-                        elapsed=time.time() - t0,
-                        digest=spec_digest(spec),
-                        pack=self._pack_manifest(),
-                        canon=self._canon_manifest(),
-                        bounds=self._bounds_manifest(),
-                        por=self._por_manifest(), obs=obs)
-                last_checkpoint = time.time()
-                obs.checkpoint(checkpoint_path, depth, fp_count, staged,
-                               FORMAT_VERSION)
-                emit(f"checkpoint written to {checkpoint_path} "
-                     f"(depth {depth}, {fp_count} distinct)")
-            if rescue is not None:
-                obs.rescue(checkpoint_path or "", depth, fp_count,
-                           rescue)
-                emit(f"preempted by {rescue}: rescue snapshot at depth "
-                     f"{depth} ({checkpoint_path}); exiting resumable")
-                raise Preempted(checkpoint_path, depth, fp_count,
-                                rescue)
-            if n_front == 0:
-                break
-            if max_states and fp_count >= max_states:
-                res.error = f"state limit {max_states} reached"
-                break
-            if fp_count > 0.5 * fp_cap:
-                table = grow(table)
-                fp_cap *= 4
-                self._fresh_jit = True       # shape change
-                obs.grow("fpset", fp_cap)
-                emit(f"FPSet grown to {fp_cap} slots")
-
-        res.diameter = depth
-        return self._finish(res, obs, fp_count,
-                            table=table, fp_cap=fp_cap)
-
+    def _snapshot_keywords(self, run):
+        from .spill import SpillTier
+        # disk-tiered frontier: STREAM pages into the staged
+        # npz (peak residency = one page) instead of
+        # materializing n_front dense rows (ISSUE 13 satellite
+        # — the PR 11 save_checkpoint residual)
+        kw = ({"frontier_blocks":
+               self._front_dense_blocks(run.host_front, run.n_front)}
+              if isinstance(run.host_front, SpillTier) else
+              self._snapshot_frontier(run.host_front, run.n_front))
+        if self._edges_on:
+            # edge-stream seam (ISSUE 15): the gid column,
+            # the drained edge rows up to this committed
+            # level, and — on a retain_levels (temporal) run
+            # — the retained level blocks, so a SIGTERM'd
+            # temporal run resumes to a bit-identical CSR
+            kw["gids"] = np.asarray(run.table["gids"])
+            kw["edge_blocks"] = self.edge_sink.blocks()
+            if self.retain_levels:
+                kw["graph_blocks"] = iter(self.level_blocks)
+        return kw
 
     def _final_gauges(self, res, obs, fp_count, table, fp_cap):
         obs.count("page_shapes", len(self._run_page_shapes))

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..engine.checked import CheckedModel, of_model
 from ..engine.device_bfs import grown_caps, slot_error, static_cap
 from ..engine.fpset import dedup_batch, insert_core
 from ..obs import closes_observer, spans
@@ -197,8 +198,7 @@ def convert_sharded_snapshot(path, spec, log=None):
 #
 # Trace pointers (parent gid, action, lane param) ride with the state
 # rows; the host keeps only those per level (10 B/state) and
-# reconstructs counterexamples by replaying the recorded action chain —
-# exactly the single-device DeviceBFS protocol.
+# `CheckedModel.trace` replays the recorded action chain.
 
 RUNNING = 0
 R_VIOLATION = 2
@@ -654,17 +654,28 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
 class ShardedBFS:
     """Host driver: run the sharded level kernel to fixpoint.
 
-    The multi-chip counterpart of engine.device_bfs.DeviceBFS — same
-    pause/grow/re-enter protocol, same host-side trace-pointer store and
-    replay-based counterexample reconstruction; the frontier and the
-    fingerprint set are hash-partitioned over the mesh axis and states
-    migrate to their owner in the in-level all_to_all."""
+    What is checked (codec, kernel, lever specs, snapshot manifests and
+    their refusals, trace replay) is the one-chip engines' own
+    `CheckedModel` (engine/checked.py).  The host loop is this
+    engine's: every decision in it is rank-agreed (`agree`), its
+    frontier is `[D, ...]`, its budget is tested between levels only;
+    the frontier and the fingerprint set are hash-partitioned over the
+    mesh axis and states migrate to their owner in the in-level
+    all_to_all."""
 
     # what a caller may have sized its capacities by, and names in
     # `requires`: the start of a run packs on the host the rows that
     # exist, never D x next_capacity (ISSUE 27; before it 131 s a
     # run() at 4 x 262,144 rows)
     PROVIDES = frozenset({"start_packs_live_rows"})
+
+    # what is checked lives on `self.model` (engine/checked.py); the
+    # names this file and the tests read it by
+    codec, kern = of_model("codec"), of_model("kern")
+    commit, _inv, _facts = (of_model("commit"), of_model("inv"),
+                            of_model("facts"))
+    _pk, _canon = of_model("pk"), of_model("canon")
+    _por, _por_active = of_model("por"), of_model("por_active")
 
     def __init__(self, spec, mesh: Mesh, axis: str = "d", max_msgs=None,
                  tile=32, bucket_cap=None, next_capacity=1 << 12,
@@ -674,29 +685,27 @@ class ShardedBFS:
                  sleep=time.sleep, pack="auto", commit="fused",
                  symmetry="auto", bounds="auto", por="off", requires=()):
         from ..core.values import TLAError
-        if commit not in ("fused", "per-action"):
-            raise TLAError(f"commit must be 'fused' or 'per-action' "
-                           f"(got {commit!r})")
         # refuse before anything is built: an engine without a
         # property the capacities were sized by would run, for minutes
         missing = sorted(set(requires) - self.PROVIDES)
         if missing:
             raise TLAError(f"this ShardedBFS does not provide {missing} "
                            f"(it provides {sorted(self.PROVIDES)})")
+        # the spec and the five levers as a codec, a kernel and the
+        # specs bound to them (engine/checked.py).  Edge emission
+        # (ISSUE 15) is a single-device paged seam: the key is
+        # journaled off.  The POR filter is the static one (the
+        # monotone-witness C3 proviso the owner-partitioned FPSet
+        # forces, see make_sharded_level), the canon spec runs inside
+        # the step, pre-bucketing
+        self.model = CheckedModel(
+            spec, model_factory, pack=pack, commit=commit,
+            symmetry=symmetry, bounds=bounds, por=por, sharded=True)
         self.spec = spec
         self.mesh = mesh
         self.axis = axis
         self.D = mesh.shape[axis]
         self.tile = tile
-        # streamed edge emission (ISSUE 15) is a single-device paged
-        # seam — the sharded engine journals the key as off
-        self._edges_on = False
-        # level-kernel commit mode (ISSUE 10): "fused" compacts each
-        # tile's enabled lanes through the guard matrix before
-        # expansion (occupancy-packed; exact-need cap growth);
-        # "per-action" is the step_all full-lane expansion.  Results
-        # are bit-identical between the two.
-        self.commit = commit
         self.expand_caps = None       # fused per-action caps (lanes)
         self._need_seen = None
         # bounded exponential-backoff budget for transient exchange
@@ -719,19 +728,6 @@ class ShardedBFS:
         # gone.  Semantics are identical at every K
         # (tests/test_pipeline.py).
         self.pipe_window = max(1, int(pipeline))
-        # packed frontier encoding (ISSUE 9): "auto" packs whenever the
-        # codec declares plane_bounds; False runs dense; True forces
-        # the interchange format (ratio 1.0 without bounds).  Results
-        # are bit-identical either way.
-        self._pack_req = pack
-        # symmetry canonicalization (ISSUE 11): "auto" = on iff the
-        # cfg declares SYMMETRY; the CanonSpec runs inside the sharded
-        # step, pre-bucketing (see make_sharded_level)
-        self._symmetry_req = symmetry
-        # model_factory(spec, max_msgs=..) -> (codec, kernel); default
-        # is the hand-kernel registry (DeviceBFS parity — tests drive
-        # the driver with stub kernels through this hook)
-        self._model_factory = model_factory
         # bucket_cap=None: occupancy-calibrated — start minimal and let
         # R_BUCKET_GROW converge to the run's high-water mark (wire
         # volume is cap-bound; see module docstring)
@@ -739,26 +735,7 @@ class ShardedBFS:
             else max(64, tile)
         self.N = next_capacity          # per-device frontier capacity
         self.fp_cap = fpset_capacity    # per-device FPSet slots
-        self.inv_names = list(spec.cfg.invariants)
         self._ckd = bool(check_deadlock)
-        self._mat = {}
-        # speclint bounds pre-pass (ISSUE 13): same consumption seam
-        # as DeviceBFS — dead-action pruning, tightened packing, exact
-        # fanout caps; see engine/bounds.resolve_bounds
-        from ..engine.bounds import resolve_bounds
-        self._facts = resolve_bounds(spec, bounds)
-        self._pruned = []
-        # ample-set partial-order reduction (ISSUE 16): same resolve
-        # contract as DeviceBFS (constructor default "off", CLI
-        # -por auto); the filter is rebuilt in _build with
-        # sharded=True — the static monotone-witness C3 proviso the
-        # owner-partitioned FPSet forces (see make_sharded_level)
-        from ..engine.por import resolve_por
-        self._por_facts = resolve_por(
-            spec, por,
-            temporal=bool(getattr(spec, "temporal_props", ())),
-            edges=False, commit=self.commit)
-        self._por = None
         self._por_kept = self._por_full = self._por_amp = 0
         self._build(max_msgs)
 
@@ -766,55 +743,7 @@ class ShardedBFS:
         from ..models import registry
         registry.ensure_compile_cache()
         registry.ensure_debug_flags()
-        factory = self._model_factory or (
-            lambda spec, max_msgs=None: registry.make_model(
-                spec, max_msgs=max_msgs, fold_symmetry=False))
-        self.codec, self.kern = factory(self.spec, max_msgs=max_msgs)
-        # statically dead actions (bounds pass): prune the kernel lane
-        # tables before the step builds its guard segments (ISSUE 13)
-        if self._facts is not None and self._facts.dead_actions:
-            from ..engine.bounds import prune_kernel
-            dead = [n for n in self._facts.dead_actions
-                    if n in self.kern.action_names]
-            if dead and len(dead) < len(self.kern.action_names):
-                self.kern = prune_kernel(self.kern, dead)
-                self._pruned = dead
-        self._inv = self.kern.invariant_fn(self.inv_names)
-        self._mat = {}
-        # symmetry canonicalization spec (rebuilt with the codec).
-        # A factory-supplied FOLDED kernel already owns the reduction:
-        # the canon seam stands down, and forcing -symmetry off is a
-        # loud error (see DeviceBFS._build)
-        from ..core.values import TLAError
-        from ..engine.canon import build_canon_spec, kernel_fold_order
-        self._sym_fold = kernel_fold_order(self.kern)
-        if self.spec.symmetry_perms and self._sym_fold > 1:
-            if self._symmetry_req is False:
-                raise TLAError(
-                    "symmetry=False requested but the model factory "
-                    "built a kernel with a FOLDED perm table; rebuild "
-                    "it with fold_symmetry=False "
-                    "(registry.make_model) to make -symmetry off real")
-            self._canon = None
-        else:
-            self._canon = build_canon_spec(self.spec, self.codec,
-                                           self.kern,
-                                           self._symmetry_req)
-        # packed-frontier spec for THIS codec binding (rebuilt with the
-        # codec on bag growth — MAX_MSGS changes the lane count)
-        from ..engine.pack import build_pack_spec
-        tighten = (self._facts.plane_tighten()
-                   if self._facts is not None else {})
-        if self._pack_req is False:
-            self._pk = None
-            self._pk_decl = None
-        else:
-            self._pk = build_pack_spec(self.codec, spec=self.spec,
-                                       force=self._pack_req is True,
-                                       tighten=tighten or None)
-            self._pk_decl = (build_pack_spec(
-                self.codec, spec=self.spec,
-                force=self._pack_req is True) if tighten else self._pk)
+        self.model.build(max_msgs)
         if self.commit == "fused":
             names = self.kern.action_names
             tl = [self.tile * self.kern._lane_count(n) for n in names]
@@ -835,27 +764,7 @@ class ShardedBFS:
             if self._need_seen is None or \
                     len(self._need_seen) != len(names):
                 self._need_seen = np.zeros(len(names), np.int64)
-        self._por = None
-        if self._por_facts is not None:
-            from ..engine.por import PORFilter
-            self._por = PORFilter(self._por_facts, self.kern,
-                                  sharded=True)
-        self._por_active = (self._por is not None
-                            and self._por.any_eligible
-                            and self.commit == "fused")
-        self._step = make_sharded_level(self.kern, self._inv, self.mesh,
-                                        self.axis, self.tile,
-                                        self.bucket_cap,
-                                        check_deadlock=self._ckd,
-                                        pack_spec=self._pk,
-                                        commit=self.commit,
-                                        expand_caps=self.expand_caps,
-                                        canon=self._canon,
-                                        por=(self._por
-                                             if self._por_active
-                                             else None))
-        self._fresh_jit = True   # first dispatch after a (re)jit is
-        #                          charged to the "compile" phase
+        self._make_step()
         # the start's two programs, built once per engine: a run()
         # of a built engine finds them compiled
         self._sharded_ins = make_sharded_insert(self.mesh, self.axis)
@@ -876,31 +785,27 @@ class ShardedBFS:
         # reshard to replicated first (parallel/multihost.py)
         self._pull = make_replicator(self.mesh)
 
-    # borrowed single-device helpers (same attribute contract)
-    from ..engine.device_bfs import DeviceBFS as _DB
-    _materialize_one = _DB._materialize_one
-    _trace = _DB._trace
-    _fetch_row = _DB._fetch_row
-    _pack_manifest = _DB._pack_manifest
-    _check_pack_manifest = _DB._check_pack_manifest
-    _pack_gauges = _DB._pack_gauges
-    _fp_batch = _DB._fp_batch
-    _canon_manifest = _DB._canon_manifest
-    _check_canon_manifest = _DB._check_canon_manifest
-    _symmetry_on = _DB._symmetry_on
-    _bounds_doc = _DB._bounds_doc
-    _bounds_manifest = _DB._bounds_manifest
-    _check_bounds_manifest = _DB._check_bounds_manifest
-    _bounds_gauges = _DB._bounds_gauges
-    _por_doc = _DB._por_doc
-    _por_manifest = _DB._por_manifest
-    _check_por_manifest = _DB._check_por_manifest
-    _por_gauges = _DB._por_gauges
+    def _make_step(self):
+        """The sharded step for the caps, the bucket and the levers as
+        they stand (`_build`, and a growth of a cap or the bucket)."""
+        self._step = make_sharded_level(
+            self.kern, self._inv, self.mesh, self.axis, self.tile,
+            self.bucket_cap, check_deadlock=self._ckd, pack_spec=self._pk,
+            commit=self.commit, expand_caps=self.expand_caps,
+            canon=self._canon,
+            por=self._por if self._por_active else None)
+        self._fresh_jit = True   # first dispatch after a (re)jit is
+        #                          charged to the "compile" phase
 
-    def _flush_pointers(self):
-        """No-op: the sharded driver's pointer pulls are synchronous
-        (they ride the per-level collective gather already)."""
-    del _DB
+    def _trace(self, gid, extra=None):
+        """The counterexample that ends at `gid` (and one step
+        `extra` past it), replayed from the host pointer table (the
+        pointer pulls are synchronous: nothing to flush)."""
+        return self.model.trace(
+            (np.concatenate(self._h_parent),
+             np.concatenate(self._h_action),
+             np.concatenate(self._h_param)),
+            self._init_states, gid, extra)
 
     def _put(self, arr, obs=None):
         """Host array -> sharded global array.  With `obs` (the puts a
@@ -1004,13 +909,7 @@ class ShardedBFS:
         preflight(self.spec, log=log)   # fail fast, before any dispatch
         obs = RunObserver.ensure(obs, "sharded", self.spec, log=log,
                                  progress_every=progress_every)
-        obs.pipeline = self.pipe_window
-        obs.pack = self._pk is not None
-        obs.commit = self.commit
-        obs.symmetry = self._symmetry_on()
-        obs.bounds = self._bounds_doc()
-        obs.edges = self._edges_on
-        obs.por = self._por_doc()
+        self.model.announce(obs, pipeline=self.pipe_window)
         self._obs_active = obs          # closes_observer finalizes it
         self._act_counts = np.zeros(len(self.kern.action_names),
                                     np.int64)
@@ -1049,16 +948,10 @@ class ShardedBFS:
         # are accumulated with the row size current at the time (the
         # codec — and so the state row — grows on R_BAG_GROW)
         def _row_bytes():
-            # state bytes as the wire actually moves them: packed words
-            # when the pack spec is bound (the exchange buckets carry
-            # packed rows), dense planes otherwise
-            if self._pk is not None:
-                state_b = self._pk.packed_bytes
-            else:
-                zero = self.codec.zero_state()
-                state_b = sum(int(np.prod(np.shape(v)) or 1) * 4
-                              for v in zero.values())
-            return state_b + 16 + 1 + 12      # + fps/mask/meta
+            # state bytes as the wire actually moves them (the
+            # exchange buckets carry packed rows where a pack spec is
+            # bound) + fps/mask/meta
+            return self.model.row_bytes() + 16 + 1 + 12
         exch_rows_useful = 0
         exch_rows_wire = 0
         exch_bytes_useful = 0
@@ -1100,19 +993,9 @@ class ShardedBFS:
                     ex["bucket_cap"] != self.bucket_cap:
                 self.bucket_cap = int(ex["bucket_cap"])
                 self._build(ck["max_msgs"])
-            # AFTER the max_msgs rebuild: the pack-spec version digests
-            # the lane count, so a snapshot from a grown-bag run only
-            # matches the spec rebuilt at ITS MAX_MSGS (DeviceBFS
-            # orders these the same way)
-            self._check_bounds_manifest(ck, resume_from)
-            self._check_pack_manifest(ck, resume_from)
-            self._check_canon_manifest(ck, resume_from)
-            # POR flip/digest policy (ISSUE 16): the explored state
-            # sets of a reduced and an unreduced run are not
-            # comparable (no level markers to rebuild here — the
-            # sharded C3 proviso is fully static)
-            if self._por_active or ck.get("por"):
-                self._check_por_manifest(ck, resume_from)
+            # AFTER the max_msgs rebuild (no POR level markers to
+            # rebuild here: the sharded C3 proviso is fully static)
+            self.model.check_manifests(ck, resume_from)
             rows = ck["frontier"]
             h_parent = np.asarray(ck["h_parent"])
             h_action = np.asarray(ck["h_action"])
@@ -1144,7 +1027,7 @@ class ShardedBFS:
                 # same global order as the trace tail)
                 # canonical fingerprints (when symmetry is on) so the
                 # re-route matches the live exchange's ownership rule
-                ffps = np.asarray(self._fp_batch(
+                ffps = np.asarray(self.model.fp_batch(
                     {k: np.asarray(v) for k, v in rows.items()}))
                 fowner = (np.asarray(route(jnp.asarray(ffps)))
                           % np.uint32(D)).astype(np.int64)
@@ -1213,7 +1096,7 @@ class ShardedBFS:
                 init_states = list(spec.init_states())
                 dense = [codec.encode(st) for st in init_states]
                 batch = {k: np.stack([d[k] for d in dense]) for k in dense[0]}
-                fps = np.asarray(self._fp_batch(batch))
+                fps = np.asarray(self.model.fp_batch(batch))
                 keep, seen = [], set()
                 for i in range(len(dense)):
                     t = tuple(fps[i])
@@ -1484,14 +1367,7 @@ class ShardedBFS:
                          f"{self.codec.shape.MAX_MSGS} (recompiling)")
                 elif reason == R_BUCKET_GROW:
                     self.bucket_cap *= 2
-                    self._step = make_sharded_level(
-                        self.kern, self._inv, self.mesh, self.axis,
-                        self.tile, self.bucket_cap,
-                        check_deadlock=self._ckd, pack_spec=self._pk,
-                        commit=self.commit,
-                        expand_caps=self.expand_caps,
-                        canon=self._canon)
-                    self._fresh_jit = True
+                    self._make_step()
                     obs.grow("exchange_bucket", self.bucket_cap)
                     emit(f"exchange bucket grown to {self.bucket_cap} "
                          f"(recompiling)")
@@ -1519,14 +1395,7 @@ class ShardedBFS:
                             self.expand_caps[a] * 2)
                         grown = [(self.kern.action_names[a],
                                   self.expand_caps[a])]
-                    self._step = make_sharded_level(
-                        self.kern, self._inv, self.mesh, self.axis,
-                        self.tile, self.bucket_cap,
-                        check_deadlock=self._ckd, pack_spec=self._pk,
-                        commit=self.commit,
-                        expand_caps=self.expand_caps,
-                        canon=self._canon)
-                    self._fresh_jit = True
+                    self._make_step()
                     for _n, cap in grown:
                         obs.grow("expand_buffer", cap)
                     emit("expand caps grown (headroom over the exact need): "
@@ -1639,10 +1508,7 @@ class ShardedBFS:
                             expand_mults=[],
                             elapsed=_time.time() - t0,
                             digest=spec_digest(spec),
-                            pack=self._pack_manifest(),
-                            canon=self._canon_manifest(),
-                            bounds=self._bounds_manifest(),
-                            por=self._por_manifest(), obs=obs,
+                            **self.model.manifests(), obs=obs,
                             extra={"sharded": True,
                                    "shard_counts": [int(x) for x in nn_h],
                                    "bucket_cap": self.bucket_cap,
@@ -1701,16 +1567,9 @@ class ShardedBFS:
                           levels=getattr(self, "level_sizes", None))
 
     def _final_gauges(self, res, obs, fp_count):
-        self._bounds_gauges(obs)
-        self._por_gauges(obs)
         res.distinct_states = fp_count
-        self._pack_gauges(obs)
-        obs.gauge("symmetry_perms",
-                  self._canon.perms if self._canon is not None
-                  else self._sym_fold)
-        if res.states_generated and fp_count:
-            obs.gauge("orbit_ratio",
-                      round(res.states_generated / fp_count, 4))
+        self.model.gauges(obs, res.states_generated, fp_count,
+                          (self._por_kept, self._por_full, self._por_amp))
         cap_total = self.fp_cap * self.D
         obs.gauge("fpset_capacity", cap_total)
         obs.gauge("fpset_occupancy",

@@ -263,8 +263,9 @@ def stub_device_engine(cls=None, spec=None, inv_bound=None,
     cls = cls or DeviceBFS
     return cls(spec or counter_spec(inv_bound,
                                     dead_action=dead_action),
-               model_factory=stub_model_factory(
-                   inv_bound=inv_bound, dead_action=dead_action),
+               model_factory=kw.pop("model_factory", None)
+               or stub_model_factory(inv_bound=inv_bound,
+                                     dead_action=dead_action),
                hash_mode="full", tile_size=kw.pop("tile_size", 4),
                fpset_capacity=kw.pop("fpset_capacity", 1 << 8),
                next_capacity=kw.pop("next_capacity", 1 << 6), **kw)
@@ -301,10 +302,24 @@ def stub_sharded_engine(n_devices=2, spec=None, inv_x_bound=None,
     mesh = Mesh(np.array(jax.devices()[:n_devices]), ("d",))
     return ShardedBFS(
         spec or counter_spec(inv_x_bound=inv_x_bound), mesh,
-        model_factory=stub_model_factory(inv_x_bound=inv_x_bound),
+        model_factory=kw.pop("model_factory", None)
+        or stub_model_factory(inv_x_bound=inv_x_bound),
         tile=kw.pop("tile", 4), bucket_cap=kw.pop("bucket_cap", 64),
         next_capacity=kw.pop("next_capacity", 1 << 6),
         fpset_capacity=kw.pop("fpset_capacity", 1 << 8), **kw)
+
+
+def stub_bfs_engine(engine, sym=False, **kw):
+    """One of the three BFS engines by name — "device", "paged" or
+    "sharded" (two virtual devices) — over the counter spec, or over
+    the SymPair fixture (`sym`): the harness of the tests that hold
+    every engine to one rule."""
+    if engine == "sharded":
+        make = stub_sym_sharded if sym else stub_sharded_engine
+        return make(n_devices=2, **kw)
+    from .engine.paged_bfs import PagedBFS
+    make = stub_sym_engine if sym else stub_device_engine
+    return make(cls=PagedBFS if engine == "paged" else None, **kw)
 
 
 def stub_fleet(spec=None, inv_bound=None, inv_x_bound=None,
