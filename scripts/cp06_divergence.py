@@ -1,6 +1,16 @@
 """Diagnose the CP06 device/interpreter invariant divergence hit by
 recovery_fixpoints (device flags a violation after
-ReceiveNewCheckpointMsg at parent gid ~1446; interpreter accepts)."""
+ReceiveNewCheckpointMsg at parent gid ~1446; interpreter accepts).
+
+Mended since (`CP06Kernel._op_of`, CP06:1219-1222;
+scripts/recovery_fixpoints.json: `engines_agree` true), so this script
+now ends with "no divergence reproduced"; it needs the `.tla`.  Without
+it the mended behaviour is held by tests/test_native_cp06.py: the plain
+reference (benchmark/tools/checkpoint_recovery_reference.py) meets the
+first such parent in the same level (6, numbers 705-1583 of its own
+breadth-first order), its ReceiveNewCheckpointMsg successor is flagged
+by a raw-log NoLogDivergence and accepted through OpOf by reference and
+kernel alike, and that parent is a root of the crafted subtree."""
 
 import os
 import sys

@@ -63,8 +63,12 @@ def _consts():
 
 # a module's layout at the constants its committed cfg binds, through
 # the native door (load_spec by name) instead of the hand-made dict
-NATIVE_DOOR = {"VR_STATE_TRANSFER:native-door": (
-    "VR_STATE_TRANSFER", "benchmark/configs/vr-state-transfer.cfg")}
+NATIVE_DOOR = {
+    "VR_STATE_TRANSFER:native-door": (
+        "VR_STATE_TRANSFER", "benchmark/configs/vr-state-transfer.cfg"),
+    "VR_REPLICA_RECOVERY_CP:native-door": (
+        "VR_REPLICA_RECOVERY_CP",
+        "benchmark/configs/vr-replica-recovery-cp.cfg")}
 
 
 def _layout_spec(mod, max_msgs=6):

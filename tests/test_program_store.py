@@ -140,8 +140,18 @@ def _changed_module(tmp_path, monkeypatch):
     return DeviceBFS(load_spec("VR_STATE_TRANSFER", str(cfg)))
 
 
+def _changed_module_cp06(tmp_path, monkeypatch):
+    """The third module through the native door,
+    VR_REPLICA_RECOVERY_CP, at its small cfg: a key of its own, and
+    not VR_STATE_TRANSFER's, whose kernel is its base class."""
+    eng = DeviceBFS(load_spec("VR_REPLICA_RECOVERY_CP", os.path.join(
+        REPO, "examples", "VR_REPLICA_RECOVERY_CP_small.cfg")))
+    assert _key(eng) != _key(_changed_module(tmp_path, monkeypatch))
+    return eng
+
+
 CHANGES = {f.__name__[len("_changed_"):]: f for f in (
-    _changed_module,
+    _changed_module, _changed_module_cp06,
     _changed_constant, _changed_invariant, _changed_tile, _changed_cap,
     _changed_commit_piece, _changed_next_capacity, _changed_hash_mode,
     _changed_commit, _changed_env, _changed_source, _changed_x64)}

@@ -30,8 +30,8 @@ import numpy as np
 from ..core.values import FnVal, TLAError
 from .rr05 import RR05Codec
 from .st03 import MSGTYPE_NAMES as ST03_MSGTYPE_NAMES
-from .vsr import (CP_NHDR, H_COMMIT, H_CP, H_DEST, H_FIRST, H_FLAG, H_OP, H_SRC,
-                  H_TYPE, H_VIEW, H_X)
+from .vsr import (CP_NHDR, H_COMMIT, H_CP, H_DEST, H_FIRST, H_FLAG, H_LNV,
+                  H_OP, H_SRC, H_TYPE, H_VIEW, H_X)
 
 M_RECOVERY, M_RECOVERYRESP = 8, 9          # same codes as RR05/AL05
 M_GETCP, M_NEWCP = 10, 11

@@ -51,6 +51,22 @@ REP_KEYS = RR05Kernel.REP_KEYS + (
 
 class CP06Kernel(RR05Kernel):
     action_names = ACTION_NAMES
+    # CP06's own line range of each action this file or SURVEY 2.1-2.2
+    # cites one for: the location a native spec prints for a
+    # counterexample step.  An inherited action whose CP06 lines no
+    # record gives has no entry and prints the generic location
+    # (models/native.py), never a base module's lines
+    ACTION_LINES = {
+        "SendDVC": (785, 816), "ReceiveHigherDVC": (825, 844),
+        "ReceiveMatchingDVC": (846, 862), "SendSV": (898, 937),
+        "ReceiveSV": (939, 971), "ReceiveGetState": (644, 680),
+        "ReceiveNewState": (682, 712), "Crash": (985, 1009),
+        "ReceiveGetCheckpointMsg": (1017, 1043),
+        "ReceiveNewCheckpointMsg": (1051, 1079),
+        "ReceiveRecoveryMsg": (1081, 1105),
+        "ReceiveRecoveryResponseMsg": (1107, 1121),
+        "CompleteRecovery": (1138, 1170),
+    }
     REP_KEYS = REP_KEYS
     MSG_KEYS = RR05Kernel.MSG_KEYS + ("m_cp",)
     PERM_REP_KEYS = ("log", "app", "dvc_log", "dvc_cp", "rec_log",
@@ -101,6 +117,26 @@ class CP06Kernel(RR05Kernel):
         row["cp"] = cp if cp is not None \
             else jnp.zeros((self.MAX_OPS,), I32)
         return row
+
+    #: ST03's four, then what this module adds (the same hook)
+    COMMIT_STATS = ST03Kernel.COMMIT_STATS + (
+        ("recovering_states", "sum"), ("gc_states", "sum"),
+        ("rec_set_peak", "max"), ("dvc_set_peak", "max"))
+
+    def commit_stats(self, st):
+        """[8] uint32 of one state: ST03's four, whether a replica is
+        Recovering, whether a replica's log has a garbage-collected
+        (NoOp) prefix (HighestGCedOp > 0), and the fullest
+        RecoveryResponse and DoViewChange receive-set, in records of
+        the R slots each has (one a source: a second record of one
+        source is ``ERR_REC_OVERFLOW`` / ``ERR_DVC_OVERFLOW``, which
+        stops a run)."""
+        mine = jnp.stack([
+            (st["status"] == RECOVERING).any(),
+            (st["log"] == self.NOOP).any(),
+            (st["rec"] == 1).sum(-1).max(),
+            (st["dvc"] == 1).sum(-1).max()]).astype(jnp.uint32)
+        return jnp.concatenate([super().commit_stats(st), mine])
 
     # ------------------------------------------------------------------
     # checkpoint helpers

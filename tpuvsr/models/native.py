@@ -30,12 +30,17 @@ decoded states.  This module provides exactly that facade:
   interpreter engine.
 
 ``engine.spec.load_spec`` resolves here when its spec argument is not
-an existing file but a module name the registry knows.  Two modules
-have a committed init trace today: VSR, and VR_STATE_TRANSFER (ST03,
-the base kernel of the analysis family; its trace holds entry 1 alone,
-the state ``ST03Codec.decode`` prints for the zero state in view 1,
-and its action locations are the line ranges the kernel cites).  The
-other six modules are refused by name until each has one.
+an existing file but a module name the registry knows.  Three modules
+have a committed init trace today: VSR, VR_STATE_TRANSFER (ST03, the
+base kernel of the analysis family) and VR_REPLICA_RECOVERY_CP (CP06,
+the family's last: crash with a checkpoint, log GC, recovery; its
+kernel runs AS04's and RR05's as base classes).  The two analysis
+traces hold entry 1 alone, the state the module's codec decodes for
+the zero state in view 1 (CP06's with its own planes: ``rep_app_state``,
+``rep_rec_number``, ``rep_rec_recv``, ``rep_recv_dvc``,
+``aux_restart``), and their action locations are the line ranges the
+kernel class itself cites.  The other five modules are refused by name
+until each has one.
 """
 
 from __future__ import annotations
@@ -57,6 +62,8 @@ INIT_TRACES = {
     "VSR": os.path.join(REPO, "examples", "found_violation_trace.txt"),
     "VR_STATE_TRANSFER": os.path.join(
         REPO, "examples", "VR_STATE_TRANSFER_init_trace.txt"),
+    "VR_REPLICA_RECOVERY_CP": os.path.join(
+        REPO, "examples", "VR_REPLICA_RECOVERY_CP_init_trace.txt"),
 }
 
 # module name -> cfg SYMMETRY definition name -> the constant set the
