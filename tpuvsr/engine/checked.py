@@ -335,6 +335,13 @@ class CheckedModel:
                     self.pk.total_bits:
                 ratio = self.pk_decl.total_bits / self.pk.total_bits
             obs.gauge("bound_tightening_ratio", round(ratio, 4))
+        # guard_table_lanes (ISSUE 47): the lanes of the actions whose
+        # guard the kernel declares as one table a state
+        # (`GUARD_TABLES`); 0 on a kernel that declares none
+        tables = getattr(self.kern, "GUARD_TABLES", ())
+        obs.gauge("guard_table_lanes", sum(
+            self.kern._lane_count(n) for n in self.kern.action_names
+            if n in tables))
         # por_cut_ratio / ample_states (ISSUE 16): generated kept /
         # generated full under the ample filter (1.0 when inert), and
         # how many expanded states took the shortcut with real work

@@ -23,14 +23,17 @@ Next, CP06:1186-1213).  Subclasses the RR05 kernel with:
 
 from __future__ import annotations
 
+import numpy as np
+
 import jax.numpy as jnp
 
 from .as04_kernel import AS04Kernel
 from .cp06 import M_GETCP, M_NEWCP, M_RECOVERY, M_RECOVERYRESP, CP06Codec
 from .rr05 import RECOVERING
 from .rr05_kernel import RR05Kernel
-from .st03 import (ANYDEST, M_DVC, M_GETSTATE, M_NEWSTATE, M_PREPAREOK,
-                   M_SV, M_SVC, NORMAL, STATETRANSFER, VIEWCHANGE)
+from .st03 import (ANYDEST, M_DVC, M_GETSTATE, M_NEWSTATE, M_PREPARE,
+                   M_PREPAREOK, M_SV, M_SVC, NORMAL, STATETRANSFER,
+                   VIEWCHANGE)
 from .st03_kernel import INF, I32, ST03Kernel
 from .vsr import (ERR_REC_OVERFLOW, H_COMMIT, H_CP, H_DEST, H_FIRST,
                   H_FLAG, H_LNV, H_OP, H_SRC, H_TYPE, H_VIEW, H_X)
@@ -49,8 +52,20 @@ REP_KEYS = RR05Kernel.REP_KEYS + (
     "dvc_cpn", "dvc_cp", "rec_flag", "rec_first", "rec_cp", "rec_cpn")
 
 
+def _lanes_of(table):
+    """The guard a lane that the engines call (`_guard_fns`), read off
+    the guard's table of the state: under a vmap over lanes the table
+    stays unbatched, so it is computed once a state."""
+    def guard(self, st, lane):
+        return table(self, st).reshape(-1)[lane]
+    return guard
+
+
 class CP06Kernel(RR05Kernel):
     action_names = ACTION_NAMES
+    #: the actions whose guard is one table a state (every one here):
+    #: what a run's gauge ``guard_table_lanes`` counts the lanes of
+    GUARD_TABLES = ACTION_NAMES
     # CP06's own line range of each action this file or SURVEY 2.1-2.2
     # cites one for: the location a native spec prints for a
     # counterexample step.  An inherited action whose CP06 lines no
@@ -188,6 +203,12 @@ class CP06Kernel(RR05Kernel):
     # ------------------------------------------------------------------
     # view change: checkpointed DVC / SV
     # ------------------------------------------------------------------
+    def act_receive_matching_svc(self, st, lane):  # AS04:589-607
+        # AS04's body takes its `en` from `guard_receive_matching_svc`,
+        # which is a table here; the oracle keeps AS04's guard a lane
+        s2, _en = ST03Kernel.act_receive_matching_svc(self, st, lane)
+        return s2, AS04Kernel.guard_receive_matching_svc(self, st, lane)
+
     def act_send_dvc(self, st, lane):             # CP06:785-816
         C = self.MAX_OPS + 1
         i = lane // C
@@ -215,17 +236,6 @@ class CP06Kernel(RR05Kernel):
                                    st["commit"][i], suffix, cp_plane, cp,
                                    pred=self_case & en)
         return s2, en
-
-    def guard_send_dvc(self, st, lane):
-        C = self.MAX_OPS + 1
-        i = lane // C
-        cp = lane % C
-        hgc = self._hgc(st["log"][i])
-        return (self._can_progress(st, i)
-                & (st["status"][i] == VIEWCHANGE)
-                & (st["sent_dvc"][i] == 0)
-                & (self._svc_tombstones(st, i) >= self.R // 2)
-                & (cp >= hgc + 1) & (cp <= st["commit"][i]))
 
     def _dvc_slot_add_cp(self, s2, i, j, lnv, op, commit, suffix,
                          cp_plane, cpn, pred):
@@ -406,10 +416,6 @@ class CP06Kernel(RR05Kernel):
         s2 = self._bag_send(s2, row)
         return s2, en
 
-    def guard_receive_get_state(self, st, lane):
-        en, _k, _i, _cp, _g = self._get_state_en(st, lane)
-        return en
-
     def act_receive_new_state(self, st, lane):    # CP06:682-712
         k = lane
         hdr = st["m_hdr"][k]
@@ -445,13 +451,6 @@ class CP06Kernel(RR05Kernel):
         s2 = self._bag_discard(s2, k)
         return s2, en
 
-    def guard_receive_new_state(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_NEWSTATE)
-                & self._can_progress(st, i)
-                & (st["status"][i] == STATETRANSFER)
-                & (st["view"][i] == st["m_hdr"][k, H_VIEW]))
-
     # ------------------------------------------------------------------
     # recovery: GetCheckpoint -> NewCheckpoint -> Recovery -> responses
     # ------------------------------------------------------------------
@@ -485,15 +484,6 @@ class CP06Kernel(RR05Kernel):
         s2 = self._bag_send(s2, row)
         return s2, en
 
-    def guard_crash(self, st, lane):
-        C = self.MAX_OPS + 1
-        i = lane // C
-        cp = lane % C
-        row = self._row(M_GETCP, dest=ANYDEST, src=i + 1)
-        return ((st["aux_restart"] < self.crash_limit)
-                & (cp <= st["commit"][i])
-                & ~self._row_eq(st, row).any())
-
     def act_receive_get_checkpoint(self, st, lane):  # CP06:1017-1043
         C = self.MAX_OPS + 1
         k = lane // (self.R * C)
@@ -517,22 +507,6 @@ class CP06Kernel(RR05Kernel):
         s2 = self._bag_send(s2, row)
         return s2, en
 
-    def guard_receive_get_checkpoint(self, st, lane):
-        C = self.MAX_OPS + 1
-        k = lane // (self.R * C)
-        rest = lane % (self.R * C)
-        i = rest // C
-        cp = rest % C
-        r = i + 1
-        hdr = st["m_hdr"][k]
-        return ((st["m_present"][k] == 1) & (st["m_count"][k] > 0)
-                & (hdr[H_TYPE] == M_GETCP)
-                & ((hdr[H_DEST] == r)
-                   | ((hdr[H_DEST] == ANYDEST) & (hdr[H_SRC] != r)))
-                & self._can_progress(st, i)
-                & self._not_recovering(st, i)
-                & (cp <= st["commit"][i]))
-
     def act_receive_new_checkpoint(self, st, lane):  # CP06:1051-1079
         k = lane
         hdr = st["m_hdr"][k]
@@ -554,12 +528,6 @@ class CP06Kernel(RR05Kernel):
         s2 = self._broadcast(
             s2, self._row(M_RECOVERY, src=r, x=u, op=cpn), r)
         return s2, en
-
-    def guard_receive_new_checkpoint(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_NEWCP)
-                & self._can_progress(st, i)
-                & (st["status"][i] == RECOVERING))
 
     def act_receive_recovery(self, st, lane):     # CP06:1081-1105
         C = self.MAX_OPS + 1
@@ -606,26 +574,6 @@ class CP06Kernel(RR05Kernel):
         s2 = self._bag_send(s2, row)
         return s2, en
 
-    def guard_receive_recovery(self, st, lane):
-        C = self.MAX_OPS + 1
-        k = lane // C
-        cp = lane % C
-        hdr = st["m_hdr"][k]
-        r = hdr[H_DEST]
-        i = jnp.clip(r - 1, 0, self.R - 1)
-        base = (self._recv_guard(st, k, M_RECOVERY)
-                & (st["status"][i] == NORMAL))
-        prim = self._is_normal_primary(st, i, r)
-        m_op = hdr[H_OP]
-        gced = (st["op"][i] > m_op) \
-            & (st["log"][i][jnp.clip(m_op, 0, self.MAX_OPS - 1)]
-               == self.NOOP)
-        hgc = self._hgc(st["log"][i])
-        en_cp = base & prim & gced & (cp >= hgc + 1) \
-            & (cp <= st["commit"][i])
-        en_other = base & (~prim | ~gced) & (cp == 0)
-        return en_cp | en_other
-
     def act_receive_recovery_response(self, st, lane):  # CP06:1107-1121
         k = lane
         hdr = st["m_hdr"][k]
@@ -655,12 +603,6 @@ class CP06Kernel(RR05Kernel):
         s2["err"] = s2["err"] | jnp.where(collide, ERR_REC_OVERFLOW, 0)
         s2 = self._bag_discard(s2, k)
         return s2, en
-
-    def guard_receive_recovery_response(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_RECOVERYRESP)
-                & (st["rec_number"][i] == st["m_hdr"][k, H_X])
-                & (st["status"][i] == RECOVERING))
 
     def act_complete_recovery(self, st, lane):    # CP06:1138-1170
         i = lane
@@ -697,12 +639,329 @@ class CP06Kernel(RR05Kernel):
         s2 = self._clear_rec(s2, i)
         return s2, en
 
-    def guard_complete_recovery(self, st, lane):
-        i = lane
-        cand, _j = self._best_rec(st, i)
-        return ((st["status"][i] == RECOVERING)
-                & ((st["rec"][i] == 1).sum() > self.R // 2)
-                & cand.any())
+    # ------------------------------------------------------------------
+    # guards: one table a state (stage 1 of the level program)
+    # ------------------------------------------------------------------
+    # Stage 1 evaluates every guard on every lane of every row of a
+    # chunk, in every dispatch.  Written a lane at a time (decode k, i,
+    # cp; gather the message and the replica; scan the log for NoOps,
+    # the bag for tombstones or for the record a SendOnce would send)
+    # the 812 lanes cost 12.8 ns a lane and row on the v5e, 57 % of a
+    # traced slice (PERF.md, PR 46).  So every guard here is
+    # ``guard_x_table(st)``: the action's enabling for ALL its lanes,
+    # shaped like its lane decode ([M], [R, C], [M, C], [M, R, C], ...;
+    # row-major = the lane number ``_lane_count`` and the ``act_*``
+    # use), built from factors that each read the state once: of the
+    # message [M], of the replica [R], of replica and checkpoint
+    # [R, C], of the pair [M, R], combined by broadcast.  The per-lane
+    # function the engines call is ``table(st).reshape(-1)[lane]``:
+    # under their vmap over lanes the table depends on no lane, stays
+    # unbatched and is computed once a state.
+    #
+    # What a table may read: the state's planes whole, never a plane
+    # at a lane's index.  A replica's column at a message's dest is a
+    # one-hot select over R (`_at_dest`), a log position a one-hot over
+    # MAX_OPS: R and MAX_OPS are small, and the gather a lane is what
+    # the tables exist to avoid.  Factors two guards share (the dest
+    # one-hot, `_hgc_all`, `_addressed`) are written once and traced
+    # by each; XLA's CSE makes them one in the level program.
+    #
+    # The action bodies are the oracle and are not written in terms of
+    # these: each ``act_*`` computes its own ``en`` a lane, from the
+    # cited lines of the module, and tests/test_native_cp06_guard_tables
+    # .py holds table == ``en`` on every lane.  A lane a guard loses is
+    # a state the checker loses; a lane it adds is only wasted work
+    # (the expand's own ``en`` masks it).
+    def _at_dest(self, st):
+        """``at(plane)``: an ``[R, ...]`` plane (or ``st[plane]``) at
+        each message's dest replica, ``[M, ...]``.  The replica is
+        `_dest_i`'s: dest - 1 clipped into 0..R-1 (AnyDest reads
+        replica 0, as the guards a lane did)."""
+        dest_i = jnp.clip(st["m_hdr"][:, H_DEST] - 1, 0, self.R - 1)
+        hot = dest_i[:, None] == jnp.arange(self.R, dtype=I32)   # [M, R]
+
+        def at(plane):
+            if isinstance(plane, str):
+                plane = st[plane]
+            sel = hot.reshape(hot.shape + (1,) * (plane.ndim - 1))
+            if plane.dtype == jnp.bool_:
+                return (sel & plane).any(1)
+            return jnp.where(sel, plane, 0).sum(1)
+        return at
+
+    @property
+    def _ids(self):
+        """[R]: the replica ids 1..R."""
+        return jnp.arange(1, self.R + 1, dtype=I32)
+
+    def _addressed(self, st):
+        """[M, R]: message k may be received by replica i + 1 — named
+        dest, or AnyDest and not its source (ST03:213-218)."""
+        hdr = st["m_hdr"]
+        dest, src = hdr[:, H_DEST, None], hdr[:, H_SRC, None]
+        ids = self._ids
+        return (dest == ids) | ((dest == ANYDEST) & (src != ids))
+
+    def _hgc_all(self, st):
+        """[R]: HighestGCedOp of every replica (`_hgc` a row)."""
+        pos = jnp.arange(self.MAX_OPS, dtype=I32)
+        return jnp.where(st["log"] == self.NOOP, pos + 1, 0).max(-1)
+
+    def _noop_at(self, log, op):
+        """Whether ``log[..., clip(op)]`` is a NoOp, the position taken
+        by a one-hot over MAX_OPS; `log` and `op` broadcast."""
+        pos = jnp.arange(self.MAX_OPS, dtype=I32)
+        at_op = jnp.clip(op, 0, self.MAX_OPS - 1)[..., None] == pos
+        return (at_op & (log == self.NOOP)).any(-1)
+
+    def _cp_upto_commit(self, st):
+        """[R, C]: ``last_cp`` in 0..commit (Crash, GetCheckpoint)."""
+        cps = jnp.arange(self.MAX_OPS + 1, dtype=I32)
+        return cps <= st["commit"][:, None]
+
+    def _cp_above_gc(self, st):
+        """[R, C]: ``last_cp`` in HighestGCedOp+1..commit, wherever a
+        checkpoint is sent (CP06:799)."""
+        cps = jnp.arange(self.MAX_OPS + 1, dtype=I32)
+        return (cps >= self._hgc_all(st)[:, None] + 1) \
+            & self._cp_upto_commit(st)
+
+    def _normal_primary(self, view, status, r):
+        return (self._primary(view, self.R) == r) & (status == NORMAL)
+
+    def _sent_once(self, st, tmpl, vary):
+        """[M]: the bag's slots (tombstones too) equal to record `tmpl`
+        in every plane of `ROW_PLANES` and every header column but
+        `vary`: SendOnce's membership test, all of it that does not
+        depend on the sender."""
+        free = np.zeros((self.NHDR,), bool)
+        free[list(vary)] = True
+        same = (st["m_present"] == 1) \
+            & ((st["m_hdr"] == tmpl["hdr"]) | free).all(-1)
+        for rk, plane in self.ROW_PLANES:
+            cmp = st[plane] == tmpl[rk]
+            same = same & (cmp if cmp.ndim == 1 else cmp.all(-1))
+        return same
+
+    # -- R-lane guards ----------------------------------------------------
+    def guard_timer_send_svc_table(self, st):                   # [R]
+        return ((st["aux_svc"] < self.shape.timer_limit)
+                & (st["no_prog"] == 0)
+                & ~self._normal_primary(st["view"], st["status"],
+                                        self._ids)
+                & (st["status"] != RECOVERING))
+
+    def guard_send_dvc_table(self, st):                         # [R, C]
+        hdr = st["m_hdr"]
+        tomb = ((st["m_present"] == 1) & (st["m_count"] == 0)
+                & (hdr[:, H_TYPE] == M_SVC))[:, None] \
+            & (hdr[:, H_DEST, None] == self._ids) \
+            & (hdr[:, H_VIEW, None] == st["view"])              # [M, R]
+        rep = ((st["no_prog"] == 0) & (st["status"] == VIEWCHANGE)
+               & (st["sent_dvc"] == 0) & (tomb.sum(0) >= self.R // 2))
+        return rep[:, None] & self._cp_above_gc(st)
+
+    def guard_send_sv_table(self, st):                          # [R]
+        return ((st["no_prog"] == 0) & (st["status"] == VIEWCHANGE)
+                & (st["sent_sv"] == 0)
+                & ((st["dvc"] == 1).sum(-1) >= self.R // 2 + 1))
+
+    def guard_receive_client_request_table(self, st):           # [R, V]
+        rep = (st["no_prog"] == 0) \
+            & self._normal_primary(st["view"], st["status"], self._ids)
+        return rep[:, None] & (st["aux_acked"] == 0)
+
+    def guard_execute_op_table(self, st):                       # [R]
+        opn = st["commit"] + 1
+        committed = (st["peer_op"] >= opn[:, None]).sum(-1) >= self.R // 2
+        return ((st["no_prog"] == 0)
+                & self._normal_primary(st["view"], st["status"], self._ids)
+                & (st["commit"] < st["op"]) & committed)
+
+    def guard_crash_table(self, st):                            # [R, C]
+        # SendOnce: the record lane (i, cp) would send is
+        # _row(M_GETCP, dest=ANYDEST, src=i + 1) whatever cp, so only
+        # the source varies
+        same = self._sent_once(st, self._row(M_GETCP, dest=ANYDEST),
+                               (H_SRC,))
+        sent = (same[:, None]
+                & (st["m_hdr"][:, H_SRC, None] == self._ids)).any(0)
+        return ((st["aux_restart"] < self.crash_limit)
+                & ~sent[:, None] & self._cp_upto_commit(st))
+
+    def guard_complete_recovery_table(self, st):                # [R]
+        pres = st["rec"] == 1                                   # [R, R]
+        vmax = jnp.where(pres, st["rec_view"], -1).max(-1)
+        cand = pres & (st["rec_has_log"] == 1) \
+            & (st["rec_view"] == vmax[:, None])
+        return ((st["status"] == RECOVERING)
+                & (pres.sum(-1) > self.R // 2) & cand.any(-1))
+
+    def guard_no_progress_change_table(self, st):               # [1 << R]
+        bits = (np.arange(1 << self.R)[:, None] >> np.arange(self.R)) & 1
+        return ((st["np_ctr"] < self.shape.np_limit)
+                & jnp.asarray(bits.sum(-1) <= self.R // 2))
+
+    # -- M-lane guards: message k at its dest ------------------------------
+    def guard_receive_higher_svc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_SVC) & (at("no_prog") == 0)
+                & (st["m_hdr"][:, H_VIEW] > at("view"))
+                & (at("status") != RECOVERING))
+
+    def guard_receive_matching_svc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_SVC) & (at("no_prog") == 0)
+                & (at("status") == VIEWCHANGE)
+                & (st["m_hdr"][:, H_VIEW] == at("view"))
+                & (at("sent_dvc") == 0))
+
+    def guard_receive_higher_dvc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_DVC) & (at("no_prog") == 0)
+                & (st["m_hdr"][:, H_VIEW] > at("view"))
+                & (at("status") != RECOVERING))
+
+    def guard_receive_matching_dvc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_DVC) & (at("no_prog") == 0)
+                & (at("status") == VIEWCHANGE)
+                & (st["m_hdr"][:, H_VIEW] == at("view")))
+
+    def guard_receive_sv_table(self, st):
+        at = self._at_dest(st)
+        hv = st["m_hdr"][:, H_VIEW]
+        return (self._recv_guard(st, ..., M_SV) & (at("no_prog") == 0)
+                & (((hv == at("view")) & (at("status") == VIEWCHANGE))
+                   | (hv > at("view")))
+                & (at("status") != RECOVERING))
+
+    def guard_receive_prepare_table(self, st):
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        return (self._recv_guard(st, ..., M_PREPARE)
+                & (at("no_prog") == 0)
+                & ~self._normal_primary(at("view"), at("status"),
+                                        hdr[:, H_DEST])
+                & (at("status") == NORMAL)
+                & (hdr[:, H_VIEW] == at("view"))
+                & (hdr[:, H_OP] == at("op") + 1))
+
+    def guard_receive_prepare_ok_table(self, st):
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        src_i = jnp.clip(hdr[:, H_SRC] - 1, 0, self.R - 1)
+        from_src = src_i[:, None] == jnp.arange(self.R, dtype=I32)
+        peer_op = jnp.where(from_src, at("peer_op"), 0).sum(-1)
+        return (self._recv_guard(st, ..., M_PREPAREOK)
+                & (at("no_prog") == 0)
+                & self._normal_primary(at("view"), at("status"),
+                                       hdr[:, H_DEST])
+                & (hdr[:, H_VIEW] == at("view"))
+                & (hdr[:, H_OP] > peer_op))
+
+    def guard_send_get_state_table(self, st):
+        # SendOnce: lane k would send _get_state_row(st, k, i) =
+        # _row(M_GETSTATE, view=the Prepare's, op=commit[i],
+        # dest=ANYDEST, src=i + 1), so view, op and source vary
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        en = (self._recv_guard(st, ..., M_PREPARE) & (at("no_prog") == 0)
+              & ~self._normal_primary(at("view"), at("status"),
+                                      hdr[:, H_DEST])
+              & (at("status") == NORMAL)
+              & (hdr[:, H_VIEW] > at("view"))
+              & (hdr[:, H_OP] > at("op") + 1))
+        same = self._sent_once(st, self._row(M_GETSTATE, dest=ANYDEST),
+                               (H_VIEW, H_OP, H_SRC))           # [k']
+        src = jnp.clip(hdr[:, H_DEST] - 1, 0, self.R - 1) + 1
+        hit = (same & (hdr[:, H_VIEW] == hdr[:, H_VIEW, None])
+               & (hdr[:, H_OP] == at("commit")[:, None])
+               & (hdr[:, H_SRC] == src[:, None]))               # [k, k']
+        return en & ~hit.any(-1)
+
+    def guard_receive_new_state_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_NEWSTATE)
+                & (at("no_prog") == 0) & (at("status") == STATETRANSFER)
+                & (at("view") == st["m_hdr"][:, H_VIEW]))
+
+    def guard_receive_new_checkpoint_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_NEWCP) & (at("no_prog") == 0)
+                & (at("status") == RECOVERING))
+
+    def guard_receive_recovery_response_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_RECOVERYRESP)
+                & (at("rec_number") == st["m_hdr"][:, H_X])
+                & (at("status") == RECOVERING))
+
+    def guard_receive_recovery_table(self, st):                 # [M, C]
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        base = self._recv_guard(st, ..., M_RECOVERY) \
+            & (at("status") == NORMAL)
+        prim = self._normal_primary(at("view"), at("status"),
+                                    hdr[:, H_DEST])
+        gced = (at("op") > hdr[:, H_OP]) \
+            & self._noop_at(at("log"), hdr[:, H_OP])
+        # the primary behind a GC'd position replies with a checkpoint
+        # (the cp lanes of its window); anyone else on lane cp == 0
+        cps = jnp.arange(self.MAX_OPS + 1, dtype=I32)
+        return base[:, None] & jnp.where((prim & gced)[:, None],
+                                         at(self._cp_above_gc(st)),
+                                         cps == 0)
+
+    # -- [M, R, last_cp] guards: message k, receiving replica i ------------
+    def guard_receive_get_state_table(self, st):
+        hdr = st["m_hdr"]
+        rep = (st["no_prog"] == 0) & (st["status"] == NORMAL)   # [R]
+        base = (self._recv_guard(st, ..., M_GETSTATE)[:, None]
+                & self._addressed(st) & rep
+                & (st["view"] == hdr[:, H_VIEW, None])
+                & (st["op"] > hdr[:, H_OP, None]))              # [M, R]
+        # branch select: GC'd at m.op + 1 -> checkpoint reply (the cp
+        # lanes of the window), else log-suffix reply (lane cp == 0)
+        gced = self._noop_at(st["log"], hdr[:, H_OP, None])     # [M, R]
+        cps = jnp.arange(self.MAX_OPS + 1, dtype=I32)
+        return base[:, :, None] & jnp.where(gced[:, :, None],
+                                            self._cp_above_gc(st), cps == 0)
+
+    def guard_receive_get_checkpoint_table(self, st):
+        rep = (st["no_prog"] == 0) & (st["status"] != RECOVERING)
+        pair = (self._recv_guard(st, ..., M_GETCP)[:, None]
+                & self._addressed(st) & rep)                    # [M, R]
+        return pair[:, :, None] & self._cp_upto_commit(st)
+
+    guard_timer_send_svc = _lanes_of(guard_timer_send_svc_table)
+    guard_receive_higher_svc = _lanes_of(guard_receive_higher_svc_table)
+    guard_receive_matching_svc = _lanes_of(
+        guard_receive_matching_svc_table)
+    guard_send_dvc = _lanes_of(guard_send_dvc_table)
+    guard_receive_higher_dvc = _lanes_of(guard_receive_higher_dvc_table)
+    guard_receive_matching_dvc = _lanes_of(
+        guard_receive_matching_dvc_table)
+    guard_send_sv = _lanes_of(guard_send_sv_table)
+    guard_receive_sv = _lanes_of(guard_receive_sv_table)
+    guard_receive_client_request = _lanes_of(
+        guard_receive_client_request_table)
+    guard_receive_prepare = _lanes_of(guard_receive_prepare_table)
+    guard_receive_prepare_ok = _lanes_of(guard_receive_prepare_ok_table)
+    guard_execute_op = _lanes_of(guard_execute_op_table)
+    guard_send_get_state = _lanes_of(guard_send_get_state_table)
+    guard_receive_get_state = _lanes_of(guard_receive_get_state_table)
+    guard_receive_new_state = _lanes_of(guard_receive_new_state_table)
+    guard_crash = _lanes_of(guard_crash_table)
+    guard_receive_get_checkpoint = _lanes_of(
+        guard_receive_get_checkpoint_table)
+    guard_receive_new_checkpoint = _lanes_of(
+        guard_receive_new_checkpoint_table)
+    guard_receive_recovery = _lanes_of(guard_receive_recovery_table)
+    guard_receive_recovery_response = _lanes_of(
+        guard_receive_recovery_response_table)
+    guard_complete_recovery = _lanes_of(guard_complete_recovery_table)
+    guard_no_progress_change = _lanes_of(guard_no_progress_change_table)
 
     # ------------------------------------------------------------------
     # action table
