@@ -10,9 +10,9 @@ action's lanes), and all of them as one program, which is what stage 1
 runs (XLA shares what two guards both compute, so the sum of the
 guards alone is an upper bound, and a guard alone reads no lower than
 the call's own floor: 0.6-0.7 ms on the v5e with CP06's 40 planes
-passed).  Reads nothing but the kernel's public attributes, so the
-same file times a parent tree's guards: run it from a checkout of
-each.
+passed).  Of the kernel it reads the attributes the engines read, so
+a parent tree's copy of this file times that tree's guards the same
+way: run each from its own checkout.
 
 ``--states``: an ``.npz`` of dense planes ``[N, ...]`` (a snapshot's
 frontier, say); without it the codec's init state, tiled.  The guards
@@ -36,6 +36,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from tpuvsr.engine.spec import load_spec  # noqa: E402
+from tpuvsr.models.guard_tables import table_lanes  # noqa: E402
 
 REPEATS = 20
 
@@ -87,9 +88,7 @@ def main():
     doc = {"device": jax.devices()[0].device_kind, "module": args.module,
            "rows": args.rows, "max_msgs": args.max_msgs,
            "lanes": int(sum(x.size for x in lanes)),
-           "table_lanes": int(sum(
-               kern._lane_count(n)
-               for n in getattr(kern, "GUARD_TABLES", ()))),
+           "table_lanes": table_lanes(kern),
            "guards": {}}
     for name, g, ln in zip(names, guards, lanes):
         ms, out = _ms(jax.jit(matrix(g, ln)), batch)

@@ -27,6 +27,7 @@ from tests.test_native_cp06 import (  # noqa: F401  (fixtures)
     to_tlc)
 from tpuvsr.models.cp06 import M_GETCP, M_NEWCP, M_RECOVERY
 from tpuvsr.models.cp06_kernel import ACTION_NAMES, CP06Kernel
+from tpuvsr.models.guard_tables import table_lanes
 from tpuvsr.models.st03 import (ANYDEST, M_GETSTATE, M_SVC, NORMAL,
                                 VIEWCHANGE)
 from tpuvsr.models.vsr import (H_COMMIT, H_CP, H_DEST, H_FIRST, H_FLAG,
@@ -220,12 +221,14 @@ def both(model, sample):
     return run
 
 
-def test_every_guard_is_declared_a_table(model):
+def test_every_guard_is_a_table(model):
     _codec, kern = model
     assert type(kern) is CP06Kernel
-    assert kern.GUARD_TABLES == ACTION_NAMES == tuple(kern.action_names)
-    assert sum(kern._lane_count(n) for n in kern.GUARD_TABLES) \
-        == kern.n_lanes == 812
+    assert ACTION_NAMES == tuple(kern.action_names)
+    # each is this class's own table, not one inherited from ST03
+    for guard in kern._guard_fns():
+        assert guard.table in vars(CP06Kernel).values()
+    assert table_lanes(kern) == kern.n_lanes == 812
 
 
 @pytest.mark.parametrize("action", ACTION_NAMES)
@@ -356,9 +359,9 @@ def test_no_table_guard_works_a_lane(action, model, sample):
         return lambda s: jax.vmap(lambda ln: fn(s, ln))(lanes)
 
     guard = kern._guard_fns()[a]
-    (name,) = [n for n, v in vars(CP06Kernel).items()
-               if v is guard.__func__]
-    table = getattr(kern, name + "_table")
+
+    def table(s):
+        return guard.table(kern, s)
     assert table(st).size == L
     own = largest(table)
     # SendGetState holds each Prepare against each slot: [k, k']
@@ -371,10 +374,10 @@ def test_no_table_guard_works_a_lane(action, model, sample):
         assert largest(en) >= L * kern.M * kern.NHDR
 
 
-def test_a_runs_record_counts_the_declared_lanes(spec):
-    """Gauge ``guard_table_lanes``: set on the host, from the kernel's
-    declaration, by the one owner of the lever gauges; 0 on a kernel
-    that declares nothing."""
+def test_a_runs_record_counts_the_table_lanes(spec):
+    """Gauge ``guard_table_lanes``: set on the host, from the guards
+    the kernel hands the engines, by the one owner of the lever
+    gauges; 0 on a kernel with no table."""
     from tpuvsr.engine.checked import CheckedModel
     from tpuvsr.obs.metrics import Metrics
     from tpuvsr.testing import stub_device_engine

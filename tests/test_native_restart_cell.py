@@ -189,16 +189,22 @@ def test_symmetry_on_levels_equal_the_reference(build, spec, reference):
 # Read at commit 1d23b93 (the parent of the PR that gave the receive-set
 # its K slots), in a process of its own, with this file's `_program`.
 # A PR that changes the level program of the restart-free shapes on
-# purpose reads them again; this PR had to leave them alone.
+# purpose reads them again; this PR had to leave them alone.  PR 48
+# (every guard of `VSRKernel` a table of the state) read "lowered"
+# again: pack manifest and store key are 1d23b93's, and of the level
+# program's jaxpr every equation outside the scope
+# `tpuvsr.level.guard_matrix` is the parent's, in order (34,505 of
+# them at vsr-defect, 23,384 at vsr-shipped; stage 1 itself 1,723 →
+# 1,400).
 BEFORE_K = {
     "vsr-shipped": {
         "pack": "4794ecbbab3f66ae8443bca05016080f448e065ace9c9565337be2002ba5b17c",
         "key": "34582b919a2c249e7187a7a41a9789c92531f4bc6bb3ef01e40e4884d7faad2b",
-        "lowered": "7ae60d01018a035a50438d586bc0315e8764a0357359c18b08b434bbc0b5d801"},
+        "lowered": "56a76fa6487b58827469488cd0f7c9dd93eb58ace4980741fa21139dcb4e6d6a"},
     "vsr-defect": {
         "pack": "1730ab9928885a97b25695c893b0321fda8edeb4d2ed3b664416ebd42db6952d",
         "key": "102b033ef3ff7e2083075e2a2c730dcbd346bd4e7f58f097191b9eebc6c06278",
-        "lowered": "e866da7456c9b4ce2c902c74e42c8dd7ed28142f42ea74bce4a344228fc6b0c7"},
+        "lowered": "87b630396cc3d1d4df1457190a02df409a021092c01f202ef8b4912684cbe681"},
 }
 
 

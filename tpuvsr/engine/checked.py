@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from ..core.values import TLAError
 from ..models import registry
+from ..models.guard_tables import table_lanes
 from .bounds import prune_kernel, resolve_bounds
 from .canon import build_canon_spec, kernel_fold_order
 from .pack import build_pack_spec
@@ -336,12 +337,10 @@ class CheckedModel:
                 ratio = self.pk_decl.total_bits / self.pk.total_bits
             obs.gauge("bound_tightening_ratio", round(ratio, 4))
         # guard_table_lanes (ISSUE 47): the lanes of the actions whose
-        # guard the kernel declares as one table a state
-        # (`GUARD_TABLES`); 0 on a kernel that declares none
-        tables = getattr(self.kern, "GUARD_TABLES", ())
-        obs.gauge("guard_table_lanes", sum(
-            self.kern._lane_count(n) for n in self.kern.action_names
-            if n in tables))
+        # guard, as the kernel hands it to the engines, is one table a
+        # state (read off the functions: `guard_tables.lanes_of`
+        # marks them); 0 on a kernel that has none
+        obs.gauge("guard_table_lanes", table_lanes(self.kern))
         # por_cut_ratio / ample_states (ISSUE 16): generated kept /
         # generated full under the ample filter (1.0 when inert), and
         # how many expanded states took the shortcut with real work

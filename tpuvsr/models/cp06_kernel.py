@@ -28,6 +28,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .as04_kernel import AS04Kernel
+from .guard_tables import lanes_of
 from .cp06 import M_GETCP, M_NEWCP, M_RECOVERY, M_RECOVERYRESP, CP06Codec
 from .rr05 import RECOVERING
 from .rr05_kernel import RR05Kernel
@@ -52,20 +53,8 @@ REP_KEYS = RR05Kernel.REP_KEYS + (
     "dvc_cpn", "dvc_cp", "rec_flag", "rec_first", "rec_cp", "rec_cpn")
 
 
-def _lanes_of(table):
-    """The guard a lane that the engines call (`_guard_fns`), read off
-    the guard's table of the state: under a vmap over lanes the table
-    stays unbatched, so it is computed once a state."""
-    def guard(self, st, lane):
-        return table(self, st).reshape(-1)[lane]
-    return guard
-
-
 class CP06Kernel(RR05Kernel):
     action_names = ACTION_NAMES
-    #: the actions whose guard is one table a state (every one here):
-    #: what a run's gauge ``guard_table_lanes`` counts the lanes of
-    GUARD_TABLES = ACTION_NAMES
     # CP06's own line range of each action this file or SURVEY 2.1-2.2
     # cites one for: the location a native spec prints for a
     # counterexample step.  An inherited action whose CP06 lines no
@@ -205,9 +194,15 @@ class CP06Kernel(RR05Kernel):
     # ------------------------------------------------------------------
     def act_receive_matching_svc(self, st, lane):  # AS04:589-607
         # AS04's body takes its `en` from `guard_receive_matching_svc`,
-        # which is a table here; the oracle keeps AS04's guard a lane
+        # a table here and half a table there; the oracle is ST03's
+        # body and the module's conjuncts, a lane
         s2, _en = ST03Kernel.act_receive_matching_svc(self, st, lane)
-        return s2, AS04Kernel.guard_receive_matching_svc(self, st, lane)
+        i = self._dest_i(st, lane)
+        en = (self._recv_guard(st, lane, M_SVC) & self._can_progress(st, i)
+              & (st["status"][i] == VIEWCHANGE)
+              & (st["m_hdr"][lane, H_VIEW] == st["view"][i])
+              & (st["sent_dvc"][i] == 0))
+        return s2, en
 
     def act_send_dvc(self, st, lane):             # CP06:785-816
         C = self.MAX_OPS + 1
@@ -672,36 +667,12 @@ class CP06Kernel(RR05Kernel):
     # .py holds table == ``en`` on every lane.  A lane a guard loses is
     # a state the checker loses; a lane it adds is only wasted work
     # (the expand's own ``en`` masks it).
-    def _at_dest(self, st):
-        """``at(plane)``: an ``[R, ...]`` plane (or ``st[plane]``) at
-        each message's dest replica, ``[M, ...]``.  The replica is
-        `_dest_i`'s: dest - 1 clipped into 0..R-1 (AnyDest reads
-        replica 0, as the guards a lane did)."""
-        dest_i = jnp.clip(st["m_hdr"][:, H_DEST] - 1, 0, self.R - 1)
-        hot = dest_i[:, None] == jnp.arange(self.R, dtype=I32)   # [M, R]
-
-        def at(plane):
-            if isinstance(plane, str):
-                plane = st[plane]
-            sel = hot.reshape(hot.shape + (1,) * (plane.ndim - 1))
-            if plane.dtype == jnp.bool_:
-                return (sel & plane).any(1)
-            return jnp.where(sel, plane, 0).sum(1)
-        return at
-
-    @property
-    def _ids(self):
-        """[R]: the replica ids 1..R."""
-        return jnp.arange(1, self.R + 1, dtype=I32)
-
-    def _addressed(self, st):
-        """[M, R]: message k may be received by replica i + 1 — named
-        dest, or AnyDest and not its source (ST03:213-218)."""
-        hdr = st["m_hdr"]
-        dest, src = hdr[:, H_DEST, None], hdr[:, H_SRC, None]
-        ids = self._ids
-        return (dest == ids) | ((dest == ANYDEST) & (src != ids))
-
+    #
+    # `_at_dest`, `_ids`, `_addressed`, `_normal_primary` and
+    # `_sent_once` are `ST03Kernel`'s, whose own guards are tables of
+    # the same kind (ISSUE 48).  The 22 tables here stay this class's:
+    # they carry AS04's and RR05's conjuncts, which a `super()` on
+    # ST03's table would skip.
     def _hgc_all(self, st):
         """[R]: HighestGCedOp of every replica (`_hgc` a row)."""
         pos = jnp.arange(self.MAX_OPS, dtype=I32)
@@ -725,23 +696,6 @@ class CP06Kernel(RR05Kernel):
         cps = jnp.arange(self.MAX_OPS + 1, dtype=I32)
         return (cps >= self._hgc_all(st)[:, None] + 1) \
             & self._cp_upto_commit(st)
-
-    def _normal_primary(self, view, status, r):
-        return (self._primary(view, self.R) == r) & (status == NORMAL)
-
-    def _sent_once(self, st, tmpl, vary):
-        """[M]: the bag's slots (tombstones too) equal to record `tmpl`
-        in every plane of `ROW_PLANES` and every header column but
-        `vary`: SendOnce's membership test, all of it that does not
-        depend on the sender."""
-        free = np.zeros((self.NHDR,), bool)
-        free[list(vary)] = True
-        same = (st["m_present"] == 1) \
-            & ((st["m_hdr"] == tmpl["hdr"]) | free).all(-1)
-        for rk, plane in self.ROW_PLANES:
-            cmp = st[plane] == tmpl[rk]
-            same = same & (cmp if cmp.ndim == 1 else cmp.all(-1))
-        return same
 
     # -- R-lane guards ----------------------------------------------------
     def guard_timer_send_svc_table(self, st):                   # [R]
@@ -934,34 +888,34 @@ class CP06Kernel(RR05Kernel):
                 & self._addressed(st) & rep)                    # [M, R]
         return pair[:, :, None] & self._cp_upto_commit(st)
 
-    guard_timer_send_svc = _lanes_of(guard_timer_send_svc_table)
-    guard_receive_higher_svc = _lanes_of(guard_receive_higher_svc_table)
-    guard_receive_matching_svc = _lanes_of(
+    guard_timer_send_svc = lanes_of(guard_timer_send_svc_table)
+    guard_receive_higher_svc = lanes_of(guard_receive_higher_svc_table)
+    guard_receive_matching_svc = lanes_of(
         guard_receive_matching_svc_table)
-    guard_send_dvc = _lanes_of(guard_send_dvc_table)
-    guard_receive_higher_dvc = _lanes_of(guard_receive_higher_dvc_table)
-    guard_receive_matching_dvc = _lanes_of(
+    guard_send_dvc = lanes_of(guard_send_dvc_table)
+    guard_receive_higher_dvc = lanes_of(guard_receive_higher_dvc_table)
+    guard_receive_matching_dvc = lanes_of(
         guard_receive_matching_dvc_table)
-    guard_send_sv = _lanes_of(guard_send_sv_table)
-    guard_receive_sv = _lanes_of(guard_receive_sv_table)
-    guard_receive_client_request = _lanes_of(
+    guard_send_sv = lanes_of(guard_send_sv_table)
+    guard_receive_sv = lanes_of(guard_receive_sv_table)
+    guard_receive_client_request = lanes_of(
         guard_receive_client_request_table)
-    guard_receive_prepare = _lanes_of(guard_receive_prepare_table)
-    guard_receive_prepare_ok = _lanes_of(guard_receive_prepare_ok_table)
-    guard_execute_op = _lanes_of(guard_execute_op_table)
-    guard_send_get_state = _lanes_of(guard_send_get_state_table)
-    guard_receive_get_state = _lanes_of(guard_receive_get_state_table)
-    guard_receive_new_state = _lanes_of(guard_receive_new_state_table)
-    guard_crash = _lanes_of(guard_crash_table)
-    guard_receive_get_checkpoint = _lanes_of(
+    guard_receive_prepare = lanes_of(guard_receive_prepare_table)
+    guard_receive_prepare_ok = lanes_of(guard_receive_prepare_ok_table)
+    guard_execute_op = lanes_of(guard_execute_op_table)
+    guard_send_get_state = lanes_of(guard_send_get_state_table)
+    guard_receive_get_state = lanes_of(guard_receive_get_state_table)
+    guard_receive_new_state = lanes_of(guard_receive_new_state_table)
+    guard_crash = lanes_of(guard_crash_table)
+    guard_receive_get_checkpoint = lanes_of(
         guard_receive_get_checkpoint_table)
-    guard_receive_new_checkpoint = _lanes_of(
+    guard_receive_new_checkpoint = lanes_of(
         guard_receive_new_checkpoint_table)
-    guard_receive_recovery = _lanes_of(guard_receive_recovery_table)
-    guard_receive_recovery_response = _lanes_of(
+    guard_receive_recovery = lanes_of(guard_receive_recovery_table)
+    guard_receive_recovery_response = lanes_of(
         guard_receive_recovery_response_table)
-    guard_complete_recovery = _lanes_of(guard_complete_recovery_table)
-    guard_no_progress_change = _lanes_of(guard_no_progress_change_table)
+    guard_complete_recovery = lanes_of(guard_complete_recovery_table)
+    guard_no_progress_change = lanes_of(guard_no_progress_change_table)
 
     # ------------------------------------------------------------------
     # action table

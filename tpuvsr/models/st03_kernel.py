@@ -33,6 +33,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .guard_tables import at_dest, lanes_of, replica_ids
 from .st03 import (ANYDEST, ERR_BAG_OVERFLOW, M_DVC, M_GETSTATE,
                    M_NEWSTATE, M_PREPARE, M_PREPAREOK, M_SV, M_SVC,
                    NORMAL, STATETRANSFER, VIEWCHANGE, ST03Codec)
@@ -597,7 +598,11 @@ class ST03Kernel:
         return s2, en
 
     # ==================================================================
-    # guards (cheap enabling pass, no successor construction)
+    # guards: one table a state (stage 1 of the level program), under
+    # the rules that stand above `CP06Kernel`'s tables.  A subclass
+    # that overrides a guard a lane (`super().guard_x(st, k) & ...`)
+    # reads the inherited half off the table and keeps its own
+    # conjunct a lane.
     # ==================================================================
     def _recv_guard(self, st, k, mtype):
         return ((st["m_present"][k] == 1) & (st["m_count"][k] > 0)
@@ -606,126 +611,187 @@ class ST03Kernel:
     def _dest_i(self, st, k):
         return jnp.clip(st["m_hdr"][k, H_DEST] - 1, 0, self.R - 1)
 
-    def guard_timer_send_svc(self, st, lane):
-        i = lane
+    _at_dest = at_dest
+
+    _ids = property(replica_ids)
+
+    def _addressed(self, st):
+        """[M, R]: message k may be received by replica i + 1 — named
+        dest, or AnyDest and not its source (ST03:213-218)."""
+        hdr = st["m_hdr"]
+        dest, src = hdr[:, H_DEST, None], hdr[:, H_SRC, None]
+        ids = self._ids
+        return (dest == ids) | ((dest == ANYDEST) & (src != ids))
+
+    def _normal_primary(self, view, status, r):
+        return (self._primary(view, self.R) == r) & (status == NORMAL)
+
+    def _sent_once(self, st, tmpl, vary):
+        """[M]: the bag's slots (tombstones too) equal to record `tmpl`
+        in every plane of `ROW_PLANES` and every header column but
+        `vary`: SendOnce's membership test, all of it that does not
+        depend on the sender."""
+        free = np.zeros((self.NHDR,), bool)
+        free[list(vary)] = True
+        same = (st["m_present"] == 1) \
+            & ((st["m_hdr"] == tmpl["hdr"]) | free).all(-1)
+        for rk, plane in self.ROW_PLANES:
+            cmp = st[plane] == tmpl[rk]
+            same = same & (cmp if cmp.ndim == 1 else cmp.all(-1))
+        return same
+
+    def _processed(self, st, mtype):
+        """[R]: the count-0 records of `mtype` addressed to each
+        replica in its own view (`_svc_tombstones`, `_valid_dvc`)."""
+        hdr = st["m_hdr"]
+        mine = ((st["m_present"] == 1) & (st["m_count"] == 0)
+                & (hdr[:, H_TYPE] == mtype))[:, None] \
+            & (hdr[:, H_DEST, None] == self._ids) \
+            & (hdr[:, H_VIEW, None] == st["view"])              # [M, R]
+        return mine.sum(0)
+
+    # -- R-lane guards ----------------------------------------------------
+    def guard_timer_send_svc_table(self, st):                   # [R]
         return ((st["aux_svc"] < self.shape.timer_limit)
-                & self._can_progress(st, i)
-                & ~self._is_normal_primary(st, i, i + 1))
+                & (st["no_prog"] == 0)
+                & ~self._normal_primary(st["view"], st["status"],
+                                        self._ids))
 
-    def guard_receive_higher_svc(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_SVC) & self._can_progress(st, i)
-                & (st["m_hdr"][k, H_VIEW] > st["view"][i]))
+    def guard_send_dvc_table(self, st):                         # [R]
+        return ((st["no_prog"] == 0) & (st["status"] == VIEWCHANGE)
+                & (st["sent_dvc"] == 0)
+                & (self._processed(st, M_SVC) >= self.R // 2))
 
-    def guard_receive_matching_svc(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_SVC) & self._can_progress(st, i)
-                & (st["status"][i] == VIEWCHANGE)
-                & (st["m_hdr"][k, H_VIEW] == st["view"][i]))
+    def guard_send_sv_table(self, st):                          # [R]
+        return ((st["no_prog"] == 0) & (st["status"] == VIEWCHANGE)
+                & (st["sent_sv"] == 0)
+                & (self._processed(st, M_DVC) >= self.R // 2 + 1))
 
-    def guard_send_dvc(self, st, lane):
-        i = lane
-        return (self._can_progress(st, i)
-                & (st["status"][i] == VIEWCHANGE)
-                & (st["sent_dvc"][i] == 0)
-                & (self._svc_tombstones(st, i) >= self.R // 2))
+    def guard_receive_client_request_table(self, st):           # [R, V]
+        rep = (st["no_prog"] == 0) \
+            & self._normal_primary(st["view"], st["status"], self._ids)
+        return rep[:, None] & (st["aux_acked"] == 0)
 
-    def guard_receive_higher_dvc(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_DVC) & self._can_progress(st, i)
-                & (st["m_hdr"][k, H_VIEW] > st["view"][i]))
+    def guard_execute_op_table(self, st):                       # [R]
+        opn = st["commit"] + 1
+        committed = (st["peer_op"] >= opn[:, None]).sum(-1) >= self.R // 2
+        return ((st["no_prog"] == 0)
+                & self._normal_primary(st["view"], st["status"], self._ids)
+                & (st["commit"] < st["op"]) & committed)
 
-    def guard_receive_matching_dvc(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_DVC) & self._can_progress(st, i)
-                & (st["status"][i] == VIEWCHANGE)
-                & (st["m_hdr"][k, H_VIEW] == st["view"][i]))
-
-    def guard_send_sv(self, st, lane):
-        i = lane
-        return (self._can_progress(st, i)
-                & (st["status"][i] == VIEWCHANGE)
-                & (st["sent_sv"][i] == 0)
-                & (self._valid_dvc(st, i).sum() >= self.R // 2 + 1))
-
-    def guard_receive_sv(self, st, k):
-        i = self._dest_i(st, k)
-        hv = st["m_hdr"][k, H_VIEW]
-        return (self._recv_guard(st, k, M_SV) & self._can_progress(st, i)
-                & (((hv == st["view"][i])
-                    & (st["status"][i] == VIEWCHANGE))
-                   | (hv > st["view"][i])))
-
-    def guard_receive_client_request(self, st, lane):
-        i = lane // self.V
-        v = lane % self.V + 1
-        return (self._can_progress(st, i)
-                & self._is_normal_primary(st, i, i + 1)
-                & (st["aux_acked"][v - 1] == 0))
-
-    def guard_receive_prepare(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_PREPARE)
-                & self._can_progress(st, i)
-                & ~self._is_normal_primary(st, i, st["m_hdr"][k, H_DEST])
-                & (st["status"][i] == NORMAL)
-                & (st["m_hdr"][k, H_VIEW] == st["view"][i])
-                & (st["m_hdr"][k, H_OP] == st["op"][i] + 1))
-
-    def guard_receive_prepare_ok(self, st, k):
-        i = self._dest_i(st, k)
-        j = jnp.clip(st["m_hdr"][k, H_SRC] - 1, 0, self.R - 1)
-        return (self._recv_guard(st, k, M_PREPAREOK)
-                & self._can_progress(st, i)
-                & self._is_normal_primary(st, i, st["m_hdr"][k, H_DEST])
-                & (st["m_hdr"][k, H_VIEW] == st["view"][i])
-                & (st["m_hdr"][k, H_OP] > st["peer_op"][i, j]))
-
-    def guard_execute_op(self, st, lane):
-        i = lane
-        opn = st["commit"][i] + 1
-        committed = (st["peer_op"][i] >= opn).sum() >= self.R // 2
-        return (self._can_progress(st, i)
-                & self._is_normal_primary(st, i, i + 1)
-                & (st["commit"][i] < st["op"][i]) & committed)
-
-    def guard_send_get_state(self, st, k):
-        hdr = st["m_hdr"][k]
-        i = self._dest_i(st, k)
-        en = (self._recv_guard(st, k, M_PREPARE)
-              & self._can_progress(st, i)
-              & ~self._is_normal_primary(st, i, hdr[H_DEST])
-              & (st["status"][i] == NORMAL)
-              & (hdr[H_VIEW] > st["view"][i])
-              & (hdr[H_OP] > st["op"][i] + 1))
-        row = self._get_state_row(st, k, i)
-        return en & ~self._row_eq(st, row).any()
-
-    def guard_receive_get_state(self, st, lane):
-        k = lane // self.R
-        i = lane % self.R
-        r = i + 1
-        hdr = st["m_hdr"][k]
-        return ((st["m_present"][k] == 1) & (st["m_count"][k] > 0)
-                & (hdr[H_TYPE] == M_GETSTATE)
-                & ((hdr[H_DEST] == r)
-                   | ((hdr[H_DEST] == ANYDEST) & (hdr[H_SRC] != r)))
-                & self._can_progress(st, i)
-                & (st["status"][i] == NORMAL)
-                & (st["view"][i] == hdr[H_VIEW])
-                & (st["op"][i] > hdr[H_OP]))
-
-    def guard_receive_new_state(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_NEWSTATE)
-                & self._can_progress(st, i)
-                & (st["status"][i] == STATETRANSFER)
-                & (st["m_hdr"][k, H_VIEW] > st["view"][i]))
-
-    def guard_no_progress_change(self, st, lane):
-        bits = (lane >> jnp.arange(self.R, dtype=I32)) & 1
+    def guard_no_progress_change_table(self, st):               # [1 << R]
+        bits = (np.arange(1 << self.R)[:, None] >> np.arange(self.R)) & 1
         return ((st["np_ctr"] < self.shape.np_limit)
-                & (bits.sum() <= self.R // 2))
+                & jnp.asarray(bits.sum(-1) <= self.R // 2))
+
+    # -- M-lane guards: message k at its dest ------------------------------
+    def guard_receive_higher_svc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_SVC) & (at("no_prog") == 0)
+                & (st["m_hdr"][:, H_VIEW] > at("view")))
+
+    def guard_receive_matching_svc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_SVC) & (at("no_prog") == 0)
+                & (at("status") == VIEWCHANGE)
+                & (st["m_hdr"][:, H_VIEW] == at("view")))
+
+    def guard_receive_higher_dvc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_DVC) & (at("no_prog") == 0)
+                & (st["m_hdr"][:, H_VIEW] > at("view")))
+
+    def guard_receive_matching_dvc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_DVC) & (at("no_prog") == 0)
+                & (at("status") == VIEWCHANGE)
+                & (st["m_hdr"][:, H_VIEW] == at("view")))
+
+    def guard_receive_sv_table(self, st):
+        at = self._at_dest(st)
+        hv = st["m_hdr"][:, H_VIEW]
+        return (self._recv_guard(st, ..., M_SV) & (at("no_prog") == 0)
+                & (((hv == at("view")) & (at("status") == VIEWCHANGE))
+                   | (hv > at("view"))))
+
+    def guard_receive_prepare_table(self, st):
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        return (self._recv_guard(st, ..., M_PREPARE)
+                & (at("no_prog") == 0)
+                & ~self._normal_primary(at("view"), at("status"),
+                                        hdr[:, H_DEST])
+                & (at("status") == NORMAL)
+                & (hdr[:, H_VIEW] == at("view"))
+                & (hdr[:, H_OP] == at("op") + 1))
+
+    def guard_receive_prepare_ok_table(self, st):
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        src_i = jnp.clip(hdr[:, H_SRC] - 1, 0, self.R - 1)
+        from_src = src_i[:, None] == jnp.arange(self.R, dtype=I32)
+        peer_op = jnp.where(from_src, at("peer_op"), 0).sum(-1)
+        return (self._recv_guard(st, ..., M_PREPAREOK)
+                & (at("no_prog") == 0)
+                & self._normal_primary(at("view"), at("status"),
+                                       hdr[:, H_DEST])
+                & (hdr[:, H_VIEW] == at("view"))
+                & (hdr[:, H_OP] > peer_op))
+
+    def guard_send_get_state_table(self, st):
+        # SendOnce: lane k would send _get_state_row(st, k, i) =
+        # _row(M_GETSTATE, view=the Prepare's, op=commit[i],
+        # dest=ANYDEST, src=i + 1), so view, op and source vary
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        en = (self._recv_guard(st, ..., M_PREPARE) & (at("no_prog") == 0)
+              & ~self._normal_primary(at("view"), at("status"),
+                                      hdr[:, H_DEST])
+              & (at("status") == NORMAL)
+              & (hdr[:, H_VIEW] > at("view"))
+              & (hdr[:, H_OP] > at("op") + 1))
+        same = self._sent_once(st, self._row(M_GETSTATE, dest=ANYDEST),
+                               (H_VIEW, H_OP, H_SRC))           # [k']
+        src = jnp.clip(hdr[:, H_DEST] - 1, 0, self.R - 1) + 1
+        hit = (same & (hdr[:, H_VIEW] == hdr[:, H_VIEW, None])
+               & (hdr[:, H_OP] == at("commit")[:, None])
+               & (hdr[:, H_SRC] == src[:, None]))               # [k, k']
+        return en & ~hit.any(-1)
+
+    def guard_receive_new_state_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_NEWSTATE)
+                & (at("no_prog") == 0) & (at("status") == STATETRANSFER)
+                & (st["m_hdr"][:, H_VIEW] > at("view")))
+
+    # -- [M, R]: message k, receiving replica i (AnyDest) ------------------
+    def guard_receive_get_state_table(self, st):
+        hdr = st["m_hdr"]
+        rep = (st["no_prog"] == 0) & (st["status"] == NORMAL)   # [R]
+        return (self._recv_guard(st, ..., M_GETSTATE)[:, None]
+                & self._addressed(st) & rep
+                & (st["view"] == hdr[:, H_VIEW, None])
+                & (st["op"] > hdr[:, H_OP, None]))
+
+    guard_timer_send_svc = lanes_of(guard_timer_send_svc_table)
+    guard_receive_higher_svc = lanes_of(guard_receive_higher_svc_table)
+    guard_receive_matching_svc = lanes_of(
+        guard_receive_matching_svc_table)
+    guard_send_dvc = lanes_of(guard_send_dvc_table)
+    guard_receive_higher_dvc = lanes_of(guard_receive_higher_dvc_table)
+    guard_receive_matching_dvc = lanes_of(
+        guard_receive_matching_dvc_table)
+    guard_send_sv = lanes_of(guard_send_sv_table)
+    guard_receive_sv = lanes_of(guard_receive_sv_table)
+    guard_receive_client_request = lanes_of(
+        guard_receive_client_request_table)
+    guard_receive_prepare = lanes_of(guard_receive_prepare_table)
+    guard_receive_prepare_ok = lanes_of(guard_receive_prepare_ok_table)
+    guard_execute_op = lanes_of(guard_execute_op_table)
+    guard_send_get_state = lanes_of(guard_send_get_state_table)
+    guard_receive_get_state = lanes_of(guard_receive_get_state_table)
+    guard_receive_new_state = lanes_of(guard_receive_new_state_table)
+    guard_no_progress_change = lanes_of(guard_no_progress_change_table)
 
     def _guard_fns(self):
         return [

@@ -61,6 +61,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .guard_tables import at_dest, lanes_of, replica_ids
 from .vsr import (E_OPER, E_REQ, ERR_BAG_OVERFLOW,
                   ERR_DVC_OVERFLOW, ERR_REC_OVERFLOW, H_COMMIT, H_DEST,
                   H_FIRST, H_LNV, H_OP, H_SRC, H_TYPE, H_VIEW, H_X,
@@ -947,15 +948,22 @@ class VSRKernel:
         return s2, en
 
     # ==================================================================
-    # guard-only evaluation (the cheap pass of the two-phase expand)
+    # guard-only evaluation (stage 1 of the level program): one table a
+    # state
     #
-    # Each guard replicates exactly the `en` conjunction of its action —
-    # reading a handful of scalars/rows — so the engine can evaluate
-    # enabledness over the full [T, n_lanes] lane space at ~1% of the
-    # cost of building successors, then expand only the enabled lanes.
-    # Kept in lockstep with the action bodies; `test_guard_fns_match`
-    # holds them to the actions differentially (and, with no reference
-    # mounted, tests/test_native_guard_table.py holds SendGetState's).
+    # Each ``guard_x_table(st)`` is the `en` conjunction of its action
+    # for ALL the action's lanes, shaped like the lane decode ([R],
+    # [R, V], [M], [M, R]) and built from the state's planes whole, so
+    # the engine evaluates enabledness over the full [T, n_lanes] lane
+    # space at a few percent of the cost of building successors and
+    # expands only the enabled lanes.  The engines call
+    # ``guard_x(st, lane)``, the table read at the lane (`lanes_of`);
+    # the rules are the ones above `CP06Kernel`'s tables.  Kept in
+    # lockstep with the action bodies, which are the oracle:
+    # tests/test_native_guard_tables.py holds every table to its
+    # action's own `en` on every lane (test_native_guard_table.py
+    # SendGetState's), and `test_guard_fns_match` differentially where
+    # the reference is mounted.
     # ==================================================================
     def _recv_guard(self, st, k, mtype):
         return ((st["m_present"][k] == 1) & (st["m_count"][k] > 0)
@@ -964,96 +972,115 @@ class VSRKernel:
     def _dest_i(self, st, k):
         return jnp.clip(st["m_hdr"][k, H_DEST] - 1, 0, self.R - 1)
 
-    def guard_timer_send_svc(self, st, lane):
-        i = lane
+    _at_dest = at_dest
+
+    _ids = property(replica_ids)
+
+    # -- R-lane guards ----------------------------------------------------
+    def guard_timer_send_svc_table(self, st):                   # [R]
         return ((st["aux_svc"] < self.shape.timer_limit)
-                & ~self._is_primary(st, i, i + 1))
+                & (self._primary(st["view"], self.R) != self._ids))
 
-    def guard_receive_higher_svc(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_SVC)
-                & (st["m_hdr"][k, H_VIEW] > st["view"][i]))
+    def guard_send_dvc_table(self, st):                         # [R]
+        return ((st["status"] == VIEWCHANGE) & (st["sent_dvc"] == 0)
+                & (st["svc"].sum(-1) >= self.R // 2))
 
-    def guard_receive_matching_svc(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_SVC)
-                & (st["m_hdr"][k, H_VIEW] == st["view"][i])
-                & (st["status"][i] == VIEWCHANGE))
+    def guard_send_sv_table(self, st):                          # [R]
+        # every record of the set counts, one slot a source or K
+        held = (st["dvc"] == 1).reshape(self.R, -1).sum(-1)
+        return ((st["status"] == VIEWCHANGE) & (st["sent_sv"] == 0)
+                & (held >= self.R // 2 + 1))
 
-    def guard_send_dvc(self, st, lane):
-        i = lane
-        return ((st["status"][i] == VIEWCHANGE) & (st["sent_dvc"][i] == 0)
-                & (st["svc"][i].sum() >= self.R // 2))
+    def guard_receive_client_request_table(self, st):           # [R, V]
+        rep = ((self._primary(st["view"], self.R) == self._ids)
+               & (st["status"] == NORMAL) & (st["ct"][:, 0, T_EXEC] == 1))
+        return rep[:, None] & (st["aux_acked"] == 0)
 
-    def guard_receive_higher_dvc(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_DVC)
-                & (st["m_hdr"][k, H_VIEW] > st["view"][i]))
+    def guard_execute_op_table(self, st):                       # [R]
+        opn = st["commit"] + 1
+        committed = (st["peer_op"] >= opn[:, None]).sum(-1) >= self.R // 2
+        return ((self._primary(st["view"], self.R) == self._ids)
+                & (st["status"] == NORMAL)
+                & (st["commit"] < st["op"]) & committed)
 
-    def guard_receive_matching_dvc(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_DVC)
-                & (st["m_hdr"][k, H_VIEW] == st["view"][i]))
+    def guard_restart_empty_table(self, st):                    # [R]
+        return jnp.broadcast_to(
+            st["aux_restart"] < self.shape.restart_limit, (self.R,))
 
-    def guard_send_sv(self, st, lane):
-        i = lane
-        return ((st["status"][i] == VIEWCHANGE) & (st["sent_sv"][i] == 0)
-                & ((st["dvc"][i] == 1).sum() >= self.R // 2 + 1))
+    def guard_complete_recovery_table(self, st):                # [R]
+        pres = st["rec"] == 1                                   # [R, R]
+        return ((st["status"] == RECOVERING)
+                & (pres.sum(-1) > self.R // 2)
+                & (pres & (st["rec_has_log"] == 1)).any(-1))
 
-    def guard_receive_sv(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_SV)
-                & (st["m_hdr"][k, H_VIEW] >= st["view"][i]))
+    # -- M-lane guards: message k at its dest ------------------------------
+    def guard_receive_higher_svc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_SVC)
+                & (st["m_hdr"][:, H_VIEW] > at("view")))
 
-    def guard_receive_client_request(self, st, lane):
-        i = lane // self.V
-        v = lane % self.V + 1
-        return (self._is_primary(st, i, i + 1) & (st["status"][i] == NORMAL)
-                & (st["aux_acked"][v - 1] == 0)
-                & (st["ct"][i, 0, T_EXEC] == 1))
+    def guard_receive_matching_svc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_SVC)
+                & (st["m_hdr"][:, H_VIEW] == at("view"))
+                & (at("status") == VIEWCHANGE))
 
-    def guard_receive_prepare(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_PREPARE)
-                & (st["status"][i] == NORMAL)
-                & (st["m_hdr"][k, H_VIEW] == st["view"][i])
-                & (st["m_hdr"][k, H_OP] == st["op"][i] + 1))
+    def guard_receive_higher_dvc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_DVC)
+                & (st["m_hdr"][:, H_VIEW] > at("view")))
 
-    def guard_receive_prepare_ok(self, st, k):
-        i = self._dest_i(st, k)
-        j = jnp.clip(st["m_hdr"][k, H_SRC] - 1, 0, self.R - 1)
-        return (self._recv_guard(st, k, M_PREPAREOK)
-                & self._is_primary(st, i, st["m_hdr"][k, H_DEST])
-                & (st["status"][i] == NORMAL)
-                & (st["m_hdr"][k, H_VIEW] == st["view"][i])
-                & (st["m_hdr"][k, H_OP] > st["peer_op"][i, j]))
+    def guard_receive_matching_dvc_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_DVC)
+                & (st["m_hdr"][:, H_VIEW] == at("view")))
 
-    def guard_execute_op(self, st, lane):
-        i = lane
-        opn = st["commit"][i] + 1
-        committed = (st["peer_op"][i] >= opn).sum() >= self.R // 2
-        return (self._is_primary(st, i, i + 1) & (st["status"][i] == NORMAL)
-                & (st["commit"][i] < st["op"][i]) & committed)
+    def guard_receive_sv_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_SV)
+                & (st["m_hdr"][:, H_VIEW] >= at("view")))
+
+    def guard_receive_prepare_table(self, st):
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        return (self._recv_guard(st, ..., M_PREPARE)
+                & (at("status") == NORMAL)
+                & (hdr[:, H_VIEW] == at("view"))
+                & (hdr[:, H_OP] == at("op") + 1))
+
+    def guard_receive_prepare_ok_table(self, st):
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        # peer_op at (dest, source): the dest's row, then a one-hot
+        # over the source
+        src_i = jnp.clip(hdr[:, H_SRC] - 1, 0, self.R - 1)
+        from_src = src_i[:, None] == jnp.arange(self.R, dtype=I32)
+        peer_op = jnp.where(from_src, at("peer_op"), 0).sum(-1)
+        return (self._recv_guard(st, ..., M_PREPAREOK)
+                & (self._primary(at("view"), self.R) == hdr[:, H_DEST])
+                & (at("status") == NORMAL)
+                & (hdr[:, H_VIEW] == at("view"))
+                & (hdr[:, H_OP] > peer_op))
 
     def guard_send_get_state_table(self, st):
         """[M, R] bool: SendGetState's guard for every (message k,
         rDest d + 1) at once; lane ``k * R + d`` of the action.
 
-        Everything here depends on the state alone, never on a lane:
-        under a vmap over lanes it stays unbatched and is computed
-        once a state.  SendOnce (VSR.tla:250-252) asks whether the
-        GetState record the lane would send is in the bag's domain
-        already, tombstones included.  The action's truncation
-        rewrites one replica's log, op and view and leaves the bag
-        alone, so the membership test may read the parent state.  Of
-        that record only four columns vary — view (the Prepare's), op
-        (the truncated log's length), dest (rDest) and source (the
-        Prepare's dest) — and every other column, entry and log word
-        is ``_row(M_GETSTATE)``'s.  So the bag is read once for the
-        slots that are GetState records in all but those four ([M]),
-        each message is held against each such slot on three of them
-        ([M, M]) and the fourth, dest, spreads a hit over rDest."""
-        R = self.R
+        SendOnce (VSR.tla:250-252) asks whether the GetState record
+        the lane would send is in the bag's domain already, tombstones
+        included.  The action's truncation rewrites one replica's log,
+        op and view and leaves the bag alone, so the membership test
+        may read the parent state.  Of that record only four columns
+        vary — view (the Prepare's), op (the truncated log's length),
+        dest (rDest) and source (the Prepare's dest) — and every other
+        column, entry and log word is ``_row(M_GETSTATE)``'s.  So the
+        bag is read once for the slots that are GetState records in
+        all but those four ([M]), each message is held against each
+        such slot on three of them ([M, M]) and the fourth, dest,
+        spreads a hit over rDest.  On the v5e the read of 96 lanes
+        from 8,192 tables costs nothing beside the table (0.26 ms a
+        chunk with it, 0.28 ms for the rows taken whole; CHANGES.md,
+        PR 32)."""
         hdr = st["m_hdr"]                                       # [M, NHDR]
         tmpl = self._row(M_GETSTATE)
         vary = np.zeros((self.NHDR,), bool)
@@ -1064,68 +1091,70 @@ class VSRKernel:
               & (st["m_log"] == tmpl["log"]).all((-1, -2))
               & (st["m_log_len"] == tmpl["log_len"])
               & (st["m_has_log"] == tmpl["has_log"]))           # [M]
-        # the replica columns at each message's dest: R is small, a
-        # one-hot select and not a gather a message
+        at = self._at_dest(st)
         r = hdr[:, H_DEST]                                      # [M]
-        dests = jnp.arange(1, R + 1, dtype=I32)
-        at_r = jnp.clip(r, 1, R)[:, None] == dests              # [M, R]
-
-        def at(col):
-            return jnp.where(at_r, st[col], 0).sum(-1)
         view, op = at("view"), at("op")
         cheap = (self._recv_guard(st, ..., M_PREPARE)            # all k
-                 & (self._primary(view, R) != r)
+                 & (self._primary(view, self.R) != r)
                  & (at("status") == NORMAL)
                  & (hdr[:, H_VIEW] > view) & (hdr[:, H_OP] > op + 1))
         trunc = jnp.minimum(at("commit"), at("log_len"))
         hit = (gs & (hdr[:, H_VIEW] == hdr[:, H_VIEW, None])
                & (hdr[:, H_OP] == trunc[:, None])
                & (hdr[:, H_SRC] == r[:, None]))                 # [k, k']
-        to_d = hdr[:, H_DEST, None] == dests                    # [k', R]
+        to_d = hdr[:, H_DEST, None] == self._ids                # [k', R]
         blocked = (hit[:, :, None] & to_d).any(1)               # [M, R]
-        return cheap[:, None] & (r[:, None] != dests) & ~blocked
+        return cheap[:, None] & (r[:, None] != self._ids) & ~blocked
 
-    def guard_send_get_state(self, st, lane):
-        # on the v5e this read of 96 lanes from 8,192 tables costs
-        # nothing beside the table (0.26 ms a chunk with it, 0.28 ms
-        # for the rows taken whole; CHANGES.md, PR 32)
-        return self.guard_send_get_state_table(st).reshape(-1)[lane]
+    def guard_receive_get_state_table(self, st):
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        return (self._recv_guard(st, ..., M_GETSTATE)
+                & (at("view") == hdr[:, H_VIEW])
+                & (at("status") == NORMAL)
+                & (at("op") > hdr[:, H_OP]))
 
-    def guard_receive_get_state(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_GETSTATE)
-                & (st["view"][i] == st["m_hdr"][k, H_VIEW])
-                & (st["status"][i] == NORMAL)
-                & (st["op"][i] > st["m_hdr"][k, H_OP]))
+    def guard_receive_new_state_table(self, st):
+        at = self._at_dest(st)
+        hdr = st["m_hdr"]
+        return (self._recv_guard(st, ..., M_NEWSTATE)
+                & (at("view") == hdr[:, H_VIEW])
+                & (at("status") == NORMAL)
+                & (at("op") == hdr[:, H_FIRST] - 1))
 
-    def guard_receive_new_state(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_NEWSTATE)
-                & (st["view"][i] == st["m_hdr"][k, H_VIEW])
-                & (st["status"][i] == NORMAL)
-                & (st["op"][i] == st["m_hdr"][k, H_FIRST] - 1))
+    def guard_receive_recovery_table(self, st):
+        return (self._recv_guard(st, ..., M_RECOVERY)
+                & (self._at_dest(st)("status") == NORMAL))
 
-    def guard_restart_empty(self, st, lane):
-        del lane
-        return st["aux_restart"] < self.shape.restart_limit
+    def guard_receive_recovery_response_table(self, st):
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_RECOVERYRESP)
+                & (at("rec_number") == st["m_hdr"][:, H_X])
+                & (at("status") == RECOVERING))
 
-    def guard_receive_recovery(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_RECOVERY)
-                & (st["status"][i] == NORMAL))
-
-    def guard_receive_recovery_response(self, st, k):
-        i = self._dest_i(st, k)
-        return (self._recv_guard(st, k, M_RECOVERYRESP)
-                & (st["rec_number"][i] == st["m_hdr"][k, H_X])
-                & (st["status"][i] == RECOVERING))
-
-    def guard_complete_recovery(self, st, lane):
-        i = lane
-        cand = (st["rec"][i] == 1) & (st["rec_has_log"][i] == 1)
-        return ((st["status"][i] == RECOVERING)
-                & ((st["rec"][i] == 1).sum() > self.R // 2)
-                & cand.any())
+    guard_timer_send_svc = lanes_of(guard_timer_send_svc_table)
+    guard_receive_higher_svc = lanes_of(guard_receive_higher_svc_table)
+    guard_receive_matching_svc = lanes_of(
+        guard_receive_matching_svc_table)
+    guard_send_dvc = lanes_of(guard_send_dvc_table)
+    guard_receive_higher_dvc = lanes_of(guard_receive_higher_dvc_table)
+    guard_receive_matching_dvc = lanes_of(
+        guard_receive_matching_dvc_table)
+    guard_send_sv = lanes_of(guard_send_sv_table)
+    guard_receive_sv = lanes_of(guard_receive_sv_table)
+    guard_receive_client_request = lanes_of(
+        guard_receive_client_request_table)
+    guard_receive_prepare = lanes_of(guard_receive_prepare_table)
+    guard_receive_prepare_ok = lanes_of(guard_receive_prepare_ok_table)
+    guard_execute_op = lanes_of(guard_execute_op_table)
+    guard_send_get_state = lanes_of(guard_send_get_state_table)
+    guard_receive_get_state = lanes_of(guard_receive_get_state_table)
+    guard_receive_new_state = lanes_of(guard_receive_new_state_table)
+    guard_restart_empty = lanes_of(guard_restart_empty_table)
+    guard_receive_recovery = lanes_of(guard_receive_recovery_table)
+    guard_receive_recovery_response = lanes_of(
+        guard_receive_recovery_response_table)
+    guard_complete_recovery = lanes_of(guard_complete_recovery_table)
 
     def _guard_fns(self):
         return [
