@@ -32,6 +32,7 @@ from tpuvsr.engine.spec import load_spec
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark", "tools"))
 import checkpoint_recovery_reference as reference  # noqa: E402
+import quorum_counts  # noqa: E402
 from checkpoint_recovery_reference import (ANY_DEST, NIL, NOOP,  # noqa: E402
                                            NORMAL, RECOVERING,
                                            STATE_TRANSFER, Msg)
@@ -49,9 +50,33 @@ DEPTH = 6
 TRIO = set(reference.STATE_TRANSFER_ACTIONS)
 CHAIN = set(reference.CHECKPOINT_RECOVERY_ACTIONS)
 BATCH = 128
+# ST03's six (the two quorum counters since PR 49), then CP06's four
 STATS = ("state_transfer_states", "bag_slots", "bag_tombstones",
-         "bag_peak", "recovering_states", "gc_states", "rec_set_peak",
-         "dvc_set_peak")
+         "bag_peak", "quorum_waiting_states", "svc_quorum_waiting_states",
+         "recovering_states", "gc_states", "rec_set_peak", "dvc_set_peak")
+
+
+def quorum_waits(state, constants):
+    """`ST03Kernel`'s two quorum counters as this kernel carries them
+    (the reference's `commit_stats` is older than they are): a replica
+    in ViewChange whose DoViewChange is still to send has processed
+    some StartViewChanges of its view and fewer than f (tombstones, as
+    ST03 counts them: never at three replicas), or whose StartView is
+    still to send holds some DoViewChanges and fewer than f + 1 (the
+    receive-set, as this family counts them since AS04)."""
+    # the StartViewChange half is ST03's own count (the states share
+    # its field names); its tombstone count of DoViewChanges is not
+    # what this family's SendSV reads
+    svc_waits, _dvc_tombstones = quorum_counts.waiting(state, constants)
+    need = constants.replicas // 2 + 1
+    dvc_waits = any(
+        status == reference.VIEW_CHANGE and not sent
+        and 0 < len(received) < need
+        for status, sent, received in zip(
+            state.rep_status, state.rep_sent_sv, state.rep_recv_dvc))
+    return {"quorum_waiting_states": svc_waits or dvc_waits,
+            "svc_quorum_waiting_states": svc_waits}
+
 
 @pytest.fixture(scope="module")
 def spec():
@@ -381,7 +406,8 @@ def compare(spec, model, constants):
                 assert list(ok[i]) == [
                     reference.INVARIANT_FNS[n](state, constants)
                     for n in inv_names], state
-                host = reference.commit_stats(state)
+                host = dict(reference.commit_stats(state),
+                            **quorum_waits(state, constants))
                 assert list(stats[i]) == [int(host[n]) for n in STATS], \
                     state
         return fired
@@ -624,7 +650,8 @@ def _build(name, spec):
 
 
 @pytest.mark.parametrize("name", ENGINES)
-def test_engine_levels_equal_the_references(name, spec, ref_run):
+def test_engine_levels_equal_the_references(name, spec, ref_run,
+                                            constants):
     eng = _build(name, spec)
     res = eng.run(max_depth=DEPTH)
     assert res.ok and res.error == f"depth limit {DEPTH} reached"
@@ -640,7 +667,11 @@ def test_engine_levels_equal_the_references(name, spec, ref_run):
         assert sum(fired.values()) + 1 == res.states_generated \
             == ref_run["generated"]
         assert sum(fired[a] for a in CHAIN) * 4 > sum(fired.values())
-        committed = ref_run["committed"]
+        committed = dict(ref_run["committed"])
+        for state in (s for level in ref_run["levels"][1:] for s in level):
+            for stat, waits in quorum_waits(state, constants).items():
+                committed[stat] = committed.get(stat, 0) + int(waits)
+        assert committed["svc_quorum_waiting_states"] == 0
         for stat in STATS:
             got = (res.metrics["gauges"] if stat.endswith("_peak")
                    else counters).get(stat, 0)

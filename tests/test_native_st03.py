@@ -15,7 +15,6 @@ the real `.tla`: the interpreter's fixpoint and level sizes at
 
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -24,15 +23,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from tpuvsr.core.values import FnVal, TLAError, mk_record
+from tests.st03_reference import (MODULE, REPO, STATS, explore,
+                                  make_compare, quorum_counts, reference,
+                                  to_tlc)
+from tpuvsr.core.values import TLAError
 from tpuvsr.engine.spec import load_spec
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "benchmark", "tools"))
-import state_transfer_reference as reference  # noqa: E402
-from state_transfer_reference import Msg  # noqa: E402
-
-MODULE = "VR_STATE_TRANSFER"
+Msg = reference.Msg
 CFG = os.path.join(REPO, "benchmark", "configs", "vr-state-transfer.cfg")
 MAX_MSGS = 24
 # the reference's level sizes at the cell's constants (depth 8)
@@ -154,107 +151,11 @@ def test_reference_levels_at_the_cells_constants(ref_run):
 # ---------------------------------------------------------------------
 # (b), (c) the kernel against the reference, state by state
 # ---------------------------------------------------------------------
-def to_tlc(state, spec):
-    """A reference `State` as the TLC-valued dict the codec encodes."""
-    c = spec.cfg.constants
-    value = {v.name: v for v in c["Values"]}
-    reps = range(1, len(state.rep_status) + 1)
-
-    def fn(values, conv=lambda x: x):
-        return FnVal((r, conv(values[r - 1])) for r in reps)
-
-    def log(entries, first=1):
-        return FnVal((first + i, mk_record(operation=value[v]))
-                     for i, v in enumerate(entries))
-
-    def msg(m):
-        f = dict(type=c[m.type], view_number=m.view_number,
-                 dest=c["AnyDest"] if m.dest == reference.ANY_DEST
-                 else m.dest, source=m.source)
-        for k in ("op_number", "commit_number", "last_normal_vn",
-                  "first_op"):
-            if getattr(m, k) is not None:
-                f[k] = getattr(m, k)
-        if m.message is not None:
-            f["message"] = mk_record(operation=value[m.message])
-        if m.log is not None:
-            f["log"] = log(m.log, m.first_op or 1)
-        return mk_record(**f)
-
-    return {
-        "replicas": frozenset(reps),
-        "rep_status": fn(state.rep_status, lambda s: c[s]),
-        "rep_view_number": fn(state.rep_view_number),
-        "rep_op_number": fn(state.rep_op_number),
-        "rep_commit_number": fn(state.rep_commit_number),
-        "rep_last_normal_view": fn(state.rep_last_normal_view),
-        "rep_log": fn(state.rep_log, log),
-        "rep_peer_op_number": fn(
-            state.rep_peer_op_number,
-            lambda row: FnVal((p, row[p - 1]) for p in reps)),
-        "rep_sent_dvc": fn(state.rep_sent_dvc),
-        "rep_sent_sv": fn(state.rep_sent_sv),
-        "no_progress": fn(state.no_progress),
-        "no_progress_ctr": state.no_progress_ctr,
-        "messages": FnVal((msg(m), n) for m, n in state.messages),
-        "aux_svc": state.aux_svc,
-        "aux_client_acked": FnVal((value[v], a)
-                                  for v, a in state.aux_client_acked),
-    }
-
-
 @pytest.fixture(scope="module")
 def compare(spec, model, constants):
-    """compare(states): every state's kernel successors, as sets per
-    action name, equal the reference's; every guard equals its
-    action's enabling; every cfg invariant's kernel function equals
-    the reference's.  Returns the actions that fired."""
-    codec, kern = model
-    names = kern.action_names
-    lane_action = np.asarray(kern.lane_action)
-    guards = kern._guard_fns()
-
-    def guard_lanes(st):
-        return jnp.concatenate([
-            jax.vmap(lambda ln, g=g: g(st, ln))(
-                jnp.arange(kern._lane_count(n), dtype=jnp.int32))
-            for n, g in zip(names, guards)])
-    guard_batch = jax.jit(jax.vmap(guard_lanes))
-    inv_names = list(spec.cfg.invariants)
-    inv_batch = jax.jit(jax.vmap(lambda st: jnp.stack(
-        [kern.invariant_fn([n])(st) for n in inv_names])))
-
-    def run(states):
-        fired = set()
-        for lo in range(0, len(states), BATCH):
-            part = states[lo:lo + BATCH]
-            tlc = [to_tlc(s, spec) for s in part]
-            dense = [codec.encode(t) for t in tlc]
-            dense += [dense[-1]] * (BATCH - len(part))  # one program
-            batch = {k: np.stack([d[k] for d in dense]) for k in dense[0]}
-            succs, en = kern.step_batch(batch)
-            en = np.asarray(en)
-            assert np.array_equal(np.asarray(guard_batch(batch)), en)
-            ok = np.asarray(inv_batch(batch))
-            succs = {k: np.asarray(v) for k, v in succs.items()}
-            for i, state in enumerate(part):
-                assert codec.decode(dense[i]) == tlc[i]
-                got = set()
-                for lane in np.flatnonzero(en[i]):
-                    assert succs["err"][i, lane] == 0
-                    got.add((names[lane_action[lane]], reference.from_tlc(
-                        codec.decode({k: v[i, lane]
-                                      for k, v in succs.items()}),
-                        constants)))
-                want = set(reference.successors(state, constants))
-                assert got == want, (state, sorted(
-                    a for a, _ in got ^ want))
-                fired |= {a for a, _ in want}
-                assert list(ok[i]) == [
-                    reference.INVARIANT_FNS[n](state, constants)
-                    for n in inv_names], state
-        return fired
-    return run
+    """The state-by-state comparison of `tests/st03_reference.py` at
+    this file's cfg."""
+    return make_compare(spec, model, constants, BATCH)
 
 
 def test_kernel_equals_reference_on_levels_0_to_7(compare, ref_run):
@@ -295,21 +196,9 @@ def test_state_transfer_subtree(compare, constants):
     addressed to AnyDest is delivered by each eligible replica but its
     sender."""
     start = _state_transfer_start(constants)
-    seen, frontier, states = {start[:reference.N_VIEW]}, [start], [start]
-    by_action = {}
-    for depth in range(6):
-        nxt = []
-        for s in frontier:
-            for action, succ in reference.successors(s, constants):
-                by_action.setdefault(action, []).append((s, succ))
-                if succ[:reference.N_VIEW] in seen:
-                    continue
-                if depth >= 4 and action not in TRIO:
-                    continue
-                seen.add(succ[:reference.N_VIEW])
-                nxt.append(succ)
-        frontier = nxt
-        states += nxt
+    states, by_action = explore(
+        start, constants, 6,
+        follow=lambda depth, action: depth < 4 or action in TRIO)
     assert TRIO <= set(by_action)
     # AnyDest: the GetState of replica 3 from the crafted state is
     # answered by 1 (one entry) and by 2 (two), never by 3 itself
@@ -399,7 +288,8 @@ def _build(name, spec):
 
 
 @pytest.mark.parametrize("name", ENGINES)
-def test_engine_levels_equal_the_references(name, spec, ref_run):
+def test_engine_levels_equal_the_references(name, spec, ref_run,
+                                            constants):
     eng = _build(name, spec)
     res = eng.run(max_depth=DEPTH)
     assert res.ok and res.error == f"depth limit {DEPTH} reached"
@@ -422,19 +312,29 @@ def test_engine_levels_equal_the_references(name, spec, ref_run):
         assert counters.get("state_transfer_states", 0) == 0
         assert res.metrics["gauges"]["bag_peak"] \
             == ref_run["bag_peak"] <= MAX_MSGS
+        # one record IS the StartViewChange quorum at three replicas:
+        # nobody ever waits on one; on a DoViewChange quorum the new
+        # primary does, with its own record in
+        want = quorum_counts.committed(ref_run["levels"], constants)
+        assert counters["quorum_waiting_states"] \
+            == want["quorum_waiting_states"] > 0
+        assert counters.get("svc_quorum_waiting_states", 0) \
+            == want["svc_quorum_waiting_states"] == 0
 
 
 def test_commit_stats_count_a_state_transfer_state(model, constants, spec):
     codec, kern = model
     names = [n for n, _how in kern.COMMIT_STATS]
-    assert names == ["state_transfer_states", "bag_slots",
-                     "bag_tombstones", "bag_peak"]
+    assert tuple(names) == STATS
     start = _state_transfer_start(constants)
     asked = next(succ for action, succ in
                  reference.successors(start, constants)
                  if action == "SendGetState")
     stats = jax.jit(kern.commit_stats)
-    for state, want in ((start, [0, 5, 1, 5]), (asked, [1, 6, 1, 6])):
+    # replica 1's Prepare is delivered: no view change anywhere, so no
+    # quorum is waited on
+    for state, want in ((start, [0, 5, 1, 5, 0, 0]),
+                        (asked, [1, 6, 1, 6, 0, 0])):
         assert list(np.asarray(stats(codec.encode(
             to_tlc(state, spec))))) == want
 

@@ -68,7 +68,10 @@ NATIVE_DOOR = {
         "VR_STATE_TRANSFER", "benchmark/configs/vr-state-transfer.cfg"),
     "VR_REPLICA_RECOVERY_CP:native-door": (
         "VR_REPLICA_RECOVERY_CP",
-        "benchmark/configs/vr-replica-recovery-cp.cfg")}
+        "benchmark/configs/vr-replica-recovery-cp.cfg"),
+    # five replicas: replica ids up to 5 in the dest / source columns
+    "VR_STATE_TRANSFER:native-door-r5": (
+        "VR_STATE_TRANSFER", "benchmark/configs/vr-state-transfer-r5.cfg")}
 
 
 def _layout_spec(mod, max_msgs=6):

@@ -321,6 +321,12 @@ class AS04Kernel(ST03Kernel):
         return (super().guard_receive_matching_svc(st, k)
                 & (st["sent_dvc"][i] == 0))
 
+    def _dvc_quorum(self, st):
+        """([R], need): from here on the family keeps the DoViewChanges
+        a replica has received in its ``dvc`` receive-set, one slot a
+        source; what SendSV counts and ``commit_stats`` reads."""
+        return (st["dvc"] == 1).sum(-1), self.R // 2 + 1
+
     def guard_send_sv(self, st, lane):
         i = lane
         return (self._can_progress(st, i)

@@ -122,13 +122,13 @@ class CP06Kernel(RR05Kernel):
             else jnp.zeros((self.MAX_OPS,), I32)
         return row
 
-    #: ST03's four, then what this module adds (the same hook)
+    #: ST03's six, then what this module adds (the same hook)
     COMMIT_STATS = ST03Kernel.COMMIT_STATS + (
         ("recovering_states", "sum"), ("gc_states", "sum"),
         ("rec_set_peak", "max"), ("dvc_set_peak", "max"))
 
     def commit_stats(self, st):
-        """[8] uint32 of one state: ST03's four, whether a replica is
+        """[10] uint32 of one state: ST03's six, whether a replica is
         Recovering, whether a replica's log has a garbage-collected
         (NoOp) prefix (HighestGCedOp > 0), and the fullest
         RecoveryResponse and DoViewChange receive-set, in records of

@@ -41,6 +41,21 @@ the zero state in view 1 (CP06's with its own planes: ``rep_app_state``,
 ``aux_restart``), and their action locations are the line ranges the
 kernel class itself cites.  The other five modules are refused by name
 until each has one.
+
+**Which (module, ReplicaCount) the door admits.**  Every committed
+trace is a state of three replicas, and Init is that state wherever
+the cfg binds ``ReplicaCount = 3``.  At another R a trace cannot speak,
+but "the codec's zero state in view 1" can: for a module ``INIT_AT_R``
+lists, Init at a listed R is what the module's codec decodes for that
+state at that R, and the rule is held to the committed trace every
+time it is used (the same rule at the trace's own R must give the
+trace's entry 1, value for value).  The table lists only what a tier-1
+test holds to a plain reference: VR_STATE_TRANSFER at 3 and 5
+(``tests/test_native_st03_r5.py``,
+``benchmark/tools/state_transfer_reference.py``).  VSR and
+VR_REPLICA_RECOVERY_CP have no reference that reads R and stay at 3:
+any other R there, an even R and an R the table lacks are refused with
+the R named — never a silently wrong state space.
 """
 
 from __future__ import annotations
@@ -65,6 +80,11 @@ INIT_TRACES = {
     "VR_REPLICA_RECOVERY_CP": os.path.join(
         REPO, "examples", "VR_REPLICA_RECOVERY_CP_init_trace.txt"),
 }
+
+# module name -> the ReplicaCounts at which Init is "the module's codec's
+# zero state in view 1", the committed trace's own R among them (see the
+# module doc); a module that is not here starts from its trace alone
+INIT_AT_R = {"VR_STATE_TRANSFER": (3, 5)}
 
 # module name -> cfg SYMMETRY definition name -> the constant set the
 # definition permutes (VSR.tla:151: symmValues == Permutations(Values))
@@ -129,9 +149,13 @@ class NativeSpec:
     # -- checkable interface (engine/spec.SpecModel's) ------------------
     def init_states(self):
         from ..frontend.trace_parse import parse_trace_text
+        name = self.module.name
         first, *rest = re.split(r"\],\s*\n\[", self._trace_text.strip(), 1)
         st = parse_trace_text(first + "]\n>>" if rest else first,
                               self)[0].state
+        R = self.cfg.constants.get("ReplicaCount")
+        if name in INIT_AT_R and R != len(st["replicas"]):
+            st = self._zero_init_at(R, st)
         codec, _, _ = self._model_for(st)
         try:
             fits = codec.decode(codec.encode(st)) == st
@@ -139,10 +163,37 @@ class NativeSpec:
             fits = False
         if not fits:
             raise TLAError(
-                f"native spec {self.module.name!r}: the committed init "
-                f"state ({INIT_TRACES[self.module.name]}) does not fit "
-                f"this cfg's constants")
+                f"native spec {name!r}: the committed init "
+                f"state ({INIT_TRACES[name]}) does not fit "
+                f"this cfg's constants (ReplicaCount = {R})")
         yield st
+
+    def _zero_init_at(self, R, anchor):
+        """Init at a ReplicaCount the committed trace does not have:
+        the codec's zero state in view 1 at the cfg's constants,
+        admitted only where ``INIT_AT_R`` lists (module, R) and only
+        while the same rule at the trace's own R gives `anchor`, the
+        trace's entry 1."""
+        name = self.module.name
+        if R not in INIT_AT_R[name]:
+            raise TLAError(
+                f"native spec {name!r}: ReplicaCount = {R} is not "
+                f"admitted; Init is held to a plain reference at "
+                f"ReplicaCount in {list(INIT_AT_R[name])} "
+                f"(models/native.INIT_AT_R)")
+
+        def zero_in_view_1(constants):
+            codec = self._codec_cls(constants, max_msgs=16)
+            zero = codec.zero_state()
+            zero["view"][:] = 1
+            return codec.decode(zero)
+        if zero_in_view_1(dict(self.cfg.constants, ReplicaCount=len(
+                anchor["replicas"]))) != anchor:
+            raise TLAError(
+                f"native spec {name!r}: the committed init state "
+                f"({INIT_TRACES[name]}) is not the codec's zero state "
+                f"in view 1, so it says nothing of ReplicaCount = {R}")
+        return zero_in_view_1(self.cfg.constants)
 
     def check_invariants(self, state):
         """Name of the first cfg invariant the kernel's invariant fn

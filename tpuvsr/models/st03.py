@@ -72,7 +72,11 @@ def shape_from_cfg(constants, max_msgs=None):
     T = constants["StartViewOnTimerLimit"]
     np_limit = constants.get("NoProgressChangeLimit", 0)
     if max_msgs is None:
-        max_msgs = 8 * (1 + T)
+        # the bag gains R - 1 records a broadcast: 8 * (1 + T) at
+        # R = 3, as it always was (24 at timer 2: bag peak 21 through
+        # depth 13), and 48 at R = 5, timer 2 (4 slots a level there:
+        # 32 through depth 8)
+        max_msgs = 4 * (R - 1) * (1 + T)
     return ST03Shape(R=R, V=V, MAX_OPS=V, MAX_MSGS=max_msgs,
                      MAX_VIEW=1 + T, timer_limit=T, np_limit=np_limit)
 

@@ -147,18 +147,26 @@ class ST03Kernel:
     #: the level program counts over the states it commits (the hook
     #: ``DeviceBFS`` / ``PagedBFS`` read, fused commit)
     COMMIT_STATS = (("state_transfer_states", "sum"), ("bag_slots", "sum"),
-                    ("bag_tombstones", "sum"), ("bag_peak", "max"))
+                    ("bag_tombstones", "sum"), ("bag_peak", "max"),
+                    ("quorum_waiting_states", "sum"),
+                    ("svc_quorum_waiting_states", "sum"))
 
     def commit_stats(self, st):
-        """[4] uint32 of one state: whether a replica is in
+        """[6] uint32 of one state: whether a replica is in
         StateTransfer, the bag's present slots (twice: summed, and the
-        run's peak, what ``max_msgs`` is sized by) and those at count
-        0, the tombstones the quorum guards scan (ST03:595-600, 703)."""
+        run's peak, what ``max_msgs`` is sized by), those at count
+        0, the tombstones the quorum guards scan (ST03:595-600, 703),
+        whether a replica waits on a partly filled StartViewChange or
+        DoViewChange quorum, and on a StartViewChange quorum alone
+        (``_quorum_waiting``: never at R = 3, where one record is the
+        quorum)."""
         present = st["m_present"] == 1
         slots = present.sum()
+        svc, dvc = self._quorum_waiting(st)
         return jnp.stack([(st["status"] == STATETRANSFER).any(), slots,
                           (present & (st["m_count"] == 0)).sum(),
-                          slots]).astype(jnp.uint32)
+                          slots, (svc | dvc).any(), svc.any()
+                          ]).astype(jnp.uint32)
 
     def _nmsg(self):
         # hdr + entry + log + count
@@ -650,6 +658,31 @@ class ST03Kernel:
             & (hdr[:, H_VIEW, None] == st["view"])              # [M, R]
         return mine.sum(0)
 
+    def _svc_quorum(self, st):
+        """([R], need): the StartViewChanges each replica has processed
+        in its view and the f of them SendDVC needs (ST03:595-600; the
+        sender is implicit).  What the guard and the counters read."""
+        return self._processed(st, M_SVC), self.R // 2
+
+    def _dvc_quorum(self, st):
+        """([R], need): the DoViewChanges each replica has processed in
+        its view, its own among them, and the f + 1 SendSV needs
+        (ST03:669-674, 703)."""
+        return self._processed(st, M_DVC), self.R // 2 + 1
+
+    def _quorum_waiting(self, st):
+        """([R], [R]): replica i waits on its StartViewChange /
+        DoViewChange quorum — in ViewChange with the quorum's send
+        still to make, at least one record counted toward it and fewer
+        than it needs.  At R = 3 SendDVC needs one record, so the first
+        half is False by construction."""
+        def waits(sent, quorum):
+            counted, need = quorum
+            return ((st["status"] == VIEWCHANGE) & (st[sent] == 0)
+                    & (counted > 0) & (counted < need))
+        return (waits("sent_dvc", self._svc_quorum(st)),
+                waits("sent_sv", self._dvc_quorum(st)))
+
     # -- R-lane guards ----------------------------------------------------
     def guard_timer_send_svc_table(self, st):                   # [R]
         return ((st["aux_svc"] < self.shape.timer_limit)
@@ -658,14 +691,14 @@ class ST03Kernel:
                                         self._ids))
 
     def guard_send_dvc_table(self, st):                         # [R]
+        counted, need = self._svc_quorum(st)
         return ((st["no_prog"] == 0) & (st["status"] == VIEWCHANGE)
-                & (st["sent_dvc"] == 0)
-                & (self._processed(st, M_SVC) >= self.R // 2))
+                & (st["sent_dvc"] == 0) & (counted >= need))
 
     def guard_send_sv_table(self, st):                          # [R]
+        counted, need = self._dvc_quorum(st)
         return ((st["no_prog"] == 0) & (st["status"] == VIEWCHANGE)
-                & (st["sent_sv"] == 0)
-                & (self._processed(st, M_DVC) >= self.R // 2 + 1))
+                & (st["sent_sv"] == 0) & (counted >= need))
 
     def guard_receive_client_request_table(self, st):           # [R, V]
         rep = (st["no_prog"] == 0) \
