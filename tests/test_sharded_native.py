@@ -127,6 +127,18 @@ def test_defect_counts_equal_the_single_device_engine(built):
     assert list(one.level_sizes) == built.first_levels
     assert res.distinct_states == built.first.distinct_states
     assert res.states_generated == built.first.states_generated
+    # per action too, and the sharded step ran them in the blocks of
+    # the four-chip cell's shape (caps of 128 and 96 slots, blocks of
+    # 32: ISSUE 50), most of which it skipped
+    acts = built.first.metrics["gauges"]["action_expansions"]
+    assert acts == res.metrics["gauges"]["action_expansions"]
+    from tpuvsr.engine.device_bfs import block_rows
+    assert sorted({(c, block_rows(c)) for c in built.engine._caps()}) == \
+        [(96, 32), (128, 32)]
+    c = built.first.metrics["counters"]
+    assert 0 < c["expand_blocks_run"] < c["expand_blocks_cap"] / 3
+    assert built.first.metrics["gauges"]["occupancy"] == round(
+        sum(acts.values()) / (c["expand_blocks_run"] * 32), 4)
 
 
 def _old_start_frontier(engine, rows, counts0):

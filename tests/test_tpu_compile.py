@@ -217,10 +217,10 @@ def test_sharded_control_scalars(four_chip_engine, no_cache):
     eng = four_chip_engine
     D, A = eng.D, len(eng.kern.action_names)
     vec = jax.ShapeDtypeStruct((D,), jnp.int32, sharding=eng._sh)
-    _compile(eng._pack_scalars.lower(
-        vec, vec, vec, vec, vec,
-        jax.ShapeDtypeStruct((D, A), jnp.uint32, sharding=eng._sh)),
-        f"sharded control scalars D={D} A={A}")
+    counts = jax.ShapeDtypeStruct((D, A), jnp.uint32, sharding=eng._sh)
+    _compile(eng._pack_scalars.lower(vec, vec, vec, vec, vec, counts,
+                                     counts),
+             f"sharded control scalars D={D} A={A}")
 
 
 @pytest.mark.parametrize("what", ["insert", "stats", "page-out"])

@@ -155,13 +155,15 @@ def _align8(n):
 # (growth and calibration both): the needs creep up level by level as
 # frontier states grow richer, and 2x was breached five times in the
 # 24-level flagship run — six compiles of the level program.  What the
-# padding costs the one-chip level program since ISSUE 30: the headroom
-# gate (a tile commits only while the next buffer has room for the sum
-# of the caps) and the `nonzero` of each action's segment.  Stage 2
-# expands only the blocks that hold enabled lanes (EXPAND_BLOCK) and
-# appends them to a dense queue; stage 3 walks what was appended
-# (COMMIT_PIECE).  The sharded step still runs its own stages 2 and 3
-# at the sum of the caps.
+# padding costs a level program since ISSUE 30: the headroom gate of
+# the one-chip body (a tile commits only while the next buffer has room
+# for the sum of the caps) and the `nonzero` of each action's segment.
+# Stage 2 expands only the blocks that hold enabled lanes (EXPAND_BLOCK,
+# `Stage2`: the one-chip body's and, since ISSUE 50, the sharded
+# step's) and appends them to a dense queue.  The one-chip stage 3
+# walks what was appended (COMMIT_PIECE); the sharded step's dedup,
+# buckets and exchange still read a queue as wide as the sum of the
+# caps.
 CAP_HEADROOM = 4
 
 
@@ -187,15 +189,23 @@ CAP_START = 4
 # (PERF.md, PR 28): at 128 the run commits 33,431 and 33,234 states/s
 # (12,279 and 12,287 with one call over every cap lane), at 64 33,874
 # and 33,960 on the same seeds.  1.3-2.2 % does not pay for twice the
-# device events a second, so 128: the size the sharded step's segments
-# already showed to be bound by lanes, not by launches.
+# device events a second, so 128 for every segment wider than that:
+# every cap of a tile of 128 states (384 and up).
 EXPAND_BLOCK = 128
 
 
 def block_rows(cap):
-    """Slots in one block of a segment of `cap`: a block never exceeds
-    its action's cap."""
-    return min(EXPAND_BLOCK, cap)
+    """Slots in one block of a segment of `cap`.  A segment no wider
+    than EXPAND_BLOCK would be one block with nothing to skip: the
+    sharded step's caps at its tile of 32 states are 128 and 96, of
+    which 9 % hold a lane (PERF.md, PR 50).  Such a segment is walked
+    in quarters of EXPAND_BLOCK (that step on one v5e chip, the defect
+    window to depth 9: 29,608 states/s in blocks of 16, 36,040 of 32,
+    35,672 of 64; 18,840 with every cap slot expanded); a block never
+    exceeds its action's cap."""
+    if cap > EXPAND_BLOCK:
+        return EXPAND_BLOCK
+    return min(cap, max(1, EXPAND_BLOCK // 4))
 
 
 # Lanes of the tile-local commit queue that stage 3 of the fused body
@@ -256,6 +266,291 @@ def slot_error(codec):
             f"{getattr(codec.shape, 'restart_limit', 0)}), or a second "
             f"recovery response of one source to one nonce; the run "
             f"stops here and drops nothing (models/vsr.py, layout)")
+
+
+class Stage2:
+    """Stage 2 of the fused tile body for one built kernel, the same
+    for every engine that runs it (ISSUE 50): the one-chip level
+    program (`DeviceBFS._fused_body_factory`, `PagedBFS` through it)
+    and the sharded step (`parallel/sharded_bfs.make_sharded_level`).
+
+    `tile_pass(caps, width)` gives the function a tile body calls: per
+    action the `nonzero` of its enabled segment, then ONLY the blocks
+    of `block_rows(cap)` slots that hold an enabled lane (stage 1
+    counted them exactly) are gathered, expanded, fingerprinted,
+    invariant-checked and packed, and appended at the running end of
+    one dense tile-local queue.  What commits the queue (stage 3: a
+    local insert and scatter, or ownership buckets and an exchange)
+    is the engine's own.
+
+    The per-successor stages (`fp_stage`, `inv_stage`, and the block
+    stages made on demand) are traced once for THIS kernel: a grown
+    message table is a new kernel and a new `Stage2`, a grown cap
+    finds its stages traced."""
+
+    def __init__(self, model, incremental, stat_fn=None,
+                 count_moved=False):
+        self.kern, self.pk, self.canon = model.kern, model.pk, model.canon
+        # the incremental hash reconstitutes a fingerprint from the
+        # parent's per-row parts; the orbit-least image of a canon run
+        # cannot be, and forces the full hash (the orbit-factor state
+        # cut dwarfs the incremental saving)
+        self.incremental = incremental and self.canon is None
+        # the kernel's counts over committed states (``commit_stats``)
+        # and whether a slot records that its least image is not the
+        # identity's: a queue plane each, for the engine that reads it
+        self.stat_fn, self.count_moved = stat_fn, count_moved
+        kern = self.kern
+        self.fp_stage = trace_once(
+            kern.fingerprint_incremental if self.incremental
+            else self._fingerprint_least
+            if self.canon is not None else kern.fingerprint,
+            "fingerprint")
+        self.inv_stage = trace_once(model.inv, "invariants")
+        self._expand_stages = {}    # (action, block rows) -> stage
+        self._pack_stages = {}      # block rows -> stage
+        # the types of one unpacked state row, of one queue row and of
+        # a row's hash parts
+        if self.pk is not None:
+            self.row = jax.eval_shape(self.pk.unpack, jax.ShapeDtypeStruct(
+                (self.pk.words,), jnp.uint32))
+            self.qrow = jax.ShapeDtypeStruct((self.pk.words,), jnp.uint32)
+        else:
+            self.row = {k: jax.ShapeDtypeStruct(np.shape(v), np.int32)
+                        for k, v in model.codec.zero_state().items()}
+            self.qrow = self.row
+        self.parts_row = (jax.eval_shape(kern.parent_parts, self.row)
+                          if self.incremental else None)
+
+    def _fingerprint_least(self, st):
+        """``CanonSpec.fingerprint_fn`` with what the level program
+        counts as ``canon_relabelled``: (fingerprint of `st`'s least
+        orbit image, whether that image is not the identity's)."""
+        with jax.named_scope(spans.CANON):
+            image, moved = self.canon.least(st)
+        return self.kern.fingerprint(image), moved
+
+    def successor_fn(self, name, fn):
+        """Stage 2 of every tile body for one enabled (state, lane)
+        item of action `name`: expand it with `fn`, fingerprint the
+        successor, check the invariants — each under its stage scope.
+        Returns ``one(st, parts, lane) -> (successor, fingerprint,
+        enabled, invariants ok, err, relabelled)`` for the body to vmap
+        over its compacted lanes; `parts` is the parent's hash parts
+        (``kern.parent_parts``) under the incremental hash and None
+        under the full one; `relabelled` is None unless the run
+        canonicalizes.  With a kernel that counts over committed
+        states (``commit_stats``) the tuple ends with the successor's
+        stat vector."""
+        kern = self.kern
+        fp_stage, inv_stage = self.fp_stage, self.inv_stage
+        incremental = self.incremental
+        canon = self.canon is not None
+        stat_fn = self.stat_fn
+
+        def one(st, parts, lane):
+            with jax.named_scope(spans.EXPAND):
+                succ, en = fn(kern.seed_touch(st) if incremental else st,
+                              lane)
+            clean = {k: v for k, v in succ.items()
+                     if not k.startswith("_")}
+            # ISSUE 11 commit stage: under canon the fingerprint is
+            # taken on the canonical orbit image while the staged queue
+            # keeps the generated state — orbit-mates dedup to one
+            # committed representative
+            moved = None
+            with jax.named_scope(spans.FINGERPRINT):
+                if incremental:
+                    fp = fp_stage(succ, kern.lane_replica(name, st, lane),
+                                  parts, st)
+                elif canon:
+                    fp, moved = fp_stage(clean)
+                else:
+                    fp = fp_stage(clean)
+            with jax.named_scope(spans.INVARIANTS):
+                iok = inv_stage(clean)
+            out = (clean, fp, en, iok, clean["err"], moved)
+            return out + (stat_fn(clean),) if stat_fn else out
+
+        return one
+
+    def expand_stage(self, aid, rows, sharding=None):
+        """One block of `rows` compacted items of action `aid`:
+        `successor_fn` under `vmap`, traced HERE, outside the loops,
+        and inlined where the tile pass's block loop uses it.  The loop
+        is a `while` inside the tile loop's `while` inside `jit`, and
+        the 19 action functions cost twice the Python seconds when
+        they are traced from in there (v5e host, the small config:
+        10.3 s against 6.1 s with one `vmap` in the tile loop; 6.3 s
+        so).  `sharding` is what the types of the loop's values carry
+        (`tile_pass`)."""
+        key = (aid, rows)
+        if key not in self._expand_stages:
+            kern = self.kern
+
+            def batch(s):
+                return jax.ShapeDtypeStruct((rows,) + s.shape, s.dtype,
+                                            sharding=sharding)
+
+            stage = jax.jit(jax.vmap(self.successor_fn(
+                kern.action_names[aid], kern._action_fns()[aid])),
+                inline=True)
+            stage.trace(jax.tree_util.tree_map(batch, self.row),
+                        jax.tree_util.tree_map(batch, self.parts_row),
+                        jax.ShapeDtypeStruct((rows,), I32, sharding=sharding))
+            self._expand_stages[key] = stage
+        return self._expand_stages[key]
+
+    def pack_stage(self, rows, sharding=None):
+        """`pk.pack` over one block of `rows` successors, traced HERE
+        once per kernel and block size like `expand_stage`, so the 19
+        block loops share one pack instead of each tracing theirs.
+        Without a pack spec a queue row is the plane dict itself."""
+        if self.pk is None:
+            return lambda succ: succ
+        if rows not in self._pack_stages:
+            stage = jax.jit(jax.vmap(self.pk.pack), inline=True)
+            stage.trace(jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct((rows,) + s.shape, s.dtype,
+                                               sharding=sharding),
+                self.row))
+            self._pack_stages[rows] = stage
+        return self._pack_stages[rows]
+
+    def tile_pass(self, caps, width, like=None):
+        """``run(tile, en_segs, cnts, each) -> (queue, q_end, blocks)``
+        for per-action caps `caps` (slots) and a queue of `width` slots
+        (at least their sum).  The block stages are traced here: call
+        it outside the tile loop.  Under `shard_map` call it inside
+        the mapped function and pass a value of that trace as `like`:
+        the types of values there name the mesh, a stage traced on
+        types that do not is not found again by the loops (they would
+        each trace their action function anew, and every action its
+        own fingerprint and invariant stage).
+
+        `tile` holds the unpacked states of one tile, `en_segs[a]` the
+        [T, L_a] lanes of action a that are to be expanded (the guard
+        matrix, masked by whatever the engine masks it by) and `cnts`
+        their counts.  The queue is action-major and, within an
+        action, in `nonzero` order, and holds no slot of a block that
+        did not run: planes `rows` (packed where the run packs), `fp`,
+        `en`, `aid`, `pidx` (the parent's row in the tile) and `lane`,
+        and `moved` / `stat` where the engine asked for them; a slot no
+        block wrote keeps its zeros, with `en` False, and `q_end` is
+        the end of what was written.  `blocks[a]` is the number of
+        blocks action a ran.  After an action's blocks `each(aid, seg)`
+        gets its per-slot verdicts in segment order, ``seg = (pidx,
+        lane, sel_ok, en, iok, err)`` of `caps[aid]` slots each, for
+        the engine to fold into its own failure vote."""
+        kern, row, qrow = self.kern, self.row, self.qrow
+        incremental = self.incremental
+        moved_plane, stats = self.count_moved, self.stat_fn is not None
+        n_stat = len(kern.COMMIT_STATS) if stats else 0
+        sharding = None if like is None else jax.typeof(like).sharding
+        expand_of = [self.expand_stage(aid, block_rows(cap), sharding)
+                     for aid, cap in enumerate(caps)]
+        pack_of = [self.pack_stage(block_rows(cap), sharding)
+                   for cap in caps]
+
+        def put(bufs, vals, at):
+            """`vals` written into `bufs` from row `at` on.  The
+            primitive, bound bare: `at` needs no wrap-around
+            arithmetic, and 41 planes an action would each trace
+            theirs."""
+            zero = jnp.asarray(0, I32)
+            return jax.tree_util.tree_map(
+                lambda buf, v: jax.lax.dynamic_update_slice_p.bind(
+                    buf, v.astype(buf.dtype), at,
+                    *[zero] * (buf.ndim - 1)),
+                bufs, vals)
+
+        def lanes(dtype, *shape):
+            return jax.lax.full((width,) + shape, 0, dtype)
+
+        def run(tile, en_segs, cnts, each):
+            T = en_segs[0].shape[0]
+            if incremental:
+                with jax.named_scope(spans.FINGERPRINT):
+                    parts = jax.vmap(kern.parent_parts)(tile)
+            else:
+                parts = None
+            blk_segs = []
+            # the queue: a slot no block wrote keeps its zeros, with
+            # `en` False
+            queue = {
+                "rows": jax.tree_util.tree_map(
+                    lambda s: lanes(s.dtype, *s.shape), qrow),
+                "fp": lanes(jnp.uint32, 4), "en": lanes(bool),
+                "aid": lanes(I32), "pidx": lanes(I32),
+                "lane": lanes(I32)}
+            if moved_plane:
+                queue["moved"] = lanes(bool)
+            if stats:
+                queue["stat"] = lanes(jnp.uint32, n_stat)
+            q_end = jnp.asarray(0, I32)
+            for aid, name in enumerate(kern.action_names):
+                L_a = kern._lane_count(name)
+                TL = T * L_a
+                E_a = caps[aid]
+                with jax.named_scope(spans.COMPACT):
+                    en_f = en_segs[aid].reshape(TL)
+                    (sel,) = jnp.nonzero(en_f, size=E_a, fill_value=TL)
+                    sel_ok = sel < TL
+                    pidx = jnp.clip(sel // L_a, 0, T - 1).astype(I32)
+                    lane_sel = (sel % L_a).astype(I32)
+                # only the blocks of the segment that hold an enabled
+                # lane are expanded: stage 1 counted them exactly.  A
+                # slot at or past cnt in a block that ran has sel_ok
+                # False, so `en` is False there and every later read is
+                # masked by it
+                B = block_rows(E_a)
+                n_blk = (jnp.minimum(cnts[aid], E_a) + B - 1) // B
+                blk_segs.append(n_blk)
+                expand, pack = expand_of[aid], pack_of[aid]
+                aid_b = jax.lax.full((B,), aid, I32)
+
+                def block(b, out):
+                    # the last block of a cap that is no multiple of B
+                    # is clamped onto the one before it: the rows they
+                    # share are written twice, the same
+                    queue, seg = out
+                    with jax.named_scope(spans.COMPACT):
+                        lo = jnp.minimum(b * B, E_a - B)
+                        pidx_b = jax.lax.dynamic_slice_in_dim(pidx, lo, B)
+                        lane_b = jax.lax.dynamic_slice_in_dim(
+                            lane_sel, lo, B)
+                        ok_b = jax.lax.dynamic_slice_in_dim(sel_ok, lo, B)
+                        st_b = {k: v[pidx_b] for k, v in tile.items()}
+                        parts_b = jax.tree_util.tree_map(
+                            lambda v: v[pidx_b], parts)
+                    succ, fp, en2, iok, errv, moved, *stat = expand(
+                        st_b, parts_b, lane_b)
+                    # a successor is a state row: packed here, a block
+                    # at a time, it never exists unpacked at the
+                    # queue's width
+                    rows_b = pack({k: succ[k].astype(s.dtype)
+                                   for k, s in row.items()})
+                    with jax.named_scope(spans.COMPACT):
+                        item = {"rows": rows_b, "fp": fp,
+                                "en": en2 & ok_b, "aid": aid_b,
+                                "pidx": pidx_b, "lane": lane_b}
+                        if moved_plane:
+                            item["moved"] = moved
+                        if stats:
+                            item["stat"] = stat[0]
+                        queue = put(queue, item, q_end + lo)
+                        return queue, put(seg, (en2, iok, errv), lo)
+
+                no = jax.lax.full((E_a,), False, bool)
+                queue, (en2, iok, errv) = jax.lax.fori_loop(
+                    0, n_blk, block,
+                    (queue, (no, no, jax.lax.full(
+                        (E_a,), 0, row["err"].dtype))))
+                q_end = q_end + jnp.minimum(n_blk * B, E_a)
+                each(aid, (pidx, lane_sel, sel_ok, en2, iok, errv))
+            return queue, q_end, blk_segs
+
+        return run
 
 
 def _cut(v, start, rows):
@@ -498,19 +793,6 @@ class DeviceBFS:
                     len(self._need_seen) != len(names):
                 self._need_seen = np.zeros(len(names), np.int64)
         self.L = self.kern.n_lanes
-        # the per-successor stages that no action changes, traced once
-        # for THIS kernel (trace_once): the level body uses each once
-        # per action.  Canon runs hash the orbit-least image, which
-        # cannot be reconstituted from the parent's per-row hash parts:
-        # they force the full hash path (the orbit-factor state cut
-        # dwarfs the incremental saving)
-        self._fp_incremental = (self.hash_mode == "incremental"
-                                and self._canon is None)
-        self._fp_stage = trace_once(
-            self.kern.fingerprint_incremental if self._fp_incremental
-            else self._fingerprint_least
-            if self._canon is not None else self.kern.fingerprint,
-            "fingerprint")
         # what canon did, counted on the device beside the action
         # counts (the fused body; ISSUE 33): only a program that
         # canonicalizes carries the counter
@@ -527,9 +809,14 @@ class DeviceBFS:
         self._stat_sums = np.array(
             [how == "sum" for _n, how in self.kern.COMMIT_STATS]
             if self._stat_fn else [], bool)
-        self._inv_stage = trace_once(self._inv, "invariants")
-        self._expand_stages = {}    # (action, block rows) -> stage
-        self._pack_stages = {}      # block rows -> stage
+        # stage 2 and the per-successor stages that no action changes,
+        # traced once for THIS kernel (trace_once): the level body
+        # uses each once per action
+        self._stage2 = Stage2(self.model, self.hash_mode == "incremental",
+                              self._stat_fn, self._canon_counts)
+        self._fp_incremental = self._stage2.incremental
+        self._fp_stage = self._stage2.fp_stage
+        self._inv_stage = self._stage2.inv_stage
         self._level_jit = None  # the level pass, built lazily (_level)
         # obs accounting: the first dispatch after a (re)jit is charged
         # to the "compile" phase (jit traces+compiles at first call)
@@ -586,50 +873,6 @@ class DeviceBFS:
         stages' traces included."""
         return self._level(*args)
 
-    def _expand_stage(self, aid, rows, row, parts):
-        """Stage 2 of the fused body for one block of `rows` compacted
-        items of action `aid` (`row`, `parts`: the types of one state
-        row and of its hash parts): `_successor_fn` under `vmap`, traced
-        HERE, outside every trace, and inlined where the body's block
-        loop uses it.  The loop is a `while` inside the tile loop's
-        `while` inside `jit`, and the 19 action functions cost twice
-        the Python seconds when they are traced from in there (v5e
-        host, the small config: 10.3 s against 6.1 s with one `vmap`
-        in the tile loop; 6.3 s so).  Kept for the kernel, like the
-        `trace_once` stages: a cap growth or a calibration re-creates
-        the level program and finds the stage traced."""
-        key = (aid, rows)
-        if key not in self._expand_stages:
-            kern = self.kern
-
-            def batch(s):
-                return jax.ShapeDtypeStruct((rows,) + s.shape, s.dtype)
-
-            stage = jax.jit(jax.vmap(self._successor_fn(
-                kern.action_names[aid], kern._action_fns()[aid])),
-                inline=True)
-            stage.trace(jax.tree_util.tree_map(batch, row),
-                        jax.tree_util.tree_map(batch, parts),
-                        jax.ShapeDtypeStruct((rows,), I32))
-            self._expand_stages[key] = stage
-        return self._expand_stages[key]
-
-    def _pack_stage(self, rows, row):
-        """`pk.pack` over one block of `rows` successors (`row`: the
-        types of one state row), traced HERE once per kernel and block
-        size like `_expand_stage`, so the 19 block loops share one
-        pack instead of each tracing theirs.  Without a pack spec a
-        queue row is the plane dict itself."""
-        if self._pk is None:
-            return lambda succ: succ
-        if rows not in self._pack_stages:
-            stage = jax.jit(jax.vmap(self._pk.pack), inline=True)
-            stage.trace(jax.tree_util.tree_map(
-                lambda s: jax.ShapeDtypeStruct((rows,) + s.shape, s.dtype),
-                row))
-            self._pack_stages[rows] = stage
-        return self._pack_stages[rows]
-
     def _cap_floor(self, a, full):
         """Action `a`'s fused cap before the guard matrix has observed
         anything, and the floor calibration keeps: the static start,
@@ -675,58 +918,6 @@ class DeviceBFS:
             return segs
 
         return mat
-
-    def _fingerprint_least(self, st):
-        """``CanonSpec.fingerprint_fn`` with what the level program
-        counts as ``canon_relabelled``: (fingerprint of `st`'s least
-        orbit image, whether that image is not the identity's)."""
-        with jax.named_scope(spans.CANON):
-            image, moved = self._canon.least(st)
-        return self.kern.fingerprint(image), moved
-
-    def _successor_fn(self, name, fn):
-        """Stage 2 of both tile bodies for one enabled (state, lane)
-        item of action `name`: expand it with `fn`, fingerprint the
-        successor, check the invariants — each under its stage scope.
-        Returns ``one(st, parts, lane) -> (successor, fingerprint,
-        enabled, invariants ok, err, relabelled)`` for the body to vmap
-        over its compacted lanes; `parts` is the parent's hash parts
-        (``kern.parent_parts``) under the incremental hash and None
-        under the full one; `relabelled` is None unless the run
-        canonicalizes.  With a kernel that counts over committed
-        states (``commit_stats``) the tuple ends with the successor's
-        stat vector."""
-        kern = self.kern
-        fp_stage, inv_stage = self._fp_stage, self._inv_stage
-        incremental = self._fp_incremental
-        canon = self._canon is not None
-        stat_fn = self._stat_fn
-
-        def one(st, parts, lane):
-            with jax.named_scope(spans.EXPAND):
-                succ, en = fn(kern.seed_touch(st) if incremental else st,
-                              lane)
-            clean = {k: v for k, v in succ.items()
-                     if not k.startswith("_")}
-            # ISSUE 11 commit stage: under canon the fingerprint is
-            # taken on the canonical orbit image while the staged queue
-            # keeps the generated state — orbit-mates dedup to one
-            # committed representative
-            moved = None
-            with jax.named_scope(spans.FINGERPRINT):
-                if incremental:
-                    fp = fp_stage(succ, kern.lane_replica(name, st, lane),
-                                  parts, st)
-                elif canon:
-                    fp, moved = fp_stage(clean)
-                else:
-                    fp = fp_stage(clean)
-            with jax.named_scope(spans.INVARIANTS):
-                iok = inv_stage(clean)
-            out = (clean, fp, en, iok, clean["err"], moved)
-            return out + (stat_fn(clean),) if stat_fn else out
-
-        return one
 
     def _tile_body_factory(self):
         """Build the one-tile expansion body of the level pass
@@ -861,7 +1052,7 @@ class DeviceBFS:
                             parts_sel = jax.tree_util.tree_map(
                                 lambda v: v[pidx], parts)
                     succ_f, fp, en2, iok, errv, *_ = jax.vmap(
-                        self._successor_fn(name, fn))(
+                        self._stage2.successor_fn(name, fn))(
                             st_sel, parts_sel, lane_sel)
 
                     with jax.named_scope(spans.INVARIANTS):
@@ -1027,9 +1218,7 @@ class DeviceBFS:
             rule on a failing tile are preserved verbatim, so results
             are bit-identical to commit="per-action"."""
         kern = self.kern
-        pk = self._pk
         T = self.tile
-        incremental = self._fp_incremental
         n_act = len(kern.action_names)
         caps = self._expand_caps()
         total_E = sum(caps)
@@ -1042,21 +1231,7 @@ class DeviceBFS:
         canon_counts = self._canon_counts
         stats = self._stat_fn is not None
         stat_sums = self._stat_sums
-        if pk is not None:
-            row = jax.eval_shape(pk.unpack, jax.ShapeDtypeStruct(
-                (pk.words,), jnp.uint32))
-            qrow = jax.ShapeDtypeStruct((pk.words,), jnp.uint32)
-        else:
-            row = {k: jax.ShapeDtypeStruct(np.shape(v), np.int32)
-                   for k, v in self.codec.zero_state().items()}
-            qrow = row
-        parts_row = (jax.eval_shape(kern.parent_parts, row)
-                     if incremental else None)
-        expand_of = [self._expand_stage(aid, block_rows(cap), row,
-                                        parts_row)
-                     for aid, cap in enumerate(caps)]
-        pack_of = [self._pack_stage(block_rows(cap), row)
-                   for cap in caps]
+        stage2 = self._stage2.tile_pass(caps, Q)
         # ample-set POR (ISSUE 16): amat[a, b] says "expanding only a
         # is safe given an enabled b" (por.PORFilter).  POR and edge
         # emission are mutually exclusive (resolve_por blocker), so
@@ -1066,18 +1241,6 @@ class DeviceBFS:
         if por_active:
             assert not edges_on
             amat_dev = jnp.asarray(self._por.amat)
-
-        def put(bufs, vals, at):
-            """`vals` written into `bufs` from row `at` on.  The
-            primitive, bound bare: `at` needs no wrap-around
-            arithmetic, and 41 planes an action would each trace
-            theirs."""
-            zero = jnp.asarray(0, I32)
-            return jax.tree_util.tree_map(
-                lambda buf, v: jax.lax.dynamic_update_slice_p.bind(
-                    buf, v.astype(buf.dtype), at,
-                    *[zero] * (buf.ndim - 1)),
-                bufs, vals)
 
         def make_body(frontier, n_front, want_deadlock, chunk_ctx,
                       edge_bases, pdepth):
@@ -1151,97 +1314,17 @@ class DeviceBFS:
                 reason = jnp.where((reason == RUNNING) & ~room_edge,
                                    R_EDGE_FLUSH, reason)
 
-                # -- stage 2: work-queue compaction + expansion --------
-                if incremental:
-                    with jax.named_scope(spans.FINGERPRINT):
-                        parts = jax.vmap(kern.parent_parts)(tile)
-                else:
-                    parts = None
-                blk_segs = []
+                # -- stage 2: work-queue compaction + expansion, the
+                # blocks that hold enabled lanes (Stage2.tile_pass);
+                # each action's verdicts are folded as its blocks end
                 viol_any = jnp.asarray(False)
                 bag_err = jnp.asarray(False)
                 slot_err = jnp.asarray(False)
                 first_bad = jnp.asarray(n_act, I32)
 
-                def lanes(dtype, *shape):
-                    return jax.lax.full((Q,) + shape, 0, dtype)
-
-                # the commit queue: a slot no block wrote keeps its
-                # zeros, with `en` False
-                queue = {
-                    "rows": jax.tree_util.tree_map(
-                        lambda s: lanes(s.dtype, *s.shape), qrow),
-                    "fp": lanes(jnp.uint32, 4), "en": lanes(bool),
-                    "aid": lanes(I32), "pidx": lanes(I32),
-                    "lane": lanes(I32)}
-                if canon_counts:
-                    queue["moved"] = lanes(bool)
-                if stats:
-                    queue["stat"] = lanes(jnp.uint32, len(stat_sums))
-                q_end = jnp.asarray(0, I32)
-                for aid, name in enumerate(kern.action_names):
-                    L_a = kern._lane_count(name)
-                    TL = T * L_a
-                    E_a = caps[aid]
-                    with jax.named_scope(spans.COMPACT):
-                        en_f = en_segs[aid].reshape(TL)
-                        (sel,) = jnp.nonzero(en_f, size=E_a,
-                                             fill_value=TL)
-                        sel_ok = sel < TL
-                        pidx = jnp.clip(sel // L_a, 0, T - 1).astype(I32)
-                        lane_sel = (sel % L_a).astype(I32)
-                    # only the blocks of the segment that hold an
-                    # enabled lane are expanded: stage 1 counted them
-                    # exactly.  A slot at or past cnt in a block that
-                    # ran has sel_ok False, so `en` is False there and
-                    # every later read is masked by it
-                    B = block_rows(E_a)
-                    n_blk = (jnp.minimum(cnts[aid], E_a) + B - 1) // B
-                    blk_segs.append(n_blk)
-                    expand, pack = expand_of[aid], pack_of[aid]
-                    aid_b = jax.lax.full((B,), aid, I32)
-
-                    def block(b, out):
-                        # the last block of a cap that is no multiple
-                        # of B is clamped onto the one before it: the
-                        # rows they share are written twice, the same
-                        queue, seg = out
-                        with jax.named_scope(spans.COMPACT):
-                            lo = jnp.minimum(b * B, E_a - B)
-                            pidx_b = jax.lax.dynamic_slice_in_dim(
-                                pidx, lo, B)
-                            lane_b = jax.lax.dynamic_slice_in_dim(
-                                lane_sel, lo, B)
-                            ok_b = jax.lax.dynamic_slice_in_dim(
-                                sel_ok, lo, B)
-                            st_b = {k: v[pidx_b] for k, v in tile.items()}
-                            parts_b = jax.tree_util.tree_map(
-                                lambda v: v[pidx_b], parts)
-                        succ, fp, en2, iok, errv, moved, *stat = expand(
-                            st_b, parts_b, lane_b)
-                        # a successor is a state row: packed here, a
-                        # block at a time, it never exists unpacked at
-                        # the queue's width
-                        rows_b = pack({k: succ[k].astype(s.dtype)
-                                       for k, s in row.items()})
-                        with jax.named_scope(spans.COMPACT):
-                            item = {"rows": rows_b, "fp": fp,
-                                    "en": en2 & ok_b, "aid": aid_b,
-                                    "pidx": pidx_b, "lane": lane_b}
-                            if canon_counts:
-                                item["moved"] = moved
-                            if stats:
-                                item["stat"] = stat[0]
-                            queue = put(queue, item, q_end + lo)
-                            return queue, put(seg, (en2, iok, errv), lo)
-
-                    no = jax.lax.full((E_a,), False, bool)
-                    queue, (en2, iok, errv) = jax.lax.fori_loop(
-                        0, n_blk, block,
-                        (queue, (no, no, jax.lax.full(
-                            (E_a,), 0, row["err"].dtype))))
-                    q_end = q_end + jnp.minimum(n_blk * B, E_a)
-
+                def fold(aid, seg):
+                    nonlocal viol, viol_any, bag_err, slot_err, first_bad
+                    pidx, lane_sel, sel_ok, en2, iok, errv = seg
                     with jax.named_scope(spans.INVARIANTS):
                         en_s = en2 & sel_ok
                         errv = jnp.where(en_s, errv, 0)
@@ -1265,6 +1348,8 @@ class DeviceBFS:
                         bad_a = have_v | a_slot | a_bag | ovf_vec[aid]
                         first_bad = jnp.minimum(
                             first_bad, jnp.where(bad_a, aid, n_act))
+
+                queue, q_end, blk_segs = stage2(tile, en_segs, cnts, fold)
 
                 rows_q, fp_q, en_q = queue["rows"], queue["fp"], queue["en"]
                 aid_q, pidx_q, lane_q = (queue["aid"], queue["pidx"],

@@ -18,7 +18,10 @@ Who comes through here: every build of ``DeviceBFS._level``, which
 ``PagedBFS`` shares: the CLI, ``chip_smoke.py``, the benchmark's
 windows, and the served path, whose engines
 ``resilience/supervisor.py`` ``_make_engine`` constructs anew for each
-job.  ``ShardedBFS``'s ``shard_map`` step does not.
+job.  Since ISSUE 50 ``ShardedBFS``'s ``shard_map`` step does too, on a
+mesh of one process (``ShardedBFS._step_key_doc``: the mesh's axis and
+shape are part of the key, and the export says for how many devices
+it was made); a mesh of several processes builds its step as before.
 
 **The key covers every input of the trace**, or the store would hand
 back a wrong program and a wrong count.  It is a digest of:
@@ -31,7 +34,9 @@ back a wrong program and a wrong count.  It is a digest of:
   run or a place and that no trace reads (``RUN_SCOPED_ENV``: the
   trace-context triple a worker exports around each job, the profile
   directory, the spool, the host name);
-- what the engine says its trace reads (``DeviceBFS._level_key_doc``):
+- what the engine says its trace reads (``DeviceBFS._level_key_doc``;
+  ``ShardedBFS._step_key_doc`` has the mesh, the bucket and the
+  deadlock switch where that has the chunk and the hash mode):
   the spec's digest and its module's AST, the engine's class, the
   kernel and the codec (class and every plain attribute, so ``R, V, M,
   MAX_OPS, NHDR``, ``perms``, timer and restart limits, the lane

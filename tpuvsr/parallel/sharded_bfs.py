@@ -48,8 +48,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..engine import program_store
 from ..engine.checked import CheckedModel, of_model
-from ..engine.device_bfs import grown_caps, slot_error, static_cap
+from ..engine.device_bfs import (Stage2, block_rows, grown_caps, slot_error,
+                                 static_cap)
 from ..engine.fpset import dedup_batch, insert_core
 from ..obs import closes_observer, spans
 from ..resilience.faults import InjectedExchangeDrop, fault_point
@@ -211,17 +213,44 @@ R_BUCKET_GROW = 8
 R_EXPAND_GROW = 9   # fused commit: per-action compaction cap overflow
 
 
-def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
-                       tile: int, bucket_cap: int,
-                       check_deadlock: bool = False, pack_spec=None,
-                       commit: str = "fused", expand_caps=None,
-                       canon=None, por=None):
-    """Build the jitted one-tile sharded BFS step.
+def fused_caps(kern, tile, expand_caps):
+    """The fused step's per-action compaction caps, in slots: what the
+    engine asks for (`expand_caps`), at least 8 and at most every lane
+    of a tile."""
+    return [min(tile * kern._lane_count(n), max(8, int(c)))
+            for n, c in zip(kern.action_names, expand_caps)]
+
+
+# The step's arguments that its jit donates: the FPSet shards + the
+# next-frontier buffer set (args 0, 4-7).  The K-deep dispatch window
+# chains each step on the previous one's outputs, so donation means the
+# window holds ONE generation of the capacity-bound buffers instead of
+# K (ISSUE 9 — the lever that makes pipeline=2 the sharded default).
+# The frontier (1) and base_gid (9) are re-read by every dispatch of
+# the level's chain and must NOT be donated.
+STEP_DONATES = (0, 4, 5, 6, 7)
+
+
+def make_sharded_level(*args, **kwargs):
+    """The jitted one-tile sharded BFS step (`sharded_level`, which
+    documents it, under ``jax.jit`` with the step's donations)."""
+    return jax.jit(sharded_level(*args, **kwargs),
+                   donate_argnums=STEP_DONATES)
+
+
+def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
+                  tile: int, bucket_cap: int,
+                  check_deadlock: bool = False, pack_spec=None,
+                  commit: str = "fused", expand_caps=None,
+                  canon=None, por=None, stage2=None):
+    """Build the one-tile sharded BFS step, mapped over the mesh and
+    not yet jitted (`make_sharded_level`; `ShardedBFS` hands it to the
+    store of traced programs).
 
     step(tables, frontier, n_front, start_t, nb, nbp, nba, nbprm, nn,
          base_gid)
       -> (tables, nb, nbp, nba, nbprm, nn, t, reason, viol, gen, sent,
-          dead, act, need, gfull, amp)
+          dead, act, need, gfull, amp, blk)
     Every array is sharded over `axis`; scalars come back as [D] arrays
     (one per device; identical where globally agreed).  With
     ``check_deadlock`` a frontier state with no enabled successor
@@ -237,19 +266,24 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
     pack ratio (~11x on the defect layout).  Receivers never unpack:
     dedup/insert work on the fingerprints that ride alongside.
 
-    The jit DONATES the FPSet shards and the next-frontier buffer set
-    (the ISSUE 9 donation lever): each dispatch consumes the previous
-    one's buffers instead of holding K generations of them in HBM,
-    which is what lets ``pipeline=2`` be the sharded default.  The
-    read-only frontier and base_gid are NOT donated (the level's
-    dispatch chain re-reads them).
+    Its jit DONATES the FPSet shards and the next-frontier buffer set
+    (`STEP_DONATES`, the ISSUE 9 donation lever): each dispatch
+    consumes the previous one's buffers instead of holding K
+    generations of them in HBM, which is what lets ``pipeline=2`` be
+    the sharded default.  The read-only frontier and base_gid are NOT
+    donated (the level's dispatch chain re-reads them).
 
     Fused commit (ISSUE 10): with ``commit="fused"`` the per-tile
     expansion is guard-compacted — a guard matrix over every lane of
     the tile picks the enabled (state, lane) items, which are packed
-    into dense per-action segments sized by ``expand_caps`` and ONLY
-    those are expanded/fingerprinted (``step_all`` expanded all T x L
-    lanes, mostly disabled padding).  A per-action cap overflow is a
+    into dense per-action segments sized by ``expand_caps``, and ONLY
+    the blocks of a segment that hold an enabled lane are expanded,
+    fingerprinted, invariant-checked and packed, into one dense queue:
+    ``stage2``, the one-chip level program's own stage 2
+    (engine/device_bfs.Stage2) since ISSUE 50.  (``step_all`` expanded
+    all T x L lanes, mostly disabled padding, and until ISSUE 50 this
+    step every slot of every cap.)  ``blk`` counts the blocks each
+    action ran.  A per-action cap overflow is a
     new rank-agreed R_EXPAND_GROW pause carrying the exact per-action
     ``need`` so the host grows once to the true count.  The dedup that
     feeds the exchange tie-breaks on the canonical state-major flat
@@ -289,13 +323,11 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
         lane_counts = [kern._lane_count(n) for n in kern.action_names]
         seg_off = np.concatenate(
             [[0], np.cumsum(lane_counts)[:-1]]).astype(np.int32)
-        caps = [min(T * lc, max(8, int(c)))
-                for lc, c in zip(lane_counts,
-                                 expand_caps or [T] * n_act)]
+        caps = fused_caps(kern, T, expand_caps or [T] * n_act)
         E_tot = sum(caps)
         caps_v = jnp.asarray(caps, jnp.int32)
+        seg_off_v = jnp.asarray(seg_off)
         guards = kern._guard_fns()
-        fns = kern._action_fns()
     por_amat = (jnp.asarray(por.amat) if por is not None else None)
 
     def step_shard(tables, frontier, n_front, start_t,
@@ -305,6 +337,13 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
         n_loc = n_front[0]
         n_max = jax.lax.pmax(n_loc, axis)
         n_tiles = (n_max + T - 1) // T
+        if fused:
+            # stage 2: the queue is as wide as the caps, and everything
+            # after it reads the queue.  Its block stages are traced
+            # HERE, under the mesh axis the tile loop runs under (a
+            # trace made outside `shard_map` is not found again inside
+            # it) and outside the loops
+            expand_blocks = stage2.tile_pass(caps, E_tot, like=n_loc)
 
         def cond(c):
             return (c["t"] < n_tiles) & (c["reason"] == RUNNING)
@@ -371,42 +410,44 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                     ovf_e = ovf_vec.any()
                     need = jnp.maximum(c["need"], cnts.astype(U32))
 
-                # -- stage 2: per-action work-queue compaction; only
-                # REAL items are expanded (step_all expanded all T x L
-                # lanes, mostly padding)
-                succ_segs, en_q_segs, pos_segs = [], [], []
-                for a, (name, fn) in enumerate(
-                        zip(kern.action_names, fns)):
-                    L_a = lane_counts[a]
-                    TL_a = T * L_a
-                    off = int(seg_off[a])
-                    with jax.named_scope(spans.COMPACT):
-                        en_fa = en_segs[a].reshape(TL_a)
-                        (sel,) = jnp.nonzero(en_fa, size=caps[a],
-                                             fill_value=TL_a)
-                        sel_ok = sel < TL_a
-                        pidx = jnp.clip(sel // L_a, 0, T - 1
-                                        ).astype(jnp.int32)
-                        lane_loc = (sel % L_a).astype(jnp.int32)
-                        st_sel = {k: v[pidx]
-                                  for k, v in tile_st.items()}
-                    with jax.named_scope(spans.EXPAND):
-                        s_a, en2 = jax.vmap(fn, in_axes=(0, 0))(
-                            st_sel, lane_loc)
-                    succ_segs.append({k: v for k, v in s_a.items()
-                                      if not k.startswith("_")})
-                    en_q_segs.append(en2 & sel_ok)
-                    # canonical state-major flat position: the dense
-                    # [T, L] index this item would occupy in the
-                    # step_all path — the dedup tie-break and all
-                    # trace metadata derive from it, which is what
-                    # keeps compacted results bit-identical
-                    pos_segs.append(pidx * L + off + lane_loc)
+                # -- stage 2: the blocks of each action's segment that
+                # hold an enabled lane, into one dense queue
+                # (Stage2.tile_pass); each action's verdicts are folded
+                # as its blocks end.  The first violating lane is the
+                # one at the least CANONICAL state-major position: the
+                # dense [T, L] index the item would occupy in the
+                # step_all path — the dedup tie-break and all trace
+                # metadata derive from it, which is what keeps
+                # compacted results bit-identical
+                no_pos = jnp.int32(2**31 - 1)
+                vpos = no_pos
+                bag_err = slot_err = jnp.asarray(False)
+
+                def fold(aid, seg):
+                    nonlocal vpos, bag_err, slot_err
+                    pidx, lane_loc, sel_ok, en2, iok_a, err_a = seg
+                    with jax.named_scope(spans.INVARIANTS):
+                        en_a = en2 & sel_ok
+                        err_a = jnp.where(en_a, err_a, 0)
+                        viol_a = en_a & ~iok_a & (err_a == 0)
+                        pos_a = pidx * L + int(seg_off[aid]) + lane_loc
+                        vpos = jnp.minimum(
+                            vpos, jnp.where(viol_a, pos_a, no_pos).min())
+                        bag_err = bag_err | (
+                            (err_a & ERR_BAG_OVERFLOW) != 0).any()
+                        slot_err = slot_err | (
+                            (err_a & ~ERR_BAG_OVERFLOW) != 0).any()
+
+                queue, _q_end, blk_segs = expand_blocks(
+                    tile_st, en_segs, cnts, fold)
+                viol_any = vpos < no_pos
+                blk_t = jnp.stack(blk_segs).astype(U32)
                 with jax.named_scope(spans.COMPACT):
-                    flat = {k: jnp.concatenate(
-                        [s[k] for s in succ_segs]) for k in succ_segs[0]}
-                    en_f = jnp.concatenate(en_q_segs)
-                    flatpos = jnp.concatenate(pos_segs)
+                    flat_src = (queue["rows"] if pack_spec is None
+                                else {"rows": queue["rows"]})
+                    fps, en_f = queue["fp"], queue["en"]
+                    flatpos = (queue["pidx"] * L + seg_off_v[queue["aid"]]
+                               + queue["lane"])
             else:
                 with jax.named_scope(spans.EXPAND):
                     succs, en = jax.vmap(kern.step_all)(tile_st)
@@ -421,38 +462,36 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                     num_segments=n_act)
                 ovf_e = jnp.asarray(False)
                 need = c["need"]
+                blk_t = jnp.zeros((n_act,), U32)
                 flatpos = jnp.arange(T * L, dtype=jnp.int32)
-            if por_amat is None:
-                n_full = n_en
-                amp_t = jnp.asarray(0, jnp.int32)
-            if pack_spec is not None:
                 # pack successors ONCE, right after expansion: the
                 # buckets, the wire, and the next frontier all move
                 # the packed row from here on
-                flat_rows = jax.vmap(pack_spec.pack)(flat)
-            with jax.named_scope(spans.FINGERPRINT):
-                fps = jax.vmap(fpf)(flat)
-            with jax.named_scope(spans.INVARIANTS):
-                iok = jax.vmap(inv_fn)(flat)
-            errv = jnp.where(en_f, flat["err"], 0)
-            viol_l = en_f & ~iok & (errv == 0)
-            bag_err = ((errv & ERR_BAG_OVERFLOW) != 0).any()
-            slot_err = ((errv & ~ERR_BAG_OVERFLOW) != 0).any()
+                flat_src = (flat if pack_spec is None else
+                            {"rows": jax.vmap(pack_spec.pack)(flat)})
+                with jax.named_scope(spans.FINGERPRINT):
+                    fps = jax.vmap(fpf)(flat)
+                with jax.named_scope(spans.INVARIANTS):
+                    iok = jax.vmap(inv_fn)(flat)
+                errv = jnp.where(en_f, flat["err"], 0)
+                viol_l = en_f & ~iok & (errv == 0)
+                bag_err = ((errv & ERR_BAG_OVERFLOW) != 0).any()
+                slot_err = ((errv & ~ERR_BAG_OVERFLOW) != 0).any()
+                # the dense flat order IS the canonical one
+                viol_any = viol_l.any()
+                vpos = jnp.argmax(viol_l).astype(jnp.int32)
+            if por_amat is None:
+                n_full = n_en
+                amp_t = jnp.asarray(0, jnp.int32)
 
-            # first violating lane by CANONICAL state-major position
-            # (== argmax over the dense flat order; the fused queue is
-            # a reordering, so it minimizes flatpos explicitly), as
-            # (parent gid, action, param).  The lane tables (length L)
-            # are indexed by flatpos % L — a bare lane_aid[i] silently
-            # CLAMPS for i >= L and records the wrong action/param in
-            # the trace metadata
-            vidx = jnp.argmin(jnp.where(viol_l, flatpos,
-                                        jnp.int32(2**31 - 1)))
-            vpos = flatpos[vidx]
+            # the first violating lane as (parent gid, action, param).
+            # The lane tables (length L) are indexed by vpos % L — a
+            # bare lane_aid[i] silently CLAMPS for i >= L and records
+            # the wrong action/param in the trace metadata
             vinfo = jnp.stack([
                 base_gid[0] + base + (vpos // L).astype(jnp.int32),
                 lane_aid[vpos % L], lane_prm[vpos % L]])
-            viol = jnp.where(viol_l.any() & (c["viol"][0] < 0), vinfo,
+            viol = jnp.where(viol_any & (c["viol"][0] < 0), vinfo,
                              c["viol"])
 
             # local dedup, ownership bucketing (state + meta ride
@@ -476,15 +515,8 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                 b_p = jnp.zeros((n_dev, cap), jnp.int32)
                 b_a = jnp.zeros((n_dev, cap), jnp.int32)
                 b_m = jnp.zeros((n_dev, cap), jnp.int32)
-                if pack_spec is not None:
-                    b_st = {"rows": jnp.zeros(
-                        (n_dev, cap, pack_spec.words), U32)}
-                    flat_src = {"rows": flat_rows}
-                else:
-                    b_st = {k: jnp.zeros((n_dev, cap) + v.shape[1:],
-                                         v.dtype)
-                            for k, v in flat.items()}
-                    flat_src = flat
+                b_st = {k: jnp.zeros((n_dev, cap) + v.shape[1:], v.dtype)
+                        for k, v in flat_src.items()}
                 ovf_b = jnp.asarray(False)
                 for d in range(n_dev):
                     m = cand & (owner == d)
@@ -513,7 +545,7 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
             # compaction cap overflowed — the staged queue is
             # truncated, so nothing may commit until the exact-need
             # growth recompiles)
-            flags = jnp.stack([viol_l.any(), bag_err, slot_err, ovf_b,
+            flags = jnp.stack([viol_any, bag_err, slot_err, ovf_b,
                                dead_l.any(), ovf_e]).astype(jnp.int32)
             gflags = jax.lax.psum(flags, axis) > 0
             abort_pre = gflags.any()
@@ -610,6 +642,9 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                                                 n_full, 0),
                 "amp": c["amp"] + jnp.where(commit & ~g_povf,
                                             amp_t, 0),
+                # blocks of stage 2 this pass ran, committed or not
+                # (the one-chip body's `blk`)
+                "blk": c["blk"] + blk_t,
             }
 
         init = {
@@ -626,6 +661,7 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
             "sent": jnp.asarray(0, jnp.int32),
             "gfull": jnp.asarray(0, jnp.int32),
             "amp": jnp.asarray(0, jnp.int32),
+            "blk": jnp.zeros((n_act,), jnp.uint32),
         }
         out = jax.lax.while_loop(cond, body, init)
         one = lambda x: x[None]
@@ -634,21 +670,11 @@ def make_sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                 one(out["nn"]), one(out["t"]), one(out["reason"]),
                 out["viol"][None], one(out["gen"]), one(out["sent"]),
                 one(out["dead"]), out["act"][None], out["need"][None],
-                one(out["gfull"]), one(out["amp"]))
+                one(out["gfull"]), one(out["amp"]), out["blk"][None])
 
     sp = P(axis)
-    # donate the FPSet shards + the next-frontier buffer set (args 0,
-    # 4-7): the K-deep dispatch window chains each step on the previous
-    # one's outputs, so donation means the window holds ONE generation
-    # of the capacity-bound buffers instead of K (ISSUE 9 — the lever
-    # that makes pipeline=2 the sharded default).  The frontier (1) and
-    # base_gid (9) are re-read by every dispatch of the level's chain
-    # and must NOT be donated.
-    step = jax.jit(_shard_map(
-        step_shard, mesh=mesh,
-        in_specs=(sp,) * 10,
-        out_specs=(sp,) * 16), donate_argnums=(0, 4, 5, 6, 7))
-    return step
+    return _shard_map(step_shard, mesh=mesh, in_specs=(sp,) * 10,
+                      out_specs=(sp,) * 17)
 
 
 class ShardedBFS:
@@ -764,6 +790,17 @@ class ShardedBFS:
             if self._need_seen is None or \
                     len(self._need_seen) != len(names):
                 self._need_seen = np.zeros(len(names), np.int64)
+        # stage 2 of the fused step is the one-chip level program's
+        # (ISSUE 50), made per built kernel, so a grown cap or bucket
+        # finds the block stages traced.  It hashes whole successors:
+        # in blocks of 32 slots the incremental hash (the parent's
+        # parts a tile, the touched rows a slot) read 0.053 s a chip
+        # over the four-chip cell's slice where the full hash of every
+        # cap slot had read 0.006, and the step with the full hash
+        # commits 42,464 states/s on one chip where the incremental
+        # one commits 36,069 (PERF.md, PR 50)
+        self._stage2 = (Stage2(self.model, incremental=False)
+                        if self.commit == "fused" else None)
         self._make_step()
         # the start's two programs, built once per engine: a run()
         # of a built engine finds them compiled
@@ -787,15 +824,61 @@ class ShardedBFS:
 
     def _make_step(self):
         """The sharded step for the caps, the bucket and the levers as
-        they stand (`_build`, and a growth of a cap or the bucket)."""
-        self._step = make_sharded_level(
-            self.kern, self._inv, self.mesh, self.axis, self.tile,
-            self.bucket_cap, check_deadlock=self._ckd, pack_spec=self._pk,
-            commit=self.commit, expand_caps=self.expand_caps,
-            canon=self._canon,
-            por=self._por if self._por_active else None)
+        they stand (`_build`, and a growth of a cap or the bucket).
+        It goes through the store of traced programs
+        (engine/program_store.py, as `DeviceBFS._level` does): a
+        process that finds this engine's step there does not trace and
+        lower it again, which is most of a warm set-up (ISSUE 50: the
+        step with stage 2 in blocks is 5.5 MB of lowered text where it
+        was 2.2)."""
+        def mapped():
+            return sharded_level(
+                self.kern, self._inv, self.mesh, self.axis, self.tile,
+                self.bucket_cap, check_deadlock=self._ckd,
+                pack_spec=self._pk, commit=self.commit,
+                expand_caps=self.expand_caps, canon=self._canon,
+                por=self._por if self._por_active else None,
+                stage2=self._stage2)
+
+        self._step = program_store.StoredProgram(
+            mapped, "step", STEP_DONATES, self._step_key_doc())
         self._fresh_jit = True   # first dispatch after a (re)jit is
         #                          charged to the "compile" phase
+
+    def _step_key_doc(self):
+        """Everything the trace of `sharded_level` reads of this
+        engine, for the store's key (the arguments' types, where the
+        capacities live, come with the call), or None where the store
+        is not to be used: a kernel, codec or engine class the
+        package's source does not determine, or a mesh of several
+        processes (each would export its own view of one program)."""
+        import sys
+
+        from ..engine import device_bfs, fpset
+        from ..engine.checkpoint import spec_digest
+        if jax.process_count() > 1:
+            return None
+        spec, model = self.spec, self.model
+        try:
+            return program_store.describe({
+                "engine": type(self),
+                "spec": spec_digest(spec), "module": spec.module,
+                "kernel": self.kern, "codec": self.codec,
+                "pruned": model.pruned,
+                "mesh": [self.axis, list(self.mesh.axis_names),
+                         list(self.mesh.devices.shape)],
+                "tile": self.tile, "bucket_cap": self.bucket_cap,
+                "check_deadlock": self._ckd, "commit": self.commit,
+                "expand_caps": self.expand_caps,
+                "constants": program_store.module_constants(
+                    sys.modules[__name__], device_bfs, fpset),
+                "inv_names": model.inv_names,
+                "pack": model.pack_manifest(),
+                "canon": model.canon_manifest(),
+                "bounds": model.bounds_manifest(),
+                "por": [model.por_manifest(), self._por_active]})
+        except program_store.Uncovered:
+            return None
 
     def _trace(self, gid, extra=None):
         """The counterexample that ends at `gid` (and one step
@@ -915,6 +998,10 @@ class ShardedBFS:
                                     np.int64)
         self._tiles_done = 0
         self._lanes_disp = 0
+        # fused commit: blocks of stage 2 the shards ran, by action,
+        # of the blocks the caps of the committed tiles hold
+        self._blocks_act = np.zeros(len(self.kern.action_names), np.int64)
+        self._blocks_cap = 0
         self._por_kept = self._por_full = self._por_amp = 0
         # multi-process: every rank collects, only host 0 writes the
         # journal / metrics file / stats table (per-shard numbers are
@@ -1187,18 +1274,18 @@ class ShardedBFS:
             # ONE replication pull for all per-dispatch control
             # scalars — separate _pull calls cost one collective (and
             # one device round-trip) EACH; pack [D] reason/sent/
-            # gen/gfull/amp and the [D, A] act counters into a single
-            # [D, 5+A] array first
+            # gen/gfull/amp and the [D, A] act and blk counters into a
+            # single [D, 5+2A] array first
             packed = np.asarray(self._pull(
                 self._pack_scalars(o[7], o[10], o[9], o[14], o[15],
-                                   o[12])), np.int64)
+                                   o[12], o[16])), np.int64)
             reason = int(packed[0, 0])
             sent = int(packed[:, 1].sum())
             gen = int(packed[:, 2].sum())
             gfull = int(packed[:, 3].sum())
             amp = int(packed[:, 4].sum())
-            act = packed[:, 5:].sum(axis=0)
-            return reason, sent, gen, gfull, amp, act
+            act, blk = np.split(packed[:, 5:].sum(axis=0), 2)
+            return reason, sent, gen, gfull, amp, act, blk
 
         # shard context for fault hooks: the HOST process in
         # multi-process runs; a single-process mesh drives every
@@ -1272,7 +1359,8 @@ class ShardedBFS:
                     (tables, nb, nbp, nba, nbprm, nn,
                      start_t) = out[:7]
                 out, sc = pipe.collect(pull)
-                reason, sent, gen_add, gfull_add, amp_add, act_add = sc
+                (reason, sent, gen_add, gfull_add, amp_add, act_add,
+                 blk_add) = sc
                 exch_rows_useful += sent
                 exch_bytes_useful += sent * _row_bytes()
                 # generated is accumulated per dispatch attempt (a
@@ -1280,6 +1368,13 @@ class ShardedBFS:
                 # replays in the window are discarded by drain())
                 res.states_generated += gen_add
                 self._act_counts += act_add
+                # the lanes stage 2 really expanded, paused attempts
+                # included, under the caps this dispatch ran with
+                self._blocks_act += blk_add
+                if self.commit == "fused":
+                    self._lanes_disp += sum(
+                        int(n) * block_rows(cap)
+                        for n, cap in zip(blk_add, self._caps()))
                 if self._por_active:
                     self._por_kept += gen_add
                     self._por_full += gfull_add
@@ -1438,10 +1533,17 @@ class ShardedBFS:
                 exch_bytes_offchip += (tiles_lvl * D * (D - 1)
                                        * self.bucket_cap * _row_bytes())
                 nn_h = self._pull(nn)
-            # occupancy accounting (ISSUE 10): expand lanes dispatched
-            # this level, under the cap set in effect
+            # occupancy accounting (ISSUE 10): the per-action step
+            # expands every lane of every tile; the fused one only the
+            # blocks it counted (above), of the blocks the caps in
+            # effect at the level's end hold
             self._tiles_done += tiles_lvl * D
-            self._lanes_disp += tiles_lvl * D * self._lanes_per_tile()
+            if self.commit == "fused":
+                self._blocks_cap += tiles_lvl * D * sum(
+                    -(-cap // block_rows(cap)) for cap in self._caps())
+            else:
+                self._lanes_disp += (tiles_lvl * D * self.tile
+                                     * self.kern.n_lanes)
             n_next = int(nn_h.sum())
             fp_count += n_next
             obs.level_done(depth, frontier=front_total,
@@ -1592,32 +1694,30 @@ class ShardedBFS:
             obs.gauge("action_expansions",
                       {n: int(c) for n, c in
                        zip(self.kern.action_names, acts)})
-        # occupancy = real work items / expand lanes dispatched
-        # (ISSUE 10)
+        # occupancy = real work items / expand lanes the shards ran
+        # (ISSUE 10; fused: the blocks of stage 2 that held an enabled
+        # lane, of the blocks the caps hold, as DeviceBFS counts them)
         lanes = getattr(self, "_lanes_disp", 0)
         if lanes and acts is not None:
             obs.gauge("occupancy",
                       round(float(acts.sum()) / lanes, 4))
+        if self.commit == "fused" and acts is not None:
+            obs.count("expand_blocks_run", int(self._blocks_act.sum()))
+            obs.count("expand_blocks_cap", self._blocks_cap)
         obs.gauge("commit_mode", self.commit)
 
-    def _lanes_per_tile(self):
-        """Expand lanes one tile dispatches on one device: the fused
-        caps, or the full T x L dense expansion in per-action mode."""
-        if self.commit == "fused" and self.expand_caps is not None:
-            return sum(
-                min(self.tile * self.kern._lane_count(n),
-                    max(8, int(c)))
-                for n, c in zip(self.kern.action_names,
-                                self.expand_caps))
-        return self.tile * self.kern.n_lanes
+    def _caps(self):
+        """The caps the fused step runs with (make_sharded_level)."""
+        return fused_caps(self.kern, self.tile, self.expand_caps)
 
 
-def pack_scalars(reason, sent, gen, gfull, amp, act):
+def pack_scalars(reason, sent, gen, gfull, amp, act, blk):
     """A dispatch's per-shard control scalars, [D] each, and its
-    [D, A] action counters as one [D, 5+A] int32 array."""
+    [D, A] action and block counters as one [D, 5+2A] int32 array."""
     return jnp.concatenate(
         [reason[:, None], sent[:, None], gen[:, None], gfull[:, None],
-         amp[:, None], act.astype(jnp.int32)], axis=1)
+         amp[:, None], act.astype(jnp.int32), blk.astype(jnp.int32)],
+        axis=1)
 
 
 def make_packed_fill(mesh: Mesh, axis: str):
