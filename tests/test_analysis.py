@@ -600,7 +600,7 @@ def test_preflight_aborts_device_engine_without_dispatch():
         def _build(self, max_msgs):     # no kernel for module "VR_..."
             self.codec = self.kern = None
 
-        def _register_init(self, res):
+        def _register_init(self, res, obs):
             raise AssertionError("dispatch reached despite lint errors")
 
     eng = NoDispatch(spec)

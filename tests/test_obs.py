@@ -924,7 +924,8 @@ def test_span_keeps_exclusive_phase_contract():
     assert not obs.metrics._stack
 
 
-def test_span_annotations_follow_the_fixed_vocabulary(tmp_path):
+@pytest.mark.parametrize("kind", ["spans", "parts"])
+def test_span_annotations_follow_the_fixed_vocabulary(tmp_path, kind):
     from tpuvsr.obs import spans
     rec = _Recorder()
     asked = []
@@ -941,8 +942,30 @@ def test_span_annotations_follow_the_fixed_vocabulary(tmp_path):
     assert asked == [1]         # decided once, at start()
     rec.assert_nested()
     names = rec.opened()
-    assert set(names) <= set(spans.ENGINE_SPANS)
+    assert set(names) <= set(spans.ENGINE_SPANS) | set(spans.ENGINE_PARTS)
     assert not any(re.search(r"\d", n) for n in names)
+    doc = res.metrics
+    if kind == "parts":
+        # a part lies directly inside its phase's span, and the
+        # document's `phase_parts` names the parts that were opened (a
+        # read-back's lie where JAX read the cache, under any phase)
+        stack, seen = [], set()
+        for e in rec.log:
+            if e[0] == "close":
+                stack.pop()
+                continue
+            if e[1] in spans.ENGINE_PARTS \
+                    and e[1] not in spans.READ_BACK_PARTS:
+                phase, part = spans.ENGINE_PARTS[e[1]]
+                assert spans.ENGINE_SPANS[stack[-1]] == phase, rec.log
+                seen.add((phase, part))
+            stack.append(e[1])
+        assert seen == {(phase, part)
+                        for phase, parts in doc["phase_parts"].items()
+                        for part in parts}
+        assert {phase for phase, _ in seen} == {"init", "checkpoint"}
+        return
+    names = [n for n in names if n in spans.ENGINE_SPANS]
     # the root comes first and closes last, and carries the run's ids
     first, last = rec.log[0], rec.log[-1]
     assert first[:2] == ("open", spans.CHECK)
@@ -950,7 +973,6 @@ def test_span_annotations_follow_the_fixed_vocabulary(tmp_path):
                         "trace_id": "feedc0de00000001"}
     assert last == ("close", spans.CHECK)
     # one annotation per phase entry: every dispatch, every snapshot
-    doc = res.metrics
     n_launch = names.count(spans.BUILD) + names.count(spans.DISPATCH)
     assert n_launch == doc["counters"]["dispatches"]
     assert names.count(spans.BUILD) == 1

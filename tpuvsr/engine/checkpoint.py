@@ -47,6 +47,7 @@ format 3.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -57,6 +58,8 @@ import zlib
 
 import numpy as np
 from numpy.lib import format as _npformat
+
+from ..obs import spans
 
 FORMAT_VERSION = 4
 #: formats ``load_checkpoint`` reads: 3 wrote the whole table and dense
@@ -206,6 +209,12 @@ def _scatter_table(fp):
     return slots, gids
 
 
+def _part(obs, name):
+    """`name`'s part of the writer's ``checkpoint`` phase; nothing
+    without an observer (a conversion, a test)."""
+    return contextlib.nullcontext() if obs is None else obs.part(name)
+
+
 def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
                     h_action, h_param, init_dense, level_sizes, depth,
                     fp_count, states_generated, max_msgs, expand_mults,
@@ -257,133 +266,138 @@ def save_checkpoint(path, *, slots, frontier=None, n_front, h_parent,
     if os.path.isdir(tmp):
         shutil.rmtree(tmp)
     os.makedirs(tmp)
-    # stored, not deflated: fingerprints are hash words, deflate takes
-    # a fifth off them at sixteen times the time
-    np.savez(os.path.join(tmp, "fpset.npz"),
-             **_occupied_members(slots, gids))
-    extra_payloads = []
-    if edge_blocks is not None:
-        _write_frontier_chunks(os.path.join(tmp, "edges.npz"),
-                               edge_blocks)
-        extra_payloads.append("edges.npz")
-    if graph_blocks is not None:
-        _write_frontier_chunks(os.path.join(tmp, "graph.npz"),
-                               graph_blocks)
-        extra_payloads.append("graph.npz")
-    packed = frontier_blocks is None and frontier_packed is not None
-    if frontier_blocks is not None:
-        rows = _write_frontier_chunks(
-            os.path.join(tmp, "frontier.npz"), frontier_blocks)
-        if rows != int(n_front):
-            raise ValueError(
-                f"frontier_blocks yielded {rows} rows, n_front is "
-                f"{n_front}")
-    elif packed:
-        if pack is None:
-            raise ValueError("frontier_packed needs the writer's pack "
-                             "manifest to be read back")
+    # the table leaves the device here, whole, and the host finds its
+    # occupied slots
+    with _part(obs, spans.CHECKPOINT_PULL):
+        members = _occupied_members(slots, gids)
+    with _part(obs, spans.CHECKPOINT_WRITE):
+        # stored, not deflated: fingerprints are hash words, deflate takes
+        # a fifth off them at sixteen times the time
+        np.savez(os.path.join(tmp, "fpset.npz"), **members)
+        extra_payloads = []
+        if edge_blocks is not None:
+            _write_frontier_chunks(os.path.join(tmp, "edges.npz"),
+                                   edge_blocks)
+            extra_payloads.append("edges.npz")
+        if graph_blocks is not None:
+            _write_frontier_chunks(os.path.join(tmp, "graph.npz"),
+                                   graph_blocks)
+            extra_payloads.append("graph.npz")
+        packed = frontier_blocks is None and frontier_packed is not None
+        if frontier_blocks is not None:
+            rows = _write_frontier_chunks(
+                os.path.join(tmp, "frontier.npz"), frontier_blocks)
+            if rows != int(n_front):
+                raise ValueError(
+                    f"frontier_blocks yielded {rows} rows, n_front is "
+                    f"{n_front}")
+        elif packed:
+            if pack is None:
+                raise ValueError("frontier_packed needs the writer's pack "
+                                 "manifest to be read back")
+            np.savez_compressed(
+                os.path.join(tmp, "frontier.npz"),
+                packed=np.asarray(frontier_packed)[:n_front])
+        else:
+            np.savez_compressed(
+                os.path.join(tmp, "frontier.npz"),
+                **{k: np.asarray(v)[:n_front] for k, v in frontier.items()})
+        np.savez_compressed(os.path.join(tmp, "trace.npz"),
+                            parent=h_parent, action=h_action, param=h_param)
         np.savez_compressed(
-            os.path.join(tmp, "frontier.npz"),
-            packed=np.asarray(frontier_packed)[:n_front])
-    else:
-        np.savez_compressed(
-            os.path.join(tmp, "frontier.npz"),
-            **{k: np.asarray(v)[:n_front] for k, v in frontier.items()})
-    np.savez_compressed(os.path.join(tmp, "trace.npz"),
-                        parent=h_parent, action=h_action, param=h_param)
-    np.savez_compressed(
-        os.path.join(tmp, "init.npz"),
-        **{k: np.stack([np.asarray(d[k]) for d in init_dense])
-           for k in init_dense[0]})
-    # CRCs are computed over the INTENDED payload bytes, before the
-    # corrupt-ckpt fault hook below mangles anything — a fault-injected
-    # torn write is therefore CRC-detectable, like a real one
-    payloads = list(PAYLOADS) + extra_payloads
-    crcs = {name: _crc32_file(os.path.join(tmp, name))
-            for name in payloads}
-    manifest = {
-        "format": FORMAT_VERSION,
-        "n_front": int(n_front),
-        "n_init": len(init_dense),
-        "level_sizes": [int(x) for x in level_sizes],
-        "depth": int(depth),
-        "fp_count": int(fp_count),
-        "states_generated": int(states_generated),
-        "max_msgs": int(max_msgs),
-        "expand_mults": [int(x) for x in expand_mults],
-        "elapsed": float(elapsed),
-        "spec_digest": digest,
-        "payload_crc32": crcs,
-        # packed-frontier spec identity (ISSUE 9): version digest +
-        # plane table of the writer's packing spec, None when dense
-        "pack": pack,
-        # frontier.npz holds the writer's packed rows (to be unpacked
-        # by `pack`), not dense planes
-        "frontier_packed": packed,
-        # symmetry canonicalization spec (ISSUE 11): version digest +
-        # group order + orbit plane table of the writer's CanonSpec,
-        # None when the run stored raw (non-canonical) fingerprints.
-        # Resuming under a flipped -symmetry or a changed group is a
-        # policy error — the FPSet's fingerprint space would not match
-        "canon": canon,
-        # bounds-facts identity (ISSUE 13): digest of the speclint
-        # bounds pass facts the writer consumed (tightened packing +
-        # pruned action ids depend on them), None when bounds off.
-        # Resuming under a flipped -bounds or changed cfg constants
-        # is a policy error, mirroring the pack/canon rules
-        "bounds": bounds,
-        # independence-facts identity (ISSUE 16): digest of the
-        # speclint independence pass facts the writer's ample-set
-        # partial-order reduction consumed (the reduced reachable set
-        # depends on them), None when POR off.  Resuming under a
-        # flipped -por or changed facts is a policy error, mirroring
-        # the pack/canon/bounds rules
-        "por": por,
-        # engine-specific payload (e.g. the sharded driver's per-shard
-        # frontier counts and exchange capacities)
-        "extra": extra,
-    }
-    with open(os.path.join(tmp, "manifest.json"), "w") as f:
-        json.dump(manifest, f)
-        f.flush()
-        os.fsync(f.fileno())
-        staged = f.tell()
-    # sized before the fault hook below may truncate one
-    staged += sum(os.path.getsize(os.path.join(tmp, name))
-                  for name in payloads)
-    # fault hook: emulate a corrupted write AND leave the previous
-    # snapshot as .old (the crash window between rename-into-place and
-    # .old cleanup).  Two flavors (resilience/faults.py): corrupt-ckpt
-    # truncates the named payload (torn write — np.load chokes);
-    # garble-ckpt XOR-flips a byte span mid-file with the size
-    # preserved (bit rot — ONLY the manifest CRC32 catches it)
-    corrupt = fault_point("checkpoint", depth=depth, path=path, obs=obs)
-    if corrupt:
-        victim = os.path.join(tmp, corrupt.payload)
-        size = os.path.getsize(victim)
-        with open(victim, "r+b") as f:
-            if corrupt.kind == "garble-ckpt":
-                span = max(1, min(64, size // 2))
-                f.seek(size // 2)
-                chunk = f.read(span)
-                f.seek(size // 2)
-                f.write(bytes(b ^ 0xFF for b in chunk))
-            else:
-                f.truncate(max(1, size // 2))
-    for name in payloads:
-        _fsync_path(os.path.join(tmp, name))
-    _fsync_path(tmp)
-    old = path + ".old"
-    if os.path.isdir(old):
-        shutil.rmtree(old)
-    if os.path.isdir(path):
-        os.rename(path, old)
-    os.rename(tmp, path)
-    parent = os.path.dirname(os.path.abspath(path)) or "."
-    _fsync_path(parent)
-    if os.path.isdir(old) and not corrupt:
-        shutil.rmtree(old)
+            os.path.join(tmp, "init.npz"),
+            **{k: np.stack([np.asarray(d[k]) for d in init_dense])
+               for k in init_dense[0]})
+    with _part(obs, spans.CHECKPOINT_DURABLE):
+        # CRCs are computed over the INTENDED payload bytes, before the
+        # corrupt-ckpt fault hook below mangles anything — a fault-injected
+        # torn write is therefore CRC-detectable, like a real one
+        payloads = list(PAYLOADS) + extra_payloads
+        crcs = {name: _crc32_file(os.path.join(tmp, name))
+                for name in payloads}
+        manifest = {
+            "format": FORMAT_VERSION,
+            "n_front": int(n_front),
+            "n_init": len(init_dense),
+            "level_sizes": [int(x) for x in level_sizes],
+            "depth": int(depth),
+            "fp_count": int(fp_count),
+            "states_generated": int(states_generated),
+            "max_msgs": int(max_msgs),
+            "expand_mults": [int(x) for x in expand_mults],
+            "elapsed": float(elapsed),
+            "spec_digest": digest,
+            "payload_crc32": crcs,
+            # packed-frontier spec identity (ISSUE 9): version digest +
+            # plane table of the writer's packing spec, None when dense
+            "pack": pack,
+            # frontier.npz holds the writer's packed rows (to be unpacked
+            # by `pack`), not dense planes
+            "frontier_packed": packed,
+            # symmetry canonicalization spec (ISSUE 11): version digest +
+            # group order + orbit plane table of the writer's CanonSpec,
+            # None when the run stored raw (non-canonical) fingerprints.
+            # Resuming under a flipped -symmetry or a changed group is a
+            # policy error — the FPSet's fingerprint space would not match
+            "canon": canon,
+            # bounds-facts identity (ISSUE 13): digest of the speclint
+            # bounds pass facts the writer consumed (tightened packing +
+            # pruned action ids depend on them), None when bounds off.
+            # Resuming under a flipped -bounds or changed cfg constants
+            # is a policy error, mirroring the pack/canon rules
+            "bounds": bounds,
+            # independence-facts identity (ISSUE 16): digest of the
+            # speclint independence pass facts the writer's ample-set
+            # partial-order reduction consumed (the reduced reachable set
+            # depends on them), None when POR off.  Resuming under a
+            # flipped -por or changed facts is a policy error, mirroring
+            # the pack/canon/bounds rules
+            "por": por,
+            # engine-specific payload (e.g. the sharded driver's per-shard
+            # frontier counts and exchange capacities)
+            "extra": extra,
+        }
+        with open(os.path.join(tmp, "manifest.json"), "w") as f:
+            json.dump(manifest, f)
+            f.flush()
+            os.fsync(f.fileno())
+            staged = f.tell()
+        # sized before the fault hook below may truncate one
+        staged += sum(os.path.getsize(os.path.join(tmp, name))
+                      for name in payloads)
+        # fault hook: emulate a corrupted write AND leave the previous
+        # snapshot as .old (the crash window between rename-into-place and
+        # .old cleanup).  Two flavors (resilience/faults.py): corrupt-ckpt
+        # truncates the named payload (torn write — np.load chokes);
+        # garble-ckpt XOR-flips a byte span mid-file with the size
+        # preserved (bit rot — ONLY the manifest CRC32 catches it)
+        corrupt = fault_point("checkpoint", depth=depth, path=path, obs=obs)
+        if corrupt:
+            victim = os.path.join(tmp, corrupt.payload)
+            size = os.path.getsize(victim)
+            with open(victim, "r+b") as f:
+                if corrupt.kind == "garble-ckpt":
+                    span = max(1, min(64, size // 2))
+                    f.seek(size // 2)
+                    chunk = f.read(span)
+                    f.seek(size // 2)
+                    f.write(bytes(b ^ 0xFF for b in chunk))
+                else:
+                    f.truncate(max(1, size // 2))
+        for name in payloads:
+            _fsync_path(os.path.join(tmp, name))
+        _fsync_path(tmp)
+        old = path + ".old"
+        if os.path.isdir(old):
+            shutil.rmtree(old)
+        if os.path.isdir(path):
+            os.rename(path, old)
+        os.rename(tmp, path)
+        parent = os.path.dirname(os.path.abspath(path)) or "."
         _fsync_path(parent)
+        if os.path.isdir(old) and not corrupt:
+            shutil.rmtree(old)
+            _fsync_path(parent)
     return staged
 
 

@@ -1890,57 +1890,67 @@ class DeviceBFS:
         in pages of a chunk's rows: one program a buffer shape."""
         return RowPages(cut, buf, n, self.chunk_tiles * self.tile, axis)
 
-    def _register_init(self, res):
+    def _register_init(self, res, obs):
         """Encode, dedup, and FPSet-register the initial states; seed
         the host pointer store and check invariants on them.  Returns
         (table, init_batch, n0, viol_index); viol_index is non-None
-        when an init state violates, with res.trace already built."""
+        when an init state violates, with res.trace already built.
+        Inside the init span, by its parts: the interpreter's share
+        (`states`), the fingerprints up to their pull, and what is
+        enqueued on the device."""
         spec, codec = self.spec, self.codec
-        table = empty_table(self.fpset_capacity)
-        init_states = list(spec.init_states())
-        init_dense = [codec.encode(st) for st in init_states]
-        init_batch = {k: np.stack([d[k] for d in init_dense])
-                      for k in init_dense[0]}
-        fps = np.asarray(self.model.fp_batch(init_batch))
-        keep, seen = [], set()
-        for i in range(len(init_dense)):
-            key = tuple(fps[i])
-            if key not in seen:
-                seen.add(key)
-                keep.append(i)
-        init_batch = {k: v[keep] for k, v in init_batch.items()}
-        self._init_states = [init_states[i] for i in keep]
-        self._init_dense = [init_dense[i] for i in keep]
-        n0 = len(keep)
-        table, _, _ = insert_batch(
-            table, jnp.asarray(fps[keep]), jnp.ones((n0,), bool))
-        if self._edges_on:
-            # gid column (ISSUE 15): graph node ids ARE commit order,
-            # so the deduped init states take gids 0..n0-1
-            table["gids"] = store_gids(
-                table["slots"],
-                jnp.full((self.fpset_capacity,), -1, jnp.int32),
-                jnp.asarray(fps[keep]),
-                jnp.arange(n0, dtype=jnp.int32),
-                jnp.ones((n0,), bool))
-        if self._por_active:
-            # C3 level-marker column (ISSUE 16): init states are level
-            # 0, and a zeros column gives every one of them marker 0
-            # without a store pass; empty-slot values are never read
-            # (lookup_gids returns -1 for absent fingerprints)
-            table["gids"] = jnp.zeros((self.fpset_capacity,),
-                                      jnp.int32)
+        with obs.part(spans.INIT_DEVICE):
+            table = empty_table(self.fpset_capacity)
+        with obs.part(spans.INIT_STATES):
+            init_states = list(spec.init_states())
+            init_dense = [codec.encode(st) for st in init_states]
+            init_batch = {k: np.stack([d[k] for d in init_dense])
+                          for k in init_dense[0]}
+        with obs.part(spans.INIT_FINGERPRINT):
+            fps = np.asarray(self.model.fp_batch(init_batch))
+        with obs.part(spans.INIT_STATES):
+            keep, seen = [], set()
+            for i in range(len(init_dense)):
+                key = tuple(fps[i])
+                if key not in seen:
+                    seen.add(key)
+                    keep.append(i)
+            init_batch = {k: v[keep] for k, v in init_batch.items()}
+            self._init_states = [init_states[i] for i in keep]
+            self._init_dense = [init_dense[i] for i in keep]
+            n0 = len(keep)
+        with obs.part(spans.INIT_DEVICE):
+            table, _, _ = insert_batch(
+                table, jnp.asarray(fps[keep]), jnp.ones((n0,), bool))
+            if self._edges_on:
+                # gid column (ISSUE 15): graph node ids ARE commit
+                # order, so the deduped init states take gids 0..n0-1
+                table["gids"] = store_gids(
+                    table["slots"],
+                    jnp.full((self.fpset_capacity,), -1, jnp.int32),
+                    jnp.asarray(fps[keep]),
+                    jnp.arange(n0, dtype=jnp.int32),
+                    jnp.ones((n0,), bool))
+            if self._por_active:
+                # C3 level-marker column (ISSUE 16): init states are
+                # level 0, and a zeros column gives every one of them
+                # marker 0 without a store pass; empty-slot values are
+                # never read (lookup_gids returns -1 for absent
+                # fingerprints)
+                table["gids"] = jnp.zeros((self.fpset_capacity,),
+                                          jnp.int32)
         # host trace store: gid -> (parent gid, action, param)
         self._h_parent = [np.full(n0, -1, np.int64)]
         self._h_action = [np.full(n0, -1, np.int32)]
         self._h_param = [np.zeros(n0, np.int32)]
-        for i in range(n0):
-            bad = spec.check_invariants(self._init_states[i])
-            if bad:
-                res.ok = False
-                res.violated_invariant = bad
-                res.trace = self._trace(i)
-                return table, init_batch, n0, i
+        with obs.part(spans.INIT_STATES):
+            for i in range(n0):
+                bad = spec.check_invariants(self._init_states[i])
+                if bad:
+                    res.ok = False
+                    res.violated_invariant = bad
+                    res.trace = self._trace(i)
+                    return table, init_batch, n0, i
         res.states_generated += len(init_dense)
         return table, init_batch, n0, None
 
@@ -1978,10 +1988,12 @@ class DeviceBFS:
             # init-violation result
             self.level_sizes = []
             with obs.span(spans.INIT):
-                run.table, init_batch, n0, viol = self._register_init(res)
+                run.table, init_batch, n0, viol = self._register_init(
+                    res, obs)
                 run.fp_count = n0
                 if viol is None:
-                    self._start_frontier(run, init_batch, n0)
+                    with obs.part(spans.INIT_DEVICE):
+                        self._start_frontier(run, init_batch, n0)
             if viol is not None:
                 return self._finish(res, obs, run.fp_count,
                                     table=run.table, fp_cap=run.fp_cap)
@@ -2324,11 +2336,13 @@ class DeviceBFS:
             from .checkpoint import (FORMAT_VERSION, save_checkpoint,
                                      spec_digest)
             with obs.span(spans.CHECKPOINT, depth=depth):
-                self._flush_pointers()
+                with obs.part(spans.CHECKPOINT_PULL):
+                    self._flush_pointers()
+                    front = self._snapshot_keywords(run)
                 staged = save_checkpoint(
                     path,
                     slots=run.table["slots"],
-                    **self._snapshot_keywords(run),
+                    **front,
                     n_front=run.n_front,
                     h_parent=np.concatenate(self._h_parent),
                     h_action=np.concatenate(self._h_action),

@@ -441,7 +441,8 @@ class PagedBFS(DeviceBFS):
         # re-floored on every in-run rebuild — a stale floor live-locks
         # the drain loop (commit never true with an empty buffer).
         self._floor_next_cap()
-        with run.obs.span(spans.INIT):
+        with run.obs.span(spans.INIT), \
+                run.obs.part(spans.INIT_DEVICE):
             run.bufs = self._alloc_bufs(self.next_cap)
         # edge append buffer (ISSUE 15): same total_E + one-tile floor
         # as the next buffer (the kernel refuses to commit a tile

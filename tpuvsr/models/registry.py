@@ -39,7 +39,11 @@ def ensure_compile_cache():
     ``JAX_COMPILATION_CACHE_DIR`` is set JAX already keeps its cache
     there and nothing is set in code; otherwise the cache is the fixed
     ``<checkout>/.jax_cache`` (the path is part of the cache key, so it
-    must not move).  Returns the directory in use."""
+    must not move).  The cache's reads are timed where they happen
+    (``obs/builds.install_read_back_seam``).  Returns the directory in
+    use."""
+    from ..obs import builds
+    builds.install_read_back_seam()
     if not jax.config.jax_compilation_cache_dir:
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(REPO, ".jax_cache"))

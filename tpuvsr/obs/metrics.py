@@ -25,6 +25,13 @@ One ``Metrics`` instance rides a single engine run (inside a
   clock onto the phase they leave, so ``unfed`` is exclusive by phase
   like ``phases`` and each entry is at most its phase's seconds.
 
+* **parts of a phase** — seconds of a named part of the phase that is
+  current (``part_begin`` / ``part_end``, driven by
+  ``RunObserver.part``).  A part is no frame: the phase's exclusive
+  seconds are what they are without it, the part's own exclude what
+  inner timers took out of the phase while it ran, and so the parts of
+  a phase sum to at most the phase.
+
 Per-level rows (``level(...)``) capture the BFS trajectory: frontier
 size, cumulative distinct/generated, and elapsed at each level
 boundary — the data a ``-metrics FILE.json`` dump is built from — and
@@ -64,6 +71,7 @@ class Metrics:
     def __init__(self, clock=time.perf_counter):
         self.phases = {}        # name -> exclusive seconds
         self.unfed = {}         # name -> exclusive seconds, device unfed
+        self.parts = {}         # phase -> {part: seconds inside it}
         self.counters = {}      # name -> int
         self.gauges = {}        # name -> number
         self.levels = []        # per-level trajectory rows
@@ -132,6 +140,30 @@ class Metrics:
             inner_t0 = t0
         return out
 
+    # -- parts of the current phase -----------------------------------
+    def part_begin(self, phase):
+        """A part of `phase` starts; `phase` has to be the innermost
+        open frame (a RuntimeError otherwise: a part lies inside its
+        phase).  Returns what ``part_end`` wants back."""
+        if not self._stack or self._stack[-1][0] != phase:
+            raise RuntimeError(
+                f"a part of phase {phase!r} opened under "
+                f"{self._stack[-1][0] if self._stack else None!r}")
+        return self._clock(), self._stack[-1][1]
+
+    def part_end(self, phase, part, token):
+        """Charge `part` what the phase's frame accrued since
+        ``part_begin``: its seconds less those of the inner timers that
+        ran meanwhile.  A frame that ``drain`` closed first (an
+        abnormal exit) charges nothing."""
+        if not self._stack or self._stack[-1][0] != phase:
+            return
+        t0, child0 = token
+        secs = max(0.0, self._clock() - t0
+                   - (self._stack[-1][1] - child0))
+        parts = self.parts.setdefault(phase, {})
+        parts[part] = parts.get(part, 0.0) + secs
+
     @contextmanager
     def timer(self, phase):
         """Time a code section under ``phase``.  Nests: the enclosing
@@ -193,6 +225,10 @@ class Metrics:
             out["phases_unfed"] = {k: round(v, 6)
                                    for k, v in self.unfed.items()}
             out["gauges"]["unfed_s"] = round(sum(self.unfed.values()), 6)
+        if self.parts:      # only a run that opened a part
+            out["phase_parts"] = {
+                phase: {k: round(v, 6) for k, v in parts.items()}
+                for phase, parts in self.parts.items()}
         out["levels"] = list(self.levels)
         return out
 
@@ -251,6 +287,17 @@ def validate_metrics(doc, strict=False):
             if not isinstance(v, (int, float)) or v < 0:
                 raise ValueError(f"{section} {name} has non-duration "
                                  f"value {v!r}")
+    # `phase_parts` is optional like `phases_unfed`: older documents,
+    # and runs that opened no part, carry none
+    if not isinstance(doc.get("phase_parts", {}), dict):
+        raise ValueError("phase_parts must be an object")
+    for phase, parts in doc.get("phase_parts", {}).items():
+        if not isinstance(parts, dict):
+            raise ValueError(f"phase_parts {phase} must be an object")
+        for name, v in parts.items():
+            if not isinstance(v, (int, float)) or v < 0:
+                raise ValueError(f"phase_parts {phase}.{name} has "
+                                 f"non-duration value {v!r}")
     for name, v in doc["counters"].items():
         if not isinstance(v, int):
             raise ValueError(f"counter {name} has non-int value {v!r}")

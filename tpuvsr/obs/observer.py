@@ -88,6 +88,33 @@ class _Span:
         return False
 
 
+class _Part:
+    """One part of a host phase: seconds inside the phase's frame
+    (``Metrics.part_begin`` / ``part_end``) and, when the run is
+    profiled, a TraceAnnotation of the part's name nested in the
+    phase's."""
+
+    __slots__ = ("_metrics", "_phase", "_part", "_annotation", "_token")
+
+    def __init__(self, metrics, phase, part, annotation):
+        self._metrics = metrics
+        self._phase = phase
+        self._part = part
+        self._annotation = annotation
+
+    def __enter__(self):
+        self._token = self._metrics.part_begin(self._phase)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self._metrics.part_end(self._phase, self._part, self._token)
+        return False
+
+
 class RunObserver:
     def __init__(self, journal_path=None, metrics_path=None, log=None,
                  progress_every=10.0, run_id=None, primary=True,
@@ -159,6 +186,9 @@ class RunObserver:
         self.builds = builds.BuildMeter(report=self._build_event)
         self._builds_attached = False
         self._builds_previous = None
+        # `checkpoint` parts as of the last `checkpoint` event: the
+        # event carries what its own snapshot added
+        self._snapshot_parts = {}
 
     # ------------------------------------------------------------------
     @classmethod
@@ -237,6 +267,7 @@ class RunObserver:
         self._profile_cm = profile_trace(log=self._log)
         self._profile_cm.__enter__()
         self._annotation = self._annotation_factory()
+        self.builds.annotation = self._annotation
         if self.backend != "host":
             self._builds_previous = builds.attach(self.builds)
             self._builds_attached = True
@@ -297,6 +328,23 @@ class RunObserver:
                      None if annotation is None
                      else annotation(name, **attrs))
 
+    def part(self, name, **attrs):
+        """Mark a part of the host phase that is open.  `name` is one
+        of ``spans.ENGINE_PARTS`` outside ``spans.READ_BACK_PARTS`` (a
+        KeyError otherwise, as ``span``), and its phase has to be the
+        innermost open one (a RuntimeError otherwise).  The phase is
+        timed as it is without the part; the part's seconds go to the
+        metrics document's ``phase_parts`` and, when the run is
+        profiled, a TraceAnnotation of its name lies inside the
+        phase's."""
+        if name in spans.READ_BACK_PARTS:
+            raise KeyError(name)
+        phase, part = spans.ENGINE_PARTS[name]
+        annotation = self._annotation
+        return _Part(self.metrics, phase, part,
+                     None if annotation is None
+                     else annotation(name, **attrs))
+
     def boundary(self, **attrs):
         """Open the ``tpuvsr.engine.boundary`` span: the host is between
         the last collect of one unit of device work (a level; a chunk of
@@ -314,12 +362,16 @@ class RunObserver:
             span.__exit__(None, None, None)
 
     def _build_event(self, rec):
+        # a program read back through the seam of `builds.py` says what
+        # its read, its decompression and its load cost
+        read_back = {k: (round(v, 6) if isinstance(v, float) else v)
+                     for k, v in rec.items() if k in builds.READ_BACK_KEYS}
         self.journal.write(
             "build", fun_name=str(rec["fun_name"]),
             trace_s=round(rec["trace_s"], 6),
             lower_s=round(rec["lower_s"], 6),
             backend_s=round(rec["backend_s"], 6), cache=rec["cache"],
-            export=rec["export"],
+            export=rec["export"], **read_back,
             elapsed_s=round(self.elapsed(), 3))
 
     # -- metrics delegates ---------------------------------------------
@@ -348,12 +400,18 @@ class RunObserver:
     def checkpoint(self, path, depth, distinct, nbytes, fmt):
         """A level-boundary snapshot was written: `nbytes` staged
         (payloads + manifest, ``save_checkpoint``'s return; 0 on a
-        rank that wrote nothing) in snapshot format `fmt`."""
+        rank that wrote nothing) in snapshot format `fmt`.  `parts`
+        on the event: the seconds this snapshot added to each part of
+        the ``checkpoint`` phase."""
         self.count("checkpoints")
         self.count("checkpoint_bytes", nbytes)
+        now = dict(self.metrics.parts.get("checkpoint", {}))
+        parts = {k: round(v - self._snapshot_parts.get(k, 0.0), 6)
+                 for k, v in now.items()}
+        self._snapshot_parts = now
         self.journal.write("checkpoint", path=str(path), depth=int(depth),
                            distinct=int(distinct), bytes=int(nbytes),
-                           format=int(fmt),
+                           format=int(fmt), parts=parts,
                            elapsed_s=round(self.elapsed(), 3))
 
     def spill(self, depth, rows, nbytes, **extra):

@@ -9,6 +9,14 @@ Two kinds, both documented in ``SCHEMA.md``:
   on the device trace's clock.  Numbers (``depth=``, ``tiles=``) are
   attributes, never part of the name.  The service's spans carry no
   phase: the job journal already times a job.
+* **parts of a phase** — what a host phase does, named where it does
+  it.  ``RunObserver.part(name, **attrs)`` times the part INSIDE its
+  phase (the phase's own seconds do not change; the part's go to the
+  metrics document's ``phase_parts``) and, when profiling is on, opens
+  a ``TraceAnnotation`` of the part's name nested in its phase's.  The
+  three parts of a read-back from the persistent cache are timed where
+  JAX reads (``builds.py``), under whatever phase called the fresh
+  jit: annotations and gauges, not ``phase_parts``.
 * **level-program stages** — ``jax.named_scope`` names around the
   stages of the level program and the sharded step.  Metadata only:
   they reach the trace on every device operation of the stage and
@@ -46,6 +54,36 @@ ENGINE_SPANS = {
     BOUNDARY: "boundary",       # last collect of a level (paged: a chunk) -> next launch
     FINISH: "finish",           # an engine's _finish, up to RunObserver.finish
 }
+
+BUILD_CACHE_READ = "tpuvsr.engine.build.cache_read"
+BUILD_CACHE_DECOMPRESS = "tpuvsr.engine.build.cache_decompress"
+BUILD_EXECUTABLE_LOAD = "tpuvsr.engine.build.executable_load"
+INIT_STATES = "tpuvsr.engine.init.states"
+INIT_FINGERPRINT = "tpuvsr.engine.init.fingerprint"
+INIT_DEVICE = "tpuvsr.engine.init.device"
+CHECKPOINT_PULL = "tpuvsr.engine.checkpoint.pull"
+CHECKPOINT_WRITE = "tpuvsr.engine.checkpoint.write"
+CHECKPOINT_DURABLE = "tpuvsr.engine.checkpoint.durable"
+
+#: part of a host phase -> (phase key, part key)
+ENGINE_PARTS = {
+    # a read-back from the persistent cache, timed where JAX reads
+    BUILD_CACHE_READ: ("compile", "cache_read"),
+    BUILD_CACHE_DECOMPRESS: ("compile", "cache_decompress"),
+    BUILD_EXECUTABLE_LOAD: ("compile", "executable_load"),
+    INIT_STATES: ("init", "states"),            # the interpreter's share
+    INIT_FINGERPRINT: ("init", "fingerprint"),  # fp_batch up to its pull
+    INIT_DEVICE: ("init", "device"),            # table, insert, buffers
+    CHECKPOINT_PULL: ("checkpoint", "pull"),    # pointers, rows, table
+    CHECKPOINT_WRITE: ("checkpoint", "write"),  # the .npz payloads
+    # CRCs, manifest, fsyncs, renames
+    CHECKPOINT_DURABLE: ("checkpoint", "durable"),
+}
+
+#: the parts ``builds.py`` times inside JAX's cache read: no
+#: ``RunObserver.part`` opens them
+READ_BACK_PARTS = (BUILD_CACHE_READ, BUILD_CACHE_DECOMPRESS,
+                   BUILD_EXECUTABLE_LOAD)
 
 JOB = "tpuvsr.service.job"
 LOAD_SPEC = "tpuvsr.service.load_spec"

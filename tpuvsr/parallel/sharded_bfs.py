@@ -1165,7 +1165,7 @@ class ShardedBFS:
             # snapshots load as dense planes (the engine-agnostic
             # interchange format); the start packs them when packing
             # is on
-            with obs.span(spans.INIT):
+            with obs.span(spans.INIT), obs.part(spans.INIT_DEVICE):
                 front = self._start_frontier(rows, counts0, obs)
                 n_front = self._put(counts0.astype(np.int32))
             base_dev = (sum(self.level_sizes[:-1])
@@ -1176,38 +1176,49 @@ class ShardedBFS:
             with obs.span(spans.INIT):
                 # global FPSet: one independent shard per device,
                 # stacked on the leading (sharded) axis
-                tables = {"slots": self._zeros((D, self.fp_cap, 5),
-                                               np.uint32, obs)}
+                with obs.part(spans.INIT_DEVICE):
+                    tables = {"slots": self._zeros((D, self.fp_cap, 5),
+                                                   np.uint32, obs)}
 
                 # --- init states: dedup, assign to owner devices ----------
-                init_states = list(spec.init_states())
-                dense = [codec.encode(st) for st in init_states]
-                batch = {k: np.stack([d[k] for d in dense]) for k in dense[0]}
-                fps = np.asarray(self.model.fp_batch(batch))
-                keep, seen = [], set()
-                for i in range(len(dense)):
-                    t = tuple(fps[i])
-                    if t not in seen:
-                        seen.add(t)
-                        keep.append(i)
-                owners = (np.asarray(route(jnp.asarray(fps[keep])))
-                          % np.uint32(D)).astype(int)
-                order = np.argsort(owners, kind="stable")
-                keep = [keep[i] for i in order]
-                owners = owners[order]
-                self._init_states = [init_states[i] for i in keep]
-                n0 = len(keep)
-                counts0 = np.bincount(owners, minlength=D)
+                with obs.part(spans.INIT_STATES):
+                    init_states = list(spec.init_states())
+                    dense = [codec.encode(st) for st in init_states]
+                    batch = {k: np.stack([d[k] for d in dense])
+                             for k in dense[0]}
+                with obs.part(spans.INIT_FINGERPRINT):
+                    fps = np.asarray(self.model.fp_batch(batch))
+                with obs.part(spans.INIT_STATES):
+                    keep, seen = [], set()
+                    for i in range(len(dense)):
+                        t = tuple(fps[i])
+                        if t not in seen:
+                            seen.add(t)
+                            keep.append(i)
+                with obs.part(spans.INIT_DEVICE):
+                    owners = (np.asarray(route(jnp.asarray(fps[keep])))
+                              % np.uint32(D)).astype(int)
+                with obs.part(spans.INIT_STATES):
+                    order = np.argsort(owners, kind="stable")
+                    keep = [keep[i] for i in order]
+                    owners = owners[order]
+                    self._init_states = [init_states[i] for i in keep]
+                    n0 = len(keep)
+                    counts0 = np.bincount(owners, minlength=D)
 
                 F = self.N
                 self._dev_distinct = counts0.astype(np.int64).copy()
-                front = self._start_frontier(
-                    {k: v[keep] for k, v in batch.items()}, counts0, obs)
-                n_front = self._put(counts0.astype(np.int32))
-                tables, _fr, ovf = self._sharded_ins(
-                    tables, self._rep(fps[keep]),
-                    self._rep(np.ones((n0,), bool)))
-                assert not bool(self._pull(ovf).any())
+                # the fills, the puts and the insert; the overflow
+                # flag's pull is the one wait on the device
+                with obs.part(spans.INIT_DEVICE):
+                    front = self._start_frontier(
+                        {k: v[keep] for k, v in batch.items()}, counts0,
+                        obs)
+                    n_front = self._put(counts0.astype(np.int32))
+                    tables, _fr, ovf = self._sharded_ins(
+                        tables, self._rep(fps[keep]),
+                        self._rep(np.ones((n0,), bool)))
+                    assert not bool(self._pull(ovf).any())
             fp_count = n0
 
             self._h_parent = [np.full(n0, -1, np.int64)]
@@ -1581,16 +1592,18 @@ class ShardedBFS:
                 with obs.span(spans.CHECKPOINT, depth=depth):
                     # the pulls are collectives in multi-process mode —
                     # every process participates; only rank 0 writes
-                    ck_slots = self._pull(tables["slots"])
-                    # the packed rows as the shards hold them (the
-                    # loader unpacks them: any engine/pack
-                    # configuration resumes dense planes), or the
-                    # dense planes of a run that does not pack
-                    ck_front = (
-                        {"frontier_packed": self._pull_rows(front, nn_h)}
-                        if self._pk is not None else
-                        {"frontier": {k: self._pull_rows(v, nn_h)
-                                      for k, v in front.items()}})
+                    with obs.part(spans.CHECKPOINT_PULL):
+                        ck_slots = self._pull(tables["slots"])
+                        # the packed rows as the shards hold them (the
+                        # loader unpacks them: any engine/pack
+                        # configuration resumes dense planes), or the
+                        # dense planes of a run that does not pack
+                        ck_front = (
+                            {"frontier_packed":
+                             self._pull_rows(front, nn_h)}
+                            if self._pk is not None else
+                            {"frontier": {k: self._pull_rows(v, nn_h)
+                                          for k, v in front.items()}})
                     staged = 0      # only rank 0 writes
                     if jax.process_index() == 0:
                         staged = save_checkpoint(
