@@ -228,9 +228,11 @@ def interp_levels_fixpoint(spec):
     return sizes, len(seen), depth
 
 
-def assert_incremental_fp_matches(codec, kern, states):
+def assert_incremental_fp_matches(codec, kern, states, encoded=False):
     """The O(touched) incremental fingerprint must equal the full-state
-    recompute on every enabled lane of the given states."""
+    recompute on every enabled lane of the given states (interpreter
+    values, or with `encoded` the codec's dense planes).  Returns the
+    number of enabled lanes compared."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -254,11 +256,15 @@ def assert_incremental_fp_matches(codec, kern, states):
                      for i in range(3))
 
     both_j = jax.jit(both)
+    compared = 0
     for st in states:
-        dense = {k: np.asarray(v) for k, v in codec.encode(st).items()}
+        dense = {k: np.asarray(v) for k, v in
+                 (st if encoded else codec.encode(st)).items()}
         inc, full, en = both_j(dense)
         en = np.asarray(en)
         assert (np.asarray(inc)[en] == np.asarray(full)[en]).all()
+        compared += int(en.sum())
+    return compared
 
 
 def assert_guards_match_actions(codec, kern, states):

@@ -304,13 +304,78 @@ def _trace_level(eng):
     return meter.shared_calls, meter.shared_traces
 
 
+@pytest.mark.parametrize("hash_mode", [None, "incremental"])
 @pytest.mark.parametrize("commit", ["fused", "per-action"])
-def test_level_trace_runs_each_shared_stage_once(small_native, commit):
-    """19 actions use the incremental fingerprint and the invariant
-    function: 38 uses, and the Python body of each runs once."""
-    eng = DeviceBFS(small_native, commit=commit)
-    assert len(eng.kern.action_names) == 19 and eng._fp_incremental
+def test_level_trace_runs_each_shared_stage_once(small_native, commit,
+                                                 hash_mode):
+    """19 actions use the fingerprint and the invariant function: 38
+    uses, and the Python body of each runs once.  An engine at its
+    defaults hashes whole successors (ISSUE 52); the incremental hash,
+    which only `hash_mode` asks for now, is shared the same way."""
+    kw = {} if hash_mode is None else {"hash_mode": hash_mode}
+    eng = DeviceBFS(small_native, commit=commit, **kw)
+    assert len(eng.kern.action_names) == 19
+    assert eng._fp_incremental == (hash_mode == "incremental")
     assert _trace_level(eng) == (38, 2)
+
+
+# what only the incremental hash leaves in a block stage's trace, by
+# the names of the functions its equations come from: the scratch keys
+# `seed_touch` adds, the slot writes `_touch` records in them inside
+# the action functions, and the hash itself (the parts it starts from
+# are the tile's, `kern.parent_parts` outside the block: an argument of
+# the stage, `parts_row`)
+INCREMENTAL_ONLY = {"seed_touch", "_touch", "fingerprint_incremental"}
+
+
+def _default_engine(which, spec):
+    """One engine of each class at its OWN defaults: no `hash_mode`
+    given (the sharded engines take none)."""
+    import jax
+    from jax.sharding import Mesh
+    from tpuvsr.engine.paged_bfs import PagedBFS
+    from tpuvsr.parallel.sharded_bfs import ShardedBFS
+    from tpuvsr.testing import stub_sharded_engine
+    if which == "sharded-stub":
+        return stub_sharded_engine()
+    if which == "sharded":
+        return ShardedBFS(spec, Mesh(np.array(jax.devices()[:2]), ("d",)))
+    if which == "incremental":
+        return DeviceBFS(spec, hash_mode="incremental")
+    return {"device": DeviceBFS, "paged": PagedBFS}[which](spec)
+
+
+@pytest.mark.parametrize("which", ["device", "paged", "sharded",
+                                   "sharded-stub", "incremental"])
+def test_every_engine_hashes_whole_successors_by_default(small_native,
+                                                         which):
+    """ISSUE 52: `Stage2` is built with the full hash by every engine
+    at its defaults, and the trace of one block stage (an action that
+    sends, under `vmap`; traced on types, nothing compiled) holds
+    nothing of the incremental one: no parts among its arguments, no
+    touch bookkeeping in the action function.  `hash_mode=
+    "incremental"` is the control: the same search finds all of it."""
+    import jax
+    from tpuvsr.engine.device_bfs import I32
+    eng = _default_engine(which, small_native)
+    stage2, names = eng._stage2, eng.kern.action_names
+    wanted = which == "incremental"
+    assert stage2.incremental == wanted
+    assert (stage2.parts_row is not None) == wanted
+    aid, rows = (names.index("SendDVC") if "SendDVC" in names else 0), 8
+
+    def batch(s):
+        return jax.ShapeDtypeStruct((rows,) + s.shape, s.dtype)
+    traced = stage2.expand_stage(aid, rows).trace(
+        jax.tree_util.tree_map(batch, stage2.row),
+        jax.tree_util.tree_map(batch, stage2.parts_row),
+        jax.ShapeDtypeStruct((rows,), I32))
+    text = traced.jaxpr.pretty_print(source_info=True)
+    # an equation's source reads "file:line:col (Class.function)", or
+    # "(Class.function.<locals>.inner)"
+    found = {w for w in INCREMENTAL_ONLY
+             if f".{w})" in text or f".{w}." in text}
+    assert found == (INCREMENTAL_ONLY if wanted else set())
 
 
 def test_grow_msgs_rebuilds_the_shared_stages(small_native):

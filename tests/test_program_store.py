@@ -93,7 +93,10 @@ def _changed_next_capacity(tmp_path, monkeypatch):
 
 
 def _changed_hash_mode(tmp_path, monkeypatch):
-    return DeviceBFS(load_spec("VSR", SMALL_CFG), hash_mode="full")
+    """The hash that is NOT the default's (the full one since ISSUE
+    52)."""
+    assert _small_engine().hash_mode == "full"
+    return DeviceBFS(load_spec("VSR", SMALL_CFG), hash_mode="incremental")
 
 
 def _changed_commit(tmp_path, monkeypatch):

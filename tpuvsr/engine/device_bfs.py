@@ -17,8 +17,10 @@ THREE-STAGE pass (ISSUE 10, ``commit="fused"``, the default):
   tile  --work queue  --> enabled (state, lane) items compacted per
                           action; ONLY the blocks of them that hold a
                           real item are expanded (vsr_kernel),
-                          fingerprinted (VIEW + symmetry, incremental
-                          128-bit), invariant-checked and packed, then
+                          fingerprinted (VIEW + symmetry; the full
+                          128-bit hash of the whole successor,
+                          ``hash_mode="full"``, the default since
+                          ISSUE 52), invariant-checked and packed, then
                           appended to one dense tile-local queue —
                           expand FLOPs scale with `generated`, not sum
                           of static caps
@@ -286,15 +288,25 @@ class Stage2:
     The per-successor stages (`fp_stage`, `inv_stage`, and the block
     stages made on demand) are traced once for THIS kernel: a grown
     message table is a new kernel and a new `Stage2`, a grown cap
-    finds its stages traced."""
+    finds its stages traced.
+
+    Every engine builds it with `incremental` False at its defaults
+    (ISSUE 52): a block hashes its whole successors
+    (`kern.fingerprint`), and the trace holds no parent parts, no
+    parts gather and no touch bookkeeping in the action functions.
+    `DeviceBFS(hash_mode="incremental")` is what still asks for the
+    other hash, the same values from the parent's parts."""
 
     def __init__(self, model, incremental, stat_fn=None,
                  count_moved=False):
         self.kern, self.pk, self.canon = model.kern, model.pk, model.canon
         # the incremental hash reconstitutes a fingerprint from the
-        # parent's per-row parts; the orbit-least image of a canon run
-        # cannot be, and forces the full hash (the orbit-factor state
-        # cut dwarfs the incremental saving)
+        # parent's per-row parts (one replica row and R + 1 slot rows
+        # at dynamic indices a successor, against the full hash's R +
+        # max_msgs dense rows); the orbit-least image of a canon run
+        # cannot be, and forces the full hash.  No engine asks for it
+        # at its defaults: in blocks the full hash is the cheaper one
+        # on the chip (PERF.md, PRs 50 and 52)
         self.incremental = incremental and self.canon is None
         # the kernel's counts over committed states (``commit_stats``)
         # and whether a slot records that its least image is not the
@@ -681,7 +693,7 @@ class DeviceBFS:
     _pack_manifest = of_model("pack_manifest")
 
     def __init__(self, spec: SpecModel, max_msgs=None, tile_size=128,
-                 fpset_capacity=1 << 20, hash_mode="incremental",
+                 fpset_capacity=1 << 20, hash_mode="full",
                  next_capacity=1 << 14, chunk_tiles=64, expand_mult=2,
                  expand_mults=None, model_factory=None, pipeline=2,
                  pack="auto", commit="fused", symmetry="auto",

@@ -24,8 +24,9 @@ streamed path is checked against, and the A/B leg of
   pass 1  enumerate all reachable states with the paged BFS engine
           (``PagedBFS(retain_levels=True)``);
   pass 2  re-expand every level tile-by-tile through a jitted EDGE
-          pass — the level kernel's guard + compaction + incremental-
-          fingerprint phases, minus FPSet insert/scatter — resolving
+          pass — the level kernel's guard + compaction + fingerprint
+          phases (the engine's hash, whole successors at its defaults),
+          minus FPSet insert/scatter — resolving
           successor fingerprints through a separately built gid FPSet.
 
 The two paths produce the SAME CSR modulo edge order within one
@@ -240,8 +241,8 @@ class DeviceGraph:
     def _make_edge_pass(self):
         """Jitted: one tile of states -> (fp, src row, action id, ok)
         for every enabled lane, via per-action guard compaction and
-        incremental fingerprints (the level kernel's phases 1-2 with
-        recording instead of FPSet insertion)."""
+        the engine's fingerprint stage (the level kernel's phases 1-2
+        with recording instead of FPSet insertion)."""
         kern = self.eng.kern
         T = self.eng.tile
         # the engine's trace-once fingerprint stage (liveness runs

@@ -792,7 +792,8 @@ class ShardedBFS:
                 self._need_seen = np.zeros(len(names), np.int64)
         # stage 2 of the fused step is the one-chip level program's
         # (ISSUE 50), made per built kernel, so a grown cap or bucket
-        # finds the block stages traced.  It hashes whole successors:
+        # finds the block stages traced.  It hashes whole successors,
+        # as the one-chip engines do at their defaults since ISSUE 52:
         # in blocks of 32 slots the incremental hash (the parent's
         # parts a tile, the touched rows a slot) read 0.053 s a chip
         # over the four-chip cell's slice where the full hash of every
