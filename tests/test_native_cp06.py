@@ -166,8 +166,8 @@ def test_sections_that_need_the_ast_stay_refused(section, tmp_path):
 
 @pytest.mark.parametrize("module", [
     "VR_ASSUME_NEWVIEWCHANGE", "VR_INC_RESEND", "VR_APP_STATE",
-    "VR_REPLICA_RECOVERY", "VR_REPLICA_RECOVERY_ASYNC_LOG"])
-def test_the_five_other_modules_stay_shut(module):
+    "VR_REPLICA_RECOVERY"])
+def test_the_four_other_modules_stay_shut(module):
     with pytest.raises(TLAError, match="no committed init trace"):
         load_spec(module, CFG)
 

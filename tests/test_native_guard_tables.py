@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 
 from tpuvsr.engine.spec import load_spec
-from tpuvsr.models import st03, vsr
+from tpuvsr.models import rr05, st03, vsr
+from tpuvsr.models.al05_kernel import ACTION_NAMES as AL05_ACTIONS
 from tpuvsr.models.guard_tables import table_lanes
 from tpuvsr.models.st03_kernel import ACTION_NAMES as ST03_ACTIONS
 from tpuvsr.models.vsr import (H_COMMIT, H_DEST, H_FIRST, H_OP, H_SRC,
@@ -46,8 +47,13 @@ SHAPES = {
     "shipped": ("VSR", "vsr-shipped.cfg", 32, 472),
     "restart": ("VSR", "vsr-shipped-restart.cfg", 32, 472),
     "st03": ("VR_STATE_TRANSFER", "vr-state-transfer.cfg", 24, 314),
+    # ISSUE 53: AL05Kernel's ten own tables over ST03's (AS04's and
+    # RR05's conjuncts, the four recovery guards)
+    "al05": ("VR_REPLICA_RECOVERY_ASYNC_LOG",
+             "vr-replica-recovery-async-log.cfg", 24, 374),
 }
-ACTIONS = {"VSR": VSR_ACTIONS, "VR_STATE_TRANSFER": ST03_ACTIONS}
+ACTIONS = {"VSR": VSR_ACTIONS, "VR_STATE_TRANSFER": ST03_ACTIONS,
+           "VR_REPLICA_RECOVERY_ASYNC_LOG": AL05_ACTIONS}
 CASES = [(shape, action) for shape, (module, *_cell) in SHAPES.items()
          for action in ACTIONS[module]]
 BATCH = 256
@@ -91,7 +97,13 @@ def _family(kern):
     if hasattr(kern, "guard_restart_empty"):
         return dict(types=range(1, vsr.M_RECOVERYRESP + 1),
                     statuses=(vsr.NORMAL, vsr.VIEWCHANGE, vsr.RECOVERING),
-                    dests=(-1, 0, kern.R + 1))
+                    dests=(-1, 0, kern.R + 1), recovering=vsr.RECOVERING)
+    if hasattr(kern, "crash_limit"):    # the analysis family's recovery
+        return dict(types=range(1, rr05.M_RECOVERYRESP + 1),
+                    statuses=(st03.NORMAL, st03.VIEWCHANGE,
+                              st03.STATETRANSFER, rr05.RECOVERING),
+                    dests=(st03.ANYDEST, 0, kern.R + 1),
+                    recovering=rr05.RECOVERING)
     return dict(types=range(1, st03.M_NEWSTATE + 1),
                 statuses=(st03.NORMAL, st03.VIEWCHANGE, st03.STATETRANSFER),
                 dests=(st03.ANYDEST, 0, kern.R + 1))
@@ -146,7 +158,8 @@ def _planted(kern, walked, seed):
                     turned(type=t, no_prog=1)
             if "rec_number" in base:
                 for x in (0, 1):
-                    turned(type=vsr.M_RECOVERYRESP, status=vsr.RECOVERING,
+                    turned(type=vsr.M_RECOVERYRESP,
+                           status=fam["recovering"],
                            rec_number=int(base["m_hdr"][k, H_X]) + x)
     return out
 
@@ -165,7 +178,8 @@ def _scrambled(kern, walked, seed):
     out = []
     for base in walked:
         st = {k: v.copy() for k, v in base.items()}
-        for key, hi in (("status", 3), ("view", 4), ("op", P + 1),
+        for key, hi in (("status", len(fam["statuses"])), ("view", 4),
+                        ("op", P + 1),
                         ("commit", P + 1), ("log_len", P + 1),
                         ("no_prog", 2), ("np_ctr", 2), ("sent_dvc", 2),
                         ("sent_sv", 2), ("svc", 2), ("dvc", 2),

@@ -107,12 +107,13 @@ def test_sections_that_need_the_ast_stay_refused(section, tmp_path):
         load_spec(MODULE, str(cfg))
 
 
-# VR_REPLICA_RECOVERY_CP went through the door in PR 46
-# (tests/test_native_cp06.py holds it, and these five from its side)
+# VR_REPLICA_RECOVERY_CP went through the door in PR 46 and
+# VR_REPLICA_RECOVERY_ASYNC_LOG in PR 53 (tests/test_native_cp06.py and
+# tests/test_native_al05.py hold them, and these four from their side)
 @pytest.mark.parametrize("module", [
     "VR_ASSUME_NEWVIEWCHANGE", "VR_INC_RESEND", "VR_APP_STATE",
-    "VR_REPLICA_RECOVERY", "VR_REPLICA_RECOVERY_ASYNC_LOG"])
-def test_the_five_other_modules_stay_shut(module):
+    "VR_REPLICA_RECOVERY"])
+def test_the_four_other_modules_stay_shut(module):
     with pytest.raises(TLAError, match="no committed init trace"):
         load_spec(module, CFG)
 

@@ -306,7 +306,7 @@ def test_one_chip_configurations_keep_their_block():
                     cap = max(cap, min(full, _align8(tile * mults[n])))
                 assert cap >= 384 and block_rows(cap) == EXPAND_BLOCK == 128
             seen.add(doc["name"])
-    assert len(seen) == 8
+    assert len(seen) >= 9 and "vr-replica-recovery-async-log" in seen
     assert block_rows(129) == 128
     assert [block_rows(c) for c in (128, 96, 33, 32, 24, 8)] == \
         [32, 32, 32, 32, 24, 8]

@@ -20,11 +20,12 @@ import jax.numpy as jnp
 
 from .al05 import AL05Codec
 from .as04_kernel import AS04Kernel
+from .guard_tables import lanes_of
 from .rr05 import M_RECOVERY, M_RECOVERYRESP, RECOVERING
 from .rr05_kernel import RR05Kernel
-from .st03 import NORMAL
+from .st03 import M_SVC, NORMAL, VIEWCHANGE
 from .st03_kernel import I32, ST03Kernel
-from .vsr import H_DEST, H_FIRST, H_OP, H_SRC, H_X
+from .vsr import H_DEST, H_FIRST, H_OP, H_SRC, H_TYPE, H_VIEW, H_X
 
 ACTION_NAMES = (
     "TimerSendSVC", "ReceiveHigherSVC", "ReceiveMatchingSVC", "SendDVC",
@@ -40,6 +41,15 @@ REP_KEYS = RR05Kernel.REP_KEYS + ("rec_ceil",)
 
 class AL05Kernel(RR05Kernel):
     action_names = ACTION_NAMES
+    # AL05's own line range of each action SURVEY 2.1 cites one for:
+    # the location a native spec prints for a counterexample step.  An
+    # inherited action has no entry and prints the generic location
+    # (models/native.py), never a base module's lines
+    ACTION_LINES = {
+        "Crash": (851, 885), "ReceiveRecoveryMsg": (888, 915),
+        "ReceiveRecoveryResponseMsg": (918, 932),
+        "CompleteRecovery": (947, 977),
+    }
     REP_KEYS = REP_KEYS
 
     def __init__(self, codec: AL05Codec, perms=None):
@@ -66,6 +76,49 @@ class AL05Kernel(RR05Kernel):
         s2 = super()._clear_rec(s2, i)
         s2["rec_ceil"] = s2["rec_ceil"].at[i].set(0)
         return s2
+
+    #: ST03's six, then what this module adds (the same hook)
+    COMMIT_STATS = ST03Kernel.COMMIT_STATS + (
+        ("recovering_states", "sum"), ("prefix_survivor_states", "sum"),
+        ("suffix_reply_states", "sum"), ("rec_set_peak", "max"),
+        ("dvc_set_peak", "max"))
+
+    def commit_stats(self, st):
+        """[11] uint32 of one state: ST03's six, whether a replica is
+        Recovering, whether one is Recovering with an op number above 0
+        (it kept a non-empty log prefix: never in RR05, a checkpoint
+        in CP06), whether a RecoveryResponse with ``prefix_ceil`` above
+        0 is pending in the bag or held in a receive-set (a splice has
+        a prefix to keep), and the fullest RecoveryResponse and
+        DoViewChange receive-set, in records of the R slots each has
+        (one a source: a second record of one source is
+        ``ERR_REC_OVERFLOW`` / ``ERR_DVC_OVERFLOW``, which stops a
+        run)."""
+        recovering = st["status"] == RECOVERING
+        hdr = st["m_hdr"]
+        # H_OP = -1 marks a backup's Nil form, whose H_FIRST is 0
+        pending = ((st["m_present"] == 1) & (st["m_count"] > 0)
+                   & (hdr[:, H_TYPE] == M_RECOVERYRESP)
+                   & (hdr[:, H_FIRST] > 0))
+        held = (st["rec"] == 1) & (st["rec_ceil"] > 0)
+        mine = jnp.stack([
+            recovering.any(), (recovering & (st["op"] > 0)).any(),
+            pending.any() | held.any(),
+            (st["rec"] == 1).sum(-1).max(),
+            (st["dvc"] == 1).sum(-1).max()]).astype(jnp.uint32)
+        return jnp.concatenate([super().commit_stats(st), mine])
+
+    def act_receive_matching_svc(self, st, lane):  # AS04:589-607
+        # AS04's body takes its `en` from `guard_receive_matching_svc`,
+        # a table here; the oracle is ST03's body and the module's
+        # conjuncts, a lane (as `CP06Kernel` does)
+        s2, _en = ST03Kernel.act_receive_matching_svc(self, st, lane)
+        i = self._dest_i(st, lane)
+        en = (self._recv_guard(st, lane, M_SVC) & self._can_progress(st, i)
+              & (st["status"][i] == VIEWCHANGE)
+              & (st["m_hdr"][lane, H_VIEW] == st["view"][i])
+              & (st["sent_dvc"][i] == 0))
+        return s2, en
 
     # ------------------------------------------------------------------
     # async-log recovery actions
@@ -98,13 +151,6 @@ class AL05Kernel(RR05Kernel):
         s2 = self._broadcast(
             s2, self._row(M_RECOVERY, src=r, x=u, op=floor), r)
         return s2, en
-
-    def guard_crash(self, st, lane):
-        i = lane // (self.MAX_OPS + 1)
-        last_op = lane % (self.MAX_OPS + 1)
-        return ((st["aux_restart"] < self.crash_limit)
-                & self._can_progress(st, i)
-                & (last_op <= st["op"][i]))
 
     def act_receive_recovery(self, st, lane):     # AL05:888-915
         k = lane
@@ -165,17 +211,95 @@ class AL05Kernel(RR05Kernel):
         return s2, en
 
     # ------------------------------------------------------------------
+    # guards: one table a state (stage 1 of the level program), under
+    # the rules that stand above `CP06Kernel`'s tables.  ST03's sixteen
+    # are inherited as they are where no class between adds a conjunct;
+    # the ten guards here carry AS04's (the receive-set quorum of
+    # SendSV, the ``rep_sent_dvc = FALSE`` of ReceiveMatchingSVC),
+    # RR05's (not Recovering, four times) and the four recovery guards,
+    # which RR05Kernel keeps a lane.  They stand in THIS class, below
+    # `RR05Kernel`, where `CP06Kernel` (which writes its own 22) cannot
+    # pick them up.  The ``act_*`` bodies keep their own ``en`` a lane:
+    # tests/test_native_guard_tables.py (shape "al05") and
+    # tests/test_native_al05.py hold table == ``en`` on every lane.
+    # ------------------------------------------------------------------
+    def _not_recovering_at_dest(self, st):                      # [M]
+        return self._at_dest(st)("status") != RECOVERING
+
+    def guard_timer_send_svc_table(self, st):                   # [R]
+        return (ST03Kernel.guard_timer_send_svc_table(self, st)
+                & (st["status"] != RECOVERING))
+
+    def guard_receive_higher_svc_table(self, st):               # [M]
+        return (ST03Kernel.guard_receive_higher_svc_table(self, st)
+                & self._not_recovering_at_dest(st))
+
+    def guard_receive_matching_svc_table(self, st):             # [M]
+        return (ST03Kernel.guard_receive_matching_svc_table(self, st)
+                & (self._at_dest(st)("sent_dvc") == 0))
+
+    def guard_receive_higher_dvc_table(self, st):               # [M]
+        return (ST03Kernel.guard_receive_higher_dvc_table(self, st)
+                & self._not_recovering_at_dest(st))
+
+    def guard_receive_sv_table(self, st):                       # [M]
+        return (ST03Kernel.guard_receive_sv_table(self, st)
+                & self._not_recovering_at_dest(st))
+
+    def guard_crash_table(self, st):                # [R, MAX_OPS + 1]
+        last_op = jnp.arange(self.MAX_OPS + 1, dtype=I32)
+        rep = (st["aux_restart"] < self.crash_limit) & (st["no_prog"] == 0)
+        return rep[:, None] & (last_op <= st["op"][:, None])
+
+    def guard_receive_recovery_table(self, st):                 # [M]
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_RECOVERY)
+                & (at("no_prog") == 0) & (at("status") == NORMAL))
+
+    def guard_receive_recovery_response_table(self, st):        # [M]
+        at = self._at_dest(st)
+        return (self._recv_guard(st, ..., M_RECOVERYRESP)
+                & (at("no_prog") == 0)
+                & (at("rec_number") == st["m_hdr"][:, H_X])
+                & (at("status") == RECOVERING))
+
+    def guard_complete_recovery_table(self, st):                # [R]
+        pres = st["rec"] == 1                                   # [R, R]
+        vmax = jnp.where(pres, st["rec_view"], -1).max(-1)
+        cand = pres & (st["rec_has_log"] == 1) \
+            & (st["rec_view"] == vmax[:, None])
+        return ((st["no_prog"] == 0) & (st["status"] == RECOVERING)
+                & (pres.sum(-1) > self.R // 2) & cand.any(-1))
+
+    guard_timer_send_svc = lanes_of(guard_timer_send_svc_table)
+    guard_receive_higher_svc = lanes_of(guard_receive_higher_svc_table)
+    guard_receive_matching_svc = lanes_of(
+        guard_receive_matching_svc_table)
+    guard_receive_higher_dvc = lanes_of(guard_receive_higher_dvc_table)
+    # ST03's table reads `_dvc_quorum`, which AS04 turns to the
+    # receive-set: the guard a lane of AS04 says the same
+    guard_send_sv = ST03Kernel.guard_send_sv
+    guard_receive_sv = lanes_of(guard_receive_sv_table)
+    guard_crash = lanes_of(guard_crash_table)
+    guard_receive_recovery = lanes_of(guard_receive_recovery_table)
+    guard_receive_recovery_response = lanes_of(
+        guard_receive_recovery_response_table)
+    guard_complete_recovery = lanes_of(guard_complete_recovery_table)
+
+    # ------------------------------------------------------------------
     # action table (no RetryRecovery)
     # ------------------------------------------------------------------
+    def _without_retry(self, fns):
+        """RR05's table of 21 less the slot of the action this module
+        does not have."""
+        return [fn for name, fn in zip(RR05Kernel.action_names, fns)
+                if name != "RetryRecovery"]
+
     def _guard_fns(self):
-        fns = super()._guard_fns()
-        del fns[19]                   # RetryRecovery slot
-        return fns
+        return self._without_retry(super()._guard_fns())
 
     def _action_fns(self):
-        fns = super()._action_fns()
-        del fns[19]
-        return fns
+        return self._without_retry(super()._action_fns())
 
     def lane_replica(self, name, st, lane):
         if name == "Crash":

@@ -30,17 +30,26 @@ decoded states.  This module provides exactly that facade:
   interpreter engine.
 
 ``engine.spec.load_spec`` resolves here when its spec argument is not
-an existing file but a module name the registry knows.  Three modules
+an existing file but a module name the registry knows.  Four modules
 have a committed init trace today: VSR, VR_STATE_TRANSFER (ST03, the
-base kernel of the analysis family) and VR_REPLICA_RECOVERY_CP (CP06,
-the family's last: crash with a checkpoint, log GC, recovery; its
-kernel runs AS04's and RR05's as base classes).  The two analysis
-traces hold entry 1 alone, the state the module's codec decodes for
-the zero state in view 1 (CP06's with its own planes: ``rep_app_state``,
-``rep_rec_number``, ``rep_rec_recv``, ``rep_recv_dvc``,
-``aux_restart``), and their action locations are the line ranges the
-kernel class itself cites.  The other five modules are refused by name
-until each has one.
+base kernel of the analysis family), VR_REPLICA_RECOVERY_CP (CP06, the
+family's last: crash with a checkpoint, log GC, recovery; its kernel
+runs AS04's and RR05's as base classes) and
+VR_REPLICA_RECOVERY_ASYNC_LOG (AL05: a crash keeps a log prefix; over
+RR05 too).  The three analysis traces hold entry 1 alone, the state the
+module's codec decodes for the zero state in view 1 (CP06's and AL05's
+with their own planes: ``rep_app_state``, ``rep_rec_number``,
+``rep_rec_recv``, ``rep_recv_dvc``, ``aux_restart``), and their action
+locations are the line ranges the kernel class itself cites.  AL05's
+differs from that state in one variable: ``rep_last_normal_view`` is 1,
+not 0.  With 0 the one record of the real module
+(scripts/recovery_fixpoints.json) is not reproduced: a replica that
+recovers in view 1 takes last normal view 1 from the response and then
+outranks, with an empty log, replicas that never left view 1, and
+NoLogDivergence fails in level 16 of a check the record has clean to
+its fixpoint; with 1 all 30 level sizes come out
+(tests/test_native_al05.py, PR 53).  The other four modules are refused
+by name until each has one.
 
 **Which (module, ReplicaCount) the door admits.**  Every committed
 trace is a state of three replicas, and Init is that state wherever
@@ -52,8 +61,9 @@ time it is used (the same rule at the trace's own R must give the
 trace's entry 1, value for value).  The table lists only what a tier-1
 test holds to a plain reference: VR_STATE_TRANSFER at 3 and 5
 (``tests/test_native_st03_r5.py``,
-``benchmark/tools/state_transfer_reference.py``).  VSR and
-VR_REPLICA_RECOVERY_CP have no reference that reads R and stay at 3:
+``benchmark/tools/state_transfer_reference.py``).  VSR,
+VR_REPLICA_RECOVERY_CP and VR_REPLICA_RECOVERY_ASYNC_LOG have no
+reference held to a record at another R and stay at 3:
 any other R there, an even R and an R the table lacks are refused with
 the R named — never a silently wrong state space.
 """
@@ -79,6 +89,8 @@ INIT_TRACES = {
         REPO, "examples", "VR_STATE_TRANSFER_init_trace.txt"),
     "VR_REPLICA_RECOVERY_CP": os.path.join(
         REPO, "examples", "VR_REPLICA_RECOVERY_CP_init_trace.txt"),
+    "VR_REPLICA_RECOVERY_ASYNC_LOG": os.path.join(
+        REPO, "examples", "VR_REPLICA_RECOVERY_ASYNC_LOG_init_trace.txt"),
 }
 
 # module name -> the ReplicaCounts at which Init is "the module's codec's
