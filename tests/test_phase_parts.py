@@ -13,10 +13,12 @@ import numpy as np
 import pytest
 
 from tests.test_obs import _Recorder
+from tpuvsr.engine.paged_bfs import PagedBFS
 from tpuvsr.obs import (Metrics, RunObserver, builds, read_journal, spans,
                         validate_metrics)
-from tpuvsr.testing import (stub_device_engine, stub_sharded_engine,
-                            stub_sym_engine)
+from tpuvsr.testing import (SYMPAIR_DISTINCT, SYMPAIR_ORBITS,
+                            stub_device_engine, stub_sharded_engine,
+                            stub_sym_engine, stub_sym_sharded)
 
 INIT_PARTS = (spans.INIT_STATES, spans.INIT_FINGERPRINT, spans.INIT_DEVICE)
 SNAPSHOT_PARTS = (spans.CHECKPOINT_PULL, spans.CHECKPOINT_WRITE,
@@ -428,3 +430,95 @@ def test_save_checkpoint_without_an_observer_opens_no_part(tmp_path):
     assert save_checkpoint(path, **_snapshot_arguments()) > 0
     with open(os.path.join(path, "manifest.json")) as f:
         assert json.load(f)["depth"] == 1
+
+
+# ---------------------------------------------------------------------
+# the canonical fingerprint of a batch: one compiled program a model
+# (ISSUE 54)
+# ---------------------------------------------------------------------
+def _sym_batches(eng):
+    """The Init batch of the SymPair fixture as `_register_init`
+    stacks it, and every reachable state: a and b over 0..3."""
+    dense = [eng.codec.encode(st) for st in eng.spec.init_states()]
+    init = {k: np.stack([d[k] for d in dense]) for k in dense[0]}
+    a, b = (x.reshape(-1).astype(np.int32)
+            for x in np.meshgrid(np.arange(4), np.arange(4)))
+    zeros = np.zeros_like(a)
+    return {"init": init,
+            "reachable": {"status": zeros, "a": a, "b": b, "err": zeros}}
+
+
+@pytest.fixture(scope="module")
+def sym_engine():
+    return stub_sym_engine()
+
+
+@pytest.mark.parametrize("which", ["init", "reachable"])
+def test_canon_fp_batch_is_the_eager_fingerprint_bit_for_bit(sym_engine,
+                                                             which):
+    import jax
+    import jax.numpy as jnp
+    model = sym_engine.model
+    assert model.canon is not None
+    batch = _sym_batches(sym_engine)[which]
+    eager = jax.vmap(model.canon.fingerprint_fn(model.kern))(
+        {k: jnp.asarray(v) for k, v in batch.items()})
+    got = np.asarray(model.fp_batch(batch))
+    assert got.dtype == np.uint32 == np.asarray(eager).dtype
+    assert got.shape == (len(batch["a"]), 4)
+    np.testing.assert_array_equal(got, np.asarray(eager))
+    # orbit-mates share a fingerprint, so the batch holds its orbits
+    want = {"init": 1, "reachable": SYMPAIR_ORBITS}[which]
+    assert len({tuple(row) for row in got}) == want
+
+
+@pytest.mark.parametrize("which", ["init", "reachable"])
+def test_canon_fp_batch_traces_once_a_batch_shape(monkeypatch, which):
+    eng = stub_sym_engine()
+    kern, traces = eng.model.kern, []
+    fingerprint = kern.fingerprint
+    # the kernel's Python body runs while a program is traced only
+    monkeypatch.setattr(
+        kern, "fingerprint",
+        lambda st: traces.append(1) or fingerprint(st), raising=False)
+    batch = _sym_batches(eng)[which]
+    first = np.asarray(eng.model.fp_batch(batch))
+    assert len(traces) == 1
+    again = {k: v.copy() for k, v in batch.items()}
+    np.testing.assert_array_equal(np.asarray(eng.model.fp_batch(again)),
+                                  first)
+    assert len(traces) == 1
+
+
+def test_canon_off_keeps_the_kernels_own_fingerprint_batch():
+    eng = stub_sym_engine(symmetry=False)
+    assert eng.model.canon is None and eng.model._canon_fp is None
+    batch = _sym_batches(eng)["reachable"]
+    fps = np.asarray(eng.model.fp_batch(batch))
+    np.testing.assert_array_equal(
+        fps, np.asarray(eng.model.kern.fingerprint_batch(batch)))
+    assert len({tuple(row) for row in fps}) == SYMPAIR_DISTINCT
+
+
+SYM_ENGINES = {
+    "device": stub_sym_engine,
+    "paged": lambda: stub_sym_engine(cls=PagedBFS),
+    "sharded": stub_sym_sharded,
+}
+
+
+@pytest.mark.parametrize("kind", sorted(SYM_ENGINES))
+def test_a_second_run_with_canon_on_builds_nothing(kind):
+    """What a bfs cell does: a warm-up and a window on one engine
+    object.  The window finds the canonical fingerprint built."""
+    eng = SYM_ENGINES[kind]()
+    warm = eng.run(obs=RunObserver())
+    res = eng.run(obs=RunObserver())
+    assert warm.ok and res.ok
+    assert res.distinct_states == warm.distinct_states == SYMPAIR_ORBITS
+    assert warm.metrics["counters"]["build_programs"] > 0
+    counters = res.metrics["counters"]
+    assert counters["build_programs"] == 0
+    assert counters.get("grows", 0) == 0
+    assert res.metrics["gauges"]["build_trace_s"] == 0.0
+    assert res.metrics["phase_parts"]["init"]["fingerprint"] >= 0.0

@@ -162,6 +162,13 @@ class CheckedModel:
         else:
             self.canon = build_canon_spec(spec, self.codec, self.kern,
                                           self._symmetry_req)
+        # the host-side canonical fingerprint (`fp_batch`): one
+        # compiled program a model, as the kernel's own
+        # `fingerprint_batch` is; made here and not in a run, so a
+        # second run on this object finds it built
+        self._canon_fp = (
+            jax.jit(jax.vmap(self.canon.fingerprint_fn(self.kern)))
+            if self.canon is not None else None)
         if self.edges and (self.canon is not None or self.sym_fold > 1):
             raise TLAError(
                 "edge emission requires symmetry off: the behavior "
@@ -368,11 +375,13 @@ class CheckedModel:
     def fp_batch(self, batch):
         """Fingerprint a dense batch through the canonical seam (the
         host-side twin of the in-kernel fingerprint stage: init
-        registration, resume re-routing)."""
+        registration, resume re-routing).  One compiled program on
+        either branch, traced once a batch shape: the kernel's jitted
+        `fingerprint_batch` with canon off, `build`'s jit of the
+        canonical fingerprint with it on."""
         if self.canon is None:
             return self.kern.fingerprint_batch(batch)
-        arr = {k: jnp.asarray(v) for k, v in batch.items()}
-        return jax.vmap(self.canon.fingerprint_fn(self.kern))(arr)
+        return self._canon_fp(batch)
 
     def fetch_row(self, batch, i):
         """One dense state row from a frontier-format buffer (packed
