@@ -294,6 +294,40 @@ def test_a_shape_without_restarts_is_untouched(config):
     assert _program(config) == BEFORE_K[config]
 
 
+# The sharded step of the four-chip defect cell, at the capacities
+# `benchmark/configs/vsr-defect-4chip.json` builds it with, read at
+# commit bb89680 (the parent of PR 55, which taught the step to carry a
+# kernel's `commit_stats` to the owner of each row): a kernel whose
+# hook returns None for its shape, the defect cfg's, has the step it
+# had, word for word (the plain text's sha256 read c4c15971…18c7f6c5 on
+# both trees in a process of its own).
+FOUR_CHIP_STEP = \
+    "a989b64116cba534f1e4c05196ad9aad9e393a3734f35938b7d7125a4f7915fc"
+
+
+def test_the_four_chip_defect_step_is_untouched():
+    from tpuvsr.parallel.sharded_bfs import ShardedBFS
+    with open(os.path.join(CONFIGS, "vsr-defect-4chip.json")) as f:
+        doc = json.load(f)
+    D = doc["layout"]["chips"]
+    eng = ShardedBFS(
+        load_spec(doc["module"], os.path.join(REPO, "benchmark", doc["cfg"])),
+        Mesh(np.array(jax.devices()[:D]), ("d",)),
+        **doc["assumed"]["engine"]["sharded"])
+    assert eng._stat_fn is None
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=eng._sh)
+    per_dev = arg((D,), jnp.int32)
+    rows = arg((D * eng.N, eng._pk.words), jnp.uint32)
+    col = arg((D * eng.N,), jnp.int32)
+    lowered = eng._step.lower(
+        {"slots": arg((D, eng.fp_cap, 5), jnp.uint32)}, rows, per_dev,
+        per_dev, rows, col, col, col, per_dev, per_dev)
+    assert hashlib.sha256(_private_functions_folded(
+        lowered.as_text()).encode()).hexdigest() == FOUR_CHIP_STEP
+
+
 _MODULE = """module @jit_level {
   func.func public @main(%arg0: tensor<4xi1>) -> tensor<4xi32> {
     %0 = func.call @_where(%arg0) : (tensor<4xi1>) -> tensor<4xi32>

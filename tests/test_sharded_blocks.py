@@ -279,9 +279,10 @@ def test_no_action_function_runs_over_a_whole_cap(built):
 def test_one_chip_configurations_keep_their_block():
     """`block_rows` is EXPAND_BLOCK at every cap a committed one-chip
     configuration starts with (and calibration never cuts a cap below
-    its start), and a quarter of it at the four-chip cell's caps."""
+    its start), and a quarter of it at the caps each four-chip
+    configuration starts with (its own tile read)."""
     from tpuvsr.engine.checked import CheckedModel
-    seen = set()
+    seen, four_chip = set(), {}
     for path in sorted(glob.glob(os.path.join(BENCH, "configs", "*.json"))):
         with open(path) as f:
             doc = json.load(f)
@@ -291,11 +292,13 @@ def test_one_chip_configurations_keep_their_block():
             model.build(kw.get("max_msgs"))
             kern = model.kern
             if kind == "sharded":
-                caps = [static_cap(kw.get("tile", 32),
-                                   kw.get("tile", 32) * kern._lane_count(n))
+                # the caps `ShardedBFS._build` starts this configuration
+                # with: the static ones of its tile
+                tile = kw.get("tile", 32)
+                caps = [static_cap(tile, tile * kern._lane_count(n))
                         for n in kern.action_names]
                 if "4chip" in doc["name"]:
-                    assert sorted(set(caps)) == [96, 128]
+                    four_chip[doc["name"]] = sorted(set(caps))
                     assert {block_rows(c) for c in caps} == {32}
                 continue
             tile, mults = kw.get("tile_size", 128), kw.get("expand_mults", {})
@@ -307,6 +310,10 @@ def test_one_chip_configurations_keep_their_block():
                 assert cap >= 384 and block_rows(cap) == EXPAND_BLOCK == 128
             seen.add(doc["name"])
     assert len(seen) >= 9 and "vr-replica-recovery-async-log" in seen
+    # a shard's tile of 32 states starts at 4 lanes a state, and no
+    # four-chip configuration has counted a need beyond it
+    assert four_chip == {"vsr-defect-4chip": [96, 128],
+                         "vr-replica-recovery-cp-4chip": [96, 128]}
     assert block_rows(129) == 128
     assert [block_rows(c) for c in (128, 96, 33, 32, 24, 8)] == \
         [32, 32, 32, 32, 24, 8]

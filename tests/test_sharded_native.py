@@ -187,17 +187,22 @@ def test_start_frontier_is_bit_identical_to_the_old_one(built, start,
     assert eng._pk.zero_row.any()
 
 
-def test_four_chip_configuration_names_what_the_engine_provides():
-    """benchmark/configs/vsr-defect-4chip.json hands its capacities to
-    the constructor as they stand; an engine without a property it
-    requires (every commit before ISSUE 27) refuses before it builds."""
+@pytest.mark.parametrize("config", ["vsr-defect-4chip",
+                                    "vr-replica-recovery-cp-4chip"])
+def test_four_chip_configuration_names_what_the_engine_provides(config):
+    """A four-chip configuration's file hands its capacities to the
+    constructor as they stand; an engine without a property it
+    requires (start_packs_live_rows: every commit before ISSUE 27;
+    commit_stats_at_owner, which the CP06 cell's kernel readers rest
+    on: every commit before ISSUE 55) refuses before it builds."""
     import inspect
 
     from tpuvsr.core.values import TLAError
-    with open(os.path.join(BENCH, "configs", "vsr-defect-4chip.json")) as f:
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
         kw = json.load(f)["assumed"]["engine"]["sharded"]
     assert set(kw) <= set(inspect.signature(ShardedBFS).parameters)
-    assert kw["requires"] == ["start_packs_live_rows"]
+    assert kw["requires"] == ["start_packs_live_rows"] + \
+        ["commit_stats_at_owner"] * (config != "vsr-defect-4chip")
     assert set(kw["requires"]) <= ShardedBFS.PROVIDES
     with pytest.raises(TLAError, match="does not provide .'host_free"):
         ShardedBFS(None, None, requires=["host_free_levels"])

@@ -223,6 +223,46 @@ def test_sharded_control_scalars(four_chip_engine, no_cache):
              f"sharded control scalars D={D} A={A}")
 
 
+def test_sharded_step_with_commit_stats(topo, no_cache):
+    """`step_shard` of the four-chip CP06 configuration at its real
+    capacities (ISSUE 55): a second kernel class, 812 lanes a state, and
+    the kernel's ten `commit_stats` words riding each bucket row to its
+    owner: seven all_to_alls where the defect step has six, and the
+    control scalars' pull with the stat vectors in it."""
+    import json
+
+    from tpuvsr.engine.spec import load_spec
+    from tpuvsr.parallel.sharded_bfs import ShardedBFS
+    bench = os.path.join(REPO, "benchmark")
+    with open(os.path.join(bench, "configs",
+                           "vr-replica-recovery-cp-4chip.json")) as f:
+        doc = json.load(f)
+    eng = ShardedBFS(
+        load_spec(doc["module"], os.path.join(bench, doc["cfg"])),
+        Mesh(np.array(topo.devices), ("d",)),
+        **doc["assumed"]["engine"]["sharded"])
+    D, N, A = eng.D, eng.N, len(eng.kern.action_names)
+    assert (N, eng.fp_cap, A) == (1 << 19, 1 << 22, 22)
+    n_stat = len(eng.kern.COMMIT_STATS)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=eng._sh)
+    per_dev = arg((D,), jnp.int32)
+    rows, col = arg((D * N, eng._pk.words), jnp.uint32), arg((D * N,),
+                                                             jnp.int32)
+    compiled = _compile(
+        eng._step.lower({"slots": arg((D, eng.fp_cap, 5), jnp.uint32)},
+                        rows, per_dev, per_dev, rows, col, col, col,
+                        per_dev, per_dev),
+        f"sharded step with commit stats D={D} tile={eng.tile}")
+    assert compiled.as_text().count("all-to-all(") == 7
+    counts = arg((D, A), jnp.uint32)
+    _compile(eng._pack_scalars.lower(per_dev, per_dev, per_dev, per_dev,
+                                     per_dev, counts, counts,
+                                     arg((D, n_stat), jnp.uint32)),
+             f"sharded control scalars D={D} A={A} stats={n_stat}")
+
+
 @pytest.mark.parametrize("what", ["insert", "stats", "page-out"])
 def test_paged_deployment_programs(one_chip, no_cache, what):
     """The programs beside the level program that touch the paged

@@ -17,7 +17,9 @@ Design (the TPU answer to TLC's shared-memory worker pool):
 The exchange uses fixed-capacity buckets (XLA needs static shapes); a
 bucket overflow pauses the level so the host can grow the bucket and
 re-enter.  The exchange ships whole dense states (plus 16-byte
-fingerprint and 12-byte trace meta) to their owner in ONE all_to_all —
+fingerprint, 12-byte trace meta and, since ISSUE 55, the kernel's
+``commit_stats`` words, which the owner sums over what it commits) to
+their owner in ONE all_to_all —
 chosen over a fps-only + verdict-round-trip design because owner-side
 state residence is what keeps the frontier hash-balanced and the next
 level's expansion collective-free; the measured cost is reported per
@@ -250,7 +252,7 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
     step(tables, frontier, n_front, start_t, nb, nbp, nba, nbprm, nn,
          base_gid)
       -> (tables, nb, nbp, nba, nbprm, nn, t, reason, viol, gen, sent,
-          dead, act, need, gfull, amp, blk)
+          dead, act, need, gfull, amp, blk[, cs])
     Every array is sharded over `axis`; scalars come back as [D] arrays
     (one per device; identical where globally agreed).  With
     ``check_deadlock`` a frontier state with no enabled successor
@@ -304,7 +306,17 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
     single-device engines' level-marker proviso but deterministic
     and collective-free.  ``gfull``/``amp`` carry the unreduced
     generated count and the shortcut-state tally (equal to ``gen`` /
-    zero when POR is off)."""
+    zero when POR is off).
+
+    What the kernel asks to have counted over the states a run commits
+    (``commit_stats``, ISSUE 55): where `stage2` carries the kernel's
+    hook, a successor's stat vector rides in its bucket beside the row
+    (``n_stat`` more words on the wire) and the OWNER reduces it, sum
+    or maximum by ``kern.COMMIT_STATS``, over the rows its insert
+    finds fresh: a state is counted once, by the shard that commits
+    it, whichever shards generated it.  ``cs`` is each shard's vector
+    for the dispatch; a kernel without the hook has neither the plane
+    nor the output, and the step it had."""
     n_dev = mesh.shape[axis]
     L = kern.n_lanes
     T = tile
@@ -328,6 +340,11 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
         caps_v = jnp.asarray(caps, jnp.int32)
         seg_off_v = jnp.asarray(seg_off)
         guards = kern._guard_fns()
+    stats = fused and stage2.stat_fn is not None
+    if stats:
+        # which entries of the stat vector add up (the others: maxima)
+        stat_sums = jnp.asarray([how == "sum"
+                                 for _n, how in kern.COMMIT_STATS])
     por_amat = (jnp.asarray(por.amat) if por is not None else None)
 
     def step_shard(tables, frontier, n_front, start_t,
@@ -445,6 +462,8 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                 with jax.named_scope(spans.COMPACT):
                     flat_src = (queue["rows"] if pack_spec is None
                                 else {"rows": queue["rows"]})
+                    if stats:
+                        flat_src = dict(flat_src, stat=queue["stat"])
                     fps, en_f = queue["fp"], queue["en"]
                     flatpos = (queue["pidx"] * L + seg_off_v[queue["aid"]]
                                + queue["lane"])
@@ -560,6 +579,8 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                 i_m = a2a(b_m).reshape(n_dev * cap)
                 i_st = {k: a2a(v).reshape((n_dev * cap,) + v.shape[2:])
                         for k, v in b_st.items()}
+                if stats:
+                    i_stat = i_st.pop("stat")   # [D*cap, n_stat]
                 if pack_spec is not None:
                     i_st = i_st["rows"]     # [D*cap, words] packed rows
 
@@ -597,6 +618,14 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                 nba = nba.at[dest].set(i_a[src], mode="drop")
                 nbprm = nbprm.at[dest].set(i_m[src], mode="drop")
             n_fresh = fresh.sum()
+            if stats:
+                # over the states this shard commits, counted where
+                # `nn` is: an insert persists across a pause, and so
+                # does its count
+                new = jnp.where(fresh[:, None], i_stat[perm2], 0)
+                cs = jnp.where(stat_sums,
+                               c["cs"] + new.sum(0, dtype=U32),
+                               jnp.maximum(c["cs"], new.max(0)))
 
             # committed-but-unresolved probes pause the level for table
             # growth; resolved lanes landed atomically so re-entry of
@@ -620,7 +649,7 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                                                         RUNNING)))))))
             reason = jnp.where((reason == RUNNING) & g_povf,
                                R_FPSET_GROW, reason)
-            return {
+            carried = {
                 "t": jnp.where(commit & ~g_povf, t + 1, t),
                 "reason": jnp.where(c["reason"] == RUNNING, reason,
                                     c["reason"]),
@@ -646,6 +675,9 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                 # (the one-chip body's `blk`)
                 "blk": c["blk"] + blk_t,
             }
+            if stats:
+                carried["cs"] = cs
+            return carried
 
         init = {
             "t": start_t[0],
@@ -663,6 +695,8 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
             "amp": jnp.asarray(0, jnp.int32),
             "blk": jnp.zeros((n_act,), jnp.uint32),
         }
+        if stats:
+            init["cs"] = jnp.zeros((len(kern.COMMIT_STATS),), U32)
         out = jax.lax.while_loop(cond, body, init)
         one = lambda x: x[None]
         return ({"slots": out["slots"][None]},
@@ -670,11 +704,12 @@ def sharded_level(kern, inv_fn, mesh: Mesh, axis: str,
                 one(out["nn"]), one(out["t"]), one(out["reason"]),
                 out["viol"][None], one(out["gen"]), one(out["sent"]),
                 one(out["dead"]), out["act"][None], out["need"][None],
-                one(out["gfull"]), one(out["amp"]), out["blk"][None])
+                one(out["gfull"]), one(out["amp"]), out["blk"][None]) \
+            + ((out["cs"][None],) if stats else ())
 
     sp = P(axis)
     return _shard_map(step_shard, mesh=mesh, in_specs=(sp,) * 10,
-                      out_specs=(sp,) * 17)
+                      out_specs=(sp,) * (17 + stats))
 
 
 class ShardedBFS:
@@ -687,13 +722,21 @@ class ShardedBFS:
     frontier is `[D, ...]`, its budget is tested between levels only;
     the frontier and the fingerprint set are hash-partitioned over the
     mesh axis and states migrate to their owner in the in-level
-    all_to_all."""
+    all_to_all.
+
+    The kernel's ``commit_stats`` are counted as the one-chip engines
+    count them, under the same counter and gauge names, by the shard
+    that owns each committed state (`sharded_level`);
+    `_stat_shard[d]` is shard d's part of each."""
 
     # what a caller may have sized its capacities by, and names in
     # `requires`: the start of a run packs on the host the rows that
     # exist, never D x next_capacity (ISSUE 27; before it 131 s a
-    # run() at 4 x 262,144 rows)
-    PROVIDES = frozenset({"start_packs_live_rows"})
+    # run() at 4 x 262,144 rows); a kernel's ``commit_stats`` are
+    # counted by the shard that commits each state (ISSUE 55; before it
+    # a run reported none of them)
+    PROVIDES = frozenset({"start_packs_live_rows",
+                          "commit_stats_at_owner"})
 
     # what is checked lives on `self.model` (engine/checked.py); the
     # names this file and the tests read it by
@@ -790,6 +833,16 @@ class ShardedBFS:
             if self._need_seen is None or \
                     len(self._need_seen) != len(names):
                 self._need_seen = np.zeros(len(names), np.int64)
+        # what the kernel asks to have counted over the states a run
+        # commits (``commit_stats``), as `DeviceBFS._build` reads it: a
+        # kernel without the hook, or one that returns None for its
+        # shape, gets the step it always had
+        self._stat_fn = (getattr(self.kern, "commit_stats", None)
+                         if self.commit == "fused" else None)
+        # which entries of the stat vector add up (the others: maxima)
+        self._stat_sums = np.array(
+            [how == "sum" for _n, how in self.kern.COMMIT_STATS]
+            if self._stat_fn else [], bool)
         # stage 2 of the fused step is the one-chip level program's
         # (ISSUE 50), made per built kernel, so a grown cap or bucket
         # finds the block stages traced.  It hashes whole successors,
@@ -800,7 +853,8 @@ class ShardedBFS:
         # cap slot had read 0.006, and the step with the full hash
         # commits 42,464 states/s on one chip where the incremental
         # one commits 36,069 (PERF.md, PR 50)
-        self._stage2 = (Stage2(self.model, incremental=False)
+        self._stage2 = (Stage2(self.model, incremental=False,
+                               stat_fn=self._stat_fn)
                         if self.commit == "fused" else None)
         self._make_step()
         # the start's two programs, built once per engine: a run()
@@ -1003,6 +1057,9 @@ class ShardedBFS:
         # of the blocks the caps of the committed tiles hold
         self._blocks_act = np.zeros(len(self.kern.action_names), np.int64)
         self._blocks_cap = 0
+        # the kernel's commit stats, shard by shard ([D, n_stat])
+        self._stat_shard = np.zeros((self.D, len(self._stat_sums)),
+                                    np.int64)
         self._por_kept = self._por_full = self._por_amp = 0
         # multi-process: every rank collects, only host 0 writes the
         # journal / metrics file / stats table (per-shard numbers are
@@ -1038,8 +1095,9 @@ class ShardedBFS:
         def _row_bytes():
             # state bytes as the wire actually moves them (the
             # exchange buckets carry packed rows where a pack spec is
-            # bound) + fps/mask/meta
-            return self.model.row_bytes() + 16 + 1 + 12
+            # bound) + fps/mask/meta + the kernel's stat words
+            return (self.model.row_bytes() + 16 + 1 + 12
+                    + 4 * len(self._stat_sums))
         exch_rows_useful = 0
         exch_rows_wire = 0
         exch_bytes_useful = 0
@@ -1286,18 +1344,22 @@ class ShardedBFS:
             # ONE replication pull for all per-dispatch control
             # scalars — separate _pull calls cost one collective (and
             # one device round-trip) EACH; pack [D] reason/sent/
-            # gen/gfull/amp and the [D, A] act and blk counters into a
-            # single [D, 5+2A] array first
+            # gen/gfull/amp, the [D, A] act and blk counters and the
+            # kernel's [D, n_stat] commit stats (o[17:], where the step
+            # carries them) into a single [D, 5+2A+n_stat] array first
             packed = np.asarray(self._pull(
                 self._pack_scalars(o[7], o[10], o[9], o[14], o[15],
-                                   o[12], o[16])), np.int64)
+                                   o[12], o[16], *o[17:])), np.int64)
             reason = int(packed[0, 0])
             sent = int(packed[:, 1].sum())
             gen = int(packed[:, 2].sum())
             gfull = int(packed[:, 3].sum())
             amp = int(packed[:, 4].sum())
-            act, blk = np.split(packed[:, 5:].sum(axis=0), 2)
-            return reason, sent, gen, gfull, amp, act, blk
+            n_act = len(self._act_counts)
+            act, blk = np.split(packed[:, 5:5 + 2 * n_act].sum(axis=0), 2)
+            # each shard's own, uint32 on the device
+            stat = packed[:, 5 + 2 * n_act:] & 0xFFFFFFFF
+            return reason, sent, gen, gfull, amp, act, blk, stat
 
         # shard context for fault hooks: the HOST process in
         # multi-process runs; a single-process mesh drives every
@@ -1372,7 +1434,12 @@ class ShardedBFS:
                      start_t) = out[:7]
                 out, sc = pipe.collect(pull)
                 (reason, sent, gen_add, gfull_add, amp_add, act_add,
-                 blk_add) = sc
+                 blk_add, stat_add) = sc
+                # counted on the device where a row lands, so a paused
+                # attempt's commits count once, as `nn` does
+                self._stat_shard = np.where(
+                    self._stat_sums, self._stat_shard + stat_add,
+                    np.maximum(self._stat_shard, stat_add))
                 exch_rows_useful += sent
                 exch_bytes_useful += sent * _row_bytes()
                 # generated is accumulated per dispatch attempt (a
@@ -1686,6 +1753,14 @@ class ShardedBFS:
         res.distinct_states = fp_count
         self.model.gauges(obs, res.states_generated, fp_count,
                           (self._por_kept, self._por_full, self._por_amp))
+        if self._stat_fn:
+            # the shards' vectors reduced across the mesh, under the
+            # names `DeviceBFS._final_gauges` writes
+            total = np.where(self._stat_sums, self._stat_shard.sum(axis=0),
+                             self._stat_shard.max(axis=0))
+            for (name, how), value in zip(self.kern.COMMIT_STATS, total):
+                (obs.count if how == "sum" else obs.gauge)(name,
+                                                           int(value))
         cap_total = self.fp_cap * self.D
         obs.gauge("fpset_capacity", cap_total)
         obs.gauge("fpset_occupancy",
@@ -1725,12 +1800,15 @@ class ShardedBFS:
         return fused_caps(self.kern, self.tile, self.expand_caps)
 
 
-def pack_scalars(reason, sent, gen, gfull, amp, act, blk):
-    """A dispatch's per-shard control scalars, [D] each, and its
-    [D, A] action and block counters as one [D, 5+2A] int32 array."""
+def pack_scalars(reason, sent, gen, gfull, amp, act, blk, *stat):
+    """A dispatch's per-shard control scalars, [D] each, its [D, A]
+    action and block counters and, where the step carries them, its
+    [D, n_stat] commit stats (uint32, which the host reads them back
+    as) as one [D, 5+2A+n_stat] int32 array."""
     return jnp.concatenate(
         [reason[:, None], sent[:, None], gen[:, None], gfull[:, None],
-         amp[:, None], act.astype(jnp.int32), blk.astype(jnp.int32)],
+         amp[:, None], act.astype(jnp.int32), blk.astype(jnp.int32)]
+        + [jax.lax.bitcast_convert_type(x, jnp.int32) for x in stat],
         axis=1)
 
 
