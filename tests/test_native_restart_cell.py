@@ -210,16 +210,23 @@ def test_symmetry_on_levels_equal_the_reference(build, spec, reference):
 # vsr-shipped read b4260724…17666c1e (PR 49 saw it once, PR 54 once
 # and then caught both texts): the same module with ONE more private
 # `_where(128xi1, 128xi32, 128xi32)`, a second copy of a function it
-# already held, and every numbered name after it one higher.
+# already held, and every numbered name after it one higher.  PR 56
+# (stage 2 selects an action's enabled lanes with `enabled_lanes`, not
+# `jnp.nonzero`) read "lowered" again, of both shapes: pack manifest
+# and store key are as before, and of the level program's jaxpr every
+# equation outside the scope `tpuvsr.level.compact` is the parent's,
+# in order, by primitive, name stack and result types (14,508 of them
+# at vsr-defect, 19,179 at vsr-shipped; inside the scope 5,586 → 5,092
+# and 5,605 → 5,111: 26 fewer an action).
 BEFORE_K = {
     "vsr-shipped": {
         "pack": "4794ecbbab3f66ae8443bca05016080f448e065ace9c9565337be2002ba5b17c",
         "key": "aa51df70451f4b78784f12dfcb5c630a08d66816430fded995dc27b0b6a14998",
-        "lowered": "2c4c9e343281a10c2daf59f2d228ebf1e78620ee6e25ae2959871b0cb943e957"},
+        "lowered": "6ecb339591301d37ee4dd0ddec5f5b97aa7fa3a30572dcad5a5d859b618a4af0"},
     "vsr-defect": {
         "pack": "1730ab9928885a97b25695c893b0321fda8edeb4d2ed3b664416ebd42db6952d",
         "key": "18b401550e71f9e76a6e79b6c8086ac74fa968e0e736c184bd440ec63b97f52a",
-        "lowered": "c05e13ac3f5f699bbba325106703dd3f727f48d671a26d46c959a510f4c0c920"},
+        "lowered": "3e64702f0b49012e7d87df554299ad501b587ab62d062261f08a3ca8a9357d88"},
 }
 
 _PRIVATE = re.compile(r"^  func\.func private @([\w.$-]+)\(")
@@ -300,9 +307,13 @@ def test_a_shape_without_restarts_is_untouched(config):
 # kernel's `commit_stats` to the owner of each row): a kernel whose
 # hook returns None for its shape, the defect cfg's, has the step it
 # had, word for word (the plain text's sha256 read c4c15971…18c7f6c5 on
-# both trees in a process of its own).
+# both trees in a process of its own).  Read again in PR 56, whose
+# `enabled_lanes` is stage 2's selection in this step too: every
+# equation of the step's jaxpr outside the scope `tpuvsr.level.compact`
+# is the parent's, in order (14,206 of them; inside it 5,594 → 5,100);
+# the plain text's sha256 reads a4ff5f77…b2632603.
 FOUR_CHIP_STEP = \
-    "a989b64116cba534f1e4c05196ad9aad9e393a3734f35938b7d7125a4f7915fc"
+    "b740a450ebe58105033328416300dccfe4a1816cc3b5cc82c07336416022a707"
 
 
 def test_the_four_chip_defect_step_is_untouched():

@@ -159,7 +159,8 @@ def _align8(n):
 # 24-level flagship run — six compiles of the level program.  What the
 # padding costs a level program since ISSUE 30: the headroom gate of
 # the one-chip body (a tile commits only while the next buffer has room
-# for the sum of the caps) and the `nonzero` of each action's segment.
+# for the sum of the caps) and the selection of each action's segment
+# (`enabled_lanes`: its compares go by the cap's slots).
 # Stage 2 expands only the blocks that hold enabled lanes (EXPAND_BLOCK,
 # `Stage2`: the one-chip body's and, since ISSUE 50, the sharded
 # step's) and appends them to a dense queue.  The one-chip stage 3
@@ -230,6 +231,53 @@ def piece_lanes(total):
     return min(COMMIT_PIECE, total)
 
 
+def enabled_lanes(en, slots):
+    """The first `slots` enabled (state, lane) items of one action's
+    guard bits `en` [T, L], state-major and then by lane: what
+    ``jnp.nonzero(en.reshape(T * L), size=slots, fill_value=T * L)``
+    finds, as ``(pidx, lane, ok)`` of `slots` entries each, int32,
+    int32 and bool.  A slot at or past the count reads ``(T - 1, 0,
+    False)``.
+
+    `nonzero` lowers to a scatter-add of all T * L lanes, an element at
+    a time on the chip, to find the few that are enabled: 8-18 % of a
+    one-chip slice's busy seconds (PERF.md, PR 56).  Here a slot finds
+    its state by comparing its number with the running counts of the
+    states, and its lane by comparing its rank in that state with the
+    running count along the state's own bits: `slots` x T and `slots`
+    x L compares, no scatter, no sort, and no `cumsum` (a
+    `reduce_window` on the chip: the running counts are sums under a
+    triangle, which fuse with the compares).  Exact: the two products
+    multiply 0/1 by 0/1 (bfloat16 holds both) and accumulate in
+    float32, so each entry is an integer of at most L, far under
+    2**24; everything else is int32."""
+    T, L = en.shape
+    states = jnp.arange(T, dtype=I32)
+    lanes = jnp.arange(L, dtype=I32)
+    slot = jnp.arange(slots, dtype=I32)
+    row = en.sum(1, dtype=I32)
+    # enabled lanes in the states up to and with each one
+    end = ((states[None, :] <= states[:, None]) * row[None, :]).sum(
+        1, dtype=I32)
+    ok = slot < end[-1]
+    # a slot's state: the states whose running count it has passed
+    state = jnp.minimum(
+        (end[None, :] <= slot[:, None]).sum(1, dtype=I32), T - 1)
+    rank = slot - ((states[None, :] < state[:, None]) * row[None, :]).sum(
+        1, dtype=I32)
+    # the state's own bits, then their running count along the lanes
+    bits = jnp.dot((states[None, :] == state[:, None]).astype(jnp.bfloat16),
+                   en.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    upto = jnp.dot(bits.astype(jnp.bfloat16),
+                   (lanes[:, None] <= lanes[None, :]).astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    # the (rank + 1)-th set bit lies behind the lanes whose running
+    # count has not passed `rank`
+    lane = (upto <= rank[:, None].astype(jnp.float32)).sum(1, dtype=I32)
+    return jnp.where(ok, state, T - 1), jnp.where(ok, lane, 0), ok
+
+
 def static_cap(tile, full):
     """An action's expansion cap before the guard matrix has observed
     anything (and the floor calibration never shrinks below)."""
@@ -277,13 +325,13 @@ class Stage2:
     and the sharded step (`parallel/sharded_bfs.make_sharded_level`).
 
     `tile_pass(caps, width)` gives the function a tile body calls: per
-    action the `nonzero` of its enabled segment, then ONLY the blocks
-    of `block_rows(cap)` slots that hold an enabled lane (stage 1
-    counted them exactly) are gathered, expanded, fingerprinted,
-    invariant-checked and packed, and appended at the running end of
-    one dense tile-local queue.  What commits the queue (stage 3: a
-    local insert and scatter, or ownership buckets and an exchange)
-    is the engine's own.
+    action the selection of its enabled segment (`enabled_lanes`), then
+    ONLY the blocks of `block_rows(cap)` slots that hold an enabled
+    lane (stage 1 counted them exactly) are gathered, expanded,
+    fingerprinted, invariant-checked and packed, and appended at the
+    running end of one dense tile-local queue.  What commits the queue
+    (stage 3: a local insert and scatter, or ownership buckets and an
+    exchange) is the engine's own.
 
     The per-successor stages (`fp_stage`, `inv_stage`, and the block
     stages made on demand) are traced once for THIS kernel: a grown
@@ -444,8 +492,8 @@ class Stage2:
         [T, L_a] lanes of action a that are to be expanded (the guard
         matrix, masked by whatever the engine masks it by) and `cnts`
         their counts.  The queue is action-major and, within an
-        action, in `nonzero` order, and holds no slot of a block that
-        did not run: planes `rows` (packed where the run packs), `fp`,
+        action, in `enabled_lanes` order, and holds no slot of a block
+        that did not run: planes `rows` (packed where the run packs), `fp`,
         `en`, `aid`, `pidx` (the parent's row in the tile) and `lane`,
         and `moved` / `stat` where the engine asked for them; a slot no
         block wrote keeps its zeros, with `en` False, and `q_end` is
@@ -480,7 +528,6 @@ class Stage2:
             return jax.lax.full((width,) + shape, 0, dtype)
 
         def run(tile, en_segs, cnts, each):
-            T = en_segs[0].shape[0]
             if incremental:
                 with jax.named_scope(spans.FINGERPRINT):
                     parts = jax.vmap(kern.parent_parts)(tile)
@@ -500,16 +547,9 @@ class Stage2:
             if stats:
                 queue["stat"] = lanes(jnp.uint32, n_stat)
             q_end = jnp.asarray(0, I32)
-            for aid, name in enumerate(kern.action_names):
-                L_a = kern._lane_count(name)
-                TL = T * L_a
-                E_a = caps[aid]
+            for aid, E_a in enumerate(caps):
                 with jax.named_scope(spans.COMPACT):
-                    en_f = en_segs[aid].reshape(TL)
-                    (sel,) = jnp.nonzero(en_f, size=E_a, fill_value=TL)
-                    sel_ok = sel < TL
-                    pidx = jnp.clip(sel // L_a, 0, T - 1).astype(I32)
-                    lane_sel = (sel % L_a).astype(I32)
+                    pidx, lane_sel, sel_ok = enabled_lanes(en_segs[aid], E_a)
                 # only the blocks of the segment that hold an enabled
                 # lane are expanded: stage 1 counted them exactly.  A
                 # slot at or past cnt in a block that ran has sel_ok
@@ -1211,9 +1251,10 @@ class DeviceBFS:
             invariant-checked and packed.  A block is appended, packed,
             at the running end of ONE tile-local commit queue
             (ISSUE 30) with its action, parent index and lane per
-            slot: action-major and, within an action, in `nonzero`
-            order, so queue order == the per-action commit order, and
-            the queue holds no lane of a block that did not run;
+            slot: action-major and, within an action, state-major and
+            then by lane, so queue order == the per-action commit
+            order, and the queue holds no lane of a block that did not
+            run;
         (3) **commit in pieces**: the queue's written prefix is
             committed COMMIT_PIECE lanes at a time — batch dedup,
             FPSet ``insert_core``, scatter of rows and pointers — one
